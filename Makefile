@@ -2,7 +2,7 @@
 
 PY ?= python
 
-.PHONY: lint analyze gen-registry test test-slow tier1 bench bench-diff trace-report ckpt-bench serve-bench spec-bench pipeline-bench degrade-bench policy-bench sim-bench grow-bench overlap-bench master-bench goodput-bench pool-bench router-bench
+.PHONY: lint analyze gen-registry test test-slow tier1 chip-smoke bench trace-report ckpt-bench serve-bench spec-bench pipeline-bench degrade-bench policy-bench sim-bench grow-bench overlap-bench master-bench goodput-bench pool-bench router-bench
 
 # Lint = the project-native analyzer (always available, stdlib-only)
 # plus ruff (config in pyproject.toml). Ruff degrades to a skip when not
@@ -42,14 +42,17 @@ test-slow:
 tier1:
 	bash -c "set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=\$${PIPESTATUS[0]}; echo DOTS_PASSED=\$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?\$$' /tmp/_t1.log | tr -cd . | wc -c); exit \$$rc"
 
+# The quickest proof that the system still starts on the chip: kernels vs
+# the XLA reference, gpt2 124M through master -> agent -> worker, then the
+# server on the checkpoint it wrote. Needs a TPU (one chip; fails without).
+# The cross-chip path is `$(PY) chip_smoke.py --chips 4` on a four-chip host.
+chip-smoke:
+	$(PY) chip_smoke.py
+
+# Needs an accelerator: measures in-process and names the device on its
+# line; exits non-zero with no number when JAX finds only the CPU.
 bench:
 	$(PY) bench.py
-
-# Honest round-over-round bench comparison: newest BENCH_r*.json vs the
-# previous round, per numeric key, stale sections skipped (never compared
-# as if fresh). Nonzero exit on regressions beyond the 5% threshold.
-bench-diff:
-	$(PY) bench.py --diff
 
 # Incident forensics report: phase breakdowns of every committed
 # incident-<n>.json under $$OOBLECK_METRICS_DIR (or ./metrics), plus a
@@ -80,9 +83,9 @@ spec-bench:
 # schedule-replay bubble on 2 virtual CPU devices (also under bench.py's
 # "pipeline" key). Pure CPU — runs the same with or without a TPU.
 pipeline-bench:
-	JAX_PLATFORMS=cpu _OOBLECK_BENCH_PIPELINE=1 \
+	JAX_PLATFORMS=cpu \
 		XLA_FLAGS="--xla_force_host_platform_device_count=2" \
-		$(PY) bench.py
+		$(PY) bench.py --pipeline
 
 # Degraded-mode recovery microbench: reroute vs template re-instantiation
 # recovery-to-next-step latency + throughput retention on 4 virtual CPU

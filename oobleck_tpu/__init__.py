@@ -23,91 +23,3 @@ Layer map (mirrors reference SURVEY.md §1):
 """
 
 __version__ = "0.1.0"
-
-# Compat: `jax.shard_map` was promoted out of jax.experimental after 0.4.x;
-# on older jaxlib images the top-level name is missing and the experimental
-# version spells "which axes are manual" as the complementary `auto` set
-# instead of `axis_names`. Install an adapter once at package import so
-# every call site (and the tests) uses the one modern spelling.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _esm
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                   check_vma=None):
-        kw = {}
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kw["auto"] = auto
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kw)
-
-    _jax.shard_map = _shard_map
-
-# Compat: jax 0.4.x defaults `jax_threefry_partitionable` to False, under
-# which jitted `jax.random.*` draws take DIFFERENT values depending on the
-# output sharding the partitioner picks — so `init_fn` produces different
-# initial params on different mesh factorizations, breaking the "same loss
-# trajectory on every mesh" invariant (and any cross-mesh checkpoint
-# restore comparison). Partitionable threefry makes draws a pure function
-# of (key, position), invariant to sharding; it has been the default since
-# jax 0.4.36+ and this update is a no-op there.
-try:
-    _jax.config.update("jax_threefry_partitionable", True)
-except (AttributeError, KeyError):
-    pass  # flag retired: partitionable is the only behavior
-
-# Compat: jax 0.4.x defaults cross-process CPU collectives to "none", so any
-# multi-process CPU world (the test harness's jax.distributed worlds) fails
-# with "Multiprocess computations aren't implemented on the CPU backend".
-# jaxlib ships a gloo implementation; select it whenever a distributed
-# runtime is live (or about to come up) on the CPU platform. The flag only
-# matters before the CPU client is instantiated, which is why the update
-# rides jax.distributed.initialize — the one call that always precedes the
-# first backend touch in a multi-process world.
-
-
-def _enable_cpu_gloo_collectives() -> None:
-    # Local imports: this runs long after module init (module-level helper
-    # names are cleaned up below).
-    import os
-
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
-        return
-    try:
-        from jax._src import xla_bridge as _xb
-
-        if _xb.CPU_COLLECTIVES_IMPLEMENTATION.value == "none":
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (ImportError, AttributeError):
-        pass  # newer jax: gloo is already the default and the flag moved
-
-
-import jax.distributed as _jd
-
-if not getattr(_jd.initialize, "_oobleck_gloo_wrapped", False):
-    _orig_distributed_initialize = _jd.initialize
-
-    def _initialize_with_cpu_gloo(*args, **kwargs):
-        _enable_cpu_gloo_collectives()
-        return _orig_distributed_initialize(*args, **kwargs)
-
-    _initialize_with_cpu_gloo._oobleck_gloo_wrapped = True
-    _jd.initialize = _initialize_with_cpu_gloo
-
-try:
-    # Importing oobleck_tpu AFTER jax.distributed.initialize (external test
-    # drivers do this) still precedes the first computation: fix the flag now.
-    from jax._src import distributed as _dist
-
-    if _dist.global_state.client is not None:
-        _enable_cpu_gloo_collectives()
-except (ImportError, AttributeError):
-    pass
-del _jax, _jd

@@ -8,9 +8,11 @@ single JAX process) instead of one per GPU with CUDA_VISIBLE_DEVICES pinning
 
 Responsibilities:
   * register with the master over TCP, receive the job args;
-  * ensure profile data exists for the model (runs the profiler on miss,
-    reference _run_profiler, agent.py:84-110);
-  * spawn the worker with a multiprocessing Pipe for control messages;
+  * spawn the worker with a multiprocessing Pipe for control messages.
+    The agent itself never touches JAX: a chip belongs to one process at
+    a time, and that process is the worker — so profile-on-miss (the
+    reference's agent-side _run_profiler, agent.py:84-110) runs there, in
+    OobleckEngine's constructor;
   * relay the JAX coordinator address worker -> master and master -> worker
     (the reference's rank-0 port chain, agent.py:181-194);
   * on RECONFIGURATION: remove the lost ip, push it down the worker pipe; if
@@ -155,11 +157,9 @@ class OobleckAgent:
         await self.connect_to_master()
         await self.register()
         # Heartbeats must start the moment we are registered: the master's
-        # read deadline (3x ping cadence) is already ticking, and the
-        # profile-on-miss bring-up below is compile-bound — minutes, not
-        # seconds. Pinging only after profiling would get a healthy agent
-        # evicted as hung before its worker ever launched, so the bring-up
-        # runs off-thread while the event loop keeps the control plane live.
+        # read deadline (3x ping cadence) is already ticking, so the worker
+        # spawn runs off-thread while the event loop keeps the control
+        # plane live.
         tasks = [self._bringup(), self.response_loop(),
                  self.ping_loop(), self.worker_port_loop(),
                  self.worker_watch_loop()]
@@ -176,7 +176,6 @@ class OobleckAgent:
         await asyncio.gather(*tasks)
 
     async def _bringup(self) -> None:
-        await asyncio.to_thread(self.ensure_profile)
         async with self._worker_lock:
             if self.worker is None:  # a mid-bringup respawn already launched
                 await asyncio.to_thread(self.launch_worker)
@@ -393,24 +392,6 @@ class OobleckAgent:
         )
 
     # ------------------------------------------------------------------ #
-
-    def ensure_profile(self) -> None:
-        """Profile-on-miss (reference _launch_workers, agent.py:112-134)."""
-        assert self.args is not None
-        from oobleck_tpu.planning.profiler import (
-            effective_tag,
-            get_profile_path,
-            profile,
-        )
-
-        m = self.args.model
-        ex = self.args.execution
-        path = get_profile_path(m.model_name, effective_tag(m.model_tag, ex))
-        if not (path / f"mb{self.args.job.microbatch_size}.json").exists():
-            logger.info("profile missing for %s; profiling now", m.model_name)
-            profile(m.model_name, m.model_args, model_tag=m.model_tag,
-                    execution=ex,
-                    microbatch_size=self.args.job.microbatch_size)
 
     def launch_worker(self) -> None:
         """One worker per host with a control pipe (reference agent.py:148-174)."""

@@ -248,6 +248,7 @@ class OobleckMasterDaemon:
         self._reconcile_task: asyncio.Task | None = None
         self._outage_trace_id: str | None = None
         self.metrics_port: int | None = None
+        self._connections: set[asyncio.StreamWriter] = set()
         self._http: metrics.MetricsHTTPServer | None = None
         reg = metrics.registry()
         self._m_agents = reg.gauge(
@@ -408,6 +409,8 @@ class OobleckMasterDaemon:
     async def stop(self) -> None:
         if self._server is not None:
             self._server.close()
+            for writer in list(self._connections):
+                writer.close()
             await self._server.wait_closed()
         if self._http is not None:
             self._http.close()
@@ -647,6 +650,19 @@ class OobleckMasterDaemon:
 
     async def _on_connected(self, reader: asyncio.StreamReader,
                             writer: asyncio.StreamWriter) -> None:
+        # Every accepted connection is closed by the master when its
+        # handler is done with it, and by stop() while it is not: since
+        # Python 3.12 Server.wait_closed() waits for each of them, and a
+        # half-open or still-attached peer would hold stop() forever.
+        self._connections.add(writer)
+        try:
+            await self._serve_connection(reader, writer)
+        finally:
+            self._connections.discard(writer)
+            writer.close()
+
+    async def _serve_connection(self, reader: asyncio.StreamReader,
+                                writer: asyncio.StreamWriter) -> None:
         try:
             # Bounded first read: a connection that registers nothing within
             # a default heartbeat deadline is dead weight (or a socket-

@@ -118,6 +118,16 @@ def worker_main(pipe, agent_ip: str, args_dict: dict) -> None:
         _init_jax_distributed(pipe, agent_ip, args)
 
     from oobleck_tpu.execution.engine import OobleckEngine
+    from oobleck_tpu.utils.compile_cache import ensure_persistent_cache
+
+    # This process owns the host's chips from here on: profile-on-miss
+    # (inside the engine's constructor), planning and every compile.
+    import jax
+
+    local = jax.local_devices()
+    logger.info("worker on %d x %s (%s), compile cache %s", len(local),
+                local[0].device_kind, local[0].platform,
+                ensure_persistent_cache())
 
     engine = OobleckEngine(args, agent_ip=agent_ip, agent_pipe=pipe)
     engine.initialize_distributed()

@@ -31,8 +31,23 @@ def _pallas_ok() -> bool:
     toggles: off-TPU the kernels would run in interpreter mode — correct but
     slow — so auto selection falls back to XLA and explicit pallas requests
     flip `interpret=True` (CPU parity tests). One helper so the policy and
-    the toggle can never disagree."""
-    return jax.default_backend() == "tpu"
+    the toggle can never disagree.
+
+    That fallback is for processes with no TPU. One that can reach a TPU
+    while its default backend is something else would run the interpreter
+    or the XLA reference beside an idle chip, so there the question is an
+    error, not False."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return True
+    try:
+        jax.devices("tpu")
+    except RuntimeError:  # no TPU backend in this process
+        return False
+    raise RuntimeError(
+        f"a TPU is visible but the default JAX backend is {backend!r}: "
+        "refusing to pick interpret-mode Pallas or the XLA reference in "
+        "its place (fix JAX_PLATFORMS, or ask for attention_impl 'xla')")
 
 
 def _xla_causal_attention(

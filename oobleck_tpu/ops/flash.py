@@ -258,9 +258,18 @@ def _canon_bias(bias, h, s_len):
 
 def _interpret() -> bool:
     # Interpreter mode off-TPU: tests validate kernel math on the CPU mesh.
-    from oobleck_tpu.ops.attention import _pallas_ok
+    from oobleck_tpu.ops import attention
 
-    return not _pallas_ok()
+    return not attention._pallas_ok()
+
+
+def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A `pallas_call` out_shape entry varying over every mesh axis any
+    operand varies over: inside a `check_vma=True` shard_map (the fused
+    step's three phases, the MPMD stage programs) Pallas refuses an output
+    whose varying-manual-axes it would have to guess."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _bias_specs(has_bias: bool, h: int, outer_is_q: bool):
@@ -303,11 +312,14 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
         pl.BlockSpec((1, BLOCK_K, dp), lambda b_, qi, ki: (b_, ki, 0)),
     ]
     o_spec = pl.BlockSpec((1, BLOCK_Q, dp), lambda b_, qi, ki: (b_, qi, 0))
-    o_shape = jax.ShapeDtypeStruct((bh, sp, dp), q.dtype)
+    operands = ([q, k, v] + ([bias] if has_bias else [])
+                + ([slopes] if has_slopes else []))
+    o_shape = _out_struct((bh, sp, dp), q.dtype, *operands)
     if emit_lse:
         # The LSE residual is only needed when a backward pass will run;
         # forward-only (eval) calls skip the extra [BH, S, 128] HBM write.
-        out_shape = (o_shape, jax.ShapeDtypeStruct((bh, sp, LANE), jnp.float32))
+        out_shape = (o_shape,
+                     _out_struct((bh, sp, LANE), jnp.float32, *operands))
         out_specs = (o_spec, pl.BlockSpec((1, BLOCK_Q, LANE),
                                           lambda b_, qi, ki: (b_, qi, 0)))
     else:
@@ -325,8 +337,7 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
             pltpu.VMEM((BLOCK_Q, LANE), jnp.float32),
         ],
         interpret=_interpret(),
-    )(*([q, k, v] + ([bias] if has_bias else [])
-        + ([slopes] if has_slopes else [])))
+    )(*operands)
 
     out, lse = result if emit_lse else (result, None)
     out = out.reshape(b, h, sp, dp)[:, :, :s_len, :d]
@@ -351,6 +362,7 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
 
     common = ([qp, kp, vp, op, gp, lse] + ([bias] if has_bias else [])
               + ([slopes] if has_slopes else []))
+    grad_shape = _out_struct((bh, sp, dp), jnp.float32, *common)
 
     def qspec(inner_kv: bool):
         # index maps for (q-like, kv-like, lse) inputs under the two grids
@@ -373,7 +385,7 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
         functools.partial(_dq_kernel, scale=scale, blocks_k=blocks_k,
                           causal=causal, has_bias=has_bias,
                           has_slopes=has_slopes, kv_len=s_len),
-        out_shape=jax.ShapeDtypeStruct((bh, sp, dp), jnp.float32),
+        out_shape=grad_shape,
         grid=(bh, blocks_q, blocks_k),
         in_specs=(qspec(inner_kv=True)
                   + _bias_specs(has_bias, h, outer_is_q=True)
@@ -387,10 +399,7 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
         functools.partial(_dkv_kernel, scale=scale, blocks_q=blocks_q,
                           causal=causal, has_bias=has_bias,
                           has_slopes=has_slopes, kv_len=s_len),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, sp, dp), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sp, dp), jnp.float32),
-        ),
+        out_shape=(grad_shape, grad_shape),
         grid=(bh, blocks_k, blocks_q),
         in_specs=(qspec(inner_kv=False)
                   + _bias_specs(has_bias, h, outer_is_q=False)

@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from oobleck_tpu.ops.flash import _interpret, _out_struct
+
 NEG_INF = -1e9
 LANE = 128
 
@@ -234,13 +236,6 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
-def _interpret() -> bool:
-    # Interpreter mode off-TPU, same toggle as the flash kernel.
-    from oobleck_tpu.ops.attention import _pallas_ok
-
-    return not _pallas_ok()
-
-
 def _paged_decode_pallas(
     q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     block_tables: jax.Array, lengths: jax.Array, *,
@@ -301,7 +296,7 @@ def _paged_decode_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dp), q.dtype),
+        out_shape=_out_struct((b, hkv, g, dp), q.dtype, *operands),
         interpret=_interpret(),
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *operands)
@@ -424,7 +419,7 @@ def _paged_verify_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, t * g, dp), q.dtype),
+        out_shape=_out_struct((b, hkv, t * g, dp), q.dtype, *operands),
         interpret=_interpret(),
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *operands)

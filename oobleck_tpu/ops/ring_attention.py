@@ -32,10 +32,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    # lax.axis_size is a post-0.4.x name; psum of a literal is the classic
-    # spelling and constant-folds to a concrete int on every version.
-    n = (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-         else int(lax.psum(1, axis_name)))
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     s_local = q.shape[2]
     qf = q.astype(jnp.float32)

@@ -159,13 +159,8 @@ def pvary_to(x, axes: tuple[str, ...]):
     lax.cond requires both branches to have identical varying-manual-axes
     types; this normalizes a branch output (or pytree) to a superset target.
     """
-    if not hasattr(lax, "pcast"):
-        # Pre-vma jax (no lax.pcast): shard_map carries no varying-manual-
-        # axes types, so branch types already agree — nothing to normalize.
-        return x
-
     def one(v):
-        have = set(getattr(v.aval, "vma", ()) or ())
+        have = jax.typeof(v).vma
         missing = tuple(a for a in axes if a not in have)
         return lax.pcast(v, missing, to="varying") if missing else v
 

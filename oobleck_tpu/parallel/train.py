@@ -144,15 +144,30 @@ def estimate_flops_per_token(n_params: int, seq_len: int, *,
     return 6.0 * n_params + 6.0 * (num_layers * hidden_size * seq_len)
 
 
-def peak_flops(device_kind: str) -> float | None:
-    """Peak bf16 FLOP/s per chip by TPU generation (public specs);
-    None for unknown kinds (CPU, GPU) — MFU is then unreported."""
-    kind = device_kind.lower()
-    for tag, peak in (("v5 lite", 197e12), ("v5e", 197e12),
-                      ("v5p", 459e12), ("v6", 918e12), ("v4", 275e12)):
-        if tag in kind:
-            return peak
-    return None
+# Peak dense bf16 FLOP/s per chip, keyed by `jax.Device.device_kind`
+# (Google Cloud TPU documentation, per-generation system architecture pages:
+# v4 275, v5e 197, v5p 459, v6e 918 TFLOP/s).
+PEAK_BF16_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
+}
+
+
+def peak_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s of one chip of this kind. A kind that is not in the
+    table is an error, not a default: an MFU against a guessed peak is a
+    wrong number. Callers on a backend with no such peak (CPU) do not ask."""
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s known for device kind {device_kind!r}; add it "
+            "to PEAK_BF16_FLOPS with its source") from None
 
 
 def mfu_estimate(tokens_per_sec: float, flops_per_token: float,

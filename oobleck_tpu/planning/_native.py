@@ -3,13 +3,18 @@
 The reference binds its C++ planner with pybind11 (csrc/planning/bind.cpp);
 pybind11 is not in this image, so the native side exposes a C API and this
 module marshals flat arrays in and JSON out. The .so is built on demand with
-the csrc Makefile and cached next to the source.
+the csrc Makefile and cached next to the source under a name that carries
+the source's digest: the binary is git-ignored, so a checkout, a copy of
+one, or an edit of planner.cpp each find (or build) the binary of THEIR
+source, and file times — which a copy does not keep — decide nothing.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
+import os
 import subprocess
 from pathlib import Path
 
@@ -18,7 +23,6 @@ import numpy as np
 from oobleck_tpu.planning.templates import LayerProfile, PipelineTemplate
 
 _CSRC = Path(__file__).resolve().parent.parent / "csrc"
-_SO = _CSRC / "libplanner.so"
 _lib = None
 
 
@@ -26,11 +30,18 @@ def _load() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    if not _SO.exists() or _SO.stat().st_mtime < (_CSRC / "planner.cpp").stat().st_mtime:
+    digest = hashlib.sha256((_CSRC / "planner.cpp").read_bytes()).hexdigest()
+    so = _CSRC / f"libplanner-{digest[:12]}.so"
+    if not so.exists():
+        # Built under a private name and renamed into place: a concurrent
+        # process (test workers on a fresh checkout) never loads half a file.
+        tmp = so.with_name(f"{so.stem}.{os.getpid()}.tmp.so")
         subprocess.run(
-            ["make", "-C", str(_CSRC)], check=True, capture_output=True, text=True
+            ["make", "-C", str(_CSRC), f"TARGET={tmp.name}"],
+            check=True, capture_output=True, text=True,
         )
-    lib = ctypes.CDLL(str(_SO))
+        os.replace(tmp, so)
+    lib = ctypes.CDLL(str(so))
     lib.planner_create_templates.restype = ctypes.c_char_p
     lib.planner_create_templates.argtypes = [
         ctypes.c_int,                      # num_layers

@@ -27,9 +27,12 @@ native with Python fallback.
 
 from __future__ import annotations
 
+import logging
 import math
 from dataclasses import dataclass, field
 from functools import lru_cache
+
+logger = logging.getLogger("oobleck.planning")
 
 
 @dataclass(frozen=True)
@@ -249,12 +252,19 @@ class TemplateGenerator:
             try:
                 from oobleck_tpu.planning import _native
 
-                return _native.create_pipeline_templates(
+                templates = _native.create_pipeline_templates(
                     profiles, num_hosts, chips_per_host
                 )
-            except Exception:  # noqa: BLE001 — auto mode falls back to python
+                logger.info("pipeline templates from the native planner")
+                return templates
+            except Exception as e:  # noqa: BLE001 — auto falls back to python
                 if self.engine == "native":
                     raise
+                # Same templates, slower: say so, a missing compiler or a
+                # broken build must not pass unnoticed.
+                logger.warning(
+                    "native planner unavailable (%s: %s); pipeline templates "
+                    "from the Python planner", type(e).__name__, e)
         return _python_create_templates(profiles, num_hosts, chips_per_host,
                                         virtual_stages,
                                         comm_hidden_fraction)

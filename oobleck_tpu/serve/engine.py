@@ -17,9 +17,9 @@ prefill/decode executables. Two cache disciplines:
 
 Prompt lengths are padded to a small set of power-of-two buckets so the
 number of distinct prefill programs is O(log max_seq) instead of one per
-prompt length; both program families route through the PR 1 persistent
+prompt length; both program families route through the persistent
 compilation cache (`utils/compile_cache.ensure_persistent_cache`) so a
-server cold-start deserializes instead of recompiling. The paged engine
+server cold-start on an accelerator deserializes instead of recompiling. The paged engine
 additionally buckets cached-head page counts (prefix hits) the same way;
 head-bucket programs compile lazily on first hit and persist like the
 rest.
@@ -34,7 +34,6 @@ batcher-side swap is a pointer assignment.
 from __future__ import annotations
 
 import logging
-import os
 import threading
 
 import jax
@@ -48,10 +47,7 @@ from oobleck_tpu.serve.kv_blocks import (
     pages_for,
 )
 from oobleck_tpu.utils import metrics
-from oobleck_tpu.utils.compile_cache import (
-    cache_event,
-    ensure_persistent_cache,
-)
+from oobleck_tpu.utils.compile_cache import ensure_persistent_cache
 
 logger = logging.getLogger("oobleck.serve")
 
@@ -85,21 +81,11 @@ class _EngineBase:
 
         self.compile_cache_dir = ensure_persistent_cache()
         if self.compile_cache_dir is not None:
-            # JAX creates the dir lazily on first write; hit/miss
-            # classification (entry-count deltas) needs it to exist now.
-            try:
-                os.makedirs(self.compile_cache_dir, exist_ok=True)
-            except OSError:
-                self.compile_cache_dir = None
-        if self.compile_cache_dir is not None:
             # Decode programs are tiny and compile fast; the default
             # min-compile-time threshold would skip persisting them, and a
             # server cold-start wants ALL its programs served from cache.
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0)
-            except AttributeError:
-                pass
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0.0)
 
         self.params = None          # device-resident fused tree
         self.params_step: int = -1  # checkpoint step the weights came from
@@ -128,28 +114,6 @@ class _EngineBase:
         zero-dropped-requests contract."""
         self.params = device_params
         self.params_step = int(step)
-
-    # -- compile accounting --------------------------------------------- #
-
-    def _cache_entries(self) -> int | None:
-        d = self.compile_cache_dir
-        if not d or not os.path.isdir(d):
-            return None
-        try:
-            return sum(1 for n in os.listdir(d) if not n.startswith("."))
-        except OSError:
-            return None
-
-    def _classified(self, fn):
-        """Run one first-compile call, classifying it as a persistent-cache
-        hit (no new entry appeared in the cache dir) or miss."""
-        before = self._cache_entries()
-        out = fn()
-        jax.block_until_ready(out)
-        after = self._cache_entries()
-        if before is not None and after is not None:
-            cache_event("serve_hit" if after == before else "serve_miss")
-        return out
 
     def bucket_for(self, n: int) -> int | None:
         for b in self.prefill_buckets:
@@ -186,14 +150,13 @@ class DecodeEngine(_EngineBase):
         n = 0
         for b in self.prefill_buckets:
             tokens = jnp.zeros((1, b), jnp.int32)
-            logits, self.cache = self._classified(
-                lambda t=tokens: self._prefill_fn(
-                    self.params, self.cache, t, jnp.int32(0), jnp.int32(1)))
+            logits, self.cache = jax.block_until_ready(self._prefill_fn(
+                self.params, self.cache, tokens, jnp.int32(0), jnp.int32(1)))
             n += 1
         token = jnp.zeros((self.slots,), jnp.int32)
         pos = jnp.zeros((self.slots,), jnp.int32)
-        (logits, self.cache) = self._classified(
-            lambda: self._decode_fn(self.params, self.cache, token, pos))
+        logits, self.cache = jax.block_until_ready(
+            self._decode_fn(self.params, self.cache, token, pos))
         n += 1
         logger.info("serve warmup: %d programs (buckets %s), cache dir %s",
                     n, self.prefill_buckets, self.compile_cache_dir)
@@ -337,23 +300,20 @@ class PagedDecodeEngine(_EngineBase):
         tables = jnp.zeros((self.table_pages,), jnp.int32)
         for b in self.prefill_buckets:
             tokens = jnp.zeros((1, b), jnp.int32)
-            logits, self.cache = self._classified(
-                lambda t=tokens: self._prefill_fn(
-                    self.params, self.cache, t, tables, jnp.int32(1)))
+            logits, self.cache = jax.block_until_ready(self._prefill_fn(
+                self.params, self.cache, tokens, tables, jnp.int32(1)))
             n += 1
         head = jnp.zeros((self.head_buckets[0],), jnp.int32)
         tokens = jnp.zeros((1, self.prefill_buckets[0]), jnp.int32)
-        logits, self.cache = self._classified(
-            lambda: self._prefill_head_fn(
-                self.params, self.cache, tokens, tables, jnp.int32(1),
-                head, jnp.int32(0)))
+        logits, self.cache = jax.block_until_ready(self._prefill_head_fn(
+            self.params, self.cache, tokens, tables, jnp.int32(1),
+            head, jnp.int32(0)))
         n += 1
         token = np.zeros((self.lanes,), np.int32)
         pos = np.zeros((self.lanes,), np.int32)
-        (logits, self.cache) = self._classified(
-            lambda: self._decode_fn(
-                self.params, self.cache, jnp.asarray(token),
-                jnp.asarray(self.tables), jnp.asarray(pos)))
+        logits, self.cache = jax.block_until_ready(self._decode_fn(
+            self.params, self.cache, jnp.asarray(token),
+            jnp.asarray(self.tables), jnp.asarray(pos)))
         n += 1
         logger.info(
             "paged serve warmup: %d programs (buckets %s, head buckets %s, "
@@ -459,11 +419,9 @@ class PagedDecodeEngine(_EngineBase):
         tokens = np.zeros((self.lanes, t), np.int32)
         pos = np.zeros((self.lanes,), np.int32)
         live = np.zeros((self.lanes,), np.int32)
-        (logits, self.cache) = self._classified(
-            lambda: self._get_verify_fn()(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(self.tables), jnp.asarray(pos),
-                jnp.asarray(live)))
+        logits, self.cache = jax.block_until_ready(self._get_verify_fn()(
+            self.params, self.cache, jnp.asarray(tokens),
+            jnp.asarray(self.tables), jnp.asarray(pos), jnp.asarray(live)))
         logger.info("paged serve warmup: verify program T=%d compiled", t)
 
     def verify(self, tokens: np.ndarray, pos: np.ndarray,

@@ -279,7 +279,12 @@ def main() -> None:  # pragma: no cover - exercised via ServingPlane in tests
         root, model_name=os.environ.get("OOBLECK_SERVE_MODEL"),
         model_args=json.loads(os.environ.get("OOBLECK_SERVE_MODEL_ARGS", "{}")))
     plane.start()
-    print(f"serving on :{plane.server.port} from {root}")
+    import jax
+
+    devs = jax.local_devices()
+    print(f"serving on :{plane.server.port} from {root} "
+          f"({len(devs)} x {devs[0].device_kind}, {devs[0].platform})",
+          flush=True)
     try:
         threading.Event().wait()
     except KeyboardInterrupt:

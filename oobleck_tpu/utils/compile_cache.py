@@ -1,52 +1,63 @@
-"""Shared policy for JAX's persistent compilation cache.
+"""One rule for JAX's persistent compilation cache.
 
-The CPU test/gate environments are compile-bound, so the cache is ON by
-default; every consumer (tests/conftest.py, the multi-process test worlds,
-the __graft_entry__ driver gate, the recovery precompiler) resolves the
-SAME directory through this helper so subprocess worlds share entries with
-the in-process suite.
+  * `JAX_COMPILATION_CACHE_DIR` set: JAX reads it itself and that is the
+    whole mechanism. No code here, or anywhere in the repo, sets another
+    directory.
+  * unset: the cache is `<checkout>/.jax_cache` (git-ignored). The path is
+    fixed beside the code because it is what the next process has to find
+    again: it never depends on a temporary directory, a user, a pid, the
+    host CPU or the clock.
 
-Knobs:
-  * OOBLECK_JAX_CC=0 disables the cache everywhere;
-  * JAX_COMPILATION_CACHE_DIR overrides the location (taken verbatim —
-    permissions and sharing are then the operator's call).
+Every process that owns an accelerator — the worker, the serve engine,
+bench.py, chip_smoke.py's children — calls `ensure_persistent_cache()`
+before it compiles.
 
-The default dir is per-user (created 0700: cached executables are code,
-and a world-writable shared dir would let any local user plant entries
-another user's training job deserializes and runs), and keyed by jaxlib
-version PLUS a digest of the host CPU's feature flags: XLA:CPU specializes
-codegen to the detected ISA (AVX-512 vs AVX2 ...), so entries written on
-one machine can be subtly wrong on another when /tmp is shared or images
-are snapshotted across heterogeneous fleets. A poisoned entry CAN wedge
-execution (observed once: a hang inside a float(loss) readback on a cached
-fused program) — the remedy is removing the cache dir.
+On the CPU backend the cache is switched OFF instead. With this jax/jaxlib
+a warm XLA:CPU entry of a multi-device program (the 8-virtual-device fused
+step's `init_fn`/`step_fn`) aborts the process inside the first loss
+readback — not every time (10 of 20 warm runs of tests/test_smoke.py -k
+fused; 0 of 10 cold or cache-off runs), with intact entries written by the
+same machine. An abort takes a whole pytest process with it, so a CPU
+world must not read such an entry at all, whoever set the directory.
 """
 
 from __future__ import annotations
 
-import getpass
-import hashlib
 import logging
 import os
-import platform
-import tempfile
 import zlib
+from pathlib import Path
 
 logger = logging.getLogger("oobleck.compile_cache")
 
-_cpu_sig_cache: str | None = None
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+# For the environment of a CPU child process (multi-process test worlds,
+# the multichip dry run): they compile before any oobleck code can switch
+# the cache off for them.
+CPU_WORLD_ENV = {"JAX_ENABLE_COMPILATION_CACHE": "false"}
 
 # Compressed-entry magics: jax's compilation cache compresses serialized
 # executables with zstandard when importable, zlib otherwise.
 _ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
 _SCRUB_STAMP = ".oobleck_scrub_stamp"
+# JAX names an executable `<module>-<key>-cache`; beside it, where the cache
+# has a size cap, sits `<module>-<key>-atime`, eight raw bytes of clock that
+# belong to JAX's eviction and are nobody's to validate.
+_ENTRY_SUFFIX = "-cache"
+
+_JAX_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "entry_read",
+    "/jax/compilation_cache/cache_misses": "entry_written",
+}
+_JAX_COMPILE_SECONDS = "/jax/core/compile/backend_compile_duration"
+_listening = False
 
 
 def cache_event(event: str, n: int = 1) -> None:
-    """Count one persistent-cache event (enabled/disabled/hit/miss) in the
-    metrics registry. Hits/misses come from the recovery precompiler (the
-    one consumer that can tell a deserialization from a cold compile);
-    enable/disable comes from ensure_persistent_cache."""
+    """Count one persistent-cache event in the metrics registry.
+    `entry_read` / `entry_written` are JAX's own hit and write events;
+    enabled/disabled come from ensure_persistent_cache, hit/miss from the
+    recovery precompiler's walk."""
     if n <= 0:
         return
     from oobleck_tpu.utils import metrics
@@ -56,60 +67,34 @@ def cache_event(event: str, n: int = 1) -> None:
         "Persistent compile-cache events by kind").inc(n, event=event)
 
 
-def host_cpu_signature() -> str:
-    """Short stable digest of the CPU features XLA:CPU specializes against.
+def _on_jax_event(event: str, **_) -> None:
+    if event in _JAX_EVENTS:
+        cache_event(_JAX_EVENTS[event])
 
-    Linux: the `flags`/`Features` lines of /proc/cpuinfo (one physical CPU's
-    worth — cores are homogeneous for codegen purposes). Elsewhere: the
-    machine/processor identifiers. Cached per process."""
-    global _cpu_sig_cache
-    if _cpu_sig_cache is not None:
-        return _cpu_sig_cache
-    feature_text = ""
+
+def _on_jax_duration(event: str, seconds: float, **_) -> None:
+    if event == _JAX_COMPILE_SECONDS:
+        from oobleck_tpu.utils import metrics
+
+        metrics.registry().counter(
+            "oobleck_compile_seconds_total",
+            "Seconds spent obtaining XLA executables (backend compiles and "
+            "persistent-cache reads)").inc(seconds)
+
+
+def persistent_cache_dir() -> str:
+    """`JAX_COMPILATION_CACHE_DIR` verbatim, else `<checkout>/.jax_cache`."""
+    return os.environ.get(ENV_DIR) or str(
+        Path(__file__).resolve().parents[2] / ".jax_cache")
+
+
+def cache_entries(d: str | None = None) -> int:
+    """Executables persisted under `d` (bookkeeping files excluded)."""
     try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                key = line.split(":", 1)[0].strip().lower()
-                if key in ("flags", "features"):
-                    feature_text = line.split(":", 1)[1].strip()
-                    break
+        return sum(1 for n in os.listdir(d or persistent_cache_dir())
+                   if n.endswith(_ENTRY_SUFFIX))
     except OSError:
-        pass
-    if not feature_text:
-        feature_text = f"{platform.machine()}/{platform.processor()}"
-    raw = f"{platform.machine()}|{feature_text}"
-    _cpu_sig_cache = hashlib.sha256(raw.encode()).hexdigest()[:12]
-    return _cpu_sig_cache
-
-
-def persistent_cache_dir() -> str | None:
-    """Resolved cache dir, or None when disabled (OOBLECK_JAX_CC=0).
-
-    The default location is created here with mode 0700 so every consumer
-    (including `_base_env` in the multi-process tests, which exports it to
-    subprocess worlds) gets a directory that already exists with the right
-    permissions."""
-    if os.environ.get("OOBLECK_JAX_CC", "1") == "0":
-        return None
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return os.environ["JAX_COMPILATION_CACHE_DIR"]
-    import jaxlib
-
-    try:
-        user = getpass.getuser()
-    except (KeyError, OSError):
-        user = f"uid{os.getuid()}"
-    d = os.path.join(
-        tempfile.gettempdir(),
-        f"oobleck_jax_cc_{user}",
-        f"{jaxlib.__version__}_{host_cpu_signature()}",
-    )
-    os.makedirs(d, mode=0o700, exist_ok=True)
-    # makedirs mode is masked by umask and ignored for pre-existing dirs;
-    # chmod makes 0700 unconditional on the user-level parent.
-    os.chmod(os.path.dirname(d), 0o700)
-    os.chmod(d, 0o700)
-    return d
+        return 0
 
 
 def _entry_corrupt(path: str) -> bool:
@@ -151,14 +136,14 @@ def _entry_corrupt(path: str) -> bool:
 
 
 def scrub_persistent_cache(d: str | None = None, *, force: bool = False) -> int:
-    """Detect and evict poisoned/corrupt persistent-cache entries.
+    """Detect and evict corrupt persistent-cache entries.
 
-    A cache entry that fails to decompress can wedge execution at USE time
-    (observed: a hang inside a float(loss) readback on a cached fused
-    program — the failure mode that broke the fused multiprocess recovery
-    test), so corruption is caught at startup instead: every entry newer
-    than the last scrub is validated and deleted on failure (JAX then
-    recompiles and rewrites it). Returns the number evicted.
+    JAX writes an entry in place, so a worker killed mid-write — which this
+    system does on purpose — leaves a truncated one. JAX then fails to read
+    it on every later start (a warning and a recompile) and never replaces
+    it. So every entry newer than the last scrub is validated at startup
+    and deleted on failure; JAX recompiles and rewrites it. Returns the
+    number evicted.
 
     Incremental via a stamp file so repeated startups only pay for new
     entries; `force=True` rescans everything."""
@@ -174,7 +159,7 @@ def scrub_persistent_cache(d: str | None = None, *, force: bool = False) -> int:
             pass
     evicted = 0
     for name in os.listdir(d):
-        if name.startswith("."):
+        if not name.endswith(_ENTRY_SUFFIX):
             continue
         path = os.path.join(d, name)
         try:
@@ -203,22 +188,29 @@ def scrub_persistent_cache(d: str | None = None, *, force: bool = False) -> int:
 
 
 def ensure_persistent_cache() -> str | None:
-    """Point JAX's persistent compilation cache at `persistent_cache_dir()`.
+    """Apply the module's rule to this process; idempotent. Returns the
+    directory in use, or None on the CPU backend, where the cache is off.
 
-    Idempotent; returns the effective dir (None when disabled). The warm
-    recovery path depends on this: AOT-compiling a predicted plan only
-    helps a later (re)compile if the serialized executable lands in a
-    persistent cache both sides share (execution/precompile.py)."""
-    d = persistent_cache_dir()
-    if d is None:
-        cache_event("disabled")
-        return None
+    Touches the backend (`jax.default_backend()`): call it from the process
+    that owns the chip, after `jax.distributed.initialize` where there is
+    one."""
+    global _listening
     import jax
 
-    if jax.config.jax_compilation_cache_dir != d:
+    if jax.default_backend() == "cpu":
+        if jax.config.jax_enable_compilation_cache:
+            jax.config.update("jax_enable_compilation_cache", False)
+            cache_event("disabled")
+        return None
+    d = persistent_cache_dir()
+    if not _listening:
         # First enable in this process: validate entries written since the
         # last scrub before anything deserializes them.
+        _listening = True
         scrub_persistent_cache(d)
-        jax.config.update("jax_compilation_cache_dir", d)
+        if not os.environ.get(ENV_DIR):
+            jax.config.update("jax_compilation_cache_dir", d)
+        jax.monitoring.register_event_listener(_on_jax_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
         cache_event("enabled")
     return d

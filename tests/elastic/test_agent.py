@@ -207,9 +207,8 @@ async def test_worker_watchdog_terminates_agent(job_args):
 
 @pytest.mark.asyncio
 async def test_heartbeats_flow_during_slow_bringup(job_args, monkeypatch):
-    """Profile-on-miss bring-up is compile-bound (minutes); the agent must
-    heartbeat through it, or the master's read deadline evicts a healthy
-    host before its worker ever launches."""
+    """A slow worker spawn must not starve the heartbeats, or the master's
+    read deadline evicts a healthy host before its worker ever launches."""
     import oobleck_tpu.elastic.master as master_mod
     monkeypatch.setattr(master_mod, "read_deadline", lambda interval: 0.5)
     daemon, task = await start_master_with_job(job_args)
@@ -217,11 +216,12 @@ async def test_heartbeats_flow_during_slow_bringup(job_args, monkeypatch):
     agent.ping_interval = 0.1
     release = threading.Event()
     launched = []
-    monkeypatch.setattr(agent, "ensure_profile", lambda: release.wait(30))
-    monkeypatch.setattr(agent, "launch_worker", lambda: launched.append(True))
+    monkeypatch.setattr(
+        agent, "launch_worker",
+        lambda: release.wait(30) and launched.append(True))
     run_task = asyncio.create_task(agent.run())
     try:
-        # Profiling blocks the bring-up for 3x the read deadline...
+        # The spawn blocks the bring-up for 3x the read deadline...
         await asyncio.sleep(1.5)
         # ...yet the pings kept the registration alive (and no
         # RECONFIGURATION self-terminated the run task).
@@ -258,3 +258,92 @@ async def test_ping_pong_through_response_loop(job_args):
     assert not loop_task.done()
     loop_task.cancel()
     task.cancel()
+
+
+_OFF_JAX_SCRIPT = '''
+import asyncio, multiprocessing as mp, sys
+
+from oobleck_tpu.config import OobleckArguments
+from oobleck_tpu.elastic import agent as agent_mod
+from oobleck_tpu.elastic.master import OobleckMasterDaemon
+from oobleck_tpu.elastic.run import OobleckClient
+
+
+class Launcher:
+    async def launch(self, ip, master_ip, master_port, args):
+        pass
+
+
+class DoneProcess:
+    """Stands in for the spawned worker: it has already trained and left."""
+    pid, exitcode = 0, 0
+
+    def __init__(self, **kw):
+        self.target = kw["target"]
+
+    def start(self):
+        pass
+
+    def is_alive(self):
+        return False
+
+
+class Ctx:
+    Pipe = staticmethod(mp.Pipe)
+    Process = DoneProcess
+
+
+async def main():
+    daemon = OobleckMasterDaemon(port=0, launcher=Launcher())
+    await daemon.start()
+    serve = asyncio.create_task(daemon.serve_forever())
+    args = OobleckArguments()
+    args.dist.master_ip, args.dist.master_port = "127.0.0.1", daemon.port
+    args.dist.node_ips = ["10.0.0.1"]
+    client = OobleckClient(args)          # the CLI's half
+    await client.connect_to_master()
+    await client.request_job_launch()
+
+    agent_mod.mp.get_context = lambda method: Ctx
+    agents.append(agent_mod.OobleckAgent("127.0.0.1", daemon.port, "10.0.0.1"))
+    # register -> bring-up -> launch_worker -> worker exit 0 -> JOB_DONE ->
+    # SystemExit(0), which leaves the event loop from inside its task.
+    await agents[0].run()
+
+
+agents = []
+try:
+    asyncio.run(main())
+except SystemExit as e:
+    assert e.code == 0, e.code
+else:
+    raise AssertionError("agent.run returned without the worker's exit")
+# launch_worker took the real path up to the spawn itself
+target = agents[0].worker.process.target
+assert target.__module__ == "oobleck_tpu.elastic.worker", target
+import jax  # the master imports it at module top; importing is harmless
+from jax._src import xla_bridge
+
+assert not xla_bridge.backends_are_initialized(), "control plane touched JAX"
+assert "oobleck_tpu.planning.profiler" not in sys.modules, "agent profiled"
+print("CONTROL_PLANE_OFF_JAX")
+'''
+
+
+def test_master_cli_and_agent_never_initialise_a_jax_backend():
+    """A chip belongs to one process at a time, and that process is the
+    worker. One fresh interpreter plays master, CLI and agent through
+    register -> bring-up -> launch_worker -> worker exit -> JOB_DONE; at the
+    end it has no JAX backend and never imported the profiler (profile-on-
+    miss lives in OobleckEngine's constructor, i.e. in the worker)."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    proc = subprocess.run(
+        [sys.executable, "-c", _OFF_JAX_SCRIPT],
+        cwd=Path(__file__).resolve().parents[2], capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "CONTROL_PLANE_OFF_JAX" in proc.stdout
+    assert not hasattr(OobleckAgent, "ensure_profile")

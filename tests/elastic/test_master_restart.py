@@ -241,6 +241,9 @@ async def test_register_survives_half_handshake(job_args):  # noqa: F811
             return
         await send_msg(writer, {"kind": ResponseType.SUCCESS.value,
                                 "args": job_args.to_dict()})
+        # Python 3.12: wait_closed() below waits for every connection this
+        # server still holds open, so the handler closes what it accepted.
+        writer.close()
 
     server = await asyncio.start_server(serve, "127.0.0.1", 0)
     port = server.sockets[0].getsockname()[1]

@@ -59,7 +59,7 @@ def test_reconfiguration_resumes(cache_env, devices8):
 
 def test_reconfigure_non_gpt_family(cache_env, devices8):
     """Failure recovery on a non-causal-LM family: weights survive, the
-    data position carries over, training keeps converging (VERDICT round-2
+    data position carries over, training keeps converging (round-2 review,
     order #2: at least one reconfiguration test off the gpt path)."""
     engine = make_engine(num_hosts=4, steps=10, devices=devices8,
                          microbatch=2, global_mb=8, model_name="bert-tiny")

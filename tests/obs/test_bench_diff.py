@@ -1,37 +1,108 @@
-"""bench.py --diff: the honest round-over-round comparison. Stale sections
-must be skipped with explicit provenance (never compared as if fresh),
-direction must follow the lower-is-better key classification, and only
-changes beyond the noise threshold may be reported."""
+"""bench.py: no accelerator, no number; host-side microbenches as CPU
+children that cannot take the headline down; and --diff, whose direction
+must follow the lower-is-better key classification and which reports only
+changes beyond the noise threshold."""
+
+import json
+import subprocess
+import sys
+import types
+from pathlib import Path
+
+import pytest
 
 import bench
 
-
-def test_stale_sections_are_skipped_not_compared():
-    old = {"metric": "tokens_per_second", "value": 100.0, "stale": True,
-           "stale_from": "r3",
-           "serve": {"ttft_p50_ms": 10.0, "stale": False}}
-    new = {"metric": "tokens_per_second", "value": 50.0, "stale": True,
-           "stale_from": "r3",
-           "serve": {"ttft_p50_ms": 10.0, "stale": False}}
-    lines, regressions = bench.bench_diff(old, new)
-    # the 2x headline "drop" is two replays of the same measurement: it
-    # must NOT be called a regression, and the skip names the source round
-    assert regressions == []
-    assert any("skipped: stale in both (from r3)" in line for line in lines)
-    assert not any("REGRESSION" in line for line in lines)
+REPO = Path(__file__).resolve().parents[2]
 
 
-def test_stale_on_one_side_still_skips():
-    old = {"value": 100.0, "stale": False, "stale_from": None}
-    new = {"value": 100.0, "stale": True, "stale_from": "r1"}
-    lines, regressions = bench.bench_diff(old, new)
-    assert regressions == []
-    assert any("stale in new" in line for line in lines)
+def test_no_accelerator_means_no_number():
+    """JAX held to the CPU: bench.py exits non-zero and prints nothing on
+    stdout — no replayed record, no CPU stand-in under a throughput name."""
+    proc = subprocess.run([sys.executable, str(REPO / "bench.py")],
+                          capture_output=True, text=True, timeout=120,
+                          cwd=REPO)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "found none" in proc.stderr
+
+
+def test_cpu_bench_child_env_and_result(monkeypatch):
+    """A microbench child is a CPU world: off the chip, persistent compile
+    cache off, out of this process's metrics sink, rig-sized virtual
+    devices, and without the ambient knobs its bench sets for itself."""
+    monkeypatch.setenv("OOBLECK_POLICY", "reroute")
+    monkeypatch.setenv("OOBLECK_METRICS_DIR", "/somewhere")
+    code = ("import json, os; print('noise'); print(json.dumps("
+            "{k: os.environ.get(k) for k in ('JAX_PLATFORMS', "
+            "'JAX_ENABLE_COMPILATION_CACHE', 'OOBLECK_METRICS_DIR', "
+            "'OOBLECK_POLICY', 'XLA_FLAGS')}))")
+    got = bench._run_cpu_bench(["-c", code], 60, devices=4,
+                               scrub=("OOBLECK_POLICY",))
+    assert got["JAX_PLATFORMS"] == "cpu"
+    assert got["JAX_ENABLE_COMPILATION_CACHE"] == "false"
+    assert got["OOBLECK_METRICS_DIR"] == ""
+    assert got["OOBLECK_POLICY"] is None
+    assert got["XLA_FLAGS"].endswith(
+        "--xla_force_host_platform_device_count=4")
+
+
+@pytest.mark.parametrize("code,timeout_s,needle", [
+    ("import sys; print('boom', file=sys.stderr); sys.exit(3)", 60,
+     "exit 3: boom"),
+    ("print('not json')", 60, "unparseable output"),
+    ("import time; time.sleep(30)", 1, "no result within 1s"),
+])
+def test_cpu_bench_failure_is_an_error_section(code, timeout_s, needle):
+    got = bench._run_cpu_bench(["-c", code], timeout_s)
+    assert set(got) == {"error"} and needle in got["error"]
+
+
+def test_every_cpu_bench_names_a_module_that_exists():
+    for key, (argv, timeout_s, devices, scrub) in bench._CPU_BENCHES.items():
+        target = argv[1] if argv[0] == "-m" else argv[0]
+        path = (REPO / (target.replace(".", "/") + ".py")
+                if argv[0] == "-m" else Path(target))
+        assert path.is_file(), (key, path)
+        assert timeout_s > 0 and all(k.startswith("OOBLECK_") for k in scrub)
+
+
+def test_peak_flops_is_a_keyed_table():
+    from oobleck_tpu.parallel.train import peak_flops
+
+    assert peak_flops("TPU v5 lite") == 197e12
+    # not a substring match, and no default: an unknown kind is an error
+    for kind in ("TPU v5 lite pod", "tpu v5 lite", "TPU v9", "cpu"):
+        with pytest.raises(KeyError, match="no peak FLOP/s known"):
+            peak_flops(kind)
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("cpu", None, 1),                      # the test backend's assumed 16 GiB
+    ("tpu", {"bytes_limit": 8 * 2**30}, 2),  # asked, not assumed
+    ("tpu", {}, RuntimeError),
+    ("tpu", None, RuntimeError),
+])
+def test_compute_min_hosts_asks_the_tpu_for_its_memory(platform, stats, want):
+    from oobleck_tpu.execution.engine import OobleckEngine
+
+    dev = types.SimpleNamespace(platform=platform,
+                                memory_stats=lambda: stats)
+    # 6 * 2 GiB of params + 1 GiB of activations = 13 GiB
+    eng = types.SimpleNamespace(
+        profiles=[types.SimpleNamespace(mem_params=2 * 2**30,
+                                        mem_activation=2**30)],
+        devices=[dev], chips_per_host=1)
+    if isinstance(want, int):
+        assert OobleckEngine.compute_min_hosts(eng) == want
+    else:
+        with pytest.raises(want, match="reports no memory limit"):
+            OobleckEngine.compute_min_hosts(eng)
 
 
 def test_regression_direction_higher_is_better():
-    old = {"value": 100.0, "stale": False}
-    new = {"value": 80.0, "stale": False}
+    old = {"value": 100.0}
+    new = {"value": 80.0}
     lines, regressions = bench.bench_diff(old, new)
     assert regressions == ["value"]
     assert any("REGRESSION" in line for line in lines)
@@ -41,8 +112,8 @@ def test_regression_direction_higher_is_better():
 
 
 def test_regression_direction_lower_is_better():
-    old = {"serve": {"ttft_p50_ms": 10.0, "stale": False}, "stale": False}
-    new = {"serve": {"ttft_p50_ms": 20.0, "stale": False}, "stale": False}
+    old = {"serve": {"ttft_p50_ms": 10.0}}
+    new = {"serve": {"ttft_p50_ms": 20.0}}
     _, regressions = bench.bench_diff(old, new)
     assert regressions == ["serve.ttft_p50_ms"]
     _, regressions = bench.bench_diff(new, old)
@@ -59,8 +130,8 @@ def test_throughput_keys_are_higher_is_better():
     for key in ("serve.ttft_p50_ms", "step_s", "recovery.total_s",
                 "pipeline.bubble_fraction", "latency"):
         assert bench._lower_is_better(key), key
-    old = {"pipeline": {"tokens_per_sec": 100.0}, "stale": False}
-    new = {"pipeline": {"tokens_per_sec": 150.0}, "stale": False}
+    old = {"pipeline": {"tokens_per_sec": 100.0}}
+    new = {"pipeline": {"tokens_per_sec": 150.0}}
     lines, regressions = bench.bench_diff(old, new)
     assert regressions == []  # 1.5x throughput is an improvement
     assert any("improved" in line for line in lines)
@@ -69,51 +140,18 @@ def test_throughput_keys_are_higher_is_better():
 
 
 def test_noise_below_threshold_is_silent():
-    old = {"value": 100.0, "stale": False}
-    new = {"value": 100.0 * (1 - bench.DIFF_THRESHOLD / 2), "stale": False}
+    old = {"value": 100.0}
+    new = {"value": 100.0 * (1 - bench.DIFF_THRESHOLD / 2)}
     lines, regressions = bench.bench_diff(old, new)
     assert lines == [] and regressions == []
 
 
 def test_new_and_gone_keys_reported_without_regression():
-    old = {"value": 1.0, "stale": False, "pipeline": {"bubble": 0.1}}
-    new = {"value": 1.0, "stale": False, "degrade": {"retention": 0.9}}
+    old = {"value": 1.0, "pipeline": {"bubble": 0.1}}
+    new = {"value": 1.0, "degrade": {"retention": 0.9}}
     lines, regressions = bench.bench_diff(old, new)
     assert regressions == []
     assert any("(new)" in line and "retention" in line for line in lines)
     assert any("(gone)" in line and "bubble" in line for line in lines)
 
 
-def test_probe_attempted_is_provenance_not_a_metric():
-    # probe_attempted is a boolean provenance stamp: the numeric diff must
-    # ignore it even when it flips between rounds (a round that probed and
-    # found the relay down vs one that crashed before probing is a fact
-    # about the harness, not a performance delta).
-    old = {"value": 1.0, "stale": False, "probe_attempted": False}
-    new = {"value": 1.0, "stale": False, "probe_attempted": True}
-    lines, regressions = bench.bench_diff(old, new)
-    assert regressions == []
-    assert not any("probe_attempted" in line for line in lines)
-
-
-def test_stamp_provenance_covers_every_section():
-    result = {"value": 1.0, "grow": {"join_to_step_s": 2.0},
-              "sim": {"error": "sim bench hung >120s"}}
-    bench._stamp_provenance(result)
-    assert result["probe_attempted"] in (True, False)
-    # Every dict-valued section carries explicit freshness — even an
-    # errored one (the error string is the signal, the stamp still lands).
-    for section in ("grow", "sim"):
-        assert result[section]["stale"] is False
-        assert result[section]["stale_from"] is None
-
-
-def test_probe_timeout_env(monkeypatch, capsys):
-    monkeypatch.delenv("BENCH_PROBE_TIMEOUT", raising=False)
-    assert bench._probe_timeout_s() == bench.PROBE_TIMEOUT_S
-    monkeypatch.setenv("BENCH_PROBE_TIMEOUT", "7")
-    assert bench._probe_timeout_s() == 7
-    monkeypatch.setenv("BENCH_PROBE_TIMEOUT", "0")
-    assert bench._probe_timeout_s() == 1  # floored: 0 would kill the probe
-    monkeypatch.setenv("BENCH_PROBE_TIMEOUT", "soon")
-    assert bench._probe_timeout_s() == bench.PROBE_TIMEOUT_S  # malformed

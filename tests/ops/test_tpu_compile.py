@@ -1,0 +1,184 @@
+"""The main path's Pallas kernels must COMPILE for the chip, not only run
+interpreted: each case AOT-compiles for a described (not attached) TPU v5e
+at real widths and checks the Mosaic kernel is in the executable.
+
+CPU interpret-mode parity (test_ops / test_paged_ops / test_verify_ops)
+cannot see a block the chip's tiling refuses, a kernel over its VMEM
+budget, or a `pallas_call` that will not trace inside the training step's
+`check_vma=True` shard_maps. Nothing executes here; no number comes out.
+
+`jax.default_backend()` is still "cpu" during such a compile, so the tests
+steer `ops.attention._pallas_ok` themselves — the program has no option
+for it.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs: not /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from oobleck_tpu.config import ServeArguments
+from oobleck_tpu.ops import attention
+from oobleck_tpu.ops.flash import flash_attention
+from oobleck_tpu.ops.paged_attention import (
+    _select_paged_impl,
+    _select_paged_verify_impl,
+    paged_decode_attention,
+    paged_verify_attention,
+)
+from oobleck_tpu.serve.kv_blocks import pages_for
+
+# [B, H, S, D] of one microbatch's attention call: gpt2 124M
+# (examples/gpt2.yaml: microbatch 8, 12 heads of 64, seq 1024), a llama-7B
+# head geometry (flash sees K/V already repeated to 32 heads of 128), and
+# gpt3-2.7b's head_dim 80 (padded to the 128-lane width in-kernel).
+FLASH_WIDTHS = {
+    "gpt2": (8, 12, 1024, 64),
+    "llama": (2, 32, 1024, 128),
+    "gpt3-2.7b": (2, 32, 1024, 80),
+}
+# (Hq, Hkv, D) of the serve pools: gpt2 MHA and llama-style GQA.
+PAGED_WIDTHS = {"gpt2": (12, 12, 64), "llama-gqa": (32, 8, 128)}
+
+
+def _serve_geometry():
+    """Lanes / pool pages / page size / table width / verify T exactly as
+    ServingPlane._build_engine and _build_spec derive them from the
+    ServeArguments defaults."""
+    a = ServeArguments()
+    num_pages = a.kv_pages or max(2, a.slots * a.max_seq // a.page_size)
+    lanes = a.lanes or max(a.slots, min(num_pages - 1, 8 * a.slots))
+    return (lanes, num_pages, a.page_size,
+            pages_for(a.max_seq, a.page_size), a.spec_k + 1)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def _compiled_for_tpu(monkeypatch):
+    """Kernels lower through Mosaic (not the interpreter), and the
+    persistent cache stays out of it: an executable for an unattached
+    device is written but can never be read back, and warns on the way."""
+    def forget_backend_choices():  # "auto" is resolved once per process
+        for choice in (attention.select_attention_impl, _select_paged_impl,
+                       _select_paged_verify_impl):
+            choice.cache_clear()
+
+    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
+    forget_backend_choices()
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+    forget_backend_choices()
+
+
+def _compile(fn, dev, *shapes):
+    one = SingleDeviceSharding(dev)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the executable"
+    return text
+
+
+def _grads(fn):
+    return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)),
+                    argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("width", sorted(FLASH_WIDTHS))
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_flash_compiles(v5e, width, mode):
+    shape = FLASH_WIDTHS[width]
+    fn = flash_attention if mode == "fwd" else _grads(flash_attention)
+    _compile(fn, v5e[0], *[(shape, jnp.bfloat16)] * 3)
+
+
+@pytest.mark.parametrize("form", ["alibi_slopes", "bias", "non_causal"])
+def test_flash_variant_compiles(v5e, form):
+    """The Bloom (in-kernel ALiBi), materialised-bias and encoder forms,
+    forward and backward, at gpt2 widths."""
+    b, h, s, d = FLASH_WIDTHS["gpt2"]
+    qkv = [((b, h, s, d), jnp.bfloat16)] * 3
+    if form == "alibi_slopes":
+        def fn(q, k, v, slopes):
+            return _grads(lambda q, k, v: flash_attention(
+                q, k, v, alibi_slopes=slopes))(q, k, v)
+        _compile(fn, v5e[0], *qkv, ((h,), jnp.float32))
+    elif form == "bias":
+        def fn(q, k, v, bias):
+            return _grads(lambda q, k, v: flash_attention(
+                q, k, v, bias=bias))(q, k, v)
+        _compile(fn, v5e[0], *qkv, ((h, s, s), jnp.float32))
+    else:
+        fn = _grads(lambda q, k, v: flash_attention(q, k, v, causal=False))
+        _compile(fn, v5e[0], *qkv)
+
+
+def test_flash_compiles_inside_check_vma_shard_map(v5e):
+    """The training step's shape: causal_attention(impl="auto") under a
+    default (check_vma=True) shard_map with the batch split over a data
+    axis, differentiated from OUTSIDE so the spec transposes run. A bare
+    out_shape (no `vma`) fails this at trace time on any TPU."""
+    mesh = Mesh(v5e[:2], ("data",))
+    spec = P("data")
+    sm = jax.shard_map(
+        lambda q, k, v: attention.causal_attention(q, k, v, impl="auto"),
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
+    b, h, s, d = FLASH_WIDTHS["gpt2"]
+    arg = jax.ShapeDtypeStruct((2 * b, h, s, d), jnp.bfloat16,
+                               sharding=NamedSharding(mesh, spec))
+    text = jax.jit(_grads(sm)).lower(arg, arg, arg).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("width", sorted(PAGED_WIDTHS))
+@pytest.mark.parametrize("kernel", ["decode", "verify"])
+def test_paged_compiles_at_serve_defaults(v5e, width, kernel):
+    hq, hkv, d = PAGED_WIDTHS[width]
+    lanes, num_pages, page, table_pages, t = _serve_geometry()
+    pool = ((num_pages, hkv, page, d), jnp.bfloat16)
+    tables = ((lanes, table_pages), jnp.int32)
+    lengths = ((lanes,), jnp.int32)
+    if kernel == "decode":
+        q = ((lanes, hq, d), jnp.bfloat16)
+        fn = paged_decode_attention
+    else:
+        q = ((lanes, t, hq, d), jnp.bfloat16)
+        fn = paged_verify_attention
+    _compile(fn, v5e[0], q, pool, pool, tables, lengths)
+
+
+def test_paged_alibi_compiles(v5e):
+    """Bloom serving: per-head slopes ride into both paged kernels."""
+    hq, hkv, d = PAGED_WIDTHS["gpt2"]
+    lanes, num_pages, page, table_pages, t = _serve_geometry()
+    pool = ((num_pages, hkv, page, d), jnp.bfloat16)
+    rest = (pool, pool, ((lanes, table_pages), jnp.int32),
+            ((lanes,), jnp.int32), ((hq,), jnp.float32))
+
+    def decode(q, kp, vp, bt, ln, slopes):
+        return paged_decode_attention(q, kp, vp, bt, ln, alibi_slopes=slopes)
+
+    def verify(q, kp, vp, bt, ln, slopes):
+        return paged_verify_attention(q, kp, vp, bt, ln, alibi_slopes=slopes)
+
+    _compile(decode, v5e[0], ((lanes, hq, d), jnp.bfloat16), *rest)
+    _compile(verify, v5e[0], ((lanes, t, hq, d), jnp.bfloat16), *rest)

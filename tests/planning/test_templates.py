@@ -111,13 +111,14 @@ def test_native_matches_python():
 
 
 def test_native_builds_from_clean_tree(tmp_path, monkeypatch):
-    """No binary blob ships in git (round-4 hygiene): a source tree with no
-    libplanner.so must transparently build it from planner.cpp on the next
-    use (build-on-import, planning/_native.py).
+    """No binary ships in git: a source tree with no planner binary (a
+    fresh checkout, or the copy of one the chip machine gets) must build it
+    from planner.cpp on first use, and a changed planner.cpp must never load
+    the binary of the old source — it builds its own, under a name that
+    carries the source's digest (file times do not survive a copy).
 
-    Runs against a COPY of csrc in tmp_path: the old version unlinked the
-    shared libplanner.so in-tree, racing every other test in the session
-    that had already loaded (or was about to load) the planner."""
+    Runs against a COPY of csrc in tmp_path so the in-tree binary other
+    tests have loaded is untouched."""
     import shutil
 
     from oobleck_tpu.planning import _native
@@ -125,17 +126,47 @@ def test_native_builds_from_clean_tree(tmp_path, monkeypatch):
     csrc = tmp_path / "csrc"
     csrc.mkdir()
     for src in _native._CSRC.iterdir():
-        if src.name != _native._SO.name:  # clean tree: sources only
+        if src.suffix != ".so":  # clean tree: sources only
             shutil.copy2(src, csrc / src.name)
     monkeypatch.setattr(_native, "_CSRC", csrc)
-    monkeypatch.setattr(_native, "_SO", csrc / _native._SO.name)
     monkeypatch.setattr(_native, "_lib", None)
     profiles = dummy_profiles(num_layers=6, chips_per_host=2, seed=0)
     out = _native.create_pipeline_templates(profiles, (1, 2), 2)
-    assert _native._SO.exists(), "build-on-import did not produce the .so"
     assert out, "rebuilt planner returned no templates"
-    # teardown restores _CSRC/_SO/_lib to their pre-test values, so later
+    [built] = csrc.glob("*.so")
+
+    with open(csrc / "planner.cpp", "a") as f:
+        f.write("\n// edited\n")
+    monkeypatch.setattr(_native, "_lib", None)
+    assert _native.create_pipeline_templates(profiles, (1, 2), 2)
+    assert len(list(csrc.glob("*.so"))) == 2, "edited source reused the binary"
+    assert built.exists()
+    # teardown restores _CSRC/_lib to their pre-test values, so later
     # tests keep using the real in-tree planner untouched.
+
+
+def test_auto_engine_says_when_it_falls_back_to_python(monkeypatch, caplog):
+    """engine="auto" survives a native planner that cannot build (no
+    compiler on the machine) with the same templates from the Python twin —
+    and says so; engine="native" raises."""
+    import logging
+
+    from oobleck_tpu.planning import _native
+
+    def no_compiler():
+        raise FileNotFoundError("make")
+
+    monkeypatch.setattr(_native, "_load", no_compiler)
+    profiles = dummy_profiles(num_layers=6, chips_per_host=2, seed=0)
+    with caplog.at_level(logging.WARNING, logger="oobleck.planning"):
+        got = TemplateGenerator(engine="auto").create_pipeline_templates(
+            profiles, (1, 2), 2)
+    assert got == TemplateGenerator(engine="python").create_pipeline_templates(
+        profiles, (1, 2), 2)
+    assert "native planner unavailable (FileNotFoundError" in caplog.text
+    with pytest.raises(FileNotFoundError):
+        TemplateGenerator(engine="native").create_pipeline_templates(
+            profiles, (1, 2), 2)
 
 
 def test_json_roundtrip(profiles):

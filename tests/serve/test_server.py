@@ -118,45 +118,26 @@ def test_tokens_from_body_validation():
             tokens_from_body(bad, 10)
 
 
-def test_warmup_routes_through_persistent_compile_cache():
-    """Satellite (c): serve jits go through ensure_persistent_cache and
-    every warmup program is classified as a persistent-cache hit or miss.
-    A second engine after jax.clear_caches() recompiles nothing new — the
-    disk cache (warmed by the first engine, or by a previous run of this
-    very test) serves every program.
-
-    NOTE: the dir is NOT monkeypatched — JAX initializes its persistent-
-    cache singleton once per process, so the engine must account against
-    the dir this process actually writes (the conftest-wired one)."""
+def test_warmup_compiles_every_program_with_the_cpu_cache_off():
+    """Serve jits go through ensure_persistent_cache: on the CPU backend
+    that switches the persistent cache off (a warm XLA:CPU entry can abort
+    the process), the engine records no directory, and warmup still
+    compiles every prefill bucket and the decode step up front — twice
+    over for a second engine after jax.clear_caches()."""
     from oobleck_tpu.models import build_model
     from oobleck_tpu.serve.engine import DecodeEngine
-    from oobleck_tpu.utils import compile_cache, metrics
-
-    if compile_cache.persistent_cache_dir() is None:
-        pytest.skip("persistent compile cache disabled (OOBLECK_JAX_CC=0)")
-    ctr = metrics.registry().counter("oobleck_compile_cache_events_total")
 
     model = build_model("gpt2-tiny", {"num_layers": 1})
     params = model.init_params(jax.random.PRNGKey(0))
 
-    miss0 = ctr.value(event="serve_miss")
-    hit0 = ctr.value(event="serve_hit")
     eng = DecodeEngine(model, slots=1, max_seq=32)
-    assert eng.compile_cache_dir == compile_cache.persistent_cache_dir()
+    assert eng.compile_cache_dir is None
+    assert jax.config.jax_enable_compilation_cache is False
     eng.set_params(eng.stage_params(params), 1)
     n = eng.warmup()
-    assert n >= 2  # at least one prefill bucket + the decode step
-    classified = (ctr.value(event="serve_miss") - miss0
-                  + ctr.value(event="serve_hit") - hit0)
-    assert classified == n, "every warmup program must be hit/miss classified"
+    assert n == len(eng.prefill_buckets) + 1
 
-    jax.clear_caches()  # drop in-memory executables, keep the disk cache
-    miss1 = ctr.value(event="serve_miss")
-    hit1 = ctr.value(event="serve_hit")
+    jax.clear_caches()
     eng2 = DecodeEngine(model, slots=1, max_seq=32)
     eng2.set_params(eng2.stage_params(params), 1)
-    n2 = eng2.warmup()
-    assert ctr.value(event="serve_hit") - hit1 == n2, \
-        "warm restart must be served entirely from the persistent cache"
-    assert ctr.value(event="serve_miss") == miss1, \
-        "warm restart must not recompile"
+    assert eng2.warmup() == n

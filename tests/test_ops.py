@@ -265,3 +265,43 @@ def test_ulysses_alibi_bias_matches_xla(qkv, devices8):
     want = _xla_causal_attention(q, k, v, bias=bias)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
+
+
+def test_flash_traces_inside_check_vma_shard_map(qkv, devices8):
+    """The training step's shape — flash under a default (check_vma=True)
+    shard_map, differentiated from outside so the spec transposes run —
+    must trace (a bare pallas out_shape does not) and agree with XLA.
+    tests/ops/test_tpu_compile.py compiles the same shape for the chip."""
+    q, k, v = (jnp.concatenate([x, x * 0.5]) for x in qkv)
+    mesh = Mesh(np.array(devices8[:2]), ("data",))
+
+    def sharded(fn):
+        return jax.shard_map(fn, mesh=mesh, in_specs=(P("data"),) * 3,
+                             out_specs=P("data"))
+
+    loss = lambda fn: (lambda q, k, v: jnp.sum(sharded(fn)(q, k, v) ** 2))
+    got = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(_xla_causal_attention), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("tpu_visible", [False, True])
+def test_auto_never_hides_a_tpu_behind_the_cpu(monkeypatch, tpu_visible):
+    """Off-TPU "auto" is the XLA reference and explicit pallas runs
+    interpreted — for processes with no TPU. One that can reach a TPU while
+    its default backend is something else must not idle the chip quietly."""
+    from oobleck_tpu.ops import attention
+
+    def devices(backend=None):
+        if backend == "tpu" and not tpu_visible:
+            raise RuntimeError("Unknown backend tpu")
+        return ["a device"]
+
+    monkeypatch.setattr(jax, "devices", devices)
+    assert jax.default_backend() == "cpu"
+    if tpu_visible:
+        with pytest.raises(RuntimeError, match="a TPU is visible"):
+            attention._pallas_ok()
+    else:
+        assert attention._pallas_ok() is False
