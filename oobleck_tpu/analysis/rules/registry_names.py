@@ -32,8 +32,8 @@ METRIC_METHODS = {"counter", "gauge", "histogram"}
 REGISTRY_FACTORIES = {"registry"}
 FLIGHT_FACTORIES = {"flight_recorder"}
 SPAN_FACTORIES = {"span_recorder"}
-# Module-alias receivers for the ``spans.span("name")`` / ``spans.event``
-# free functions (each importer picks its own alias).
+# Module-alias receivers for the ``spans.span("name")`` / ``spans.event`` /
+# ``spans.region`` free functions (each importer picks its own alias).
 SPAN_MODULE_RECEIVERS = {"spans", "obs_spans", "spans_mod", "_spans"}
 # Conventional local receiver names for a Registry (``reg = ... or
 # metrics.registry()`` defeats assignment tracing; the idiom is stable).
@@ -84,7 +84,7 @@ def _site_kind(call: ast.Call, flight_vars: set[str],
         if chained in SPAN_FACTORIES or recv in span_vars:
             return "span"
         return None
-    if name in ("span", "event") and recv in SPAN_MODULE_RECEIVERS:
+    if name in ("span", "event", "region") and recv in SPAN_MODULE_RECEIVERS:
         return "span"
     return None
 

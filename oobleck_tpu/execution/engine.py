@@ -79,7 +79,6 @@ from oobleck_tpu.policy import (
 )
 from oobleck_tpu.utils import background, metrics, recovery
 from oobleck_tpu.utils.chaos import chaos
-from oobleck_tpu.utils.timer import measure_time, sync_timers
 
 logger = logging.getLogger("oobleck.engine")
 
@@ -104,7 +103,10 @@ host_sync_counter = HostSyncCounter()
 def _host_sync(value) -> float:
     """The engine's ONLY device->host readback funnel (counted)."""
     host_sync_counter.count += 1
-    return float(value)
+    # Where the host blocks on the device: the step's work has been
+    # enqueued, and this returns when the loss is there.
+    with obs_spans.region("engine.loss_readback"):
+        return float(value)
 
 
 class DeferredLoss:
@@ -421,9 +423,10 @@ class MultiHostDataParallelEngine:
         key = ("unpack", gi, li)
         if key not in self._jit_cache:
             layout = self.layouts[gi]
-            self._jit_cache[key] = jax.jit(
-                lambda vs, _li=li: layout.unpack(vs, _li)
-            )
+            def unpack_layer(vs, _li=li):
+                return layout.unpack(vs, _li)
+
+            self._jit_cache[key] = jax.jit(unpack_layer)
         return self._jit_cache[key](totals)
 
     def _local_sum(self, trees: list):
@@ -571,6 +574,7 @@ class ReconfigurationEngine:
 
 
 class OobleckEngine:
+    @obs_spans.span("engine.build")
     def __init__(self, args: OobleckArguments, agent_ip: str | None = None,
                  agent_pipe=None, devices: list | None = None):
         self.args = args
@@ -618,12 +622,13 @@ class OobleckEngine:
         from oobleck_tpu.planning.profiler import effective_tag
 
         tag = effective_tag(args.model.model_tag, args.execution)
-        profile(args.model.model_name, args.model.model_args,
-                model_tag=args.model.model_tag, execution=args.execution,
-                microbatch_size=args.job.microbatch_size, seq_len=seq_len)
-        self.profiles = load_profile(
-            args.model.model_name, tag, args.job.microbatch_size
-        )
+        with obs_spans.span("engine.profile"):
+            profile(args.model.model_name, args.model.model_args,
+                    model_tag=args.model.model_tag, execution=args.execution,
+                    microbatch_size=args.job.microbatch_size, seq_len=seq_len)
+            self.profiles = load_profile(
+                args.model.model_name, tag, args.job.microbatch_size
+            )
 
         # Cluster geometry: hosts partition the device list. Ranks encode
         # ORIGINAL host indices (rank = original_index * chips_per_host +
@@ -873,6 +878,7 @@ class OobleckEngine:
         logger.info("templates for host counts %s",
                     [t.num_hosts for t in self.templates])
 
+    @obs_spans.span("engine.plan")
     def _generate_templates(self, max_hosts: int) -> list[PipelineTemplate]:
         """Pipeline templates for every feasible host count in
         [compute_min_hosts(), max_hosts]. Deterministic in its inputs
@@ -1217,6 +1223,7 @@ class OobleckEngine:
                 self._recovered_at = time.monotonic()
         return restored
 
+    @obs_spans.span("engine.instantiate")
     def instantiate_pipelines(self, global_num_microbatch: int,
                               num_iterations_done: int = 0, epoch: int = 0) -> None:
         old_params = old_opt = None
@@ -1573,56 +1580,52 @@ class OobleckEngine:
     def _staged_batch(self, dl):
         """(host_batch, placed_or_None) from a loader, observing the input
         wait when a DeviceStager fronted it."""
-        if isinstance(dl, DeviceStager):
-            batch, placed = dl.next_placed()
-            self._m_input_wait.observe(dl.last_wait_s)
-            self._data_wait_s += dl.last_wait_s
-            return batch, placed
-        return dl.next_batch(), None
+        with obs_spans.region("engine.staging"):
+            if isinstance(dl, DeviceStager):
+                batch, placed = dl.next_placed()
+                self._m_input_wait.observe(dl.last_wait_s)
+                self._data_wait_s += dl.last_wait_s
+                return batch, placed
+            return dl.next_batch(), None
 
-    @measure_time("step")
     def _train_step(self) -> "float | DeferredLoss":
-        from oobleck_tpu.utils.tracing import annotate
-
-        if self.fused is not None:
-            with annotate("staging"):
+        with obs_spans.region("engine.step"):
+            if self.fused is not None:
                 batch, placed = self._staged_batch(self.dataloaders[0])
-            with annotate("fused_step"):
-                loss = self.fused.train_step(batch, placed=placed)
-            self.step += 1
-            if self._defer_losses():
-                return DeferredLoss([(loss, 1)])
-            return _host_sync(loss)
+                with obs_spans.region("engine.fused_step"):
+                    loss = self.fused.train_step(batch, placed=placed)
+                self.step += 1
+                if self._defer_losses():
+                    return DeferredLoss([(loss, 1)])
+                return _host_sync(loss)
 
-        if self.multihost:
-            return self._train_step_multihost()
+            if self.multihost:
+                return self._train_step_multihost()
 
-        losses = []
-        weights = []
-        stall_s = 0.0
-        with annotate("pipelines"):
+            losses = []
+            weights = []
+            stall_s = 0.0
             for pipe, dl in zip(self.pipelines, self.dataloaders):
-                with annotate("staging"):
-                    batch, placed = self._staged_batch(dl)
+                batch, placed = self._staged_batch(dl)
                 losses.append(pipe.train_step(batch, placed=placed))
                 weights.append(pipe.num_microbatches)
                 stall_s += pipe.last_dispatch_stall_s
-        with annotate("dp_allreduce"):
-            synced = self.dp_engine.do_allreduce()
-        with annotate("optimizer"):
-            for pipe in self.pipelines:
-                self.opt_states[pipe.pipeline_id] = pipe.apply_updates(
-                    self.optimizer, self.opt_states[pipe.pipeline_id],
-                    synced[pipe.pipeline_id],
-                )
-        self._m_dispatch_stall.observe(stall_s)
-        self.step += 1
-        if self._defer_losses():
-            return DeferredLoss(list(zip(losses, weights)))
-        total = sum(w for w in weights)
-        loss = sum(
-            _host_sync(l) * w for l, w in zip(losses, weights)) / total
-        return loss
+            with obs_spans.region("dp.allreduce"):
+                synced = self.dp_engine.do_allreduce()
+            with obs_spans.region("engine.optimizer"):
+                for pipe in self.pipelines:
+                    self.opt_states[pipe.pipeline_id] = pipe.apply_updates(
+                        self.optimizer, self.opt_states[pipe.pipeline_id],
+                        synced[pipe.pipeline_id],
+                    )
+            self._m_dispatch_stall.observe(stall_s)
+            self.step += 1
+            if self._defer_losses():
+                return DeferredLoss(list(zip(losses, weights)))
+            total = sum(w for w in weights)
+            loss = sum(
+                _host_sync(l) * w for l, w in zip(losses, weights)) / total
+            return loss
 
     def _train_step_multihost(self) -> float:
         """One step across the jax.distributed world: every process
@@ -1632,27 +1635,24 @@ class OobleckEngine:
         local layers. The reference's cross-node train step decomposes the
         same way (pipeline.train per rank + DataParallelEngine.do_allreduce,
         engine.py:645-649)."""
-        from oobleck_tpu.utils.tracing import annotate
-
         local_losses: dict[int, tuple[float, int]] = {}
-        with annotate("pipelines"):
-            for pipe, dl in zip(self.pipelines, self.dataloaders):
-                # EVERY process advances EVERY sampler in lockstep
-                # (deterministic positions), but only participants pay for
-                # batch materialization — non-owners advance position only.
-                if not pipe.participates_locally:
-                    dl.advance()
-                    continue
-                with annotate("staging"):
-                    batch = dl.next_batch()
-                loss = pipe.train_step(batch)
-                if loss is not None:
-                    local_losses[pipe.pipeline_id] = (
-                        _host_sync(loss), pipe.num_microbatches
-                    )
-        with annotate("dp_allreduce"):
+        for pipe, dl in zip(self.pipelines, self.dataloaders):
+            # EVERY process advances EVERY sampler in lockstep
+            # (deterministic positions), but only participants pay for
+            # batch materialization — non-owners advance position only.
+            if not pipe.participates_locally:
+                dl.advance()
+                continue
+            with obs_spans.region("engine.staging"):
+                batch = dl.next_batch()
+            loss = pipe.train_step(batch)
+            if loss is not None:
+                local_losses[pipe.pipeline_id] = (
+                    _host_sync(loss), pipe.num_microbatches
+                )
+        with obs_spans.region("dp.allreduce"):
             synced, global_loss = self.dp_engine.allreduce(local_losses)
-        with annotate("optimizer"):
+        with obs_spans.region("engine.optimizer"):
             for pipe in self.pipelines:
                 if pipe.participates_locally:
                     self.opt_states[pipe.pipeline_id] = pipe.apply_updates(
@@ -1966,6 +1966,11 @@ class OobleckEngine:
             # SIGTERM (TPU maintenance / preemption notice) drains the
             # in-flight snapshot before the process obeys the signal.
             plane.install_preemption_hook()
+        # "engine.bookkeeping": from one step's end to the next step's
+        # start (metrics, publish, checkpoint submit, the chaos and
+        # reconfigure polls, the stagers' pre-fence handshake). It spans
+        # the loop's back edge, so it is opened and closed by hand.
+        bookkeeping = None
         try:
             while self.step < max_steps:
                 self._tracer.on_step(self.step)
@@ -2001,10 +2006,14 @@ class OobleckEngine:
                 # separately as background_work_wait).
                 self._wait_staged_inputs()
                 self._data_wait_s = 0.0
+                if bookkeeping is not None:
+                    bookkeeping.__exit__(None, None, None)
                 with background.device_work("train_step"):
                     t0 = time.perf_counter()
                     loss = self._train_step()
                     step_s = time.perf_counter() - t0
+                bookkeeping = obs_spans.region("engine.bookkeeping")
+                bookkeeping.__enter__()
                 factor = chaos().slow_factor(self.agent_ip)
                 if factor is not None:
                     # Gray-failure injection: stretch this host's step by
@@ -2046,15 +2055,18 @@ class OobleckEngine:
                     logger.info("step %d/%d loss %.4f",
                                 self.step, max_steps, loss)
                 if self.step % 10 == 0:
-                    timers = sync_timers()
                     wire = (
                         f" | dp wire {self.dp_engine.last_wire_bytes} B/step"
                         if self.multihost and self.dp_engine is not None
                         else ""
                     )
-                    logger.info("step timer: %s | %s%s",
-                                timers.get("step"), _device_memory_summary(),
-                                wire)
+                    hist = self._m_step_seconds.series()
+                    n = sum(c["count"] for c in hist)
+                    mean_s = sum(c["sum"] for c in hist) / max(n, 1)
+                    logger.info(
+                        "step timer: n=%d, last=%.1fms, mean=%.1fms | %s%s",
+                        n, step_s * 1e3, mean_s * 1e3,
+                        _device_memory_summary(), wire)
                     self._publish_metrics()
                 if sync_interval and self.step % sync_interval == 0:
                     self._sync_replicas()
@@ -2070,6 +2082,8 @@ class OobleckEngine:
             if interval and self.step % interval != 0:
                 self.save_checkpoint()
         finally:
+            if bookkeeping is not None:
+                bookkeeping.__exit__(None, None, None)
             self._drain_pending_losses(max_steps)
             self._mirror_flush()
             if self._durable is not None:

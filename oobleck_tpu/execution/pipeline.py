@@ -44,6 +44,7 @@ from oobleck_tpu.execution.schedule import (
     send_grad_dest,
     validate_interleaving,
 )
+from oobleck_tpu.obs import spans
 from oobleck_tpu.planning.templates import PipelineTemplate
 
 logger = logging.getLogger("oobleck.pipeline")
@@ -124,6 +125,25 @@ def _project_spec(spec: P, keep: frozenset) -> P:
         names = tuple(n for n in names if n in keep)
         out.append(names[0] if len(names) == 1 else (tuple(names) or None))
     return P(*out)
+
+
+def grad_add(a, b):
+    """Microbatch gradient accumulation; the name is the XLA module's
+    (`jit_grad_add` in a device trace)."""
+    return jax.tree.map(jnp.add, a, b)
+
+
+def make_optimizer_update(optimizer):
+    """The per-layer optimizer step as a named function
+    (`jit_optimizer_update` in a device trace). PipelineInstance and the
+    recovery precompiler both jit THIS, so the precompiled program is the
+    one the live path runs."""
+
+    def optimizer_update(g, state, p):
+        updates, new_state = optimizer.update(g, state, p)
+        return optax.apply_updates(p, updates), new_state
+
+    return optimizer_update
 
 
 @dataclass
@@ -716,10 +736,11 @@ class PipelineInstance:
                 st.bwd[c] = jax.jit(bwd)
                 if (is_last and st.ctx is None
                         and hasattr(self.model, "accuracy_from_logits")):
-                    st.efwd[c] = jax.jit(
-                        lambda params_tuple, x, tokens, _apply=apply:
-                        _apply(params_tuple, x, tokens, with_metrics=True)
-                    )
+                    def eval_fwd(params_tuple, x, tokens, _apply=apply):
+                        return _apply(params_tuple, x, tokens,
+                                      with_metrics=True)
+
+                    st.efwd[c] = jax.jit(eval_fwd)
                 self._exec_cache[key] = (st.fwd[c], st.bwd[c], st.efwd[c])
 
     # ------------------------------------------------------------------ #
@@ -873,10 +894,11 @@ class PipelineInstance:
             if not pending_sends:
                 return
             t0 = time.perf_counter()
-            moved = jax.device_put(
-                [p[0] for p in pending_sends],
-                [p[1] for p in pending_sends],
-            )
+            with spans.region("pipeline.flush_sends"):
+                moved = jax.device_put(
+                    [p[0] for p in pending_sends],
+                    [p[1] for p in pending_sends],
+                )
             for (_, _, store, key), mv in zip(pending_sends, moved):
                 store[key] = mv
             pending_sends.clear()
@@ -889,7 +911,7 @@ class PipelineInstance:
         # as the round-5 elastic-MoE recovery "hang".
         add_fn = self._exec_cache.get("grad_add")
         if add_fn is None:
-            add_fn = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
+            add_fn = jax.jit(grad_add)
             self._exec_cache["grad_add"] = add_fn
 
         def accumulate(chunk_layers, stage_grads):
@@ -1026,9 +1048,10 @@ class PipelineInstance:
 
         # Execute the canonical total order (identical on every process;
         # dependency-valid by construction — see canonical_order).
-        for ins in canonical_order(S, M, v):
-            execute(ins)
-        flush_sends()
+        with spans.region("pipeline.dispatch"):
+            for ins in canonical_order(S, M, v):
+                execute(ins)
+            flush_sends()
 
         self.grads = grads
         self.last_stage_busy_s = stage_busy
@@ -1102,11 +1125,7 @@ class PipelineInstance:
         would invalidate."""
         fn = self._exec_cache.get(("opt_update", id(optimizer)))
         if fn is None:
-            def upd(g, state, p, _opt=optimizer):
-                updates, new_state = _opt.update(g, state, p)
-                return optax.apply_updates(p, updates), new_state
-
-            fn = jax.jit(upd)
+            fn = jax.jit(make_optimizer_update(optimizer))
             self._exec_cache[("opt_update", id(optimizer))] = fn
         new_state = dict(opt_state)
         for li in self.params:

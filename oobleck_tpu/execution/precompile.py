@@ -54,9 +54,9 @@ import time
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from oobleck_tpu.execution.pipeline import grad_add, make_optimizer_update
 from oobleck_tpu.utils import background
 
 logger = logging.getLogger("oobleck.precompile")
@@ -412,7 +412,7 @@ class RecoveryPrecompiler:
         if add_fn is None:
             # Same program train_step builds on first use; registering it
             # here means the live path cache-hits this jit object too.
-            add_fn = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
+            add_fn = jax.jit(grad_add)
             cache["grad_add"] = add_fn
         key = ("grad_add", tuple(str(a) for a in jax.tree.leaves(params_avals)))
         if key in self._done_keys:
@@ -430,11 +430,7 @@ class RecoveryPrecompiler:
         cache = self.engine._exec_cache
         fn = cache.get(("opt_update", id(optimizer)))
         if fn is None:
-            def upd(g, state, p, _opt=optimizer):
-                updates, new_state = _opt.update(g, state, p)
-                return optax.apply_updates(p, updates), new_state
-
-            fn = jax.jit(upd)
+            fn = jax.jit(make_optimizer_update(optimizer))
             cache[("opt_update", id(optimizer))] = fn
         replicated_of = {}
         for li, p_aval in zip(layer_ids, params_avals):

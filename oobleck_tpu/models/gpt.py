@@ -195,6 +195,7 @@ class GPTModel:
             return self.head(params, carry, ctx)
         return self.apply_block(params, carry, ctx)
 
+    @jax.named_scope("lm_head")
     def loss_from_logits(self, logits: jax.Array, batch) -> jax.Array:
         return cross_entropy_loss(logits, batch["input_ids"], self.config.vocab_size)
 
@@ -271,6 +272,7 @@ class GPTModel:
     # forward (ctx=None: plain; ctx set: manual TP/fsdp collectives)      #
     # ------------------------------------------------------------------ #
 
+    @jax.named_scope("embed")
     def embed(self, p, tokens: jax.Array, ctx: ShardCtx | None = None) -> jax.Array:
         c = self.config
         seq = tokens.shape[-1]
@@ -294,6 +296,7 @@ class GPTModel:
         x = self.attention_sublayer(p, x, ctx)
         return self.mlp_sublayer(p, x, ctx)
 
+    @jax.named_scope("mlp")
     def mlp_sublayer(self, p, x: jax.Array, ctx: ShardCtx | None = None) -> jax.Array:
         """ln2 -> gelu MLP -> residual. Shape-agnostic over leading dims:
         the decode path calls it on [B, E] single-token activations."""
@@ -310,6 +313,7 @@ class GPTModel:
         out = _maybe_reduce_from_tp(out, t, _explicit_bwd(ctx)) + p["mlp"]["bo"].astype(dt)
         return x + out
 
+    @jax.named_scope("attention")
     def attention_sublayer(self, p, x: jax.Array,
                            ctx: ShardCtx | None = None, *,
                            return_kv: bool = False):
@@ -379,6 +383,7 @@ class GPTModel:
             return x + out, qkv[1], qkv[2]
         return x + out
 
+    @jax.named_scope("lm_head")
     def head(self, p, x: jax.Array, ctx: ShardCtx | None = None) -> jax.Array:
         """Full (unsharded-output) logits in f32; masks vocab padding."""
         c = self.config
@@ -389,6 +394,7 @@ class GPTModel:
         mask = jnp.arange(logits.shape[-1]) < c.vocab_size
         return jnp.where(mask, logits, NEG_INF)
 
+    @jax.named_scope("lm_head")
     def head_loss_shifted(self, p, x: jax.Array, targets: jax.Array,
                           mask: jax.Array, ctx: ShardCtx | None = None) -> jax.Array:
         """SUM of masked per-position losses with *pre-shifted* targets
@@ -441,6 +447,7 @@ class GPTModel:
         dt = c.dtype if dtype is None else dtype
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
+    @jax.named_scope("attention")
     def _decode_attention_sublayer(self, p, x, k_cache, v_cache, pos):
         """attention_sublayer for ONE new token per slot against the KV
         cache. x [B, E]; k_cache/v_cache [B, H, S, D]; pos [B]."""
@@ -527,6 +534,7 @@ class GPTModel:
         impl = self.config.attention_impl
         return impl if impl in ("xla", "pallas") else "auto"
 
+    @jax.named_scope("attention")
     def _paged_decode_sublayer(self, p, x, k_pool, v_pool, block_tables, pos):
         """_decode_attention_sublayer against a page pool: write the new
         token's K/V through the block table, then ragged paged attention.
@@ -550,6 +558,7 @@ class GPTModel:
         out = out + p["attn"]["bo"].astype(dt)
         return x + out, k_pool, v_pool
 
+    @jax.named_scope("attention")
     def _tail_prefill_sublayer(self, p, x, k_pool, v_pool, head_tables,
                                prior_len):
         """attention_sublayer for a prompt TAIL whose head (`prior_len`
@@ -670,6 +679,7 @@ class GPTModel:
         logits = self.head(params["head"], x[:, None, :])[:, 0]
         return logits, {"k": k_new, "v": v_new}
 
+    @jax.named_scope("attention")
     def _paged_verify_sublayer(self, p, x, k_pool, v_pool, block_tables,
                                pos, n_live):
         """_paged_decode_sublayer for T speculative tokens per lane: write

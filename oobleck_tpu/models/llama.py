@@ -182,6 +182,7 @@ class LlamaModel:
             return self.head(params, carry, ctx)
         return self.apply_block(params, carry, ctx)
 
+    @jax.named_scope("lm_head")
     def loss_from_logits(self, logits, batch):
         from oobleck_tpu.models.gpt import cross_entropy_loss
 
@@ -245,6 +246,7 @@ class LlamaModel:
 
     # ---- forward ----
 
+    @jax.named_scope("embed")
     def embed(self, p, tokens, ctx: ShardCtx | None = None):
         c = self.config
         if ctx and ctx.tensor:
@@ -265,6 +267,7 @@ class LlamaModel:
         x = self.attention_sublayer(p, x, ctx)
         return self.mlp_sublayer(p, x, ctx)
 
+    @jax.named_scope("attention")
     def attention_sublayer(self, p, x, ctx: ShardCtx | None = None, *,
                            return_kv: bool = False):
         """ln1 -> RoPE attention (GQA, SP aware) -> residual. `return_kv=True`
@@ -307,6 +310,7 @@ class LlamaModel:
             return y, cached_k, cached_v
         return y
 
+    @jax.named_scope("mlp")
     def mlp_sublayer(self, p, x, ctx: ShardCtx | None = None):
         """ln2 -> SwiGLU -> residual. Shape-agnostic over leading dims: the
         decode path calls it on [B, E] single-token activations."""
@@ -323,6 +327,7 @@ class LlamaModel:
         out = g @ wo
         return x + _maybe_reduce(out, t, ctx)
 
+    @jax.named_scope("lm_head")
     def head(self, p, x, ctx: ShardCtx | None = None):
         c = self.config
         x = _rms_norm(x, p["ln_f"]["scale"], c.rms_norm_eps)
@@ -332,6 +337,7 @@ class LlamaModel:
         mask = jnp.arange(logits.shape[-1]) < c.vocab_size
         return jnp.where(mask, logits, NEG_INF)
 
+    @jax.named_scope("lm_head")
     def head_loss_shifted(self, p, x, targets, mask, ctx: ShardCtx | None = None):
         c = self.config
         x = _rms_norm(x, p["ln_f"]["scale"], c.rms_norm_eps)
@@ -374,6 +380,7 @@ class LlamaModel:
         dt = c.dtype if dtype is None else dtype
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
+    @jax.named_scope("attention")
     def _decode_attention_sublayer(self, p, x, k_cache, v_cache, pos):
         """attention_sublayer for ONE new token per slot against the KV
         cache. x [B, E]; k_cache/v_cache [B, KV, S, D]; pos [B]."""
@@ -441,6 +448,7 @@ class LlamaModel:
         impl = self.config.attention_impl
         return impl if impl in ("xla", "pallas") else "auto"
 
+    @jax.named_scope("attention")
     def _paged_decode_sublayer(self, p, x, k_pool, v_pool, block_tables, pos):
         """_decode_attention_sublayer against a page pool; GQA folds query
         heads inside paged_decode_attention against the unrepeated pool."""
@@ -461,6 +469,7 @@ class LlamaModel:
         out = jnp.einsum("bhd,hde->be", attn, p["attn"]["wo"].astype(dt))
         return x + out, k_pool, v_pool
 
+    @jax.named_scope("attention")
     def _tail_prefill_sublayer(self, p, x, k_pool, v_pool, head_tables,
                                prior_len):
         """Prompt-tail attention over a gathered cached head (see
@@ -541,6 +550,7 @@ class LlamaModel:
         logits = self.head(params["head"], x[:, None, :])[:, 0]
         return logits, {"k": k_new, "v": v_new}
 
+    @jax.named_scope("attention")
     def _paged_verify_sublayer(self, p, x, k_pool, v_pool, block_tables,
                                pos, n_live):
         """_paged_decode_sublayer for T speculative tokens per lane (see

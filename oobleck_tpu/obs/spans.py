@@ -19,6 +19,22 @@ call signature.
 Timestamps are wall-clock epoch seconds, same rationale as
 utils/recovery.py: the chain crosses master/agent/worker processes, and
 processes on one machine share a clock (TPU pods have NTP-class sync).
+
+Two calls, two jobs:
+
+* ``span(name)`` -- the incident recorder above: wall clock, ids that cross
+  processes, a record in the ring. For what happens once per incident or
+  once per process (reconfigure, restore, set-up).
+* ``region(name)`` -- a hot-path region, many times a step: nothing but a
+  ``jax.profiler.TraceAnnotation`` (the region is an event of the host
+  plane of the profiler's own trace, on the device operations' clock) and
+  one observation of ``oobleck_span_seconds{span=name}``. No ids, no wall
+  clock, no record.
+
+``span()`` opens the same annotation, so an incident's spans also lie in a
+device trace taken across it. This module is the only place in the package
+that constructs a ``TraceAnnotation``. Constructing one initialises no JAX
+backend: the master and the agent call ``span()`` too.
 """
 
 from __future__ import annotations
@@ -31,6 +47,8 @@ import os
 import threading
 import time
 import uuid
+
+import jax
 
 from oobleck_tpu.utils import metrics
 
@@ -179,12 +197,61 @@ def span(name: str, *, trace_id: str | None = None,
     stack.append(frame)
     t0 = time.time()
     try:
-        yield frame
+        with jax.profiler.TraceAnnotation(name):
+            yield frame
     finally:
         stack.pop()
         (recorder or _recorder).record(
             name, t0, time.time(), trace_id=frame["trace_id"],
             span_id=frame["span_id"], parent_id=parent_id, **attrs)
+
+
+SPAN_SECONDS = "oobleck_span_seconds"
+# name -> bound observe() of its series, for the registry generation they
+# were bound in (tests clear the registry between cases).
+_observers: dict = {}
+_observers_generation = -1
+
+
+def _observer(name: str):
+    global _observers_generation
+    reg = metrics.registry()
+    if reg.generation != _observers_generation:
+        _observers.clear()
+        _observers_generation = reg.generation
+    observe = _observers.get(name)
+    if observe is None:
+        # The literal, not SPAN_SECONDS: the registry generator reads it.
+        observe = _observers[name] = reg.histogram(
+            "oobleck_span_seconds", "Host seconds of named hot-path "
+            "regions (obs/spans.region)").bind(span=name)
+    return observe
+
+
+class region:
+    """A named hot-path region: `with region("engine.staging"): ...`.
+
+    While a profiler trace runs, the region is an event named `name` on the
+    calling thread's line of the trace's host plane; always, its host
+    seconds go to the histogram `oobleck_span_seconds{span=name}`. Nests,
+    and closes on an exception. Reads no device value: what it times is
+    what the host did, which on an asynchronous device is the enqueue
+    unless the region itself blocks."""
+
+    __slots__ = ("_name", "_annotation", "_t0")
+
+    def __init__(self, name: str):
+        self._name = name
+        self._annotation = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        _observer(self._name)(seconds)
 
 
 def event(name: str, t: float | None = None, **attrs) -> dict:

@@ -337,6 +337,7 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
             pltpu.VMEM((BLOCK_Q, LANE), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*operands)
 
     out, lse = result if emit_lse else (result, None)
@@ -393,6 +394,7 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
         out_specs=pl.BlockSpec((1, BLOCK_Q, dp), lambda b_, qi, ki: (b_, qi, 0)),
         scratch_shapes=[pltpu.VMEM((BLOCK_Q, dp), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*common)
 
     dk, dv = pl.pallas_call(
@@ -413,6 +415,7 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
             pltpu.VMEM((BLOCK_K, dp), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*common)
 
     def unpad(x, dt):

@@ -298,6 +298,7 @@ def _paged_decode_pallas(
         grid_spec=grid_spec,
         out_shape=_out_struct((b, hkv, g, dp), q.dtype, *operands),
         interpret=_interpret(),
+        name="paged_decode",
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *operands)
     return out.reshape(b, hq, dp)[:, :, :d]
@@ -421,6 +422,7 @@ def _paged_verify_pallas(
         grid_spec=grid_spec,
         out_shape=_out_struct((b, hkv, t * g, dp), q.dtype, *operands),
         interpret=_interpret(),
+        name="paged_verify",
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *operands)
     out = out.reshape(b, hkv, t, g, dp).transpose(0, 2, 1, 3, 4)

@@ -133,14 +133,14 @@ class DecodeEngine(_EngineBase):
         self.cache = model.init_kv_cache(self.slots, self.max_seq)
 
         # argnums: 0=params, 1=cache (donated), rest per call.
-        self._decode_fn = jax.jit(
-            lambda p, cache, token, pos:
-                model.forward_decode(p, token, cache, pos),
-            donate_argnums=(1,))
-        self._prefill_fn = jax.jit(
-            lambda p, cache, tokens, slot, length:
-                model.forward_prefill(p, tokens, cache, slot, length),
-            donate_argnums=(1,))
+        def decode_step(p, cache, token, pos):
+            return model.forward_decode(p, token, cache, pos)
+
+        def prefill(p, cache, tokens, slot, length):
+            return model.forward_prefill(p, tokens, cache, slot, length)
+
+        self._decode_fn = jax.jit(decode_step, donate_argnums=(1,))
+        self._prefill_fn = jax.jit(prefill, donate_argnums=(1,))
 
     def warmup(self) -> int:
         """Compile the decode step and every prefill bucket up front (cold
@@ -226,22 +226,23 @@ class PagedDecodeEngine(_EngineBase):
                               np.int32)
         self._lane_pages: list[list[int]] = [[] for _ in range(self.lanes)]
 
-        self._decode_fn = jax.jit(
-            lambda p, cache, token, tables, pos:
-                model.forward_decode_paged(p, token, cache, tables, pos),
-            donate_argnums=(1,))
+        def decode_step(p, cache, token, tables, pos):
+            return model.forward_decode_paged(p, token, cache, tables, pos)
+
+        def prefill(p, cache, tokens, tables, length):
+            return model.forward_prefill_paged(p, tokens, cache, tables,
+                                               length)
+
+        def prefill_tail(p, cache, tokens, tables, length, head, prior):
+            return model.forward_prefill_paged(
+                p, tokens, cache, tables, length,
+                head_tables=head, prior_len=prior)
+
+        self._decode_fn = jax.jit(decode_step, donate_argnums=(1,))
         # One callable; jit retraces per (tail bucket, head bucket) shape
         # pair. head_tables=None (shape-free) is the no-hit fast path.
-        self._prefill_fn = jax.jit(
-            lambda p, cache, tokens, tables, length:
-                model.forward_prefill_paged(p, tokens, cache, tables, length),
-            donate_argnums=(1,))
-        self._prefill_head_fn = jax.jit(
-            lambda p, cache, tokens, tables, length, head, prior:
-                model.forward_prefill_paged(
-                    p, tokens, cache, tables, length,
-                    head_tables=head, prior_len=prior),
-            donate_argnums=(1,))
+        self._prefill_fn = jax.jit(prefill, donate_argnums=(1,))
+        self._prefill_head_fn = jax.jit(prefill_tail, donate_argnums=(1,))
 
         reg = metrics.registry()
         self.m_pages_in_use = reg.gauge(
@@ -402,11 +403,13 @@ class PagedDecodeEngine(_EngineBase):
     def _get_verify_fn(self):
         fn = getattr(self, "_verify_fn", None)
         if fn is None:
-            fn = self._verify_fn = jax.jit(
-                lambda p, cache, tokens, tables, pos, live:
-                    self.model.forward_verify_paged(
-                        p, tokens, cache, tables, pos, live),
-                donate_argnums=(1,))
+            model = self.model
+
+            def verify_step(p, cache, tokens, tables, pos, live):
+                return model.forward_verify_paged(
+                    p, tokens, cache, tables, pos, live)
+
+            fn = self._verify_fn = jax.jit(verify_step, donate_argnums=(1,))
         return fn
 
     def warmup_verify(self, t: int) -> None:

@@ -22,6 +22,7 @@ every chaos-test failure into a self-contained postmortem artifact.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import json
 import logging
@@ -184,15 +185,25 @@ class Histogram(_Metric):
         self.buckets = tuple(sorted(float(b) for b in buckets))
 
     def observe(self, value: float, **labels) -> None:
-        value = float(value)
+        self.bind(**labels)(float(value))
+
+    def bind(self, **labels):
+        """`observe(value)` for one fixed label set: the series is looked
+        up once, here, so a hot path that keeps the result pays for the
+        bucket search and the lock only (locals, not attributes: it is
+        called a dozen times a training step)."""
         cell = self._child(labels, lambda: _HistCell(len(self.buckets)))
-        with self._lock:
-            for i, upper in enumerate(self.buckets):
-                if value <= upper:
-                    cell.counts[i] += 1
-                    break
-            cell.sum += value
-            cell.count += 1
+        lock, buckets, counts = self._lock, self.buckets, cell.counts
+
+        def observe(value: float) -> None:
+            i = bisect.bisect_left(buckets, value)
+            with lock:
+                if i < len(counts):
+                    counts[i] += 1
+                cell.sum += value
+                cell.count += 1
+
+        return observe
 
     def series(self) -> list[dict]:
         with self._lock:
@@ -208,6 +219,9 @@ class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._metrics: dict[str, _Metric] = {}
+        # Bumped by clear(): whoever keeps a bound series (Histogram.bind)
+        # across calls compares it and binds again after a clear.
+        self.generation = 0
 
     def _get(self, name: str, cls, help_text: str, **kwargs) -> _Metric:
         with self._lock:
@@ -244,6 +258,7 @@ class Registry:
     def clear(self) -> None:
         with self._lock:
             self._metrics.clear()
+            self.generation += 1
 
 
 def render_prometheus(snapshots: list[dict],

@@ -1,13 +1,14 @@
 """Runtime tracing.
 
-Capability match for the reference's torch-profiler annotations
-(record_function regions around FSDP hooks, /root/reference/oobleck/
-execution/layer.py:148-190) plus the TensorBoard wiring it lists as a dep
-but never uses (SURVEY §5): jax.profiler spans around engine regions, and an
-on-demand trace dump for a window of steps.
+An on-demand jax.profiler trace of a window of training steps (the
+TensorBoard wiring the reference lists as a dep but never uses, SURVEY §5).
+The named regions that show in it (`engine.step`, `pipeline.dispatch`, ...)
+are `obs/spans.region` calls, and the kernels, programs and model parts on
+the device plane carry stable names of their own (README, "Reading a
+trace").
 
-Enable with OOBLECK_TRACE_DIR=/path — the engine wraps steps in named
-annotations and writes a perfetto-compatible trace for steps
+Enable with OOBLECK_TRACE_DIR=/path — the engine writes a
+perfetto-compatible trace for steps
 [OOBLECK_TRACE_START, OOBLECK_TRACE_START + OOBLECK_TRACE_STEPS). Set
 OOBLECK_TRACE_EVERY=<n> to re-arm the window every n steps for long runs
 (window k covers [START + k*EVERY, START + k*EVERY + STEPS)).
@@ -20,30 +21,12 @@ double-start, and an unclosed trace loses its buffered data).
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 
 import jax
 
 logger = logging.getLogger("oobleck.tracing")
-
-
-def annotate(name: str):
-    """Named span visible in TPU profiler traces (and a no-op otherwise)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
-@contextlib.contextmanager
-def traced(name: str, **attrs):
-    """One region, both tracing planes: a jax.profiler annotation (shows in
-    device traces captured by StepTracer) AND an obs span (shows in the
-    distributed timeline, stitched to whatever trace is current/ambient)."""
-    from oobleck_tpu.obs import spans
-
-    # oobleck: allow[OBL005] -- generic helper, the caller owns the name
-    with jax.profiler.TraceAnnotation(name), spans.span(name, **attrs):
-        yield
 
 
 def _env_int(name: str, default: int) -> int:

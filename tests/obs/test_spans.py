@@ -157,3 +157,138 @@ def test_dump_disabled_without_sink(monkeypatch):
 
     monkeypatch.delenv(metrics.ENV_METRICS_DIR, raising=False)
     assert spans.SpanRecorder(capacity=4).dump("r") is None
+
+
+# ------------------------------------------------------------------ #
+# region(): the hot-path call, and the annotation both calls open
+
+
+class _Annotations:
+    """Stand-in for jax.profiler.TraceAnnotation that records opens and
+    closes in order (the real one writes to the profiler's trace)."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class Annotation:
+            def __enter__(self):
+                log.append(("open", name))
+
+            def __exit__(self, *exc):
+                log.append(("close", name))
+
+        return Annotation()
+
+
+def _span_series(name):
+    from oobleck_tpu.utils import metrics
+
+    hist = metrics.registry().histogram(spans.SPAN_SECONDS)
+    return [s for s in hist.series() if s["labels"] == {"span": name}]
+
+
+def test_region_nests_and_annotates(monkeypatch):
+    fake = _Annotations()
+    monkeypatch.setattr(spans.jax.profiler, "TraceAnnotation", fake)
+    with spans.region("t.outer"):
+        with spans.region("t.inner"):
+            pass
+    assert fake.log == [("open", "t.outer"), ("open", "t.inner"),
+                        ("close", "t.inner"), ("close", "t.outer")]
+
+
+def test_region_survives_an_exception(monkeypatch):
+    fake = _Annotations()
+    monkeypatch.setattr(spans.jax.profiler, "TraceAnnotation", fake)
+    before = sum(s["count"] for s in _span_series("t.raises"))
+    try:
+        with spans.region("t.raises"):
+            raise KeyError("x")
+    except KeyError:
+        pass
+    else:
+        raise AssertionError("region() swallowed the exception")
+    assert fake.log == [("open", "t.raises"), ("close", "t.raises")]
+    assert sum(s["count"] for s in _span_series("t.raises")) == before + 1
+
+
+def test_region_observes_span_seconds():
+    import time
+
+    before = _span_series("t.observed")
+    n0 = before[0]["count"] if before else 0
+    s0 = before[0]["sum"] if before else 0.0
+    with spans.region("t.observed"):
+        time.sleep(0.01)
+    (after,) = _span_series("t.observed")
+    assert after["count"] == n0 + 1
+    assert 0.01 <= after["sum"] - s0 < 1.0
+
+
+def test_region_binds_again_after_the_registry_is_cleared():
+    from oobleck_tpu.utils import metrics
+
+    with spans.region("t.cleared"):
+        pass
+    metrics.registry().clear()
+    with spans.region("t.cleared"):
+        pass
+    (series,) = _span_series("t.cleared")
+    assert series["count"] == 1
+
+
+def test_region_allocates_no_ids_and_records_nothing(monkeypatch):
+    def no_ids():
+        raise AssertionError("region() asked for an id")
+
+    monkeypatch.setattr(spans.uuid, "uuid4", no_ids)
+    monkeypatch.setattr(spans.time, "time", no_ids)
+    ring = len(spans.span_recorder().spans())
+    with spans.region("t.no_ids"):
+        pass
+    assert len(spans.span_recorder().spans()) == ring
+    assert spans.current() is None  # no frame on the span stack either
+
+
+def test_region_costs_microseconds_with_no_trace_running():
+    import time
+
+    n = 20_000
+    for _ in range(1000):
+        with spans.region("t.cost"):
+            pass
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with spans.region("t.cost"):
+            pass
+    per_call = (time.perf_counter() - t0) / n
+    # ~2 us on an idle host; the limit only catches a region() that grew
+    # an id, a lock convoy or a dict per call, not a busy CI machine.
+    assert per_call < 50e-6, per_call
+
+
+def test_span_opens_an_annotation_too(monkeypatch):
+    fake = _Annotations()
+    monkeypatch.setattr(spans.jax.profiler, "TraceAnnotation", fake)
+    rec = spans.SpanRecorder(capacity=4)
+    with spans.span("t.incident", recorder=rec):
+        with spans.region("t.within"):
+            pass
+    assert fake.log == [("open", "t.incident"), ("open", "t.within"),
+                        ("close", "t.within"), ("close", "t.incident")]
+    assert [s["name"] for s in rec.spans()] == ["t.incident"]
+
+
+def test_span_as_a_decorator_records_each_call():
+    rec = spans.SpanRecorder(capacity=4)
+
+    @spans.span("t.decorated", recorder=rec)
+    def work(x):
+        return x + 1
+
+    assert work(1) == 2 and work(2) == 3
+    assert [s["name"] for s in rec.spans()] == ["t.decorated"] * 2
+    assert rec.spans()[0]["span_id"] != rec.spans()[1]["span_id"]

@@ -1,0 +1,65 @@
+"""Every jitted program of the two hot paths is a named function, so its
+XLA module has a name of ours in a device trace (`jit_fwd`, `jit_bwd`,
+`jit_grad_add`, `jit_optimizer_update`, `jit_decode_step`, `jit_prefill`,
+...) and none is `jit__lambda`, which says nothing and is the same for
+every lambda in the process."""
+
+import ast
+from pathlib import Path
+
+import pytest
+
+PKG = Path(__file__).resolve().parents[2] / "oobleck_tpu"
+# Training: engine -> pipeline (+ the precompiler that warms its programs)
+# and the fused step; serving: batcher -> engine; the model families both run.
+HOT_PATH = ["execution/engine.py", "execution/pipeline.py",
+            "execution/precompile.py", "execution/fused.py",
+            "parallel/train.py", "serve/engine.py", "serve/batcher.py",
+            "models/gpt.py", "models/llama.py"]
+
+
+def _is_jit(call: ast.Call) -> bool:
+    f = call.func
+    return (isinstance(f, ast.Attribute) and f.attr == "jit") or (
+        isinstance(f, ast.Name) and f.id == "jit")
+
+
+@pytest.mark.parametrize("module", HOT_PATH)
+def test_no_jitted_program_is_a_lambda(module):
+    tree = ast.parse((PKG / module).read_text())
+    lambdas = [node.lineno for node in ast.walk(tree)
+               if isinstance(node, ast.Call) and _is_jit(node) and node.args
+               and isinstance(node.args[0], ast.Lambda)]
+    assert not lambdas, f"{module}: jax.jit(lambda ...) at lines {lambdas}"
+
+
+def test_training_programs_carry_their_names():
+    import optax
+
+    from oobleck_tpu.execution import pipeline
+
+    assert pipeline.grad_add.__name__ == "grad_add"
+    update = pipeline.make_optimizer_update(optax.sgd(0.1))
+    assert update.__name__ == "optimizer_update"
+
+
+def test_serving_programs_carry_their_names():
+    from oobleck_tpu.models import build_model
+    from oobleck_tpu.serve.engine import DecodeEngine, PagedDecodeEngine
+
+    model = build_model("gpt2-tiny", {})
+    dense = DecodeEngine(model, slots=2, max_seq=32)
+    paged = PagedDecodeEngine(model, lanes=2, max_seq=32, page_size=16,
+                              num_pages=8)
+    names = {
+        "dense decode": dense._decode_fn.__name__,
+        "dense prefill": dense._prefill_fn.__name__,
+        "paged decode": paged._decode_fn.__name__,
+        "paged prefill": paged._prefill_fn.__name__,
+        "paged prefill tail": paged._prefill_head_fn.__name__,
+        "verify": paged._get_verify_fn().__name__,
+    }
+    assert names == {
+        "dense decode": "decode_step", "dense prefill": "prefill",
+        "paged decode": "decode_step", "paged prefill": "prefill",
+        "paged prefill tail": "prefill_tail", "verify": "verify_step"}

@@ -182,3 +182,44 @@ def test_paged_alibi_compiles(v5e):
 
     _compile(decode, v5e[0], ((lanes, hq, d), jnp.bfloat16), *rest)
     _compile(verify, v5e[0], ((lanes, t, hq, d), jnp.bfloat16), *rest)
+
+
+# Every kernel has a stable name on the device: `name=` on its pallas_call
+# is the innermost component of the operation's JAX name stack, and the
+# chip's compiler names the custom call after that component. A profiler
+# trace shows that instruction name, so the benchmark's kernel metrics
+# (`%flash_fwd.`, `%flash_bwd_dq.`, ...) find it after any refactor.
+KERNEL_NAMES = {
+    "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
+    "paged_decode": "decode", "paged_verify": "verify",
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_is_named_in_location_and_executable(v5e, name):
+    import re
+
+    one = SingleDeviceSharding(v5e[0])
+    if KERNEL_NAMES[name] == "flash":
+        # Under remat, as the training step runs it: the recompute's
+        # forward call keeps the kernel's name too.
+        fn = _grads(jax.checkpoint(flash_attention))
+        shapes = [(FLASH_WIDTHS["gpt3-2.7b"], jnp.bfloat16)] * 3
+    else:
+        hq, hkv, d = PAGED_WIDTHS["gpt2"]
+        lanes, num_pages, page, table_pages, t = _serve_geometry()
+        pool = ((num_pages, hkv, page, d), jnp.bfloat16)
+        q = ((lanes, hq, d) if name == "paged_decode"
+             else (lanes, t, hq, d), jnp.bfloat16)
+        fn = (paged_decode_attention if name == "paged_decode"
+              else paged_verify_attention)
+        shapes = [q, pool, pool, ((lanes, table_pages), jnp.int32),
+                  ((lanes,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert f"/{name}/pallas_call" in lowered.as_text(debug_info=True)
+    calls = re.findall(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', lowered.compile().as_text())
+    assert name in calls, calls
+    # No Pallas call of these programs goes by a stand-in.
+    assert set(calls) <= set(KERNEL_NAMES), calls
