@@ -10,50 +10,122 @@ keeping HBM traffic linear in S in BOTH directions:
             lane-broadcast [BH, S, 128] following the layout the TPU memory
             system wants for per-row scalars).
   backward: standard two-pass recompute —
-              dq kernel   grid (BH, q_blocks, kv_blocks), kv innermost,
-                          accumulates dq for one q block across kv blocks;
-              dk/dv kernel grid (BH, kv_blocks, q_blocks), q innermost,
-                          accumulates dk/dv for one kv block across q blocks.
+              dq kernel    accumulates dq for one q block across the kv
+                           blocks of its row;
+              dk/dv kernel accumulates dk/dv for one kv block across the q
+                           blocks of its column.
             Each recomputes p = exp(s - lse) from the saved LSE — no [S, S]
             residual ever touches HBM.
 
-Supports an additive attention bias ([H, S, S] — ALiBi for the Bloom family)
-and bidirectional (non-causal) attention for encoder models. The bias is
-treated as a constant (stop_gradient): for ALiBi it is position-only, so the
-zero cotangent is exact; learned biases must use the XLA path.
+Geometry. All three kernels run on a grid of (batch * head, step), one step
+per (q block, kv block) PAIR THE MASK LEAVES ANYTHING OF: `_live_pairs`
+lists them once per shape (three small int32 tables, prefetched to SMEM,
+which the block index maps read), so a pair the causal mask empties costs
+neither a grid step nor a K/V fetch. The steps of one accumulation
+(a q block's row for forward and dq, a kv block's column for dk/dv) adjoin;
+the tables' flag says which step opens and which closes it. Block sizes
+come from `choose_tiles`, a pure function of the sequence length: 512 x 512
+where the sequence allows (a step then carries about a microsecond of MXU
+work; the fixed 128 x 128 of before carried 0.04 us under 0.4 us of step
+overhead), 128 x 128 for a 128-token prompt. There is no option for them.
 
-Layout notes: head dim is padded to the 128-lane width and sequence to the
-block size outside the kernels; zero padding is exact (padded q rows are
+Supports an additive attention bias ([H, S, S] — ALiBi for the Bloom family,
+blocked by the same tile) and bidirectional (non-causal) attention for
+encoder models. The bias is treated as a constant (stop_gradient): for ALiBi
+it is position-only, so the zero cotangent is exact; learned biases must use
+the XLA path.
+
+Layout notes: head dim and sequence are padded to the 128-lane width outside
+the kernels, and the blocks divide the padded sequence, so padding never
+reaches a whole 128-row block. Zero padding is exact (padded q rows are
 sliced off, padded k columns are causally masked or explicitly masked in the
 non-causal case, and padded dO rows are zero so they contribute nothing to
-dk/dv).
+dk/dv). Gradients leave the kernels in the operands' dtype, rounded once
+from the f32 accumulators.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e9
 
-BLOCK_Q = 128
-BLOCK_K = 128
 LANE = 128
+# The widest block of queries or keys a grid step holds, and the most
+# [Bq, Bk] logits of one block pair. 512 x 512 x 128 is about a microsecond
+# of MXU work: enough for a step to cost its matmuls and not its
+# bookkeeping (PERF.md section 6, PR 28).
+MAX_BLOCK = 512
+MAX_PAIR = MAX_BLOCK * MAX_BLOCK
+
+# Bits of a grid step's flag: the step opens, closes an accumulation.
+FIRST, LAST = 1, 2
+
+# The batch * head axis is independent work (cores may split it); the steps
+# of one accumulation are not. Every tile `choose_tiles` returns compiles
+# within the default scoped VMEM limit (16 MiB on a v5e; the dk/dv kernel
+# at 512 x 512 is the largest), so none is stated.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 
-def _block_relevant(qi, ki, causal: bool):
-    """Whether kv block ki overlaps the causal support of q block qi."""
-    if not causal:
-        return True
-    return ki * BLOCK_K <= qi * BLOCK_Q + (BLOCK_Q - 1)
+class Tiles(NamedTuple):
+    seq: int       # the sequence as the kernels see it: padded to the lane
+    block_q: int   # rows of Q (and O, dO, LSE) per block
+    block_k: int   # rows of K and V per block
 
 
-def _scores(q, k, qi, ki, scale, bias_ref, slope_ref, *, causal: bool,
-            kv_len: int):
+@functools.cache
+def choose_tiles(seq_len: int) -> Tiles:
+    """The kernels' geometry: a pure function of the call's shape, the
+    same on every start. The sequence is padded to the lane width and no
+    further, and both blocks divide it, so a short prompt stays one
+    128-row block and 640 rows stay 640. Among the divisors: the largest
+    block of queries up to MAX_BLOCK, then the largest block of keys that
+    keeps a pair within MAX_PAIR."""
+    seq = -(-seq_len // LANE) * LANE
+    n = seq // LANE
+    divisors = [LANE * m for m in range(1, n + 1) if n % m == 0]
+    block_q = max(d for d in divisors if d <= MAX_BLOCK)
+    block_k = max(d for d in divisors if d * block_q <= MAX_PAIR)
+    return Tiles(seq, block_q, block_k)
+
+
+@functools.cache
+def _live_pairs(t: Tiles, causal: bool, q_major: bool):
+    """The grid's second axis: one step per (q block, kv block) pair that
+    the causal mask leaves anything of, and no step for the others. Returns
+    three int32 tables indexed by step: the q block, the kv block, and the
+    step's flag. `q_major` orders the steps of one q block together
+    (forward, dq: FIRST and LAST bracket a q block's accumulation);
+    otherwise those of one kv block (dk/dv). Built once per shape, from
+    Python ints."""
+    pairs = [(qi, ki)
+             for qi in range(t.seq // t.block_q)
+             for ki in range(t.seq // t.block_k)
+             # the block's first key is at or before its last query
+             if not causal or ki * t.block_k < (qi + 1) * t.block_q]
+    major = (lambda p: p[0]) if q_major else (lambda p: p[1])
+    pairs.sort(key=lambda p: p if q_major else p[::-1])
+    flags = []
+    for i, p in enumerate(pairs):
+        first = i == 0 or major(pairs[i - 1]) != major(p)
+        last = i == len(pairs) - 1 or major(pairs[i + 1]) != major(p)
+        flags.append((FIRST if first else 0) | (LAST if last else 0))
+    table = lambda xs: np.asarray(xs, np.int32)
+    return (table([p[0] for p in pairs]), table([p[1] for p in pairs]),
+            table(flags))
+
+
+def _scores(q, k, qi, ki, t: Tiles, scale, bias_ref, slope_ref, *,
+            causal: bool, kv_len: int):
     """[Bq, Bk] masked, scaled, biased f32 logits for one (q, kv) block pair.
 
     Operands stay in their native dtype (bf16 in production) so the MXU runs
@@ -66,9 +138,12 @@ def _scores(q, k, qi, ki, scale, bias_ref, slope_ref, *, causal: bool,
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     if bias_ref is not None:
-        s = s + bias_ref[0].astype(jnp.float32)
-    q_pos = qi * BLOCK_Q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = ki * BLOCK_K + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = s + bias_ref[...].astype(jnp.float32)
+    padded = kv_len < t.seq
+    if slope_ref is None and not causal and not padded:
+        return s
+    q_pos = qi * t.block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = ki * t.block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     if slope_ref is not None:
         # Identical to alibi_bias_from_slopes: -slope * (q - k) causal,
         # -slope * |q - k| bidirectional (the signed form would reward
@@ -76,18 +151,20 @@ def _scores(q, k, qi, ki, scale, bias_ref, slope_ref, *, causal: bool,
         dist = (q_pos - k_pos).astype(jnp.float32)
         if not causal:
             dist = jnp.abs(dist)
-        s = s - slope_ref[0, 0, 0] * dist
+        s = s - slope_ref[...] * dist               # [1, 1] * [Bq, Bk]
+    # Every live pair is masked, not only those that cross the mask's edge:
+    # the compare and select hide under the exponentials (PERF.md, PR 28).
     if causal:
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    else:
+    elif padded:
         # Padded kv columns are not causally masked in the encoder form —
         # mask them explicitly so softmax never sees them.
         s = jnp.where(k_pos < kv_len, s, NEG_INF)
     return s
 
 
-def _fwd_kernel(*refs, scale: float, blocks_k: int, causal: bool,
-                has_bias: bool, has_slopes: bool, kv_len: int,
+def _fwd_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
+                causal: bool, has_bias: bool, has_slopes: bool, kv_len: int,
                 emit_lse: bool):
     refs = list(refs)
     bias_ref = slope_ref = lse_ref = None
@@ -101,136 +178,125 @@ def _fwd_kernel(*refs, scale: float, blocks_k: int, causal: bool,
     if emit_lse:
         lse_ref = refs.pop(0)
     acc_ref, m_ref, l_ref = refs
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(flag & FIRST != 0)
     def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Fully-masked blocks contribute exactly zero — predicate away the MXU
-    # work (the actual cost), ~halving causal FLOPs.
-    @pl.when(_block_relevant(qi, ki, causal))
-    def _():
-        q = q_ref[0]                               # [Bq, D] native dtype
-        k = k_ref[0]                               # [Bk, D]
-        v = v_ref[0]                               # [Bk, D]
-        s = _scores(q, k, qi, ki, scale, bias_ref, slope_ref,
-                    causal=causal, kv_len=kv_len)
+    q = q_ref[...]                                 # [Bq, D] native dtype
+    k = k_ref[...]                                 # [Bk, D]
+    v = v_ref[...]                                 # [Bk, D]
+    s = _scores(q, k, qi, ki, tiles, scale, bias_ref, slope_ref,
+                causal=causal, kv_len=kv_len)
 
-        m_prev = m_ref[:, :1]                      # [Bq, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                     # [Bq, Bk] f32
-        correction = jnp.exp(m_prev - m_new)       # [Bq, 1]
-
-        l_new = l_ref[:, :1] * correction + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
+    # m and l stay lane-broadcast [Bq, 128] from scratch to scratch: a row's
+    # scalar meets the [Bq, Bk] logits and the [Bq, D] accumulator by tiling
+    # whole vregs. Taken as [Bq, 1] columns they cost the forward as much
+    # again in lane broadcasts (0.95 against 0.50 ms a call, PERF.md, PR 28).
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - jnp.tile(m_new, (1, s.shape[1] // LANE)))   # [Bq, Bk] f32
+    correction = jnp.exp(m_prev - m_new)           # [Bq, 128]
+    l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    acc_ref[...] = (
+        acc_ref[...] * jnp.tile(correction, (1, acc_ref.shape[1] // LANE))
+        + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+            preferred_element_type=jnp.float32))
 
-    @pl.when(ki == blocks_k - 1)
+    @pl.when(flag & LAST != 0)
     def _():
         # Padded-out rows can have l == 0; guard the divide/log.
         l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
         if emit_lse:
-            lse_ref[0] = jnp.broadcast_to(m_ref[:, :1] + jnp.log(l),
-                                          lse_ref.shape[1:])
+            lse_ref[...] = jnp.broadcast_to(m_ref[:, :1] + jnp.log(l),
+                                            lse_ref.shape)
 
 
-def _dq_kernel(*refs, scale: float, blocks_k: int, causal: bool,
-               has_bias: bool, has_slopes: bool, kv_len: int):
+def _dq_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
+               causal: bool, has_bias: bool, has_slopes: bool, kv_len: int):
     refs = list(refs)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     del refs[:6]
     bias_ref = refs.pop(0) if has_bias else None
     slope_ref = refs.pop(0) if has_slopes else None
     dq_ref, dq_acc = refs
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(flag & FIRST != 0)
     def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_block_relevant(qi, ki, causal))
+    q, k, v = q_ref[...], k_ref[...], v_ref[...]   # native dtype (MXU-rate dots)
+    do, o = do_ref[...], o_ref[...]
+    s = _scores(q, k, qi, ki, tiles, scale, bias_ref, slope_ref,
+                causal=causal, kv_len=kv_len)
+    # The LSE block is lane-broadcast [Bq, 128]: tiled, not re-broadcast.
+    p = jnp.exp(s - jnp.tile(lse_ref[...], (1, s.shape[1] // LANE)))  # [Bq, Bk]
+    dp = jax.lax.dot_general(                      # dO @ V^T  [Bq, Bk]
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)        # [Bq, 1]
+    ds = p * (dp - delta)                          # dlogits  [Bq, Bk] f32
+    dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+    @pl.when(flag & LAST != 0)
     def _():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]     # native dtype (MXU-rate dots)
-        do, o = do_ref[0], o_ref[0]
-        s = _scores(q, k, qi, ki, scale, bias_ref, slope_ref,
-                    causal=causal, kv_len=kv_len)
-        p = jnp.exp(s - lse_ref[0][:, :1])         # [Bq, Bk] f32
-        dp = jax.lax.dot_general(                  # dO @ V^T  [Bq, Bk]
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True)    # [Bq, 1]
-        ds = p * (dp - delta)                      # dlogits  [Bq, Bk] f32
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-
-    @pl.when(ki == blocks_k - 1)
-    def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale: float, blocks_q: int, causal: bool,
-                has_bias: bool, has_slopes: bool, kv_len: int):
+def _dkv_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
+                causal: bool, has_bias: bool, has_slopes: bool, kv_len: int):
     refs = list(refs)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     del refs[:6]
     bias_ref = refs.pop(0) if has_bias else None
     slope_ref = refs.pop(0) if has_slopes else None
     dk_ref, dv_ref, dk_acc, dv_acc = refs
-    ki = pl.program_id(1)   # kv block is the OUTER sequential axis here
-    qi = pl.program_id(2)
 
-    @pl.when(qi == 0)
+    @pl.when(flag & FIRST != 0)
     def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_block_relevant(qi, ki, causal))
-    def _():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]     # native dtype (MXU-rate dots)
-        do, o = do_ref[0], o_ref[0]
-        s = _scores(q, k, qi, ki, scale, bias_ref, slope_ref,
-                    causal=causal, kv_len=kv_len)
-        p = jnp.exp(s - lse_ref[0][:, :1])         # [Bq, Bk] f32
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(   # P^T @ dO  [Bk, D]
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True)
-        ds = p * (dp - delta)
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(   # dS^T @ Q  [Bk, D]
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+    q, k, v = q_ref[...], k_ref[...], v_ref[...]   # native dtype (MXU-rate dots)
+    do, o = do_ref[...], o_ref[...]
+    s = _scores(q, k, qi, ki, tiles, scale, bias_ref, slope_ref,
+                causal=causal, kv_len=kv_len)
+    p = jnp.exp(s - jnp.tile(lse_ref[...], (1, s.shape[1] // LANE)))  # [Bq, Bk]
+    dv_acc[...] = dv_acc[...] + jax.lax.dot_general(   # P^T @ dO  [Bk, D]
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    ds = p * (dp - delta)
+    dk_acc[...] = dk_acc[...] + jax.lax.dot_general(   # dS^T @ Q  [Bk, D]
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
 
-    @pl.when(qi == blocks_q - 1)
+    @pl.when(flag & LAST != 0)
     def _():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _pad_inputs(q, k, v, bias):
-    """Pad head dim to the lane width and seq to the block size."""
+    """Pad head dim and sequence to the lane width."""
     b, h, s_len, d = q.shape
     d_pad = (LANE - d % LANE) % LANE
-    s_pad = (BLOCK_Q - s_len % BLOCK_Q) % BLOCK_Q
+    s_pad = (LANE - s_len % LANE) % LANE
     if d_pad or s_pad:
         pad = ((0, 0), (0, 0), (0, s_pad), (0, d_pad))
         q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
@@ -263,6 +329,44 @@ def _interpret() -> bool:
     return not attention._pallas_ok()
 
 
+def _call(body, name: str, pairs, operands, *, in_specs, out_shape,
+          out_specs, scratch, **statics):
+    """One `pallas_call` over the live pairs: grid (batch * head, step);
+    each step looks its pair up in the prefetched tables of `_live_pairs`
+    and runs `body(qi, ki, flag, *refs, **statics)` on it.
+
+    The interpreter evaluates a kernel's top level as plain operations of
+    the enclosing program, and inside a `check_vma=True` shard_map those
+    refuse a block (varying over the mesh) beside a constant (not varying);
+    a branch's body is opaque to that check. So under the interpreter, and
+    only there, the pair runs inside a branch that is always taken."""
+    interpret = _interpret()
+
+    def kernel(q_of, k_of, flag_of, *refs):
+        step = pl.program_id(1)
+        pair = functools.partial(body, q_of[step], k_of[step], flag_of[step],
+                                 *refs, **statics)
+        if interpret:
+            pl.when(step >= 0)(pair)
+        else:
+            pair()
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(pairs),
+            grid=(operands[0].shape[0], len(pairs[0])),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((rows, width), jnp.float32)
+                            for rows, width in scratch]),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=name,
+    )(*pairs, *operands)
+
+
 def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
     """A `pallas_call` out_shape entry varying over every mesh axis any
     operand varies over: inside a `check_vma=True` shard_map (the fused
@@ -272,73 +376,67 @@ def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _bias_specs(has_bias: bool, h: int, outer_is_q: bool):
+# Block index maps: the grid is (batch * head, step) and the step's q and
+# kv block come from the prefetched tables of `_live_pairs`.
+def _q_rows(rows: int, width: int):
+    return pl.BlockSpec((None, rows, width),
+                        lambda b_, s, q_of, k_of, flag_of: (b_, q_of[s], 0))
+
+
+def _k_rows(rows: int, width: int):
+    return pl.BlockSpec((None, rows, width),
+                        lambda b_, s, q_of, k_of, flag_of: (b_, k_of[s], 0))
+
+
+def _bias_specs(has_bias: bool, h: int, t: Tiles):
     if not has_bias:
         return []
-    if outer_is_q:
-        index = lambda b_, qi, ki: (b_ % h, qi, ki)
-    else:
-        index = lambda b_, ki, qi: (b_ % h, qi, ki)
-    return [pl.BlockSpec((1, BLOCK_Q, BLOCK_K), index)]
+    return [pl.BlockSpec(
+        (None, t.block_q, t.block_k),
+        lambda b_, s, q_of, k_of, flag_of: (b_ % h, q_of[s], k_of[s]))]
 
 
 def _slope_specs(has_slopes: bool, h: int):
     # One f32 scalar per head, shaped [H, 1, 1]; the grid's batch*head axis
-    # indexes its head row (same map under both backward grids — the block
-    # index ignores qi/ki).
+    # indexes its head row.
     if not has_slopes:
         return []
-    return [pl.BlockSpec((1, 1, 1), lambda b_, i, j: (b_ % h, 0, 0))]
+    return [pl.BlockSpec((None, 1, 1),
+                         lambda b_, s, q_of, k_of, flag_of: (b_ % h, 0, 0))]
 
 
 def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
                    emit_lse: bool = True):
     bias = _canon_bias(bias, q.shape[1], q.shape[2])
     q, k, v, bias, (b, h, s_len, d, bh, sp, dp) = _pad_inputs(q, k, v, bias)
-    blocks_q = sp // BLOCK_Q
-    blocks_k = sp // BLOCK_K
     has_bias = bias is not None
     has_slopes = slopes is not None
+    t = choose_tiles(s_len)
     if has_slopes:
         slopes = jnp.asarray(slopes, jnp.float32).reshape(h, 1, 1)
 
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, blocks_k=blocks_k, causal=causal,
-        has_bias=has_bias, has_slopes=has_slopes, kv_len=s_len,
-        emit_lse=emit_lse)
-    qkv_specs = [
-        pl.BlockSpec((1, BLOCK_Q, dp), lambda b_, qi, ki: (b_, qi, 0)),
-        pl.BlockSpec((1, BLOCK_K, dp), lambda b_, qi, ki: (b_, ki, 0)),
-        pl.BlockSpec((1, BLOCK_K, dp), lambda b_, qi, ki: (b_, ki, 0)),
-    ]
-    o_spec = pl.BlockSpec((1, BLOCK_Q, dp), lambda b_, qi, ki: (b_, qi, 0))
     operands = ([q, k, v] + ([bias] if has_bias else [])
                 + ([slopes] if has_slopes else []))
     o_shape = _out_struct((bh, sp, dp), q.dtype, *operands)
+    o_spec = _q_rows(t.block_q, dp)
     if emit_lse:
         # The LSE residual is only needed when a backward pass will run;
         # forward-only (eval) calls skip the extra [BH, S, 128] HBM write.
         out_shape = (o_shape,
                      _out_struct((bh, sp, LANE), jnp.float32, *operands))
-        out_specs = (o_spec, pl.BlockSpec((1, BLOCK_Q, LANE),
-                                          lambda b_, qi, ki: (b_, qi, 0)))
+        out_specs = (o_spec, _q_rows(t.block_q, LANE))
     else:
         out_shape, out_specs = o_shape, o_spec
-    result = pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid=(bh, blocks_q, blocks_k),
-        in_specs=(qkv_specs + _bias_specs(has_bias, h, outer_is_q=True)
-                  + _slope_specs(has_slopes, h)),
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((BLOCK_Q, dp), jnp.float32),
-            pltpu.VMEM((BLOCK_Q, LANE), jnp.float32),
-            pltpu.VMEM((BLOCK_Q, LANE), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name="flash_fwd",
-    )(*operands)
+    result = _call(
+        _fwd_kernel, "flash_fwd", _live_pairs(t, causal, q_major=True),
+        operands,
+        in_specs=([_q_rows(t.block_q, dp), _k_rows(t.block_k, dp),
+                   _k_rows(t.block_k, dp)]
+                  + _bias_specs(has_bias, h, t) + _slope_specs(has_slopes, h)),
+        out_shape=out_shape, out_specs=out_specs,
+        scratch=[(t.block_q, dp), (t.block_q, LANE), (t.block_q, LANE)],
+        scale=scale, tiles=t, causal=causal, has_bias=has_bias,
+        has_slopes=has_slopes, kv_len=s_len, emit_lse=emit_lse)
 
     out, lse = result if emit_lse else (result, None)
     out = out.reshape(b, h, sp, dp)[:, :, :s_len, :d]
@@ -348,80 +446,43 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
 def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
                     causal: bool):
     bias = _canon_bias(bias, q.shape[1], q.shape[2])
-    dtype_in = (q.dtype, k.dtype, v.dtype)
     qp, kp, vp, bias, (b, h, s_len, d, bh, sp, dp) = _pad_inputs(q, k, v, bias)
     # Pad O / dO the same way (their padded rows are zero, so padded-row
     # contributions to dk/dv vanish and padded delta rows are zero).
     op, gp, *_ = _pad_inputs(out, g, g, None)[:2]
-    blocks_q = sp // BLOCK_Q
-    blocks_k = sp // BLOCK_K
     has_bias = bias is not None
     has_slopes = slopes is not None
+    t = choose_tiles(s_len)
     if has_slopes:
         slopes = jnp.asarray(slopes, jnp.float32).reshape(h, 1, 1)
-    interpret = _interpret()
 
     common = ([qp, kp, vp, op, gp, lse] + ([bias] if has_bias else [])
               + ([slopes] if has_slopes else []))
-    grad_shape = _out_struct((bh, sp, dp), jnp.float32, *common)
+    # Gradients leave in the operands' dtype: one rounding from the f32
+    # accumulator, here and not in a cast after the kernel.
+    grad_shape = lambda x: _out_struct((bh, sp, dp), x.dtype, *common)
+    shared = dict(
+        in_specs=([_q_rows(t.block_q, dp), _k_rows(t.block_k, dp),
+                   _k_rows(t.block_k, dp), _q_rows(t.block_q, dp),
+                   _q_rows(t.block_q, dp), _q_rows(t.block_q, LANE)]
+                  + _bias_specs(has_bias, h, t) + _slope_specs(has_slopes, h)),
+        scale=scale, tiles=t, causal=causal, has_bias=has_bias,
+        has_slopes=has_slopes, kv_len=s_len)
 
-    def qspec(inner_kv: bool):
-        # index maps for (q-like, kv-like, lse) inputs under the two grids
-        if inner_kv:  # grid (bh, qi, ki)
-            qix = lambda b_, qi, ki: (b_, qi, 0)
-            kix = lambda b_, qi, ki: (b_, ki, 0)
-        else:         # grid (bh, ki, qi)
-            qix = lambda b_, ki, qi: (b_, qi, 0)
-            kix = lambda b_, ki, qi: (b_, ki, 0)
-        return [
-            pl.BlockSpec((1, BLOCK_Q, dp), qix),     # q
-            pl.BlockSpec((1, BLOCK_K, dp), kix),     # k
-            pl.BlockSpec((1, BLOCK_K, dp), kix),     # v
-            pl.BlockSpec((1, BLOCK_Q, dp), qix),     # o
-            pl.BlockSpec((1, BLOCK_Q, dp), qix),     # do
-            pl.BlockSpec((1, BLOCK_Q, LANE), qix),   # lse
-        ]
+    dq = _call(
+        _dq_kernel, "flash_bwd_dq", _live_pairs(t, causal, q_major=True),
+        common, out_shape=grad_shape(q), out_specs=_q_rows(t.block_q, dp),
+        scratch=[(t.block_q, dp)], **shared)
+    dk, dv = _call(
+        _dkv_kernel, "flash_bwd_dkv", _live_pairs(t, causal, q_major=False),
+        common, out_shape=(grad_shape(k), grad_shape(v)),
+        out_specs=(_k_rows(t.block_k, dp), _k_rows(t.block_k, dp)),
+        scratch=[(t.block_k, dp), (t.block_k, dp)], **shared)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, blocks_k=blocks_k,
-                          causal=causal, has_bias=has_bias,
-                          has_slopes=has_slopes, kv_len=s_len),
-        out_shape=grad_shape,
-        grid=(bh, blocks_q, blocks_k),
-        in_specs=(qspec(inner_kv=True)
-                  + _bias_specs(has_bias, h, outer_is_q=True)
-                  + _slope_specs(has_slopes, h)),
-        out_specs=pl.BlockSpec((1, BLOCK_Q, dp), lambda b_, qi, ki: (b_, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((BLOCK_Q, dp), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(*common)
+    def unpad(x):
+        return x.reshape(b, h, sp, dp)[:, :, :s_len, :d]
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, blocks_q=blocks_q,
-                          causal=causal, has_bias=has_bias,
-                          has_slopes=has_slopes, kv_len=s_len),
-        out_shape=(grad_shape, grad_shape),
-        grid=(bh, blocks_k, blocks_q),
-        in_specs=(qspec(inner_kv=False)
-                  + _bias_specs(has_bias, h, outer_is_q=False)
-                  + _slope_specs(has_slopes, h)),
-        out_specs=(
-            pl.BlockSpec((1, BLOCK_K, dp), lambda b_, ki, qi: (b_, ki, 0)),
-            pl.BlockSpec((1, BLOCK_K, dp), lambda b_, ki, qi: (b_, ki, 0)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((BLOCK_K, dp), jnp.float32),
-            pltpu.VMEM((BLOCK_K, dp), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(*common)
-
-    def unpad(x, dt):
-        return x.reshape(b, h, sp, dp)[:, :, :s_len, :d].astype(dt)
-
-    return unpad(dq, dtype_in[0]), unpad(dk, dtype_in[1]), unpad(dv, dtype_in[2])
+    return unpad(dq), unpad(dk), unpad(dv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))

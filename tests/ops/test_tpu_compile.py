@@ -38,11 +38,18 @@ from oobleck_tpu.serve.kv_blocks import pages_for
 # [B, H, S, D] of one microbatch's attention call: gpt2 124M
 # (examples/gpt2.yaml: microbatch 8, 12 heads of 64, seq 1024), a llama-7B
 # head geometry (flash sees K/V already repeated to 32 heads of 128), and
-# gpt3-2.7b's head_dim 80 (padded to the 128-lane width in-kernel).
+# gpt3-2.7b's head_dim 80 (padded to the 128-lane width in-kernel); then
+# the benchmark cell's real microbatch, a 2048-token sequence (a grid of 10
+# live 512 x 512 block pairs of 16) and a short serving prompt (one 128-row
+# block): the tiles follow the sequence length (flash.choose_tiles), and
+# every tile it returns has to fit the chip's VMEM.
 FLASH_WIDTHS = {
     "gpt2": (8, 12, 1024, 64),
     "llama": (2, 32, 1024, 128),
     "gpt3-2.7b": (2, 32, 1024, 80),
+    "gpt3-2.7b-cell": (4, 32, 1024, 80),
+    "seq-2048": (1, 32, 2048, 80),
+    "short-prompt": (1, 12, 128, 64),
 }
 # (Hq, Hkv, D) of the serve pools: gpt2 MHA and llama-style GQA.
 PAGED_WIDTHS = {"gpt2": (12, 12, 64), "llama-gqa": (32, 8, 128)}
@@ -182,6 +189,30 @@ def test_paged_alibi_compiles(v5e):
 
     _compile(decode, v5e[0], ((lanes, hq, d), jnp.bfloat16), *rest)
     _compile(verify, v5e[0], ((lanes, t, hq, d), jnp.bfloat16), *rest)
+
+
+# The kernels' host cost at process start, held down without a clock. A
+# process pays trace + lower of every flash call site on its first step,
+# cache hit or not (the cache's key is computed from the lowered module),
+# and that time follows the size of the kernel bodies. grad(flash) at the
+# benchmark cell's microbatch lowers to 26.7 k characters with the 128 x 128
+# kernels of PR 26 and to 28.5 k with PR 28's (the same three bodies, plus
+# three small step tables and their index maps per call); both take 0.09 to
+# 0.10 s to trace and lower here. A body unrolled in Python over the block
+# pairs of a row (2 to 8 at these tiles) adds a body's 3 k per kernel and
+# pair, and a second copy of each body (a masked and an unmasked form,
+# tried and measured to buy nothing) 4 k in all: the limit leaves room for
+# a quarter more than there is and for no such loop.
+FLASH_GRAD_MODULE_CHARS = 36_000
+
+
+def test_flash_grad_module_stays_small(v5e):
+    one = SingleDeviceSharding(v5e[0])
+    arg = jax.ShapeDtypeStruct(FLASH_WIDTHS["gpt3-2.7b-cell"], jnp.bfloat16,
+                               sharding=one)
+    text = jax.jit(_grads(flash_attention)).lower(arg, arg, arg).as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert len(text) < FLASH_GRAD_MODULE_CHARS, len(text)
 
 
 # Every kernel has a stable name on the device: `name=` on its pallas_call

@@ -120,6 +120,117 @@ def test_flash_inkernel_alibi_slopes_match_bias(shape, causal):
                                    rtol=2e-2, atol=2e-3)
 
 
+FORMS = ["causal", "non_causal", "alibi_slopes", "bias"]
+
+
+def _flash_case(seq, head_dim, form):
+    """(flash, reference) of one form, each q, k, v -> out, and the inputs:
+    one batch of two heads, enough for a per-head slope or bias."""
+    from oobleck_tpu.ops.attention import alibi_bias, alibi_slopes
+
+    ks = jax.random.split(jax.random.PRNGKey(seq + head_dim), 3)
+    q, k, v = (jax.random.normal(kk, (1, 2, seq, head_dim), jnp.float32) * 0.3
+               for kk in ks)
+    causal = form != "non_causal"
+    bias = alibi_bias(2, seq, seq) if form in ("alibi_slopes", "bias") else None
+    kw = {"causal": causal}
+    if form == "alibi_slopes":
+        kw["alibi_slopes"] = alibi_slopes(2)
+    elif form == "bias":
+        kw["bias"] = bias
+    flash = lambda q, k, v: flash_attention(q, k, v, **kw)
+    ref = lambda q, k, v: _xla_causal_attention(q, k, v, bias=bias,
+                                                causal=causal)
+    return flash, ref, (q, k, v)
+
+
+def _assert_fwd_and_grads_match(flash, ref, args):
+    # One vjp each: the forward and dq, dk, dv of the same call.
+    co = jax.random.normal(jax.random.PRNGKey(3), args[0].shape, jnp.float32)
+    both = lambda fn: jax.jit(
+        lambda q, k, v: (lambda out, vjp: (out, *vjp(co)))(
+            *jax.vjp(fn, q, k, v)))
+    got, want = both(flash)(*args), both(ref)(*args)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=2e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("head_dim", [64, 80, 128])
+@pytest.mark.parametrize("seq", [128, 384, 640, 1024, 2048])
+def test_flash_matches_xla_at_every_chosen_tile(seq, head_dim, form):
+    """Forward and dq, dk, dv at the tiles `choose_tiles` returns: one
+    block (128, 384), one q block against the whole row (640), and grids of
+    3 and 10 live 512 x 512 pairs of 4 and 16 (1024, 2048), where the
+    wholly masked pairs are no step at all."""
+    _assert_fwd_and_grads_match(*_flash_case(seq, head_dim, form))
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("seq", [600, 1000])
+def test_flash_matches_xla_with_padded_rows_in_the_last_block(seq, form):
+    """A sequence that is not a multiple of 128 is padded to the next one
+    and no further; the padded key columns sit in the last block of several
+    (masked there, and only there, in the bidirectional form)."""
+    _assert_fwd_and_grads_match(*_flash_case(seq, 80, form))
+
+
+def test_choose_tiles_is_a_pure_function_of_the_sequence_length():
+    import inspect
+
+    from oobleck_tpu.ops import flash
+
+    # No knob: the sequence length is all it takes, and nothing of the
+    # module reads the environment.
+    assert list(inspect.signature(flash.choose_tiles).parameters) == [
+        "seq_len"]
+    assert "environ" not in inspect.getsource(flash)
+    assert flash.choose_tiles(1024) is flash.choose_tiles(1024)  # memoised
+    assert flash.choose_tiles(128) == (128, 128, 128)
+    assert flash.choose_tiles(5) == (128, 128, 128)
+    assert flash.choose_tiles(640) == (640, 128, 640)
+    assert flash.choose_tiles(1024) == (1024, 512, 512)
+    assert flash.choose_tiles(2048) == (2048, 512, 512)
+    for seq_len in range(1, 4200, 7):
+        t = flash.choose_tiles(seq_len)
+        # Never a whole 128-row block of padding.
+        assert seq_len <= t.seq < seq_len + 128 and t.seq % 128 == 0
+        for block in (t.block_q, t.block_k):
+            assert block % 128 == 0 and t.seq % block == 0
+        assert t.block_q <= flash.MAX_BLOCK
+        assert t.block_q * t.block_k <= flash.MAX_PAIR
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("seq_len", [128, 600, 640, 1024, 1100, 2048, 4096])
+@pytest.mark.parametrize("q_major", [True, False])
+def test_live_pairs_are_exactly_the_pairs_the_mask_leaves(seq_len, causal,
+                                                          q_major):
+    """The grid's steps against the mask drawn out in full: a step for
+    every block pair with a live element and for no other, FIRST and LAST
+    once per accumulation, an accumulation's steps together."""
+    from oobleck_tpu.ops import flash
+
+    t = flash.choose_tiles(seq_len)
+    q_of, k_of, flag_of = flash._live_pairs(t, causal, q_major)
+    pos = np.arange(t.seq)
+    live = (pos[:, None] >= pos[None, :]) if causal else np.ones(
+        (t.seq, t.seq), bool)
+    blocks = live.reshape(t.seq // t.block_q, t.block_q,
+                          t.seq // t.block_k, t.block_k)
+    want = {(qi, ki) for qi in range(blocks.shape[0])
+            for ki in range(blocks.shape[2]) if blocks[qi, :, ki].any()}
+    got = list(zip(q_of.tolist(), k_of.tolist()))
+    assert set(got) == want and len(got) == len(want)
+    major = q_of if q_major else k_of
+    groups = len(set(major.tolist()))
+    assert sum(bool(f & flash.FIRST) for f in flag_of) == groups
+    assert sum(bool(f & flash.LAST) for f in flag_of) == groups
+    assert np.all(np.diff(major) >= 0)          # an accumulation's steps adjoin
+    assert flash._live_pairs(t, causal, q_major)[0] is q_of      # memoised
+
+
 def test_flash_bwd_is_pallas_not_xla_recompute():
     """The VJP must not rebuild the [S, S] logits through XLA: no dot with an
     S x S operand may appear in the backward jaxpr outside pallas calls."""
