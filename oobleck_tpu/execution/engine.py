@@ -600,7 +600,9 @@ class OobleckEngine:
         self.dataset = build_dataset(
             args.model.dataset_path, args.model.dataset_name,
             model_name=args.model.model_name,
-            vocab_size=getattr(cfg, "vocab_size", 0),
+            # A model that holds a share of the vocabulary says so.
+            vocab_size=getattr(cfg, "data_vocab_size",
+                               getattr(cfg, "vocab_size", 0)),
             seq_length=seq_len,
             data_kind=getattr(self.model, "data_kind", "causal_lm"),
             mask_token_id=getattr(cfg, "mask_token_id", 103),
@@ -794,6 +796,7 @@ class OobleckEngine:
             warmup_steps=args.job.warmup_steps,
             weight_decay=args.job.weight_decay,
             max_grad_norm=args.job.max_grad_norm,
+            frozen=getattr(self.model, "frozen_param_names", ()),
         )
         if agent_pipe is not None:
             ReconfigurationEngine(self, agent_pipe)

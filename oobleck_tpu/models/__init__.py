@@ -53,6 +53,18 @@ register("gpt2-moe")(lambda o: _moe(o, hidden_size=768, num_layers=12, num_heads
 register("gpt2-moe-tiny")(lambda o: _moe(o, vocab_size=256, hidden_size=64, num_layers=4, num_heads=4, max_position_embeddings=128, num_experts=4))
 
 
+def _lfm2(overrides: dict[str, Any], **preset):
+    from oobleck_tpu.models.lfm2 import Lfm2Config, Lfm2Model
+
+    return Lfm2Model(Lfm2Config().override(**preset).override(**overrides))
+
+
+# LFM2-MoE (LiquidAI lfm2_moe): gated short convolutions beside GQA layers,
+# dropless top-k sigmoid-routed experts; the defaults are LFM2-24B-A2B's.
+register("lfm2-24b-a2b")(lambda o: _lfm2(o))
+register("lfm2-moe-tiny")(lambda o: _lfm2(o, vocab_size=256, hidden_size=64, num_layers=4, num_heads=4, num_kv_heads=2, intermediate_size=128, moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, num_dense_layers=1, max_position_embeddings=128))
+
+
 # Bloom family: GPT architecture with ALiBi position biases (no wpe)
 register("bloom-560m")(lambda o: _gpt(o, vocab_size=250880, hidden_size=1024, num_layers=24, num_heads=16, position_embedding="alibi"))
 register("bloom-7b1")(lambda o: _gpt(o, vocab_size=250880, hidden_size=4096, num_layers=30, num_heads=32, position_embedding="alibi"))

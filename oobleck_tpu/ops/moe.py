@@ -17,9 +17,14 @@ unsharded formulation is tested under shard_map on the CPU mesh.
 
 from __future__ import annotations
 
+import functools
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def switch_moe(
@@ -96,3 +101,580 @@ def switch_moe(
     mean_prob = probs.mean(axis=0)
     aux = NE * jnp.sum(fraction * mean_prob)
     return y.reshape(B, S, M), aux.astype(jnp.float32)
+
+
+# ===================================================================== #
+# Routed experts: dropless top-k over a held range of the experts        #
+# ===================================================================== #
+#
+# The other expert layer of the repo. Where `switch_moe` builds a dense
+# [T, NE, C] one-hot and drops what exceeds a capacity, `routed_experts`
+# drops nothing and does work for the (token, slot) pairs that are routed
+# to the experts this chip HOLDS: pairs are laid out by expert in one
+# buffer of the static worst-case length, each expert's rows padded to a
+# whole number of row tiles, and the three products run as grouped matrix
+# multiplications that visit the tiles in use and skip the empty tail. What
+# XLA does around them (rows in and out of the buffer, silu x up) loops
+# over the tiles in use too, so no work follows the buffer's length but
+# its zero fill. Kernels (stable names on the `pallas_call`, so a device trace shows
+# `%moe_gmm.N` / `%moe_tgmm.N`):
+#
+#   moe_gmm   rows [M, K] x experts [E, K, N] -> [M, N]   forward, and dX
+#             with the expert matrices read transposed
+#   moe_tgmm  rows^T [M, K] x rows [M, N] -> [E, K, N]    dW
+#
+# On one chip nothing is exchanged; with `num_experts_held` < `num_experts`
+# the result is this chip's PART of the layer's output (the partial sums
+# of all shares add up to the uncut layer, tests/ops/test_routed_experts.py).
+
+LANE = 128
+MAX_ROW_TILE = 512        # rows of one tile: what an expert's rows pad to
+SUBLANE = 16              # a bfloat16 tile's rows
+MAX_COL_TILE = 512        # columns of the output one grid step produces
+MAX_TGMM_ROWS = 1024      # rows of dW one grid step accumulates
+_GMM_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=64 * 1024 * 1024)
+_TGMM_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _pallas_ok() -> bool:
+    from oobleck_tpu.ops import attention
+
+    return attention._pallas_ok()
+
+
+def _col_tile(n: int, limit: int) -> int:
+    """The largest multiple of the lane width that divides `n` and stays
+    within `limit`; the whole of `n` where no such tile exists (widths
+    under a lane, the tests' sizes)."""
+    tiles = [LANE * m for m in range(1, limit // LANE + 1)
+             if n % (LANE * m) == 0]
+    return max(tiles) if tiles else n
+
+
+@functools.cache
+def choose_row_tile(num_pairs: int, num_experts: int) -> int:
+    """Rows of one tile, from the call's shape: the rows an expert EXPECTS
+    (the pairs over all the experts) and half as many again, in as few
+    equal tiles as MAX_ROW_TILE allows, rounded up to the lane width (to a
+    bfloat16 sublane tile below it). An expert's rows then fill the same
+    number of tiles whatever the router does to its load within a half
+    either way. With a tile that divides the expected rows (256 at 512
+    rows: two tiles or three at the slightest excess) the tiles in use,
+    and the step's time with them, changed with every seed's router: 0.9 %
+    between six seeds of the benchmark's cell (my chip runs, PR 29)."""
+    roomy = 1.5 * num_pairs / num_experts
+    tiles = -(-roomy // MAX_ROW_TILE)
+    unit = LANE if roomy / tiles >= LANE else SUBLANE
+    return int(-(-roomy / tiles // unit) * unit)
+
+
+class RoutingPlan(NamedTuple):
+    """Where the routed pairs' rows lie, for one call, by row TILE. Pairs
+    are (token, slot) flattened to t * k + s; `order` lists them by held
+    expert (stable, so in the pairs' own order inside an expert), and a
+    tile's rows are a contiguous run of it. Nothing here is as long as the
+    buffer."""
+    tile_group: jax.Array      # [tiles] int32: the held expert of a row tile
+    num_tiles: jax.Array       # [1] int32: tiles in use; the rest is skipped
+    padded_sizes: jax.Array    # [held] int32: each expert's rows, padded
+    group_sizes: jax.Array     # [held] int32: each expert's rows
+    order: jax.Array           # [pairs + tile] int32: pairs by held expert
+    tile_first: jax.Array      # [tiles] int32: where in `order` a tile starts
+    tile_rows: jax.Array       # [tiles] int32: rows of the tile that hold a pair
+
+
+def buffer_rows(num_tokens: int, top_k: int, held: int,
+                num_experts: int) -> tuple[int, int]:
+    """(rows of the buffer, rows of a tile): the worst case, every pick of
+    every token held here, plus one tile of padding for every expert."""
+    pairs = num_tokens * min(top_k, held)
+    tile = choose_row_tile(num_tokens * top_k, num_experts)
+    return -(-pairs // tile) * tile + held * tile, tile
+
+
+def plan_routing(local_expert: jax.Array, held: int, rows: int,
+                 tile: int) -> RoutingPlan:
+    """`local_expert` [pairs]: the held expert's index, or `held` for a
+    pair routed elsewhere. Static shapes, no scatter: one stable sort, and
+    arithmetic on `held` and `rows // tile` numbers."""
+    i32 = jnp.int32
+    order = jnp.argsort(local_expert, stable=True).astype(i32)  # by expert
+    sizes = jnp.sum(local_expert[None, :] == jnp.arange(held, dtype=i32)[:, None],
+                    axis=1, dtype=i32)
+    # Every expert has one tile at least, so dW of an expert that got no
+    # token is written (as zeros) by the kernel that visits its tile.
+    tiles = jnp.maximum(-(-sizes // tile), 1)
+    first_tile = jnp.cumsum(tiles) - tiles
+    sorted_start = jnp.cumsum(sizes) - sizes
+    t = jnp.arange(rows // tile, dtype=i32)
+    group = jnp.minimum(
+        jnp.searchsorted(jnp.cumsum(tiles), t, side="right"), held - 1
+    ).astype(i32)
+    within = (t - first_tile[group]) * tile       # rows of its expert before
+    in_use = t < jnp.sum(tiles)
+    tile_rows = jnp.where(in_use, jnp.clip(sizes[group] - within, 0, tile), 0)
+    tile_first = jnp.where(in_use, sorted_start[group] + within, 0)
+    return RoutingPlan(
+        group, jnp.sum(tiles).reshape(1).astype(i32),
+        (tiles * tile).astype(i32), sizes,
+        jnp.concatenate([order, jnp.zeros((tile,), i32)]),
+        tile_first.astype(i32), tile_rows.astype(i32))
+
+
+# --------------------------------------------------------------------- #
+# kernels                                                                #
+# --------------------------------------------------------------------- #
+
+def _gmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, out_ref, w_scratch,
+              *, transpose_rhs: bool):
+    """One row tile times its expert's [K, tn] (or [tn, K], transposed)
+    block. The expert's block stays in VMEM over the tiles of one expert
+    (its block index does not change), so an expert's matrix is read once
+    a call; it is cast to the rows' dtype once per expert too."""
+    m = pl.program_id(1)
+
+    @pl.when(m < num_tiles[0])
+    def _():
+        if w_scratch is None:
+            w = rhs_ref[...]
+        else:
+            new_expert = jnp.logical_or(
+                m == 0, tile_group[m] != tile_group[jnp.maximum(m - 1, 0)])
+
+            @pl.when(new_expert)
+            def _():
+                w_scratch[...] = rhs_ref[...].astype(w_scratch.dtype)
+
+            w = w_scratch[...]
+        dims = (((1,), (1,)), ((), ())) if transpose_rhs else (
+            ((1,), (0,)), ((), ()))
+        out_ref[...] = lax.dot_general(
+            lhs_ref[...], w, dims,
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+def _interpret() -> bool:
+    return not _pallas_ok()
+
+
+def _last_tile(m, num_tiles):
+    """Tiles past the last one in use keep the block indices of the last:
+    nothing is fetched for them and nothing written."""
+    return jnp.minimum(m, num_tiles[0] - 1)
+
+
+def gmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int,
+             transpose_rhs: bool = False):
+    """`moe_gmm`: lhs [M, K] (rows by expert, whole tiles) x rhs [E, K, N]
+    -> [M, N]; with `transpose_rhs`, rhs is [E, N, K]. Rows of tiles past
+    `num_tiles` are not written."""
+    m_rows, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    assert rhs.shape[2 if transpose_rhs else 1] == k, (lhs.shape, rhs.shape)
+    assert m_rows % tile == 0, (m_rows, tile)
+    tn = _col_tile(n, MAX_COL_TILE)
+    cast = rhs.dtype != lhs.dtype
+
+    last = _last_tile
+    rows_of = lambda n_, m_, tg, nt: (last(m_, nt), 0)
+    if transpose_rhs:
+        rhs_block, rhs_of = (None, tn, k), (
+            lambda n_, m_, tg, nt: (tg[last(m_, nt)], n_, 0))
+    else:
+        rhs_block, rhs_of = (None, k, tn), (
+            lambda n_, m_, tg, nt: (tg[last(m_, nt)], 0, n_))
+    scratch = [pltpu.VMEM(rhs_block[1:], lhs.dtype)] if cast else []
+    body = functools.partial(_gmm_body, transpose_rhs=transpose_rhs)
+    kernel = body if cast else (
+        lambda tg, nt, l, r, o: body(tg, nt, l, r, o, None))
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((m_rows, n), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, m_rows // tile),
+            in_specs=[pl.BlockSpec((tile, k), rows_of),
+                      pl.BlockSpec(rhs_block, rhs_of)],
+            out_specs=pl.BlockSpec(
+                (tile, tn), lambda n_, m_, tg, nt: (last(m_, nt), n_)),
+            scratch_shapes=scratch),
+        compiler_params=_GMM_PARAMS,
+        interpret=_interpret(),
+        name="moe_gmm",
+    )(tile_group, num_tiles, lhs, rhs)
+
+
+def _tgmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, out_ref, acc):
+    """Rows^T x rows of one tile, summed over the tiles of one expert into
+    its [tk, tn] block of dW."""
+    m = pl.program_id(2)
+    used = num_tiles[0]
+
+    @pl.when(m < used)
+    def _():
+        group = tile_group[m]
+        first = jnp.logical_or(
+            m == 0, group != tile_group[jnp.maximum(m - 1, 0)])
+        last = jnp.logical_or(
+            m == used - 1,
+            group != tile_group[jnp.minimum(m + 1, used - 1)])
+
+        @pl.when(first)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        acc[...] += lax.dot_general(
+            lhs_ref[...], rhs_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(last)
+        def _():
+            out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
+              out_dtype):
+    """`moe_tgmm`: lhs [M, K], rhs [M, N] -> [E, K, N], expert e's block
+    the product over e's rows. Every expert has a tile, so every block is
+    written."""
+    m_rows, k = lhs.shape
+    n = rhs.shape[1]
+    assert rhs.shape[0] == m_rows and m_rows % tile == 0
+    tk = _col_tile(k, MAX_TGMM_ROWS)
+    tn = _col_tile(n, MAX_COL_TILE)
+
+    last = _last_tile
+    return pl.pallas_call(
+        _tgmm_body,
+        out_shape=jax.ShapeDtypeStruct((num_groups, k, n), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k // tk, n // tn, m_rows // tile),
+            in_specs=[
+                pl.BlockSpec((tile, tk),
+                             lambda k_, n_, m_, tg, nt: (last(m_, nt), k_)),
+                pl.BlockSpec((tile, tn),
+                             lambda k_, n_, m_, tg, nt: (last(m_, nt), n_))],
+            out_specs=pl.BlockSpec(
+                (None, tk, tn),
+                lambda k_, n_, m_, tg, nt: (tg[last(m_, nt)], k_, n_)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        compiler_params=_TGMM_PARAMS,
+        interpret=_interpret(),
+        name="moe_tgmm",
+    )(tile_group, num_tiles, lhs, rhs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gmm(lhs, rhs, tile_group, num_tiles, tile):
+    return gmm_call(lhs, rhs, tile_group, num_tiles, tile=tile)
+
+
+def _gmm_fwd(lhs, rhs, tile_group, num_tiles, tile):
+    out = gmm_call(lhs, rhs, tile_group, num_tiles, tile=tile)
+    return out, (lhs, rhs, tile_group, num_tiles)
+
+
+def _gmm_bwd(tile, res, d_out):
+    lhs, rhs, tile_group, num_tiles = res
+    d_lhs = gmm_call(d_out, rhs, tile_group, num_tiles, tile=tile,
+                     transpose_rhs=True)
+    d_rhs = tgmm_call(lhs, d_out, tile_group, num_tiles, tile=tile,
+                      num_groups=rhs.shape[0], out_dtype=rhs.dtype)
+    return d_lhs, d_rhs, None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(rows, experts, plan: RoutingPlan, tile: int):
+    """rows [M, K] x experts [E, K, N] -> [M, N] over the plan's layout.
+    The Pallas kernels on a TPU; elsewhere XLA's own ragged product over
+    the same padded groups (the kernels' interpreter is for their tests)."""
+    if _pallas_ok():
+        # Under an outer scope a transformation's wrapper (jvp(...),
+        # transpose(...)) goes around THAT component of the name stack and
+        # the kernels keep their own: `%moe_gmm.N`, not `%jvp_moe_gmm_.N`.
+        with jax.named_scope("routed_experts"):
+            return _gmm(rows, experts, plan.tile_group, plan.num_tiles, tile)
+    return lax.ragged_dot(rows, experts.astype(rows.dtype), plan.padded_sizes)
+
+
+# --------------------------------------------------------------------- #
+# rows in and out of the buffer                                          #
+# --------------------------------------------------------------------- #
+#
+# Both directions, forward and backward, are one of two loops over the row
+# tiles IN USE (a `fori_loop` whose trip count is the plan's `num_tiles`,
+# so the work follows the rows routed here and not the worst-case buffer):
+# a tile's rows gathered from their tokens, or a tile's rows added to
+# their tokens. A whole-buffer gather costs the chip about 80 ns a row,
+# used or not: at 8 x 1024 tokens, top 4, eight such gathers a layer and
+# microbatch were 40 % of the cell's step (my chip run, PR 29).
+
+def _tile_of(plan: RoutingPlan, i, tile: int, top_k: int):
+    """(first row, pairs, tokens, valid) of row tile `i`."""
+    pair = lax.dynamic_slice(plan.order, (plan.tile_first[i],), (tile,))
+    valid = jnp.arange(tile, dtype=jnp.int32) < plan.tile_rows[i]
+    return i * tile, pair, pair // top_k, valid
+
+
+def _rows_from_tokens(src, plan: RoutingPlan, tile: int, top_k: int,
+                      weights=None):
+    """[T, D] -> the buffer's rows [M, D]: row r is `src[token of r]`
+    (times its pair's weight); rows that hold no pair, and tiles not in
+    use, are zero."""
+    rows, d = plan.tile_group.shape[0] * tile, src.shape[1]
+
+    def one_tile(i, out):
+        start, pair, token, valid = _tile_of(plan, i, tile, top_k)
+        block = src[token]
+        if weights is not None:
+            block = block.astype(jnp.float32) * weights.reshape(-1)[pair][:, None]
+        block = jnp.where(valid[:, None], block, 0).astype(src.dtype)
+        return lax.dynamic_update_slice(out, block, (start, 0))
+
+    return lax.fori_loop(0, plan.num_tiles[0], one_tile,
+                         jnp.zeros((rows, d), src.dtype))
+
+
+def _tokens_from_rows(rows, plan: RoutingPlan, tile: int, top_k: int,
+                      num_tokens: int, weights=None):
+    """The buffer's rows [M, D] -> [T, D] float32: a token is the sum of
+    its rows (times their pairs' weights); a row that holds no pair adds
+    past the end and is dropped. (Within a tile the tokens ascend and none
+    repeats, but telling the scatter so made it 2.5 x slower on the chip:
+    1.86 against 0.76 ms over 20 tiles, my chip run, PR 29.)"""
+    d = rows.shape[1]
+
+    def one_tile(i, acc):
+        start, pair, token, valid = _tile_of(plan, i, tile, top_k)
+        block = lax.dynamic_slice(rows, (start, 0), (tile, d)).astype(
+            jnp.float32)
+        if weights is not None:
+            block = block * weights.reshape(-1)[pair][:, None]
+        return acc.at[jnp.where(valid, token, num_tokens)].add(
+            block, mode="drop")
+
+    return lax.fori_loop(0, plan.num_tiles[0], one_tile,
+                         jnp.zeros((num_tokens, d), jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _dispatch(x, plan: RoutingPlan, tile: int, top_k: int):
+    """x [T, D] -> the buffer's rows [M, D]. Backward: a token's gradient
+    is the sum of its rows'."""
+    return _rows_from_tokens(x, plan, tile, top_k)
+
+
+def _dispatch_fwd(x, plan, tile, top_k):
+    return _rows_from_tokens(x, plan, tile, top_k), (plan, x.shape[0])
+
+
+def _dispatch_bwd(tile, top_k, res, d_rows):
+    plan, tokens = res
+    dx = _tokens_from_rows(d_rows, plan, tile, top_k, tokens)
+    return dx.astype(d_rows.dtype), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(rows, weights, plan: RoutingPlan, tile: int, top_k: int):
+    """The buffer's rows [M, D] and the pairs' weights [T, k] (float32)
+    -> y [T, D], a token the weighted sum of its rows."""
+    y = _tokens_from_rows(rows, plan, tile, top_k, weights.shape[0], weights)
+    return y.astype(rows.dtype)
+
+
+def _combine_fwd(rows, weights, plan, tile, top_k):
+    return (_combine(rows, weights, plan, tile, top_k),
+            (rows, weights, plan))
+
+
+def _combine_bwd(tile, top_k, res, dy):
+    """d rows = weight x the token's dy; d weight of a pair = its row .
+    its token's dy. One loop over the tiles in use gives both."""
+    rows, weights, plan = res
+    m, d = rows.shape
+    flat = weights.reshape(-1)
+
+    def one_tile(i, carry):
+        d_rows, d_w = carry
+        start, pair, token, valid = _tile_of(plan, i, tile, top_k)
+        dy_block = jnp.where(valid[:, None], dy[token], 0).astype(jnp.float32)
+        block = lax.dynamic_slice(rows, (start, 0), (tile, d))
+        by_row = jnp.sum(
+            dy_block * jnp.where(valid[:, None], block, 0).astype(jnp.float32),
+            axis=-1)
+        d_rows = lax.dynamic_update_slice(
+            d_rows, (dy_block * flat[pair][:, None]).astype(rows.dtype),
+            (start, 0))
+        d_w = d_w.at[jnp.where(valid, pair, flat.shape[0])].add(
+            by_row, mode="drop")
+        return d_rows, d_w
+
+    d_rows, d_w = lax.fori_loop(
+        0, plan.num_tiles[0], one_tile,
+        (jnp.zeros((m, d), rows.dtype), jnp.zeros(flat.shape, jnp.float32)))
+    return d_rows, d_w.reshape(weights.shape).astype(weights.dtype), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _over_tiles(plan: RoutingPlan, tile: int, fn, *operands):
+    """`fn` on the row tiles in use of [M, .] operands, into [M, .]
+    results that are zero elsewhere: elementwise work over the buffer
+    follows the rows routed here too."""
+    m = operands[0].shape[0]
+    shapes = jax.eval_shape(fn, *[
+        jax.ShapeDtypeStruct((tile, o.shape[1]), o.dtype) for o in operands])
+
+    def one_tile(i, outs):
+        start = i * tile
+        blocks = [lax.dynamic_slice(o, (start, 0), (tile, o.shape[1]))
+                  for o in operands]
+        return tuple(lax.dynamic_update_slice(out, r, (start, 0))
+                     for out, r in zip(outs, fn(*blocks)))
+
+    return lax.fori_loop(
+        0, plan.num_tiles[0], one_tile,
+        tuple(jnp.zeros((m, sh.shape[1]), sh.dtype) for sh in shapes))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _swiglu(gate, up, plan: RoutingPlan, tile: int):
+    """silu(gate) * up in float32, over the tiles in use."""
+    return _over_tiles(plan, tile, lambda g, u: ((
+        jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    ).astype(g.dtype),), gate, up)[0]
+
+
+def _swiglu_fwd(gate, up, plan, tile):
+    return _swiglu(gate, up, plan, tile), (gate, up, plan)
+
+
+def _swiglu_bwd(tile, res, d_hidden):
+    gate, up, plan = res
+
+    def grads(g, u, d):
+        g, u, d = (a.astype(jnp.float32) for a in (g, u, d))
+        sig = jax.nn.sigmoid(g)
+        d_gate = d * u * sig * (1.0 + g * (1.0 - sig))
+        return d_gate.astype(gate.dtype), (d * g * sig).astype(up.dtype)
+
+    d_gate, d_up = _over_tiles(plan, tile, grads, gate, up, d_hidden)
+    return d_gate, d_up, None
+
+
+_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gate_and_up(rows, w1, w3, plan: RoutingPlan, tile: int):
+    """rows x W1 and rows x W3. One function, so that the two gradients of
+    `rows` are added over the tiles in use and not, as autodiff would add
+    two cotangents, over the whole buffer."""
+    return (grouped_matmul(rows, w1, plan, tile),
+            grouped_matmul(rows, w3, plan, tile))
+
+
+def _gate_and_up_fwd(rows, w1, w3, plan, tile):
+    return _gate_and_up(rows, w1, w3, plan, tile), (rows, w1, w3, plan)
+
+
+def _gate_and_up_bwd(tile, res, cotangents):
+    rows, w1, w3, plan = res
+    d_rows, pulls = [], []
+    for w, d in zip((w1, w3), cotangents):
+        _, pull = jax.vjp(lambda r, w_: grouped_matmul(r, w_, plan, tile),
+                          rows, w)
+        d_r, d_w = pull(d)
+        d_rows.append(d_r)
+        pulls.append(d_w)
+    (total,) = _over_tiles(plan, tile, lambda a, b: (a + b,), *d_rows)
+    return total, pulls[0], pulls[1], None
+
+
+_gate_and_up.defvjp(_gate_and_up_fwd, _gate_and_up_bwd)
+
+
+def route(x, router_w, expert_bias, *, top_k: int,
+          norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
+          forced_experts: jax.Array | None = None):
+    """Sigmoid scores in float32, the top-k of score + bias, weights from
+    the scores alone. x [T, D] -> (experts [T, k] int32, weights [T, k]
+    float32). The bias selects and is not trained. `forced_experts`
+    replaces the selection; the weights still come from these scores."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)                              # [T, NE]
+    if forced_experts is not None:
+        experts = forced_experts
+    else:
+        chosen = scores
+        if expert_bias is not None:
+            chosen = scores + lax.stop_gradient(
+                expert_bias.astype(jnp.float32))
+        _, experts = lax.top_k(chosen, top_k)
+    picked = jax.nn.one_hot(experts, scores.shape[-1], dtype=jnp.float32)
+    weights = jnp.einsum("tke,te->tk", picked, scores)
+    if norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    return experts.astype(jnp.int32), weights * routed_scaling_factor
+
+
+def routed_experts(
+    x: jax.Array,
+    router_w: jax.Array,
+    expert_bias: jax.Array | None,
+    w1: jax.Array,
+    w3: jax.Array,
+    w2: jax.Array,
+    *,
+    num_experts: int,
+    top_k: int,
+    expert_offset: int = 0,
+    norm_topk_prob: bool = True,
+    routed_scaling_factor: float = 1.0,
+    forced_experts: jax.Array | None = None,
+    return_routing: bool = False,
+):
+    """Dropless top-k sigmoid-routed SwiGLU experts, the part that the
+    experts held here give.
+
+    x [T, D]; router_w [D, num_experts]; expert_bias [num_experts] or
+    None; w1, w3 [held, D, F], w2 [held, F, D]: experts `expert_offset` ..
+    `expert_offset + held - 1` of `num_experts`. Every token is routed
+    over ALL experts; y [T, D] = sum over the token's picks that are held
+    of weight x W2 (silu(W1 x) * W3 x). With all experts held that is the
+    whole layer. `forced_experts` [T, k] replaces the selection (the
+    weights still come from this call's own scores). With
+    `return_routing`, also the chosen experts [T, k]."""
+    t, d = x.shape
+    held = w1.shape[0]
+    assert router_w.shape == (d, num_experts), router_w.shape
+    assert 0 <= expert_offset and expert_offset + held <= num_experts
+    experts, weights = route(
+        x, router_w, expert_bias, top_k=top_k, norm_topk_prob=norm_topk_prob,
+        routed_scaling_factor=routed_scaling_factor,
+        forced_experts=forced_experts)
+
+    local = experts.reshape(-1) - expert_offset
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    rows, tile = buffer_rows(t, top_k, held, num_experts)
+    plan = plan_routing(local.astype(jnp.int32), held, rows, tile)
+
+    xs = _dispatch(x, plan, tile, top_k)
+    gate, up = _gate_and_up(xs, w1, w3, plan, tile)
+    out = grouped_matmul(_swiglu(gate, up, plan, tile), w2, plan, tile)
+    y = _combine(out, weights, plan, tile, top_k)
+    if return_routing:
+        return y, experts
+    return y

@@ -72,26 +72,59 @@ class StepMetrics(NamedTuple):
     grad_norm: jax.Array
 
 
+def freeze_leaves(inner: optax.GradientTransformation,
+                  names: tuple[str, ...]) -> optax.GradientTransformation:
+    """`inner`, with every leaf whose dict key is in `names` left out: its
+    update is zero (no step, no weight decay, nothing in the clipped norm)
+    and the state `inner` keeps for it is EMPTY (zero-size arrays where the
+    leaf's mirrors would be). The state's tree still mirrors the
+    parameters', so `optax.tree_map_params` and the engine's placement and
+    checkpoint code need no case for it, which `optax.masked` does."""
+    names = frozenset(names)
+
+    def hide(tree):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: (jnp.zeros((0,), x.dtype)
+                             if getattr(path[-1], "key", None) in names
+                             else x), tree)
+
+    def init(params):
+        return inner.init(hide(params))
+
+    def update(grads, state, params=None):
+        updates, state = inner.update(
+            hide(grads), state, None if params is None else hide(params))
+        updates = jax.tree.map(
+            lambda u, g: jnp.zeros_like(g) if u.shape != g.shape else u,
+            updates, grads)
+        return updates, state
+
+    return optax.GradientTransformation(init, update)
+
+
 def make_optimizer(
     *,
     learning_rate: float = 1e-4,
     warmup_steps: int = 10,
     weight_decay: float = 0.01,
     max_grad_norm: float = 1.0,
+    frozen: tuple[str, ...] = (),
 ) -> optax.GradientTransformation:
     """AdamW + linear-warmup LR + global-norm clipping.
 
     Matches the reference's optimizer stack (fused AdamW + WarmupLR,
     /root/reference/oobleck/execution/pipeline.py:117-127) with clipping
-    added (reference leaves grads unclipped).
+    added (reference leaves grads unclipped). `frozen` names parameter
+    leaves that are never trained (a model's `frozen_param_names`).
     """
     def schedule(step):
         return learning_rate * jnp.minimum(1.0, (step + 1) / max(warmup_steps, 1))
 
-    return optax.chain(
+    optimizer = optax.chain(
         optax.clip_by_global_norm(max_grad_norm),
         optax.adamw(schedule, b1=0.9, b2=0.999, weight_decay=weight_decay),
     )
+    return freeze_leaves(optimizer, tuple(frozen)) if frozen else optimizer
 
 
 def state_partition_specs(model, optimizer) -> TrainState:

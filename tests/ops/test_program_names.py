@@ -15,7 +15,8 @@ PKG = Path(__file__).resolve().parents[2] / "oobleck_tpu"
 HOT_PATH = ["execution/engine.py", "execution/pipeline.py",
             "execution/precompile.py", "execution/fused.py",
             "parallel/train.py", "serve/engine.py", "serve/batcher.py",
-            "models/gpt.py", "models/llama.py"]
+            "models/gpt.py", "models/llama.py", "models/lfm2.py",
+            "ops/moe.py"]
 
 
 def _is_jit(call: ast.Call) -> bool:
@@ -63,3 +64,10 @@ def test_serving_programs_carry_their_names():
         "dense decode": "decode_step", "dense prefill": "prefill",
         "paged decode": "decode_step", "paged prefill": "prefill",
         "paged prefill tail": "prefill_tail", "verify": "verify_step"}
+
+
+def test_routing_probe_program_carries_its_name():
+    from oobleck_tpu.models import build_model, lfm2
+
+    model = build_model("lfm2-moe-tiny", {})
+    assert lfm2._probe_program(model).__name__ == "routing_probe_forward"

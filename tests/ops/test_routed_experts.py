@@ -1,0 +1,253 @@
+"""`ops/moe.routed_experts`: dropless top-k sigmoid routing over a held
+range of the experts, and the grouped-matmul kernels under it.
+
+The op against a loop over tokens (uneven loads, an expert that gets no
+token, every pick of a token held here), the shares' partial results
+against the uncut layer, gradients against a dense formulation that
+autodiff differentiates, and the kernels in interpreter mode against one
+`jnp.einsum` per group.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from oobleck_tpu.ops import moe
+
+T, D, F, NE, K = 48, 32, 64, 8, 2
+
+
+@pytest.fixture(scope="module")
+def layer():
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    bias = jax.random.normal(ks[2], (NE,)) * 0.3
+    return {
+        "x": jax.random.normal(ks[0], (T, D)),
+        "router": jax.random.normal(ks[1], (D, NE)) * 0.5,
+        # Expert 3 never wins a place; expert 5 nearly always does: the
+        # loads are uneven and one group is empty.
+        "bias": bias.at[3].set(-100.0).at[5].set(2.0),
+        "w1": jax.random.normal(ks[3], (NE, D, F)) * 0.2,
+        "w3": jax.random.normal(ks[4], (NE, D, F)) * 0.2,
+        "w2": jax.random.normal(ks[5], (NE, F, D)) * 0.2,
+    }
+
+
+def _share(layer, offset, held, **kw):
+    sl = slice(offset, offset + held)
+    return moe.routed_experts(
+        layer["x"], layer["router"], layer["bias"], layer["w1"][sl],
+        layer["w3"][sl], layer["w2"][sl], num_experts=NE, top_k=K,
+        expert_offset=offset, **kw)
+
+
+def _loop_over_tokens(layer, offset, held):
+    """The layer as its definition reads, one token and one pick at a
+    time, in float64 on the host."""
+    f64 = lambda a: np.asarray(a, np.float64)
+    x, rw, b = f64(layer["x"]), f64(layer["router"]), f64(layer["bias"])
+    scores = 1.0 / (1.0 + np.exp(-(x @ rw)))
+    y = np.zeros_like(x)
+    picks = []
+    for t in range(x.shape[0]):
+        chosen = np.argsort(-(scores[t] + b), kind="stable")[:K]
+        picks.append(chosen)
+        g = scores[t, chosen] / (scores[t, chosen].sum() + 1e-6)
+        for weight, e in zip(g, chosen):
+            if offset <= e < offset + held:
+                gate = x[t] @ f64(layer["w1"][e])
+                up = x[t] @ f64(layer["w3"][e])
+                y[t] += weight * ((gate / (1.0 + np.exp(-gate)) * up)
+                                  @ f64(layer["w2"][e]))
+    return y, np.stack(picks)
+
+
+@pytest.mark.parametrize("offset,held", [(0, NE), (2, 4), (4, 2), (3, 1)],
+                         ids=["all", "middle4", "two", "empty_expert_only"])
+def test_routed_experts_matches_a_loop_over_tokens(layer, offset, held):
+    want, picks = _loop_over_tokens(layer, offset, held)
+    got, chosen = jax.jit(
+        lambda: _share(layer, offset, held, return_routing=True))()
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    assert (np.sort(np.asarray(chosen), -1) == np.sort(picks, -1)).all()
+    if (offset, held) == (3, 1):
+        assert not np.asarray(got).any()      # its one expert got no token
+
+
+def test_no_token_is_dropped_and_loads_are_uneven(layer):
+    _, picks = _loop_over_tokens(layer, 0, NE)
+    loads = np.bincount(picks.reshape(-1), minlength=NE)
+    assert loads.sum() == T * K and loads[3] == 0 and loads.max() > 2 * T * K / NE
+    rows, tile = moe.buffer_rows(T, K, NE, NE)
+    plan = moe.plan_routing(jnp.asarray(picks.reshape(-1), jnp.int32), NE,
+                            rows, tile)
+    np.testing.assert_array_equal(np.asarray(plan.group_sizes), loads)
+    # Every pair has a row of its own, inside its expert's tiles: walk the
+    # tiles in use as the loops do.
+    order = np.asarray(plan.order)
+    first, valid_rows = np.asarray(plan.tile_first), np.asarray(plan.tile_rows)
+    group = np.asarray(plan.tile_group)
+    used = int(plan.num_tiles[0])
+    seen = []
+    for i in range(used):
+        pairs_of_tile = order[first[i]:first[i] + valid_rows[i]]
+        assert (picks.reshape(-1)[pairs_of_tile] == group[i]).all()
+        assert (np.diff(pairs_of_tile) > 0).all()      # the pairs' own order
+        seen.extend(pairs_of_tile.tolist())
+    assert sorted(seen) == list(range(T * K))
+    assert not valid_rows[used:].any()
+    assert used * tile == int(np.asarray(plan.padded_sizes).sum())
+    # An expert with no token still has its one (empty) tile.
+    assert (group[:used] == 3).sum() == 1 and valid_rows[group == 3][0] == 0
+
+
+def test_all_picks_of_a_token_can_be_held_here(layer):
+    """Experts 4..5: expert 5 is nearly everyone's pick, so some tokens
+    have BOTH picks in the held range; the buffer's worst case holds."""
+    _, picks = _loop_over_tokens(layer, 4, 2)
+    both = ((picks >= 4) & (picks < 6)).all(axis=1)
+    assert both.any()
+    want, _ = _loop_over_tokens(layer, 4, 2)
+    np.testing.assert_allclose(np.asarray(_share(layer, 4, 2)), want,
+                               atol=2e-5)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(layer):
+    whole = np.asarray(_share(layer, 0, NE))
+    for parts in (8, 4, 2):
+        held = NE // parts
+        total = sum(np.asarray(_share(layer, i * held, held))
+                    for i in range(parts))
+        np.testing.assert_allclose(total, whole, atol=2e-5)
+
+
+def _dense(x, router, bias, w1, w3, w2, offset):
+    """The same function, dense over the held experts, for autodiff."""
+    scores = jax.nn.sigmoid(x @ router)
+    _, chosen = jax.lax.top_k(scores + bias, K)
+    picked = jax.nn.one_hot(chosen, NE).sum(-2)
+    g = picked * scores
+    g = g / (g.sum(-1, keepdims=True) + 1e-6)
+    g = g[:, offset:offset + w1.shape[0]]
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", x, w1)) \
+        * jnp.einsum("td,edf->tef", x, w3)
+    return jnp.einsum("tef,efd,te->td", h, w2, g)
+
+
+@pytest.mark.parametrize("offset,held", [(0, NE), (2, 4)], ids=["all", "share"])
+def test_gradients_match_the_dense_formulation(layer, offset, held):
+    sl = slice(offset, offset + held)
+    args = (layer["x"], layer["router"], layer["w1"][sl], layer["w3"][sl],
+            layer["w2"][sl])
+    target = jax.random.normal(jax.random.PRNGKey(1), (T, D))
+
+    def routed(x, router, w1, w3, w2):
+        y = moe.routed_experts(x, router, layer["bias"], w1, w3, w2,
+                               num_experts=NE, top_k=K, expert_offset=offset)
+        return jnp.sum(y * target)
+
+    def dense(x, router, w1, w3, w2):
+        return jnp.sum(_dense(x, router, layer["bias"], w1, w3, w2, offset)
+                       * target)
+
+    got = jax.jit(jax.grad(routed, argnums=range(5)))(*args)
+    want = jax.grad(dense, argnums=range(5))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-5)
+    # The empty expert's matrices get an exact zero, not garbage.
+    if offset == 0:
+        assert not np.asarray(got[2][3]).any()
+
+
+def test_the_bias_selects_and_takes_no_gradient(layer):
+    grad = jax.grad(lambda b: jnp.sum(moe.routed_experts(
+        layer["x"], layer["router"], b, layer["w1"], layer["w3"],
+        layer["w2"], num_experts=NE, top_k=K)))(layer["bias"])
+    assert not np.asarray(grad).any()
+    unbiased = moe.routed_experts(
+        layer["x"], layer["router"], None, layer["w1"], layer["w3"],
+        layer["w2"], num_experts=NE, top_k=K)
+    assert not np.allclose(np.asarray(unbiased), np.asarray(_share(layer, 0, NE)))
+
+
+def test_forced_experts_replace_the_selection_only(layer):
+    forced = jnp.tile(jnp.asarray([[1, 6]], jnp.int32), (T, 1))
+    got, chosen = _share(layer, 0, NE, forced_experts=forced,
+                         return_routing=True)
+    np.testing.assert_array_equal(np.asarray(chosen), np.asarray(forced))
+    scores = jax.nn.sigmoid(layer["x"] @ layer["router"])
+    g = scores[:, jnp.asarray([1, 6])]
+    g = g / (g.sum(-1, keepdims=True) + 1e-6)
+    want = 0
+    for slot, e in enumerate((1, 6)):
+        h = jax.nn.silu(layer["x"] @ layer["w1"][e]) * (layer["x"] @ layer["w3"][e])
+        want = want + g[:, slot:slot + 1] * (h @ layer["w2"][e])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# --------------------------------------------------------------------- #
+# the kernels, interpreted                                               #
+# --------------------------------------------------------------------- #
+
+SIZES = [(37, 0, 70, 5, 16), (0, 0, 0, 128), (16, 16, 16, 16, 16, 16)]
+
+
+def _layout(sizes, tile=16, seed=0):
+    """A plan for groups of the given sizes (pairs in shuffled order)."""
+    held = len(sizes)
+    local = np.repeat(np.arange(held), sizes).astype(np.int32)
+    extra = np.full(11, held, np.int32)                  # routed elsewhere
+    local = np.random.default_rng(seed).permutation(np.concatenate([local, extra]))
+    rows = -(-len(local) // tile) * tile + held * tile
+    return moe.plan_routing(jnp.asarray(local), held, rows, tile), rows, tile
+
+
+@pytest.mark.parametrize("sizes", SIZES, ids=["uneven", "one_full", "even"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_gmm_kernel_equals_einsum_per_group(sizes, dtype):
+    plan, rows, tile = _layout(sizes)
+    held, k, n = len(sizes), 32, 48
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    lhs = jax.random.normal(ks[0], (rows, k)).astype(dtype)
+    rhs = jax.random.normal(ks[1], (held, k, n))          # float32 as stored
+    d_out = jax.random.normal(ks[2], (rows, n)).astype(dtype)
+    out = moe.gmm_call(lhs, rhs, plan.tile_group, plan.num_tiles, tile=tile)
+    back = moe.gmm_call(d_out, rhs, plan.tile_group, plan.num_tiles,
+                        tile=tile, transpose_rhs=True)
+    dw = moe.tgmm_call(lhs, d_out, plan.tile_group, plan.num_tiles, tile=tile,
+                       num_groups=held, out_dtype=jnp.float32)
+    assert out.dtype == dtype and dw.dtype == jnp.float32
+    padded = np.asarray(plan.padded_sizes)
+    starts = np.cumsum(padded) - padded
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    w = rhs.astype(dtype).astype(jnp.float32)
+    for g, (s, p) in enumerate(zip(starts, padded)):
+        rows_g = slice(int(s), int(s + p))
+        a = lhs[rows_g].astype(jnp.float32)
+        d = d_out[rows_g].astype(jnp.float32)
+        np.testing.assert_allclose(
+            np.asarray(out[rows_g], np.float32),
+            np.asarray(jnp.einsum("mk,kn->mn", a, w[g])), atol=tol * 8)
+        np.testing.assert_allclose(
+            np.asarray(back[rows_g], np.float32),
+            np.asarray(jnp.einsum("mn,kn->mk", d, w[g])), atol=tol * 8)
+        np.testing.assert_allclose(
+            np.asarray(dw[g]), np.asarray(jnp.einsum("mk,mn->kn", a, d)),
+            atol=tol * 8)
+
+
+def test_tiles_follow_the_shapes():
+    # The cell: 512 rows expected an expert, 768 with room, in two tiles.
+    assert moe.choose_row_tile(8192 * 4, 64) == 384
+    assert moe.choose_row_tile(1024 * 4, 64) == 96       # under a lane: sublanes
+    assert moe.choose_row_tile(8192 * 8, 8) == 512       # 12288 in 24 tiles
+    assert moe.choose_row_tile(96, 8) == 32              # the tests' sizes
+    assert moe._col_tile(1536, moe.MAX_COL_TILE) == 512
+    assert moe._col_tile(2048, moe.MAX_COL_TILE) == 512
+    assert moe._col_tile(2048, moe.MAX_TGMM_ROWS) == 1024
+    assert moe._col_tile(1536, moe.MAX_TGMM_ROWS) == 768
+    assert moe._col_tile(48, moe.MAX_COL_TILE) == 48
+    rows, tile = moe.buffer_rows(8192, 4, 8, 64)
+    assert (rows, tile) == (86 * 384 + 8 * 384, 384)

@@ -191,6 +191,68 @@ def test_paged_alibi_compiles(v5e):
     _compile(verify, v5e[0], ((lanes, t, hq, d), jnp.bfloat16), *rest)
 
 
+# Routed experts (ops/moe.py): (tokens, hidden, expert width, experts,
+# experts held, picks a token) of one microbatch's call. The benchmark
+# cell's (8 x 1024 tokens, 8 of 64 experts of 2048 x 1536, top 4: a
+# 36,096-row buffer in 384-row tiles, [2048, 512] float32 blocks of an
+# expert's matrix in VMEM), all 64 experts held, and a small call whose
+# tiles are one step above the smallest.
+MOE_WIDTHS = {
+    "lfm2-24b-a2b-cell": (8192, 2048, 1536, 64, 8, 4),
+    "all-held": (1024, 2048, 1536, 64, 64, 4),
+    "lfm2-tiles": (512, 256, 384, 16, 4, 2),
+}
+
+
+def _routed_sum(top_k):
+    from oobleck_tpu.ops.moe import routed_experts
+
+    def fn(x, router, bias, w1, w3, w2):
+        return jnp.sum(routed_experts(
+            x, router, bias, w1, w3, w2, num_experts=router.shape[1],
+            top_k=top_k).astype(jnp.float32))
+
+    return fn
+
+
+def _routed_grads(top_k):
+    return jax.grad(_routed_sum(top_k), argnums=(0, 1, 3, 4, 5))
+
+
+def _routed_shapes(t, d, f, ne, held, top_k):
+    return [((t, d), jnp.bfloat16), ((d, ne), jnp.float32),
+            ((ne,), jnp.float32), ((held, d, f), jnp.float32),
+            ((held, d, f), jnp.float32), ((held, f, d), jnp.float32)]
+
+
+@pytest.mark.parametrize("width", sorted(MOE_WIDTHS))
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_routed_experts_compile(v5e, width, mode):
+    top_k = MOE_WIDTHS[width][-1]
+    fn = _routed_sum(top_k) if mode == "fwd" else _routed_grads(top_k)
+    text = _compile(fn, v5e[0], *_routed_shapes(*MOE_WIDTHS[width]))
+    # Three products forward; three dX and three dW more backward.
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        3 if mode == "fwd" else 9)
+
+
+# A routed block's host cost at process start, as flash's below: the
+# gradient of one routed layer at the cell's shapes lowers to 112 k
+# characters (nine kernels with small bodies, the plan's sort, a dozen loops
+# over the row tiles in use); the limit leaves room for a quarter more.
+ROUTED_GRAD_MODULE_CHARS = 140_000
+
+
+def test_routed_grad_module_stays_small(v5e):
+    one = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in
+            _routed_shapes(*MOE_WIDTHS["lfm2-24b-a2b-cell"])]
+    text = jax.jit(_routed_grads(
+        MOE_WIDTHS["lfm2-24b-a2b-cell"][-1])).lower(*args).as_text()
+    assert text.count("tpu_custom_call") == 9
+    assert len(text) < ROUTED_GRAD_MODULE_CHARS, len(text)
+
+
 # The kernels' host cost at process start, held down without a clock. A
 # process pays trace + lower of every flash call site on its first step,
 # cache hit or not (the cache's key is computed from the lowered module),
@@ -223,6 +285,7 @@ def test_flash_grad_module_stays_small(v5e):
 KERNEL_NAMES = {
     "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
     "paged_decode": "decode", "paged_verify": "verify",
+    "moe_gmm": "moe", "moe_tgmm": "moe",
 }
 
 
@@ -231,7 +294,13 @@ def test_kernel_is_named_in_location_and_executable(v5e, name):
     import re
 
     one = SingleDeviceSharding(v5e[0])
-    if KERNEL_NAMES[name] == "flash":
+    if KERNEL_NAMES[name] == "moe":
+        # Under remat and grad, as a routed block's stage program runs it.
+        width = MOE_WIDTHS["lfm2-tiles"]
+        fn = jax.grad(jax.checkpoint(_routed_sum(width[-1])),
+                      argnums=(0, 1, 3, 4, 5))
+        shapes = _routed_shapes(*width)
+    elif KERNEL_NAMES[name] == "flash":
         # Under remat, as the training step runs it: the recompute's
         # forward call keeps the kernel's name too.
         fn = _grads(jax.checkpoint(flash_attention))
