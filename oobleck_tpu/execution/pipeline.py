@@ -16,7 +16,13 @@ single-controller JAX runtime:
   * the 1F1B instruction streams (execution.schedule) are interpreted by a
     dependency-driven loop; backward recomputes the stage forward inside the
     jitted VJP (activation-checkpoint discipline), so only per-microbatch
-    stage *inputs* are stashed, as in 1F1B;
+    stage *inputs* are stashed, as in 1F1B. The LAST virtual stage has
+    nothing to send forward: its forward gives the step one scalar, which
+    its backward computes anyway. So training runs ONE program per
+    microbatch there, `jit_bwd` = value-and-gradient (loss, grads, dx); its
+    FORWARD instruction only stashes the input. A one-stage pipeline
+    therefore shows no `jit_fwd` while it trains; `eval_step` still runs
+    the forward-only program;
   * compiled stage executables are cached by stage signature so
     re-instantiation after a failure reuses them — the pre-compile-per-
     template idea from SURVEY §7.3.1.
@@ -46,6 +52,7 @@ from oobleck_tpu.execution.schedule import (
 )
 from oobleck_tpu.obs import spans
 from oobleck_tpu.planning.templates import PipelineTemplate
+from oobleck_tpu.utils import metrics
 
 logger = logging.getLogger("oobleck.pipeline")
 
@@ -677,7 +684,14 @@ class PipelineInstance:
         """jit each chunk's forward and (recomputing) backward, with caching
         keyed by the chunk signature so reconfiguration reuses executables.
         Under canonical 1F1B each stage has exactly one chunk and the cache
-        key is the stage signature as before."""
+        key is the stage signature as before.
+
+        Every other chunk's `bwd(params, x, batch, dy)` returns (grads, dx).
+        The last virtual stage's `bwd(params, x, batch)` is the loss's
+        value-and-gradient and returns (loss, grads, dx): the unscaled
+        microbatch loss its `fwd` would give, gradients of loss / total
+        microbatches. train_step never calls that chunk's `fwd` (eval_step
+        does)."""
         S, v = self.num_stages, self.virtual_stages
         last_vs = S * v - 1
         scale = 1.0 / self.total_num_microbatches
@@ -705,18 +719,23 @@ class PipelineInstance:
                     return _apply(params_tuple, x, tokens)
 
                 if is_last:
-                    # Backward from the loss: d(loss·scale)/d(params, x).
+                    # The loss and d(loss·scale)/d(params, x) from one
+                    # forward: the loss rides out as the aux value,
+                    # unscaled, exactly what `fwd` returns.
                     def bwd(params_tuple, x, tokens, _apply=apply):
                         def loss_fn(pt, x_):
-                            return _apply(pt, x_, tokens) * scale
+                            loss = _apply(pt, x_, tokens)
+                            return loss * scale, loss
 
                         if x is None:
-                            grads = jax.grad(
-                                lambda pt: loss_fn(pt, None))(params_tuple)
-                            return grads, None
-                        grads, dx = jax.grad(
-                            loss_fn, argnums=(0, 1))(params_tuple, x)
-                        return grads, dx
+                            (_, loss), grads = jax.value_and_grad(
+                                lambda pt: loss_fn(pt, None),
+                                has_aux=True)(params_tuple)
+                            return loss, grads, None
+                        (_, loss), (grads, dx) = jax.value_and_grad(
+                            loss_fn, argnums=(0, 1),
+                            has_aux=True)(params_tuple, x)
+                        return loss, grads, dx
                 else:
                     def bwd(params_tuple, x, tokens, dy, _apply=apply):
                         if x is None:
@@ -866,6 +885,10 @@ class PipelineInstance:
         stage_busy: dict[int, float] = {}
         op_times: dict[tuple[int, int, str], tuple[float, int]] = {}
         dispatch_stall = 0.0
+        # FORWARD instructions of local stages: "run" dispatched the
+        # chunk's forward program, "folded" left it to the backward's
+        # value-and-gradient (the last virtual stage).
+        fwd_dispatches = {"run": 0, "folded": 0}
 
         def record_op(stage, chunk, kind, dt):
             tot, n = op_times.get((stage, chunk, kind), (0.0, 0))
@@ -940,6 +963,14 @@ class PipelineInstance:
                     return
                 flush_sends()
                 x = None if is_first else acts[key]
+                stash[key] = x
+                fwd_dispatches["folded" if is_last else "run"] += 1
+                if is_last:
+                    # Nothing but the loss would leave this forward, and
+                    # BACKWARD's program returns it: dispatch nothing. The
+                    # "f" entry stays (0 s) for last_op_times' readers.
+                    record_op(ins.stage, c, "f", 0.0)
+                    return
                 mb = stage_batch[m] if stage_batch is not None else None
                 if self.sync_op_timing and x is not None:
                     # oobleck: allow[OBL002] -- opt-in per-op profiling mode
@@ -950,11 +981,7 @@ class PipelineInstance:
                     # oobleck: allow[OBL002] -- opt-in per-op profiling mode
                     jax.block_until_ready(out)
                 record_op(ins.stage, c, "f", time.perf_counter() - t0)
-                stash[key] = x
-                if is_last:
-                    losses.append(out)
-                else:
-                    stash[(ins.stage, c, m, "out")] = out
+                stash[(ins.stage, c, m, "out")] = out
             elif ins.op == Op.SEND_ACTIVATION:
                 ds, dc = send_activation_dest(ins.stage, c, S)
                 nxt = self.stages[ds]
@@ -1000,7 +1027,9 @@ class PipelineInstance:
                         jax.block_until_ready(dy_wait)
                 t0 = time.perf_counter()
                 if is_last:
-                    stage_grads, dx = st.bwd[c](chunk_params(st, c), x, mb)
+                    loss, stage_grads, dx = st.bwd[c](
+                        chunk_params(st, c), x, mb)
+                    losses.append(loss)
                 else:
                     dy = gacts.pop(key)
                     stage_grads, dx = st.bwd[c](chunk_params(st, c), x, mb, dy)
@@ -1057,6 +1086,13 @@ class PipelineInstance:
         self.last_stage_busy_s = stage_busy
         self.last_op_times = op_times
         self.last_dispatch_stall_s = dispatch_stall
+        dispatches = metrics.registry().counter(
+            "oobleck_pipeline_forward_dispatches_total",
+            "FORWARD instructions of local pipeline stages: run as a "
+            "program, or folded into the last stage's backward")
+        for mode, n in fwd_dispatches.items():
+            if n:
+                dispatches.inc(n, mode=mode)
         if not losses:
             return None  # last stage lives on another process
         loss = sum(losses[1:], start=losses[0]) / len(losses)

@@ -380,8 +380,12 @@ class RecoveryPrecompiler:
             sample = pipe.model.sample_batch(pipe.microbatch_size, pipe.seq_len)
             mb_aval = {k: _sds(v, st.batch_sharding) for k, v in sample.items()}
 
-        st.fwd[c].lower(params_avals, x_aval, mb_aval).compile()
-        self.stats["stages_compiled"] += 1
+        # Training never runs the last virtual stage's forward-only program
+        # (its `bwd` returns the loss); eval_step does, where the stage has
+        # no eval program with metrics.
+        if not is_last or st.efwd[c] is None:
+            st.fwd[c].lower(params_avals, x_aval, mb_aval).compile()
+            self.stats["stages_compiled"] += 1
         if is_last:
             st.bwd[c].lower(params_avals, x_aval, mb_aval).compile()
         else:

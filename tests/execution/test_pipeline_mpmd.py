@@ -346,3 +346,143 @@ def test_canonical_order_interleaved_dependency_valid(S, M, v):
             ds, dc = send_grad_dest(ins.stage, ins.chunk, S)
             gacts.add((dc * S + ds, ins.microbatch))
     assert len(fwd_done) == S * v * M and len(bwd_done) == S * v * M
+
+
+# --------------------------------------------------------------------- #
+# the last virtual stage's forward is folded into its backward
+
+
+FOLD_CASES = {
+    # name: (layer splits, chips per stage, virtual stages)
+    "S1-generic": ([(0, 6)], [1], 1),
+    "S2-generic": ([(0, 3), (3, 6)], [1, 1], 1),
+    "S2v2-generic": ([(0, 3), (3, 6)], [1, 1], 2),
+    # Two chips a stage: fsdp shards the microbatch, so the stage programs
+    # are the manual-collective shard_map flavour (st.ctx is not None).
+    "S1-manual": ([(0, 6)], [2], 1),
+    "S2-manual": ([(0, 3), (3, 6)], [2, 2], 1),
+    "S2v2-manual": ([(0, 3), (3, 6)], [2, 2], 2),
+}
+
+
+def _dispatches():
+    from oobleck_tpu.utils import metrics
+
+    c = metrics.registry().counter("oobleck_pipeline_forward_dispatches_total")
+    return {mode: c.value(mode=mode) for mode in ("run", "folded")}
+
+
+@pytest.fixture(scope="module", params=list(FOLD_CASES))
+def folded_step(request, model, batch, devices8):
+    """One train_step of the case's pipeline with a counting wrapper on the
+    last virtual stage's forward program, then one eval_step."""
+    splits, chips, v = FOLD_CASES[request.param]
+    template = make_template(splits, chips, chips_per_host=chips[0])
+    pipe = _make_pipe(model, devices8, template, v)
+    manual = request.param.endswith("manual")
+    assert all((st.ctx is not None) == manual for st in pipe.stages)
+    last_st, last_c = pipe.stages[-1], v - 1
+    assert last_st.chunks[last_c][-1] == model.num_pipeline_layers - 1
+    calls = {"n": 0}
+    real_fwd = last_st.fwd[last_c]
+
+    def counting_fwd(*args):
+        calls["n"] += 1
+        return real_fwd(*args)
+
+    last_st.fwd[last_c] = counting_fwd
+    before = _dispatches()
+    loss = float(pipe.train_step(batch))
+    after = _dispatches()
+    fwd_calls_in_train = calls["n"]
+    eval_loss = float(pipe.eval_step(batch))
+    return {
+        "pipe": pipe, "S": len(splits), "v": v, "loss": loss,
+        "grads": jax.tree.map(np.asarray, pipe.grads),
+        "op_times": dict(pipe.last_op_times),
+        "dispatched": {k: after[k] - before[k] for k in after},
+        "fwd_calls_in_train": fwd_calls_in_train,
+        "fwd_calls_in_eval": calls["n"] - fwd_calls_in_train,
+        "eval_loss": eval_loss,
+    }
+
+
+@pytest.fixture(scope="module")
+def fused(model, batch):
+    loss, grads = reference_loss_and_grads(model, batch)
+    return float(loss), jax.tree.map(np.asarray, grads)
+
+
+def test_folded_loss_matches_value_and_grad(folded_step, fused):
+    assert folded_step["loss"] == pytest.approx(fused[0], rel=2e-2)
+
+
+def test_folded_every_grad_leaf_matches_value_and_grad(folded_step, fused,
+                                                       model):
+    """Every layer's every leaf against jax.value_and_grad of the whole
+    model on the same microbatches (relative L2, the bound the file's
+    interleaved parity test uses)."""
+    _, want = fused
+    n = model.num_pipeline_layers
+    got = folded_step["grads"]
+    assert sorted(got) == list(range(n))
+    for li in range(n):
+        if li == 0:
+            ref = want["embed"]
+        elif li == n - 1:
+            ref = want["head"]
+        else:
+            ref = jax.tree.map(lambda x, _i=li - 1: x[_i], want["blocks"])
+        g_leaves, g_def = jax.tree.flatten(got[li])
+        r_leaves, r_def = jax.tree.flatten(ref)
+        assert g_def == r_def
+        for a, b in zip(g_leaves, r_leaves):
+            a = np.asarray(a, np.float32)
+            b = np.asarray(b, np.float32)
+            rel = float(np.linalg.norm(a - b)) / max(
+                float(np.linalg.norm(b)), 1e-8)
+            assert rel < 5e-2, f"layer {li}: grad rel-L2 error {rel:.2e}"
+
+
+def test_last_stage_forward_never_runs_in_train_step(folded_step):
+    assert folded_step["fwd_calls_in_train"] == 0
+    # gpt2-tiny has no accuracy metric, so eval runs the forward-only
+    # program of the last stage: once a microbatch.
+    assert folded_step["fwd_calls_in_eval"] == NUM_MB
+
+
+def test_forward_dispatch_counter(folded_step):
+    S, v = folded_step["S"], folded_step["v"]
+    assert folded_step["dispatched"] == {
+        "folded": NUM_MB, "run": NUM_MB * (S * v - 1)}
+
+
+def test_eval_step_loss_unchanged_by_fold(folded_step, fused):
+    """eval_step runs the forward-only programs on the parameters the
+    train_step left untouched: the same mean loss, from programs that
+    share nothing with the folded backward."""
+    assert folded_step["eval_loss"] == pytest.approx(
+        folded_step["loss"], rel=1e-5)
+    assert folded_step["eval_loss"] == pytest.approx(fused[0], rel=2e-2)
+
+
+def test_last_op_times_keeps_f_and_b_for_every_chunk(folded_step):
+    pipe, times = folded_step["pipe"], folded_step["op_times"]
+    S, v = folded_step["S"], folded_step["v"]
+    for st in pipe.stages:
+        for c in range(len(st.chunks)):
+            for kind in ("f", "b"):
+                total, n = times[(st.stage_index, c, kind)]
+                assert n == NUM_MB and total >= 0.0
+    # The folded forward dispatched nothing, and says so.
+    assert times[(S - 1, v - 1, "f")][0] == 0.0
+    assert times[(S - 1, v - 1, "b")][0] > 0.0
+    # The readers of these keys still get a bubble out of them.
+    from oobleck_tpu.execution.schedule import Op, simulate_bubble
+
+    def dur(inst):
+        kind = "f" if inst.op is Op.FORWARD else "b"
+        total, n = times[(inst.stage, inst.chunk, kind)]
+        return total / n
+
+    assert 0.0 <= simulate_bubble(S, NUM_MB, v, dur) < 1.0

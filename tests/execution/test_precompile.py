@@ -84,3 +84,85 @@ def test_precompile_env_disable(cache_env, devices8, monkeypatch):
     assert pc is not None
     pc.wait()
     assert pc.stats["errors"] == 0, pc.stats
+
+
+# The aux programs (`jit(grad_add)`, `jit(optimizer_update)`) are warmed
+# best-effort, once per aval whatever the stage's devices, and are not held
+# to this: the stage programs are.
+STAGE_PROGRAMS = ("jit(fwd)", "jit(bwd)", "jit(eval_fwd)")
+
+
+class _CompileCounter:
+    """Backend compiles by jitted function name, from JAX's own duration
+    event (the one utils/compile_cache.py feeds its seconds counter from)."""
+
+    def __init__(self):
+        self.names: list[str] = []
+        self.on = True
+
+    def __call__(self, event, _seconds, fun_name=None, **_):
+        if self.on and event == "/jax/core/compile/backend_compile_duration":
+            self.names.append(fun_name)
+
+    def take(self, *wanted) -> list[str]:
+        got = [n for n in self.names if n in wanted]
+        self.names.clear()
+        return got
+
+
+@pytest.fixture
+def compile_counter():
+    import jax
+
+    counter = _CompileCounter()
+    jax.monitoring.register_event_duration_secs_listener(counter)
+    yield counter
+    counter.on = False  # jax keeps listeners for the process's lifetime
+
+
+@pytest.mark.parametrize("model_name,has_eval_program", [
+    ("gpt2-tiny", False),   # eval runs the last stage's forward-only program
+    ("bert-tiny", True),    # eval runs the program that returns the metric
+])
+def test_precompiled_stage_programs_are_the_ones_the_step_runs(
+        cache_env, devices8, compile_counter, model_name, has_eval_program):
+    """The walk over the LIVE pipelines compiles every program a step runs,
+    the last stage's three-output `bwd` among them: the step after it
+    compiles no stage program. The last stage's forward-only program is
+    compiled ahead only where eval_step runs it."""
+    from oobleck_tpu.execution.precompile import RecoveryPrecompiler
+
+    engine = make_engine(num_hosts=2, steps=3, devices=devices8[:4],
+                         microbatch=2, global_mb=8, model_name=model_name)
+    engine.initialize_distributed()
+    engine.instantiate_pipelines(engine.args.job.global_num_microbatch)
+    chunks, last_chunks = set(), set()
+    for pipe in engine.pipelines:
+        for st in pipe.stages:
+            for c, layers in enumerate(st.chunks):
+                key = (layers, tuple(st.ranks))
+                chunks.add(key)
+                if layers[-1] == engine.model.num_pipeline_layers - 1:
+                    last_chunks.add(key)
+                    assert (st.efwd[c] is not None) == has_eval_program
+
+    pc = RecoveryPrecompiler(engine)
+    compile_counter.take()
+    for pipe in engine.pipelines:
+        pc._aot_pipeline(pipe)
+    assert pc.stats["errors"] == 0, pc.stats
+    # Two programs a chunk: fwd + bwd, and on the last virtual stage bwd +
+    # whichever forward eval_step runs (it was three with an eval program).
+    assert pc.stats["stages_compiled"] == 2 * len(chunks)
+    walked = compile_counter.take(*STAGE_PROGRAMS)
+    assert walked.count("jit(bwd)") == len(chunks)
+    assert walked.count("jit(eval_fwd)") == (
+        len(last_chunks) if has_eval_program else 0)
+    assert walked.count("jit(fwd)") == len(chunks) - (
+        len(last_chunks) if has_eval_program else 0)
+
+    loss = engine._train_step()
+    assert np.isfinite(loss)
+    assert compile_counter.take(*STAGE_PROGRAMS) == []
+    assert np.isfinite(engine.evaluate(num_batches=1))
+    assert compile_counter.take(*STAGE_PROGRAMS) == []

@@ -323,3 +323,29 @@ def test_kernel_is_named_in_location_and_executable(v5e, name):
     assert name in calls, calls
     # No Pallas call of these programs goes by a stand-in.
     assert set(calls) <= set(KERNEL_NAMES), calls
+
+
+# The last pipeline stage's one program returns the loss with the gradients
+# (execution/pipeline.py). Asking for the loss's value must not make the
+# compiler write the float32 logits out: picking the target's logit with a
+# gather did (a second fusion output of 824 MB a microbatch at the cell's
+# [4, 1024, 50304], +13 ms and +475 MB of peak a step on the chip, PR 30);
+# as a masked sum it fuses into the reductions. The bfloat16 logits and
+# their gradient, 412 MB each, are the program's to hold.
+def test_head_loss_value_and_grad_holds_no_f32_logits(v5e):
+    from oobleck_tpu.models.gpt import cross_entropy_loss
+
+    mb, seq, hidden, vocab, padded = 4, 1024, 2560, 50257, 50304
+
+    def head_loss(w, x, tokens):
+        logits = (x @ w.astype(jnp.bfloat16)).astype(jnp.float32)
+        return cross_entropy_loss(logits, tokens, vocab)
+
+    one = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((hidden, padded), jnp.float32), ((mb, seq, hidden), jnp.bfloat16),
+        ((mb, seq), jnp.int32))]
+    compiled = jax.jit(jax.value_and_grad(head_loss, argnums=(0, 1))).lower(
+        *args).compile()
+    f32_logits = mb * (seq - 1) * padded * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < f32_logits
