@@ -3,9 +3,8 @@
 Exit status is 0 when the tree is clean (no findings beyond inline
 suppressions and the checked-in baseline) and 1 when there is anything
 new — which is what lets ``make analyze`` gate the build. ``--json``
-emits the machine-readable report bench.py embeds as provenance;
-``--write-baseline`` grandfathers the current findings (use sparingly:
-the intended fix for a finding is a fix).
+emits the machine-readable report; ``--write-baseline`` grandfathers the
+current findings (use sparingly: the intended fix for a finding is a fix).
 """
 
 from __future__ import annotations

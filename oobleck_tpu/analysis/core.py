@@ -194,7 +194,7 @@ def write_baseline(path: Path, findings: Iterable[Finding],
 # runner
 
 
-DEFAULT_TARGETS = ("oobleck_tpu", "bench.py")
+DEFAULT_TARGETS = ("oobleck_tpu",)
 _SKIP_PARTS = {"__pycache__"}
 
 

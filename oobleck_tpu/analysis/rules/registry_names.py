@@ -3,7 +3,7 @@
 History: the PR-8 forensics work found the observability plane's worst
 failure mode is silent: a typo'd metric family (``oobleck_step_secnds``)
 or flight-event kind just creates a parallel, never-read series, and the
-dashboards/bench diffs that key on the real name read zero forever. The
+dashboards that key on the real name read zero forever. The
 generated registry (``obs/registry.py``, built by
 ``python -m oobleck_tpu.analysis.genregistry``) is the single source of
 truth; this rule checks every statically-visible name against it, and

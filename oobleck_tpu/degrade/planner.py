@@ -39,8 +39,8 @@ class PipelineSpec:
     virtual_stages: int = 1
     op_times: dict = field(default_factory=dict)
     # Measured fraction of cross-stage transfer time hidden under compute
-    # (bench `overlap` key / oobleck_comm_hidden_fraction gauge). 0.0 keeps
-    # the classic fully-serialized projection; 1.0 projects comm as free.
+    # (`parallel/overlap.comm_hidden_fraction`). 0.0 keeps the classic
+    # fully-serialized projection; 1.0 projects comm as free.
     comm_hidden_fraction: float = 0.0
 
     def duration_fn(self):

@@ -1911,23 +1911,6 @@ class OobleckEngine:
             digest["path"] = path
         self._incident_record = digest
 
-    def export_pipeline_trace(self, path: str | None = None) -> dict | None:
-        """Write the live pipelines' per-(stage, chunk, microbatch) Perfetto
-        timeline (obs/pipeline_trace); `path` defaults to
-        $OOBLECK_PIPELINE_TRACE, and no path means no export."""
-        import os
-
-        from oobleck_tpu.obs import pipeline_trace as ptrace
-
-        path = path or os.environ.get(ptrace.ENV_PIPELINE_TRACE)
-        if not path or not self.pipelines:
-            return None
-        try:
-            return ptrace.write_pipeline_trace(path, self.pipelines)
-        except OSError as e:
-            logger.warning("pipeline trace export failed: %s", e)
-            return None
-
     def _publish_metrics(self) -> None:
         """Ship the registry snapshot up the agent pipe (relayed to the
         master's /metrics) and append it to the JSONL sink."""
@@ -2092,10 +2075,8 @@ class OobleckEngine:
             if self._durable is not None:
                 self._durable.flush()
             self._publish_metrics()
-            # Observability exports: the per-op pipeline timeline (only
-            # when OOBLECK_PIPELINE_TRACE names a file) and the span ring
-            # (only when the JSONL metrics sink is enabled).
-            self.export_pipeline_trace()
+            # The span ring is written only when the JSONL metrics sink is
+            # enabled.
             if metrics.metrics_dir() is not None:
                 obs_spans.span_recorder().dump("train_end")
             if self._tracer is not None:

@@ -250,9 +250,6 @@ class PipelineInstance:
         self.model = model
         self.num_microbatches = num_microbatches
         self.total_num_microbatches = total_num_microbatches
-        # Pre-reroute share; set by adopt_microbatches so the obs pipeline
-        # trace can tag reroute-borrowed microbatches (obs/pipeline_trace).
-        self.original_num_microbatches: int | None = None
         self.microbatch_size = microbatch_size
         self.seq_len = seq_len
         self._exec_cache = exec_cache if exec_cache is not None else {}
@@ -273,7 +270,7 @@ class PipelineInstance:
         # activation/grad transfers are sent eagerly (unbatched) and timed
         # as kinds "cf"/"cb", which stay OUT of stage-busy — they are the
         # overlappable component the degrade planner's effective_comm
-        # projection discounts. Serializes execution — bench/tests only,
+        # projection discounts. Serializes execution — tests only,
         # never the training hot path.
         self.sync_op_timing = False
         my_process = comm.process_index if comm is not None else None
@@ -846,8 +843,6 @@ class PipelineInstance:
         stream."""
         validate_interleaving(self.num_stages, new_num_microbatches,
                               self.virtual_stages)
-        if self.original_num_microbatches is None:
-            self.original_num_microbatches = self.num_microbatches
         self.num_microbatches = new_num_microbatches
 
     def train_step(self, batch, placed=None):

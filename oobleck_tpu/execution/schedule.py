@@ -223,10 +223,7 @@ def replay_schedule(num_stages: int, num_microbatches: int,
     planner replays rerouted streams through the same dependency rules,
     which is what makes its makespan estimate and the test-side replay of
     the emitted schedule one computation instead of two. `on_op(stage,
-    inst, start, end)` observes every scheduled compute unit — the obs
-    pipeline-trace exporter renders these into per-(stage, chunk,
-    microbatch) Perfetto slices, so the exported timeline and the bubble
-    estimate cannot drift apart.
+    inst, start, end)` observes every scheduled compute unit.
     """
     S, M, v = num_stages, num_microbatches, virtual_stages
     if duration_fn is None:

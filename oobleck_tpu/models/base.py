@@ -15,7 +15,7 @@ Two views of the same parameters:
     the MPMD pipeline interpreter, where each stage owns a contiguous slice.
   - fused/stacked (`init_params` / `loss`): blocks stacked on a leading
     [num_blocks, ...] axis so the SPMD pipeline can shard them over the
-    `stage` mesh axis and scan over them; used by the fast path and bench.
+    `stage` mesh axis and scan over them; used by the fast path.
 
 `stack_layer_params` / `unstack_layer_params` convert between them.
 """

@@ -1,26 +1,16 @@
 """Observability plane: distributed tracing and incident forensics.
 
-Three pieces, all dependency-free:
+Two pieces, both dependency-free:
 
 - ``spans``: bounded-ring span recorder with trace-context propagation
   over the elastic verbs and Chrome-trace/Perfetto export.
-- ``pipeline_trace``: the dispatched pipeline instruction schedule
-  rendered as per-(stage, chunk, microbatch) Perfetto slices, from the
-  same replay that produces the measured bubble gauge.
 - ``incident``: joins spans + flight-recorder rings + metrics snapshots
   into atomically committed ``incident-<n>.json`` postmortems with a
   recovery phase breakdown; rendered by ``python -m
   oobleck_tpu.obs.report`` (``make trace-report``).
 """
 
-# NOTE: the pipeline_trace() builder function is intentionally NOT
-# re-exported here — the bare name would shadow the submodule of the same
-# name on this package.
 from oobleck_tpu.obs.incident import IncidentBuilder, list_incidents
-from oobleck_tpu.obs.pipeline_trace import (
-    ENV_PIPELINE_TRACE,
-    write_pipeline_trace,
-)
 from oobleck_tpu.obs.spans import (
     TRACE_KEY,
     SpanRecorder,
@@ -36,7 +26,6 @@ from oobleck_tpu.obs.spans import (
 )
 
 __all__ = [
-    "ENV_PIPELINE_TRACE",
     "IncidentBuilder",
     "SpanRecorder",
     "TRACE_KEY",
@@ -50,5 +39,4 @@ __all__ = [
     "span_recorder",
     "to_chrome_trace",
     "write_chrome_trace",
-    "write_pipeline_trace",
 ]

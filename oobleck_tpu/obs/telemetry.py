@@ -15,9 +15,7 @@ Design constraints, in order:
     — it is covered by oobleck-lint's OBL002/OBL003 fence rules exactly
     like the step loop it instruments, so a readback cannot sneak in.
 2.  **Bounded, allocation-light.** Samples land in a preallocated ring
-    (a deque of tuples); recording is an append and nothing else. The
-    steady-state cost is measured by ``make goodput-bench`` and must
-    stay under 1% of step time.
+    (a deque of tuples); recording is an append and nothing else.
 3.  **Digest, not firehose.** The wire carries a compact windowed digest
     (piggybacked on the agent's existing heartbeat as one extra JSON
     key — legacy masters ignore it), never raw samples.

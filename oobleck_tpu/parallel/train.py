@@ -172,8 +172,7 @@ def estimate_flops_per_token(n_params: int, seq_len: int, *,
                              num_layers: int = 0,
                              hidden_size: int = 0) -> float:
     """Training FLOPs per token: 6N for the matmuls (fwd+bwd) plus the
-    causal-attention term. Shared by bench.py's headline MFU and the
-    engine's per-step MFU gauge so the two can never diverge."""
+    causal-attention term. Read by the engine's per-step MFU gauge."""
     return 6.0 * n_params + 6.0 * (num_layers * hidden_size * seq_len)
 
 
@@ -208,10 +207,8 @@ def mfu_estimate(tokens_per_sec: float, flops_per_token: float,
                  ) -> float | None:
     """Model FLOPs utilization from the planner's FLOPs model: achieved
     training FLOP/s over the fleet's peak. One definition shared by the
-    engine's per-step gauge, the goodput ledger, and bench.py — so the
-    MFU in /status.fleet_health and the MFU in a bench record can never
-    be computed two different ways. None when peak is unknown (CPU) or
-    the inputs are degenerate."""
+    engine's per-step gauge and the goodput ledger. None when peak is
+    unknown (CPU) or the inputs are degenerate."""
     if (peak_flops_per_chip is None or peak_flops_per_chip <= 0
             or n_chips <= 0 or tokens_per_sec <= 0):
         return None
@@ -554,8 +551,8 @@ def build_train_step(model, mesh, *, num_microbatches: int, optimizer=None,
     wrapped_step.state_shardings = state_shardings
     wrapped_step.token_sharding = token_sharding
     wrapped_step.overlap = overlap
-    # (loss, grads) probe for parity tests and the overlap bench — the same
-    # core the step uses, without the optimizer update or donation.
+    # (loss, grads) probe for parity tests — the same core the step uses,
+    # without the optimizer update or donation.
     wrapped_step.loss_and_grads = jax.jit(
         loss_and_grads,
         in_shardings=(state_shardings.params, token_sharding, token_sharding),

@@ -28,10 +28,10 @@ from oobleck_tpu.utils import metrics
 logger = logging.getLogger("oobleck.policy")
 
 # Latency priors (seconds) used until a mechanism has measured history.
-# reroute/reinstantiate-warm come from the degrade bench (~0.56 s / ~0.64 s
-# on the reference shape, rounded up); reinstantiate-respawn and restore
-# from the multiprocess recovery runs (~21 s respawn; restore adds durable
-# read + re-instantiation on top).
+# reroute/reinstantiate-warm come from a two-host CPU rig (~0.56 s / ~0.64 s,
+# rounded up); reinstantiate-respawn and restore from the multiprocess
+# recovery runs (~21 s respawn; restore adds durable read + re-instantiation
+# on top). None was taken on a TPU: measured history replaces them.
 PRIOR_LATENCY_S = {
     "reroute": 0.6,
     "reinstantiate": 0.7,          # warm in-place re-instantiation

@@ -18,9 +18,6 @@ plane negotiate who restores, who degrades, and who yields chips:
                  through the SAME classify->score->broadcast chain as
                  every other incident (policy/scorer.py, extended with
                  cross-tenant SLO-debt and preemption-cost terms)
-    bench.py     `make pool-bench`: a real master + agents + serving
-                 plane driven through a full borrow/return cycle by a
-                 chaos `traffic_wave`
 
 The pool plane is inert unless ``OOBLECK_POOL=1``: a single-job cluster
 pays one env read and keeps its exact pre-pool behavior.

@@ -14,7 +14,6 @@ serving, without dropping in-flight requests.
     reload.py    CheckpointWatcher — poll committed steps, stage off the
                  decode path, swap at a decode-step barrier
     server.py    stdlib HTTP: POST /v1/generate, GET /healthz, /metrics
-    bench.py     tokens/sec, TTFT and reload-pause percentiles
     router/      multi-replica front door: prefix-affine routing,
                  failover, pool-driven scale-out (own package docstring)
 

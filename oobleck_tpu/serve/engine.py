@@ -6,8 +6,8 @@ prefill/decode executables. Two cache disciplines:
 
   DecodeEngine       dense slots — `[L, slots, H, max_seq, D]`, HBM per
                      slot scales with max_seq regardless of actual
-                     lengths. Kept as the baseline the paged bench gate
-                     compares against.
+                     lengths. Kept as the reference the paged engine's
+                     tests compare against.
   PagedDecodeEngine  block/paged — `[L, N_pages, Hkv, page, D]` pool,
                      per-request page chains (serve/kv_blocks.py), ragged
                      paged attention (ops/paged_attention.py), prefix

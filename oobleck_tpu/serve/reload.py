@@ -66,9 +66,9 @@ def publish_params(root, model, params, *, step: int,
                    model_name: str | None = None,
                    model_args: dict | None = None) -> None:
     """Write a fused params tree as one committed checkpoint step (no
-    optimizer state) — the minimal trainer->server handoff, used by the
-    serve bench and tests. Training jobs publish through the engine's
-    durable-state plane instead."""
+    optimizer state) — the minimal trainer->server handoff, used by
+    tests. Training jobs publish through the engine's durable-state plane
+    instead."""
     from oobleck_tpu.ckpt import DurableStatePlane
     from oobleck_tpu.execution.fused import params_to_layers
 

@@ -26,8 +26,8 @@ chip pool so the set can grow under load and shrink on reclaim:
                 replicas absorbing traffic; LEASE_RECLAIM drains them
                 through the router with zero dropped requests.
 
-``RouterPlane`` wires the pieces; tests and the bench compose the parts
-directly when they need seams.
+``RouterPlane`` wires the pieces; tests compose the parts directly when
+they need seams.
 
 Env knobs: ``OOBLECK_ROUTER_PORT`` (listen port, 0 = ephemeral),
 ``OOBLECK_ROUTER_PROBE_S`` (health-probe period),

@@ -1,22 +1,19 @@
 """Corpus loader: recorded traces -> typed events the simulator can replay.
 
-Three sources, one Corpus:
+Two sources, one Corpus:
 
   * ``incident-<n>.json`` — the obs plane's committed postmortems
     (schema-validated: unknown ``schema_version`` is skipped with a
     warning, records missing the core keys are skipped, duplicate
     trace_ids are deduped first-wins);
   * ``flight-*.jsonl`` — dumped flight-recorder rings (one JSON event per
-    line; unparseable lines are counted, not fatal);
-  * ``BENCH_r*.json`` — driver-committed bench rounds whose ``parsed``
-    payload may carry a ``degrade`` section with measured recovery
-    latencies.
+    line; unparseable lines are counted, not fatal).
 
 Beyond replay, the corpus is the policy plane's training set:
 ``latency_samples()`` extracts per-mechanism measured recovery latencies
 (incident ``total_s`` preferred — it is the failure-to-resume metric the
 scorer prices; flight ``degrade_decision`` / ``policy_decision_measured``
-events and bench rounds fill in incidents the obs plane never committed),
+events fill in incidents the obs plane never committed),
 deduped so an incident's embedded flight tail and a separately dumped
 ring never double-count one recovery. ``priors.py`` fits
 ``learned_priors.json`` from exactly these samples.
@@ -36,18 +33,9 @@ from oobleck_tpu.utils import metrics
 logger = logging.getLogger("oobleck.sim")
 
 _FLIGHT_RE = re.compile(r"flight-.*\.jsonl$")
-_BENCH_RE = re.compile(r"BENCH_r\d+\.json$")
 
 # Keys a parseable incident must carry to be replayable at all.
 _REQUIRED_INCIDENT_KEYS = ("trace_id", "lost_ip", "marks")
-
-# Bench-round degrade section -> prior-table mechanism key.
-_BENCH_MECHANISMS = (
-    ("reroute", "reroute"),
-    ("reinstantiate_respawn", "reinstantiate_respawn"),
-    ("reinstantiate_inplace", "reinstantiate"),
-)
-
 
 @dataclass
 class IncidentEvent:
@@ -78,28 +66,17 @@ class FlightEvent:
 
 
 @dataclass
-class BenchRound:
-    """One driver-committed bench round (the ``parsed`` payload)."""
-
-    path: str
-    round_n: int
-    parsed: dict
-    degrade: dict = field(default_factory=dict)
-
-
-@dataclass
 class Corpus:
     """Everything loadable under one trace directory, plus what was not."""
 
     root: str
     incidents: list[IncidentEvent] = field(default_factory=list)
     flight: list[FlightEvent] = field(default_factory=list)
-    bench_rounds: list[BenchRound] = field(default_factory=list)
     skipped: list[tuple[str, str]] = field(default_factory=list)
 
     def latency_samples(self) -> dict[str, list[float]]:
         """mechanism -> measured recovery seconds, one sample per distinct
-        recovery across all three sources (see module docstring)."""
+        recovery across both sources (see module docstring)."""
         samples: dict[str, list[float]] = {}
         consumed: set = set()
 
@@ -126,11 +103,6 @@ class Corpus:
                 consumed.add(key)
                 add(fe.fields.get("mechanism", ""),
                     fe.fields.get("measured_recovery_s"))
-        for rnd in self.bench_rounds:
-            for section, mechanism in _BENCH_MECHANISMS:
-                sec = rnd.degrade.get(section)
-                if isinstance(sec, dict):
-                    add(mechanism, sec.get("recovery_to_next_step_s"))
         return samples
 
     def stats(self) -> dict:
@@ -138,7 +110,6 @@ class Corpus:
         return {
             "incidents": len(self.incidents),
             "flight_events": len(self.flight),
-            "bench_rounds": len(self.bench_rounds),
             "skipped": len(self.skipped),
             "latency_samples": {m: len(v)
                                 for m, v in self.latency_samples().items()},
@@ -178,7 +149,7 @@ def load_corpus(root: str) -> Corpus:
     reg = metrics.registry()
     events_total = reg.counter(
         "oobleck_sim_corpus_events_total",
-        "Corpus records loaded by kind (incident/flight/bench_round)")
+        "Corpus records loaded by kind (incident/flight)")
     skipped_total = reg.counter(
         "oobleck_sim_corpus_skipped_total",
         "Corpus records skipped at load time, by reason")
@@ -253,23 +224,4 @@ def load_corpus(root: str) -> Corpus:
                 continue
             if bad:
                 skip(path, f"unparseable_lines:{bad}")
-        elif _BENCH_RE.match(name):
-            try:
-                with open(path) as f:
-                    rec = json.load(f)
-            except (OSError, ValueError) as e:
-                skip(path, f"unreadable:{e.__class__.__name__}")
-                continue
-            if not isinstance(rec, dict):
-                skip(path, "not_a_dict")
-                continue
-            parsed = rec.get("parsed") if isinstance(rec.get("parsed"),
-                                                     dict) else rec
-            degrade = parsed.get("degrade")
-            corpus.bench_rounds.append(BenchRound(
-                path=path,
-                round_n=int(rec.get("n") or 0),
-                parsed=parsed,
-                degrade=degrade if isinstance(degrade, dict) else {}))
-            events_total.inc(kind="bench_round")
     return corpus

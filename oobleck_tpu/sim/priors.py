@@ -2,9 +2,9 @@
 
 The shipped ``PRIOR_LATENCY_S`` table is what the scorer believes before
 any history exists; this module replaces belief with evidence. Every
-committed incident, dumped flight ring, and bench round contributes its
-measured failure-to-resume latency (``Corpus.latency_samples``); the fit
-is the per-mechanism median — robust to the one 20x outlier a respawn
+committed incident and dumped flight ring contributes its measured
+failure-to-resume latency (``Corpus.latency_samples``); the fit is the
+per-mechanism median — robust to the one 20x outlier a respawn
 under load produces, and deterministic (no wall clock in the output, so
 re-fitting an unchanged corpus is byte-identical).
 
@@ -64,7 +64,6 @@ def fit_priors(corpus: Corpus, *, min_samples: int = 1) -> dict:
             "fitted_from": corpus.root,
             "incidents": len(corpus.incidents),
             "flight_events": len(corpus.flight),
-            "bench_rounds": len(corpus.bench_rounds),
             "estimator": "median",
             "mechanisms": provenance,
         },

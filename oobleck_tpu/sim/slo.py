@@ -1,7 +1,6 @@
 """Fleet SLO reduction: one simulator run -> the numbers a PR is gated on.
 
-Three SLO families, mirroring what the live bench gate measures but at
-fleet scale no hardware run could cover:
+Three SLO families, at fleet scale no hardware run could cover:
 
   * recovery latency percentiles (nearest-rank, so the report is exact
     and deterministic — no interpolation float drift);

@@ -71,7 +71,7 @@ directives; each directive is ``action=arg[:qual][@ip]``:
                                 ``kill_master=5:3`` advises the harness
                                 to restart the master 3 s after the kill
                                 (the master cannot restart itself; the
-                                bench/test harness reads the qual)
+                                test harness reads the qual)
     partition_master=10.0.0.1:8 network partition: agent 10.0.0.1 loses
                                 its master link for 8 s — the master
                                 stays up and evicts the host on heartbeat
@@ -487,7 +487,7 @@ class Chaos:
         """One-shot (kill_after_s, restart_after_s|None) if a kill_master
         rule is pending, else None. The MASTER reads this at startup and
         schedules its own SIGKILL; restart_after_s is advisory — the
-        master cannot restart itself, so the bench/test harness reads the
+        master cannot restart itself, so the test harness reads the
         same rule (non-consumed, different process) to time the restart.
         Consuming within a process: a master only dies once."""
         for r in self.rules:

@@ -9,7 +9,7 @@
     host CPU or the clock.
 
 Every process that owns an accelerator — the worker, the serve engine,
-bench.py, chip_smoke.py's children — calls `ensure_persistent_cache()`
+chip_smoke.py's children — calls `ensure_persistent_cache()`
 before it compiles.
 
 On the CPU backend the cache is switched OFF instead. With this jax/jaxlib

@@ -6,8 +6,7 @@ histograms (all label-aware, all thread-safe). Two sinks:
 - Prometheus text exposition (``render_prometheus``), served cluster-wide
   by the master's ``MetricsHTTPServer`` (/metrics and /status);
 - an append-only JSONL file under ``OOBLECK_METRICS_DIR``
-  (``dump_jsonl``), consumed by bench.py for tokens/sec, MFU, and
-  recovery-latency percentiles.
+  (``dump_jsonl``).
 
 Snapshots are plain JSON dicts so they travel over the elastic protocol
 (worker -> agent mp pipe -> master TCP METRICS push) and merge on the

@@ -14,7 +14,7 @@ from oobleck_tpu.analysis import (
     run_analysis,
 )
 from oobleck_tpu.analysis.__main__ import main as cli_main
-from oobleck_tpu.analysis.core import write_baseline
+from oobleck_tpu.analysis.core import DEFAULT_TARGETS, write_baseline
 from oobleck_tpu.analysis.genregistry import generate, registry_path
 from tests.analysis.conftest import codes
 
@@ -199,6 +199,14 @@ def test_repo_tree_is_lint_clean():
     assert [f.render() for f in result.new] == []
     assert result.files_scanned > 50
     assert result.rules_run == 6
+
+
+def test_default_targets_all_exist():
+    """A default target that names nothing is skipped in silence, and the
+    gate then covers less than its line says."""
+    assert DEFAULT_TARGETS
+    for target in DEFAULT_TARGETS:
+        assert (REPO_ROOT / target).exists(), target
 
 
 def test_checked_in_registry_is_fresh():

@@ -166,9 +166,8 @@ def test_keep_last_k_gc(tmp_path):
 
 def test_async_writer_at_most_one_in_flight_and_cheaper_than_sync(tmp_path):
     """The async submit returns after enqueue (stall = drain + capture);
-    the sync baseline pays capture + write + commit inline. The acceptance
-    bar (<25%) is measured by bench.py on an engine-family model; here we
-    assert the direction and the at-most-one-in-flight discipline."""
+    the sync baseline pays capture + write + commit inline. Here we assert
+    the direction and the at-most-one-in-flight discipline."""
     big = {0: {"w": np.zeros((512, 1024), np.float32)}}  # 2 MB
     opt = {0: (np.zeros((512, 1024), np.float32),)}
 

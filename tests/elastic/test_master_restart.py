@@ -5,6 +5,7 @@ alone through the normal policy chain; the epoch fence refuses stale
 masters and stale verbs in both directions."""
 
 import asyncio
+import json
 
 import pytest
 
@@ -14,14 +15,17 @@ from oobleck_tpu.elastic.agent import OobleckAgent
 from oobleck_tpu.elastic.message import (
     EPOCH_KEY,
     PROTOCOL_VERSION,
+    TENANT_KEY,
     RequestType,
     ResponseType,
     recv_msg,
     send_msg,
     send_request,
 )
+from oobleck_tpu.pool import arbiter as arbiter_mod
 from oobleck_tpu.utils import metrics
 
+from tests.elastic.scripted import pool_rpc
 from tests.elastic.test_control_plane import (
     RecordingLauncher,
     job_args,  # noqa: F401 — fixture re-export
@@ -183,6 +187,50 @@ async def test_host_dead_during_outage_recovered_from_journal(
             assert daemon2._recoveries[-1]["cause"] == "master_outage"
         for _, w, _ in survivors:
             w.close()
+    finally:
+        task2.cancel()
+        await daemon2.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("history", ["fleet", "fleet_failure_lease"])
+async def test_replay_reaches_the_registry_the_dead_master_held(
+        job_args, state_dir, monkeypatch, history):  # noqa: F811
+    """Whatever the dead master had folded into its journal (the fleet
+    alone; or a lost host with its open incident and failure history, and
+    a chip lease out to another tenant), the restarted one replays into
+    the SAME registry, key for key, and burns a new epoch."""
+    monkeypatch.delenv("OOBLECK_DEGRADE", raising=False)
+    monkeypatch.setenv(arbiter_mod.ENV_POOL, "1")
+    daemon, _, task = await start_master()
+    port = daemon.port
+    await launch_job(daemon, job_args)
+    socks = [await register_agent(daemon, ip)
+             for ip in job_args.dist.node_ips]
+    if history == "fleet_failure_lease":
+        socks[2][1].close()                 # 10.0.0.3 dies: an incident
+        for r, _, _ in socks[:2]:
+            assert (await recv_msg(r, timeout=5))["lost_ip"] == "10.0.0.3"
+        granted = await pool_rpc(port, {
+            TENANT_KEY: "serve-a", "chips": 1,
+            "pressure": {"slo_debt_s": 90.0}, "lease_ttl_s": 60.0})
+        assert granted["kind"] == ResponseType.SUCCESS.value
+    held = json.loads(json.dumps(daemon.journal.state))
+    if history == "fleet_failure_lease":
+        assert held["open_incidents"] and held["leases"]
+        assert held["failures"]["10.0.0.3"]
+
+    hard_kill(daemon)
+    task.cancel()
+    await daemon.stop()
+    for _, w, _ in socks:
+        w.close()
+
+    daemon2, task2 = await restart_master(port)
+    try:
+        assert daemon2.journal.state == held
+        assert daemon2.master_epoch == 2
+        assert daemon2.journal.replayed_entries >= 4
     finally:
         task2.cancel()
         await daemon2.stop()

@@ -9,8 +9,6 @@ import asyncio
 
 import pytest
 
-from oobleck_tpu.config import OobleckArguments
-from oobleck_tpu.elastic.master import OobleckMasterDaemon
 from oobleck_tpu.elastic.message import (
     RequestType,
     ResponseType,
@@ -20,17 +18,12 @@ from oobleck_tpu.elastic.message import (
 from oobleck_tpu.policy.engine import DECISION_KEY
 from oobleck_tpu.utils import metrics
 
+from tests.elastic.scripted import launch_job, start_master
+
 
 async def _start_master(node_ips):
-    args = OobleckArguments()
-    args.dist.node_ips = list(node_ips)
-    daemon = OobleckMasterDaemon(port=0, launcher=None)
-    await daemon.start()
-    task = asyncio.create_task(daemon.serve_forever())
-    r, w = await asyncio.open_connection("127.0.0.1", daemon.port)
-    await send_request(w, RequestType.LAUNCH_JOB, {"args": args.to_dict()})
-    assert (await recv_msg(r))["kind"] == ResponseType.SUCCESS.value
-    w.close()
+    daemon, task = await start_master()
+    await launch_job(daemon.port, node_ips)
     return daemon, task
 
 

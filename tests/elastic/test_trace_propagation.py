@@ -9,7 +9,6 @@ import types
 
 import pytest
 
-from oobleck_tpu.config import OobleckArguments
 from oobleck_tpu.elastic.agent import OobleckAgent
 from oobleck_tpu.elastic.master import OobleckMasterDaemon
 from oobleck_tpu.elastic.message import (
@@ -20,21 +19,11 @@ from oobleck_tpu.elastic.message import (
 )
 from oobleck_tpu.obs import spans
 
-
-async def _start_master():
-    daemon = OobleckMasterDaemon(port=0, launcher=None)
-    await daemon.start()
-    task = asyncio.create_task(daemon.serve_forever())
-    return daemon, task
+from tests.elastic.scripted import launch_job, start_master
 
 
 async def _launch_and_register(daemon, ips):
-    args = OobleckArguments()
-    args.dist.node_ips = list(ips)
-    r, w = await asyncio.open_connection("127.0.0.1", daemon.port)
-    await send_request(w, RequestType.LAUNCH_JOB, {"args": args.to_dict()})
-    assert (await recv_msg(r))["kind"] == ResponseType.SUCCESS.value
-    w.close()
+    await launch_job(daemon.port, ips)
     conns = []
     for ip in ips:
         r, w = await asyncio.open_connection("127.0.0.1", daemon.port)
@@ -50,7 +39,7 @@ async def test_recovery_verb_carries_trace_context(monkeypatch):
     trace context (trace_id + master-side wall marks) AND keep the legacy
     shape (kind/lost_ip) untouched, so pre-trace agents parse it fine."""
     monkeypatch.delenv("OOBLECK_DEGRADE", raising=False)
-    daemon, task = await _start_master()
+    daemon, task = await start_master()
     try:
         (r1, w1), (r2, w2) = await _launch_and_register(
             daemon, ["10.0.0.1", "10.0.0.2"])

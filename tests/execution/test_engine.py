@@ -7,6 +7,7 @@ and test_engine_families.py (model-family breadth) so each module fits the
 per-call test budget."""
 
 import os
+import types
 
 import numpy as np
 import pytest
@@ -148,6 +149,38 @@ def test_min_hosts_bound(cache_env, devices8):
     engine = make_engine(num_hosts=4, devices=devices8)
     engine.chips_per_host = 2
     assert engine.compute_min_hosts() >= 1
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("cpu", None, 1),                      # the test backend's assumed 16 GiB
+    ("tpu", {"bytes_limit": 8 * 2**30}, 2),  # asked, not assumed
+    ("tpu", {}, RuntimeError),
+    ("tpu", None, RuntimeError),
+])
+def test_compute_min_hosts_asks_the_tpu_for_its_memory(platform, stats, want):
+    dev = types.SimpleNamespace(platform=platform,
+                                memory_stats=lambda: stats)
+    # 6 * 2 GiB of params + 1 GiB of activations = 13 GiB
+    eng = types.SimpleNamespace(
+        profiles=[types.SimpleNamespace(mem_params=2 * 2**30,
+                                        mem_activation=2**30)],
+        devices=[dev], chips_per_host=1)
+    if isinstance(want, int):
+        assert OobleckEngine.compute_min_hosts(eng) == want
+    else:
+        with pytest.raises(want, match="reports no memory limit"):
+            OobleckEngine.compute_min_hosts(eng)
+
+
+def test_peak_flops_is_a_keyed_table():
+    """The engine's MFU gauge divides by this: not a substring match, and
+    no default. An unknown device kind is an error."""
+    from oobleck_tpu.parallel.train import peak_flops
+
+    assert peak_flops("TPU v5 lite") == 197e12
+    for kind in ("TPU v5 lite pod", "tpu v5 lite", "TPU v9", "cpu"):
+        with pytest.raises(KeyError, match="no peak FLOP/s known"):
+            peak_flops(kind)
 
 
 def test_evaluate(trained_engine):

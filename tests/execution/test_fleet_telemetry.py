@@ -11,6 +11,7 @@ import time
 
 import pytest
 
+from oobleck_tpu.execution import engine as engine_mod
 from oobleck_tpu.obs import telemetry as telemetry_mod
 from oobleck_tpu.obs.goodput import BUCKETS
 from oobleck_tpu.obs.incident import IncidentBuilder
@@ -19,6 +20,25 @@ from oobleck_tpu.utils import chaos as chaos_mod
 from oobleck_tpu.utils import metrics
 
 from tests.execution.test_engine import cache_env, make_engine  # noqa: F401
+
+
+FACTOR = 3.0
+
+
+class _RecordingClock:
+    """The engine module's `time`, with every sleep the engine asks for
+    written down (and still slept): the gray-failure injection is the
+    engine's only sleep, one per slowed step."""
+
+    def __init__(self):
+        self.slept = []
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+    def sleep(self, seconds):
+        self.slept.append(seconds)
+        time.sleep(seconds)
 
 
 class _Pipe:
@@ -38,6 +58,7 @@ def slow_engine(cache_env, devices8):  # noqa: F811
     slow after step 0 (the @1 delay leaves step 0 as the in-run
     baseline). No metrics dir: nothing lands on disk."""
     old_dir = os.environ.pop(metrics.ENV_METRICS_DIR, None)
+    clock = _RecordingClock()
     telemetry_mod.reset()
     eng = make_engine(num_hosts=1, steps=6, devices=devices8[:2],
                       microbatch=2, global_mb=4, agent_ip="10.0.0.0")
@@ -47,11 +68,14 @@ def slow_engine(cache_env, devices8):  # noqa: F811
     # eng.step, so the loop below runs the remaining 4 steps: 3..6).
     for _ in range(2):
         eng._train_step()
+    engine_mod.time = clock
     try:
-        chaos_mod.reset("slow_host=10.0.0.0:3@1")
+        chaos_mod.reset(f"slow_host=10.0.0.0:{FACTOR:g}@1")
         eng.train()
     finally:
         chaos_mod.reset("")
+        engine_mod.time = time
+    eng.injected_sleeps = clock.slept
     yield eng
     if old_dir is not None:
         os.environ[metrics.ENV_METRICS_DIR] = old_dir
@@ -60,12 +84,19 @@ def slow_engine(cache_env, devices8):  # noqa: F811
 def test_gray_failure_is_visible_in_the_telemetry_ring(slow_engine):
     samples = telemetry_mod.telemetry().samples()
     assert [s[0] for s in samples] == [3, 4, 5, 6]  # one per step, in order
-    base, inflated = samples[0][1], [s[1] for s in samples[1:]]
-    assert base > 0
-    # Steps 1-3 ran under the 3x gray failure: every one of them must be
-    # well clear of the baseline (1.5x leaves room for timing noise; the
-    # injection stretches each step by exactly 3x its own measure).
-    assert min(inflated) > 1.5 * base
+    assert samples[0][1] > 0
+    # Step 0 of the run is the baseline and the next three ran under the
+    # 3x gray failure: the engine slept (factor - 1) x each of those steps'
+    # own measure and reports factor x it. So the sample is the recorded
+    # sleep scaled by factor / (factor - 1), to the float, and at least
+    # factor x the compute the pipelines timed inside that step. No two
+    # wall-clock readings are compared.
+    slept = slow_engine.injected_sleeps
+    assert len(slept) == len(samples) - 1
+    for (_, step_s, compute_s, *_), sleep_s in zip(samples[1:], slept):
+        assert sleep_s > 0
+        assert step_s == pytest.approx(sleep_s * FACTOR / (FACTOR - 1.0))
+        assert step_s >= FACTOR * compute_s > 0
     # The injection itself was flight-recorded exactly once (activation
     # is one-shot even though the rule keeps matching).
     # (The recorder is process-wide: other modules' slow_host injections
@@ -74,7 +105,7 @@ def test_gray_failure_is_visible_in_the_telemetry_ring(slow_engine):
             if e["event"] == "chaos_injection"
             and e.get("action") == "slow_host" and e["ip"] == "10.0.0.0"]
     assert len(slow) == 1
-    assert slow[0]["factor"] == pytest.approx(3.0)
+    assert slow[0]["factor"] == pytest.approx(FACTOR)
 
 
 def test_published_snapshot_carries_digest_and_ledger(slow_engine):

@@ -112,3 +112,42 @@ def test_restore_infeasible_without_checkpoint_falls_back(cache_env,
     assert last["mechanism"] == MECH_REINSTANTIATE
     assert last["reason"].startswith("forced:restore:infeasible:")
     assert np.isfinite(eng._train_step())
+
+
+@pytest.mark.parametrize("mode,want", [
+    ("adaptive", [(None, ""), ("reinstantiate", "")]),
+    ("reroute", [("reroute", "forced:reroute"),
+                 ("reinstantiate", "forced:reroute:infeasible:")]),
+    ("reinstantiate", [("reinstantiate", "forced:reinstantiate"),
+                       ("reinstantiate", "forced:reinstantiate")]),
+])
+def test_churn_of_one_loss_then_a_correlated_pair(cache_env, devices8, mode,
+                                                  want):
+    """One host lost, then two at once, on four hosts: no single fixed
+    mechanism fits both. The first is reroute territory, the second rules
+    rerouting out (correlated_failure) and, with no checkpoint, leaves
+    re-instantiation alone: a forced reroute has to fall back, live, with
+    the reason on the record; under every mode the last host trains on."""
+    eng = _live_engine(devices8)
+    eng._policy = PolicyEngine(multihost=False, mode=mode)
+    eng._train_step()
+    got = []
+    for lost in (["10.0.0.3"], ["10.0.0.1", "10.0.0.2"]):
+        before = len(_flight("policy_decision"))
+        for ip in lost:
+            eng.request_reconfiguration(ip)
+        eng._maybe_reconfigure()
+        decisions = _flight("policy_decision")[before:]
+        assert len(decisions) == 1            # one incident, one verdict
+        assert sorted(decisions[0]["lost_ips"]) == lost
+        got.append(decisions[0])
+        assert np.isfinite(eng._train_step())
+    for decision, (mechanism, reason) in zip(got, want):
+        if mechanism is None:                 # the scorer's to choose
+            assert decision["mechanism"] in ("reroute", "reinstantiate")
+        else:
+            assert decision["mechanism"] == mechanism
+        assert decision["reason"].startswith(reason)
+    assert "reroute" not in got[0]["infeasible"]
+    assert got[1]["infeasible"]["reroute"] == "correlated_failure"
+    assert eng.host_ips == ["10.0.0.0"]

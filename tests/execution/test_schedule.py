@@ -5,7 +5,8 @@ pins down: per-(microbatch, chunk) forward-before-backward ordering,
 send/recv matching across neighbor streams, the closed-form warmup/steady/
 cooldown phase structure, exact degeneration of v=1 to the canonical 1F1B
 streams, rejection of invalid (S, M, v), and the dependency-replay bubble
-reproducing the closed forms under the uniform fwd=1/bwd=2 cost model.
+reproducing the closed forms under the uniform fwd=1/bwd=2 cost model,
+with the replay's observed slices adding up to the same bubble.
 """
 
 import pytest
@@ -16,6 +17,7 @@ from oobleck_tpu.execution.schedule import (
     all_instructions,
     bubble_fraction,
     interleaved_warmup,
+    replay_schedule,
     send_activation_dest,
     send_grad_dest,
     simulate_bubble,
@@ -204,3 +206,30 @@ def test_simulated_bubble_tracks_interleaving_gain():
     interleaved schedule strictly below 1F1B for the same (S, M)."""
     for S, M in ((2, 4), (2, 8), (4, 8)):
         assert simulate_bubble(S, M, 2) < simulate_bubble(S, M, 1)
+
+
+@pytest.mark.parametrize("S,M,v", [(2, 8, 1), (2, 8, 2), (4, 8, 1)])
+def test_replayed_gap_matches_simulate_bubble(S, M, v):
+    """What `on_op` observes is the replay itself: every scheduled unit
+    once, no two units of a stage overlapping, and the gap between the
+    observed slices equal to simulate_bubble's."""
+    slices = []
+
+    def on_op(stage, inst, start, end):
+        assert inst.stage == stage
+        slices.append((inst, start, end))
+
+    makespan, busy = replay_schedule(S, M, v, on_op=on_op)
+    # S*v forward + S*v backward units per microbatch
+    assert len(slices) == S * v * M * 2
+    assert len({(i.op, i.stage, i.chunk, i.microbatch)
+                for i, _, _ in slices}) == len(slices)
+    for stage in range(S):
+        own = sorted((start, end) for inst, start, end in slices
+                     if inst.stage == stage)
+        assert all(a_end <= b_start for (_, a_end), (b_start, _)
+                   in zip(own, own[1:]))
+    assert sum(end - start for _, start, end in slices) == pytest.approx(busy)
+    assert max(end for _, _, end in slices) == pytest.approx(makespan)
+    gap = 1.0 - busy / (S * makespan)
+    assert gap == pytest.approx(simulate_bubble(S, M, v), rel=1e-12)

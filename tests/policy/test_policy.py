@@ -349,7 +349,7 @@ def test_forced_grow_arm_wins_and_falls_back_to_absorb():
 
 def test_forced_modes_do_not_cross_directions():
     """A loss-direction forced mode consulted in the GROW direction (and
-    vice versa) degrades to adaptive — a bench forcing `restore` must not
+    vice versa) degrades to adaptive — a run forcing `restore` must not
     wedge the join path, and forcing `grow_dp` must not wedge recovery."""
     from oobleck_tpu.policy import GROW_MODES, MECH_GROW_DP
 

@@ -8,21 +8,23 @@ import asyncio
 
 import pytest
 
-from oobleck_tpu.config import OobleckArguments
 from oobleck_tpu.elastic import journal as journal_mod
-from oobleck_tpu.elastic.master_bench import ScriptedAgent, _start_master
 from oobleck_tpu.elastic.message import (
     JOINED_KEY,
     LEASE_KEY,
     TENANT_KEY,
-    RequestType,
     ResponseType,
-    recv_msg,
-    send_request,
 )
 from oobleck_tpu.pool import arbiter as arbiter_mod
 from oobleck_tpu.policy.engine import DECISION_KEY
 from oobleck_tpu.utils import metrics
+
+from tests.elastic.scripted import (
+    ScriptedAgent,
+    launch_job,
+    pool_rpc,
+    start_master,
+)
 
 AGENTS = ("10.9.0.1", "10.9.0.2", "10.9.0.3")
 
@@ -36,22 +38,9 @@ def pool_env(tmp_path, monkeypatch):
     monkeypatch.setattr(metrics, "_flight", metrics.FlightRecorder())
 
 
-async def pool_rpc(port, payload):
-    r, w = await asyncio.open_connection("127.0.0.1", port)
-    await send_request(w, RequestType.POOL_BORROW, payload)
-    msg = await recv_msg(r)
-    w.close()
-    return msg
-
-
 async def start_fleet():
-    args = OobleckArguments()
-    args.dist.node_ips = list(AGENTS)
-    m, task = await _start_master(0)
-    r, w = await asyncio.open_connection("127.0.0.1", m.port)
-    await send_request(w, RequestType.LAUNCH_JOB, {"args": args.to_dict()})
-    assert (await recv_msg(r))["kind"] == ResponseType.SUCCESS.value
-    w.close()
+    m, task = await start_master()
+    await launch_job(m.port, AGENTS)
     fleet = [ScriptedAgent(ip) for ip in AGENTS]
     for a in fleet:
         await a.register(m.port)

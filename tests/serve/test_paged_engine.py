@@ -96,6 +96,51 @@ def test_pool_exhaustion_is_queue_backpressure(model_and_params):
     b.stop()
 
 
+@pytest.mark.parametrize("page", [4, 8])
+def test_paged_pool_admits_more_than_dense_slots_at_equal_bytes(
+        model_and_params, page):
+    """The paged cache's claim, as a count: a pool of the SAME bytes as
+    `slots x max_seq` dense rows holds a burst of short requests by their
+    true span (8 tokens), not by a max_seq reservation each. One admission
+    pass puts usable_pages // pages_per_request of them in flight where the
+    dense engine holds `slots`, and both finish the whole burst."""
+    model, params = model_and_params
+    slots, span = 2, 8                         # 4 prompt + 4 generated
+    num_pages = slots * MAX_SEQ // page        # the same token budget
+    dense = DecodeEngine(model, slots=slots, max_seq=MAX_SEQ)
+    dense.set_params(dense.stage_params(params), 1)
+    paged = PagedDecodeEngine(model, lanes=num_pages - 1, max_seq=MAX_SEQ,
+                              page_size=page, num_pages=num_pages)
+    paged.set_params(paged.stage_params(params), 1)
+    nbytes = lambda cache: sum(int(x.nbytes) for x in jax.tree.leaves(cache))
+    assert nbytes(paged.cache) == nbytes(dense.cache)
+
+    burst = num_pages + 4                      # oversubscribes both
+    in_flight = {}
+    for name, eng in (("dense", dense), ("paged", paged)):
+        b = ContinuousBatcher(eng, max_queue=burst)  # scheduler NOT started
+        reqs = [b.submit(GenRequest(
+            [1 + (4 * i + j) % 97 for j in range(4)], max_tokens=4))
+            for i in range(burst)]
+        b._admit()
+        in_flight[name] = b.slots_active
+        for _ in range(50 * burst):
+            if all(r.done.is_set() for r in reqs):
+                break
+            b._admit()
+            if b.slots_active:
+                b._decode_step()
+            assert b.slots_active <= in_flight[name]
+        assert all(r.finish_reason == "length" and len(r.out_tokens) == 4
+                   for r in reqs)
+        b.stop()
+    assert in_flight["dense"] == slots
+    usable = num_pages - 1                     # page 0 is the garbage page
+    assert in_flight["paged"] == usable // (span // page)
+    assert in_flight["paged"] > in_flight["dense"]
+    assert paged.allocator.free_pages == usable
+
+
 def test_hot_reload_mid_decode_keeps_block_tables(model_and_params):
     """Weights swap at the decode-step barrier while a paged request is
     mid-generation: the request keeps its pages and finishes under the new

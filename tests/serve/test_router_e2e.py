@@ -18,6 +18,7 @@ import threading
 import time
 
 import jax
+import pytest
 
 from oobleck_tpu.config import ServeArguments
 from oobleck_tpu.models import build_model
@@ -51,6 +52,14 @@ def _get(port, path):
     return resp.status, out
 
 
+def _wait_routable(router, n):
+    """Self-registration is async: wait until the router can route to n."""
+    deadline = time.monotonic() + 30
+    while len(router.registry.routable()[0]) < n:
+        assert time.monotonic() < deadline, "replicas never all registered"
+        time.sleep(0.05)
+
+
 def test_three_replicas_one_router_kill_and_reload_mid_traffic(
         tmp_path, monkeypatch):
     monkeypatch.setenv("OOBLECK_METRICS_DIR", str(tmp_path / "obs"))
@@ -70,16 +79,7 @@ def test_three_replicas_one_router_kill_and_reload_mid_traffic(
     try:
         for p in planes:
             p.start()
-        # Self-registration is async; wait until the router can route
-        # to all three.
-        deadline = time.monotonic() + 30
-        while time.monotonic() < deadline:
-            fresh, _ = router.registry.routable()
-            if len(fresh) == 3:
-                break
-            time.sleep(0.05)
-        else:
-            raise AssertionError("replicas never all registered")
+        _wait_routable(router, 3)
         _, health = _get(router.port, "/healthz")
         assert health["replicas"] == 3 and health["fleet_weights_step"] == 1
 
@@ -157,6 +157,62 @@ def test_three_replicas_one_router_kill_and_reload_mid_traffic(
         status, out = _post(router.port, {"tokens": head,
                                           "max_tokens": 4})
         assert status == 200 and out["routed_to"] != victim_key
+    finally:
+        chaos_mod.reset("")
+        for p in planes:
+            p.stop()
+        router.stop()
+
+
+@pytest.mark.parametrize("affinity", [True, False])
+def test_prefix_hits_follow_the_routing(tmp_path, affinity):
+    """Why affinity exists, counted on real engines: a request hits the
+    prefix cache exactly when the replica it was routed to served that
+    prompt head before. With affinity every repeat of a head goes back to
+    the replica that holds it, so every repeat is a hit; with affinity off
+    the router balances by load, and the fleet earns only the hits its
+    routing happens to give."""
+    model = build_model(MODEL, MODEL_ARGS)
+    params = model.init_params(jax.random.PRNGKey(0))
+    root = tmp_path / "ckpt"
+    publish_params(root, model, params, step=1,
+                   model_name=MODEL, model_args=MODEL_ARGS)
+    router = RouterPlane(host="127.0.0.1", probe_s=0.1, seed=0,
+                         affinity=affinity).start()
+    planes = [ServingPlane(
+        root,
+        args=ServeArguments(port=0, slots=2, max_seq=64, reload_secs=5.0,
+                            page_size=PAGE, kv_pages=64, lanes=2),
+        router_url=f"127.0.0.1:{router.port}") for _ in range(2)]
+    chaos_mod.reset("")
+    hits = metrics.registry().counter("oobleck_serve_prefix_hits_total", "")
+    heads = [[(h * 31 + j) % 199 + 1 for j in range(2 * PAGE)]
+             for h in range(5)]
+    rounds = 3
+    try:
+        for p in planes:
+            p.start()
+        _wait_routable(router, 2)
+        hits0 = hits.value()
+        served = {}                    # head index -> replicas that hold it
+        expected = repeats = 0
+        for r in range(rounds):
+            for h, head in enumerate(heads):
+                status, out = _post(router.port, {
+                    "tokens": head + [r + 1], "max_tokens": 2,
+                    "temperature": 0.0})
+                assert status == 200, out
+                assert out["route_reason"] == (
+                    "affine" if affinity else "balanced")
+                holders = served.setdefault(h, set())
+                expected += out["routed_to"] in holders
+                repeats += r > 0
+                holders.add(out["routed_to"])
+        assert hits.value() - hits0 == expected
+        assert expected <= repeats == (rounds - 1) * len(heads)
+        if affinity:
+            assert all(len(holders) == 1 for holders in served.values())
+            assert expected == repeats
     finally:
         chaos_mod.reset("")
         for p in planes:

@@ -69,8 +69,11 @@ def _mk_batcher(model, params, *, mode, k=4, lanes=2, num_pages=64,
 
 
 def _drive(b, reqs, max_iters=400):
+    """Runs the scheduler by hand until every request is done; returns the
+    number of decode / verify steps it took."""
     for r in reqs:
         b.submit(r)
+    steps = 0
     for _ in range(max_iters):
         b._admit()
         if b.slots_active:
@@ -78,8 +81,9 @@ def _drive(b, reqs, max_iters=400):
                 b._spec_step()
             else:
                 b._decode_step()
+            steps += 1
         if all(r.done.is_set() for r in reqs):
-            return
+            return steps
     raise AssertionError("requests did not finish")
 
 
@@ -164,6 +168,34 @@ def test_spec_parity_with_model_drafter(model_and_params):
     assert drafted > 0
     # Self-drafting is always right: every drafted token accepted.
     assert b.spec.m_accepted.value() == drafted
+
+
+@pytest.mark.parametrize("mode", ["draft", "lookup"])
+def test_verify_steps_count_what_drafts_saved(model_and_params, mode):
+    """What speculation buys, as counts: after the prefill's token every
+    verify step emits one token plus the drafts it accepted, so steps +
+    accepted is the k = 0 run's step count at the same output; perfect
+    drafts take ceil(n / (k + 1)) steps, and no drafter takes more steps
+    than decoding one token at a time."""
+    model, params = model_and_params
+    n_new, k = 24, 4
+    b_off = _mk_batcher(model, params, mode="off")
+    off = GenRequest(list(PROMPT), max_tokens=n_new)
+    steps_off = _drive(b_off, [off])
+    assert steps_off == n_new - 1          # the prefill gave the first
+
+    drafter = ModelDrafter(model, params) if mode == "draft" else None
+    b = _mk_batcher(model, params, mode=mode, k=k, drafter=drafter)
+    on = GenRequest(list(PROMPT), max_tokens=n_new)
+    steps = _drive(b, [on])
+    assert on.out_tokens == off.out_tokens
+    accepted = int(b.spec.m_accepted.value())
+    assert steps + accepted == steps_off
+    assert steps <= steps_off
+    if mode == "draft":
+        assert accepted == int(b.spec.m_drafted.value())
+        assert steps == -(-(n_new - 1) // (k + 1))
+        assert b.spec.m_rollbacks.value() == 0
 
 
 def test_spec_parity_on_prefix_cache_hit(model_and_params):

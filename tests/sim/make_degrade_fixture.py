@@ -1,12 +1,14 @@
 """Regenerate the recorded degrade-bench corpus fixture.
 
-Runs the degrade bench's reroute arm (the REAL engine on the 2-host CPU
-rig — see oobleck_tpu/degrade/bench.py for the rig's documentation) with
-a longer measurement window, and commits what a production incident
-leaves behind: the flight-recorder ring (including the engine's own
+Runs a reroute on the REAL engine (two identical 2-stage pipelines, one
+per host, on 4 virtual CPU devices; host 1 is lost and the survivor
+absorbs its microbatches on the same topology: no re-plan, no state
+movement, no recompile) and commits what a production incident leaves
+behind: the flight-recorder ring (including the engine's own
 ``degrade_decision``), an ``incident-0.json`` built by the real
 IncidentBuilder with wall-clock marks from the measured recovery, and a
-``degrade-bench.json`` summary. The incident's attrs additionally freeze
+``degrade-bench.json`` summary. The numbers are a CPU rig's, kept only as
+the simulator's cross-validation ground truth; none is a device metric. The incident's attrs additionally freeze
 the rig shape, calibrated per-op durations, and the measured step
 timings — which is exactly what ``sim.slo.replay_incident`` needs to
 cross-validate the simulator against this measurement.
@@ -48,10 +50,62 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 
+_MODEL_ARGS = {"hidden_size": 128, "num_layers": 8,
+               "max_position_embeddings": 64}
+
+
+def _make_engine():
+    import jax
+
+    from oobleck_tpu.config import (
+        DistributedArguments,
+        JobArguments,
+        ModelArguments,
+        OobleckArguments,
+    )
+    from oobleck_tpu.execution.engine import OobleckEngine
+
+    hosts = ["10.0.0.0", "10.0.0.1"]
+    args = OobleckArguments(
+        dist=DistributedArguments(node_ips=hosts),
+        job=JobArguments(
+            microbatch_size=1,
+            global_microbatch_size=8,
+            steps=64,
+            learning_rate=1e-3,
+            warmup_steps=2,
+        ),
+        model=ModelArguments(
+            model_name="gpt2-tiny", dataset_path="synthetic",
+            model_tag="degrade-bench",  # own profile cache: non-default args
+            model_args=dict(_MODEL_ARGS),
+        ),
+    )
+    args.execution.degrade_enabled = True
+    args.execution.precompile_recovery_depth = 0  # mechanism cost, not warmth
+    args.execution.eval_fraction = 0.0
+    engine = OobleckEngine(args, devices=jax.devices()[:2 * len(hosts)])
+    engine.initialize_distributed()
+    engine.instantiate_pipelines(args.job.global_num_microbatch)
+    return engine
+
+
+def _steps(engine, n: int) -> None:
+    for _ in range(n):
+        engine._train_step()
+
+
+def _recover_and_step(engine, lost_ip: str) -> float:
+    """Failure-to-next-step latency: reconfigure + the first step after."""
+    t0 = time.perf_counter()
+    engine.reconfigure(lost_ip)
+    engine._train_step()
+    return time.perf_counter() - t0
+
+
 def _median_step_s(eng, n: int) -> float:
-    """Median wall-clock seconds per step over n individually timed steps
-    — the bench's mean (_steps) is fine on quiet hardware, but one
-    scheduler hiccup in the mean corrupts a fixture forever."""
+    """Median wall-clock seconds per step over n individually timed steps:
+    one scheduler hiccup in a mean would corrupt a fixture forever."""
     samples = []
     for _ in range(n):
         t0 = time.perf_counter()
@@ -68,13 +122,12 @@ def main() -> int:
     os.makedirs(out_dir)
     os.environ["OOBLECK_METRICS_DIR"] = out_dir
 
-    from oobleck_tpu.degrade.bench import _make_engine, _recover_and_step, _steps
     from oobleck_tpu.degrade.classify import classify_failure
     from oobleck_tpu.degrade.planner import PipelineSpec, plan_reroute
     from oobleck_tpu.obs.incident import IncidentBuilder
     from oobleck_tpu.utils import metrics
 
-    eng = _make_engine(degrade_enabled=True)
+    eng = _make_engine()
     assert len(eng.pipelines) == 2, [p.ranks for p in eng.pipelines]
     _steps(eng, WARMUP_STEPS)
 
@@ -125,9 +178,9 @@ def main() -> int:
         "post_reroute_step_s": round(post_step_s, 6),
         "recovery_to_next_step_s": round(recovery_s, 6),
         "reconfigure_s": round(reconfigure_s, 6),
-        # Bench formula: the survivor's step cost after absorbing the dead
-        # replica's microbatches vs its pre-failure share (half the
-        # serialized two-replica step on this homogeneous rig).
+        # The survivor's step cost after absorbing the dead replica's
+        # microbatches vs its pre-failure share (half the serialized
+        # two-replica step on this homogeneous rig).
         "survivor_slowdown_measured": round(post_step_s / (pre_step_s / 2), 6),
         "survivor_slowdown_projected": round(1.0 / retention_projected, 6),
         "throughput_retention_projected": round(retention_projected, 6),

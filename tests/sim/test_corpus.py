@@ -129,19 +129,6 @@ def test_latency_samples_standalone_flight_counts(tmp_path):
     assert load_corpus(d).latency_samples() == {"restore": [30.0]}
 
 
-def test_bench_round_samples(tmp_path):
-    d = str(tmp_path)
-    _write(d, "BENCH_r3.json", {"n": 3, "parsed": {"degrade": {
-        "reroute": {"recovery_to_next_step_s": 0.61},
-        "reinstantiate_inplace": {"recovery_to_next_step_s": 0.72},
-    }}})
-    corpus = load_corpus(d)
-    assert corpus.bench_rounds[0].round_n == 3
-    samples = corpus.latency_samples()
-    assert samples["reroute"] == [0.61]
-    assert samples["reinstantiate"] == [0.72]
-
-
 def test_stats_shape(tmp_path):
     d = str(tmp_path)
     _write(d, "incident-0.json", _incident("t0", flight=[

@@ -1,5 +1,6 @@
 """Determinism contract: same seed + corpus -> byte-identical SLO report,
-at the 1024-host scale the acceptance bar names, in well under a minute."""
+for every scenario family, and at the 1024-host scale the acceptance bar
+names in well under a minute."""
 
 from __future__ import annotations
 
@@ -10,7 +11,7 @@ import pytest
 
 from oobleck_tpu.sim import slo
 from oobleck_tpu.sim.cluster import SimCluster, SimConfig
-from oobleck_tpu.sim.scenarios import make_scenario
+from oobleck_tpu.sim.scenarios import GENERATORS, make_scenario
 from oobleck_tpu.utils import metrics
 
 
@@ -38,6 +39,32 @@ def test_1024_host_churn_storm_byte_identical_and_fast():
     report = json.loads(a)
     assert report["incidents"] > 50
     assert report["recovery"]["p99_s"] is not None
+
+
+@pytest.mark.parametrize("family", sorted(GENERATORS))
+def test_every_family_simulates_to_one_report(family, monkeypatch):
+    """Each scenario family, at 64 hosts, through the real classify /
+    plan / policy chain from fresh state, twice: one canonical render, and
+    a report whose counts add up (every incident got exactly one
+    mechanism; the oracle judged every one of them)."""
+    def render():
+        monkeypatch.setattr(metrics, "_registry", metrics.Registry())
+        scenario = make_scenario(family, seed=1117, hosts=64,
+                                 duration_s=600.0)
+        return slo.render(slo.slo_report(
+            SimCluster(SimConfig(hosts=64), scenario).run()))
+
+    first = render()
+    assert first == render()
+    report = json.loads(first)
+    assert report["scenario"]["hosts"] == 64
+    assert report["incidents"] >= 1
+    assert sum(report["mechanisms"].values()) == report["incidents"]
+    assert 0.0 < report["goodput_ratio"] <= 1.0
+    assert 0.0 <= report["regret"]["oracle_agreement"] <= 1.0
+    assert report["regret"]["mean_s"] >= 0.0
+    assert report["recovery"]["p99_s"] >= report["recovery"]["p50_s"] > 0.0
+    assert ("pool" in report) == (family == "shared_pool")
 
 
 def test_different_seed_different_report():

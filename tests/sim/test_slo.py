@@ -1,10 +1,9 @@
-"""SLO reducer: percentiles, regret accounting, and the bench suite."""
+"""SLO reducer: percentiles and regret accounting."""
 
 from __future__ import annotations
 
 import pytest
 
-from oobleck_tpu.sim import bench as sim_bench
 from oobleck_tpu.sim import slo
 from oobleck_tpu.utils import metrics
 
@@ -90,15 +89,3 @@ def test_render_is_canonical():
     s = slo.render(report)
     assert s == slo.render(slo.slo_report(_run([])))
     assert "\n" not in s and ": " not in s
-
-
-def test_bench_one_summary_shape():
-    summary, render = sim_bench._one("smoke", "churn_storm", 16, 120.0, 3,
-                                     {})
-    assert set(summary) == {"incidents", "recovery_p99_s", "goodput_ratio",
-                            "regret_mean_s", "oracle_agreement",
-                            "elapsed_s"}
-    import json
-
-    parsed = json.loads(render)
-    assert parsed["scenario"]["hosts"] == 16
