@@ -23,6 +23,10 @@ single-controller JAX runtime:
     FORWARD instruction only stashes the input. A one-stage pipeline
     therefore shows no `jit_fwd` while it trains; `eval_step` still runs
     the forward-only program;
+  * microbatch gradients accumulate inside `jit_bwd`: a chunk's running
+    sum is a donated operand that comes back with the microbatch's
+    gradients added, in its own buffers. A step holds one gradient set per
+    chunk and dispatches no program that only adds;
   * compiled stage executables are cached by stage signature so
     re-instantiation after a failure reuses them — the pre-compile-per-
     template idea from SURVEY §7.3.1.
@@ -134,10 +138,12 @@ def _project_spec(spec: P, keep: frozenset) -> P:
     return P(*out)
 
 
-def grad_add(a, b):
-    """Microbatch gradient accumulation; the name is the XLA module's
-    (`jit_grad_add` in a device trace)."""
-    return jax.tree.map(jnp.add, a, b)
+def grad_zero(params_tuple):
+    """A chunk's zero-filled gradient sum, one per step: what the first
+    microbatch's backward accumulates into (`jit_grad_zero` in a device
+    trace). The parameters give the tree, shapes and dtypes and are not
+    read: jit drops them from the program's operands."""
+    return jax.tree.map(jnp.zeros_like, params_tuple)
 
 
 def make_optimizer_update(optimizer):
@@ -176,6 +182,7 @@ class StageRuntime:
     fwd: list[Callable | None] = field(default_factory=list)   # per chunk
     bwd: list[Callable | None] = field(default_factory=list)   # per chunk
     efwd: list[Callable | None] = field(default_factory=list)  # eval fwd w/ metrics
+    zero: list[Callable | None] = field(default_factory=list)  # gradient-sum fill
 
     @property
     def ctx(self):
@@ -683,12 +690,20 @@ class PipelineInstance:
         Under canonical 1F1B each stage has exactly one chunk and the cache
         key is the stage signature as before.
 
-        Every other chunk's `bwd(params, x, batch, dy)` returns (grads, dx).
-        The last virtual stage's `bwd(params, x, batch)` is the loss's
-        value-and-gradient and returns (loss, grads, dx): the unscaled
-        microbatch loss its `fwd` would give, gradients of loss / total
-        microbatches. train_step never calls that chunk's `fwd` (eval_step
-        does)."""
+        Microbatch gradients accumulate INSIDE the backward program: `acc`,
+        the chunk's running gradient sum (the parameters' tree, dtypes and
+        shardings), is a donated operand and `acc + grads` comes back in its
+        buffers, so a step holds one gradient set per chunk and no separate
+        add runs. `zero(params)` fills the sum the step's first microbatch
+        adds to (0 + g is g): one backward program per chunk, whichever
+        microbatch.
+
+        Every other chunk's `bwd(params, acc, x, batch, dy)` returns
+        (acc + grads, dx). The last virtual stage's `bwd(params, acc, x,
+        batch)` is the loss's value-and-gradient and returns (loss, acc +
+        grads, dx): the unscaled microbatch loss its `fwd` would give,
+        gradients of loss / total microbatches. train_step never calls that
+        chunk's `fwd` (eval_step does)."""
         S, v = self.num_stages, self.virtual_stages
         last_vs = S * v - 1
         scale = 1.0 / self.total_num_microbatches
@@ -696,6 +711,7 @@ class PipelineInstance:
             st.fwd = [None] * len(st.chunks)
             st.bwd = [None] * len(st.chunks)
             st.efwd = [None] * len(st.chunks)
+            st.zero = [None] * len(st.chunks)
             if not st.is_local:
                 continue
             for c, chunk_layers in enumerate(st.chunks):
@@ -708,9 +724,14 @@ class PipelineInstance:
                     self.total_num_microbatches, st.tp, st.sp, st.use_fsdp,
                 )
                 if key in self._exec_cache:
-                    st.fwd[c], st.bwd[c], st.efwd[c] = self._exec_cache[key]
+                    (st.fwd[c], st.bwd[c], st.efwd[c],
+                     st.zero[c]) = self._exec_cache[key]
                     continue
                 apply = self._stage_apply(st, chunk_layers)
+                # The sum leaves each program where it came in: the
+                # parameters' own shardings, or the donation does not take.
+                acc_shardings = tuple(
+                    st.param_shardings[li] for li in chunk_layers)
 
                 def fwd(params_tuple, x, tokens, _apply=apply):
                     return _apply(params_tuple, x, tokens)
@@ -719,7 +740,7 @@ class PipelineInstance:
                     # The loss and d(loss·scale)/d(params, x) from one
                     # forward: the loss rides out as the aux value,
                     # unscaled, exactly what `fwd` returns.
-                    def bwd(params_tuple, x, tokens, _apply=apply):
+                    def bwd(params_tuple, acc, x, tokens, _apply=apply):
                         def loss_fn(pt, x_):
                             loss = _apply(pt, x_, tokens)
                             return loss * scale, loss
@@ -728,28 +749,35 @@ class PipelineInstance:
                             (_, loss), grads = jax.value_and_grad(
                                 lambda pt: loss_fn(pt, None),
                                 has_aux=True)(params_tuple)
-                            return loss, grads, None
-                        (_, loss), (grads, dx) = jax.value_and_grad(
-                            loss_fn, argnums=(0, 1),
-                            has_aux=True)(params_tuple, x)
-                        return loss, grads, dx
+                            dx = None
+                        else:
+                            (_, loss), (grads, dx) = jax.value_and_grad(
+                                loss_fn, argnums=(0, 1),
+                                has_aux=True)(params_tuple, x)
+                        return loss, jax.tree.map(jnp.add, acc, grads), dx
+
+                    out_shardings = (None, acc_shardings, None)
                 else:
-                    def bwd(params_tuple, x, tokens, dy, _apply=apply):
+                    def bwd(params_tuple, acc, x, tokens, dy, _apply=apply):
                         if x is None:
                             # First chunk: differentiate wrt params only.
                             _, vjp = jax.vjp(
                                 lambda pt: _apply(pt, None, tokens),
                                 params_tuple)
-                            (grads,) = vjp(dy)
-                            return grads, None
-                        _, vjp = jax.vjp(
-                            lambda pt, x_: _apply(pt, x_, tokens),
-                            params_tuple, x)
-                        grads, dx = vjp(dy)
-                        return grads, dx
+                            (grads,), dx = vjp(dy), None
+                        else:
+                            _, vjp = jax.vjp(
+                                lambda pt, x_: _apply(pt, x_, tokens),
+                                params_tuple, x)
+                            grads, dx = vjp(dy)
+                        return jax.tree.map(jnp.add, acc, grads), dx
+
+                    out_shardings = (acc_shardings, None)
 
                 st.fwd[c] = jax.jit(fwd)
-                st.bwd[c] = jax.jit(bwd)
+                st.bwd[c] = jax.jit(bwd, donate_argnums=1,
+                                    out_shardings=out_shardings)
+                st.zero[c] = jax.jit(grad_zero, out_shardings=acc_shardings)
                 if (is_last and st.ctx is None
                         and hasattr(self.model, "accuracy_from_logits")):
                     def eval_fwd(params_tuple, x, tokens, _apply=apply):
@@ -757,7 +785,8 @@ class PipelineInstance:
                                       with_metrics=True)
 
                     st.efwd[c] = jax.jit(eval_fwd)
-                self._exec_cache[key] = (st.fwd[c], st.bwd[c], st.efwd[c])
+                self._exec_cache[key] = (
+                    st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c])
 
     # ------------------------------------------------------------------ #
 
@@ -861,6 +890,9 @@ class PipelineInstance:
         v = self.virtual_stages
         last_vs = S * v - 1
         assert next(iter(batch.values())).shape[0] == M
+        # Last step's gradients have been applied: free them before this
+        # step's sums are allocated.
+        self.grads = {}
         if placed is None:
             # No DeviceStager staged this batch ahead of time
             # (execution/dataloader.py) — place on the critical path.
@@ -884,6 +916,9 @@ class PipelineInstance:
         # chunk's forward program, "folded" left it to the backward's
         # value-and-gradient (the last virtual stage).
         fwd_dispatches = {"run": 0, "folded": 0}
+        # Gradient sums of local chunks: started from a zero fill, added
+        # to inside a backward program.
+        accumulated = {"backward": 0, "zero_fill": 0}
 
         def record_op(stage, chunk, kind, dt):
             tot, n = op_times.get((stage, chunk, kind), (0.0, 0))
@@ -921,26 +956,6 @@ class PipelineInstance:
                 store[key] = mv
             pending_sends.clear()
             dispatch_stall += time.perf_counter() - t0
-
-        # Microbatch gradient accumulation as ONE jitted add per stage per
-        # microbatch (jit specializes per treedef/shape/sharding): eager
-        # per-leaf jnp.add over multi-chip-sharded stages is a dispatch
-        # storm — same disease the jitted optimizer update cures, observed
-        # as the round-5 elastic-MoE recovery "hang".
-        add_fn = self._exec_cache.get("grad_add")
-        if add_fn is None:
-            add_fn = jax.jit(grad_add)
-            self._exec_cache["grad_add"] = add_fn
-
-        def accumulate(chunk_layers, stage_grads):
-            if chunk_layers[0] in grads:
-                prev = tuple(grads[li] for li in chunk_layers)
-                summed = add_fn(prev, tuple(stage_grads))
-                for li, g in zip(chunk_layers, summed):
-                    grads[li] = g
-            else:
-                for li, g in zip(chunk_layers, stage_grads):
-                    grads[li] = g
 
         def execute(ins: Instruction) -> None:
             st = self.stages[ins.stage]
@@ -1020,19 +1035,29 @@ class PipelineInstance:
                     if dy_wait is not None:
                         # oobleck: allow[OBL002] -- opt-in per-op profiling mode
                         jax.block_until_ready(dy_wait)
+                # The chunk's running sum goes in donated and comes back
+                # with this microbatch's gradients added; the step's first
+                # BACKWARD of a chunk starts it from zeros, so every
+                # microbatch runs the same program.
+                chunk_layers, params = st.chunks[c], chunk_params(st, c)
+                if chunk_layers[0] in grads:
+                    acc = tuple(grads.pop(li) for li in chunk_layers)
+                else:
+                    acc = st.zero[c](params)
+                    accumulated["zero_fill"] += 1
                 t0 = time.perf_counter()
                 if is_last:
-                    loss, stage_grads, dx = st.bwd[c](
-                        chunk_params(st, c), x, mb)
+                    loss, acc, dx = st.bwd[c](params, acc, x, mb)
                     losses.append(loss)
                 else:
                     dy = gacts.pop(key)
-                    stage_grads, dx = st.bwd[c](chunk_params(st, c), x, mb, dy)
+                    acc, dx = st.bwd[c](params, acc, x, mb, dy)
                 if self.sync_op_timing:
                     # oobleck: allow[OBL002] -- opt-in per-op profiling mode
-                    jax.block_until_ready(stage_grads)
+                    jax.block_until_ready(acc)
                 record_op(ins.stage, c, "b", time.perf_counter() - t0)
-                accumulate(st.chunks[c], stage_grads)
+                grads.update(zip(chunk_layers, acc))
+                accumulated["backward"] += 1
                 if dx is not None:
                     stash[(ins.stage, c, m, "dx")] = dx
                 acts.pop(key, None)
@@ -1088,6 +1113,13 @@ class PipelineInstance:
         for mode, n in fwd_dispatches.items():
             if n:
                 dispatches.inc(n, mode=mode)
+        accumulations = metrics.registry().counter(
+            "oobleck_pipeline_grad_accumulations_total",
+            "Microbatch gradient sums of local pipeline chunks: added to "
+            "inside a backward program, or started from a zero fill")
+        for where, n in accumulated.items():
+            if n:
+                accumulations.inc(n, where=where)
         if not losses:
             return None  # last stage lives on another process
         loss = sum(losses[1:], start=losses[0]) / len(losses)

@@ -19,9 +19,9 @@ RecoveryPrecompiler closes that gap. On a background thread it
      (`materialize_params=False`: meshes, shardings and jitted stage fns
      only — no arrays, no optimizer state);
   3. AOT-lowers and compiles every process-local stage executable
-     (fwd/bwd/efwd, plus best-effort grad-accumulate and optimizer-update
-     programs) against abstract inputs carrying the exact shardings the
-     live path will dispatch with.
+     (fwd/bwd/efwd, plus best-effort gradient-sum fill and
+     optimizer-update programs) against abstract inputs carrying the exact
+     shardings the live path will dispatch with.
 
 Warmth propagates through two layers:
 
@@ -56,7 +56,7 @@ from typing import Any
 import jax
 import numpy as np
 
-from oobleck_tpu.execution.pipeline import grad_add, make_optimizer_update
+from oobleck_tpu.execution.pipeline import make_optimizer_update
 from oobleck_tpu.utils import background
 
 logger = logging.getLogger("oobleck.precompile")
@@ -386,44 +386,35 @@ class RecoveryPrecompiler:
         if not is_last or st.efwd[c] is None:
             st.fwd[c].lower(params_avals, x_aval, mb_aval).compile()
             self.stats["stages_compiled"] += 1
+        # The running gradient sum `bwd` takes (donated) and returns has
+        # the parameters' avals: tree, shapes, dtypes, shardings.
         if is_last:
-            st.bwd[c].lower(params_avals, x_aval, mb_aval).compile()
+            st.bwd[c].lower(
+                params_avals, params_avals, x_aval, mb_aval).compile()
         else:
             dy_aval = jax.tree.map(
                 lambda a: _sds(a, st.batch_sharding),
                 pipe._edge_aval(chunk_layers[-1]),
             )
-            st.bwd[c].lower(params_avals, x_aval, mb_aval, dy_aval).compile()
+            st.bwd[c].lower(
+                params_avals, params_avals, x_aval, mb_aval, dy_aval
+            ).compile()
         self.stats["stages_compiled"] += 1
         if st.efwd[c] is not None:
             st.efwd[c].lower(params_avals, x_aval, mb_aval).compile()
             self.stats["stages_compiled"] += 1
 
         # Aux programs, best-effort (small next to a stage fwd+bwd, but the
-        # MoE recovery hang showed eager fallbacks here are not free):
-        # microbatch grad accumulation and the per-layer optimizer update.
+        # MoE recovery hang showed eager fallbacks here are not free): the
+        # chunk's gradient-sum fill and the per-layer optimizer update.
         try:
-            self._aot_grad_add(params_avals)
+            st.zero[c].lower(params_avals).compile()
+            self.stats["aux_compiled"] += 1
             self._aot_opt_update(chunk_layers, st, params_avals)
         except Exception:
             self.stats["errors"] += 1
             logger.debug("aux AOT warm failed for stage %d chunk %d",
                          st.stage_index, c, exc_info=True)
-
-    def _aot_grad_add(self, params_avals) -> None:
-        cache = self.engine._exec_cache
-        add_fn = cache.get("grad_add")
-        if add_fn is None:
-            # Same program train_step builds on first use; registering it
-            # here means the live path cache-hits this jit object too.
-            add_fn = jax.jit(grad_add)
-            cache["grad_add"] = add_fn
-        key = ("grad_add", tuple(str(a) for a in jax.tree.leaves(params_avals)))
-        if key in self._done_keys:
-            return
-        add_fn.lower(params_avals, params_avals).compile()
-        self._done_keys.add(key)
-        self.stats["aux_compiled"] += 1
 
     def _aot_opt_update(self, layer_ids, st, params_avals) -> None:
         import optax

@@ -486,3 +486,146 @@ def test_last_op_times_keeps_f_and_b_for_every_chunk(folded_step):
         return total / n
 
     assert 0.0 <= simulate_bubble(S, NUM_MB, v, dur) < 1.0
+
+
+# --------------------------------------------------------------------- #
+# microbatch gradients accumulate inside the backward program
+
+
+ACC_CASES = {
+    # name: (layer splits, chips per stage, virtual stages)
+    "S1": ([(0, 6)], [1], 1),
+    "S2": ([(0, 3), (3, 6)], [1, 1], 1),
+    "S2v2": ([(0, 3), (3, 6)], [1, 1], 2),
+    # fsdp over two chips a stage: the sums are sharded like the parameters
+    # and the gradients leave a shard_map before the add.
+    "S2-manual": ([(0, 3), (3, 6)], [2, 2], 1),
+}
+
+
+def _accumulations():
+    from oobleck_tpu.utils import metrics
+
+    c = metrics.registry().counter("oobleck_pipeline_grad_accumulations_total")
+    return {w: c.value(where=w) for w in ("backward", "zero_fill")}
+
+
+@pytest.fixture(scope="module", params=list(ACC_CASES))
+def accumulated(request, model, batch, devices8):
+    """Two train_steps on the same batch and parameters. In the first,
+    every BACKWARD's program also runs on a fresh zero sum, which gives that
+    microbatch's own gradients (0 + g) from the very program under test."""
+    import warnings
+
+    splits, chips, v = ACC_CASES[request.param]
+    template = make_template(splits, chips, chips_per_host=chips[0])
+    pipe = _make_pipe(model, devices8, template, v)
+    own: dict[tuple[int, ...], list] = {}   # chunk layers -> per microbatch
+
+    def recording(st, c):
+        real, layers = st.bwd[c], st.chunks[c]
+
+        def bwd(params, acc, *rest):
+            alone = real(params, st.zero[c](params), *rest)
+            own.setdefault(layers, []).append(
+                jax.tree.map(np.asarray, alone[-2]))
+            return real(params, acc, *rest)
+
+        return bwd
+
+    real_bwds = {}
+    for st in pipe.stages:
+        for c in range(len(st.chunks)):
+            real_bwds[(st.stage_index, c)] = st.bwd[c]
+            st.bwd[c] = recording(st, c)
+    before = _accumulations()
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        pipe.train_step(batch)
+    after_first = _accumulations()
+    for st in pipe.stages:
+        for c in range(len(st.chunks)):
+            st.bwd[c] = real_bwds[(st.stage_index, c)]
+    kept = pipe.grads          # held as the DP engine holds it
+    first = jax.tree.map(np.asarray, kept)
+    pipe.train_step(batch)
+    after_second = _accumulations()
+    return {
+        "pipe": pipe, "chunks": len(splits) * v, "own": own, "first": first,
+        "second": jax.tree.map(np.asarray, pipe.grads),
+        "kept_after_second": jax.tree.map(np.asarray, kept),
+        "counted": [{w: b[w] - a[w] for w in a} for a, b in (
+            (before, after_first), (after_first, after_second))],
+        "warnings": [str(w.message) for w in caught],
+    }
+
+
+def _close(a, b):
+    # Float32 sums of the same numbers in the same order: the only room is
+    # the compiler's (an add fused into a product may round once, not twice).
+    np.testing.assert_allclose(a, b, rtol=2e-6, atol=1e-9)
+
+
+def test_grads_are_the_ordered_sum_of_each_microbatchs_own(accumulated):
+    own, got = accumulated["own"], accumulated["first"]
+    assert sorted(li for layers in own for li in layers) == sorted(got)
+    for layers, per_mb in own.items():
+        assert len(per_mb) == NUM_MB
+        total = per_mb[0]
+        for g in per_mb[1:]:
+            total = jax.tree.map(np.add, total, g)
+        for li, want in zip(layers, total):
+            jax.tree.map(_close, got[li], want)
+
+
+def test_grads_match_the_separate_add_program(accumulated):
+    """What the step gave before the sum moved into `bwd`: the first
+    microbatch's gradients as they are, then one jitted tree add a
+    microbatch."""
+    separate_add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
+    got = accumulated["first"]
+    for layers, per_mb in accumulated["own"].items():
+        total = per_mb[0]
+        for g in per_mb[1:]:
+            total = separate_add(total, g)
+        for li, want in zip(layers, total):
+            jax.tree.map(_close, got[li], jax.tree.map(np.asarray, want))
+
+
+def test_second_step_holds_only_its_own_gradients(accumulated):
+    """Same batch, same parameters: the second step's sums start from
+    zeros again, and what a reader kept of the first step is not touched
+    (only a step's own running sum is ever donated)."""
+    first, second = accumulated["first"], accumulated["second"]
+    assert sorted(first) == sorted(second)
+    for li in first:
+        jax.tree.map(np.testing.assert_array_equal, second[li], first[li])
+        jax.tree.map(np.testing.assert_array_equal,
+                     accumulated["kept_after_second"][li], first[li])
+    assert any(float(np.abs(leaf).max()) > 0
+               for li in first for leaf in jax.tree.leaves(first[li]))
+
+
+def test_grad_accumulation_counter(accumulated):
+    """A step: every BACKWARD adds into its chunk's sum, and each chunk's
+    sum is filled with zeros once. (The recording first step ran no extra
+    instruction: its second program call is the test's own.)"""
+    chunks = accumulated["chunks"]
+    assert accumulated["counted"] == [
+        {"backward": NUM_MB * chunks, "zero_fill": chunks}] * 2
+
+
+def test_donated_sum_is_taken(accumulated):
+    """The compiler reports a donated operand it could not alias to an
+    output; the sum's tree, dtypes and shardings are the output's."""
+    assert not [w for w in accumulated["warnings"] if "donat" in w.lower()]
+    pipe = accumulated["pipe"]
+    for st in pipe.stages:
+        for layers in st.chunks:
+            for li in layers:
+                want = jax.tree.leaves(
+                    st.param_shardings[li],
+                    is_leaf=lambda x: hasattr(x, "mesh"))
+                got = [g.sharding for g in jax.tree.leaves(pipe.grads[li])]
+                assert all(g.is_equivalent_to(w, np.ndim(leaf)) for g, w, leaf
+                           in zip(got, want, jax.tree.leaves(pipe.grads[li])))

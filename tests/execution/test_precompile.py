@@ -13,7 +13,7 @@ from tests.execution.test_engine import cache_env, make_engine  # noqa: F401
 
 def _stage_keys(cache):
     # Stage-signature keys are the 11-tuples _build_stage_fns computes;
-    # "grad_add" / ("opt_update", id) aux entries are keyed differently.
+    # the ("opt_update", id) aux entries are keyed differently.
     return {k for k in cache if isinstance(k, tuple) and len(k) == 11}
 
 
@@ -86,10 +86,11 @@ def test_precompile_env_disable(cache_env, devices8, monkeypatch):
     assert pc.stats["errors"] == 0, pc.stats
 
 
-# The aux programs (`jit(grad_add)`, `jit(optimizer_update)`) are warmed
-# best-effort, once per aval whatever the stage's devices, and are not held
-# to this: the stage programs are.
-STAGE_PROGRAMS = ("jit(fwd)", "jit(bwd)", "jit(eval_fwd)")
+# `jit(optimizer_update)` is warmed best-effort, once per aval whatever the
+# stage's devices, and is not held to this: the stage programs are, and with
+# them each chunk's gradient-sum fill, which `bwd`'s donated operand needs
+# before the step's first microbatch.
+STAGE_PROGRAMS = ("jit(fwd)", "jit(bwd)", "jit(eval_fwd)", "jit(grad_zero)")
 
 
 class _CompileCounter:
@@ -127,9 +128,10 @@ def compile_counter():
 def test_precompiled_stage_programs_are_the_ones_the_step_runs(
         cache_env, devices8, compile_counter, model_name, has_eval_program):
     """The walk over the LIVE pipelines compiles every program a step runs,
-    the last stage's three-output `bwd` among them: the step after it
-    compiles no stage program. The last stage's forward-only program is
-    compiled ahead only where eval_step runs it."""
+    each chunk's `bwd` with the running gradient sum among its operands
+    (the last stage's gives three outputs) and the fill that starts the
+    sum: the step after it compiles no stage program. The last stage's
+    forward-only program is compiled ahead only where eval_step runs it."""
     from oobleck_tpu.execution.precompile import RecoveryPrecompiler
 
     engine = make_engine(num_hosts=2, steps=3, devices=devices8[:4],
@@ -156,6 +158,12 @@ def test_precompiled_stage_programs_are_the_ones_the_step_runs(
     assert pc.stats["stages_compiled"] == 2 * len(chunks)
     walked = compile_counter.take(*STAGE_PROGRAMS)
     assert walked.count("jit(bwd)") == len(chunks)
+    # One fill a chunk is lowered and compiled too (an aux program, beside
+    # the optimizer updates). `grad_zero` is one function for every chunk,
+    # so JAX may answer a fill from an equal one an earlier engine of this
+    # process compiled: the backend count is at most the chunks.
+    assert pc.stats["aux_compiled"] >= len(chunks)
+    assert walked.count("jit(grad_zero)") <= len(chunks)
     assert walked.count("jit(eval_fwd)") == (
         len(last_chunks) if has_eval_program else 0)
     assert walked.count("jit(fwd)") == len(chunks) - (

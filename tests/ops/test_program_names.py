@@ -1,6 +1,6 @@
 """Every jitted program of the two hot paths is a named function, so its
 XLA module has a name of ours in a device trace (`jit_fwd`, `jit_bwd`,
-`jit_grad_add`, `jit_optimizer_update`, `jit_decode_step`, `jit_prefill`,
+`jit_grad_zero`, `jit_optimizer_update`, `jit_decode_step`, `jit_prefill`,
 ...) and none is `jit__lambda`, which says nothing and is the same for
 every lambda in the process."""
 
@@ -39,7 +39,7 @@ def test_training_programs_carry_their_names():
 
     from oobleck_tpu.execution import pipeline
 
-    assert pipeline.grad_add.__name__ == "grad_add"
+    assert pipeline.grad_zero.__name__ == "grad_zero"
     update = pipeline.make_optimizer_update(optax.sgd(0.1))
     assert update.__name__ == "optimizer_update"
 
