@@ -19,6 +19,26 @@ from typing import Any
 import yaml
 
 
+# What a job that states no `seq_len` trains at, where the model's context
+# allows: the length every profile and plan was made at before the job
+# could say.
+DEFAULT_MAX_SEQ_LEN = 1024
+
+
+def training_seq_len(model_config, seq_len: int | None = None) -> int:
+    """The sequence length a job trains, profiles and draws its data at:
+    `seq_len` where the job states one (refused above the model's
+    context), else the model's context up to DEFAULT_MAX_SEQ_LEN."""
+    context = getattr(model_config, "max_position_embeddings", None)
+    if seq_len is None:
+        return min(context or DEFAULT_MAX_SEQ_LEN, DEFAULT_MAX_SEQ_LEN)
+    if seq_len < 1 or (context is not None and seq_len > context):
+        raise ValueError(
+            f"job.seq_len={seq_len} is outside the model's context of "
+            f"{context} positions")
+    return int(seq_len)
+
+
 @dataclass
 class DistributedArguments:
     """Cluster topology and control-plane addressing."""
@@ -44,6 +64,9 @@ class JobArguments:
     warmup_steps: int = 10
     weight_decay: float = 0.01
     max_grad_norm: float = 1.0
+    # Tokens of one training sequence. None: the model's context, up to
+    # DEFAULT_MAX_SEQ_LEN (`training_seq_len`, the one place with the rule).
+    seq_len: int | None = None
 
     def __post_init__(self) -> None:
         if self.global_microbatch_size % self.microbatch_size != 0:

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from oobleck_tpu.config import OobleckArguments
+from oobleck_tpu.config import OobleckArguments, training_seq_len
 from oobleck_tpu.elastic.message import JOINED_KEY
 from oobleck_tpu.execution.dataloader import (
     DeviceStager,
@@ -64,7 +64,12 @@ from oobleck_tpu.obs import spans as obs_spans
 from oobleck_tpu.obs import telemetry as obs_telemetry
 from oobleck_tpu.parallel.train import make_optimizer
 from oobleck_tpu.planning.instantiator import HeterogeneousPlan, PipelineInstantiator
-from oobleck_tpu.planning.profiler import load_profile, profile
+from oobleck_tpu.planning.profiler import (
+    effective_tag,
+    job_tag,
+    load_profile,
+    profile,
+)
 from oobleck_tpu.planning.templates import PipelineTemplate, TemplateGenerator
 from oobleck_tpu.policy import DECISION_KEY as POLICY_DECISION_KEY
 from oobleck_tpu.policy import (
@@ -592,7 +597,7 @@ class OobleckEngine:
                 "set execution.engine_path: mpmd"
             )
         cfg = self.model.config
-        seq_len = min(getattr(cfg, "max_position_embeddings", 1024), 1024)
+        seq_len = training_seq_len(cfg, args.job.seq_len)
         self.seq_len = seq_len
         logger.info("model %s: %d pipeline layers, hidden %s, seq_len %d",
                     args.model.model_name, self.model.num_pipeline_layers,
@@ -621,15 +626,14 @@ class OobleckEngine:
         # Planning inputs. Profile-on-miss runs HERE, in the process that
         # owns the chips — never in the agent. The profiled model carries the same execution overrides as the
         # trained one — a bf16 profile must not plan an f32 run.
-        from oobleck_tpu.planning.profiler import effective_tag
-
-        tag = effective_tag(args.model.model_tag, args.execution)
         with obs_spans.span("engine.profile"):
             profile(args.model.model_name, args.model.model_args,
-                    model_tag=args.model.model_tag, execution=args.execution,
+                    model_tag=job_tag(args.model.model_tag, args.job.seq_len),
+                    execution=args.execution,
                     microbatch_size=args.job.microbatch_size, seq_len=seq_len)
             self.profiles = load_profile(
-                args.model.model_name, tag, args.job.microbatch_size
+                args.model.model_name, self._profile_tag(),
+                args.job.microbatch_size
             )
 
         # Cluster geometry: hosts partition the device list. Ranks encode
@@ -802,6 +806,13 @@ class OobleckEngine:
             ReconfigurationEngine(self, agent_pipe)
 
     # ------------------------------------------------------------------ #
+
+    def _profile_tag(self) -> str:
+        """The planner's profile of this job: by model tag, the job's
+        sequence length where it states one, and the execution knobs."""
+        return effective_tag(
+            job_tag(self.args.model.model_tag, self.args.job.seq_len),
+            self.args.execution)
 
     def initialize_distributed(self) -> None:
         """Bind to the visible devices and compute templates.
@@ -1019,8 +1030,7 @@ class OobleckEngine:
         import dataclasses
 
         from oobleck_tpu.planning.profiler import (
-            effective_tag, get_profile_path,
-            measure_allreduce_across_processes)
+            get_profile_path, measure_allreduce_across_processes)
 
         P = self.comm.process_count
         if P < 2:
@@ -1028,7 +1038,7 @@ class OobleckEngine:
         sizes = sorted({p.mem_params for p in self.profiles})
         path = get_profile_path(
             self.args.model.model_name,
-            effective_tag(self.args.model.model_tag, self.args.execution),
+            self._profile_tag(),
         )
         # Reuse a previously MEASURED table when process 0's cache holds
         # one covering this world size — a post-failure respawn re-enters

@@ -65,6 +65,20 @@ register("lfm2-24b-a2b")(lambda o: _lfm2(o))
 register("lfm2-moe-tiny")(lambda o: _lfm2(o, vocab_size=256, hidden_size=64, num_layers=4, num_heads=4, num_kv_heads=2, intermediate_size=128, moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, num_dense_layers=1, max_position_embeddings=128))
 
 
+def _deepseek_v3(overrides: dict[str, Any], **preset):
+    from oobleck_tpu.models.deepseek_v3 import DeepseekV3Config, DeepseekV3Model
+
+    return DeepseekV3Model(
+        DeepseekV3Config().override(**preset).override(**overrides))
+
+
+# DeepSeek-V3 family (`deepseek_v3`): latent attention (MLA), shared
+# experts beside dropless top-k sigmoid-routed experts; the defaults are
+# Moonlight-16B-A3B's.
+register("moonlight-16b-a3b")(lambda o: _deepseek_v3(o))
+register("moonlight-tiny")(lambda o: _deepseek_v3(o, vocab_size=256, hidden_size=64, num_layers=3, num_heads=4, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128, moe_intermediate_size=32, num_experts=8, num_experts_per_tok=3, max_position_embeddings=128))
+
+
 # Bloom family: GPT architecture with ALiBi position biases (no wpe)
 register("bloom-560m")(lambda o: _gpt(o, vocab_size=250880, hidden_size=1024, num_layers=24, num_heads=16, position_embedding="alibi"))
 register("bloom-7b1")(lambda o: _gpt(o, vocab_size=250880, hidden_size=4096, num_layers=30, num_heads=32, position_embedding="alibi"))

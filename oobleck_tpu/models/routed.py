@@ -1,0 +1,307 @@
+"""What the decoders with routed experts share (`models/lfm2.py`,
+`models/deepseek_v3.py`): a layer list whose blocks DIFFER, one chip's share of
+an expert-parallel deployment as a model of its own, and the probe that
+reads where a sequence was routed.
+
+Every block is `h = x + Op(N(x)); y = h + FF(N(h))` with RMSNorm `N`. A
+family says what `Op` is for a block (`operator_out`) and which blocks are
+routed (`is_routed`); the dense SwiGLU, the routed call
+(`ops/moe.routed_experts`), the embedding and the untied head over the
+vocabulary rows held, the loss and the chaining of layers are here.
+
+The share: `num_experts_held` experts from `expert_offset` (the router still
+scores all `num_experts`; the layer gives the part its held experts give),
+and the first `vocab_rows_held` rows of the vocabulary (embedding and head;
+token ids, logits and the loss are over those rows, and the engine draws
+its data from them: `data_vocab_size`). Nothing stands in for the absent
+chips.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from oobleck_tpu.models.gpt import cross_entropy_loss
+from oobleck_tpu.models.llama import _rms_norm as rms_norm
+
+
+class HeldShare:
+    """Properties of a config dataclass with `vocab_size`,
+    `vocab_rows_held`, `vocab_pad_multiple`, `num_experts`,
+    `num_experts_held`, `expert_offset`, `intermediate_size`."""
+
+    @property
+    def data_vocab_size(self) -> int:
+        """Rows of the vocabulary this model holds: what token ids range
+        over (execution/engine.py draws its data from it)."""
+        return (self.vocab_size if self.vocab_rows_held is None
+                else self.vocab_rows_held)
+
+    @property
+    def padded_vocab_size(self) -> int:
+        m = self.vocab_pad_multiple
+        return (self.data_vocab_size + m - 1) // m * m
+
+    @property
+    def ffn_dim(self) -> int:
+        return self.intermediate_size
+
+    @property
+    def experts_held(self) -> int:
+        return (self.num_experts if self.num_experts_held is None
+                else self.num_experts_held)
+
+    def check_share(self) -> None:
+        if not 0 < self.data_vocab_size <= self.vocab_size:
+            raise ValueError(
+                f"vocab_rows_held {self.vocab_rows_held} of {self.vocab_size}")
+        if self.expert_offset + self.experts_held > self.num_experts:
+            raise ValueError(
+                f"experts {self.expert_offset}..+{self.experts_held} of "
+                f"{self.num_experts}")
+
+
+# --------------------------------------------------------------------- #
+# rotary embedding, rotate-half form                                     #
+# --------------------------------------------------------------------- #
+
+def rotate_half(x: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half rotary embedding at positions 0..S-1 over the whole last
+    dimension. x: [..., S, D]."""
+    d, s = x.shape[-1], x.shape[-2]
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)       # [S, D]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
+# --------------------------------------------------------------------- #
+# the layer list                                                         #
+# --------------------------------------------------------------------- #
+
+class RoutedShareModel:
+    """Layer list [embed, block_0 .. block_{L-1}, head], as every family's,
+    so the planner profiles and the MPMD pipeline splits it unchanged;
+    generic stage path only (no manual-collective contract)."""
+
+    data_kind = "causal_lm"
+    fused_supported = False
+
+    def __init__(self, config):
+        self.config = config
+
+    # ---- what a family says ----
+
+    def is_routed(self, block: int) -> bool:
+        raise NotImplementedError
+
+    def operator_out(self, block: int, p, h):
+        """Op(h) of block `block`, `p` the block's parameters."""
+        raise NotImplementedError
+
+    def _init_block(self, rng, block: int):
+        raise NotImplementedError
+
+    # ---- layer list ----
+
+    @property
+    def num_pipeline_layers(self) -> int:
+        return self.config.num_layers + 2
+
+    def layer_name(self, index: int) -> str:
+        if index == 0:
+            return "embed"
+        if index == self.num_pipeline_layers - 1:
+            return "head"
+        return f"block_{index - 1}"
+
+    @property
+    def routed_blocks(self) -> tuple[int, ...]:
+        return tuple(b for b in range(self.config.num_layers)
+                     if self.is_routed(b))
+
+    def init_layer(self, rng: jax.Array, index: int):
+        ks = jax.random.split(rng, 3)
+        if index == 0:
+            return self._init_embed(ks[0])
+        if index == self.num_pipeline_layers - 1:
+            return self._init_head(ks[2])
+        return self._init_block(jax.random.fold_in(ks[1], index), index - 1)
+
+    def apply_layer(self, index: int, params, carry, batch, ctx=None):
+        if index == 0:
+            return self.embed(params, batch["input_ids"])
+        if index == self.num_pipeline_layers - 1:
+            return self.head(params, carry)
+        return self.apply_block(index - 1, params, carry)
+
+    @jax.named_scope("lm_head")
+    def loss_from_logits(self, logits, batch):
+        return cross_entropy_loss(logits, batch["input_ids"],
+                                  self.config.data_vocab_size)
+
+    def sample_batch(self, batch_size: int, seq_len: int):
+        tokens = jax.random.randint(
+            jax.random.PRNGKey(0), (batch_size, seq_len), 0,
+            self.config.data_vocab_size, dtype=jnp.int32)
+        return {"input_ids": tokens}
+
+    # ---- init ----
+
+    def _init_embed(self, rng):
+        c = self.config
+        return {"wte": jax.random.normal(
+            rng, (c.padded_vocab_size, c.hidden_size), c.param_dtype
+        ) * c.initializer_range}
+
+    def _init_head(self, rng):
+        c = self.config
+        return {
+            "ln_f": {"scale": jnp.ones((c.hidden_size,), c.param_dtype)},
+            "w": jax.random.normal(
+                rng, (c.hidden_size, c.padded_vocab_size), c.param_dtype
+            ) * c.initializer_range,
+        }
+
+    # ---- forward ----
+
+    @jax.named_scope("embed")
+    def embed(self, p, tokens):
+        return p["wte"][tokens].astype(self.config.dtype)
+
+    def dense_ff(self, p, h):
+        """SwiGLU: W2 (silu(W1 h) * W3 h)."""
+        dt = self.config.dtype
+        g = jax.nn.silu(h @ p["w1"].astype(dt)) * (h @ p["w3"].astype(dt))
+        return g @ p["w2"].astype(dt)
+
+    def routed_ff(self, p, h, *, forced_experts=None,
+                  return_routing: bool = False):
+        """The part of the routed layer that the experts held here give.
+        With `return_routing`, (that, the chosen experts [B, S, k])."""
+        from oobleck_tpu.ops.moe import routed_experts
+
+        c = self.config
+        b, s, e = h.shape
+        out = routed_experts(
+            h.reshape(b * s, e), p["router"], p.get("expert_bias"),
+            p["w1"], p["w3"], p["w2"],
+            num_experts=c.num_experts, top_k=c.num_experts_per_tok,
+            expert_offset=c.expert_offset, norm_topk_prob=c.norm_topk_prob,
+            routed_scaling_factor=c.routed_scaling_factor,
+            forced_experts=(None if forced_experts is None
+                            else forced_experts.reshape(b * s, -1)),
+            return_routing=return_routing)
+        if return_routing:
+            y, experts = out
+            return y.reshape(b, s, e), experts.reshape(b, s, -1)
+        return out.reshape(b, s, e)
+
+    @jax.named_scope("mlp")
+    def feed_forward(self, block: int, p, h, *, forced_experts=None,
+                     return_routing: bool = False):
+        """Dense SwiGLU or the routed experts, by the block. With
+        `return_routing` a routed block also returns its chosen experts
+        [B, S, k]."""
+        if not self.is_routed(block):
+            return self.dense_ff(p, h)
+        return self.routed_ff(p, h, forced_experts=forced_experts,
+                              return_routing=return_routing)
+
+    def apply_block(self, block: int, p, x, *, forced_experts=None,
+                    return_routing: bool = False):
+        c = self.config
+        h = rms_norm(x, p["ln_op"]["scale"], c.norm_eps)
+        x = x + self.operator_out(block, p, h)
+        h = rms_norm(x, p["ln_ff"]["scale"], c.norm_eps)
+        out = self.feed_forward(block, p["ff"], h,
+                                forced_experts=forced_experts,
+                                return_routing=return_routing)
+        if return_routing and self.is_routed(block):
+            return x + out[0], out[1]
+        return x + out
+
+    @jax.named_scope("lm_head")
+    def head(self, p, x):
+        c = self.config
+        x = rms_norm(x, p["ln_f"]["scale"], c.norm_eps)
+        return (x @ p["w"].astype(c.dtype)).astype(jnp.float32)
+
+    # Forward for one device: chain the layers as the pipeline does.
+    def forward(self, params_list, tokens, *, return_routing: bool = False):
+        """Logits [B, S, padded vocab]; with `return_routing` also the
+        experts every routed block chose, one [B, S, k] per block in
+        `routed_blocks` order."""
+        x = self.embed(params_list[0], tokens)
+        routing = []
+        for block in range(self.config.num_layers):
+            p = params_list[block + 1]
+            if return_routing and self.is_routed(block):
+                x, experts = self.apply_block(block, p, x,
+                                              return_routing=True)
+                routing.append(experts)
+            else:
+                x = self.apply_block(block, p, x)
+        logits = self.head(params_list[-1], x)
+        return (logits, routing) if return_routing else logits
+
+    def loss(self, params_list, batch):
+        return self.loss_from_logits(
+            self.forward(params_list, batch["input_ids"]), batch)
+
+
+def routing_probe(model: RoutedShareModel, params_list, tokens):
+    """The experts every routed block chooses for `tokens` [B, S], read out
+    on demand: one jitted forward chaining the model's own layers on the
+    given per-layer parameters. Returns a list of host arrays [B, S, k] in
+    `routed_blocks` order, and counts what it saw: the probed tokens and,
+    per block, the (token, slot) pairs whose expert is held here.
+
+    On demand and not every step: the pipeline's stage programs have no
+    output beside the carry and the loss, so a training step cannot say
+    where it routed."""
+    from oobleck_tpu.obs import spans
+    from oobleck_tpu.utils import metrics
+
+    c = model.config
+    reg = metrics.registry()
+    pairs = reg.counter(
+        "oobleck_moe_routed_pairs_total",
+        "(token, slot) pairs a routing probe saw routed to experts held "
+        "here, by routed block")
+    probed = reg.counter(
+        "oobleck_moe_probed_tokens_total",
+        "Tokens a routing probe read the routing of")
+    held_rows = reg.gauge(
+        "oobleck_moe_held_rows",
+        "(token, slot) pairs of the LAST probed tokens routed to experts "
+        "held here, by routed block")
+    with spans.span("moe.routing_probe"):
+        probe = _probe_program(model)
+        routing = [np.asarray(r)  # oobleck: allow[OBL002] -- on-demand probe
+                   for r in probe(tuple(params_list), tokens)]
+    probed.inc(int(routing[0].shape[0] * routing[0].shape[1]) if routing
+               else 0)
+    for block, chosen in zip(model.routed_blocks, routing):
+        local = chosen - c.expert_offset
+        held = int(((local >= 0) & (local < c.experts_held)).sum())
+        pairs.inc(held, layer=str(block))
+        held_rows.set(held, layer=str(block))
+    return routing
+
+
+def _probe_program(model: RoutedShareModel):
+    fn = getattr(model, "_routing_probe_fn", None)
+    if fn is None:
+        def routing_probe_forward(params_list, tokens):
+            return model.forward(list(params_list), tokens,
+                                 return_routing=True)[1]
+
+        fn = model._routing_probe_fn = jax.jit(routing_probe_forward)
+    return fn

@@ -267,3 +267,50 @@ def causal_attention(
                                      causal=causal)
     return fn(q, k, v, scale=scale, bias=bias, alibi_slopes=alibi_slopes,
               causal=causal)
+
+
+def latent_qk(q_nope, q_rope, k_nope, k_rope):
+    """Latent attention's scores as ONE width: q = [q_nope | q_rope],
+    k = [k_nope | k_rope], the one rotary key a position ([B, S, Dr])
+    broadcast over the heads; its gradient is then the sum over them."""
+    b, h, s_len, _ = q_nope.shape
+    if k_rope.shape != (b, s_len, q_rope.shape[-1]):
+        raise ValueError(
+            f"k_rope must be [B, S, Dr] = {(b, s_len, q_rope.shape[-1])}, "
+            f"one key a position for all heads; got {k_rope.shape}")
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, None], q_rope.shape)], axis=-1)
+    return q, k
+
+
+def latent_attention(
+    q_nope: jax.Array,
+    q_rope: jax.Array,
+    k_nope: jax.Array,
+    k_rope: jax.Array,
+    v: jax.Array,
+    *,
+    impl: str = "auto",
+    scale: float | None = None,
+) -> jax.Array:
+    """Causal latent attention (DeepSeek's MLA, the form trained): scores
+    over [q_nope | q_rope] . [k_nope | k_rope], `k_rope` [B, S, Dr] ONE
+    rotated key a position shared by all heads, values of their own width.
+    [B, H, S, Dn] / [B, H, S, Dr] / [B, H, S, Dv] -> [B, H, S, Dv].
+
+    The flash kernels on a TPU ("auto") or where asked for ("pallas": the
+    interpreter elsewhere); otherwise the XLA reference on the same
+    operands. Ring and Ulysses have no latent form and take the
+    single-device choice."""
+    if impl in ("ring", "ulysses"):
+        impl = "auto"
+    if impl == "pallas" or (impl == "auto" and _pallas_ok()):
+        from oobleck_tpu.ops.flash import latent_flash_attention
+
+        return latent_flash_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                      scale=scale)
+    if impl not in ("auto", "xla"):
+        raise ValueError(f"unknown attention impl: {impl!r}")
+    q, k = latent_qk(q_nope, q_rope, k_nope, k_rope)
+    return _xla_causal_attention(q, k, v, scale=scale)

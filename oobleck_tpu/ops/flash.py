@@ -76,6 +76,21 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
+class KernelNames(NamedTuple):
+    """What a device trace calls the three kernels of one attention (a TPU
+    trace names a kernel by its `pallas_call`'s `name=` alone)."""
+    fwd: str
+    bwd_dq: str
+    bwd_dkv: str
+
+
+PLAIN = KernelNames("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+# Latent attention's calls (`latent_flash_attention`): the same kernels at
+# scores wider than the values, under names of their own, so that a reader
+# of `%flash_fwd.` never counts them at one width.
+LATENT = KernelNames("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")
+
+
 class Tiles(NamedTuple):
     seq: int       # the sequence as the kernels see it: padded to the lane
     block_q: int   # rows of Q (and O, dO, LSE) per block
@@ -293,19 +308,25 @@ def _dkv_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
 
 
 def _pad_inputs(q, k, v, bias):
-    """Pad head dim and sequence to the lane width."""
+    """Pad head dims and sequence to the lane width. `v` (and with it O and
+    dO) has its own width: where it is narrower than q and k (latent
+    attention: 192-wide scores over 128-wide values) it is not padded to
+    theirs."""
     b, h, s_len, d = q.shape
-    d_pad = (LANE - d % LANE) % LANE
+    dv = v.shape[-1]
     s_pad = (LANE - s_len % LANE) % LANE
-    if d_pad or s_pad:
-        pad = ((0, 0), (0, 0), (0, s_pad), (0, d_pad))
-        q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
-        if bias is not None:
-            bias = jnp.pad(bias, ((0, 0), (0, s_pad), (0, s_pad)))
-    bh = b * h
-    sp, dp = q.shape[2], q.shape[3]
-    q, k, v = (x.reshape(bh, sp, dp) for x in (q, k, v))
-    return q, k, v, bias, (b, h, s_len, d, bh, sp, dp)
+
+    def pad(x):
+        d_pad = (LANE - x.shape[-1] % LANE) % LANE
+        if d_pad or s_pad:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, s_pad), (0, d_pad)))
+        return x.reshape(b * h, x.shape[2], x.shape[3])
+
+    q, k, v = pad(q), pad(k), pad(v)
+    if bias is not None and s_pad:
+        bias = jnp.pad(bias, ((0, 0), (0, s_pad), (0, s_pad)))
+    return q, k, v, bias, (b, h, s_len, d, dv, b * h, q.shape[1], q.shape[2],
+                           v.shape[2])
 
 
 def _canon_bias(bias, h, s_len):
@@ -406,9 +427,10 @@ def _slope_specs(has_slopes: bool, h: int):
 
 
 def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
-                   emit_lse: bool = True):
+                   names: KernelNames, emit_lse: bool = True):
     bias = _canon_bias(bias, q.shape[1], q.shape[2])
-    q, k, v, bias, (b, h, s_len, d, bh, sp, dp) = _pad_inputs(q, k, v, bias)
+    q, k, v, bias, (b, h, s_len, _, dv, bh, sp, dp, dvp) = _pad_inputs(
+        q, k, v, bias)
     has_bias = bias is not None
     has_slopes = slopes is not None
     t = choose_tiles(s_len)
@@ -417,8 +439,8 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
 
     operands = ([q, k, v] + ([bias] if has_bias else [])
                 + ([slopes] if has_slopes else []))
-    o_shape = _out_struct((bh, sp, dp), q.dtype, *operands)
-    o_spec = _q_rows(t.block_q, dp)
+    o_shape = _out_struct((bh, sp, dvp), q.dtype, *operands)
+    o_spec = _q_rows(t.block_q, dvp)
     if emit_lse:
         # The LSE residual is only needed when a backward pass will run;
         # forward-only (eval) calls skip the extra [BH, S, 128] HBM write.
@@ -428,25 +450,26 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
     else:
         out_shape, out_specs = o_shape, o_spec
     result = _call(
-        _fwd_kernel, "flash_fwd", _live_pairs(t, causal, q_major=True),
+        _fwd_kernel, names.fwd, _live_pairs(t, causal, q_major=True),
         operands,
         in_specs=([_q_rows(t.block_q, dp), _k_rows(t.block_k, dp),
-                   _k_rows(t.block_k, dp)]
+                   _k_rows(t.block_k, dvp)]
                   + _bias_specs(has_bias, h, t) + _slope_specs(has_slopes, h)),
         out_shape=out_shape, out_specs=out_specs,
-        scratch=[(t.block_q, dp), (t.block_q, LANE), (t.block_q, LANE)],
+        scratch=[(t.block_q, dvp), (t.block_q, LANE), (t.block_q, LANE)],
         scale=scale, tiles=t, causal=causal, has_bias=has_bias,
         has_slopes=has_slopes, kv_len=s_len, emit_lse=emit_lse)
 
     out, lse = result if emit_lse else (result, None)
-    out = out.reshape(b, h, sp, dp)[:, :, :s_len, :d]
+    out = out.reshape(b, h, sp, dvp)[:, :, :s_len, :dv]
     return out, lse
 
 
 def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
-                    causal: bool):
+                    causal: bool, names: KernelNames):
     bias = _canon_bias(bias, q.shape[1], q.shape[2])
-    qp, kp, vp, bias, (b, h, s_len, d, bh, sp, dp) = _pad_inputs(q, k, v, bias)
+    qp, kp, vp, bias, (b, h, s_len, d, dv, bh, sp, dp, dvp) = _pad_inputs(
+        q, k, v, bias)
     # Pad O / dO the same way (their padded rows are zero, so padded-row
     # contributions to dk/dv vanish and padded delta rows are zero).
     op, gp, *_ = _pad_inputs(out, g, g, None)[:2]
@@ -460,47 +483,48 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
               + ([slopes] if has_slopes else []))
     # Gradients leave in the operands' dtype: one rounding from the f32
     # accumulator, here and not in a cast after the kernel.
-    grad_shape = lambda x: _out_struct((bh, sp, dp), x.dtype, *common)
+    grad_shape = lambda x, width: _out_struct((bh, sp, width), x.dtype,
+                                              *common)
     shared = dict(
         in_specs=([_q_rows(t.block_q, dp), _k_rows(t.block_k, dp),
-                   _k_rows(t.block_k, dp), _q_rows(t.block_q, dp),
-                   _q_rows(t.block_q, dp), _q_rows(t.block_q, LANE)]
+                   _k_rows(t.block_k, dvp), _q_rows(t.block_q, dvp),
+                   _q_rows(t.block_q, dvp), _q_rows(t.block_q, LANE)]
                   + _bias_specs(has_bias, h, t) + _slope_specs(has_slopes, h)),
         scale=scale, tiles=t, causal=causal, has_bias=has_bias,
         has_slopes=has_slopes, kv_len=s_len)
 
     dq = _call(
-        _dq_kernel, "flash_bwd_dq", _live_pairs(t, causal, q_major=True),
-        common, out_shape=grad_shape(q), out_specs=_q_rows(t.block_q, dp),
+        _dq_kernel, names.bwd_dq, _live_pairs(t, causal, q_major=True),
+        common, out_shape=grad_shape(q, dp), out_specs=_q_rows(t.block_q, dp),
         scratch=[(t.block_q, dp)], **shared)
-    dk, dv = _call(
-        _dkv_kernel, "flash_bwd_dkv", _live_pairs(t, causal, q_major=False),
-        common, out_shape=(grad_shape(k), grad_shape(v)),
-        out_specs=(_k_rows(t.block_k, dp), _k_rows(t.block_k, dp)),
-        scratch=[(t.block_k, dp), (t.block_k, dp)], **shared)
+    dk, dv_ = _call(
+        _dkv_kernel, names.bwd_dkv, _live_pairs(t, causal, q_major=False),
+        common, out_shape=(grad_shape(k, dp), grad_shape(v, dvp)),
+        out_specs=(_k_rows(t.block_k, dp), _k_rows(t.block_k, dvp)),
+        scratch=[(t.block_k, dp), (t.block_k, dvp)], **shared)
 
-    def unpad(x):
-        return x.reshape(b, h, sp, dp)[:, :, :s_len, :d]
+    def unpad(x, width):
+        return x.reshape(b, h, sp, x.shape[-1])[:, :, :s_len, :width]
 
-    return unpad(dq), unpad(dk), unpad(dv)
+    return unpad(dq, d), unpad(dk, d), unpad(dv_, dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _flash(q, k, v, bias, slopes, scale, causal):
-    out, _ = _flash_forward(q, k, v, bias, slopes, scale, causal,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash(q, k, v, bias, slopes, scale, causal, names):
+    out, _ = _flash_forward(q, k, v, bias, slopes, scale, causal, names,
                             emit_lse=False)
     return out
 
 
-def _flash_fwd(q, k, v, bias, slopes, scale, causal):
-    out, lse = _flash_forward(q, k, v, bias, slopes, scale, causal)
+def _flash_fwd(q, k, v, bias, slopes, scale, causal, names):
+    out, lse = _flash_forward(q, k, v, bias, slopes, scale, causal, names)
     return out, (q, k, v, bias, slopes, out, lse)
 
 
-def _flash_bwd(scale, causal, res, g):
+def _flash_bwd(scale, causal, names, res, g):
     q, k, v, bias, slopes, out, lse = res
     dq, dk, dv = _flash_backward(q, k, v, bias, slopes, out, lse, g, scale,
-                                 causal)
+                                 causal, names)
     # Bias/slopes are constants (ALiBi): position-only, so the zero
     # cotangent is exact. Learned biases must use the XLA path
     # (attention.py routes them).
@@ -542,4 +566,42 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 f"alibi_slopes must be [H]={q.shape[1]}, got "
                 f"{alibi_slopes.shape}")
         alibi_slopes = jax.lax.stop_gradient(alibi_slopes)
-    return _flash(q, k, v, bias, alibi_slopes, scale, causal)
+    return _flash(q, k, v, bias, alibi_slopes, scale, causal, PLAIN)
+
+
+def latent_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
+                           k_nope: jax.Array, k_rope: jax.Array,
+                           v: jax.Array, *,
+                           scale: float | None = None) -> jax.Array:
+    """Causal latent attention's core (DeepSeek's MLA, as trained).
+
+    q_nope, k_nope [B, H, S, Dn]: the part of a head's query and key that
+    carries no position; q_rope [B, H, S, Dr], rotated; k_rope [B, S, Dr]:
+    ONE rotated key a position, shared by all H heads; v [B, H, S, Dv].
+    Scores are (q_nope . k_nope + q_rope . k_rope) * scale, scale
+    1 / sqrt(Dn + Dr) by default; returns softmax x v, [B, H, S, Dv].
+
+    One path: the plain kernels at scores wider than the values (192
+    against 128; `_pad_inputs` pads each to the lane width by itself),
+    under `LATENT`'s names. The shared key is broadcast over the heads
+    outside the kernels (`attention.latent_qk`), so its gradient is the sum
+    over the heads of what the dk/dv kernel gives each."""
+    from oobleck_tpu.ops.attention import latent_qk
+
+    q, k = latent_qk(q_nope, q_rope, k_nope, k_rope)
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    _count_latent_calls()
+    return _flash(q, k, v, None, None, scale, True, LATENT)
+
+
+def _count_latent_calls() -> None:
+    """`oobleck_flash_mla_calls_total{kernel}`: counted where the kernels
+    are built, once a kernel of every program traced (not once a step)."""
+    from oobleck_tpu.utils import metrics
+
+    built = metrics.registry().counter(
+        "oobleck_flash_mla_calls_total",
+        "Latent-attention kernels built into traced programs, by kernel")
+    for name in LATENT:
+        built.inc(kernel=name)

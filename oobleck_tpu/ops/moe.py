@@ -132,6 +132,7 @@ MAX_ROW_TILE = 512        # rows of one tile: what an expert's rows pad to
 SUBLANE = 16              # a bfloat16 tile's rows
 MAX_COL_TILE = 512        # columns of the output one grid step produces
 MAX_TGMM_ROWS = 1024      # rows of dW one grid step accumulates
+MAX_WHOLE_COLS = 2048     # a width no tile but one lane divides, taken whole
 _GMM_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"),
     vmem_limit_bytes=64 * 1024 * 1024)
@@ -149,27 +150,43 @@ def _pallas_ok() -> bool:
 def _col_tile(n: int, limit: int) -> int:
     """The largest multiple of the lane width that divides `n` and stays
     within `limit`; the whole of `n` where no such tile exists (widths
-    under a lane, the tests' sizes)."""
+    under a lane, the tests' sizes) and where the only one is a single
+    lane and `n` fits MAX_WHOLE_COLS (1408 = 11 x 128: in 128-column tiles
+    the rows are read eleven times over and a grid step carries a tenth of
+    a microsecond of products; whole, `moe_gmm_roofline` read 27.2 % against
+    16.9 % and the step 920.6 against 1,008.8 ms, my chip runs, PR 35)."""
     tiles = [LANE * m for m in range(1, limit // LANE + 1)
              if n % (LANE * m) == 0]
+    if tiles == [LANE] and LANE < n <= MAX_WHOLE_COLS:
+        return n
     return max(tiles) if tiles else n
 
 
 @functools.cache
 def choose_row_tile(num_pairs: int, num_experts: int) -> int:
-    """Rows of one tile, from the call's shape: the rows an expert EXPECTS
-    (the pairs over all the experts) and half as many again, in as few
-    equal tiles as MAX_ROW_TILE allows, rounded up to the lane width (to a
-    bfloat16 sublane tile below it). An expert's rows then fill the same
-    number of tiles whatever the router does to its load within a half
-    either way. With a tile that divides the expected rows (256 at 512
-    rows: two tiles or three at the slightest excess) the tiles in use,
-    and the step's time with them, changed with every seed's router: 0.9 %
-    between six seeds of the benchmark's cell (my chip runs, PR 29)."""
-    roomy = 1.5 * num_pairs / num_experts
-    tiles = -(-roomy // MAX_ROW_TILE)
-    unit = LANE if roomy / tiles >= LANE else SUBLANE
-    return int(-(-roomy / tiles // unit) * unit)
+    """Rows of one tile, from the call's shape: among the multiples of the
+    lane width up to MAX_ROW_TILE (of a bfloat16 sublane tile where an
+    expert's rows and half as many again stay under a lane), the tile whose
+    edges lie furthest from the rows an expert EXPECTS (the pairs over all
+    the experts), so that an expert's rows fill the same number of tiles
+    whatever the router does to its load within that room; room beyond half
+    the expected rows counts for nothing. Ties go to the fewest tiles an
+    expert, then to the smallest tile. 512 expected rows get 384 (two
+    tiles from 385 to 768 rows), 384 get 512 (one tile up to 512), 256 get
+    384. With a tile whose edge the expected rows sit on (256 at 512 rows:
+    two tiles or three at the slightest excess) the tiles in use, and the
+    step's time with them, changed with every seed's router: 0.9 % between
+    six seeds of the benchmark's cell (my chip runs, PR 29)."""
+    expected = num_pairs / num_experts
+    unit = LANE if 1.5 * expected >= LANE else SUBLANE
+
+    def preference(tile: int):
+        tiles = max(-(-expected // tile), 1)
+        room = min(expected - (tiles - 1) * tile, tiles * tile - expected,
+                   expected / 2)
+        return (-room, tiles, tile)
+
+    return min(range(unit, MAX_ROW_TILE + 1, unit), key=preference)
 
 
 class RoutingPlan(NamedTuple):

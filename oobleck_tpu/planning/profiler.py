@@ -31,8 +31,17 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 
+from oobleck_tpu.config import training_seq_len
 from oobleck_tpu.models.base import param_bytes
 from oobleck_tpu.planning.templates import LayerProfile
+
+# What a cached profile was measured WITH, beside the model and the tag: a
+# change to the timed programs raises it, and a cache written under another
+# number (or, before there was one, under none) is measured again. 2: a
+# layer's parameters are arguments of the timed program (PR 35); closed over,
+# as constants, the compiler could fold casts into them, so the layer times
+# the planner reads may differ from a version-1 cache's.
+PROFILE_VERSION = 2
 
 WARMUP = 2
 ITERS = 3  # matches reference profiler.py:18-19
@@ -92,12 +101,18 @@ def _dispatch_overhead_ms() -> float:
     return _overhead_cache[0]
 
 
-def _time_repeated(fn_once, x0, reps: int = REPS) -> float:
-    """Time `fn_once(x)` by scanning it `reps` times inside one jit call.
+def _time_repeated(fn_once, x0, *fixed, reps: int = REPS) -> float:
+    """Time `fn_once(x, *fixed)` by scanning it `reps` times inside one
+    jit call.
 
     Each iteration's input is data-perturbed by 0 derived from the previous
     output, forcing a sequential chain XLA cannot hoist or CSE (a float*0 is
     not folded). Returns per-iteration ms with dispatch overhead removed.
+    `fixed` (a layer's parameters, its input) are ARGUMENTS of the timed
+    program, as they are of a stage program: closed over they are constants
+    of hundreds of megabytes, which the compiler takes minutes to embed and
+    may fold a weight's cast into (698 s of `moonlight-16b-a3b.steady`'s
+    cold start for four kinds of layer, my chip run, PR 35).
     """
     def perturb(x, leaf):
         zero = leaf * 0.0
@@ -105,17 +120,17 @@ def _time_repeated(fn_once, x0, reps: int = REPS) -> float:
             lambda v: v + zero.astype(v.dtype), x
         )
 
-    def run(x):
+    def run(x, *fixed):
         def body(carry, _):
             x, acc = carry
-            out = fn_once(x)
+            out = fn_once(x, *fixed)
             leaf = jax.tree.leaves(out)[0].ravel()[0].astype(jnp.float32)
             return (perturb(x, leaf), acc + leaf), None
 
         (_, acc), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), None, length=reps)
         return acc
 
-    total = _time_call(jax.jit(run), x0)
+    total = _time_call(jax.jit(run), x0, *fixed)
     return max((total - _dispatch_overhead_ms()) / reps, 1e-4)
 
 
@@ -158,9 +173,7 @@ def profile_execution_layers(model, microbatch_size: int, seq_len: int | None = 
     mem_required: [param_bytes, activation_bytes]} per layer
     (cf. reference profile_execution_layers, profiler.py:41-123).
     """
-    c = model.config
-    if seq_len is None:
-        seq_len = min(getattr(c, "max_position_embeddings", 1024), 1024)
+    seq_len = training_seq_len(model.config, seq_len)
     rng = jax.random.PRNGKey(0)
     batch = model.sample_batch(microbatch_size, seq_len)
     results = []
@@ -186,22 +199,22 @@ def profile_execution_layers(model, microbatch_size: int, seq_len: int | None = 
         # chain it. `batch` rides along for mid-pipeline consumers (T5's
         # bridge reads decoder_input_ids).
         if idx == 0:
-            def fwd(x, p=params):
+            def fwd(x, p):
                 return model.apply_layer(0, p, None, x)
             x0 = batch
         else:
-            def fwd(x, p=params, i=idx):
+            def fwd(x, p, i=idx):
                 return model.apply_layer(i, p, x, batch)
             x0 = _ones_like_tree(carry_t)
 
-        out_t = jax.eval_shape(fwd, x0)
+        out_t = jax.eval_shape(fwd, x0, params)
         reused = proto_rows.get(prefix) if prefix else None
         if reused is not None:
             results.append(dict(reused))
             carry_t = out_t
             continue
         pbytes = param_bytes(params)
-        fwd_ms = _time_repeated(fwd, x0)
+        fwd_ms = _time_repeated(fwd, x0, params)
         ct0 = _ones_like_tree(out_t)
 
         if idx == 0:
@@ -209,22 +222,24 @@ def profile_execution_layers(model, microbatch_size: int, seq_len: int | None = 
             # activation cotangent to chain on) — measured, not the
             # reference's 3x-forward estimate (profiler.py:41-123) nor the
             # earlier 2x guess here.
-            def bwd(ct, p=params):
+            def bwd(ct, p):
                 _, vjp = jax.vjp(
                     lambda p_: model.apply_layer(0, p_, None, batch), p
                 )
                 return vjp(ct)
+            bwd_fixed = (params,)
         else:
             # VJP wrt (activations, params) — both cotangent paths, like the
             # real backward. jax.vjp re-runs the forward inside, so this cost
             # includes recompute, matching execution under jax.checkpoint.
-            def bwd(ct, x=x0, p=params, i=idx):
+            def bwd(ct, x, p, i=idx):
                 _, vjp = jax.vjp(
                     lambda x_, p_: model.apply_layer(i, p_, x_, batch), x, p
                 )
                 return vjp(ct)
+            bwd_fixed = (x0, params)
 
-        bwd_ms = _time_repeated(bwd, ct0)
+        bwd_ms = _time_repeated(bwd, ct0, *bwd_fixed)
 
         act_bytes = sum(
             math.prod(s.shape) * s.dtype.itemsize
@@ -346,6 +361,13 @@ def effective_tag(model_tag: str, execution=None) -> str:
     return "+".join(parts)
 
 
+def job_tag(model_tag: str, seq_len: int | None) -> str:
+    """The model tag of a job that states its sequence length
+    (`job.seq_len`): two lengths never share a profile. A job that states
+    none keeps the tag it always had."""
+    return model_tag if seq_len is None else f"{model_tag}+seq{seq_len}"
+
+
 def profile(model_name: str, model_args: dict, *, model_tag: str = "default",
             microbatch_size: int = 1, seq_len: int | None = None,
             chips_per_host: int = 4, max_hosts: int = 32,
@@ -355,6 +377,9 @@ def profile(model_name: str, model_args: dict, *, model_tag: str = "default",
     `execution` (ExecutionArguments, duck-typed) must match what the engine
     trains with: it changes the measured model (dtype/remat/attention) AND
     the cache tag (pass the same object to effective_tag for loading).
+    `seq_len` is the length measured at (None: `training_seq_len`'s
+    default); a job that states its own passes a `model_tag` that names it
+    (`job_tag`).
 
     File layout matches the reference (profiler.py:290-319) so the planner's
     loader is schema-compatible.
@@ -364,11 +389,18 @@ def profile(model_name: str, model_args: dict, *, model_tag: str = "default",
     path = get_profile_path(model_name, effective_tag(model_tag, execution))
     files = [f"mb{microbatch_size}.json", "allreduce_in_node.json",
              "allreduce_across_nodes.json", "model_args.json"]
-    if all((path / f).exists() for f in files) and not force:
+    version = path / "profile_version.json"
+    fresh = (version.exists()
+             and json.loads(version.read_text()) == PROFILE_VERSION)
+    if fresh and all((path / f).exists() for f in files) and not force:
         # Cache hit requires ALL files: a killed run may have written some.
         validate_model_args(path, model_args)
         return path
     path.mkdir(parents=True, exist_ok=True)
+    if not fresh:
+        # Another microbatch size's rows are of the old version too.
+        for stale in path.glob("mb*.json"):
+            stale.unlink()
     model = build_model(model_name, model_args, execution=execution)
 
     contents = {
@@ -379,6 +411,7 @@ def profile(model_name: str, model_args: dict, *, model_tag: str = "default",
         "allreduce_across_nodes.json":
             json.dumps(profile_allreduce_across_nodes(model, max_hosts)),
         "model_args.json": json.dumps(model_args),
+        "profile_version.json": json.dumps(PROFILE_VERSION),
     }
     # Atomic publish: write temps, then rename — a crash mid-profile never
     # leaves a partial cache that later runs mistake for a hit.

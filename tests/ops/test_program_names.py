@@ -16,6 +16,7 @@ HOT_PATH = ["execution/engine.py", "execution/pipeline.py",
             "execution/precompile.py", "execution/fused.py",
             "parallel/train.py", "serve/engine.py", "serve/batcher.py",
             "models/gpt.py", "models/llama.py", "models/lfm2.py",
+            "models/routed.py", "models/deepseek_v3.py", "ops/flash.py",
             "ops/moe.py"]
 
 

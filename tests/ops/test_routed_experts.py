@@ -238,16 +238,52 @@ def test_gmm_kernel_equals_einsum_per_group(sizes, dtype):
             atol=tol * 8)
 
 
+# (tokens, top k, experts) -> the tile: `lfm2-24b-a2b.steady`'s call (512
+# rows expected an expert: two tiles of 384 from 385 to 768 rows, 16 tiles a
+# layer), `moonlight-16b-a3b.steady`'s (384 expected: ONE tile of 512, where
+# the rule before gave 384 and put the expected rows on its edge), its
+# fallback's (2048 tokens: 192 expected, one tile of 384), 256 expected, a
+# lane's worth and the tests'.
+ROW_TILES = {
+    "lfm2_cell": ((8192, 4, 64), 384),
+    "moonlight_cell": ((4096, 6, 64), 512),
+    "moonlight_fallback": ((2048, 6, 64), 384),
+    "expects_256": ((8192, 8, 256), 384),
+    "under_a_lane": ((1024, 4, 64), 96),
+    "tests_sizes": ((48, 2, 8), 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_TILES))
+def test_row_tile_keeps_the_expected_rows_off_its_edges(case):
+    (tokens, top_k, experts), want = ROW_TILES[case]
+    tile = moe.choose_row_tile(tokens * top_k, experts)
+    assert tile == want
+    expected = tokens * top_k / experts
+    tiles_at = lambda rows: max(-(-int(rows) // tile), 1)
+    # Loads a quarter either way fill the tiles the expected load fills.
+    assert {tiles_at(expected * f) for f in (0.76, 0.9, 1.0, 1.1, 1.25)} == {
+        tiles_at(expected)}
+    unit = moe.LANE if 1.5 * expected >= moe.LANE else moe.SUBLANE
+    assert tile % unit == 0 and tile <= moe.MAX_ROW_TILE
+
+
 def test_tiles_follow_the_shapes():
-    # The cell: 512 rows expected an expert, 768 with room, in two tiles.
-    assert moe.choose_row_tile(8192 * 4, 64) == 384
-    assert moe.choose_row_tile(1024 * 4, 64) == 96       # under a lane: sublanes
-    assert moe.choose_row_tile(8192 * 8, 8) == 512       # 12288 in 24 tiles
-    assert moe.choose_row_tile(96, 8) == 32              # the tests' sizes
+    # lfm2's cell: 16 tiles a layer, as before the rule looked at edges.
+    assert 8 * -(-512 // moe.choose_row_tile(8192 * 4, 64)) == 16
+    # Many tiles an expert: no tile keeps 8192 rows off an edge by much;
+    # 384 leaves 128 rows below and 256 above where 512 sits on one.
+    assert moe.choose_row_tile(8192 * 8, 8) == 384
     assert moe._col_tile(1536, moe.MAX_COL_TILE) == 512
     assert moe._col_tile(2048, moe.MAX_COL_TILE) == 512
     assert moe._col_tile(2048, moe.MAX_TGMM_ROWS) == 1024
     assert moe._col_tile(1536, moe.MAX_TGMM_ROWS) == 768
     assert moe._col_tile(48, moe.MAX_COL_TILE) == 48
+    # 1408 = 11 x 128 (moonlight-16b-a3b's experts): whole, not in lanes.
+    assert moe._col_tile(1408, moe.MAX_COL_TILE) == 1408
+    assert moe._col_tile(1408, moe.MAX_TGMM_ROWS) == 1408
+    assert moe._col_tile(128, moe.MAX_COL_TILE) == 128
+    assert moe._col_tile(384, moe.MAX_COL_TILE) == 384
+    assert moe._col_tile(128 * 17, moe.MAX_COL_TILE) == 128   # over the cap
     rows, tile = moe.buffer_rows(8192, 4, 8, 64)
     assert (rows, tile) == (86 * 384 + 8 * 384, 384)

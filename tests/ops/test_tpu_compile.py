@@ -26,7 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from oobleck_tpu.config import ServeArguments
 from oobleck_tpu.ops import attention
-from oobleck_tpu.ops.flash import flash_attention
+from oobleck_tpu.ops.flash import flash_attention, latent_flash_attention
 from oobleck_tpu.ops.paged_attention import (
     _select_paged_impl,
     _select_paged_verify_impl,
@@ -199,6 +199,8 @@ def test_paged_alibi_compiles(v5e):
 # tiles are one step above the smallest.
 MOE_WIDTHS = {
     "lfm2-24b-a2b-cell": (8192, 2048, 1536, 64, 8, 4),
+    "moonlight-16b-a3b-cell": (4096, 2048, 1408, 64, 8, 6),
+    "moonlight-16b-a3b-seq-2048": (2048, 2048, 1408, 64, 8, 6),
     "all-held": (1024, 2048, 1536, 64, 64, 4),
     "lfm2-tiles": (512, 256, 384, 16, 4, 2),
 }
@@ -277,6 +279,49 @@ def test_flash_grad_module_stays_small(v5e):
     assert len(text) < FLASH_GRAD_MODULE_CHARS, len(text)
 
 
+# Latent attention at `moonlight-16b-a3b.steady`'s microbatch, one sequence
+# of 4096 (and at 2048, ISSUE 35's fallback: the same tiles in fewer pairs), 16
+# heads, scores 128 + 64 wide (padded to 256 in the kernel),
+# values 128 wide and NOT padded to the scores' width, one rotary key a
+# position for all heads. The same three bodies as the plain calls, so the
+# lowered gradient stays within the bound the plain kernels are held to.
+LATENT_WIDTHS = (16, 128, 64, 128)          # heads, Dn, Dr, Dv
+
+
+def _latent_shapes(b, h, s, dn, dr, dv):
+    bf = jnp.bfloat16
+    return [((b, h, s, dn), bf), ((b, h, s, dr), bf), ((b, h, s, dn), bf),
+            ((b, s, dr), bf), ((b, h, s, dv), bf)]
+
+
+def _latent_grads(fn):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=(0, 1, 2, 3, 4))
+
+
+@pytest.mark.parametrize("seq", [2048, 4096])
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_latent_flash_compiles_at_the_cell(v5e, mode, seq):
+    fn = (latent_flash_attention if mode == "fwd"
+          else _latent_grads(latent_flash_attention))
+    text = _compile(fn, v5e[0], *_latent_shapes(
+        1, LATENT_WIDTHS[0], seq, *LATENT_WIDTHS[1:]))
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if mode == "fwd" else 3)
+
+
+def test_latent_grad_module_stays_small(v5e):
+    one = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in
+            _latent_shapes(1, LATENT_WIDTHS[0], 4096, *LATENT_WIDTHS[1:])]
+    text = jax.jit(_latent_grads(latent_flash_attention)).lower(
+        *args).as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert len(text) < FLASH_GRAD_MODULE_CHARS, len(text)
+    # Values, O and dO travel 128 wide; q and k 256.
+    assert "16x4096x128xbf16" in text and "16x4096x256xbf16" in text
+
+
 # Every kernel has a stable name on the device: `name=` on its pallas_call
 # is the innermost component of the operation's JAX name stack, and the
 # chip's compiler names the custom call after that component. A profiler
@@ -284,6 +329,8 @@ def test_flash_grad_module_stays_small(v5e):
 # (`%flash_fwd.`, `%flash_bwd_dq.`, ...) find it after any refactor.
 KERNEL_NAMES = {
     "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
+    "flash_mla_fwd": "latent", "flash_mla_bwd_dq": "latent",
+    "flash_mla_bwd_dkv": "latent",
     "paged_decode": "decode", "paged_verify": "verify",
     "moe_gmm": "moe", "moe_tgmm": "moe",
 }
@@ -305,6 +352,9 @@ def test_kernel_is_named_in_location_and_executable(v5e, name):
         # forward call keeps the kernel's name too.
         fn = _grads(jax.checkpoint(flash_attention))
         shapes = [(FLASH_WIDTHS["gpt3-2.7b"], jnp.bfloat16)] * 3
+    elif KERNEL_NAMES[name] == "latent":
+        fn = _latent_grads(jax.checkpoint(latent_flash_attention))
+        shapes = _latent_shapes(1, 4, 1024, *LATENT_WIDTHS[1:])
     else:
         hq, hkv, d = PAGED_WIDTHS["gpt2"]
         lanes, num_pages, page, table_pages, t = _serve_geometry()

@@ -63,6 +63,28 @@ def test_profile_cache_hit_and_validation(cache):
         profile("gpt2-tiny", {"n_layer": 2}, microbatch_size=2, seq_len=32)
 
 
+@pytest.mark.parametrize("stale", ["absent", "older"])
+def test_profile_of_another_version_is_measured_again(cache, stale):
+    """A cache written before the timed programs changed (no version file,
+    or another number) is no hit, and its rows of other microbatch sizes
+    go with it."""
+    path = profile("gpt2-tiny", {}, microbatch_size=2, seq_len=32)
+    assert json.loads((path / "profile_version.json").read_text()) \
+        == prof.PROFILE_VERSION
+    (path / "mb2.json").write_text(json.dumps("old rows"))
+    (path / "mb4.json").write_text(json.dumps("old rows"))
+    if stale == "absent":
+        (path / "profile_version.json").unlink()
+    else:
+        (path / "profile_version.json").write_text(
+            json.dumps(prof.PROFILE_VERSION - 1))
+    profile("gpt2-tiny", {}, microbatch_size=2, seq_len=32)
+    assert len(json.loads((path / "mb2.json").read_text())) == 6
+    assert not (path / "mb4.json").exists()
+    assert json.loads((path / "profile_version.json").read_text()) \
+        == prof.PROFILE_VERSION
+
+
 def test_profiles_feed_planner(cache):
     profile("gpt2-tiny", {}, microbatch_size=2, seq_len=32)
     profiles = load_profile("gpt2-tiny", "default", 2)
