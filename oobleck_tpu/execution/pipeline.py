@@ -15,8 +15,12 @@ single-controller JAX runtime:
     (pipeline.py:288-427) — shapes are static, no protocol needed;
   * the 1F1B instruction streams (execution.schedule) are interpreted by a
     dependency-driven loop; backward recomputes the stage forward inside the
-    jitted VJP (activation-checkpoint discipline), so only per-microbatch
-    stage *inputs* are stashed, as in 1F1B. The LAST virtual stage has
+    jitted VJP, so only per-microbatch stage *inputs* are stashed, as in
+    1F1B. Inside that VJP a layer's checkpoint (`ops/flash.checkpoint_layer`)
+    keeps the layer's input and what the flash forward kernel wrote (O and
+    the row logsumexp) and recomputes the rest: the forward kernel runs once
+    a layer in `jit_bwd`, and a layer on the XLA path keeps its input
+    alone. The LAST virtual stage has
     nothing to send forward: its forward gives the step one scalar, which
     its backward computes anyway. So training runs ONE program per
     microbatch there, `jit_bwd` = value-and-gradient (loss, grads, dx); its
@@ -55,6 +59,7 @@ from oobleck_tpu.execution.schedule import (
     validate_interleaving,
 )
 from oobleck_tpu.obs import spans
+from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.planning.templates import PipelineTemplate
 from oobleck_tpu.utils import metrics
 
@@ -589,7 +594,7 @@ class PipelineInstance:
             def layer_fn(li):
                 fn = lambda p, c, b: model.apply_layer(li, p, c, b)
                 if remat and 0 < li < last_layer:
-                    fn = jax.checkpoint(fn)
+                    fn = checkpoint_layer(fn)
                 return fn
 
             def apply(params_tuple, x, batch, with_metrics=False):
@@ -622,7 +627,7 @@ class PipelineInstance:
             + (("seq",) if ctx.seq else ())
         )
         block_fn = lambda p, x: model.apply_block(p, x, ctx)
-        block = jax.checkpoint(block_fn) if remat else block_fn
+        block = checkpoint_layer(block_fn) if remat else block_fn
         denom = float(self.microbatch_size * (self.seq_len - 1))
         seq_ax = "seq" if st.sp > 1 else None
         x_spec = P("fsdp" if st.use_fsdp else None, seq_ax, None)

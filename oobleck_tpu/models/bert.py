@@ -27,6 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from oobleck_tpu.models.base import stack_layer_params
 from oobleck_tpu.models.gpt import NEG_INF, ShardCtx, _layer_norm
+from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.ops.attention import _xla_causal_attention
 
 
@@ -210,7 +211,7 @@ class BertModel:
     def forward(self, params, tokens):
         block = self.apply_block
         if self.config.remat:
-            block = jax.checkpoint(block)
+            block = checkpoint_layer(block)
         x = self.embed(params["embed"], tokens)
 
         def body(x, bp):

@@ -30,6 +30,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from oobleck_tpu.models.base import stack_layer_params
+from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.ops.attention import causal_attention
 from oobleck_tpu.parallel.collectives import (
     megatron_f,
@@ -423,7 +424,7 @@ class GPTModel:
         x = self.embed(params["embed"], tokens)
         block = self.apply_block
         if c.remat:
-            block = jax.checkpoint(block)
+            block = checkpoint_layer(block)
 
         def body(x, bp):
             return block(bp, x), None

@@ -28,6 +28,7 @@ from oobleck_tpu.models.gpt import (
     _explicit_bwd,
     _maybe_megatron_f,
 )
+from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.ops.attention import causal_attention
 from oobleck_tpu.parallel.collectives import (
     reduce_from_tp,
@@ -358,7 +359,7 @@ class LlamaModel:
         x = self.embed(params["embed"], tokens)
         block = self.apply_block
         if c.remat:
-            block = jax.checkpoint(block)
+            block = checkpoint_layer(block)
 
         def body(x, bp):
             return block(bp, x), None

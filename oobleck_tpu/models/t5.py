@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from oobleck_tpu.models.base import stack_layer_params
+from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.ops.attention import _xla_causal_attention
 
 NEG_INF = -1e9
@@ -331,8 +332,8 @@ class T5Model:
         enc_block = self.apply_encoder_block
         dec_block = self.apply_decoder_block
         if c.remat:
-            enc_block = jax.checkpoint(enc_block)
-            dec_block = jax.checkpoint(dec_block)
+            enc_block = checkpoint_layer(enc_block)
+            dec_block = checkpoint_layer(dec_block)
 
         x = self.embed(params["embed"], input_ids)
         x, _ = jax.lax.scan(lambda x, bp: (enc_block(bp, x), None), x,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from oobleck_tpu.models.base import stack_layer_params
 from oobleck_tpu.models.gpt import _layer_norm
 from oobleck_tpu.models.bert import BertConfig, BertModel
+from oobleck_tpu.ops import checkpoint_layer
 
 
 @dataclass(frozen=True)
@@ -174,7 +175,7 @@ class ViTModel:
     def forward(self, params, pixels):
         block = self._encoder.apply_block
         if self.config.remat:
-            block = jax.checkpoint(block)
+            block = checkpoint_layer(block)
         x = self.embed(params["embed"], pixels)
 
         def body(x, bp):

@@ -5,6 +5,12 @@ call sites; this surface re-exports the dispatching entry points."""
 from oobleck_tpu.ops.attention import causal_attention, select_attention_impl
 
 
+def checkpoint_layer(fn, **kwargs):
+    from oobleck_tpu.ops.flash import checkpoint_layer as wrap
+
+    return wrap(fn, **kwargs)
+
+
 def ring_attention(*args, **kwargs):
     from oobleck_tpu.ops.ring_attention import ring_attention as fn
 
@@ -23,5 +29,5 @@ def switch_moe(*args, **kwargs):
     return fn(*args, **kwargs)
 
 
-__all__ = ["causal_attention", "select_attention_impl", "ring_attention",
-           "ulysses_attention", "switch_moe"]
+__all__ = ["causal_attention", "select_attention_impl", "checkpoint_layer",
+           "ring_attention", "ulysses_attention", "switch_moe"]

@@ -52,6 +52,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -89,6 +90,21 @@ PLAIN = KernelNames("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 # scores wider than the values, under names of their own, so that a reader
 # of `%flash_fwd.` never counts them at one width.
 LATENT = KernelNames("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")
+
+
+# The forward rule's names for the kernel's two outputs, O and the row
+# logsumexp: the residuals that only a second kernel call could give back.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
+def checkpoint_layer(fn, **kwargs):
+    """`jax.checkpoint` for a layer whose body can reach `_flash`: the
+    backward pass recomputes the layer from its input, all but what the
+    flash forward kernel wrote. A layer whose attention took the XLA path
+    has no value by these names, and its checkpoint keeps nothing."""
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES), **kwargs)
 
 
 class Tiles(NamedTuple):
@@ -518,6 +534,14 @@ def _flash(q, k, v, bias, slopes, scale, causal, names):
 
 def _flash_fwd(q, k, v, bias, slopes, scale, causal, names):
     out, lse = _flash_forward(q, k, v, bias, slopes, scale, causal, names)
+    # All that the kernel wrote goes by a name, so that a layer's checkpoint
+    # (`checkpoint_layer`) keeps it and the recomputed forward holds no
+    # kernel call. q, k, v are not named: they come back from the layer's
+    # input by cheap XLA, at three times O's bytes. LSE stays in the layout
+    # the backward kernels read.
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
+    _count_named_residuals(names.fwd)
     return out, (q, k, v, bias, slopes, out, lse)
 
 
@@ -593,6 +617,18 @@ def latent_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
         scale = q.shape[-1] ** -0.5
     _count_latent_calls()
     return _flash(q, k, v, None, None, scale, True, LATENT)
+
+
+def _count_named_residuals(kernel: str) -> None:
+    """`oobleck_flash_residuals_named_total{kernel}`: once a forward rule
+    traced (not once a step), by the forward kernel whose O and LSE it
+    named."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_flash_residuals_named_total",
+        "Flash forward rules traced with O and LSE named for the layer's "
+        "checkpoint, by kernel").inc(kernel=kernel)
 
 
 def _count_latent_calls() -> None:

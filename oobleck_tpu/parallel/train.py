@@ -12,7 +12,9 @@ dispatching per-instruction NCCL ops): here the whole schedule is *compiled*.
            block slice (Megatron-TP + fsdp gathers inside), `lax.ppermute`
            shifts activations to the next stage. XLA differentiates through
            the permute, so the backward pipeline comes from `jax.grad`, with
-           `jax.checkpoint` standing in for 1F1B's memory discipline.
+           a checkpoint of each tick (`ops.checkpoint_layer`: the tick's
+           input and flash's O and LSE are kept) standing in for 1F1B's
+           memory discipline.
   Phase C  head/loss: vocab-parallel cross-entropy, microbatches again
            sharded over `stage` so the lm-head matmul uses all devices.
 
@@ -49,6 +51,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from oobleck_tpu.models.gpt import ShardCtx
+from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.parallel import overlap as ovl
 from oobleck_tpu.parallel.collectives import pvary_to
 from oobleck_tpu.parallel.mesh import (
@@ -308,7 +311,7 @@ def _overlap_loss_and_grads(model, mesh, specs, ctx: ShardCtx, cfg,
                         outputs), None
 
             tick_fn = tick_db if db_sends else tick_plain
-            tick = jax.checkpoint(tick_fn) if remat else tick_fn
+            tick = checkpoint_layer(tick_fn) if remat else tick_fn
             zero = jnp.zeros_like(x[0])
             init = ((zero, zero, jnp.zeros_like(x)) if db_sends
                     else (zero, jnp.zeros_like(x)))
@@ -416,7 +419,7 @@ def build_train_step(model, mesh, *, num_microbatches: int, optimizer=None,
             state = lax.ppermute(out, AXIS_STAGE, perm)
             return (state, outputs), None
 
-        tick = jax.checkpoint(tick_fn) if remat else tick_fn
+        tick = checkpoint_layer(tick_fn) if remat else tick_fn
         vary = (AXIS_DATA, AXIS_FSDP, AXIS_STAGE)
         state0 = pvary_to(jnp.zeros_like(x[0]), vary)
         outputs0 = pvary_to(jnp.zeros_like(x), vary)

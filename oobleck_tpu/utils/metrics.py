@@ -492,6 +492,10 @@ class FlightRecorder:
         with self._lock:
             return list(self._ring)
 
+    def clear(self) -> None:
+        with self._lock:
+            self._ring.clear()
+
     def dump(self, reason: str) -> str | None:
         d = metrics_dir()
         if d is None:

@@ -74,6 +74,18 @@ def _clear_jax_caches():
     jax.clear_caches()
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _empty_flight_ring():
+    """Every test module starts from an empty flight ring. The ring is
+    process-global and bounded (256 events); tests count the events an
+    action added (`events()[n0:]`, `len(...) == before + 1`), which reads
+    nothing new once the modules a worker ran earlier have filled it."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.flight_recorder().clear()
+    yield
+
+
 @pytest.fixture(scope="session")
 def devices8():
     devs = jax.devices()

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from oobleck_tpu.ops import attention, flash
+from tests.ops.programs import pallas_calls
 
 NAMES = ("out", "dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv")
 
@@ -108,25 +109,9 @@ def test_a_rotary_key_per_head_is_refused():
 
 def _kernels(fn, *args):
     """(name, operand shapes) of every pallas_call of grad(fn)."""
-    found = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append((eqn.params["name"],
-                              [v.aval.shape for v in eqn.invars[3:]]))
-                continue
-            for param in eqn.params.values():
-                for sub in (param if isinstance(param, (list, tuple))
-                            else [param]):
-                    inner = getattr(sub, "jaxpr", None)
-                    if inner is not None:
-                        walk(inner if hasattr(inner, "eqns") else inner.jaxpr)
-
-    walk(jax.make_jaxpr(jax.grad(
+    return pallas_calls(jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(fn(*a)), argnums=tuple(range(len(args)))))(
             *args).jaxpr)
-    return found
 
 
 def test_names_of_both_families():

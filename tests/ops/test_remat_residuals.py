@@ -1,0 +1,176 @@
+"""What a layer's checkpoint keeps of flash attention (`ops/flash.py`).
+
+`_flash_fwd` names the two things the forward kernel wrote, O and the row
+logsumexp, and `checkpoint_layer` is `jax.checkpoint` with the policy that
+keeps values by those names. So the forward a backward pass recomputes
+holds no kernel call: its only consumers of the kernel's outputs are the
+two kept values, and a `pallas_call` has no side effect.
+
+(i) The three benchmark cells' one-stage `jit_bwd`, compiled for a
+described (not attached) TPU v5e: each forward kernel once a layer that
+has attention, the expert kernels as they were, temporaries under a bound.
+Nothing executes there; no number comes out. (ii) On the CPU, kernels
+interpreted: half the forward-kernel equations of a bare `jax.checkpoint`
+and bit-identical gradients. (iii) A layer whose attention took the XLA
+path has no named value, and lowers to the text a bare checkpoint gives.
+
+A file of its own: under `--dist loadfile` its three compiles (about 100 s
+together) do not lengthen `test_tpu_compile.py`'s worker.
+"""
+
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs: not /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+
+from oobleck_tpu.ops import attention, flash
+from tests.ops.programs import cell_stage, pallas_calls
+
+# cell -> (microbatch, sequence), its attention's kernels and how many
+# layers call them, the bound on `jit_bwd`'s temporaries. The compile gave
+# 1,188,759,552 / 2,136,438,784 / 1,732,474,368 bytes when the policy went
+# in (PR 36; 923 MB / 2.22 GB / 1.68 GB before): O and LSE of every
+# attention layer are the program's to hold, a second copy is not.
+CELLS = {
+    "gpt3-2.7b": ((4, 1024), flash.PLAIN, 3, 1.25e9),
+    "lfm2-24b-a2b": ((8, 1024), flash.PLAIN, 1, 2.3e9),
+    "moonlight-16b-a3b": ((1, 4096), flash.LATENT, 5, 1.8e9),
+}
+ROUTED = {"moe_gmm": 36, "moe_tgmm": 12}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture
+def compiled_for_tpu(monkeypatch):
+    """As `test_tpu_compile.py`'s: kernels lower through Mosaic, "auto"
+    resolves again, and the persistent cache stays out of it."""
+    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
+    attention.select_attention_impl.cache_clear()
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+    attention.select_attention_impl.cache_clear()
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
+                                                      cell):
+    (mb, seq), names, layers, temp_bound = CELLS[cell]
+    st, params, batch = cell_stage(cell, v5e, microbatch=mb, seq=seq)
+    compiled = st.bwd[0].lower(params, params, None, batch).compile()
+    calls = re.findall(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', compiled.as_text())
+    count = {k: calls.count(k) for k in set(calls)}
+    assert {k: count.pop(k, 0) for k in names} == dict.fromkeys(names, layers)
+    # What the policy does not name is recomputed as before: the routed
+    # layers' three forward products run twice (ROADMAP.md S8 a).
+    assert count == ({} if cell == "gpt3-2.7b" else ROUTED)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_bound
+
+
+def _plain_block(w, x):
+    b, s, d = x.shape
+    q, k, v = (jnp.transpose((x @ w[i]).reshape(b, s, 2, d // 2), (0, 2, 1, 3))
+               for i in range(3))
+    out = flash.flash_attention(q, k, v)
+    return x + jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, d) @ w[3]
+
+
+def _latent_block(w, x):
+    # Two heads: scores 16 + 8 wide over values of 16, one rotary key a
+    # position.
+    b, s, d = x.shape
+    heads = lambda y: jnp.transpose(y.reshape(b, s, 2, -1), (0, 2, 1, 3))
+    q_nope, k_nope, v = (heads(x @ w[i]) for i in range(3))
+    q_rope = heads(x @ w[3][:, :16])
+    k_rope = x @ w[3][:, 16:24]
+    out = flash.latent_flash_attention(q_nope, q_rope, k_nope, k_rope, v)
+    return x + jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, d) @ w[3]
+
+
+def _xla_block(w, x):
+    b, s, d = x.shape
+    q, k, v = (jnp.transpose((x @ w[i]).reshape(b, s, 2, d // 2), (0, 2, 1, 3))
+               for i in range(3))
+    out = attention.causal_attention(q, k, v, impl="xla")
+    return x + jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, d) @ w[3]
+
+
+def _two_blocks(block, wrap):
+    layer = wrap(block)
+    return jax.grad(lambda w, x: jnp.sum(layer(w[1], layer(w[0], x)) ** 2),
+                    argnums=(0, 1))
+
+
+def _operands():
+    rng = np.random.default_rng(0)
+    w = jnp.asarray(rng.normal(0, 0.2, (2, 4, 32, 32)), jnp.float32)
+    x = jnp.asarray(rng.normal(0, 1.0, (1, 128, 32)), jnp.float32)
+    return w, x
+
+
+@pytest.mark.parametrize("block,names", [(_plain_block, flash.PLAIN),
+                                         (_latent_block, flash.LATENT)],
+                         ids=["plain", "latent"])
+def test_kept_residuals_halve_the_forward_calls_and_keep_the_gradients(
+        block, names):
+    w, x = _operands()
+    bare = _two_blocks(block, jax.checkpoint)
+    kept = _two_blocks(block, flash.checkpoint_layer)
+    calls = lambda fn: [name for name, _ in pallas_calls(
+        jax.make_jaxpr(fn)(w, x).jaxpr)]
+    assert calls(bare).count(names.fwd) == 4
+    assert calls(kept).count(names.fwd) == 2
+    for fn in (bare, kept):     # the backward kernels: once a block
+        assert calls(fn).count(names.bwd_dq) == 2
+        assert calls(fn).count(names.bwd_dkv) == 2
+    # A kept value is the value a second call would have written.
+    for got, want in zip(jax.tree.leaves(jax.jit(kept)(w, x)),
+                         jax.tree.leaves(jax.jit(bare)(w, x))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_layer_on_the_xla_path_lowers_as_under_a_bare_checkpoint():
+    """Names absent, nothing kept: what every CPU test leans on."""
+    w, x = _operands()
+    text = lambda wrap: jax.jit(_two_blocks(_xla_block, wrap)).lower(
+        w, x).as_text()
+    assert text(flash.checkpoint_layer) == text(jax.checkpoint)
+    assert not pallas_calls(jax.make_jaxpr(
+        _two_blocks(_xla_block, flash.checkpoint_layer))(w, x).jaxpr)
+
+
+def test_named_residuals_are_counted_once_a_forward_rule_traced():
+    from oobleck_tpu.utils import metrics
+
+    named = metrics.registry().counter("oobleck_flash_residuals_named_total")
+    before = {n: named.value(kernel=n) for n in ("flash_fwd", "flash_mla_fwd")}
+    w, x = _operands()
+    fn = jax.jit(jax.grad(lambda w, x: jnp.sum(_plain_block(w[0], x))))
+    fn(w, x)
+    fn(w, x)                        # a cache hit traces nothing
+    assert named.value(kernel="flash_fwd") - before["flash_fwd"] == 1
+    # The XLA path and a call that is not differentiated name nothing.
+    jax.jit(jax.grad(lambda w, x: jnp.sum(_xla_block(w[0], x))))(w, x)
+    jax.jit(_latent_block)(w[0], x)
+    assert named.value(kernel="flash_fwd") - before["flash_fwd"] == 1
+    assert named.value(kernel="flash_mla_fwd") == before["flash_mla_fwd"]

@@ -13,6 +13,7 @@ for it.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs: not /tmp
 
@@ -26,7 +27,11 @@ from jax.sharding import SingleDeviceSharding
 
 from oobleck_tpu.config import ServeArguments
 from oobleck_tpu.ops import attention
-from oobleck_tpu.ops.flash import flash_attention, latent_flash_attention
+from oobleck_tpu.ops.flash import (
+    checkpoint_layer,
+    flash_attention,
+    latent_flash_attention,
+)
 from oobleck_tpu.ops.paged_attention import (
     _select_paged_impl,
     _select_paged_verify_impl,
@@ -34,6 +39,7 @@ from oobleck_tpu.ops.paged_attention import (
     paged_verify_attention,
 )
 from oobleck_tpu.serve.kv_blocks import pages_for
+from tests.ops.programs import cell_stage
 
 # [B, H, S, D] of one microbatch's attention call: gpt2 124M
 # (examples/gpt2.yaml: microbatch 8, 12 heads of 64, seq 1024), a llama-7B
@@ -139,21 +145,26 @@ def test_flash_variant_compiles(v5e, form):
         _compile(fn, v5e[0], *qkv)
 
 
-def test_flash_compiles_inside_check_vma_shard_map(v5e):
+@pytest.mark.parametrize("remat", [False, True], ids=["bare", "remat"])
+def test_flash_compiles_inside_check_vma_shard_map(v5e, remat):
     """The training step's shape: causal_attention(impl="auto") under a
     default (check_vma=True) shard_map with the batch split over a data
     axis, differentiated from OUTSIDE so the spec transposes run. A bare
-    out_shape (no `vma`) fails this at trace time on any TPU."""
+    out_shape (no `vma`) fails this at trace time on any TPU. Under the
+    fused step's remat the body is a `checkpoint_layer` inside the
+    shard_map: O and LSE keep their varying axes through their names, and
+    the forward kernel is in the program once."""
     mesh = Mesh(v5e[:2], ("data",))
     spec = P("data")
-    sm = jax.shard_map(
-        lambda q, k, v: attention.causal_attention(q, k, v, impl="auto"),
-        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
+    body = lambda q, k, v: attention.causal_attention(q, k, v, impl="auto")
+    sm = jax.shard_map(checkpoint_layer(body) if remat else body,
+                       mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
     b, h, s, d = FLASH_WIDTHS["gpt2"]
     arg = jax.ShapeDtypeStruct((2 * b, h, s, d), jnp.bfloat16,
                                sharding=NamedSharding(mesh, spec))
     text = jax.jit(_grads(sm)).lower(arg, arg, arg).compile().as_text()
     assert "tpu_custom_call" in text
+    assert len(re.findall(r"%flash_fwd(?:\.\d+)? = ", text)) == 1
 
 
 @pytest.mark.parametrize("width", sorted(PAGED_WIDTHS))
@@ -338,8 +349,6 @@ KERNEL_NAMES = {
 
 @pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
 def test_kernel_is_named_in_location_and_executable(v5e, name):
-    import re
-
     one = SingleDeviceSharding(v5e[0])
     if KERNEL_NAMES[name] == "moe":
         # Under remat and grad, as a routed block's stage program runs it.
@@ -409,35 +418,7 @@ def test_head_loss_value_and_grad_holds_no_f32_logits(v5e):
 # room, held a second time (a donation that did not take, or a gradient set
 # built in temporaries and added afterwards) they do not.
 def test_cell_sized_backward_holds_the_gradients_once(v5e):
-    import re
-
-    from oobleck_tpu.config import ExecutionArguments
-    from oobleck_tpu.execution.pipeline import PipelineInstance
-    from oobleck_tpu.execution.precompile import _sds as sds
-    from oobleck_tpu.models import build_model
-    from oobleck_tpu.planning.templates import PipelineTemplate, StageSpec
-
-    mb, seq, num_mb = 4, 1024, 8
-    model = build_model(
-        "gpt3-2.7b", {"num_layers": 3},
-        execution=ExecutionArguments(precision="bfloat16", remat=True))
-    n = model.num_pipeline_layers
-    template = PipelineTemplate(
-        (StageSpec(tuple(range(n)), 1, 1.0, 3.0, 1000),), 10.0, n, 1, 1)
-    pipe = PipelineInstance(
-        pipeline_id=0, template=template, ranks=[0], model=model,
-        devices=list(v5e), num_microbatches=num_mb,
-        total_num_microbatches=num_mb, microbatch_size=mb, seq_len=seq,
-        materialize_params=False)
-    st = pipe.stages[0]
-
-    params = tuple(
-        jax.tree.map(sds, jax.eval_shape(
-            lambda r, _li=li: model.init_layer(r, _li), jax.random.PRNGKey(0)),
-            st.param_shardings[li])
-        for li in st.chunks[0])
-    batch = {k: sds(v, st.batch_sharding)
-             for k, v in model.sample_batch(mb, seq).items()}
+    st, params, batch = cell_stage("gpt3-2.7b", v5e, microbatch=4, seq=1024)
     leaves = jax.tree.leaves(params)
     grad_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
 
