@@ -79,6 +79,20 @@ register("moonlight-16b-a3b")(lambda o: _deepseek_v3(o))
 register("moonlight-tiny")(lambda o: _deepseek_v3(o, vocab_size=256, hidden_size=64, num_layers=3, num_heads=4, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128, moe_intermediate_size=32, num_experts=8, num_experts_per_tok=3, max_position_embeddings=128))
 
 
+def _nemotron_h(overrides: dict[str, Any], **preset):
+    from oobleck_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
+
+    return NemotronHModel(
+        NemotronHConfig().override(**preset).override(**overrides))
+
+
+# Nemotron-H family (`nemotron_h`): every layer ONE mixer by a published
+# pattern: Mamba-2, attention, or routed experts without a gate beside a
+# shared one; the defaults are NVIDIA-Nemotron-3-Nano-30B-A3B's.
+register("nemotron-3-nano-30b-a3b")(lambda o: _nemotron_h(o))
+register("nemotron-h-tiny")(lambda o: _nemotron_h(o, vocab_size=256, hidden_size=64, num_layers=5, hybrid_override_pattern="MEM*E", mamba_num_heads=4, mamba_head_dim=16, ssm_state_size=16, n_groups=2, chunk_size=16, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=40, moe_intermediate_size=40, moe_shared_expert_intermediate_size=80, num_experts=8, num_experts_per_tok=3, max_position_embeddings=128))
+
+
 # Bloom family: GPT architecture with ALiBi position biases (no wpe)
 register("bloom-560m")(lambda o: _gpt(o, vocab_size=250880, hidden_size=1024, num_layers=24, num_heads=16, position_embedding="alibi"))
 register("bloom-7b1")(lambda o: _gpt(o, vocab_size=250880, hidden_size=4096, num_layers=30, num_heads=32, position_embedding="alibi"))

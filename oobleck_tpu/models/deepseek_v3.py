@@ -23,12 +23,13 @@ Every block is `h = x + Attn(N(x)); y = h + FF(N(h))`, RMSNorm `N`:
                 `moe_intermediate_size` on every token, weight 1.
 
 The layer list, one chip's share (`num_experts_held`, `expert_offset`,
-`vocab_rows_held`), the feed-forwards and the routing probe are
-`models/routed.py`'s, shared with `models/lfm2.py`. The shared experts are
-added HERE and not by `routed_experts`: every chip of an expert-parallel
-group computes them alike, so the parts the shares give add up to the layer
-with them counted once. `layer_name` names a block by its kind, so the
-planner's profiler times each kind once.
+`vocab_rows_held`), the feed-forwards, the shared experts' sum and the
+routing probe are `models/routed.py`'s, shared with `models/lfm2.py` and
+`models/nemotron_h.py`. The shared experts are added there and not by
+`routed_experts`: every chip of an expert-parallel group computes them
+alike, so the parts the shares give add up to the layer with them counted
+once. `layer_name` names a block by its kind, so the planner's profiler
+times each kind once.
 """
 
 from __future__ import annotations
@@ -180,16 +181,3 @@ class DeepseekV3Model(RoutedShareModel):
             kv[..., :dn], rotate_half(kv_a[..., r:], c.rope_theta),
             kv[..., dn:], impl=c.attention_impl)
         return jnp.einsum("bhsd,hde->bse", attn, p["wo"].astype(dt))
-
-    @jax.named_scope("mlp")
-    def feed_forward(self, block: int, p, h, *, forced_experts=None,
-                     return_routing: bool = False):
-        """A routed block: the held experts' part plus the shared experts."""
-        if not self.is_routed(block):
-            return self.dense_ff(p, h)
-        shared = self.dense_ff(p["shared"], h)
-        out = self.routed_ff(p, h, forced_experts=forced_experts,
-                             return_routing=return_routing)
-        if return_routing:
-            return out[0] + shared, out[1]
-        return out + shared

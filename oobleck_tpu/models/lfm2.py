@@ -44,6 +44,7 @@ from oobleck_tpu.models.routed import (  # noqa: F401  (routing_probe: its calle
     rms_norm,
     rotate_half,
     routing_probe,
+    short_conv,
 )
 from oobleck_tpu.ops.attention import causal_attention
 
@@ -112,18 +113,6 @@ class Lfm2Config(HeldShare):
                 f"{CONV!r} / {ATTN!r}, got {ops}")
         new.check_share()
         return new
-
-
-def short_conv(bu: jax.Array, taps: jax.Array) -> jax.Array:
-    """Depthwise causal convolution over the sequence, as shifted
-    multiply-adds: z_t = sum_j taps[j] * bu_{t-j}. bu [B, S, D], taps
-    [L, D]."""
-    s = bu.shape[1]
-    z = bu * taps[0]
-    for j in range(1, taps.shape[0]):
-        shifted = jnp.pad(bu, ((0, 0), (j, 0), (0, 0)))[:, :s]
-        z = z + shifted * taps[j]
-    return z
 
 
 class Lfm2Model(RoutedShareModel):

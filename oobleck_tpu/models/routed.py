@@ -1,13 +1,16 @@
 """What the decoders with routed experts share (`models/lfm2.py`,
-`models/deepseek_v3.py`): a layer list whose blocks DIFFER, one chip's share of
-an expert-parallel deployment as a model of its own, and the probe that
-reads where a sequence was routed.
+`models/deepseek_v3.py`, `models/nemotron_h.py`): a layer list whose blocks
+DIFFER, one chip's share of an expert-parallel deployment as a model of its
+own, and the probe that reads where a sequence was routed.
 
-Every block is `h = x + Op(N(x)); y = h + FF(N(h))` with RMSNorm `N`. A
-family says what `Op` is for a block (`operator_out`) and which blocks are
-routed (`is_routed`); the dense SwiGLU, the routed call
-(`ops/moe.routed_experts`), the embedding and the untied head over the
-vocabulary rows held, the loss and the chaining of layers are here.
+A block is one or two residual branches, each behind an RMSNorm `N` of its
+own: `x + Op(N(x))` (`OP`) and `x + FF(N(x))` (`FF`). A family says which
+a block has (`branches`: both, in that order, unless it says otherwise),
+what `Op` is (`operator_out`) and which blocks are routed (`is_routed`);
+the feed-forwards (SwiGLU, or `W2 relu(W1 h)^2` for an entry without `w3`),
+the routed call (`ops/moe.routed_experts`), the shared expert added to the
+routed sum, the embedding and the untied head over the vocabulary rows
+held, the loss and the chaining of layers are here.
 
 The share: `num_experts_held` experts from `expert_offset` (the router still
 scores all `num_experts`; the layer gives the part its held experts give),
@@ -81,9 +84,26 @@ def rotate_half(x: jax.Array, theta: float) -> jax.Array:
     return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
+def short_conv(bu: jax.Array, taps: jax.Array) -> jax.Array:
+    """Depthwise causal convolution over the sequence, as shifted
+    multiply-adds: z_t = sum_j taps[j] * bu_{t-j}. bu [B, S, D], taps
+    [L, D]."""
+    s = bu.shape[1]
+    z = bu * taps[0]
+    for j in range(1, taps.shape[0]):
+        shifted = jnp.pad(bu, ((0, 0), (j, 0), (0, 0)))[:, :s]
+        z = z + shifted * taps[j]
+    return z
+
+
 # --------------------------------------------------------------------- #
 # the layer list                                                         #
 # --------------------------------------------------------------------- #
+
+# A block's residual branches, by the norm and the parameters they read:
+# `ln_op` and the operator's, `ln_ff` and `ff`.
+OP, FF = "op", "ff"
+
 
 class RoutedShareModel:
     """Layer list [embed, block_0 .. block_{L-1}, head], as every family's,
@@ -104,6 +124,10 @@ class RoutedShareModel:
     def operator_out(self, block: int, p, h):
         """Op(h) of block `block`, `p` the block's parameters."""
         raise NotImplementedError
+
+    def branches(self, block: int) -> tuple[str, ...]:
+        """The residual branches of block `block`, in order."""
+        return (OP, FF)
 
     def _init_block(self, rng, block: int):
         raise NotImplementedError
@@ -176,9 +200,14 @@ class RoutedShareModel:
         return p["wte"][tokens].astype(self.config.dtype)
 
     def dense_ff(self, p, h):
-        """SwiGLU: W2 (silu(W1 h) * W3 h)."""
+        """SwiGLU, W2 (silu(W1 h) * W3 h); an entry without `w3` has no
+        gate: W2 relu(W1 h)^2."""
         dt = self.config.dtype
-        g = jax.nn.silu(h @ p["w1"].astype(dt)) * (h @ p["w3"].astype(dt))
+        if "w3" in p:
+            g = jax.nn.silu(h @ p["w1"].astype(dt)) * (h @ p["w3"].astype(dt))
+        else:
+            g = jnp.square(jax.nn.relu(
+                (h @ p["w1"].astype(dt)).astype(jnp.float32))).astype(dt)
         return g @ p["w2"].astype(dt)
 
     def routed_ff(self, p, h, *, forced_experts=None,
@@ -191,7 +220,7 @@ class RoutedShareModel:
         b, s, e = h.shape
         out = routed_experts(
             h.reshape(b * s, e), p["router"], p.get("expert_bias"),
-            p["w1"], p["w3"], p["w2"],
+            p["w1"], p.get("w3"), p["w2"],
             num_experts=c.num_experts, top_k=c.num_experts_per_tok,
             expert_offset=c.expert_offset, norm_topk_prob=c.norm_topk_prob,
             routed_scaling_factor=c.routed_scaling_factor,
@@ -206,26 +235,43 @@ class RoutedShareModel:
     @jax.named_scope("mlp")
     def feed_forward(self, block: int, p, h, *, forced_experts=None,
                      return_routing: bool = False):
-        """Dense SwiGLU or the routed experts, by the block. With
-        `return_routing` a routed block also returns its chosen experts
-        [B, S, k]."""
+        """The dense feed-forward or the routed experts, by the block: of
+        a routed block the part its held experts give, plus, where the
+        entry has them, the `shared` experts (on every token, weight 1:
+        every chip of an expert-parallel group computes them alike, so the
+        parts the shares give add up to the layer with them counted once).
+        With `return_routing` a routed block also returns its chosen
+        experts [B, S, k]."""
         if not self.is_routed(block):
             return self.dense_ff(p, h)
-        return self.routed_ff(p, h, forced_experts=forced_experts,
-                              return_routing=return_routing)
+        shared = self.dense_ff(p["shared"], h) if "shared" in p else None
+        out = self.routed_ff(p, h, forced_experts=forced_experts,
+                             return_routing=return_routing)
+        if shared is None:
+            return out
+        if return_routing:
+            return out[0] + shared, out[1]
+        return out + shared
 
     def apply_block(self, block: int, p, x, *, forced_experts=None,
                     return_routing: bool = False):
         c = self.config
-        h = rms_norm(x, p["ln_op"]["scale"], c.norm_eps)
-        x = x + self.operator_out(block, p, h)
-        h = rms_norm(x, p["ln_ff"]["scale"], c.norm_eps)
-        out = self.feed_forward(block, p["ff"], h,
-                                forced_experts=forced_experts,
-                                return_routing=return_routing)
+        experts = None
+        for branch in self.branches(block):
+            if branch == OP:
+                h = rms_norm(x, p["ln_op"]["scale"], c.norm_eps)
+                x = x + self.operator_out(block, p, h)
+                continue
+            h = rms_norm(x, p["ln_ff"]["scale"], c.norm_eps)
+            out = self.feed_forward(block, p["ff"], h,
+                                    forced_experts=forced_experts,
+                                    return_routing=return_routing)
+            if return_routing and self.is_routed(block):
+                out, experts = out
+            x = x + out
         if return_routing and self.is_routed(block):
-            return x + out[0], out[1]
-        return x + out
+            return x, experts
+        return x
 
     @jax.named_scope("lm_head")
     def head(self, p, x):

@@ -112,9 +112,10 @@ def switch_moe(
 # drops nothing and does work for the (token, slot) pairs that are routed
 # to the experts this chip HOLDS: pairs are laid out by expert in one
 # buffer of the static worst-case length, each expert's rows padded to a
-# whole number of row tiles, and the three products run as grouped matrix
+# whole number of row tiles, and the products (three of a SwiGLU expert, two
+# of an expert without a gate) run as grouped matrix
 # multiplications that visit the tiles in use and skip the empty tail. What
-# XLA does around them (rows in and out of the buffer, silu x up) loops
+# XLA does around them (rows in and out of the buffer, the activation) loops
 # over the tiles in use too, so no work follows the buffer's length but
 # its zero fill. Kernels (stable names on the `pallas_call`, so a device trace shows
 # `%moe_gmm.N` / `%moe_tgmm.N`):
@@ -154,7 +155,10 @@ def _col_tile(n: int, limit: int) -> int:
     lane and `n` fits MAX_WHOLE_COLS (1408 = 11 x 128: in 128-column tiles
     the rows are read eleven times over and a grid step carries a tenth of
     a microsecond of products; whole, `moe_gmm_roofline` read 27.2 % against
-    16.9 % and the step 920.6 against 1,008.8 ms, my chip runs, PR 35)."""
+    16.9 % and the step 920.6 against 1,008.8 ms, my chip runs, PR 35).
+    1856 = 14.5 x 128 has no tile at all and is taken whole too: padded to
+    1920 by the call (384-column tiles) the step read 993.1 against 928.4
+    ms and `moe_tgmm` 0.622 against 0.399 ms a call (my chip runs, PR 37)."""
     tiles = [LANE * m for m in range(1, limit // LANE + 1)
              if n % (LANE * m) == 0]
     if tiles == [LANE] and LANE < n <= MAX_WHOLE_COLS:
@@ -593,6 +597,30 @@ def _swiglu_bwd(tile, res, d_hidden):
 _swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _relu2(pre, plan: RoutingPlan, tile: int):
+    """relu(pre)^2 in float32, over the tiles in use: the activation of
+    experts without a gate."""
+    return _over_tiles(plan, tile, lambda g: (
+        jnp.square(jax.nn.relu(g.astype(jnp.float32))).astype(g.dtype),),
+        pre)[0]
+
+
+def _relu2_fwd(pre, plan, tile):
+    return _relu2(pre, plan, tile), (pre, plan)
+
+
+def _relu2_bwd(tile, res, d_hidden):
+    pre, plan = res
+    (d_pre,) = _over_tiles(plan, tile, lambda g, d: ((
+        2.0 * jax.nn.relu(g.astype(jnp.float32)) * d.astype(jnp.float32)
+    ).astype(pre.dtype),), pre, d_hidden)
+    return d_pre, None
+
+
+_relu2.defvjp(_relu2_fwd, _relu2_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _gate_and_up(rows, w1, w3, plan: RoutingPlan, tile: int):
     """rows x W1 and rows x W3. One function, so that the two gradients of
@@ -620,6 +648,17 @@ def _gate_and_up_bwd(tile, res, cotangents):
 
 
 _gate_and_up.defvjp(_gate_and_up_fwd, _gate_and_up_bwd)
+
+
+def _count_ungated_call() -> None:
+    """`oobleck_moe_ungated_calls_total`: counted where the call is built,
+    once a routed layer of every program traced (not once a step)."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_moe_ungated_calls_total",
+        "Routed-expert calls without a gate (W2 relu(W1 x)^2) built into "
+        "traced programs").inc()
 
 
 def route(x, router_w, expert_bias, *, top_k: int,
@@ -652,7 +691,7 @@ def routed_experts(
     router_w: jax.Array,
     expert_bias: jax.Array | None,
     w1: jax.Array,
-    w3: jax.Array,
+    w3: jax.Array | None,
     w2: jax.Array,
     *,
     num_experts: int,
@@ -663,8 +702,10 @@ def routed_experts(
     forced_experts: jax.Array | None = None,
     return_routing: bool = False,
 ):
-    """Dropless top-k sigmoid-routed SwiGLU experts, the part that the
-    experts held here give.
+    """Dropless top-k sigmoid-routed experts, the part that the experts
+    held here give. SwiGLU experts, or, with `w3` None, experts WITHOUT a
+    gate: W2 relu(W1 x)^2, two grouped products forward and four backward
+    (`grouped_matmul`'s own dX and dW) where SwiGLU has three and six.
 
     x [T, D]; router_w [D, num_experts]; expert_bias [num_experts] or
     None; w1, w3 [held, D, F], w2 [held, F, D]: experts `expert_offset` ..
@@ -689,8 +730,13 @@ def routed_experts(
     plan = plan_routing(local.astype(jnp.int32), held, rows, tile)
 
     xs = _dispatch(x, plan, tile, top_k)
-    gate, up = _gate_and_up(xs, w1, w3, plan, tile)
-    out = grouped_matmul(_swiglu(gate, up, plan, tile), w2, plan, tile)
+    if w3 is None:
+        _count_ungated_call()
+        hidden = _relu2(grouped_matmul(xs, w1, plan, tile), plan, tile)
+    else:
+        gate, up = _gate_and_up(xs, w1, w3, plan, tile)
+        hidden = _swiglu(gate, up, plan, tile)
+    out = grouped_matmul(hidden, w2, plan, tile)
     y = _combine(out, weights, plan, tile, top_k)
     if return_routing:
         return y, experts

@@ -16,8 +16,9 @@ HOT_PATH = ["execution/engine.py", "execution/pipeline.py",
             "execution/precompile.py", "execution/fused.py",
             "parallel/train.py", "serve/engine.py", "serve/batcher.py",
             "models/gpt.py", "models/llama.py", "models/lfm2.py",
-            "models/routed.py", "models/deepseek_v3.py", "ops/flash.py",
-            "ops/moe.py"]
+            "models/routed.py", "models/deepseek_v3.py",
+            "models/nemotron_h.py", "ops/flash.py", "ops/moe.py",
+            "ops/ssd.py"]
 
 
 def _is_jit(call: ast.Call) -> bool:

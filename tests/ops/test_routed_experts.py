@@ -287,3 +287,169 @@ def test_tiles_follow_the_shapes():
     assert moe._col_tile(128 * 17, moe.MAX_COL_TILE) == 128   # over the cap
     rows, tile = moe.buffer_rows(8192, 4, 8, 64)
     assert (rows, tile) == (86 * 384 + 8 * 384, 384)
+
+
+# --------------------------------------------------------------------- #
+# experts without a gate: W2 relu(W1 x)^2 (`w3=None`)                    #
+# --------------------------------------------------------------------- #
+
+F_ODD = 40          # no multiple of any tile: the width is taken whole
+
+
+@pytest.fixture(scope="module")
+def ungated(layer):
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    return dict(layer, w1=jax.random.normal(ks[0], (NE, D, F_ODD)) * 0.2,
+                w2=jax.random.normal(ks[1], (NE, F_ODD, D)) * 0.2)
+
+
+def _ungated_share(layer, offset, held, **kw):
+    sl = slice(offset, offset + held)
+    return moe.routed_experts(
+        layer["x"], layer["router"], layer["bias"], layer["w1"][sl], None,
+        layer["w2"][sl], num_experts=NE, top_k=K, expert_offset=offset, **kw)
+
+
+def _ungated_loop(layer, offset, held):
+    """The layer as its definition reads, one held expert at a time, in
+    float64 on the host."""
+    f64 = lambda a: np.asarray(a, np.float64)
+    x, rw, b = f64(layer["x"]), f64(layer["router"]), f64(layer["bias"])
+    scores = 1.0 / (1.0 + np.exp(-(x @ rw)))
+    y = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        chosen = np.argsort(-(scores[t] + b), kind="stable")[:K]
+        g = scores[t, chosen] / (scores[t, chosen].sum() + 1e-6)
+        for weight, e in zip(g, chosen):
+            if offset <= e < offset + held:
+                pre = np.maximum(x[t] @ f64(layer["w1"][e]), 0.0)
+                y[t] += weight * (pre ** 2 @ f64(layer["w2"][e]))
+    return y
+
+
+@pytest.mark.parametrize("offset,held", [(0, NE), (2, 4), (4, 2), (3, 1)],
+                         ids=["all", "share_4", "share_2", "empty_expert"])
+def test_ungated_experts_match_a_loop_over_tokens(ungated, offset, held):
+    np.testing.assert_allclose(
+        np.asarray(_ungated_share(ungated, offset, held)),
+        _ungated_loop(ungated, offset, held), atol=2e-5)
+
+
+@pytest.mark.parametrize("parts", [8, 4, 2])
+def test_the_ungated_shares_add_up_to_the_uncut_layer(ungated, parts):
+    held = NE // parts
+    total = sum(np.asarray(_ungated_share(ungated, i * held, held))
+                for i in range(parts))
+    np.testing.assert_allclose(
+        total, np.asarray(_ungated_share(ungated, 0, NE)), atol=2e-5)
+
+
+def _ungated_dense(x, router, bias, w1, w2, offset):
+    """The same function, dense over the held experts, for autodiff."""
+    scores = jax.nn.sigmoid(x @ router)
+    _, chosen = jax.lax.top_k(scores + bias, K)
+    g = jax.nn.one_hot(chosen, NE).sum(-2) * scores
+    g = g / (g.sum(-1, keepdims=True) + 1e-6)
+    g = g[:, offset:offset + w1.shape[0]]
+    h = jnp.square(jax.nn.relu(jnp.einsum("td,edf->tef", x, w1)))
+    return jnp.einsum("tef,efd,te->td", h, w2, g)
+
+
+UNGATED_OPERANDS = ("x", "router", "w1", "w2")
+
+
+@pytest.mark.parametrize("wrt", range(4), ids=UNGATED_OPERANDS)
+@pytest.mark.parametrize("offset,held", [(0, NE), (2, 4)], ids=["all", "share"])
+def test_ungated_gradients_match_the_dense_formulation(ungated, offset, held,
+                                                       wrt):
+    sl = slice(offset, offset + held)
+    args = (ungated["x"], ungated["router"], ungated["w1"][sl],
+            ungated["w2"][sl])
+    target = jax.random.normal(jax.random.PRNGKey(1), (T, D))
+
+    def routed(x, router, w1, w2):
+        y = moe.routed_experts(x, router, ungated["bias"], w1, None, w2,
+                               num_experts=NE, top_k=K, expert_offset=offset)
+        return jnp.sum(y * target)
+
+    def dense(x, router, w1, w2):
+        return jnp.sum(_ungated_dense(x, router, ungated["bias"], w1, w2,
+                                      offset) * target)
+
+    got = jax.jit(jax.grad(routed, argnums=wrt))(*args)
+    want = jax.jit(jax.grad(dense, argnums=wrt))(*args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
+    # The empty expert's matrices get an exact zero, not garbage.
+    if offset == 0 and UNGATED_OPERANDS[wrt] in ("w1", "w2"):
+        assert not np.asarray(got[3]).any()
+
+
+def test_an_ungated_call_holds_two_products_forward_and_four_backward(
+        ungated, monkeypatch):
+    """`grouped_matmul`'s own backward: dX and dW of each of the two.
+    Counted on the kernels' path (interpreted)."""
+    from tests.ops.programs import pallas_calls
+
+    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
+    monkeypatch.setattr(moe, "_interpret", lambda: True)
+    sl = slice(2, 6)
+
+    def loss(x, w1, w2):
+        return jnp.sum(moe.routed_experts(
+            x, ungated["router"], ungated["bias"], w1, None, w2,
+            num_experts=NE, top_k=K, expert_offset=2))
+
+    args = (ungated["x"], ungated["w1"][sl], ungated["w2"][sl])
+    forward = [n for n, _ in pallas_calls(jax.make_jaxpr(loss)(*args).jaxpr)]
+    assert forward == ["moe_gmm"] * 2
+    both = [n for n, _ in pallas_calls(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)))(*args).jaxpr)]
+    assert sorted(both) == ["moe_gmm"] * 4 + ["moe_tgmm"] * 2
+
+
+def test_ungated_calls_are_counted_where_they_are_built(ungated):
+    from oobleck_tpu.utils import metrics
+
+    built = metrics.registry().counter("oobleck_moe_ungated_calls_total")
+    before = built.value()
+    fn = jax.jit(lambda x: _ungated_share(dict(ungated, x=x), 0, NE))
+    fn(ungated["x"])
+    fn(ungated["x"])                # a cache hit traces nothing
+    assert built.value() - before == 1
+    jax.jit(lambda x: _share(dict(ungated, w1=ungated["w3"][..., :F_ODD],
+                                  w3=ungated["w3"][..., :F_ODD], x=x),
+                             0, NE))(ungated["x"])
+    assert built.value() - before == 1          # a SwiGLU call counts nothing
+
+
+def test_the_swiglu_call_lowers_to_the_text_it_lowered_to_before():
+    """Experts without a gate went in beside the SwiGLU path, not through
+    it: value-and-gradient of the SwiGLU call lowers to the text it lowered
+    to at the parent of the PR that added them (sha256 of the module's
+    text and its length, taken there)."""
+    import hashlib
+
+    s = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+
+    def f(x, router, bias, w1, w3, w2):     # the module is named after it
+        return jnp.sum(moe.routed_experts(
+            x, router, bias, w1, w3, w2, num_experts=NE, top_k=K,
+            expert_offset=2))
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 3, 4, 5))).lower(
+        s((T, D), f32), s((D, NE), f32), s((NE,), f32), s((4, D, F), f32),
+        s((4, D, F), f32), s((4, F, D), f32)).as_text()
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16], len(text)) == (
+        "c20d547349e09276", 76341)
+
+
+def test_odd_widths_are_taken_whole_by_the_kernels():
+    # 1856 = 14.5 x 128 (nemotron-3-nano-30b-a3b's experts): no tile
+    # divides it; 2688 = 21 x 128 gets 384 columns and 896 rows of dW.
+    assert moe._col_tile(1856, moe.MAX_COL_TILE) == 1856
+    assert moe._col_tile(1856, moe.MAX_TGMM_ROWS) == 1856
+    assert moe._col_tile(2688, moe.MAX_COL_TILE) == 384
+    assert moe._col_tile(2688, moe.MAX_TGMM_ROWS) == 896
+    # The cell's call: 192 rows expected an expert, one tile of 384.
+    assert moe.choose_row_tile(4096 * 6, 128) == 384

@@ -56,6 +56,7 @@ FLASH_WIDTHS = {
     "gpt3-2.7b-cell": (4, 32, 1024, 80),
     "seq-2048": (1, 32, 2048, 80),
     "short-prompt": (1, 12, 128, 64),
+    "nemotron-3-nano-30b-a3b-cell": (1, 32, 4096, 128),
 }
 # (Hq, Hkv, D) of the serve pools: gpt2 MHA and llama-style GQA.
 PAGED_WIDTHS = {"gpt2": (12, 12, 64), "llama-gqa": (32, 8, 128)}
@@ -247,6 +248,43 @@ def test_routed_experts_compile(v5e, width, mode):
     # Three products forward; three dX and three dW more backward.
     assert text.count('custom_call_target="tpu_custom_call"') == (
         3 if mode == "fwd" else 9)
+
+
+# Experts WITHOUT a gate (`w3=None`), at `nemotron-3-nano-30b-a3b.steady`'s
+# call: 1 x 4096 tokens, 8 of 128 experts of 2688 x 1856, top 6. 1856 =
+# 14.5 x 128 has no lane-multiple tile: Mosaic takes 1856 columns (rows x W1,
+# dW2) and a 1856-deep contraction (act x W2, dX of W1) whole. This is where
+# that width meets the TPU compiler before the chip does.
+UNGATED_WIDTHS = {
+    "nemotron-3-nano-30b-a3b-cell": (4096, 2688, 1856, 128, 8, 6),
+    "nemotron-tiles": (512, 384, 232, 16, 4, 2),
+}
+
+
+def _ungated_sum(top_k):
+    from oobleck_tpu.ops.moe import routed_experts
+
+    def fn(x, router, bias, w1, w2):
+        return jnp.sum(routed_experts(
+            x, router, bias, w1, None, w2, num_experts=router.shape[1],
+            top_k=top_k).astype(jnp.float32))
+
+    return fn
+
+
+@pytest.mark.parametrize("width", sorted(UNGATED_WIDTHS))
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_ungated_experts_compile(v5e, width, mode):
+    t, d, f, ne, held, top_k = UNGATED_WIDTHS[width]
+    fn = _ungated_sum(top_k) if mode == "fwd" else jax.grad(
+        _ungated_sum(top_k), argnums=(0, 1, 3, 4))
+    shapes = _routed_shapes(t, d, f, ne, held, top_k)
+    text = _compile(fn, v5e[0], *shapes[:4], shapes[5])
+    # Two products forward; two dX and two dW more backward.
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        2 if mode == "fwd" else 6)
+    # The parameters stay as wide as published: no operand is padded.
+    assert f"{held},{d},{f}" in text.replace(" ", "")
 
 
 # A routed block's host cost at process start, as flash's below: the

@@ -100,3 +100,24 @@ def test_allreduce_model_monotone():
     t8 = prof.allreduce_time_model(10_000_000, 8, cross_host=True)
     assert 0 < t2 < t8
     assert prof.allreduce_time_model(10_000_000, 1, cross_host=True) == 0.0
+
+
+def test_five_kinds_of_layer_in_one_list_are_each_timed_once(cache):
+    """`nemotron-h-tiny` (pattern MEM*E): embedding, Mamba-2, experts,
+    attention, head. A kind is timed at its first layer and its row
+    reused: the two `M` rows and the two `E` rows are equal, the kinds
+    differ, and the planner takes the list."""
+    path = profile("nemotron-h-tiny", {}, microbatch_size=1, seq_len=32,
+                   chips_per_host=4, max_hosts=4)
+    rows = json.loads((path / "mb1.json").read_text())
+    assert len(rows) == 7           # embed, M, E, M, *, E, head
+    assert rows[1] == rows[3] and rows[2] == rows[5]
+    params = [r["mem_required"][0] for r in rows]
+    assert len({params[1], params[2], params[4]}) == 3
+    assert all(r["forward"] > 0 and r["backward"] > 0 for r in rows)
+    profiles = load_profile("nemotron-h-tiny", "default", 1)
+    templates = TemplateGenerator(engine="python").create_pipeline_templates(
+        profiles, (1, 2), 1)
+    assert [t.num_hosts for t in templates] == [1, 2]
+    assert all(sum(len(s.layer_indices) for s in t.stages) == 7
+               for t in templates)
