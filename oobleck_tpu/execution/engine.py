@@ -794,6 +794,15 @@ class OobleckEngine:
         # The engine owns its tracer so reconfigure() can close a mid-window
         # jax.profiler trace before tearing the old topology down.
         self._tracer = None
+        # What the host did in each step, kept per step (obs/telemetry.py):
+        # the train thread's region seconds and open regions, the watchdog
+        # that looks at a step while it is open (one thread per train()
+        # call; None outside it and from a reconfiguration to the next
+        # step), and the end of the previous step for `between_s`.
+        self._step_acc = obs_spans.StepAccumulator()
+        self._watchdog: obs_telemetry.StepWatchdog | None = None
+        self._last_step_end: float | None = None
+        self._between_s = 0.0
 
         self.optimizer = make_optimizer(
             learning_rate=args.job.learning_rate,
@@ -1834,10 +1843,14 @@ class OobleckEngine:
         # so they are a separate ledger bucket, not a step subdivision.
         ckpt_s = sum(self.ckpt_stall_s[self._ckpt_stall_seen:])
         self._ckpt_stall_seen = len(self.ckpt_stall_s)
-        obs_telemetry.telemetry().record_step(
+        sample = obs_telemetry.telemetry().record_step(
             self.step, step_s, compute_s=compute_s, comm_s=comm_s,
             data_wait_s=self._data_wait_s, ckpt_s=ckpt_s,
-            live_bytes=self._live_bytes)
+            live_bytes=self._live_bytes, between_s=self._between_s,
+            phases=obs_telemetry.phases_of(self._step_acc.seconds),
+            hbm=_hbm_sample())
+        if self._watchdog is not None:
+            self._watchdog.step_recorded(sample)
         self._ledger.account_step(step_s, bubble_frac=bubble_frac,
                                   data_wait_s=self._data_wait_s)
         if ckpt_s > 0:
@@ -1957,6 +1970,8 @@ class OobleckEngine:
         interval = self.args.execution.checkpoint_interval
         sync_interval = self.args.execution.replica_sync_interval
         self._tracer = StepTracer()
+        self._step_acc.install()
+        self._last_step_end = time.perf_counter()
         plane = self._durable_plane()
         if plane is not None:
             # SIGTERM (TPU maintenance / preemption notice) drains the
@@ -2004,10 +2019,20 @@ class OobleckEngine:
                 self._data_wait_s = 0.0
                 if bookkeeping is not None:
                     bookkeeping.__exit__(None, None, None)
+                if self._watchdog is None:
+                    self._watchdog = self._start_watchdog()
                 with background.device_work("train_step"):
+                    self._step_acc.begin()
+                    self._watchdog.step_opens(self.step + 1)
                     t0 = time.perf_counter()
                     loss = self._train_step()
-                    step_s = time.perf_counter() - t0
+                    t1 = time.perf_counter()
+                    self._watchdog.step_closes()
+                step_s = t1 - t0
+                self._between_s = t0 - self._last_step_end
+                self._last_step_end = t1
+                if self._tracer.stall_open:
+                    self._tracer.close_stall_window()
                 bookkeeping = obs_spans.region("engine.bookkeeping")
                 bookkeeping.__enter__()
                 factor = chaos().slow_factor(self.agent_ip)
@@ -2062,7 +2087,8 @@ class OobleckEngine:
                     logger.info(
                         "step timer: n=%d, last=%.1fms, mean=%.1fms | %s%s",
                         n, step_s * 1e3, mean_s * 1e3,
-                        _device_memory_summary(), wire)
+                        _device_memory_summary(
+                            obs_telemetry.telemetry().last()), wire)
                     self._publish_metrics()
                 if sync_interval and self.step % sync_interval == 0:
                     self._sync_replicas()
@@ -2089,9 +2115,28 @@ class OobleckEngine:
             # enabled.
             if metrics.metrics_dir() is not None:
                 obs_spans.span_recorder().dump("train_end")
-            if self._tracer is not None:
-                self._tracer.close()
-                self._tracer = None
+            self._close_step_watch()
+            self._tracer = None
+            self._step_acc.uninstall()
+
+    def _start_watchdog(self) -> obs_telemetry.StepWatchdog:
+        tracer = self._tracer
+        return obs_telemetry.StepWatchdog(
+            obs_telemetry.telemetry(), self._step_acc,
+            open_trace=None if tracer is None else tracer.open_stall_window,
+        ).start()
+
+    def _close_step_watch(self) -> None:
+        """Stop the watchdog's thread, then close whatever profiler window
+        is open (train()'s end, and every change of topology: a trace must
+        not straddle one, and the first step after it may compile, which
+        the next watchdog skips as its first). The thread first, so that it
+        cannot open a stall window behind the close."""
+        if self._watchdog is not None:
+            self._watchdog.stop()
+            self._watchdog = None
+        if self._tracer is not None:
+            self._tracer.close()
 
     # ------------------------------------------------------------------ #
 
@@ -3012,8 +3057,7 @@ class OobleckEngine:
         agent."""
         from oobleck_tpu.degrade.apply import try_degrade
 
-        if self._tracer is not None:
-            self._tracer.close()
+        self._close_step_watch()
         ddec = try_degrade(self, lost_ip, self._host_index[lost_ip], t0)
         if ddec.mechanism == "reroute":
             self._observe_policy_measured(
@@ -3309,8 +3353,7 @@ class OobleckEngine:
         correlated = len(lost_ips) > 1
         # A mid-window jax.profiler trace must not straddle the topology
         # change: close it now; the tracer re-arms on its next window.
-        if self._tracer is not None:
-            self._tracer.close()
+        self._close_step_watch()
         if self.fused is not None:
             # Fused recovery is a mesh shrink; one host at a time.
             for ip in lost_ips:
@@ -3843,20 +3886,34 @@ def _scale_template_chips(t: PipelineTemplate, tp: int) -> PipelineTemplate:
     )
 
 
-def _device_memory_summary() -> str:
-    """Peak/in-use device memory (reference logs CUDA memory every 10 steps,
-    engine.py:657-659); CPU backends report no stats."""
-    try:
-        # local_devices: on multi-host, devices()[0] is process 0's chip and
-        # is non-addressable from other workers.
-        stats = jax.local_devices()[0].memory_stats() or {}
-        used = stats.get("bytes_in_use", 0)
-        peak = stats.get("peak_bytes_in_use", used)
-        limit = stats.get("bytes_limit", 0)
-        return (f"mem {used / 2**30:.2f}GiB (peak {peak / 2**30:.2f}"
-                f"{f' / limit {limit / 2**30:.0f}' if limit else ''}GiB)")
-    except Exception:
+def _hbm_sample() -> tuple:
+    """(bytes in use, limit, largest free block) of the fullest local
+    device, each None where the platform reports none (the CPU does):
+    what the allocator has left at a step's end, for the telemetry ring.
+    Called once a step, in bookkeeping; asks the allocator for its
+    counters and waits for nothing on the device."""
+    # local_devices: on multi-host, devices()[0] is process 0's chip and
+    # is non-addressable from other workers.
+    stats = max((d.memory_stats() or {} for d in jax.local_devices()),
+                key=lambda m: m.get("bytes_in_use", 0))
+    return (stats.get("bytes_in_use"), stats.get("bytes_limit"),
+            stats.get("largest_free_block_bytes"))
+
+
+def _device_memory_summary(sample: tuple | None) -> str:
+    """Device memory at the last step's end, from the telemetry ring's
+    sample (reference logs CUDA memory every 10 steps, engine.py:657-659);
+    CPU backends report no stats."""
+    if sample is None or sample[obs_telemetry.HBM_IN_USE] is None:
         return "mem n/a"
+    out = f"mem {sample[obs_telemetry.HBM_IN_USE] / 2**30:.2f}GiB"
+    limit = sample[obs_telemetry.HBM_LIMIT]
+    if limit:
+        out += f" / limit {limit / 2**30:.0f}GiB"
+    free = sample[obs_telemetry.HBM_LARGEST_FREE]
+    if free is not None:
+        out += f", largest free block {free / 2**30:.2f}GiB"
+    return out
 
 
 def _place_opt_state(optimizer, state, param_sharding_tree):

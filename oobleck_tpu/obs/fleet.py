@@ -214,6 +214,12 @@ class FleetTracker:
                 "flagged": row.flagged,
                 "step": row.digest.get("step"),
                 "age_s": round(now - row.updated_at, 3),
+                # Why it is slow, where its agent says (obs/telemetry.py):
+                # held dispatching, held on the device, between steps, or
+                # out of device memory. Absent from a legacy agent's digest.
+                "cause": {k: row.digest[k] for k in (
+                    "dispatch_s", "readback_s", "between_s", "hbm_free_frac")
+                    if k in row.digest},
             }
         return {
             "hosts": hosts,

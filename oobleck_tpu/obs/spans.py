@@ -29,7 +29,11 @@ Two calls, two jobs:
   ``jax.profiler.TraceAnnotation`` (the region is an event of the host
   plane of the profiler's own trace, on the device operations' clock) and
   one observation of ``oobleck_span_seconds{span=name}``. No ids, no wall
-  clock, no record.
+  clock, no record. Where the calling thread owns a ``StepAccumulator``
+  (the engine's train thread does), the region's seconds are also added
+  to the current step's, by name, and its name lies on the accumulator's
+  stack while it is open: what ``obs/telemetry.py`` writes into each
+  step's sample, and what its watchdog reads of a step that overstays.
 
 ``span()`` opens the same annotation, so an incident's spans also lie in a
 device trace taken across it. This module is the only place in the package
@@ -150,7 +154,15 @@ def span_recorder() -> SpanRecorder:
 # context: thread-local span stack + process-wide ambient trace
 
 
-_tls = threading.local()
+class _ThreadState(threading.local):
+    # Class-level defaults: a thread that never set one reads None without
+    # the AttributeError a bare `threading.local` raises inside `getattr`
+    # (0.5 us a region on every thread that owns no accumulator).
+    stack: list | None = None                 # open span() frames
+    step: "StepAccumulator | None" = None     # see StepAccumulator.install
+
+
+_tls = _ThreadState()
 _ambient_lock = threading.Lock()
 _ambient: dict | None = None
 
@@ -171,7 +183,7 @@ def ambient() -> dict | None:
 
 def current() -> dict | None:
     """The innermost open span's context, else the ambient one."""
-    stack = getattr(_tls, "stack", None)
+    stack = _tls.stack
     if stack:
         return dict(stack[-1])
     return ambient()
@@ -191,7 +203,7 @@ def span(name: str, *, trace_id: str | None = None,
     if parent_id is None and ctx:
         parent_id = ctx.get("span_id")
     frame = {"trace_id": trace_id or new_trace_id(), "span_id": new_span_id()}
-    stack = getattr(_tls, "stack", None)
+    stack = _tls.stack
     if stack is None:
         stack = _tls.stack = []
     stack.append(frame)
@@ -228,6 +240,42 @@ def _observer(name: str):
     return observe
 
 
+class StepAccumulator:
+    """Host seconds of the regions ONE thread closed since `begin()`, by
+    name, and the names of the regions it has open now, outermost first.
+
+    `install()` hands it to the calling thread; regions entered on any
+    other thread (the stager's worker, checkpoint writers) never see it.
+    Only the owner writes. Another thread may read `innermost()`: a list's
+    last element is one bytecode under the interpreter lock, so neither
+    side takes a lock."""
+
+    __slots__ = ("owner", "seconds", "stack")
+
+    def __init__(self) -> None:
+        self.owner: int | None = None
+        self.seconds: dict[str, float] = {}
+        self.stack: list[str] = []
+
+    def install(self) -> None:
+        self.owner = threading.get_ident()
+        _tls.step = self
+
+    def uninstall(self) -> None:
+        if _tls.step is self:
+            _tls.step = None
+
+    def begin(self) -> None:
+        """A step starts: forget the seconds of what closed before it."""
+        self.seconds.clear()
+
+    def innermost(self) -> str | None:
+        try:
+            return self.stack[-1]
+        except IndexError:
+            return None
+
+
 class region:
     """A named hot-path region: `with region("engine.staging"): ...`.
 
@@ -236,15 +284,19 @@ class region:
     seconds go to the histogram `oobleck_span_seconds{span=name}`. Nests,
     and closes on an exception. Reads no device value: what it times is
     what the host did, which on an asynchronous device is the enqueue
-    unless the region itself blocks."""
+    unless the region itself blocks. On a thread that owns a
+    `StepAccumulator` the seconds also go to the current step's."""
 
-    __slots__ = ("_name", "_annotation", "_t0")
+    __slots__ = ("_name", "_annotation", "_t0", "_step")
 
     def __init__(self, name: str):
         self._name = name
         self._annotation = jax.profiler.TraceAnnotation(name)
 
     def __enter__(self) -> None:
+        step = self._step = _tls.step
+        if step is not None:
+            step.stack.append(self._name)
         self._annotation.__enter__()
         self._t0 = time.perf_counter()
 
@@ -252,6 +304,11 @@ class region:
         seconds = time.perf_counter() - self._t0
         self._annotation.__exit__(exc_type, exc, tb)
         _observer(self._name)(seconds)
+        step = self._step
+        if step is not None:
+            step.stack.pop()
+            step.seconds[self._name] = (
+                step.seconds.get(self._name, 0.0) + seconds)
 
 
 def event(name: str, t: float | None = None, **attrs) -> dict:

@@ -20,6 +20,20 @@ Design constraints, in order:
     (piggybacked on the agent's existing heartbeat as one extra JSON
     key — legacy masters ignore it), never raw samples.
 
+A sample is one tuple a step. Positions 0-6 are (step, step_s, compute_s,
+comm_s, data_wait_s, ckpt_s, live_bytes); since PR 41 it goes on, at its
+END only, with what the host did in THAT step: ``between_s`` (wall from
+the previous step's end to this step's start), ``phases`` (host seconds in
+the order of ``PHASES``, from the train thread's
+``obs/spans.StepAccumulator``) and the allocator's headroom at the step's
+end (``hbm_in_use``, ``hbm_limit``, ``hbm_largest_free``; bytes of the
+fullest local device, None where the platform reports none). A step's
+self time is ``step_s`` less the sum of its phases.
+
+``StepWatchdog`` is the one reader that looks at a step while it is still
+open: a daemon thread per ``train()`` call that records what the train
+thread is inside when a step has lasted twice the recent median.
+
 Knobs:
     OOBLECK_TELEMETRY=0            disable sampling entirely
     OOBLECK_TELEMETRY_CAPACITY     ring size in samples (default 512)
@@ -29,8 +43,15 @@ Knobs:
 from __future__ import annotations
 
 import collections
+import logging
 import os
+import statistics
+import sys
 import threading
+import time
+from oobleck_tpu.utils import metrics
+
+logger = logging.getLogger("oobleck.telemetry")
 
 ENV_TELEMETRY = "OOBLECK_TELEMETRY"
 ENV_CAPACITY = "OOBLECK_TELEMETRY_CAPACITY"
@@ -45,8 +66,26 @@ DIGEST_VERSION = 1
 
 # Sample tuple layout (kept positional: a tuple append is the cheapest
 # thing CPython can do per step, and the digest is the only reader).
-_STEP, _STEP_S, _COMPUTE_S, _COMM_S, _DATA_WAIT_S, _CKPT_S, _LIVE_BYTES = \
-    range(7)
+(_STEP, _STEP_S, _COMPUTE_S, _COMM_S, _DATA_WAIT_S, _CKPT_S, _LIVE_BYTES,
+ BETWEEN_S, PHASES_AT, HBM_IN_USE, HBM_LIMIT, HBM_LARGEST_FREE) = range(12)
+SAMPLE_LEN = HBM_LARGEST_FREE + 1
+
+# The regions of a step whose host seconds a sample keeps, in the order of
+# its `phases` tuple (readers import this, never a position). On the fused
+# path `engine.fused_step` is the dispatch and takes its place.
+PHASES = ("engine.staging", "pipeline.dispatch", "dp.allreduce",
+          "engine.optimizer", "engine.loss_readback")
+FUSED_DISPATCH = "engine.fused_step"
+_DISPATCH = PHASES.index("pipeline.dispatch")
+_READBACK = PHASES.index("engine.loss_readback")
+
+
+def phases_of(seconds: dict) -> tuple:
+    """A closed step's region seconds (`StepAccumulator.seconds`) as the
+    sample's `phases` tuple."""
+    out = [seconds.get(name, 0.0) for name in PHASES]
+    out[_DISPATCH] += seconds.get(FUSED_DISPATCH, 0.0)
+    return tuple(out)
 
 
 def _env_int(name: str, default: int) -> int:
@@ -84,14 +123,19 @@ class TelemetryRing:
     def record_step(self, step: int, step_s: float, *,
                     compute_s: float = 0.0, comm_s: float = 0.0,
                     data_wait_s: float = 0.0, ckpt_s: float = 0.0,
-                    live_bytes: int = 0) -> None:
-        """Append one step's host-side timings. All arguments are plain
-        host floats the caller already measured — never device values."""
+                    live_bytes: int = 0, between_s: float = 0.0,
+                    phases: tuple = (0.0,) * len(PHASES),
+                    hbm: tuple = (None, None, None)) -> tuple | None:
+        """Append one step's host-side timings; returns the sample. All
+        arguments are plain host numbers the caller already measured —
+        never device values. `hbm` is (in_use, limit, largest_free)."""
         if not self.enabled:
-            return
+            return None
+        sample = (step, step_s, compute_s, comm_s, data_wait_s, ckpt_s,
+                  live_bytes, between_s, tuple(phases), *hbm)
         with self._lock:
-            self._ring.append((step, step_s, compute_s, comm_s,
-                               data_wait_s, ckpt_s, live_bytes))
+            self._ring.append(sample)
+        return sample
 
     # -- digest (publish cadence, not per-step) ----------------------------- #
 
@@ -105,6 +149,10 @@ class TelemetryRing:
             return None
         n = len(tail)
         steps = sorted(s[_STEP_S] for s in tail)
+        last = tail[-1]
+        free_frac = None
+        if last[HBM_LIMIT] and last[HBM_IN_USE] is not None:
+            free_frac = round(1.0 - last[HBM_IN_USE] / last[HBM_LIMIT], 6)
         return {
             "v": DIGEST_VERSION,
             "n": n,
@@ -116,12 +164,31 @@ class TelemetryRing:
             "comm_s": round(sum(s[_COMM_S] for s in tail) / n, 6),
             "data_wait_s": round(sum(s[_DATA_WAIT_S] for s in tail) / n, 6),
             "ckpt_s": round(sum(s[_CKPT_S] for s in tail), 6),
-            "live_bytes": tail[-1][_LIVE_BYTES],
+            "live_bytes": last[_LIVE_BYTES],
+            # Why a straggler is slow (obs/fleet.py flags on step_max_s):
+            # held while dispatching, held on the device, or between steps.
+            "dispatch_s": round(
+                sum(s[PHASES_AT][_DISPATCH] for s in tail) / n, 6),
+            "readback_s": round(
+                sum(s[PHASES_AT][_READBACK] for s in tail) / n, 6),
+            "between_s": round(sum(s[BETWEEN_S] for s in tail) / n, 6),
+            "hbm_free_frac": free_frac,
         }
 
     def samples(self) -> list[tuple]:
         with self._lock:
             return list(self._ring)
+
+    def last(self) -> tuple | None:
+        with self._lock:
+            return self._ring[-1] if self._ring else None
+
+    def recent_step_s(self, n: int) -> list[float]:
+        """`step_s` of the newest `n` samples, oldest first."""
+        with self._lock:
+            size = len(self._ring)
+            return [self._ring[i][_STEP_S]
+                    for i in range(max(size - n, 0), size)]
 
     def __len__(self) -> int:
         with self._lock:
@@ -154,3 +221,164 @@ def reset(capacity: int | None = None,
     global _instance
     _instance = TelemetryRing(capacity, window)
     return _instance
+
+
+# ---------------------------------------------------------------------------
+# a step that overstays
+
+
+def sample_fields(sample: tuple) -> dict:
+    """What a sample holds beyond the digest's means, by name: the form
+    the `step_stall*` flight events and the log line carry."""
+    return {
+        "step": sample[_STEP], "step_s": round(sample[_STEP_S], 6),
+        "between_s": round(sample[BETWEEN_S], 6),
+        "phases": {name: round(sec, 6)
+                   for name, sec in zip(PHASES, sample[PHASES_AT])},
+        "hbm_in_use": sample[HBM_IN_USE], "hbm_limit": sample[HBM_LIMIT],
+        "hbm_largest_free": sample[HBM_LARGEST_FREE],
+    }
+
+
+def thread_frames(limit: int, train_ident: int | None = None) -> dict:
+    """The innermost `limit` frames of every thread of the process, each
+    `dir/file.py:line:function`, innermost first; the thread `train_ident`
+    is listed as `train`."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    out = {}
+    for ident, frame in sys._current_frames().items():
+        rows = []
+        while frame is not None and len(rows) < limit:
+            code = frame.f_code
+            where = "/".join(code.co_filename.split(os.sep)[-2:])
+            rows.append(f"{where}:{frame.f_lineno}:{code.co_name}")
+            frame = frame.f_back
+        name = ("train" if ident == train_ident
+                else f"{names.get(ident, 'thread')}-{ident}")
+        out[name] = rows
+    return out
+
+
+class StepWatchdog:
+    """Looks at a training step WHILE it is open, and says what the train
+    thread is inside when the step has lasted `OVERSTAY` x the median
+    `step_s` of the ring's newest `MEDIAN_OVER` samples.
+
+    One daemon thread per `train()` call. The train thread publishes the
+    open step as ONE attribute (`open`: a (start, step) pair or None), so
+    the step path takes no lock and the reader never pairs one step's start
+    with another's number. The first step after `start()` is never
+    published: it follows set-up, a reconfiguration or a grow, and may
+    compile. Under `ARMED_AT` samples there is no median and nothing fires
+    (the median and not an average: a warm-up's compiling step would sit in
+    an average for ten steps). No knob: steps repeat to 0.5 %, and the
+    shortest stall on record is 3 x its step.
+
+    Fires once a step: flight event `step_stall` (step, seconds open, the
+    median, the innermost region open on the train thread, every thread's
+    innermost frames, the memory sample of the last step's end), the same
+    as one warning line, `oobleck_step_stalls_total{phase}`, and a profiler
+    window over the rest of the step where `open_trace` grants one. The
+    train thread adds `step_stall_end` with the whole sample once the step
+    is over. It reads no device value and never calls into the runtime
+    itself: a second thread entering the allocator while the first is held
+    there is not something to find out in production."""
+
+    OVERSTAY = 2.0
+    MEDIAN_OVER = 8
+    ARMED_AT = 3
+    FRAMES = 12
+    IDLE_S = 0.25
+
+    def __init__(self, ring: TelemetryRing, accumulator, *, open_trace=None,
+                 clock=time.monotonic, wait=None):
+        self._ring = ring
+        self._acc = accumulator
+        self._open_trace = open_trace      # (step) -> bool, or None
+        self._clock = clock
+        self._stop = threading.Event()
+        self._wait = wait or self._stop.wait    # (seconds) -> stopping?
+        self._thread: threading.Thread | None = None
+        self._first = True
+        self.open: tuple | None = None     # written by the train thread
+        self.fired_step = -1               # written by the watchdog's
+        self._m_stalls = metrics.registry().counter(
+            "oobleck_step_stalls_total", "Training steps that stayed open "
+            "twice the recent median, by the region open on the train "
+            "thread when the watchdog looked")
+
+    # -- the train thread ---------------------------------------------------- #
+
+    def step_opens(self, step: int) -> None:
+        if self._first:
+            self._first = False
+            return
+        self.open = (self._clock(), step)
+
+    def step_closes(self) -> None:
+        self.open = None
+
+    def step_recorded(self, sample: tuple | None) -> None:
+        """The closed step's sample is in the ring: if the watchdog fired
+        in that step, say how it ended."""
+        if sample is not None and sample[_STEP] == self.fired_step:
+            fields = sample_fields(sample)
+            metrics.flight_recorder().record("step_stall_end", **fields)
+            logger.warning("step_stall_end %s", fields)
+
+    # -- the watchdog's thread ----------------------------------------------- #
+
+    def start(self) -> "StepWatchdog":
+        self._thread = threading.Thread(
+            target=self._run, name="oobleck-step-watchdog", daemon=True)
+        self._thread.start()
+        return self
+
+    def stop(self) -> None:
+        self._stop.set()
+        thread, self._thread = self._thread, None
+        if thread is not None:
+            thread.join(timeout=30.0)
+            if thread.is_alive():
+                logger.warning("step watchdog did not stop within 30 s")
+
+    def _run(self) -> None:
+        while not self._stop.is_set():
+            if self._wait(self.check()):
+                return
+
+    def check(self) -> float:
+        """One look at the open step; returns the seconds until the next:
+        the time left until the step now open would be overdue, else a
+        quarter of the median."""
+        recent = self._ring.recent_step_s(self.MEDIAN_OVER)
+        if len(recent) < self.ARMED_AT:
+            return self.IDLE_S
+        median = statistics.median(recent)
+        idle = min(max(median / 4, 0.001), self.IDLE_S)
+        opened = self.open
+        if opened is None or opened[1] == self.fired_step:
+            return idle
+        since, step = opened
+        open_s = self._clock() - since
+        left = self.OVERSTAY * median - open_s
+        if left > 0:
+            return max(left, 0.001)
+        self.fired_step = step
+        self._fire(step, open_s, median)
+        return idle
+
+    def _fire(self, step: int, open_s: float, median: float) -> None:
+        phase = self._acc.innermost() or "none"
+        frames = thread_frames(self.FRAMES, self._acc.owner)
+        last = self._ring.last()
+        before = sample_fields(last) if last is not None else None
+        event = dict(step=step, open_s=round(open_s, 3),
+                     median_s=round(median, 6), phase=phase, frames=frames,
+                     last_sample=before)
+        metrics.flight_recorder().record("step_stall", **event)
+        self._m_stalls.inc(phase=phase)
+        # The same on one line: a run with no sink set shows it on stderr.
+        logger.warning("step_stall %s", event)
+        if self._open_trace is not None:
+            self._open_trace(step)

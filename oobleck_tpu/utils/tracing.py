@@ -13,6 +13,13 @@ perfetto-compatible trace for steps
 OOBLECK_TRACE_EVERY=<n> to re-arm the window every n steps for long runs
 (window k covers [START + k*EVERY, START + k*EVERY + STEPS)).
 
+With OOBLECK_TRACE_DIR set, the step watchdog (obs/telemetry.py) also gets
+a window for a step that overstays: `open_stall_window(step)` starts a
+trace into <dir>/stall-<step> from the watchdog's thread unless a session
+is open, and the train thread closes it at that step's end
+(`close_stall_window`). The rest of the stall is then a profiler trace with
+the runtime's own threads and the device plane in it.
+
 Lifecycle: the engine owns one StepTracer per train() and calls close()
 from its finally AND from reconfigure() — a mid-window failure or topology
 change must not leave a jax.profiler trace open (start_trace raises on
@@ -49,6 +56,10 @@ class StepTracer:
         self.every = _env_int("OOBLECK_TRACE_EVERY", 0)
         self._active = False
         self._done = False  # one-shot mode: window consumed (or closed)
+        # Set by the watchdog's thread once its start_trace has returned,
+        # read and cleared by the train thread at a step's end. A step that
+        # ends while the session is still starting is closed one step late.
+        self.stall_open = False
 
     def _window_start(self, step: int) -> int:
         if self.every > 0 and step >= self.start:
@@ -79,11 +90,38 @@ class StepTracer:
                 return
             self._active = True
 
-    def _stop(self) -> None:
+    def open_stall_window(self, step: int) -> bool:
+        """A window over the rest of a step that overstays; from the
+        watchdog's thread. Skipped without a trace directory and while a
+        session is open, this tracer's or anyone's (`start_trace` raising
+        is the skip `on_step` has)."""
+        if not self.trace_dir or self._active or self.stall_open:
+            return False
+        path = os.path.join(self.trace_dir, f"stall-{step}")
+        try:
+            jax.profiler.start_trace(path)
+        except RuntimeError as e:
+            logger.warning("stall window for step %d skipped: %s", step, e)
+            return False
+        self.stall_open = True
+        logger.warning("stall window open: tracing the rest of step %d "
+                       "into %s", step, path)
+        return True
+
+    def close_stall_window(self) -> None:
+        if self.stall_open:
+            self.stall_open = False
+            self._stop_session()
+
+    @staticmethod
+    def _stop_session() -> None:
         try:
             jax.profiler.stop_trace()
         except RuntimeError as e:
             logger.warning("stop_trace failed: %s", e)
+
+    def _stop(self) -> None:
+        self._stop_session()
         self._active = False
         if self.every <= 0:
             self._done = True
@@ -92,6 +130,7 @@ class StepTracer:
         """Idempotent: stop an open window (engine shutdown/reconfigure).
         One-shot mode stays closed; periodic mode re-arms at the next
         window boundary."""
+        self.close_stall_window()
         if self._active:
             self._stop()
         if self.every <= 0:

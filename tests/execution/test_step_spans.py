@@ -6,7 +6,7 @@ steps, and set-up leaves its spans in the program's ring."""
 import jax
 
 from oobleck_tpu.execution import engine as engine_mod
-from oobleck_tpu.obs import spans
+from oobleck_tpu.obs import spans, telemetry
 from tests.execution.test_engine import cache_env, make_engine  # noqa: F401
 
 STEP = ["engine.step", "engine.staging", "pipeline.dispatch", "dp.allreduce",
@@ -46,8 +46,29 @@ def test_two_steps_record_every_span_in_order(cache_env, monkeypatch):  # noqa: 
     fake = _Recording()
     monkeypatch.setattr(spans.jax.profiler, "TraceAnnotation", fake)
     syncs0 = engine_mod.host_sync_counter.count
+    ring = telemetry.reset()
     eng.train()
     assert eng.step == 2
+    # Each step left one sample, and in it the host seconds of the step's
+    # own regions, in the module's order: the regions are disjoint and lie
+    # inside the step, so they sum to no more than it.
+    assert telemetry.PHASES == tuple(STEP[1:])
+    first, second = ring.samples()
+    assert [first[0], second[0]] == [1, 2]
+    for sample in (first, second):
+        assert len(sample) == telemetry.SAMPLE_LEN
+        phases = sample[telemetry.PHASES_AT]
+        assert len(phases) == len(telemetry.PHASES)
+        assert all(p >= 0.0 for p in phases)
+        by_name = dict(zip(telemetry.PHASES, phases))
+        assert by_name["pipeline.dispatch"] > 0.0
+        assert by_name["engine.loss_readback"] > 0.0
+        assert sum(phases) <= sample[1]
+        assert sample[telemetry.BETWEEN_S] > 0.0
+        # The CPU reports no memory statistics.
+        assert sample[telemetry.HBM_IN_USE:] == (None, None, None)
+    # The train thread's accumulator went with train(), and its watchdog.
+    assert spans._tls.step is None and eng._watchdog is None
     opens = [name for what, name in fake.log if what == "open"]
     assert opens == STEP + ["engine.bookkeeping"] + STEP + [
         "engine.bookkeeping"]
