@@ -30,7 +30,11 @@ single-controller JAX runtime:
   * microbatch gradients accumulate inside `jit_bwd`: a chunk's running
     sum is a donated operand that comes back with the microbatch's
     gradients added, in its own buffers. A step holds one gradient set per
-    chunk and dispatches no program that only adds;
+    chunk and dispatches no program that only adds. XLA fuses that add into
+    the product that gives a gradient; where a kernel gives it (the routed
+    experts' dW, `ops/moe.py`) the model says so and the sum goes down into
+    the kernel, which starts from it: on a one-device stage no pass over a
+    gradient only adds;
   * compiled stage executables are cached by stage signature so
     re-instantiation after a failure reuses them — the pre-compile-per-
     template idea from SURVEY §7.3.1.
@@ -38,6 +42,7 @@ single-controller JAX runtime:
 
 from __future__ import annotations
 
+import functools
 import logging
 import time
 from collections import deque
@@ -151,6 +156,42 @@ def grad_zero(params_tuple):
     return jax.tree.map(jnp.zeros_like, params_tuple)
 
 
+def _accumulate(acc, backward, *, layers, in_kernel):
+    """A chunk's new gradient sum and whatever else its backward gives.
+    `backward(**sums)` returns (grads, rest). `in_kernel` marks, a layer,
+    the leaves the model sums in a kernel (a tree of booleans, or None):
+    those leaves of `acc` go down as `sums` (`ops/moe.GradSum`, named
+    after layer and leaf), each is taken by exactly one kernel call or the
+    trace fails, and they come back in `grads` as sum + gradient. Every
+    other leaf is `acc + grads`."""
+    if not any(jax.tree.leaves(in_kernel)):
+        grads, rest = backward()
+        return jax.tree.map(jnp.add, acc, grads), rest
+    from oobleck_tpu.ops import moe
+
+    names = []
+
+    def hand(li, marks, layer_acc):
+        def leaf(path, a, on):
+            if not on:
+                return None
+            names.append(f"layer{li}{jax.tree_util.keystr(path)}")
+            return moe.GradSum(a, names[-1])
+
+        if marks is None:
+            return None
+        return jax.tree_util.tree_map_with_path(leaf, layer_acc, marks)
+
+    sums = tuple(map(hand, layers, in_kernel, acc))
+    with moe.handing_sums(names):
+        grads, rest = backward(sums=sums)
+    new = tuple(
+        jax.tree.map(jnp.add, a, g) if marks is None else jax.tree.map(
+            lambda a_, g_, on: g_ if on else a_ + g_, a, g, marks)
+        for a, g, marks in zip(acc, grads, in_kernel))
+    return new, rest
+
+
 def make_optimizer_update(optimizer):
     """The per-layer optimizer step as a named function
     (`jit_optimizer_update` in a device trace). PipelineInstance and the
@@ -188,6 +229,8 @@ class StageRuntime:
     bwd: list[Callable | None] = field(default_factory=list)   # per chunk
     efwd: list[Callable | None] = field(default_factory=list)  # eval fwd w/ metrics
     zero: list[Callable | None] = field(default_factory=list)  # gradient-sum fill
+    # Leaves a chunk's backward sums inside a kernel (`_sums_in_kernel`).
+    kernel_sums: list[int] = field(default_factory=list)
 
     @property
     def ctx(self):
@@ -591,15 +634,19 @@ class PipelineInstance:
             # apply_layer, with the last layer's logits fed to the model's
             # own loss_from_logits — the engine is objective-agnostic like
             # the reference's (pipeline.py:169-216).
-            def layer_fn(li):
-                fn = lambda p, c, b: model.apply_layer(li, p, c, b)
+            def layer_fn(li, handed):
+                # Gradient sums handed to a layer are inputs of its
+                # checkpoint, like its parameters: kept, not recomputed.
+                kw = {} if handed is None else {"grad_sums": handed}
+                fn = lambda p, c, b: model.apply_layer(li, p, c, b, **kw)
                 if remat and 0 < li < last_layer:
                     fn = checkpoint_layer(fn)
                 return fn
 
-            def apply(params_tuple, x, batch, with_metrics=False):
+            def apply(params_tuple, x, batch, with_metrics=False, sums=None):
                 carry = x
-                for li, p in zip(layers, params_tuple):
+                sums = sums or (None,) * len(layers)
+                for li, p, handed in zip(layers, params_tuple, sums):
                     if li == last_layer:
                         logits = model.apply_layer(li, p, carry, batch)
                         loss = model.loss_from_logits(logits, batch)
@@ -610,7 +657,7 @@ class PipelineInstance:
                             c, n = model.accuracy_from_logits(logits, batch)
                             return loss, c, n
                         return loss
-                    carry = layer_fn(li)(p, carry, batch)
+                    carry = layer_fn(li, handed)(p, carry, batch)
                 return carry
 
             return apply
@@ -697,16 +744,20 @@ class PipelineInstance:
 
         Microbatch gradients accumulate INSIDE the backward program: `acc`,
         the chunk's running gradient sum (the parameters' tree, dtypes and
-        shardings), is a donated operand and `acc + grads` comes back in its
+        shardings), is a donated operand and the new sum comes back in its
         buffers, so a step holds one gradient set per chunk and no separate
         add runs. `zero(params)` fills the sum the step's first microbatch
         adds to (0 + g is g): one backward program per chunk, whichever
-        microbatch.
+        microbatch. The new sum is `acc + grads`, an add XLA fuses into the
+        product that gives the gradient, for every leaf but those the
+        model takes INTO a kernel (`_sums_in_kernel`): a custom call's
+        output takes no fused add, so the kernel starts from the sum and
+        that leaf of `grads` already is the new sum (`_accumulate`).
 
         Every other chunk's `bwd(params, acc, x, batch, dy)` returns
-        (acc + grads, dx). The last virtual stage's `bwd(params, acc, x,
-        batch)` is the loss's value-and-gradient and returns (loss, acc +
-        grads, dx): the unscaled microbatch loss its `fwd` would give,
+        (new sum, dx). The last virtual stage's `bwd(params, acc, x,
+        batch)` is the loss's value-and-gradient and returns (loss, new
+        sum, dx): the unscaled microbatch loss its `fwd` would give,
         gradients of loss / total microbatches. train_step never calls that
         chunk's `fwd` (eval_step does)."""
         S, v = self.num_stages, self.virtual_stages
@@ -717,6 +768,7 @@ class PipelineInstance:
             st.bwd = [None] * len(st.chunks)
             st.efwd = [None] * len(st.chunks)
             st.zero = [None] * len(st.chunks)
+            st.kernel_sums = [0] * len(st.chunks)
             if not st.is_local:
                 continue
             for c, chunk_layers in enumerate(st.chunks):
@@ -729,10 +781,15 @@ class PipelineInstance:
                     self.total_num_microbatches, st.tp, st.sp, st.use_fsdp,
                 )
                 if key in self._exec_cache:
-                    (st.fwd[c], st.bwd[c], st.efwd[c],
-                     st.zero[c]) = self._exec_cache[key]
+                    (st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c],
+                     st.kernel_sums[c]) = self._exec_cache[key]
                     continue
                 apply = self._stage_apply(st, chunk_layers)
+                in_kernel = tuple(
+                    self._sums_in_kernel(st, li) for li in chunk_layers)
+                st.kernel_sums[c] = sum(jax.tree.leaves(in_kernel))
+                accumulate = functools.partial(
+                    _accumulate, layers=chunk_layers, in_kernel=in_kernel)
                 # The sum leaves each program where it came in: the
                 # parameters' own shardings, or the donation does not take.
                 acc_shardings = tuple(
@@ -745,37 +802,44 @@ class PipelineInstance:
                     # The loss and d(loss·scale)/d(params, x) from one
                     # forward: the loss rides out as the aux value,
                     # unscaled, exactly what `fwd` returns.
-                    def bwd(params_tuple, acc, x, tokens, _apply=apply):
-                        def loss_fn(pt, x_):
-                            loss = _apply(pt, x_, tokens)
-                            return loss * scale, loss
+                    def bwd(params_tuple, acc, x, tokens, _apply=apply,
+                            _accumulate=accumulate):
+                        def backward(**sums):
+                            def loss_fn(pt, x_):
+                                loss = _apply(pt, x_, tokens, **sums)
+                                return loss * scale, loss
 
-                        if x is None:
-                            (_, loss), grads = jax.value_and_grad(
-                                lambda pt: loss_fn(pt, None),
-                                has_aux=True)(params_tuple)
-                            dx = None
-                        else:
+                            if x is None:
+                                (_, loss), grads = jax.value_and_grad(
+                                    lambda pt: loss_fn(pt, None),
+                                    has_aux=True)(params_tuple)
+                                return grads, (loss, None)
                             (_, loss), (grads, dx) = jax.value_and_grad(
                                 loss_fn, argnums=(0, 1),
                                 has_aux=True)(params_tuple, x)
-                        return loss, jax.tree.map(jnp.add, acc, grads), dx
+                            return grads, (loss, dx)
+
+                        new, (loss, dx) = _accumulate(acc, backward)
+                        return loss, new, dx
 
                     out_shardings = (None, acc_shardings, None)
                 else:
-                    def bwd(params_tuple, acc, x, tokens, dy, _apply=apply):
-                        if x is None:
-                            # First chunk: differentiate wrt params only.
+                    def bwd(params_tuple, acc, x, tokens, dy, _apply=apply,
+                            _accumulate=accumulate):
+                        def backward(**sums):
+                            if x is None:
+                                # First chunk: differentiate wrt params only.
+                                _, vjp = jax.vjp(
+                                    lambda pt: _apply(pt, None, tokens,
+                                                      **sums),
+                                    params_tuple)
+                                return vjp(dy)[0], None
                             _, vjp = jax.vjp(
-                                lambda pt: _apply(pt, None, tokens),
-                                params_tuple)
-                            (grads,), dx = vjp(dy), None
-                        else:
-                            _, vjp = jax.vjp(
-                                lambda pt, x_: _apply(pt, x_, tokens),
+                                lambda pt, x_: _apply(pt, x_, tokens, **sums),
                                 params_tuple, x)
-                            grads, dx = vjp(dy)
-                        return jax.tree.map(jnp.add, acc, grads), dx
+                            return vjp(dy)
+
+                        return _accumulate(acc, backward)
 
                     out_shardings = (acc_shardings, None)
 
@@ -791,7 +855,20 @@ class PipelineInstance:
 
                     st.efwd[c] = jax.jit(eval_fwd)
                 self._exec_cache[key] = (
-                    st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c])
+                    st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c],
+                    st.kernel_sums[c])
+
+    def _sums_in_kernel(self, st: StageRuntime, li: int):
+        """The leaves of layer `li` whose running gradient sum goes down
+        into a kernel, as the model marks them (a tree of booleans over the
+        layer's parameters), or None. Only on a stage of one device: there
+        no mesh axis reduces gradients (a reduction would sum the running
+        sum with them) and no partitioner stands between the donated sum
+        and the kernel that writes into it."""
+        marks = getattr(self.model, "sums_in_kernel", None)
+        if marks is None or st.mesh.size > 1:
+            return None
+        return marks(li, st.param_shardings[li])
 
     # ------------------------------------------------------------------ #
 
@@ -923,7 +1000,7 @@ class PipelineInstance:
         fwd_dispatches = {"run": 0, "folded": 0}
         # Gradient sums of local chunks: started from a zero fill, added
         # to inside a backward program.
-        accumulated = {"backward": 0, "zero_fill": 0}
+        accumulated = {"backward": 0, "zero_fill": 0, "moe_tgmm": 0}
 
         def record_op(stage, chunk, kind, dt):
             tot, n = op_times.get((stage, chunk, kind), (0.0, 0))
@@ -1063,6 +1140,7 @@ class PipelineInstance:
                 record_op(ins.stage, c, "b", time.perf_counter() - t0)
                 grads.update(zip(chunk_layers, acc))
                 accumulated["backward"] += 1
+                accumulated["moe_tgmm"] += st.kernel_sums[c]
                 if dx is not None:
                     stash[(ins.stage, c, m, "dx")] = dx
                 acts.pop(key, None)
@@ -1121,7 +1199,8 @@ class PipelineInstance:
         accumulations = metrics.registry().counter(
             "oobleck_pipeline_grad_accumulations_total",
             "Microbatch gradient sums of local pipeline chunks: added to "
-            "inside a backward program, or started from a zero fill")
+            "inside a backward program, or started from a zero fill; "
+            "moe_tgmm: leaves a backward summed inside that kernel")
         for where, n in accumulated.items():
             if n:
                 accumulations.inc(n, where=where)

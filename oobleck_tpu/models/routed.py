@@ -158,12 +158,34 @@ class RoutedShareModel:
             return self._init_head(ks[2])
         return self._init_block(jax.random.fold_in(ks[1], index), index - 1)
 
-    def apply_layer(self, index: int, params, carry, batch, ctx=None):
+    def apply_layer(self, index: int, params, carry, batch, ctx=None,
+                    grad_sums=None):
         if index == 0:
             return self.embed(params, batch["input_ids"])
         if index == self.num_pipeline_layers - 1:
             return self.head(params, carry)
-        return self.apply_block(index - 1, params, carry)
+        return self.apply_block(index - 1, params, carry,
+                                grad_sums=grad_sums)
+
+    def sums_in_kernel(self, index: int, params):
+        """Which leaves' running gradient sums layer `index` takes down
+        into the dW kernel (`ops/moe.GradSum`), as a tree of booleans over
+        `params` (any tree of the layer's structure), or None for none:
+        the held experts' matrices of a routed block, where the expert
+        kernels run. They go from the parameter tree into the grouped
+        products with nothing between (`routed_ff`), so such a leaf's
+        cotangent comes back as sum + gradient and `apply_layer` wants the
+        sums of the marked leaves in `grad_sums`."""
+        from oobleck_tpu.ops import moe
+
+        block = index - 1
+        if not (0 <= block < self.config.num_layers and self.is_routed(block)
+                and moe._pallas_ok()):
+            return None
+        marks = jax.tree.map(lambda _: False, params)
+        marks["ff"].update(
+            {w: True for w in ("w1", "w3", "w2") if w in params["ff"]})
+        return marks
 
     @jax.named_scope("lm_head")
     def loss_from_logits(self, logits, batch):
@@ -211,13 +233,16 @@ class RoutedShareModel:
         return g @ p["w2"].astype(dt)
 
     def routed_ff(self, p, h, *, forced_experts=None,
-                  return_routing: bool = False):
+                  return_routing: bool = False, grad_sums=None):
         """The part of the routed layer that the experts held here give.
-        With `return_routing`, (that, the chosen experts [B, S, k])."""
+        With `return_routing`, (that, the chosen experts [B, S, k]).
+        `grad_sums`: `p`'s tree with the experts' running gradient sums
+        (`sums_in_kernel`)."""
         from oobleck_tpu.ops.moe import routed_experts
 
         c = self.config
         b, s, e = h.shape
+        sums = {} if grad_sums is None else grad_sums
         out = routed_experts(
             h.reshape(b * s, e), p["router"], p.get("expert_bias"),
             p["w1"], p.get("w3"), p["w2"],
@@ -226,7 +251,8 @@ class RoutedShareModel:
             routed_scaling_factor=c.routed_scaling_factor,
             forced_experts=(None if forced_experts is None
                             else forced_experts.reshape(b * s, -1)),
-            return_routing=return_routing)
+            return_routing=return_routing,
+            dw_sums=tuple(sums.get(w) for w in ("w1", "w3", "w2")))
         if return_routing:
             y, experts = out
             return y.reshape(b, s, e), experts.reshape(b, s, -1)
@@ -234,7 +260,7 @@ class RoutedShareModel:
 
     @jax.named_scope("mlp")
     def feed_forward(self, block: int, p, h, *, forced_experts=None,
-                     return_routing: bool = False):
+                     return_routing: bool = False, grad_sums=None):
         """The dense feed-forward or the routed experts, by the block: of
         a routed block the part its held experts give, plus, where the
         entry has them, the `shared` experts (on every token, weight 1:
@@ -246,7 +272,8 @@ class RoutedShareModel:
             return self.dense_ff(p, h)
         shared = self.dense_ff(p["shared"], h) if "shared" in p else None
         out = self.routed_ff(p, h, forced_experts=forced_experts,
-                             return_routing=return_routing)
+                             return_routing=return_routing,
+                             grad_sums=grad_sums)
         if shared is None:
             return out
         if return_routing:
@@ -254,7 +281,7 @@ class RoutedShareModel:
         return out + shared
 
     def apply_block(self, block: int, p, x, *, forced_experts=None,
-                    return_routing: bool = False):
+                    return_routing: bool = False, grad_sums=None):
         c = self.config
         experts = None
         for branch in self.branches(block):
@@ -263,9 +290,10 @@ class RoutedShareModel:
                 x = x + self.operator_out(block, p, h)
                 continue
             h = rms_norm(x, p["ln_ff"]["scale"], c.norm_eps)
-            out = self.feed_forward(block, p["ff"], h,
-                                    forced_experts=forced_experts,
-                                    return_routing=return_routing)
+            out = self.feed_forward(
+                block, p["ff"], h, forced_experts=forced_experts,
+                return_routing=return_routing,
+                grad_sums=None if grad_sums is None else grad_sums["ff"])
             if return_routing and self.is_routed(block):
                 out, experts = out
             x = x + out

@@ -17,7 +17,10 @@ unsharded formulation is tested under shard_map on the CPU mesh.
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import functools
+import threading
 from typing import NamedTuple
 
 import jax
@@ -122,7 +125,8 @@ def switch_moe(
 #
 #   moe_gmm   rows [M, K] x experts [E, K, N] -> [M, N]   forward, and dX
 #             with the expert matrices read transposed
-#   moe_tgmm  rows^T [M, K] x rows [M, N] -> [E, K, N]    dW
+#   moe_tgmm  rows^T [M, K] x rows [M, N] -> [E, K, N]    dW, on top of
+#             a running gradient sum where the caller hands one (`GradSum`)
 #
 # On one chip nothing is exchanged; with `num_experts_held` < `num_experts`
 # the result is this chip's PART of the layer's output (the partial sums
@@ -329,9 +333,12 @@ def gmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int,
     )(tile_group, num_tiles, lhs, rhs)
 
 
-def _tgmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, out_ref, acc):
+def _tgmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, *refs):
     """Rows^T x rows of one tile, summed over the tiles of one expert into
-    its [tk, tn] block of dW."""
+    its [tk, tn] block of dW. `refs` is (out, acc) or (start, out, acc):
+    with `start`, an expert's sum begins from its block of it and not from
+    zeros."""
+    *start_ref, out_ref, acc = refs
     m = pl.program_id(2)
     used = num_tiles[0]
 
@@ -346,7 +353,10 @@ def _tgmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, out_ref, acc):
 
         @pl.when(first)
         def _():
-            acc[...] = jnp.zeros_like(acc)
+            if start_ref:
+                acc[...] = start_ref[0][...].astype(acc.dtype)
+            else:
+                acc[...] = jnp.zeros_like(acc)
 
         acc[...] += lax.dot_general(
             lhs_ref[...], rhs_ref[...], (((0,), (0,)), ((), ())),
@@ -358,10 +368,16 @@ def _tgmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, out_ref, acc):
 
 
 def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
-              out_dtype):
+              out_dtype, start=None):
     """`moe_tgmm`: lhs [M, K], rhs [M, N] -> [E, K, N], expert e's block
     the product over e's rows. Every expert has a tile, so every block is
-    written."""
+    written.
+
+    With `start` [E, K, N] in `out_dtype` the result is `start` + that
+    product, IN `start`'s buffer: a third tensor operand under the output's
+    own block map, aliased to the output. Every block is visited once (an
+    expert's tiles are contiguous), read before its one write, and no two
+    blocks overlap, which is what makes the alias sound."""
     m_rows, k = lhs.shape
     n = rhs.shape[1]
     assert rhs.shape[0] == m_rows and m_rows % tile == 0
@@ -369,9 +385,17 @@ def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
     tn = _col_tile(n, MAX_COL_TILE)
 
     last = _last_tile
+    out_shape = jax.ShapeDtypeStruct((num_groups, k, n), out_dtype)
+    block_of_dw = pl.BlockSpec(
+        (None, tk, tn),
+        lambda k_, n_, m_, tg, nt: (tg[last(m_, nt)], k_, n_))
+    started = [] if start is None else [start]
+    for operand in started:
+        assert (operand.shape, operand.dtype) == (
+            out_shape.shape, out_shape.dtype), (operand, out_shape)
     return pl.pallas_call(
         _tgmm_body,
-        out_shape=jax.ShapeDtypeStruct((num_groups, k, n), out_dtype),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(k // tk, n // tn, m_rows // tile),
@@ -379,49 +403,104 @@ def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
                 pl.BlockSpec((tile, tk),
                              lambda k_, n_, m_, tg, nt: (last(m_, nt), k_)),
                 pl.BlockSpec((tile, tn),
-                             lambda k_, n_, m_, tg, nt: (last(m_, nt), n_))],
-            out_specs=pl.BlockSpec(
-                (None, tk, tn),
-                lambda k_, n_, m_, tg, nt: (tg[last(m_, nt)], k_, n_)),
+                             lambda k_, n_, m_, tg, nt: (last(m_, nt), n_)),
+                *[block_of_dw for _ in started]],
+            out_specs=block_of_dw,
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        # Operands count the two prefetched tables: `start` is the fifth.
+        input_output_aliases={4: 0} if started else {},
         compiler_params=_TGMM_PARAMS,
         interpret=_interpret(),
         name="moe_tgmm",
-    )(tile_group, num_tiles, lhs, rhs)
+    )(tile_group, num_tiles, lhs, rhs, *started)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _gmm(lhs, rhs, tile_group, num_tiles, tile):
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class GradSum:
+    """A weight's running gradient sum, handed down to the product that
+    reads the weight: `grouped_matmul`'s backward rule then gives `value` +
+    dW as the weight's cotangent, written by `moe_tgmm` into `value`'s own
+    buffer, and whoever handed it takes that cotangent as the new sum where
+    it would have added. `name` says whose sum it is (static: it rides
+    through `jax.checkpoint` and the rule's residuals)."""
+    value: jax.Array
+    name: str = dataclasses.field(metadata=dict(static=True))
+
+
+_HANDED = threading.local()     # .open: {name: kernel calls that took it}
+
+
+@contextlib.contextmanager
+def handing_sums(names):
+    """Around the trace of a differentiated program that hands `GradSum`s
+    of these names down. A sum that no `moe_tgmm` call took leaves the
+    cotangent without it, and one that two took holds it twice: neither
+    shows in a shape, so both fail here, at trace time."""
+    assert getattr(_HANDED, "open", None) is None, "handing_sums does not nest"
+    _HANDED.open = taken = dict.fromkeys(names, 0)
+    try:
+        yield
+    finally:
+        _HANDED.open = None
+    wrong = {name: n for name, n in taken.items() if n != 1}
+    if wrong:
+        raise ValueError(
+            "gradient sums handed down and not taken by exactly one "
+            f"moe_tgmm call (name: calls): {wrong}")
+
+
+def _take(dw_sum: GradSum) -> jax.Array:
+    taken = getattr(_HANDED, "open", None)
+    if taken is None or dw_sum.name not in taken:
+        raise ValueError(
+            f"gradient sum {dw_sum.name!r} reached moe_tgmm outside "
+            "`handing_sums`, or under a name it was not opened with")
+    taken[dw_sum.name] += 1
+    return dw_sum.value
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gmm(lhs, rhs, dw_sum, tile_group, num_tiles, tile):
+    del dw_sum      # the backward rule's
     return gmm_call(lhs, rhs, tile_group, num_tiles, tile=tile)
 
 
-def _gmm_fwd(lhs, rhs, tile_group, num_tiles, tile):
+def _gmm_fwd(lhs, rhs, dw_sum, tile_group, num_tiles, tile):
     out = gmm_call(lhs, rhs, tile_group, num_tiles, tile=tile)
-    return out, (lhs, rhs, tile_group, num_tiles)
+    return out, (lhs, rhs, dw_sum, tile_group, num_tiles)
 
 
 def _gmm_bwd(tile, res, d_out):
-    lhs, rhs, tile_group, num_tiles = res
+    """dX, and dW as `rhs`'s cotangent: on top of `dw_sum` (None: of zeros)
+    and in its buffer where one was handed, so the cotangent IS the new
+    sum. The sum itself gets no cotangent."""
+    lhs, rhs, dw_sum, tile_group, num_tiles = res
     d_lhs = gmm_call(d_out, rhs, tile_group, num_tiles, tile=tile,
                      transpose_rhs=True)
     d_rhs = tgmm_call(lhs, d_out, tile_group, num_tiles, tile=tile,
-                      num_groups=rhs.shape[0], out_dtype=rhs.dtype)
-    return d_lhs, d_rhs, None, None
+                      num_groups=rhs.shape[0], out_dtype=rhs.dtype,
+                      start=None if dw_sum is None else _take(dw_sum))
+    return d_lhs, d_rhs, None, None, None
 
 
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
-def grouped_matmul(rows, experts, plan: RoutingPlan, tile: int):
+def grouped_matmul(rows, experts, plan: RoutingPlan, tile: int,
+                   dw_sum: GradSum | None = None):
     """rows [M, K] x experts [E, K, N] -> [M, N] over the plan's layout.
     The Pallas kernels on a TPU; elsewhere XLA's own ragged product over
-    the same padded groups (the kernels' interpreter is for their tests)."""
+    the same padded groups (the kernels' interpreter is for their tests).
+    `dw_sum`, `experts`' running gradient sum, is the kernels' alone."""
     if _pallas_ok():
         # Under an outer scope a transformation's wrapper (jvp(...),
         # transpose(...)) goes around THAT component of the name stack and
         # the kernels keep their own: `%moe_gmm.N`, not `%jvp_moe_gmm_.N`.
         with jax.named_scope("routed_experts"):
-            return _gmm(rows, experts, plan.tile_group, plan.num_tiles, tile)
+            return _gmm(rows, experts, dw_sum, plan.tile_group,
+                        plan.num_tiles, tile)
+    assert dw_sum is None, "a gradient sum handed off the kernels' path"
     return lax.ragged_dot(rows, experts.astype(rows.dtype), plan.padded_sizes)
 
 
@@ -621,30 +700,31 @@ def _relu2_bwd(tile, res, d_hidden):
 _relu2.defvjp(_relu2_fwd, _relu2_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _gate_and_up(rows, w1, w3, plan: RoutingPlan, tile: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gate_and_up(rows, w1, w3, dw_sums, plan: RoutingPlan, tile: int):
     """rows x W1 and rows x W3. One function, so that the two gradients of
     `rows` are added over the tiles in use and not, as autodiff would add
-    two cotangents, over the whole buffer."""
+    two cotangents, over the whole buffer. `dw_sums`: (W1's, W3's)."""
     return (grouped_matmul(rows, w1, plan, tile),
             grouped_matmul(rows, w3, plan, tile))
 
 
-def _gate_and_up_fwd(rows, w1, w3, plan, tile):
-    return _gate_and_up(rows, w1, w3, plan, tile), (rows, w1, w3, plan)
+def _gate_and_up_fwd(rows, w1, w3, dw_sums, plan, tile):
+    return (_gate_and_up(rows, w1, w3, dw_sums, plan, tile),
+            (rows, w1, w3, dw_sums, plan))
 
 
 def _gate_and_up_bwd(tile, res, cotangents):
-    rows, w1, w3, plan = res
+    rows, w1, w3, dw_sums, plan = res
     d_rows, pulls = [], []
-    for w, d in zip((w1, w3), cotangents):
-        _, pull = jax.vjp(lambda r, w_: grouped_matmul(r, w_, plan, tile),
-                          rows, w)
+    for w, dw_sum, d in zip((w1, w3), dw_sums, cotangents):
+        _, pull = jax.vjp(
+            lambda r, w_: grouped_matmul(r, w_, plan, tile, dw_sum), rows, w)
         d_r, d_w = pull(d)
         d_rows.append(d_r)
         pulls.append(d_w)
     (total,) = _over_tiles(plan, tile, lambda a, b: (a + b,), *d_rows)
-    return total, pulls[0], pulls[1], None
+    return total, pulls[0], pulls[1], None, None
 
 
 _gate_and_up.defvjp(_gate_and_up_fwd, _gate_and_up_bwd)
@@ -701,6 +781,8 @@ def routed_experts(
     routed_scaling_factor: float = 1.0,
     forced_experts: jax.Array | None = None,
     return_routing: bool = False,
+    dw_sums: tuple[GradSum | None, GradSum | None, GradSum | None] = (
+        None, None, None),
 ):
     """Dropless top-k sigmoid-routed experts, the part that the experts
     held here give. SwiGLU experts, or, with `w3` None, experts WITHOUT a
@@ -714,7 +796,9 @@ def routed_experts(
     of weight x W2 (silu(W1 x) * W3 x). With all experts held that is the
     whole layer. `forced_experts` [T, k] replaces the selection (the
     weights still come from this call's own scores). With
-    `return_routing`, also the chosen experts [T, k]."""
+    `return_routing`, also the chosen experts [T, k]. `dw_sums`: the
+    running gradient sums of (w1, w3, w2) that the dW kernels are to add
+    to (`GradSum`); their cotangents then come back as sum + dW."""
     t, d = x.shape
     held = w1.shape[0]
     assert router_w.shape == (d, num_experts), router_w.shape
@@ -732,11 +816,12 @@ def routed_experts(
     xs = _dispatch(x, plan, tile, top_k)
     if w3 is None:
         _count_ungated_call()
-        hidden = _relu2(grouped_matmul(xs, w1, plan, tile), plan, tile)
+        hidden = _relu2(grouped_matmul(xs, w1, plan, tile, dw_sums[0]),
+                        plan, tile)
     else:
-        gate, up = _gate_and_up(xs, w1, w3, plan, tile)
+        gate, up = _gate_and_up(xs, w1, w3, dw_sums[:2], plan, tile)
         hidden = _swiglu(gate, up, plan, tile)
-    out = grouped_matmul(hidden, w2, plan, tile)
+    out = grouped_matmul(hidden, w2, plan, tile, dw_sums[2])
     y = _combine(out, weights, plan, tile, top_k)
     if return_routing:
         return y, experts

@@ -92,6 +92,43 @@ def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
     assert count == {"gpt3-2.7b": {}, "nemotron-3-nano-30b-a3b": UNGATED}.get(
         cell, ROUTED)
     assert compiled.memory_analysis().temp_size_in_bytes < temp_bound
+    if cell in EXPERT_SETS:
+        _the_experts_sums_are_the_kernels(cell, compiled.as_text())
+
+
+# cell -> the float32 shapes of a routed layer's held experts (w1 / w3,
+# w2), and the copies of them the compiler may leave. The running gradient
+# sums of these go INTO `moe_tgmm` (ops/moe.py, execution/pipeline.py): a
+# plain `add` of such a shape is a sum that stayed outside (6–15 ms each on
+# the chip, PERF.md), a `copy` one that XLA could not alias in place.
+# 1856 is no multiple of a lane, so the compiler lays [8, 2688, 1856]
+# ENTRY operands out {1,2,0} and copies to and from the kernels' {2,1,0}:
+# two reads of w1, the sum in and the sum out, a layer, as before the sums
+# moved (PERF.md: the odd width's copy stayed, the add went).
+EXPERT_SETS = {
+    "lfm2-24b-a2b": (("8,2048,1536", "8,1536,2048"), 0),
+    "moonlight-16b-a3b": (("8,2048,1408", "8,1408,2048"), 0),
+    "nemotron-3-nano-30b-a3b": (("8,2688,1856", "8,1856,2688"), 3 * 4),
+}
+
+
+def _the_experts_sums_are_the_kernels(cell, text):
+    shapes, layout_copies = EXPERT_SETS[cell]
+    of_a_set = "|".join(re.escape(s) for s in shapes)
+    ops = re.findall(rf"%([a-z_\-]+)[.\d]* = f32\[(?:{of_a_set})\]\S* "
+                     r"(\S+?)\(([^\n]*)", text)
+    dw = [operands for name, kind, operands in ops if name == "moe_tgmm"]
+    assert len(dw) == {"nemotron-3-nano-30b-a3b": UNGATED}.get(
+        cell, ROUTED)["moe_tgmm"]
+    for operands in dw:
+        # The sum is the call's last operand: the donated `acc` leaf
+        # itself (or its layout copy), written in place.
+        assert re.search(r", %(acc_\w+|copy)[.\d]*\), custom_call_target",
+                         operands), operands[:300]
+        assert "output_to_operand_aliasing={{}: (4, {})}" in operands
+    kinds = [kind for _, kind, _ in ops]
+    assert kinds.count("add") == 0
+    assert kinds.count("copy") == layout_copies
 
 
 def _plain_block(w, x):

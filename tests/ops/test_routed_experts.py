@@ -5,7 +5,8 @@ The op against a loop over tokens (uneven loads, an expert that gets no
 token, every pick of a token held here), the shares' partial results
 against the uncut layer, gradients against a dense formulation that
 autodiff differentiates, and the kernels in interpreter mode against one
-`jnp.einsum` per group.
+`jnp.einsum` per group; a running gradient sum handed to the dW kernel
+against the sum plus what the kernel gives without it.
 """
 
 import numpy as np
@@ -236,6 +237,113 @@ def test_gmm_kernel_equals_einsum_per_group(sizes, dtype):
         np.testing.assert_allclose(
             np.asarray(dw[g]), np.asarray(jnp.einsum("mk,mn->kn", a, d)),
             atol=tol * 8)
+
+
+@pytest.mark.parametrize("sizes", SIZES, ids=["uneven", "one_full", "even"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_tgmm_kernel_starts_from_a_sum_it_is_handed(sizes, dtype):
+    """Experts with only their pad tile (a size of 0) included: that
+    tile's rows are the kernel's to multiply like any other's."""
+    plan, rows, tile = _layout(sizes)
+    held, k, n = len(sizes), 32, 48
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    lhs = jax.random.normal(ks[0], (rows, k)).astype(dtype)
+    d_out = jax.random.normal(ks[1], (rows, n)).astype(dtype)
+    start = jax.random.normal(ks[2], (held, k, n)) * 3.0
+    call = lambda **kw: moe.tgmm_call(
+        lhs, d_out, plan.tile_group, plan.num_tiles, tile=tile,
+        num_groups=held, out_dtype=jnp.float32, **kw)
+    got, alone = call(start=start), call()
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(start + alone),
+                               atol=1e-5)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The kernels' path, in the interpreter."""
+    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
+    monkeypatch.setattr(moe, "_interpret", lambda: True)
+
+
+def _weights_and_sums(layer, offset, held, gated):
+    sl = slice(offset, offset + held)
+    names = ("w1", "w3", "w2") if gated else ("w1", "w2")
+    ws = {n: layer[n][sl] for n in names}
+    keys = jax.random.split(jax.random.PRNGKey(13), len(names))
+    sums = {n: jax.random.normal(k, ws[n].shape) for n, k in zip(names, keys)}
+    return ws, sums
+
+
+def _weight_grads(layer, offset, ws, sums, names=None):
+    """d sum(y^2) / d the held experts' matrices, each `sums` entry handed
+    down under its own name (or under `names`' entry for it; the names
+    opened are `names`' values)."""
+    names = {n: n for n in sums} if names is None else names
+
+    @jax.jit
+    def grads(ws, sums):
+        handed = {n: moe.GradSum(s, names[n]) for n, s in sums.items()}
+
+        def loss(ws):
+            y = moe.routed_experts(
+                layer["x"], layer["router"], layer["bias"], ws["w1"],
+                ws.get("w3"), ws["w2"], num_experts=NE, top_k=K,
+                expert_offset=offset,
+                dw_sums=tuple(handed.get(n) for n in ("w1", "w3", "w2")))
+            return jnp.sum(y ** 2)
+
+        with moe.handing_sums(set(names.values())):
+            return jax.grad(loss)(ws)
+
+    return grads(ws, sums)
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "ungated"])
+@pytest.mark.parametrize("offset,held", [(0, NE), (2, 4), (3, 1)],
+                         ids=["all", "share", "empty_expert_only"])
+def test_gradients_with_sums_handed_are_the_sums_plus_the_gradients(
+        layer, interpreted, offset, held, gated):
+    ws, sums = _weights_and_sums(layer, offset, held, gated)
+    plain = _weight_grads(layer, offset, ws, {})
+    got = _weight_grads(layer, offset, ws, sums)
+    assert sorted(got) == sorted(ws)
+    for n in ws:
+        np.testing.assert_allclose(np.asarray(got[n]),
+                                   np.asarray(sums[n] + plain[n]), atol=2e-5)
+
+
+WRONGLY_HANDED = {
+    # w3's sum rides under w1's name: one name taken twice, one never.
+    "taken_twice": ({"w1": "w1", "w3": "w1", "w2": "w2"}, "'w1': 2"),
+    # A name opened and handed to nothing.
+    "not_taken": ({"w1": "w1", "w3": "w3", "w2": "w2", "lost": "lost"},
+                  "'lost': 0"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WRONGLY_HANDED))
+def test_a_sum_not_taken_exactly_once_fails_the_trace(layer, interpreted,
+                                                      case):
+    names, said = WRONGLY_HANDED[case]
+    ws, sums = _weights_and_sums(layer, 2, 4, True)
+    with pytest.raises(ValueError, match=said):
+        _weight_grads(layer, 2, ws, sums, names)
+
+
+def test_a_sum_cannot_be_handed_outside_its_ledger_or_off_the_kernels(layer,
+                                                                      monkeypatch):
+    ws, sums = _weights_and_sums(layer, 2, 4, True)
+    handed = tuple(moe.GradSum(sums[n], n) for n in ("w1", "w3", "w2"))
+    loss = lambda ws: jnp.sum(moe.routed_experts(
+        layer["x"], layer["router"], layer["bias"], ws["w1"], ws["w3"],
+        ws["w2"], num_experts=NE, top_k=K, expert_offset=2, dw_sums=handed))
+    with pytest.raises(AssertionError, match="off the kernels' path"):
+        jax.grad(loss)(ws)              # `lax.ragged_dot` takes no sum
+    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
+    monkeypatch.setattr(moe, "_interpret", lambda: True)
+    with pytest.raises(ValueError, match="outside `handing_sums`"):
+        jax.grad(loss)(ws)
 
 
 # (tokens, top k, experts) -> the tile: `lfm2-24b-a2b.steady`'s call (512

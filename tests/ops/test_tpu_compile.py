@@ -480,3 +480,59 @@ def test_cell_sized_backward_holds_the_gradients_once(v5e):
     assert fill.argument_size_in_bytes == 0      # the parameters are not read
     assert fill.temp_size_in_bytes == 0
     assert grad_bytes <= fill.output_size_in_bytes <= grad_bytes + slack
+
+
+# The routed experts' running gradient sums go INTO `moe_tgmm` (ops/moe.py):
+# the model marks the held experts' matrices, the stage's `jit_bwd` hands
+# their sums down, and the dW kernel takes each as a third tensor operand
+# aliased to its output. (cell: (microbatch, sequence), dW kernels a
+# backward = routed layers x matrices an expert.) Lowered, not compiled:
+# what the compiler then leaves of adds and copies is read where the cells'
+# `jit_bwd` is compiled anyway, tests/ops/test_remat_residuals.py.
+ROUTED_CELLS = {
+    "lfm2-24b-a2b": ((8, 1024), 4 * 3),
+    "moonlight-16b-a3b": ((1, 4096), 4 * 3),
+    "nemotron-3-nano-30b-a3b": ((1, 4096), 3 * 2),
+}
+
+
+def _kernel_calls(text):
+    """(kernel name, the call's attributes) of every Mosaic call of a
+    lowered module, without the kernels' serialized bodies."""
+    return re.findall(
+        r'stablehlo\.custom_call @tpu_custom_call\([^)]*\) \{backend_config = '
+        r'"[^"]*", kernel_name = "(\w+)"([^\n]*)', text)
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
+def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
+    (mb, seq), sums = ROUTED_CELLS[cell]
+    st, params, batch = cell_stage(cell, v5e, microbatch=mb, seq=seq)
+    assert st.kernel_sums == [sums]
+    calls = _kernel_calls(
+        st.bwd[0].lower(params, params, None, batch).as_text())
+    # The rooflines and `moe_*_ms` match `%moe_gmm.` / `%moe_tgmm.`.
+    assert {n for n, _ in calls if n.startswith("moe")} == {
+        "moe_gmm", "moe_tgmm"}
+    dw = [attrs for n, attrs in calls if n == "moe_tgmm"]
+    assert len(dw) == sums
+    for attrs in dw:        # operands: two tables, rows, rows, the sum
+        assert ("output_operand_aliases = [#stablehlo.output_operand_alias<"
+                "output_tuple_indices = [], operand_index = 4, "
+                "operand_tuple_indices = []>]") in attrs
+    assert not any("output_operand_aliases" in attrs
+                   for n, attrs in calls if n == "moe_gmm")
+
+
+def test_a_cell_without_routed_layers_lowers_to_the_text_it_lowered_to(v5e):
+    """`gpt3-2.7b`'s `jit_bwd` has no sum to hand down: sha256 and length of
+    its lowered text at the parent of the PR that moved the sums, the
+    kernels' serialized bodies (which carry source lines) blanked."""
+    import hashlib
+
+    st, params, batch = cell_stage("gpt3-2.7b", v5e, microbatch=4, seq=1024)
+    assert st.kernel_sums == [0]
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""',
+                  st.bwd[0].lower(params, params, None, batch).as_text())
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16], len(text)) == (
+        "1c314568b7c7d581", 172933)
