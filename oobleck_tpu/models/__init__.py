@@ -93,6 +93,21 @@ register("nemotron-3-nano-30b-a3b")(lambda o: _nemotron_h(o))
 register("nemotron-h-tiny")(lambda o: _nemotron_h(o, vocab_size=256, hidden_size=64, num_layers=5, hybrid_override_pattern="MEM*E", mamba_num_heads=4, mamba_head_dim=16, ssm_state_size=16, n_groups=2, chunk_size=16, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=40, moe_intermediate_size=40, moe_shared_expert_intermediate_size=80, num_experts=8, num_experts_per_tok=3, max_position_embeddings=128))
 
 
+def _qwen3_next(overrides: dict[str, Any], **preset):
+    from oobleck_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextModel
+
+    return Qwen3NextModel(
+        Qwen3NextConfig().override(**preset).override(**overrides))
+
+
+# Qwen3-Next family (`qwen3_next`): Gated DeltaNet mixers three layers in
+# four beside one gated softmax-attention layer, top-k softmax-routed
+# experts beside a sigmoid-gated shared one in every layer; the defaults are
+# Qwen3-Next-80B-A3B-Instruct's.
+register("qwen3-next-80b-a3b")(lambda o: _qwen3_next(o))
+register("qwen3-next-tiny")(lambda o: _qwen3_next(o, vocab_size=256, hidden_size=64, num_layers=4, linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=16, chunk_size=16, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=128, moe_intermediate_size=32, shared_expert_intermediate_size=32, num_experts=16, num_experts_per_tok=4, max_position_embeddings=128))
+
+
 # Bloom family: GPT architecture with ALiBi position biases (no wpe)
 register("bloom-560m")(lambda o: _gpt(o, vocab_size=250880, hidden_size=1024, num_layers=24, num_heads=16, position_embedding="alibi"))
 register("bloom-7b1")(lambda o: _gpt(o, vocab_size=250880, hidden_size=4096, num_layers=30, num_heads=32, position_embedding="alibi"))

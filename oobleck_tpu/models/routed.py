@@ -1,16 +1,20 @@
 """What the decoders with routed experts share (`models/lfm2.py`,
-`models/deepseek_v3.py`, `models/nemotron_h.py`): a layer list whose blocks
-DIFFER, one chip's share of an expert-parallel deployment as a model of its
-own, and the probe that reads where a sequence was routed.
+`models/deepseek_v3.py`, `models/nemotron_h.py`, `models/qwen3_next.py`): a
+layer list whose blocks DIFFER, one chip's share of an expert-parallel
+deployment as a model of its own, and the probe that reads where a sequence
+was routed.
 
 A block is one or two residual branches, each behind an RMSNorm `N` of its
 own: `x + Op(N(x))` (`OP`) and `x + FF(N(x))` (`FF`). A family says which
 a block has (`branches`: both, in that order, unless it says otherwise),
-what `Op` is (`operator_out`) and which blocks are routed (`is_routed`);
-the feed-forwards (SwiGLU, or `W2 relu(W1 h)^2` for an entry without `w3`),
-the routed call (`ops/moe.routed_experts`), the shared expert added to the
-routed sum, the embedding and the untied head over the vocabulary rows
-held, the loss and the chaining of layers are here.
+what `Op` is (`operator_out`), which blocks are routed (`is_routed`), what
+its router scores with (`router_score`) and what its norm is (`norm`: the
+plain RMSNorm unless it says otherwise); the feed-forwards (SwiGLU, or
+`W2 relu(W1 h)^2` for an entry without `w3`), the routed call
+(`ops/moe.routed_experts`), the shared expert added to the routed sum
+(times `sigmoid(h w_g)` where the entry has a gate `w_g`), the embedding
+and the untied head over the vocabulary rows held, the loss and the
+chaining of layers are here.
 
 The share: `num_experts_held` experts from `expert_offset` (the router still
 scores all `num_experts`; the layer gives the part its held experts give),
@@ -112,6 +116,8 @@ class RoutedShareModel:
 
     data_kind = "causal_lm"
     fused_supported = False
+    # What the family's router scores with (`ops/moe.route`).
+    router_score = "sigmoid"
 
     def __init__(self, config):
         self.config = config
@@ -128,6 +134,10 @@ class RoutedShareModel:
     def branches(self, block: int) -> tuple[str, ...]:
         """The residual branches of block `block`, in order."""
         return (OP, FF)
+
+    def norm(self, x, scale):
+        """The RMSNorm in front of every branch and of the head."""
+        return rms_norm(x, scale, self.config.norm_eps)
 
     def _init_block(self, rng, block: int):
         raise NotImplementedError
@@ -252,7 +262,8 @@ class RoutedShareModel:
             forced_experts=(None if forced_experts is None
                             else forced_experts.reshape(b * s, -1)),
             return_routing=return_routing,
-            dw_sums=tuple(sums.get(w) for w in ("w1", "w3", "w2")))
+            dw_sums=tuple(sums.get(w) for w in ("w1", "w3", "w2")),
+            score=self.router_score)
         if return_routing:
             y, experts = out
             return y.reshape(b, s, e), experts.reshape(b, s, -1)
@@ -271,6 +282,11 @@ class RoutedShareModel:
         if not self.is_routed(block):
             return self.dense_ff(p, h)
         shared = self.dense_ff(p["shared"], h) if "shared" in p else None
+        if shared is not None and "w_g" in p["shared"]:
+            # A shared expert behind a gate of its own, sigmoid(h w_g).
+            shared = shared * jax.nn.sigmoid(jnp.einsum(
+                "bse,e->bs", h, p["shared"]["w_g"].astype(h.dtype),
+                preferred_element_type=jnp.float32))[..., None].astype(h.dtype)
         out = self.routed_ff(p, h, forced_experts=forced_experts,
                              return_routing=return_routing,
                              grad_sums=grad_sums)
@@ -282,14 +298,13 @@ class RoutedShareModel:
 
     def apply_block(self, block: int, p, x, *, forced_experts=None,
                     return_routing: bool = False, grad_sums=None):
-        c = self.config
         experts = None
         for branch in self.branches(block):
             if branch == OP:
-                h = rms_norm(x, p["ln_op"]["scale"], c.norm_eps)
+                h = self.norm(x, p["ln_op"]["scale"])
                 x = x + self.operator_out(block, p, h)
                 continue
-            h = rms_norm(x, p["ln_ff"]["scale"], c.norm_eps)
+            h = self.norm(x, p["ln_ff"]["scale"])
             out = self.feed_forward(
                 block, p["ff"], h, forced_experts=forced_experts,
                 return_routing=return_routing,
@@ -304,7 +319,7 @@ class RoutedShareModel:
     @jax.named_scope("lm_head")
     def head(self, p, x):
         c = self.config
-        x = rms_norm(x, p["ln_f"]["scale"], c.norm_eps)
+        x = self.norm(x, p["ln_f"]["scale"])
         return (x @ p["w"].astype(c.dtype)).astype(jnp.float32)
 
     # Forward for one device: chain the layers as the pipeline does.

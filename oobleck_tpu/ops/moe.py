@@ -741,16 +741,38 @@ def _count_ungated_call() -> None:
         "traced programs").inc()
 
 
+def _count_softmax_call() -> None:
+    """`oobleck_moe_softmax_routed_calls_total`: counted as the ungated
+    calls are, once a routed layer of every program traced."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_moe_softmax_routed_calls_total",
+        "Routed-expert calls whose scores are a softmax over all the "
+        "experts, built into traced programs").inc()
+
+
+SIGMOID, SOFTMAX = "sigmoid", "softmax"
+
+
 def route(x, router_w, expert_bias, *, top_k: int,
           norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
-          forced_experts: jax.Array | None = None):
-    """Sigmoid scores in float32, the top-k of score + bias, weights from
-    the scores alone. x [T, D] -> (experts [T, k] int32, weights [T, k]
-    float32). The bias selects and is not trained. `forced_experts`
-    replaces the selection; the weights still come from these scores."""
+          forced_experts: jax.Array | None = None, score: str = SIGMOID):
+    """Scores in float32 as the family says (`score`): sigmoid, the top-k
+    of score + bias, weights from the scores alone, normalised over the
+    chosen (+ 1e-6); or a softmax over ALL the experts, its top-k, weights
+    from it, normalised over the chosen (their sum is no less than
+    k / experts: no epsilon). x [T, D] -> (experts [T, k] int32, weights
+    [T, k] float32). The bias selects and is not trained.
+    `forced_experts` replaces the selection; the weights still come from
+    these scores."""
+    assert score in (SIGMOID, SOFTMAX), score
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)                              # [T, NE]
+    if score == SOFTMAX:
+        scores = jax.nn.softmax(logits, axis=-1)                 # [T, NE]
+    else:
+        scores = jax.nn.sigmoid(logits)
     if forced_experts is not None:
         experts = forced_experts
     else:
@@ -762,7 +784,8 @@ def route(x, router_w, expert_bias, *, top_k: int,
     picked = jax.nn.one_hot(experts, scores.shape[-1], dtype=jnp.float32)
     weights = jnp.einsum("tke,te->tk", picked, scores)
     if norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + (0.0 if score == SOFTMAX else 1e-6))
     return experts.astype(jnp.int32), weights * routed_scaling_factor
 
 
@@ -783,11 +806,13 @@ def routed_experts(
     return_routing: bool = False,
     dw_sums: tuple[GradSum | None, GradSum | None, GradSum | None] = (
         None, None, None),
+    score: str = SIGMOID,
 ):
-    """Dropless top-k sigmoid-routed experts, the part that the experts
-    held here give. SwiGLU experts, or, with `w3` None, experts WITHOUT a
-    gate: W2 relu(W1 x)^2, two grouped products forward and four backward
-    (`grouped_matmul`'s own dX and dW) where SwiGLU has three and six.
+    """Dropless top-k routed experts (`score`: sigmoid or softmax scores,
+    `route`), the part that the experts held here give. SwiGLU experts,
+    or, with `w3` None, experts WITHOUT a gate: W2 relu(W1 x)^2, two
+    grouped products forward and four backward (`grouped_matmul`'s own dX
+    and dW) where SwiGLU has three and six.
 
     x [T, D]; router_w [D, num_experts]; expert_bias [num_experts] or
     None; w1, w3 [held, D, F], w2 [held, F, D]: experts `expert_offset` ..
@@ -806,7 +831,9 @@ def routed_experts(
     experts, weights = route(
         x, router_w, expert_bias, top_k=top_k, norm_topk_prob=norm_topk_prob,
         routed_scaling_factor=routed_scaling_factor,
-        forced_experts=forced_experts)
+        forced_experts=forced_experts, score=score)
+    if score == SOFTMAX:
+        _count_softmax_call()
 
     local = experts.reshape(-1) - expert_offset
     local = jnp.where((local >= 0) & (local < held), local, held)

@@ -10,6 +10,19 @@ import jax
 CONFIGS = Path(__file__).resolve().parents[2] / "benchmarks" / "configs"
 
 
+def all_eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (a scan's body,
+    a checkpoint's, a custom rule's call) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = sub if hasattr(sub, "eqns") else getattr(
+                    sub, "jaxpr", None)
+                if hasattr(inner, "eqns"):
+                    yield from all_eqns(inner)
+
+
 def pallas_calls(jaxpr, found=None):
     """(name, operand shapes after the prefetched tables) of every
     `pallas_call` equation, sub-jaxprs included."""

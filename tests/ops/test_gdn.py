@@ -1,0 +1,263 @@
+"""`ops/gdn.gated_delta_rule`: Gated DeltaNet's recurrence in chunks,
+against the recurrence itself, one position after another, written out
+here.
+
+Forward and every gradient (q, k, v, g, beta) over lengths, chunks, value
+heads and key heads: a length that is no multiple of the chunk, one chunk
+only, one key head for all value heads, a key head a value head; decays of
+e^-20 a chunk and far beyond (no inf, no nan, forward or backward: `D` comes
+from a difference of running sums); beta at 0 (nothing is written) and at 1
+(the plain delta rule); the inverse of a unit lower-triangular matrix as a
+product; bfloat16 operands; and what the rule counts where it is built.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from oobleck_tpu.ops.gdn import gated_delta_rule, unit_lower_inverse
+from tests.ops.programs import all_eqns
+
+# (length, chunk, value heads, key heads)
+CASES = {
+    "whole_chunks": (64, 16, 4, 2),
+    "ragged_tail": (37, 16, 4, 2),
+    "one_chunk_only": (24, 32, 4, 2),
+    "one_position_chunks": (9, 1, 2, 1),
+    "one_key_head": (40, 8, 6, 1),
+    "a_key_head_a_value_head": (33, 8, 3, 3),
+}
+B, DK, DV = 2, 16, 8
+ARGS = ("q", "k", "v", "g", "beta")
+
+
+def recurrence(q, k, v, g, beta):
+    """S' = exp(g_t) S_{t-1}; u_t = beta_t (v_t - S'^T k_t); S_t = S' +
+    k_t u_t^T; o_t = S_t^T q_t, one position after another; value head h
+    reads key head h // (H / G)."""
+    bsz, _, heads, dv = v.shape
+    rep = heads // k.shape[2]
+    qh, kh = jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2)
+
+    def position(state, row):
+        q_t, k_t, v_t, g_t, beta_t = row
+        state = jnp.exp(g_t)[..., None, None] * state
+        u_t = beta_t[..., None] * (
+            v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
+        state = state + k_t[..., :, None] * u_t[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    _, o = lax.scan(position, jnp.zeros((bsz, heads, k.shape[-1], dv)),
+                    tuple(jnp.moveaxis(t, 1, 0) for t in (qh, kh, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def rule(*args, chunk):
+    return jax.jit(functools.partial(gated_delta_rule, chunk=chunk))(*args)
+
+
+def unit(x):
+    return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+
+def operands(length, heads, groups, *, seed=0):
+    """q and k as the mixer hands them over: unit length a head, q scaled
+    by dk^-1/2; g the log of a decay; beta a sigmoid."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (unit(jax.random.normal(ks[0], (B, length, groups, DK))) * DK ** -0.5,
+            unit(jax.random.normal(ks[1], (B, length, groups, DK))),
+            jax.random.normal(ks[2], (B, length, heads, DV)),
+            -jax.nn.softplus(jax.random.normal(ks[3], (B, length, heads))),
+            jax.nn.sigmoid(jax.random.normal(ks[4], (B, length, heads))))
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_chunked_rule_is_the_recurrence(case):
+    length, chunk, heads, groups = CASES[case]
+    args = operands(length, heads, groups)
+    got = rule(*args, chunk=chunk)
+    assert got.shape == (B, length, heads, DV) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(jax.jit(recurrence)(*args)),
+                               atol=2e-5)
+
+
+@functools.cache
+def _both_gradients(case):
+    """All five gradients of a case, chunked and step by step: computed
+    once, compared one operand a test."""
+    length, chunk, heads, groups = CASES[case]
+    args = operands(length, heads, groups, seed=1)
+    target = jax.random.normal(jax.random.PRNGKey(9), (B, length, heads, DV))
+    got = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=chunk) * target),
+        argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(recurrence(*a) * target),
+                            argnums=range(5)))(*args)
+    return got, want
+
+
+@pytest.mark.parametrize("wrt", range(5), ids=ARGS)
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_every_gradient_is_the_recurrences(case, wrt):
+    got, want = (g[wrt] for g in _both_gradients(case))
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5 * max(scale, 1.0), rtol=2e-4)
+
+
+@pytest.mark.parametrize("decay_a_chunk", [20.0, 2000.0],
+                         ids=["e-20", "e-2000"])
+def test_a_chunk_that_decays_to_nothing_has_no_inf_and_no_nan(decay_a_chunk):
+    """exp(cum_i) / exp(cum_j) would be 0 / 0 here; exp(cum_i - cum_j)
+    is not. The masked half of `D` (a POSITIVE difference, e^+2000 = inf)
+    must not reach a gradient either."""
+    length, chunk, heads, groups = 48, 16, 4, 2
+    q, k, v, g, beta = operands(length, heads, groups)
+    args = (q, k, v, jnp.full_like(g, -decay_a_chunk / chunk), beta)
+    o = rule(*args, chunk=chunk)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(np.asarray(o),
+                               np.asarray(jax.jit(recurrence)(*args)),
+                               atol=2e-5, rtol=2e-4)
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=chunk)),
+        argnums=range(5)))(*args)
+    assert all(np.isfinite(np.asarray(x)).all() for x in grads)
+
+
+@pytest.mark.parametrize("strength", [0.0, 1.0], ids=["beta_0", "beta_1"])
+def test_beta_at_its_ends(strength):
+    """beta = 0 writes nothing: the state stays 0 and so does the output.
+    beta = 1 with no decay is the plain delta rule: right after position t
+    wrote, the state answers k_t with v_t (k_t of unit length)."""
+    length, chunk, heads, groups = 40, 16, 4, 2
+    q, k, v, g, beta = operands(length, heads, groups)
+    args = (q, k, v, g, jnp.full_like(beta, strength))
+    o = rule(*args, chunk=chunk)
+    np.testing.assert_allclose(np.asarray(o),
+                               np.asarray(jax.jit(recurrence)(*args)),
+                               atol=2e-5)
+    if strength == 0.0:
+        assert not np.asarray(o).any()
+        return
+    read_back = rule(k, k, v, jnp.zeros_like(g), jnp.ones_like(beta),
+                     chunk=chunk)
+    np.testing.assert_allclose(np.asarray(read_back), np.asarray(v),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("size", [1, 2, 16, 64])
+def test_the_inverse_of_a_unit_lower_triangle_is_a_product(size):
+    a = jnp.tril(jax.random.normal(jax.random.PRNGKey(size),
+                                   (3, size, size)) * 0.3, -1)
+    got = np.asarray(jax.jit(unit_lower_inverse)(a), np.float64)
+    eye = np.eye(size)
+    np.testing.assert_allclose(got @ (eye + np.asarray(a, np.float64)),
+                               np.broadcast_to(eye, a.shape), atol=1e-4)
+    assert not np.triu(got, 1).any()
+    # log2(size) squarings and as many products, no triangular solve.
+    names = [e.primitive.name for e in
+             all_eqns(jax.make_jaxpr(unit_lower_inverse)(a).jaxpr)]
+    assert names.count("dot_general") == 2 * max(size.bit_length() - 2, 0)
+    assert "triangular_solve" not in names
+
+
+def _series(a):
+    """The same product, left to JAX's differentiation."""
+    power = -a
+    inverse = jnp.eye(a.shape[-1]) + power
+    for _ in range(max(a.shape[-1].bit_length() - 2, 0)):
+        power = power @ power
+        inverse = inverse + inverse @ power
+    return inverse
+
+
+def test_the_inverse_s_gradient_is_two_products_and_the_series_own():
+    """`-X^T dX X^T`: what differentiating the series gives, in two
+    products where that takes two a product of the series (twenty at 64
+    positions a chunk; the compiler drops two)."""
+    a = jnp.tril(jax.random.normal(jax.random.PRNGKey(3), (3, 64, 64)) * 0.2,
+                 -1)
+    target = jax.random.normal(jax.random.PRNGKey(4), (3, 64, 64))
+    loss = lambda inverse: lambda a: jnp.sum(inverse(jnp.tril(a, -1)) * target)
+    got = jax.grad(loss(unit_lower_inverse))(a)
+    want = jax.grad(loss(_series))(a)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
+    assert not np.triu(np.asarray(got)).any()
+    dots = lambda f: [e.primitive.name for e in all_eqns(
+        jax.make_jaxpr(jax.grad(loss(f)))(a).jaxpr)].count("dot_general")
+    assert (dots(unit_lower_inverse), dots(_series)) == (10 + 2, 10 + 20)
+
+
+def test_padding_rows_move_no_state():
+    """A ragged tail is padded with g = 0, beta = 0 rows: the positions
+    before it read what they read without it."""
+    length, chunk, heads, groups = 37, 16, 4, 2
+    args = operands(length, heads, groups)
+    whole = rule(*args, chunk=chunk)
+    cut = rule(*(a[:, :32] for a in args), chunk=chunk)
+    np.testing.assert_allclose(np.asarray(whole[:, :32]), np.asarray(cut),
+                               atol=1e-6)
+
+
+def test_bfloat16_operands_keep_decays_inverse_and_state_in_float32():
+    length, chunk, heads, groups = 64, 16, 4, 2
+    q, k, v, g, beta = operands(length, heads, groups)
+    bf = lambda t: t.astype(jnp.bfloat16)
+    f32 = lambda t: bf(t).astype(jnp.float32)
+    got = rule(bf(q), bf(k), bf(v), g, beta, chunk=chunk)
+    assert got.dtype == jnp.bfloat16
+    want = recurrence(f32(q), f32(k), f32(v), g, beta)
+    err = np.abs(np.asarray(got, np.float32) - np.asarray(want))
+    assert err.max() < 0.05 * np.abs(np.asarray(want)).max()
+    # The running sums never went through bfloat16: every exp of the jaxpr
+    # reads a float32 operand; the inverse's products are float32 too, and
+    # the scan carries a float32 state.
+    jaxpr = jax.make_jaxpr(lambda *a: gated_delta_rule(*a, chunk=chunk))(
+        bf(q), bf(k), bf(v), g, beta)
+    exps = [e for e in all_eqns(jaxpr.jaxpr) if e.primitive.name == "exp"]
+    assert exps and all(e.invars[0].aval.dtype == jnp.float32 for e in exps)
+    square = [e for e in all_eqns(jaxpr.jaxpr)
+              if e.primitive.name == "dot_general"
+              and e.params["precision"] is not None]
+    assert len(square) == 2 * (chunk.bit_length() - 2)
+    assert all(v.aval.dtype == jnp.float32 for e in square for v in e.invars)
+    (scan,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert scan.outvars[0].aval.dtype == jnp.float32
+    assert scan.outvars[0].aval.shape == (B, groups, heads // groups, DK, DV)
+
+
+def test_key_heads_are_read_through_an_index_never_copied():
+    """No value of the jaxpr is q or k repeated to the value heads."""
+    length, chunk, heads, groups = 32, 8, 6, 2
+    args = operands(length, heads, groups)
+    jaxpr = jax.make_jaxpr(lambda *a: gated_delta_rule(*a, chunk=chunk))(*args)
+    shapes = {tuple(v.aval.shape) for e in jaxpr.jaxpr.eqns
+              for v in e.outvars}
+    assert shapes and not any(s[-2:] == (heads, DK) for s in shapes)
+    # [.., G, R, Q, dk] is W = T (K e^cum), a product a value head, and
+    # its cast: nothing else has K's columns a value head.
+    w_like = [v for e in jaxpr.jaxpr.eqns for v in e.outvars
+              if tuple(v.aval.shape[-4:]) == (groups, heads // groups, chunk,
+                                              DK)]
+    assert len(w_like) <= 2
+
+
+def test_the_rule_counts_what_it_builds():
+    from oobleck_tpu.utils import metrics
+
+    reg = metrics.registry()
+    built = reg.counter("oobleck_gdn_scans_total")
+    before = built.value()
+    args = operands(37, 4, 2)
+    fn = jax.jit(lambda *a: gated_delta_rule(*a, chunk=16, layer="3"))
+    fn(*args)
+    fn(*args)                       # a cache hit traces nothing
+    assert built.value() - before == 1
+    assert reg.gauge("oobleck_gdn_chunks").value(layer="3") == 3

@@ -14,7 +14,7 @@ interpreted: half the forward-kernel equations of a bare `jax.checkpoint`
 and bit-identical gradients. (iii) A layer whose attention took the XLA
 path has no named value, and lowers to the text a bare checkpoint gives.
 
-A file of its own: under `--dist loadfile` its four compiles (about 130 s
+A file of its own: under `--dist loadfile` its five compiles (about 200 s
 together) do not lengthen `test_tpu_compile.py`'s worker.
 """
 
@@ -44,6 +44,11 @@ CELLS = {
     "moonlight-16b-a3b": ((1, 4096), flash.LATENT, 5, 1.8e9),
     # 1,592,583,680 when the cell went in (PR 37): under ISSUE 37's 2.2 GB.
     "nemotron-3-nano-30b-a3b": ((1, 4096), flash.PLAIN, 1, 2.2e9),
+    # 4,402,778,624 when the cell went in (PR 43): the dropless buffers of
+    # 4096 x 10 + 16 x 128 rows (168 MB each at 2048 bfloat16 columns)
+    # beside the delta rule's float32 [64, 64] blocks. One attention layer
+    # of four, at heads of 256.
+    "qwen3-next-80b-a3b": ((1, 4096), flash.PLAIN, 1, 4.6e9),
 }
 # Four routed layers of SwiGLU experts: 3 forward + 3 recomputed + 3 dX
 # products, and 3 dW, a layer. Three of experts without a gate: 2 + 2 + 2
@@ -109,6 +114,7 @@ EXPERT_SETS = {
     "lfm2-24b-a2b": (("8,2048,1536", "8,1536,2048"), 0),
     "moonlight-16b-a3b": (("8,2048,1408", "8,1408,2048"), 0),
     "nemotron-3-nano-30b-a3b": (("8,2688,1856", "8,1856,2688"), 3 * 4),
+    "qwen3-next-80b-a3b": (("16,2048,512", "16,512,2048"), 0),
 }
 
 

@@ -358,6 +358,10 @@ ROW_TILES = {
     "moonlight_fallback": ((2048, 6, 64), 384),
     "expects_256": ((8192, 8, 256), 384),
     "under_a_lane": ((1024, 4, 64), 96),
+    # `qwen3-next-80b-a3b.steady`'s: 80 rows expected an expert, the first
+    # call under a lane at a benchmark's size: one bfloat16-sublane multiple
+    # that holds 120 rows (half as many again), not a lane for its own sake.
+    "qwen3_next_cell": ((4096, 10, 512), 128),
     "tests_sizes": ((48, 2, 8), 32),
 }
 
@@ -561,3 +565,170 @@ def test_odd_widths_are_taken_whole_by_the_kernels():
     assert moe._col_tile(2688, moe.MAX_TGMM_ROWS) == 896
     # The cell's call: 192 rows expected an expert, one tile of 384.
     assert moe.choose_row_tile(4096 * 6, 128) == 384
+
+
+# --------------------------------------------------------------------- #
+# softmax scores (`score="softmax"`): the top k of a softmax over ALL     #
+# --------------------------------------------------------------------- #
+
+NE_S, K_S = 32, 10      # as the Qwen3-Next family: ten of many, no bias
+
+
+@pytest.fixture(scope="module")
+def softmaxed():
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    return {
+        "x": jax.random.normal(ks[0], (T, D)),
+        "router": jax.random.normal(ks[1], (D, NE_S)) * 0.5,
+        "w1": jax.random.normal(ks[2], (NE_S, D, F)) * 0.2,
+        "w3": jax.random.normal(ks[3], (NE_S, D, F)) * 0.2,
+        "w2": jax.random.normal(ks[4], (NE_S, F, D)) * 0.2,
+    }
+
+
+def _softmax_share(layer, offset, held, **kw):
+    sl = slice(offset, offset + held)
+    return moe.routed_experts(
+        layer["x"], layer["router"], None, layer["w1"][sl], layer["w3"][sl],
+        layer["w2"][sl], num_experts=NE_S, top_k=K_S, expert_offset=offset,
+        score="softmax", **kw)
+
+
+def _softmax_loop(layer, offset, held):
+    """The layer as its definition reads: p = softmax(x Wr) over ALL the
+    experts, its top ten, weights p / sum of the ten; one token and one
+    pick at a time, in float64 on the host."""
+    f64 = lambda a: np.asarray(a, np.float64)
+    x, logits = f64(layer["x"]), f64(layer["x"]) @ f64(layer["router"])
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    y = np.zeros_like(x)
+    picks = []
+    for t in range(x.shape[0]):
+        chosen = np.argsort(-probs[t], kind="stable")[:K_S]
+        picks.append(chosen)
+        g = probs[t, chosen] / probs[t, chosen].sum()
+        for weight, e in zip(g, chosen):
+            if offset <= e < offset + held:
+                gate = x[t] @ f64(layer["w1"][e])
+                up = x[t] @ f64(layer["w3"][e])
+                y[t] += weight * ((gate / (1.0 + np.exp(-gate)) * up)
+                                  @ f64(layer["w2"][e]))
+    return y, np.stack(picks)
+
+
+@pytest.mark.parametrize("offset,held", [(0, NE_S), (8, 8), (30, 2), (5, 1)],
+                         ids=["all", "8_to_15", "30_to_31", "one"])
+def test_softmax_top_ten_matches_a_loop_over_tokens(softmaxed, offset, held):
+    y, chosen = jax.jit(lambda: _softmax_share(
+        softmaxed, offset, held, return_routing=True))()
+    want, picks = _softmax_loop(softmaxed, offset, held)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    np.testing.assert_array_equal(np.sort(np.asarray(chosen), -1),
+                                  np.sort(picks, -1))
+
+
+@pytest.mark.parametrize("parts", [32, 8, 2],
+                         ids=["thirty_two_chips", "eight_chips", "two_chips"])
+def test_the_softmax_shares_add_up_to_the_uncut_layer(softmaxed, parts):
+    """Every share routes over all 32 and gives its own experts' part; the
+    parts add up to the uncut layer (the shared expert is the model's to
+    add, once: tests/models/test_qwen3_next.py)."""
+    held = NE_S // parts
+    total = sum(_softmax_share(softmaxed, i * held, held)
+                for i in range(parts))
+    np.testing.assert_allclose(np.asarray(total),
+                               np.asarray(_softmax_share(softmaxed, 0, NE_S)),
+                               atol=3e-6)
+
+
+def _softmax_dense(x, router, w1, w3, w2, offset):
+    """The same function, dense over the held experts, for autodiff."""
+    probs = jax.nn.softmax(x @ router, -1)
+    _, chosen = jax.lax.top_k(probs, K_S)
+    g = jax.nn.one_hot(chosen, NE_S).sum(-2) * probs
+    g = g / g.sum(-1, keepdims=True)
+    g = g[:, offset:offset + w1.shape[0]]
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", x, w1)) \
+        * jnp.einsum("td,edf->tef", x, w3)
+    return jnp.einsum("tef,efd,te->td", h, w2, g)
+
+
+SOFTMAX_OPERANDS = ("x", "router", "w1", "w3", "w2")
+
+
+@pytest.mark.parametrize("wrt", range(5), ids=SOFTMAX_OPERANDS)
+@pytest.mark.parametrize("offset,held", [(0, NE_S), (8, 8)],
+                         ids=["all", "share"])
+def test_softmax_gradients_match_the_dense_formulation(softmaxed, offset,
+                                                       held, wrt):
+    sl = slice(offset, offset + held)
+    args = (softmaxed["x"], softmaxed["router"], softmaxed["w1"][sl],
+            softmaxed["w3"][sl], softmaxed["w2"][sl])
+    target = jax.random.normal(jax.random.PRNGKey(1), (T, D))
+
+    def routed(x, router, w1, w3, w2):
+        y = moe.routed_experts(x, router, None, w1, w3, w2, num_experts=NE_S,
+                               top_k=K_S, expert_offset=offset,
+                               score="softmax")
+        return jnp.sum(y * target)
+
+    def dense(x, router, w1, w3, w2):
+        return jnp.sum(_softmax_dense(x, router, w1, w3, w2, offset) * target)
+
+    got = jax.jit(jax.grad(routed, argnums=wrt))(*args)
+    want = jax.grad(dense, argnums=wrt)(*args)
+    scale = max(float(jnp.max(jnp.abs(want))), 1e-3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=3e-5 * scale + 1e-6)
+
+
+def test_softmax_weights_are_normalised_over_the_chosen_without_an_epsilon(
+        softmaxed):
+    x, router = softmaxed["x"], softmaxed["router"]
+    experts, weights = moe.route(x, router, None, top_k=K_S, score="softmax")
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0, atol=1e-6)
+    probs = np.asarray(jax.nn.softmax(x @ router, -1))
+    picked = np.take_along_axis(probs, np.asarray(experts), -1)
+    np.testing.assert_allclose(np.asarray(weights),
+                               picked / picked.sum(-1, keepdims=True),
+                               atol=1e-6)
+    # The chosen are the ten largest of the softmax (of the logits alike).
+    assert (picked.min(-1) >= np.sort(probs, -1)[:, -K_S] - 1e-7).all()
+    # Forced choices replace the selection; the weights still come from
+    # this call's own softmax, over the forced ten.
+    forced = (np.asarray(experts) + 1) % NE_S
+    again, w_forced = moe.route(x, router, None, top_k=K_S, score="softmax",
+                                forced_experts=jnp.asarray(forced))
+    np.testing.assert_array_equal(np.asarray(again), forced)
+    f_picked = np.take_along_axis(probs, forced, -1)
+    np.testing.assert_allclose(np.asarray(w_forced),
+                               f_picked / f_picked.sum(-1, keepdims=True),
+                               atol=1e-6)
+    with pytest.raises(AssertionError):
+        moe.route(x, router, None, top_k=K_S, score="tanh")
+
+
+def test_softmax_calls_are_counted_where_they_are_built(softmaxed, layer):
+    from oobleck_tpu.utils import metrics
+
+    built = metrics.registry().counter(
+        "oobleck_moe_softmax_routed_calls_total")
+    before = built.value()
+    fn = jax.jit(lambda x: _softmax_share(dict(softmaxed, x=x), 0, NE_S))
+    fn(softmaxed["x"])
+    fn(softmaxed["x"])              # a cache hit traces nothing
+    assert built.value() - before == 1
+    jax.jit(lambda x: _share(dict(layer, x=x), 0, NE))(layer["x"])
+    assert built.value() - before == 1          # a sigmoid call counts nothing
+
+
+def test_the_qwen3_next_cell_s_call_is_sized_for_every_pick_held_here():
+    """80 rows expected an expert in tiles of 128, one a held expert; the
+    buffer is the dropless worst case (every pick of every token held
+    here) plus a tile an expert, and the kernels visit the tiles in use."""
+    assert moe.choose_row_tile(4096 * 10, 512) == 128
+    rows, tile = moe.buffer_rows(4096, 10, 16, 512)
+    assert (rows, tile) == (4096 * 10 + 16 * 128, 128)
+    assert moe._col_tile(512, moe.MAX_COL_TILE) == 512
+    assert moe._col_tile(512, moe.MAX_TGMM_ROWS) == 512

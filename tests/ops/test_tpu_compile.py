@@ -57,6 +57,9 @@ FLASH_WIDTHS = {
     "seq-2048": (1, 32, 2048, 80),
     "short-prompt": (1, 12, 128, 64),
     "nemotron-3-nano-30b-a3b-cell": (1, 32, 4096, 128),
+    # q, k AND v 256 wide (the latent call has 256-wide scores over
+    # 128-wide values): 16 heads, the 2 key-value heads already repeated.
+    "qwen3-next-80b-a3b-cell": (1, 16, 4096, 256),
 }
 # (Hq, Hkv, D) of the serve pools: gpt2 MHA and llama-style GQA.
 PAGED_WIDTHS = {"gpt2": (12, 12, 64), "llama-gqa": (32, 8, 128)}
@@ -213,6 +216,8 @@ MOE_WIDTHS = {
     "lfm2-24b-a2b-cell": (8192, 2048, 1536, 64, 8, 4),
     "moonlight-16b-a3b-cell": (4096, 2048, 1408, 64, 8, 6),
     "moonlight-16b-a3b-seq-2048": (2048, 2048, 1408, 64, 8, 6),
+    # 80 rows expected an expert: 128-row tiles, a 43,008-row buffer.
+    "qwen3-next-80b-a3b-cell": (4096, 2048, 512, 512, 16, 10),
     "all-held": (1024, 2048, 1536, 64, 64, 4),
     "lfm2-tiles": (512, 256, 384, 16, 4, 2),
 }
