@@ -56,6 +56,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from oobleck_tpu.ops import gdn
+
 NEG_INF = -1e9
 
 LANE = 128
@@ -98,13 +100,15 @@ RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def checkpoint_layer(fn, **kwargs):
-    """`jax.checkpoint` for a layer whose body can reach `_flash`: the
-    backward pass recomputes the layer from its input, all but what the
-    flash forward kernel wrote. A layer whose attention took the XLA path
-    has no value by these names, and its checkpoint keeps nothing."""
+    """`jax.checkpoint` for a layer, whatever its body can reach: the
+    backward pass recomputes the layer from its input, all but what the op
+    modules' forward rules named: what the flash forward kernel wrote, the
+    delta rule's inverse (`ops/gdn.RESIDUAL_NAMES`). A layer whose body
+    emits no value by a name (attention on the XLA path, no delta rule)
+    keeps nothing by it."""
     return jax.checkpoint(
         fn, policy=jax.checkpoint_policies.save_only_these_names(
-            *RESIDUAL_NAMES), **kwargs)
+            *RESIDUAL_NAMES, *gdn.RESIDUAL_NAMES), **kwargs)
 
 
 class Tiles(NamedTuple):

@@ -26,9 +26,13 @@ matrix products the compiler knows, and no triangular solve walked row by
 row. Plain `jax.numpy`: `einsum`s, gradients by JAX's differentiation of
 them (under the layer's checkpoint like every other layer), but for the
 inverse, whose gradient is written down (`-X^T dX X^T`: two products a head
-and chunk where the series' own would be eighteen). No Pallas kernel: `D`,
-`A` and `T` are written out, [Q, Q] float32 blocks a head and chunk, and
-the traffic that costs is what a kernel for this rule would save.
+and chunk where the series' own would be eighteen) and whose forward rule
+NAMES it (`RESIDUAL_NAMES`), so that a layer's checkpoint
+(`ops/flash.checkpoint_layer`) keeps it and the recomputed forward holds no
+series: the one value of the rule that only `2 (log2 Q - 1)` more float32
+products could give back. No Pallas kernel: `D`, `A` and `T` are written
+out, [Q, Q] float32 blocks a head and chunk, and the traffic that costs is
+what a kernel for this rule would save.
 
 Held to what `ops/ssd.py` is held to: `g`, `cum`, every `exp`, the inverse
 and the state in float32 (the inverse's products at `Precision.HIGHEST`);
@@ -46,6 +50,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+# The forward rule's name for the inverse it returns: the residual that
+# only the series' products could give back (`decay`, `kk` and `a` come
+# back by cheap XLA; `W` and `U` are two products of the kept value).
+RESIDUAL_NAMES = ("gdn_inverse",)
 
 
 def _count(chunks: int, layer: str | None) -> None:
@@ -65,11 +75,23 @@ def _count(chunks: int, layer: str | None) -> None:
         "into, by layer").set(chunks, layer=str(layer))
 
 
+def _count_named_residuals() -> None:
+    """`oobleck_gdn_residuals_named_total`: once a forward rule of the
+    inverse traced (not once a step), with the inverse named."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_gdn_residuals_named_total",
+        "Forward rules of the delta rule's inverse traced with the inverse "
+        "named for the layer's checkpoint").inc()
+
+
 def _dot(x: jax.Array, y: jax.Array) -> jax.Array:
     return jnp.matmul(x, y, precision=lax.Precision.HIGHEST)
 
 
 @jax.custom_vjp
+@jax.named_scope("gdn_inverse")
 def unit_lower_inverse(a: jax.Array) -> jax.Array:
     """(I + a)^-1 for `a` [..., Q, Q] strictly lower triangular, float32:
     with n = -a, (I + n)(I + n^2)(I + n^4)... until the power is zero.
@@ -88,10 +110,12 @@ def unit_lower_inverse(a: jax.Array) -> jax.Array:
 
 
 def _inverse_fwd(a):
-    inverse = unit_lower_inverse(a)
+    inverse = checkpoint_name(unit_lower_inverse(a), RESIDUAL_NAMES[0])
+    _count_named_residuals()
     return inverse, inverse
 
 
+@jax.named_scope("gdn_inverse")
 def _inverse_bwd(inverse, d_inverse):
     transposed = jnp.swapaxes(inverse, -1, -2)
     return (-_dot(_dot(transposed, d_inverse), transposed),)

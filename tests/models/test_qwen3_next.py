@@ -392,6 +392,7 @@ def test_engine_end_to_end_on_the_generic_stage_path(tmp_path):
         OobleckArguments,
     )
     from oobleck_tpu.execution.engine import OobleckEngine
+    from oobleck_tpu.utils import metrics
 
     old = os.environ.get("OOBLECK_TPU_CACHE")
     os.environ["OOBLECK_TPU_CACHE"] = str(tmp_path / "profiles")
@@ -406,6 +407,8 @@ def test_engine_end_to_end_on_the_generic_stage_path(tmp_path):
                 model_args={"num_experts_held": 4, "expert_offset": 4,
                             "vocab_rows_held": 100}),
         )
+        named = metrics.registry().counter("oobleck_gdn_residuals_named_total")
+        unnamed = named.value()
         engine = OobleckEngine(args, devices=jax.devices()[:1])
         assert engine.dataset.vocab_size == 100       # the rows held
         assert engine.seq_len == 40                   # no multiple of 16
@@ -415,6 +418,9 @@ def test_engine_end_to_end_on_the_generic_stage_path(tmp_path):
         before = jax.tree.map(np.asarray, dict(pipe.params))
         losses = [engine._train_step() for _ in range(2)]
         assert all(np.isfinite(l) for l in losses)
+        # The stage programs differentiated the rule: its inverse went by
+        # a name the layers' checkpoint keeps (ops/gdn.py).
+        assert named.value() > unnamed
         moved = lambda a, b: np.abs(np.asarray(a) - b).max() > 0
         for name in ("w_qkvz", "w_ba", "conv_taps", "dt_bias", "A_log",
                      "norm", "w_out"):

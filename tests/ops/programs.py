@@ -1,7 +1,9 @@
 """What the tests of tests/ops share when they look INTO a program: the
-Pallas calls of a jaxpr, and a benchmark cell's one-stage pipeline built
-for described (not attached) devices."""
+Pallas calls of a jaxpr, a checkpoint that keeps values by the names it is
+given, and a benchmark cell's one-stage pipeline built for described (not
+attached) devices."""
 
+import functools
 import json
 from pathlib import Path
 
@@ -21,6 +23,14 @@ def all_eqns(jaxpr):
                     sub, "jaxpr", None)
                 if hasattr(inner, "eqns"):
                     yield from all_eqns(inner)
+
+
+def checkpoint_keeping(*names):
+    """`jax.checkpoint` with the policy that keeps values by `names` and
+    nothing else: what `ops/flash.checkpoint_layer` is for its own list."""
+    return functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
 def pallas_calls(jaxpr, found=None):

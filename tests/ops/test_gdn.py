@@ -8,7 +8,9 @@ only, one key head for all value heads, a key head a value head; decays of
 e^-20 a chunk and far beyond (no inf, no nan, forward or backward: `D` comes
 from a difference of running sums); beta at 0 (nothing is written) and at 1
 (the plain delta rule); the inverse of a unit lower-triangular matrix as a
-product; bfloat16 operands; and what the rule counts where it is built.
+product; bfloat16 operands; what the rule counts where it is built; and what
+a layer's checkpoint keeps of it: the inverse by its name, so that the
+recomputed forward holds no series.
 """
 
 import functools
@@ -20,8 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from oobleck_tpu.ops import flash, gdn
 from oobleck_tpu.ops.gdn import gated_delta_rule, unit_lower_inverse
-from tests.ops.programs import all_eqns
+from tests.ops.programs import all_eqns, checkpoint_keeping
 
 # (length, chunk, value heads, key heads)
 CASES = {
@@ -261,3 +264,57 @@ def test_the_rule_counts_what_it_builds():
     fn(*args)                       # a cache hit traces nothing
     assert built.value() - before == 1
     assert reg.gauge("oobleck_gdn_chunks").value(layer="3") == 3
+
+
+def _layer_gradients(wrap):
+    """All five gradients of one rule at 64 positions a chunk (ten
+    products a series) under a layer's checkpoint `wrap`."""
+    layer = wrap(functools.partial(gated_delta_rule, chunk=64))
+    return jax.grad(lambda *a: jnp.sum(layer(*a) ** 2), argnums=range(5))
+
+
+def _products(fn, *args):
+    return [e.primitive.name for e in all_eqns(
+        jax.make_jaxpr(fn)(*args).jaxpr)].count("dot_general")
+
+
+@pytest.mark.parametrize("wrap,fewer", [
+    (flash.checkpoint_layer, 10),
+    (checkpoint_keeping(*gdn.RESIDUAL_NAMES), 10),
+    # The name is emitted and the policy does not keep it: a bare
+    # checkpoint's count, the series twice.
+    (checkpoint_keeping(*flash.RESIDUAL_NAMES), 0),
+], ids=["the_layers_checkpoint", "the_rules_name_alone", "flash_s_names_alone"])
+def test_a_checkpoint_that_keeps_the_inverse_recomputes_no_series(wrap, fewer):
+    args = operands(128, 4, 2)
+    bare = _products(_layer_gradients(jax.checkpoint), *args)
+    assert bare - _products(_layer_gradients(wrap), *args) == fewer
+
+
+@functools.cache
+def _kept_and_bare_gradients():
+    args = operands(128, 4, 2, seed=2)
+    return (jax.jit(_layer_gradients(flash.checkpoint_layer))(*args),
+            jax.jit(_layer_gradients(jax.checkpoint))(*args))
+
+
+@pytest.mark.parametrize("wrt", range(5), ids=ARGS)
+def test_the_kept_inverse_is_the_one_a_second_series_would_give(wrt):
+    got, want = (g[wrt] for g in _kept_and_bare_gradients())
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_named_inverse_is_counted_once_a_forward_rule_traced():
+    from oobleck_tpu.utils import metrics
+
+    named = metrics.registry().counter("oobleck_gdn_residuals_named_total")
+    before = named.value()
+    args = operands(37, 4, 2)
+    fn = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=16)), argnums=(0, 1)))
+    fn(*args)
+    fn(*args)                       # a cache hit traces nothing
+    assert named.value() - before == 1
+    # A rule that is not differentiated runs no forward rule.
+    jax.jit(lambda *a: gated_delta_rule(*a, chunk=16, layer="5"))(*args)
+    assert named.value() - before == 1

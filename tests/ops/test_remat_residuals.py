@@ -1,18 +1,22 @@
-"""What a layer's checkpoint keeps of flash attention (`ops/flash.py`).
+"""What a layer's checkpoint keeps: of flash attention (`ops/flash.py`)
+and of the gated delta rule (`ops/gdn.py`).
 
 `_flash_fwd` names the two things the forward kernel wrote, O and the row
-logsumexp, and `checkpoint_layer` is `jax.checkpoint` with the policy that
-keeps values by those names. So the forward a backward pass recomputes
-holds no kernel call: its only consumers of the kernel's outputs are the
-two kept values, and a `pallas_call` has no side effect.
+logsumexp, `ops/gdn._inverse_fwd` the inverse its series gave, and
+`checkpoint_layer` is `jax.checkpoint` with the policy that keeps values
+by those names. So the forward a backward pass recomputes holds no kernel
+call and no series: their only consumers are the kept values, and neither
+has a side effect.
 
 (i) The benchmark cells' one-stage `jit_bwd`, compiled for a
 described (not attached) TPU v5e: each forward kernel once a layer that
-has attention, the expert kernels as they were, temporaries under a bound.
-Nothing executes there; no number comes out. (ii) On the CPU, kernels
-interpreted: half the forward-kernel equations of a bare `jax.checkpoint`
-and bit-identical gradients. (iii) A layer whose attention took the XLA
-path has no named value, and lowers to the text a bare checkpoint gives.
+has attention, the inverse's series once a layer that has the rule, the
+expert kernels as they were, temporaries under a bound. Nothing executes
+there; no number comes out. (ii) On the CPU, kernels interpreted: half the
+forward-kernel equations of a bare `jax.checkpoint` and bit-identical
+gradients (the rule's: `tests/ops/test_gdn.py`). (iii) A layer emits no
+value by a name: nothing is kept by it, and the layer lowers to the text
+it lowered to without that name in the policy.
 
 A file of its own: under `--dist loadfile` its five compiles (about 200 s
 together) do not lengthen `test_tpu_compile.py`'s worker.
@@ -31,7 +35,8 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 
 from oobleck_tpu.ops import attention, flash
-from tests.ops.programs import cell_stage, pallas_calls
+from tests.ops.programs import (
+    cell_stage, checkpoint_keeping, pallas_calls)
 
 # cell -> (microbatch, sequence), its attention's kernels and how many
 # layers call them, the bound on `jit_bwd`'s temporaries. The compile gave
@@ -47,9 +52,16 @@ CELLS = {
     # 4,402,778,624 when the cell went in (PR 43): the dropless buffers of
     # 4096 x 10 + 16 x 128 rows (168 MB each at 2048 bfloat16 columns)
     # beside the delta rule's float32 [64, 64] blocks. One attention layer
-    # of four, at heads of 256.
+    # of four, at heads of 256. 3,732,470,272 since the layers' checkpoint
+    # keeps the rule's inverse (PR 44): 100.7 MB kept, and the recompute
+    # holds no power and no partial product of the series.
     "qwen3-next-80b-a3b": ((1, 4096), flash.PLAIN, 1, 4.6e9),
 }
+# The three Gated DeltaNet layers' inverse (`ops/gdn.unit_lower_inverse`,
+# scope `gdn_inverse`): ten [64, 64] float32 products of the series a
+# layer, and two of its own gradient rule. The series again in the
+# recompute would be thirty more.
+INVERSE_PRODUCTS = {"qwen3-next-80b-a3b": 3 * 10 + 3 * 2}
 # Four routed layers of SwiGLU experts: 3 forward + 3 recomputed + 3 dX
 # products, and 3 dW, a layer. Three of experts without a gate: 2 + 2 + 2
 # and 2.
@@ -88,8 +100,9 @@ def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
     (mb, seq), names, layers, temp_bound = CELLS[cell]
     st, params, batch = cell_stage(cell, v5e, microbatch=mb, seq=seq)
     compiled = st.bwd[0].lower(params, params, None, batch).compile()
+    text = compiled.as_text()
     calls = re.findall(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target="
-                       r'"tpu_custom_call"', compiled.as_text())
+                       r'"tpu_custom_call"', text)
     count = {k: calls.count(k) for k in set(calls)}
     assert {k: count.pop(k, 0) for k in names} == dict.fromkeys(names, layers)
     # What the policy does not name is recomputed as before: the routed
@@ -98,7 +111,11 @@ def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
         cell, ROUTED)
     assert compiled.memory_analysis().temp_size_in_bytes < temp_bound
     if cell in EXPERT_SETS:
-        _the_experts_sums_are_the_kernels(cell, compiled.as_text())
+        _the_experts_sums_are_the_kernels(cell, text)
+    inverse = re.findall(
+        r'= f32\[[\d,]*64,64\]\S* convolution\([^\n]*op_name="[^"]*/gdn/'
+        r'gdn_inverse/dot_general"', text)
+    assert len(inverse) == INVERSE_PRODUCTS.get(cell, 0)
 
 
 # cell -> the float32 shapes of a routed layer's held experts (w1 / w3,
@@ -199,14 +216,28 @@ def test_kept_residuals_halve_the_forward_calls_and_keep_the_gradients(
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def _lowered(block, wrap):
+    w, x = _operands()
+    return jax.jit(_two_blocks(block, wrap)).lower(w, x).as_text()
+
+
 def test_a_layer_on_the_xla_path_lowers_as_under_a_bare_checkpoint():
     """Names absent, nothing kept: what every CPU test leans on."""
     w, x = _operands()
-    text = lambda wrap: jax.jit(_two_blocks(_xla_block, wrap)).lower(
-        w, x).as_text()
-    assert text(flash.checkpoint_layer) == text(jax.checkpoint)
+    assert _lowered(_xla_block, flash.checkpoint_layer) == _lowered(
+        _xla_block, jax.checkpoint)
     assert not pallas_calls(jax.make_jaxpr(
         _two_blocks(_xla_block, flash.checkpoint_layer))(w, x).jaxpr)
+
+
+@pytest.mark.parametrize("block", [_plain_block, _latent_block],
+                         ids=["plain", "latent"])
+def test_a_layer_without_the_delta_rule_keeps_nothing_new(block):
+    """The policy also names the rule's inverse (`ops/gdn.py`); a layer
+    that emits no such value lowers to the text it lowered to under
+    flash's two names alone."""
+    assert _lowered(block, flash.checkpoint_layer) == _lowered(
+        block, checkpoint_keeping(*flash.RESIDUAL_NAMES))
 
 
 def test_named_residuals_are_counted_once_a_forward_rule_traced():

@@ -529,15 +529,29 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
                    for n, attrs in calls if n == "moe_gmm")
 
 
-def test_a_cell_without_routed_layers_lowers_to_the_text_it_lowered_to(v5e):
-    """`gpt3-2.7b`'s `jit_bwd` has no sum to hand down: sha256 and length of
-    its lowered text at the parent of the PR that moved the sums, the
-    kernels' serialized bodies (which carry source lines) blanked."""
+# cell -> sha256 and length of the one-stage `jit_bwd`'s lowered text with
+# the kernels' serialized bodies (which carry source lines) blanked.
+# `gpt3-2.7b`'s as at the parent of the PR that moved the sums (it has none
+# to hand down); the three routed cells' as at the parent of the PR that
+# widened the layers' checkpoint to the delta rule's inverse (PR 44: no
+# layer of theirs emits a value by that name). A PR that changes what one
+# of these programs computes takes its new text's pair from a failing run.
+LOWERED = {
+    "gpt3-2.7b": ("1c314568b7c7d581", 172933),
+    "lfm2-24b-a2b": ("cfd3c52916dfe114", 608880),
+    "moonlight-16b-a3b": ("8fa068f290c39b8a", 728618),
+    "nemotron-3-nano-30b-a3b": ("242209411d5adff9", 659173),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(LOWERED))
+def test_a_cell_s_backward_lowers_to_the_text_it_lowered_to(v5e, cell):
     import hashlib
 
-    st, params, batch = cell_stage("gpt3-2.7b", v5e, microbatch=4, seq=1024)
-    assert st.kernel_sums == [0]
+    (mb, seq), sums = ROUTED_CELLS.get(cell, ((4, 1024), 0))
+    st, params, batch = cell_stage(cell, v5e, microbatch=mb, seq=seq)
+    assert st.kernel_sums == [sums]
     text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""',
                   st.bwd[0].lower(params, params, None, batch).as_text())
-    assert (hashlib.sha256(text.encode()).hexdigest()[:16], len(text)) == (
-        "1c314568b7c7d581", 172933)
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16],
+            len(text)) == LOWERED[cell]
