@@ -108,6 +108,28 @@ register("qwen3-next-80b-a3b")(lambda o: _qwen3_next(o))
 register("qwen3-next-tiny")(lambda o: _qwen3_next(o, vocab_size=256, hidden_size=64, num_layers=4, linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=16, chunk_size=16, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=128, moe_intermediate_size=32, shared_expert_intermediate_size=32, num_experts=16, num_experts_per_tok=4, max_position_embeddings=128))
 
 
+def _smallthinker(overrides: dict[str, Any], **preset):
+    from oobleck_tpu.models.smallthinker import (
+        SmallThinkerConfig,
+        SmallThinkerModel,
+    )
+
+    return SmallThinkerModel(
+        SmallThinkerConfig().override(**preset).override(**overrides))
+
+
+# SmallThinker family: sliding-window attention with rotary three layers in
+# four beside one full-attention layer without a positional term, a router
+# that reads the block's input before the attention, top-k softmax-routed
+# ReGLU experts and no shared one; the defaults are
+# SmallThinker-21BA3B-Instruct's.
+register("smallthinker-21b-a3b")(lambda o: _smallthinker(o))
+# The published `model_name`, which a configuration file copied from the
+# model's own config.json carries under that key.
+register("smallthinker_21b_instruct")(lambda o: _smallthinker(o))
+register("smallthinker-tiny")(lambda o: _smallthinker(o, vocab_size=256, hidden_size=64, num_layers=4, num_heads=4, num_kv_heads=2, head_dim=16, sliding_window_size=24, moe_intermediate_size=32, num_experts=16, num_experts_per_tok=4, max_position_embeddings=128))
+
+
 # Bloom family: GPT architecture with ALiBi position biases (no wpe)
 register("bloom-560m")(lambda o: _gpt(o, vocab_size=250880, hidden_size=1024, num_layers=24, num_heads=16, position_embedding="alibi"))
 register("bloom-7b1")(lambda o: _gpt(o, vocab_size=250880, hidden_size=4096, num_layers=30, num_heads=32, position_embedding="alibi"))

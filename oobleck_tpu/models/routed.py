@@ -1,5 +1,6 @@
 """What the decoders with routed experts share (`models/lfm2.py`,
-`models/deepseek_v3.py`, `models/nemotron_h.py`, `models/qwen3_next.py`): a
+`models/deepseek_v3.py`, `models/nemotron_h.py`, `models/qwen3_next.py`,
+`models/smallthinker.py`): a
 layer list whose blocks DIFFER, one chip's share of an expert-parallel
 deployment as a model of its own, and the probe that reads where a sequence
 was routed.
@@ -8,7 +9,10 @@ A block is one or two residual branches, each behind an RMSNorm `N` of its
 own: `x + Op(N(x))` (`OP`) and `x + FF(N(x))` (`FF`). A family says which
 a block has (`branches`: both, in that order, unless it says otherwise),
 what `Op` is (`operator_out`), which blocks are routed (`is_routed`), what
-its router scores with (`router_score`) and what its norm is (`norm`: the
+its router scores with (`router_score`), what its router READS
+(`router_reads`: `FF`'s normed input as the experts do, or `OP`'s, a router
+placed before the block's operator), its gated experts' activation
+(`expert_activation`) and what its norm is (`norm`: the
 plain RMSNorm unless it says otherwise); the feed-forwards (SwiGLU, or
 `W2 relu(W1 h)^2` for an entry without `w3`), the routed call
 (`ops/moe.routed_experts`), the shared expert added to the routed sum
@@ -118,6 +122,11 @@ class RoutedShareModel:
     fused_supported = False
     # What the family's router scores with (`ops/moe.route`).
     router_score = "sigmoid"
+    # The branch whose normed input the router scores: `FF`'s, the rows
+    # the experts read, or `OP`'s, the operator's input (`apply_block`).
+    router_reads = FF
+    # The gated experts' activation (`ops/moe.routed_experts`).
+    expert_activation = "swiglu"
 
     def __init__(self, config):
         self.config = config
@@ -243,11 +252,13 @@ class RoutedShareModel:
         return g @ p["w2"].astype(dt)
 
     def routed_ff(self, p, h, *, forced_experts=None,
-                  return_routing: bool = False, grad_sums=None):
+                  return_routing: bool = False, grad_sums=None,
+                  router_in=None):
         """The part of the routed layer that the experts held here give.
         With `return_routing`, (that, the chosen experts [B, S, k]).
         `grad_sums`: `p`'s tree with the experts' running gradient sums
-        (`sums_in_kernel`)."""
+        (`sums_in_kernel`). `router_in` [B, S, E]: what the router scores
+        where that is not `h`."""
         from oobleck_tpu.ops.moe import routed_experts
 
         c = self.config
@@ -263,7 +274,9 @@ class RoutedShareModel:
                             else forced_experts.reshape(b * s, -1)),
             return_routing=return_routing,
             dw_sums=tuple(sums.get(w) for w in ("w1", "w3", "w2")),
-            score=self.router_score)
+            score=self.router_score, activation=self.expert_activation,
+            router_x=(None if router_in is None
+                      else router_in.reshape(b * s, e)))
         if return_routing:
             y, experts = out
             return y.reshape(b, s, e), experts.reshape(b, s, -1)
@@ -271,7 +284,8 @@ class RoutedShareModel:
 
     @jax.named_scope("mlp")
     def feed_forward(self, block: int, p, h, *, forced_experts=None,
-                     return_routing: bool = False, grad_sums=None):
+                     return_routing: bool = False, grad_sums=None,
+                     router_in=None):
         """The dense feed-forward or the routed experts, by the block: of
         a routed block the part its held experts give, plus, where the
         entry has them, the `shared` experts (on every token, weight 1:
@@ -289,7 +303,7 @@ class RoutedShareModel:
                 preferred_element_type=jnp.float32))[..., None].astype(h.dtype)
         out = self.routed_ff(p, h, forced_experts=forced_experts,
                              return_routing=return_routing,
-                             grad_sums=grad_sums)
+                             grad_sums=grad_sums, router_in=router_in)
         if shared is None:
             return out
         if return_routing:
@@ -298,16 +312,18 @@ class RoutedShareModel:
 
     def apply_block(self, block: int, p, x, *, forced_experts=None,
                     return_routing: bool = False, grad_sums=None):
-        experts = None
+        experts = router_in = None
         for branch in self.branches(block):
             if branch == OP:
                 h = self.norm(x, p["ln_op"]["scale"])
+                if self.router_reads == OP:
+                    router_in = h
                 x = x + self.operator_out(block, p, h)
                 continue
             h = self.norm(x, p["ln_ff"]["scale"])
             out = self.feed_forward(
                 block, p["ff"], h, forced_experts=forced_experts,
-                return_routing=return_routing,
+                return_routing=return_routing, router_in=router_in,
                 grad_sums=None if grad_sums is None else grad_sums["ff"])
             if return_routing and self.is_routed(block):
                 out, experts = out

@@ -52,12 +52,16 @@ def _pallas_ok() -> bool:
 
 def _xla_causal_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float | None = None,
-    bias: jax.Array | None = None, causal: bool = True
+    bias: jax.Array | None = None, causal: bool = True,
+    window: int | None = None
 ) -> jax.Array:
     """Masked-softmax attention. [B, H, S, D] -> [B, H, S, D].
 
     `bias` ([H, Sq, Sk] or broadcastable) supports ALiBi (Bloom family);
-    `causal=False` gives the bidirectional encoder form (BERT/ViT)."""
+    `causal=False` gives the bidirectional encoder form (BERT/ViT);
+    `window` (causal only): query i sees key j iff 0 <= i - j < window."""
+    if window is not None and not causal:
+        raise ValueError("a sliding window is a causal call's")
     *_, seq_q, head_dim = q.shape
     seq_k = k.shape[-2]
     if scale is None:
@@ -69,7 +73,10 @@ def _xla_causal_attention(
         # Supports seq_q != seq_k (ring attention partial blocks).
         q_pos = jnp.arange(seq_q)[:, None] + (seq_k - seq_q)
         k_pos = jnp.arange(seq_k)[None, :]
-        logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen = seen & (q_pos - k_pos < window)
+        logits = jnp.where(seen, logits, NEG_INF)
     # Softmax in f32 for stability regardless of compute dtype.
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
@@ -222,6 +229,7 @@ def causal_attention(
     alibi_slopes: jax.Array | None = None,
     causal: bool = True,
     constant_bias: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """Dispatching attention entry point.
 
@@ -234,9 +242,18 @@ def causal_attention(
     flash kernel generates the bias block in-kernel from the slopes, so no
     O(H S^2) buffer exists in HBM at any S; non-flash fallbacks
     materialize it from the slopes only where unavoidable.
+
+    `window`: a sliding window over a causal call (query i sees key j iff
+    0 <= i - j < window), on the flash kernels and the XLA path. Ring and
+    Ulysses have no windowed form and a non-causal call has no window:
+    each raises, none ignores it.
     """
     if bias is not None and alibi_slopes is not None:
         raise ValueError("pass bias OR alibi_slopes, not both")
+    if window is not None and (not causal or impl in ("ring", "ulysses")):
+        raise ValueError(
+            f"a sliding window needs a causal call on the flash or XLA "
+            f"path (causal={causal}, impl={impl!r})")
     fn = select_attention_impl(impl)
     from oobleck_tpu.ops.ring_attention import ring_attention
 
@@ -264,9 +281,9 @@ def causal_attention(
         if alibi_slopes is not None:
             bias = slope_bias()
         return _xla_causal_attention(q, k, v, scale=scale, bias=bias,
-                                     causal=causal)
+                                     causal=causal, window=window)
     return fn(q, k, v, scale=scale, bias=bias, alibi_slopes=alibi_slopes,
-              causal=causal)
+              causal=causal, window=window)
 
 
 def latent_qk(q_nope, q_rope, k_nope, k_rope):

@@ -29,6 +29,15 @@ where the sequence allows (a step then carries about a microsecond of MXU
 work; the fixed 128 x 128 of before carried 0.04 us under 0.4 us of step
 overhead), 128 x 128 for a 128-token prompt. There is no option for them.
 
+A sliding window (`window`: query i sees key j iff 0 <= i - j < window,
+causal calls only) is the same kernels over a shorter table: `_live_pairs`
+also drops the pairs that lie wholly behind the window, so the band's
+pairs alone cost a step, and `_scores` masks the window's edge in the
+select it makes on every live pair anyway. At 16384 positions and
+512 x 512 blocks the causal table has 528 steps a head and a window of
+4096 leaves 252 of them (108 of 136 at 8192). Such calls go out under
+`WINDOW`'s names: a reader of `%flash_fwd.` counts a causal half.
+
 Supports an additive attention bias ([H, S, S] — ALiBi for the Bloom family,
 blocked by the same tile) and bidirectional (non-causal) attention for
 encoder models. The bias is treated as a constant (stop_gradient): for ALiBi
@@ -92,6 +101,8 @@ PLAIN = KernelNames("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 # scores wider than the values, under names of their own, so that a reader
 # of `%flash_fwd.` never counts them at one width.
 LATENT = KernelNames("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")
+# Calls with a sliding window: the same kernels over the band's pairs.
+WINDOW = KernelNames("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv")
 
 
 # The forward rule's names for the kernel's two outputs, O and the row
@@ -134,19 +145,28 @@ def choose_tiles(seq_len: int) -> Tiles:
 
 
 @functools.cache
-def _live_pairs(t: Tiles, causal: bool, q_major: bool):
+def _live_pairs(t: Tiles, causal: bool, q_major: bool,
+                window: int | None = None):
     """The grid's second axis: one step per (q block, kv block) pair that
-    the causal mask leaves anything of, and no step for the others. Returns
+    the mask leaves anything of, and no step for the others. Returns
     three int32 tables indexed by step: the q block, the kv block, and the
     step's flag. `q_major` orders the steps of one q block together
     (forward, dq: FIRST and LAST bracket a q block's accumulation);
     otherwise those of one kv block (dk/dv). Built once per shape, from
     Python ints."""
+    def live(qi: int, ki: int) -> bool:
+        if not causal:
+            return True
+        # the block's first key is at or before its last query
+        if ki * t.block_k >= (qi + 1) * t.block_q:
+            return False
+        # ... and its last key inside the window of the block's first query
+        return (window is None
+                or qi * t.block_q - ((ki + 1) * t.block_k - 1) < window)
+
     pairs = [(qi, ki)
              for qi in range(t.seq // t.block_q)
-             for ki in range(t.seq // t.block_k)
-             # the block's first key is at or before its last query
-             if not causal or ki * t.block_k < (qi + 1) * t.block_q]
+             for ki in range(t.seq // t.block_k) if live(qi, ki)]
     major = (lambda p: p[0]) if q_major else (lambda p: p[1])
     pairs.sort(key=lambda p: p if q_major else p[::-1])
     flags = []
@@ -160,7 +180,7 @@ def _live_pairs(t: Tiles, causal: bool, q_major: bool):
 
 
 def _scores(q, k, qi, ki, t: Tiles, scale, bias_ref, slope_ref, *,
-            causal: bool, kv_len: int):
+            causal: bool, kv_len: int, window: int | None = None):
     """[Bq, Bk] masked, scaled, biased f32 logits for one (q, kv) block pair.
 
     Operands stay in their native dtype (bf16 in production) so the MXU runs
@@ -190,7 +210,14 @@ def _scores(q, k, qi, ki, t: Tiles, scale, bias_ref, slope_ref, *,
     # Every live pair is masked, not only those that cross the mask's edge:
     # the compare and select hide under the exponentials (PERF.md, PR 28).
     if causal:
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        seen = q_pos >= k_pos
+        if window is not None:
+            # A row whose keys of this pair all lie behind the window meets
+            # its diagonal pair later in the same accumulation (a row's
+            # steps ascend in k), which rescales what this one added to
+            # nothing.
+            seen = seen & (q_pos - k_pos < window)
+        s = jnp.where(seen, s, NEG_INF)
     elif padded:
         # Padded kv columns are not causally masked in the encoder form —
         # mask them explicitly so softmax never sees them.
@@ -200,7 +227,7 @@ def _scores(q, k, qi, ki, t: Tiles, scale, bias_ref, slope_ref, *,
 
 def _fwd_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
                 causal: bool, has_bias: bool, has_slopes: bool, kv_len: int,
-                emit_lse: bool):
+                window: int | None, emit_lse: bool):
     refs = list(refs)
     bias_ref = slope_ref = lse_ref = None
     q_ref, k_ref, v_ref = refs[:3]
@@ -224,7 +251,7 @@ def _fwd_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
     k = k_ref[...]                                 # [Bk, D]
     v = v_ref[...]                                 # [Bk, D]
     s = _scores(q, k, qi, ki, tiles, scale, bias_ref, slope_ref,
-                causal=causal, kv_len=kv_len)
+                causal=causal, kv_len=kv_len, window=window)
 
     # m and l stay lane-broadcast [Bq, 128] from scratch to scratch: a row's
     # scalar meets the [Bq, Bk] logits and the [Bq, D] accumulator by tiling
@@ -253,7 +280,8 @@ def _fwd_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
 
 
 def _dq_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
-               causal: bool, has_bias: bool, has_slopes: bool, kv_len: int):
+               causal: bool, has_bias: bool, has_slopes: bool, kv_len: int,
+               window: int | None):
     refs = list(refs)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     del refs[:6]
@@ -268,7 +296,7 @@ def _dq_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
     q, k, v = q_ref[...], k_ref[...], v_ref[...]   # native dtype (MXU-rate dots)
     do, o = do_ref[...], o_ref[...]
     s = _scores(q, k, qi, ki, tiles, scale, bias_ref, slope_ref,
-                causal=causal, kv_len=kv_len)
+                causal=causal, kv_len=kv_len, window=window)
     # The LSE block is lane-broadcast [Bq, 128]: tiled, not re-broadcast.
     p = jnp.exp(s - jnp.tile(lse_ref[...], (1, s.shape[1] // LANE)))  # [Bq, Bk]
     dp = jax.lax.dot_general(                      # dO @ V^T  [Bq, Bk]
@@ -288,7 +316,8 @@ def _dq_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
 
 
 def _dkv_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
-                causal: bool, has_bias: bool, has_slopes: bool, kv_len: int):
+                causal: bool, has_bias: bool, has_slopes: bool, kv_len: int,
+                window: int | None):
     refs = list(refs)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     del refs[:6]
@@ -304,7 +333,7 @@ def _dkv_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
     q, k, v = q_ref[...], k_ref[...], v_ref[...]   # native dtype (MXU-rate dots)
     do, o = do_ref[...], o_ref[...]
     s = _scores(q, k, qi, ki, tiles, scale, bias_ref, slope_ref,
-                causal=causal, kv_len=kv_len)
+                causal=causal, kv_len=kv_len, window=window)
     p = jnp.exp(s - jnp.tile(lse_ref[...], (1, s.shape[1] // LANE)))  # [Bq, Bk]
     dv_acc[...] = dv_acc[...] + jax.lax.dot_general(   # P^T @ dO  [Bk, D]
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -382,6 +411,7 @@ def _call(body, name: str, pairs, operands, *, in_specs, out_shape,
     a branch's body is opaque to that check. So under the interpreter, and
     only there, the pair runs inside a branch that is always taken."""
     interpret = _interpret()
+    _count_call(name, len(pairs[0]), statics["window"])
 
     def kernel(q_of, k_of, flag_of, *refs):
         step = pl.program_id(1)
@@ -447,7 +477,8 @@ def _slope_specs(has_slopes: bool, h: int):
 
 
 def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
-                   names: KernelNames, emit_lse: bool = True):
+                   names: KernelNames, window: int | None = None,
+                   emit_lse: bool = True):
     bias = _canon_bias(bias, q.shape[1], q.shape[2])
     q, k, v, bias, (b, h, s_len, _, dv, bh, sp, dp, dvp) = _pad_inputs(
         q, k, v, bias)
@@ -470,15 +501,16 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
     else:
         out_shape, out_specs = o_shape, o_spec
     result = _call(
-        _fwd_kernel, names.fwd, _live_pairs(t, causal, q_major=True),
-        operands,
+        _fwd_kernel, names.fwd,
+        _live_pairs(t, causal, q_major=True, window=window), operands,
         in_specs=([_q_rows(t.block_q, dp), _k_rows(t.block_k, dp),
                    _k_rows(t.block_k, dvp)]
                   + _bias_specs(has_bias, h, t) + _slope_specs(has_slopes, h)),
         out_shape=out_shape, out_specs=out_specs,
         scratch=[(t.block_q, dvp), (t.block_q, LANE), (t.block_q, LANE)],
         scale=scale, tiles=t, causal=causal, has_bias=has_bias,
-        has_slopes=has_slopes, kv_len=s_len, emit_lse=emit_lse)
+        has_slopes=has_slopes, kv_len=s_len, window=window,
+        emit_lse=emit_lse)
 
     out, lse = result if emit_lse else (result, None)
     out = out.reshape(b, h, sp, dvp)[:, :, :s_len, :dv]
@@ -486,7 +518,8 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
 
 
 def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
-                    causal: bool, names: KernelNames):
+                    causal: bool, names: KernelNames,
+                    window: int | None = None):
     bias = _canon_bias(bias, q.shape[1], q.shape[2])
     qp, kp, vp, bias, (b, h, s_len, d, dv, bh, sp, dp, dvp) = _pad_inputs(
         q, k, v, bias)
@@ -511,15 +544,17 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
                    _q_rows(t.block_q, dvp), _q_rows(t.block_q, LANE)]
                   + _bias_specs(has_bias, h, t) + _slope_specs(has_slopes, h)),
         scale=scale, tiles=t, causal=causal, has_bias=has_bias,
-        has_slopes=has_slopes, kv_len=s_len)
+        has_slopes=has_slopes, kv_len=s_len, window=window)
 
     dq = _call(
-        _dq_kernel, names.bwd_dq, _live_pairs(t, causal, q_major=True),
-        common, out_shape=grad_shape(q, dp), out_specs=_q_rows(t.block_q, dp),
+        _dq_kernel, names.bwd_dq,
+        _live_pairs(t, causal, q_major=True, window=window), common,
+        out_shape=grad_shape(q, dp), out_specs=_q_rows(t.block_q, dp),
         scratch=[(t.block_q, dp)], **shared)
     dk, dv_ = _call(
-        _dkv_kernel, names.bwd_dkv, _live_pairs(t, causal, q_major=False),
-        common, out_shape=(grad_shape(k, dp), grad_shape(v, dvp)),
+        _dkv_kernel, names.bwd_dkv,
+        _live_pairs(t, causal, q_major=False, window=window), common,
+        out_shape=(grad_shape(k, dp), grad_shape(v, dvp)),
         out_specs=(_k_rows(t.block_k, dp), _k_rows(t.block_k, dvp)),
         scratch=[(t.block_k, dp), (t.block_k, dvp)], **shared)
 
@@ -529,15 +564,16 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
     return unpad(dq, d), unpad(dk, d), unpad(dv_, dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash(q, k, v, bias, slopes, scale, causal, names):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, bias, slopes, scale, causal, names, window):
     out, _ = _flash_forward(q, k, v, bias, slopes, scale, causal, names,
-                            emit_lse=False)
+                            window, emit_lse=False)
     return out
 
 
-def _flash_fwd(q, k, v, bias, slopes, scale, causal, names):
-    out, lse = _flash_forward(q, k, v, bias, slopes, scale, causal, names)
+def _flash_fwd(q, k, v, bias, slopes, scale, causal, names, window):
+    out, lse = _flash_forward(q, k, v, bias, slopes, scale, causal, names,
+                              window)
     # All that the kernel wrote goes by a name, so that a layer's checkpoint
     # (`checkpoint_layer`) keeps it and the recomputed forward holds no
     # kernel call. q, k, v are not named: they come back from the layer's
@@ -549,10 +585,10 @@ def _flash_fwd(q, k, v, bias, slopes, scale, causal, names):
     return out, (q, k, v, bias, slopes, out, lse)
 
 
-def _flash_bwd(scale, causal, names, res, g):
+def _flash_bwd(scale, causal, names, window, res, g):
     q, k, v, bias, slopes, out, lse = res
     dq, dk, dv = _flash_backward(q, k, v, bias, slopes, out, lse, g, scale,
-                                 causal, names)
+                                 causal, names, window)
     # Bias/slopes are constants (ALiBi): position-only, so the zero
     # cotangent is exact. Learned biases must use the XLA path
     # (attention.py routes them).
@@ -568,7 +604,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     scale: float | None = None,
                     bias: jax.Array | None = None,
                     alibi_slopes: jax.Array | None = None,
-                    causal: bool = True) -> jax.Array:
+                    causal: bool = True,
+                    window: int | None = None) -> jax.Array:
     """Flash attention. [B, H, S, D] -> [B, H, S, D].
 
     `bias` is an additive [H, S, S] (or broadcastable) logit bias, treated as
@@ -576,8 +613,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     `alibi_slopes` ([H] f32) for ALiBi: the bias block is generated
     IN-KERNEL from the slopes and position iotas, so no O(H S^2) bias
     buffer exists in HBM at any sequence length. `causal=False` gives the
-    bidirectional encoder form.
+    bidirectional encoder form. `window` (causal calls only): query i sees
+    key j iff 0 <= i - j < window, itself and the `window - 1` keys before
+    it; the kernels visit the band's block pairs alone, under `WINDOW`'s
+    names.
     """
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"a sliding window is a causal call's and at least 1 "
+            f"(causal={causal}, window={window})")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if q.shape[-2] != k.shape[-2]:
@@ -594,7 +638,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 f"alibi_slopes must be [H]={q.shape[1]}, got "
                 f"{alibi_slopes.shape}")
         alibi_slopes = jax.lax.stop_gradient(alibi_slopes)
-    return _flash(q, k, v, bias, alibi_slopes, scale, causal, PLAIN)
+    return _flash(q, k, v, bias, alibi_slopes, scale, causal,
+                  PLAIN if window is None else WINDOW,
+                  None if window is None else int(window))
 
 
 def latent_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
@@ -620,7 +666,7 @@ def latent_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     _count_latent_calls()
-    return _flash(q, k, v, None, None, scale, True, LATENT)
+    return _flash(q, k, v, None, None, scale, True, LATENT, None)
 
 
 def _count_named_residuals(kernel: str) -> None:
@@ -633,6 +679,25 @@ def _count_named_residuals(kernel: str) -> None:
         "oobleck_flash_residuals_named_total",
         "Flash forward rules traced with O and LSE named for the layer's "
         "checkpoint, by kernel").inc(kernel=kernel)
+
+
+def _count_call(kernel: str, steps: int, window: int | None) -> None:
+    """Where a kernel is built into a traced program (not once a step):
+    `oobleck_flash_live_pairs{kernel}`, the grid steps a head of the last
+    such call, and `oobleck_flash_window_calls_total{kernel}` where the
+    call has a window."""
+    from oobleck_tpu.utils import metrics
+
+    reg = metrics.registry()
+    reg.gauge(
+        "oobleck_flash_live_pairs",
+        "Grid steps a head (live block pairs) of the last flash kernel "
+        "built into a traced program, by kernel").set(steps, kernel=kernel)
+    if window is not None:
+        reg.counter(
+            "oobleck_flash_window_calls_total",
+            "Flash kernels with a sliding window built into traced "
+            "programs, by kernel").inc(kernel=kernel)
 
 
 def _count_latent_calls() -> None:

@@ -184,7 +184,18 @@ def choose_row_tile(num_pairs: int, num_experts: int) -> int:
     384. With a tile whose edge the expected rows sit on (256 at 512 rows:
     two tiles or three at the slightest excess) the tiles in use, and the
     step's time with them, changed with every seed's router: 0.9 % between
-    six seeds of the benchmark's cell (my chip runs, PR 29)."""
+    six seeds of the benchmark's cell (my chip runs, PR 29).
+
+    1,536 expected rows (16384 tokens x 6 picks over 64 experts) are 3 x
+    512 = 4 x 384 = 6 x 256 = 12 x 128: on the edge of EVERY tile up to
+    MAX_ROW_TILE. At 512 each held expert filled three tiles or four as its
+    1,536 +- 60 rows fell, 105 to 117 tiles over a sequence's four layers
+    from seed to seed, and `smallthinker-21b-a3b.steady`'s rate followed
+    them: 39,270 to 39,626 tokens/s over six seeds, a spread of 0.55 % (my
+    chip runs, PR 45). Only where every tile up to MAX_ROW_TILE leaves the
+    expected rows no room at all are tiles up to twice that looked at: 1024
+    here, two tiles an expert from 1,025 to 2,048 rows. Every other call's
+    tile is what it was."""
     expected = num_pairs / num_experts
     unit = LANE if 1.5 * expected >= LANE else SUBLANE
 
@@ -194,7 +205,13 @@ def choose_row_tile(num_pairs: int, num_experts: int) -> int:
                    expected / 2)
         return (-room, tiles, tile)
 
-    return min(range(unit, MAX_ROW_TILE + 1, unit), key=preference)
+    def best(limit: int) -> int:
+        return min(range(unit, limit + 1, unit), key=preference)
+
+    tile = best(MAX_ROW_TILE)
+    if preference(tile)[0] == 0:
+        tile = best(2 * MAX_ROW_TILE)
+    return tile
 
 
 class RoutingPlan(NamedTuple):
@@ -676,6 +693,33 @@ def _swiglu_bwd(tile, res, d_hidden):
 _swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _reglu(gate, up, plan: RoutingPlan, tile: int):
+    """relu(gate) * up in float32, over the tiles in use."""
+    return _over_tiles(plan, tile, lambda g, u: ((
+        jax.nn.relu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    ).astype(g.dtype),), gate, up)[0]
+
+
+def _reglu_fwd(gate, up, plan, tile):
+    return _reglu(gate, up, plan, tile), (gate, up, plan)
+
+
+def _reglu_bwd(tile, res, d_hidden):
+    gate, up, plan = res
+
+    def grads(g, u, d):
+        g, u, d = (a.astype(jnp.float32) for a in (g, u, d))
+        return (jnp.where(g > 0, d * u, 0.0).astype(gate.dtype),
+                (d * jax.nn.relu(g)).astype(up.dtype))
+
+    d_gate, d_up = _over_tiles(plan, tile, grads, gate, up, d_hidden)
+    return d_gate, d_up, None
+
+
+_reglu.defvjp(_reglu_fwd, _reglu_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _relu2(pre, plan: RoutingPlan, tile: int):
     """relu(pre)^2 in float32, over the tiles in use: the activation of
@@ -752,7 +796,29 @@ def _count_softmax_call() -> None:
         "experts, built into traced programs").inc()
 
 
+def _count_reglu_call() -> None:
+    """`oobleck_moe_reglu_calls_total`: counted as the ungated calls are."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_moe_reglu_calls_total",
+        "Routed-expert calls whose experts are ReGLU (W2 (relu(W1 x) * "
+        "W3 x)) built into traced programs").inc()
+
+
+def _count_early_router_call() -> None:
+    """`oobleck_moe_early_router_calls_total`: counted as the ungated
+    calls are."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_moe_early_router_calls_total",
+        "Routed-expert calls whose router reads rows of its own, not the "
+        "rows the experts are handed, built into traced programs").inc()
+
+
 SIGMOID, SOFTMAX = "sigmoid", "softmax"
+SWIGLU, REGLU = "swiglu", "reglu"
 
 
 def route(x, router_w, expert_bias, *, top_k: int,
@@ -807,12 +873,18 @@ def routed_experts(
     dw_sums: tuple[GradSum | None, GradSum | None, GradSum | None] = (
         None, None, None),
     score: str = SIGMOID,
+    activation: str = SWIGLU,
+    router_x: jax.Array | None = None,
 ):
     """Dropless top-k routed experts (`score`: sigmoid or softmax scores,
-    `route`), the part that the experts held here give. SwiGLU experts,
-    or, with `w3` None, experts WITHOUT a gate: W2 relu(W1 x)^2, two
-    grouped products forward and four backward (`grouped_matmul`'s own dX
-    and dW) where SwiGLU has three and six.
+    `route`), the part that the experts held here give. Gated experts by
+    `activation`, SwiGLU or ReGLU (`W2 (relu(W1 x) * W3 x)`: the XLA
+    between the grouped products differs, the kernels do not), or, with
+    `w3` None, experts WITHOUT a gate: W2 relu(W1 x)^2, two grouped
+    products forward and four backward (`grouped_matmul`'s own dX and dW)
+    where a gated expert has three and six. `router_x` [T, D]: the rows
+    the router scores, where they are not the rows the experts are handed
+    (a router placed before the block's attention); None: `x`.
 
     x [T, D]; router_w [D, num_experts]; expert_bias [num_experts] or
     None; w1, w3 [held, D, F], w2 [held, F, D]: experts `expert_offset` ..
@@ -828,8 +900,13 @@ def routed_experts(
     held = w1.shape[0]
     assert router_w.shape == (d, num_experts), router_w.shape
     assert 0 <= expert_offset and expert_offset + held <= num_experts
+    assert activation in (SWIGLU, REGLU), activation
+    if router_x is not None:
+        assert router_x.shape == x.shape, (router_x.shape, x.shape)
+        _count_early_router_call()
     experts, weights = route(
-        x, router_w, expert_bias, top_k=top_k, norm_topk_prob=norm_topk_prob,
+        x if router_x is None else router_x, router_w, expert_bias,
+        top_k=top_k, norm_topk_prob=norm_topk_prob,
         routed_scaling_factor=routed_scaling_factor,
         forced_experts=forced_experts, score=score)
     if score == SOFTMAX:
@@ -847,7 +924,11 @@ def routed_experts(
                         plan, tile)
     else:
         gate, up = _gate_and_up(xs, w1, w3, dw_sums[:2], plan, tile)
-        hidden = _swiglu(gate, up, plan, tile)
+        if activation == REGLU:
+            _count_reglu_call()
+            hidden = _reglu(gate, up, plan, tile)
+        else:
+            hidden = _swiglu(gate, up, plan, tile)
     out = grouped_matmul(hidden, w2, plan, tile, dw_sums[2])
     y = _combine(out, weights, plan, tile, top_k)
     if return_routing:

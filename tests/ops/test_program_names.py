@@ -17,7 +17,8 @@ HOT_PATH = ["execution/engine.py", "execution/pipeline.py",
             "parallel/train.py", "serve/engine.py", "serve/batcher.py",
             "models/gpt.py", "models/llama.py", "models/lfm2.py",
             "models/routed.py", "models/deepseek_v3.py",
-            "models/nemotron_h.py", "models/qwen3_next.py", "ops/flash.py",
+            "models/nemotron_h.py", "models/qwen3_next.py",
+            "models/smallthinker.py", "ops/flash.py",
             "ops/moe.py", "ops/ssd.py", "ops/gdn.py"]
 
 

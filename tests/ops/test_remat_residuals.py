@@ -18,7 +18,7 @@ gradients (the rule's: `tests/ops/test_gdn.py`). (iii) A layer emits no
 value by a name: nothing is kept by it, and the layer lowers to the text
 it lowered to without that name in the policy.
 
-A file of its own: under `--dist loadfile` its five compiles (about 200 s
+A file of its own: under `--dist loadfile` its six compiles (about 250 s
 together) do not lengthen `test_tpu_compile.py`'s worker.
 """
 
@@ -38,24 +38,35 @@ from oobleck_tpu.ops import attention, flash
 from tests.ops.programs import (
     cell_stage, checkpoint_keeping, pallas_calls)
 
-# cell -> (microbatch, sequence), its attention's kernels and how many
-# layers call them, the bound on `jit_bwd`'s temporaries. The compile gave
+def _calls(names, layers):
+    return dict.fromkeys(names, layers)
+
+
+# cell -> (microbatch, sequence), its attention's kernels with how many
+# layers call each, the bound on `jit_bwd`'s temporaries. The compile gave
 # 1,188,759,552 / 2,136,438,784 / 1,732,474,368 bytes when the policy went
 # in (PR 36; 923 MB / 2.22 GB / 1.68 GB before): O and LSE of every
 # attention layer are the program's to hold, a second copy is not.
 CELLS = {
-    "gpt3-2.7b": ((4, 1024), flash.PLAIN, 3, 1.25e9),
-    "lfm2-24b-a2b": ((8, 1024), flash.PLAIN, 1, 2.3e9),
-    "moonlight-16b-a3b": ((1, 4096), flash.LATENT, 5, 1.8e9),
+    "gpt3-2.7b": ((4, 1024), _calls(flash.PLAIN, 3), 1.25e9),
+    "lfm2-24b-a2b": ((8, 1024), _calls(flash.PLAIN, 1), 2.3e9),
+    "moonlight-16b-a3b": ((1, 4096), _calls(flash.LATENT, 5), 1.8e9),
     # 1,592,583,680 when the cell went in (PR 37): under ISSUE 37's 2.2 GB.
-    "nemotron-3-nano-30b-a3b": ((1, 4096), flash.PLAIN, 1, 2.2e9),
+    "nemotron-3-nano-30b-a3b": ((1, 4096), _calls(flash.PLAIN, 1), 2.2e9),
     # 4,402,778,624 when the cell went in (PR 43): the dropless buffers of
     # 4096 x 10 + 16 x 128 rows (168 MB each at 2048 bfloat16 columns)
     # beside the delta rule's float32 [64, 64] blocks. One attention layer
     # of four, at heads of 256. 3,732,470,272 since the layers' checkpoint
     # keeps the rule's inverse (PR 44): 100.7 MB kept, and the recompute
     # holds no power and no partial product of the series.
-    "qwen3-next-80b-a3b": ((1, 4096), flash.PLAIN, 1, 4.6e9),
+    "qwen3-next-80b-a3b": ((1, 4096), _calls(flash.PLAIN, 1), 4.6e9),
+    # 5,650,993,664 when the cell went in (PR 45), at ONE sequence of 16384:
+    # the dropless buffers of 16384 x 6 + 8 x 1024 rows (545 MB each at 2560
+    # bfloat16 columns) beside the head's float32 logits. One full-attention
+    # layer through the plain kernels (528 grid steps a head) and three
+    # windowed ones through `flash_swa_*` (252): one forward kernel a layer.
+    "smallthinker-21b-a3b": ((1, 16384), {**_calls(flash.PLAIN, 1),
+                                          **_calls(flash.WINDOW, 3)}, 5.9e9),
 }
 # The three Gated DeltaNet layers' inverse (`ops/gdn.unit_lower_inverse`,
 # scope `gdn_inverse`): ten [64, 64] float32 products of the series a
@@ -97,14 +108,14 @@ def compiled_for_tpu(monkeypatch):
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
                                                       cell):
-    (mb, seq), names, layers, temp_bound = CELLS[cell]
+    (mb, seq), kernels, temp_bound = CELLS[cell]
     st, params, batch = cell_stage(cell, v5e, microbatch=mb, seq=seq)
     compiled = st.bwd[0].lower(params, params, None, batch).compile()
     text = compiled.as_text()
     calls = re.findall(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
     count = {k: calls.count(k) for k in set(calls)}
-    assert {k: count.pop(k, 0) for k in names} == dict.fromkeys(names, layers)
+    assert {k: count.pop(k, 0) for k in kernels} == kernels
     # What the policy does not name is recomputed as before: the routed
     # layers' three forward products run twice (ROADMAP.md S8 a).
     assert count == {"gpt3-2.7b": {}, "nemotron-3-nano-30b-a3b": UNGATED}.get(
@@ -132,6 +143,7 @@ EXPERT_SETS = {
     "moonlight-16b-a3b": (("8,2048,1408", "8,1408,2048"), 0),
     "nemotron-3-nano-30b-a3b": (("8,2688,1856", "8,1856,2688"), 3 * 4),
     "qwen3-next-80b-a3b": (("16,2048,512", "16,512,2048"), 0),
+    "smallthinker-21b-a3b": (("8,2560,768", "8,768,2560"), 0),
 }
 
 

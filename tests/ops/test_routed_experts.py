@@ -732,3 +732,188 @@ def test_the_qwen3_next_cell_s_call_is_sized_for_every_pick_held_here():
     assert (rows, tile) == (4096 * 10 + 16 * 128, 128)
     assert moe._col_tile(512, moe.MAX_COL_TILE) == 512
     assert moe._col_tile(512, moe.MAX_TGMM_ROWS) == 512
+
+
+# --------------------------------------------------------------------- #
+# ReGLU experts, and a router that reads rows of its own                 #
+# --------------------------------------------------------------------- #
+#
+# `smallthinker-21b-a3b`'s call: `activation="reglu"` (W2 (relu(W1 y) * W3
+# y): the XLA between the grouped products differs, the kernels do not) and
+# `router_x` (the router scores the block's input, the experts are handed
+# the normed residual stream after the attention).
+
+def _reglu_dense(r, y, router, w1, w3, w2, offset):
+    """Dense over the held experts, for autodiff: a softmax over all, its
+    top k renormalised, scored on `r`; ReGLU experts on `y`."""
+    scores = jax.nn.softmax(r @ router, -1)
+    _, chosen = jax.lax.top_k(scores, K)
+    g = jax.nn.one_hot(chosen, NE).sum(-2) * scores
+    g = g / g.sum(-1, keepdims=True)
+    g = g[:, offset:offset + w1.shape[0]]
+    h = jax.nn.relu(jnp.einsum("td,edf->tef", y, w1)) \
+        * jnp.einsum("td,edf->tef", y, w3)
+    return jnp.einsum("tef,efd,te->td", h, w2, g)
+
+
+def _reglu_share(layer, r, offset, held, **kw):
+    sl = slice(offset, offset + held)
+    return moe.routed_experts(
+        layer["x"], layer["router"], None, layer["w1"][sl], layer["w3"][sl],
+        layer["w2"][sl], num_experts=NE, top_k=K, expert_offset=offset,
+        score="softmax", activation="reglu", router_x=r, **kw)
+
+
+@pytest.fixture(scope="module")
+def router_rows():
+    return jax.random.normal(jax.random.PRNGKey(11), (T, D))
+
+
+@pytest.mark.parametrize("offset,held", [(0, NE), (2, 4), (7, 1)],
+                         ids=["all", "share", "one_expert"])
+def test_reglu_experts_with_a_router_of_their_own_match_the_dense_form(
+        layer, router_rows, offset, held):
+    sl = slice(offset, offset + held)
+    args = (router_rows, layer["x"], layer["router"], layer["w1"][sl],
+            layer["w3"][sl], layer["w2"][sl])
+    target = jax.random.normal(jax.random.PRNGKey(1), (T, D))
+
+    def routed(r, y, router, w1, w3, w2):
+        out = moe.routed_experts(
+            y, router, None, w1, w3, w2, num_experts=NE, top_k=K,
+            expert_offset=offset, score="softmax", activation="reglu",
+            router_x=r)
+        return jnp.sum(out * target), out
+
+    def dense(r, y, router, w1, w3, w2):
+        out = _reglu_dense(r, y, router, w1, w3, w2, offset)
+        return jnp.sum(out * target), out
+
+    got, out = jax.jit(jax.grad(routed, argnums=range(6), has_aux=True))(*args)
+    want, ref_out = jax.grad(dense, argnums=range(6), has_aux=True)(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               atol=2e-5)
+    for name, g, w in zip(("r", "y", "router", "w1", "w3", "w2"), got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-5,
+                                   err_msg=name)
+    # The router's rows take a gradient (through the weights) and the
+    # experts' rows one of their own: neither is the other's.
+    assert np.asarray(got[0]).any() and np.asarray(got[1]).any()
+
+
+def test_the_router_s_rows_choose_and_the_experts_rows_are_computed_on(
+        layer, router_rows):
+    out, chosen = _reglu_share(layer, router_rows, 0, NE, return_routing=True)
+    _, want = jax.lax.top_k(router_rows @ layer["router"], K)
+    np.testing.assert_array_equal(np.sort(np.asarray(chosen), -1),
+                                  np.sort(np.asarray(want), -1))
+    # Other rows for the experts: the same choices, another result.
+    moved = dict(layer, x=layer["x"] + 1.0)
+    out2, chosen2 = _reglu_share(moved, router_rows, 0, NE,
+                                 return_routing=True)
+    np.testing.assert_array_equal(np.asarray(chosen), np.asarray(chosen2))
+    assert float(jnp.max(jnp.abs(out - out2))) > 1e-3
+    # `router_x` left out is the rows handed over; handing them over again
+    # changes nothing.
+    np.testing.assert_array_equal(
+        np.asarray(_reglu_share(layer, None, 0, NE)),
+        np.asarray(_reglu_share(layer, layer["x"], 0, NE)))
+    with pytest.raises(AssertionError):
+        _reglu_share(layer, router_rows[:-1], 0, NE)
+    with pytest.raises(AssertionError):
+        moe.routed_experts(layer["x"], layer["router"], None, layer["w1"],
+                           layer["w3"], layer["w2"], num_experts=NE, top_k=K,
+                           activation="gelu")
+
+
+def test_the_reglu_shares_add_up_to_the_uncut_layer(layer, router_rows):
+    whole = np.asarray(_reglu_share(layer, router_rows, 0, NE))
+    for parts in (8, 4, 2):
+        held = NE // parts
+        total = sum(np.asarray(_reglu_share(layer, router_rows, i * held, held))
+                    for i in range(parts))
+        np.testing.assert_allclose(total, whole, atol=2e-5)
+
+
+def test_a_reglu_call_holds_swiglu_s_nine_products(layer, router_rows,
+                                                   monkeypatch):
+    """Three grouped products forward, three dX and three dW backward: the
+    kernels are SwiGLU's, under the same names."""
+    from tests.ops.programs import pallas_calls
+
+    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
+    monkeypatch.setattr(moe, "_interpret", lambda: True)
+    fn = lambda y: jnp.sum(_reglu_share(dict(layer, x=y), router_rows, 2, 4))
+    names = [n for n, _ in pallas_calls(jax.make_jaxpr(fn)(layer["x"]).jaxpr)]
+    assert names == ["moe_gmm"] * 3
+    grads = jax.grad(lambda y, w1: jnp.sum(moe.routed_experts(
+        y, layer["router"], None, w1, layer["w3"][2:6], layer["w2"][2:6],
+        num_experts=NE, top_k=K, expert_offset=2, score="softmax",
+        activation="reglu", router_x=router_rows)), argnums=(0, 1))
+    names = [n for n, _ in pallas_calls(
+        jax.make_jaxpr(grads)(layer["x"], layer["w1"][2:6]).jaxpr)]
+    # (The jaxpr also holds `_gate_and_up`'s forward products again, which
+    # its backward rule traces to pull through and XLA drops.)
+    assert set(names) == {"moe_gmm", "moe_tgmm"}
+    assert names.count("moe_tgmm") == 3
+
+
+def test_reglu_and_early_router_calls_are_counted_where_they_are_built(
+        layer, router_rows):
+    from oobleck_tpu.utils import metrics
+
+    reg = metrics.registry()
+    reglu = reg.counter("oobleck_moe_reglu_calls_total")
+    early = reg.counter("oobleck_moe_early_router_calls_total")
+    before = (reglu.value(), early.value())
+    fn = jax.jit(lambda y: _reglu_share(dict(layer, x=y), router_rows, 0, NE))
+    fn(layer["x"])
+    fn(layer["x"])                  # a cache hit traces nothing
+    assert (reglu.value() - before[0], early.value() - before[1]) == (1, 1)
+    jax.jit(lambda y: _reglu_share(dict(layer, x=y), None, 0, NE))(layer["x"])
+    assert (reglu.value() - before[0], early.value() - before[1]) == (2, 1)
+    jax.jit(lambda x: _share(dict(layer, x=x), 0, NE))(layer["x"])
+    assert (reglu.value() - before[0], early.value() - before[1]) == (2, 1)
+
+
+# Each benchmark cell's call (tokens a microbatch, picks a token, experts):
+# the tile `choose_row_tile` gives it. A change to the rule keeps the five
+# accepted cells' tiles (ISSUE 45, fallback (c)).
+CELL_TILES = {
+    "lfm2-24b-a2b": ((8192, 4, 64), 384),
+    "moonlight-16b-a3b": ((4096, 6, 64), 512),
+    "nemotron-3-nano-30b-a3b": ((4096, 6, 128), 384),
+    "qwen3-next-80b-a3b": ((4096, 10, 512), 128),
+    "smallthinker-21b-a3b": ((16384, 6, 64), 1024),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_TILES))
+def test_every_cell_keeps_its_row_tile(cell):
+    (tokens, top_k, experts), want = CELL_TILES[cell]
+    assert moe.choose_row_tile(tokens * top_k, experts) == want
+
+
+def test_the_smallthinker_cell_s_expected_rows_sit_on_every_tile_s_edge():
+    """1,536 rows expected an expert = 3 x 512 = 4 x 384 = 6 x 256 = 12 x
+    128: no tile up to MAX_ROW_TILE keeps them off an edge (at 512 an
+    expert at 1,537 rows fills a fourth tile, and the cell's rate followed
+    the tiles from seed to seed: PERF.md section 6, PR 45). Only then are
+    tiles up to twice MAX_ROW_TILE looked at: 1024, two tiles an expert
+    with 512 rows of room either way."""
+    expected = 16384 * 6 / 64
+    assert expected == 1536
+    assert all(expected % tile == 0
+               for tile in range(moe.LANE, moe.MAX_ROW_TILE + 1, moe.LANE))
+    tile = moe.choose_row_tile(16384 * 6, 64)
+    assert tile == 1024 == 2 * moe.MAX_ROW_TILE
+    tiles_at = lambda rows: -(-rows // tile)
+    assert {tiles_at(r) for r in (1025, 1400, 1536, 1700, 2048)} == {2}
+    rows, buffer_tile = moe.buffer_rows(16384, 6, 8, 64)
+    assert (rows, buffer_tile) == (16384 * 6 + 8 * 1024, 1024)
+    assert moe._col_tile(768, moe.MAX_COL_TILE) == 384
+    assert moe._col_tile(2560, moe.MAX_COL_TILE) == 512
+    # A call with any room at all within MAX_ROW_TILE never looks further:
+    # 1,535 and 1,537 expected rows keep a tile of their own within it.
+    for pairs in (1535 * 64, 1537 * 64, 3000 * 64):
+        assert moe.choose_row_tile(pairs, 64) <= moe.MAX_ROW_TILE

@@ -376,6 +376,70 @@ def test_latent_grad_module_stays_small(v5e):
     assert "16x4096x128xbf16" in text and "16x4096x256xbf16" in text
 
 
+# A sliding window (`flash_attention(window=)`): the plain kernels over the
+# band's block pairs, under `flash_swa_*`. `smallthinker-21b-a3b.steady`'s
+# call: one sequence of 16384, 28 heads of 128 (the 4 key-value heads
+# already repeated), window 4096: 252 grid steps a head of the causal 528;
+# and a window no multiple of a block. The scalar-prefetched tables follow the band, the bodies do not
+# change, so the lowered gradient stays within the plain kernels' bound.
+WINDOW_CALLS = {"cell": (16384, 4096), "ragged-window": (2048, 700)}
+
+
+def _windowed(window):
+    return lambda q, k, v: flash_attention(q, k, v, window=window)
+
+
+@pytest.mark.parametrize("call", sorted(WINDOW_CALLS))
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_window_flash_compiles(v5e, mode, call):
+    seq, window = WINDOW_CALLS[call]
+    fn = _windowed(window) if mode == "fwd" else _grads(_windowed(window))
+    text = _compile(fn, v5e[0], *[((1, 28, seq, 128), jnp.bfloat16)] * 3)
+    calls = re.findall(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    # (Bare of any outer scope a transformation's wrapper goes around the
+    # kernel's own name, `jvp_flash_swa_fwd_`: a layer's scope keeps it
+    # whole, `test_kernel_is_named_in_location_and_executable`.)
+    assert len(calls) == (1 if mode == "fwd" else 3)
+    assert all("flash_swa_" in name for name in calls), calls
+
+
+def test_window_grad_module_stays_small(v5e):
+    one = SingleDeviceSharding(v5e[0])
+    arg = jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.bfloat16,
+                               sharding=one)
+    text = jax.jit(_grads(_windowed(4096))).lower(arg, arg, arg).as_text()
+    assert text.count("tpu_custom_call") == 3
+    # 41,258: the plain kernels' three bodies and, written out as
+    # constants, nine tables of 252 steps (the causal call at this length
+    # would hold nine of 528).
+    assert len(text) < FLASH_GRAD_MODULE_CHARS + 9_000, len(text)
+    assert "tensor<252xi32>" in text and "tensor<528xi32>" not in text
+
+
+# ReGLU experts whose router reads rows of its own (`routed_experts(
+# activation="reglu", router_x=)`), value and gradient, at the same cell's
+# call: 16384 tokens, 8 of 64 experts of 2560 x 768, top 6. 1,536 rows
+# expected an expert sit on the edge of every tile up to 512: 1024-row
+# tiles, a 106,496-row buffer. The kernels are SwiGLU's nine, the XLA
+# between them differs.
+def test_reglu_experts_with_a_router_of_their_own_compile(v5e):
+    from oobleck_tpu.ops.moe import routed_experts
+
+    t, d, f, ne, held, top_k = 16384, 2560, 768, 64, 8, 6
+
+    def fn(x, router, r, w1, w3, w2):
+        return jnp.sum(routed_experts(
+            x, router, None, w1, w3, w2, num_experts=ne, top_k=top_k,
+            score="softmax", activation="reglu",
+            router_x=r).astype(jnp.float32))
+
+    shapes = _routed_shapes(t, d, f, ne, held, top_k)
+    shapes[2] = shapes[0]                       # the router's rows, not a bias
+    text = _compile(jax.grad(fn, argnums=(0, 1, 2, 3, 4, 5)), v5e[0], *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+
+
 # Every kernel has a stable name on the device: `name=` on its pallas_call
 # is the innermost component of the operation's JAX name stack, and the
 # chip's compiler names the custom call after that component. A profiler
@@ -385,6 +449,8 @@ KERNEL_NAMES = {
     "flash_fwd": "flash", "flash_bwd_dq": "flash", "flash_bwd_dkv": "flash",
     "flash_mla_fwd": "latent", "flash_mla_bwd_dq": "latent",
     "flash_mla_bwd_dkv": "latent",
+    "flash_swa_fwd": "window", "flash_swa_bwd_dq": "window",
+    "flash_swa_bwd_dkv": "window",
     "paged_decode": "decode", "paged_verify": "verify",
     "moe_gmm": "moe", "moe_tgmm": "moe",
 }
@@ -404,6 +470,9 @@ def test_kernel_is_named_in_location_and_executable(v5e, name):
         # forward call keeps the kernel's name too.
         fn = _grads(jax.checkpoint(flash_attention))
         shapes = [(FLASH_WIDTHS["gpt3-2.7b"], jnp.bfloat16)] * 3
+    elif KERNEL_NAMES[name] == "window":
+        fn = _grads(jax.checkpoint(_windowed(512)))
+        shapes = [(FLASH_WIDTHS["seq-2048"], jnp.bfloat16)] * 3
     elif KERNEL_NAMES[name] == "latent":
         fn = _latent_grads(jax.checkpoint(latent_flash_attention))
         shapes = _latent_shapes(1, 4, 1024, *LATENT_WIDTHS[1:])
@@ -498,6 +567,8 @@ ROUTED_CELLS = {
     "lfm2-24b-a2b": ((8, 1024), 4 * 3),
     "moonlight-16b-a3b": ((1, 4096), 4 * 3),
     "nemotron-3-nano-30b-a3b": ((1, 4096), 3 * 2),
+    "qwen3-next-80b-a3b": ((1, 4096), 4 * 3),
+    "smallthinker-21b-a3b": ((1, 16384), 4 * 3),
 }
 
 
@@ -541,6 +612,11 @@ LOWERED = {
     "lfm2-24b-a2b": ("cfd3c52916dfe114", 608880),
     "moonlight-16b-a3b": ("8fa068f290c39b8a", 728618),
     "nemotron-3-nano-30b-a3b": ("242209411d5adff9", 659173),
+    # As at the parent of the PR that gave the kernels a window, the
+    # router rows of its own and the experts ReGLU (PR 45: none of them is
+    # this cell's); the newest cell's as that PR left it.
+    "qwen3-next-80b-a3b": ("80a276b2f1715215", 1038252),
+    "smallthinker-21b-a3b": ("1dc805d5d6213ffa", 680532),
 }
 
 
