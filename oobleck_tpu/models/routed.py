@@ -30,6 +30,8 @@ chips.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,18 +80,60 @@ class HeldShare:
 # rotary embedding, rotate-half form                                     #
 # --------------------------------------------------------------------- #
 
-def rotate_half(x: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half rotary embedding at positions 0..S-1 over the whole last
-    dimension. x: [..., S, D]."""
+def _half_turn(d: int, dtype) -> jax.Array:
+    """The signed permutation `P` with `x @ P = [-x2, x1]`: `P[j + d/2, j]
+    = -1`, `P[j, j + d/2] = +1`. From an iota, so a lowered module holds no
+    [d, d] literal; the compiler folds it."""
+    j = jnp.arange(d)
+    col_less_row = j[None, :] - j[:, None]
+    return ((col_less_row == d // 2).astype(dtype)
+            - (col_less_row == -(d // 2)).astype(dtype))
+
+
+def _rotate(x: jax.Array, theta: float, sign: float) -> jax.Array:
+    """`x cos + [-x2, x1] (sign sin)`, products and sum in float32, one
+    rounding to x's dtype. `[-x2, x1]` is `x @ P`: every output is ONE
+    operand element, plus or minus, beside zeros, so the product is exact
+    (float32 operands at HIGHEST: the default would round them to
+    bfloat16), and XLA makes the whole rotation one pass that reads x and
+    writes the result: no float32 or half-width copy of x goes to HBM."""
     d, s = x.shape[-1], x.shape[-2]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)       # [S, D]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return (x32 * cos + rotated * sin).astype(x.dtype)
+    rotated = jnp.einsum(
+        "...d,de->...e", x, _half_turn(d, x.dtype),
+        preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST if x.dtype.itemsize > 2
+                   else None))
+    return (x.astype(jnp.float32) * cos + rotated * (sign * sin)).astype(
+        x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rotary(x: jax.Array, theta: float) -> jax.Array:
+    return _rotate(x, theta, 1.0)
+
+
+# The transpose is the rotation by the negated angle, in the same one pass
+# (what autodiff would build multiplies the float32 cotangent by sin BEFORE
+# the permutation: a float32 operand the size of x).
+_rotary.defvjp(lambda x, theta: (_rotate(x, theta, 1.0), None),
+               lambda theta, _, g: (_rotate(g, theta, -1.0),))
+
+
+def rotate_half(x: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half rotary embedding at positions 0..S-1 over the whole last
+    dimension. x: [..., S, D]. `oobleck_rotary_calls_total{width}` counts
+    the rotations built into traced programs (not once a step)."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_rotary_calls_total",
+        "Rotary embeddings built into traced programs, by the width "
+        "rotated").inc(width=str(x.shape[-1]))
+    return _rotary(x, theta)
 
 
 def short_conv(bu: jax.Array, taps: jax.Array) -> jax.Array:

@@ -29,6 +29,11 @@ def _stage_sums(hist_name="oobleck_recovery_latency_seconds"):
 def test_chaos_kill_drives_exactly_one_incident(cache_env, devices8,
                                                 tmp_path, monkeypatch):
     monkeypatch.setenv(metrics.ENV_METRICS_DIR, str(tmp_path))
+    # As tests/elastic/conftest.py: the policy scores its arms from the
+    # latency history in the PROCESS-GLOBAL registry, and a slow reroute
+    # some engine module measured earlier on this worker would pick
+    # another verb than the degrade path this test follows.
+    metrics.registry().clear()
     before = _stage_sums()
     eng = _dp2_engine(devices8, steps=3)
     try:

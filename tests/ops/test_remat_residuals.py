@@ -65,6 +65,10 @@ CELLS = {
     # bfloat16 columns) beside the head's float32 logits. One full-attention
     # layer through the plain kernels (528 grid steps a head) and three
     # windowed ones through `flash_swa_*` (252): one forward kernel a layer.
+    # 5,762,886,144 since the rotary embedding is one pass (PR 46), where
+    # its parent compiled to 5,764,079,616: the float32 copies of q and k
+    # are gone (88.7 -> 70.2 GB of `bytes accessed`) and were never alive at
+    # the peak, so there is nothing to lower the bound by.
     "smallthinker-21b-a3b": ((1, 16384), {**_calls(flash.PLAIN, 1),
                                           **_calls(flash.WINDOW, 3)}, 5.9e9),
 }

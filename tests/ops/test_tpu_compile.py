@@ -12,6 +12,8 @@ steer `ops.attention._pallas_ok` themselves — the program has no option
 for it.
 """
 
+import functools
+import math
 import os
 import re
 
@@ -417,6 +419,35 @@ def test_window_grad_module_stays_small(v5e):
     assert "tensor<252xi32>" in text and "tensor<528xi32>" not in text
 
 
+# The rotary embedding ALONE (`models/routed.rotate_half`) at the same
+# cell's q, forward and transposed: one pass, the operand in and the result
+# out. The formula by slices and a concatenation compiled to the operand
+# WRITTEN OUT as float32 (235 MB), its two halves as `f32[..., 64]` (a
+# 64-wide minor dimension fills 128 lanes: 235 MB each) and a fusion that
+# read all three: 2.533 GB a call each way where 0.235 is operand in +
+# result out; as a product with a signed permutation 0.420 (XLA's own
+# count). No kernel in it: seconds, not a cell's `jit_bwd`.
+@pytest.mark.parametrize("mode", ["fwd", "vjp"])
+def test_rotary_alone_is_one_pass_over_its_operand(v5e, mode):
+    from oobleck_tpu.models.routed import rotate_half
+
+    shape, theta = (1, 28, 16384, 128), 1.5e6
+    rotate = lambda x: rotate_half(x, theta)
+    fn = rotate if mode == "fwd" else (
+        lambda x, g: jax.vjp(rotate, x)[1](g)[0])
+    one = SingleDeviceSharding(v5e[0])
+    arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    compiled = jax.jit(fn).lower(*[arg] * (1 if mode == "fwd" else 2)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    elements = math.prod(shape)
+    wide = [m.group(0) for m in re.finditer(r"= f32\[([\d,]+)\]", entry)
+            if math.prod(map(int, m.group(1).split(",")))
+            in (elements, elements // 2)]
+    assert not wide, wide
+    assert compiled.cost_analysis()["bytes accessed"] < 0.6e9
+
+
 # ReGLU experts whose router reads rows of its own (`routed_experts(
 # activation="reglu", router_x=)`), value and gradient, at the same cell's
 # call: 16384 tokens, 8 of 64 experts of 2560 x 768, top 6. 1,536 rows
@@ -580,13 +611,28 @@ def _kernel_calls(text):
         r'"[^"]*", kernel_name = "(\w+)"([^\n]*)', text)
 
 
+@functools.cache
+def _lowered_backward(cell, devices):
+    """(the lowered text of the cell's one-stage `jit_bwd`, the rotations
+    `models/routed.rotate_half` built into that one trace, by width):
+    lowered once a cell for the tests below."""
+    from oobleck_tpu.utils import metrics
+
+    built = metrics.registry().counter("oobleck_rotary_calls_total")
+    count = lambda: {w: built.value(width=w) for w in ("64", "128")}
+    (mb, seq), sums = ROUTED_CELLS.get(cell, ((4, 1024), 0))
+    st, params, batch = cell_stage(cell, devices, microbatch=mb, seq=seq)
+    assert st.kernel_sums == [sums]
+    before = count()
+    text = st.bwd[0].lower(params, params, None, batch).as_text()
+    return text, {w: n - before[w] for w, n in count().items()
+                  if n > before[w]}
+
+
 @pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
 def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
-    (mb, seq), sums = ROUTED_CELLS[cell]
-    st, params, batch = cell_stage(cell, v5e, microbatch=mb, seq=seq)
-    assert st.kernel_sums == [sums]
-    calls = _kernel_calls(
-        st.bwd[0].lower(params, params, None, batch).as_text())
+    sums = ROUTED_CELLS[cell][1]
+    calls = _kernel_calls(_lowered_backward(cell, tuple(v5e))[0])
     # The rooflines and `moe_*_ms` match `%moe_gmm.` / `%moe_tgmm.`.
     assert {n for n, _ in calls if n.startswith("moe")} == {
         "moe_gmm", "moe_tgmm"}
@@ -603,20 +649,32 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
 # cell -> sha256 and length of the one-stage `jit_bwd`'s lowered text with
 # the kernels' serialized bodies (which carry source lines) blanked.
 # `gpt3-2.7b`'s as at the parent of the PR that moved the sums (it has none
-# to hand down); the three routed cells' as at the parent of the PR that
-# widened the layers' checkpoint to the delta rule's inverse (PR 44: no
-# layer of theirs emits a value by that name). A PR that changes what one
-# of these programs computes takes its new text's pair from a failing run.
+# to hand down); `nemotron-3-nano-30b-a3b`'s as at the parent of the PR
+# that widened the layers' checkpoint to the delta rule's inverse (PR 44:
+# no layer of its emits a value by that name). Both stood through the PR
+# that made the rotary embedding one pass (PR 46), and that is the proof
+# that they bypass it: neither program has a rotary. The four others' are
+# as that PR left them. A PR that changes what one of these programs
+# computes takes its new text's pair from a failing run.
 LOWERED = {
     "gpt3-2.7b": ("1c314568b7c7d581", 172933),
-    "lfm2-24b-a2b": ("cfd3c52916dfe114", 608880),
-    "moonlight-16b-a3b": ("8fa068f290c39b8a", 728618),
+    "lfm2-24b-a2b": ("9998540bdf66e85b", 619478),
+    "moonlight-16b-a3b": ("b8d82ceeb5b0420b", 782058),
     "nemotron-3-nano-30b-a3b": ("242209411d5adff9", 659173),
-    # As at the parent of the PR that gave the kernels a window, the
-    # router rows of its own and the experts ReGLU (PR 45: none of them is
-    # this cell's); the newest cell's as that PR left it.
-    "qwen3-next-80b-a3b": ("80a276b2f1715215", 1038252),
-    "smallthinker-21b-a3b": ("1dc805d5d6213ffa", 680532),
+    "qwen3-next-80b-a3b": ("e663c8b772819372", 1048990),
+    "smallthinker-21b-a3b": ("00be656e1d3abd6c", 715689),
+}
+
+
+# cell -> rotations `models/routed.rotate_half` builds into one trace of the
+# stage: q and k of each rotary layer (the windowed three of four, the one
+# attention layer of five, q's and the shared key's rope columns in all
+# five blocks, the one gated-attention layer). Zero where six are expected
+# means the mechanism did not engage; the two cells at zero have no rotary.
+ROTATIONS = {
+    "gpt3-2.7b": {}, "nemotron-3-nano-30b-a3b": {},
+    "lfm2-24b-a2b": {"64": 2}, "moonlight-16b-a3b": {"64": 10},
+    "qwen3-next-80b-a3b": {"64": 2}, "smallthinker-21b-a3b": {"128": 6},
 }
 
 
@@ -624,10 +682,8 @@ LOWERED = {
 def test_a_cell_s_backward_lowers_to_the_text_it_lowered_to(v5e, cell):
     import hashlib
 
-    (mb, seq), sums = ROUTED_CELLS.get(cell, ((4, 1024), 0))
-    st, params, batch = cell_stage(cell, v5e, microbatch=mb, seq=seq)
-    assert st.kernel_sums == [sums]
-    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""',
-                  st.bwd[0].lower(params, params, None, batch).as_text())
+    text, rotations = _lowered_backward(cell, tuple(v5e))
+    assert rotations == ROTATIONS[cell]
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
     assert (hashlib.sha256(text.encode()).hexdigest()[:16],
             len(text)) == LOWERED[cell]
