@@ -9,20 +9,24 @@ keeping HBM traffic linear in S in BOTH directions:
   forward:  online softmax, emits O and the row logsumexp (LSE, stored
             lane-broadcast [BH, S, 128] following the layout the TPU memory
             system wants for per-row scalars).
-  backward: standard two-pass recompute —
-              dq kernel    accumulates dq for one q block across the kv
-                           blocks of its row;
-              dk/dv kernel accumulates dk/dv for one kv block across the q
-                           blocks of its column.
-            Each recomputes p = exp(s - lse) from the saved LSE — no [S, S]
-            residual ever touches HBM.
+  backward: ONE kernel in kv-major order. A block pair recomputes
+            p = exp(s - lse) from the saved LSE and dS from it once, and
+            the pair's five products follow: dv and dk accumulate for one kv
+            block across the q blocks of its column; dq of the WHOLE head
+            accumulates in VMEM ([S, D] f32, a q block's rows added to once
+            a column) and is written when the head's steps end. No [S, S]
+            residual and no unreduced dq ever touches HBM. The call asks
+            for the VMEM its shape needs (`vmem_limit_bytes`: the resident
+            dq beside the default scoped limit); a head whose dq would not
+            fit (`MAX_RESIDENT_DQ`: 65536 positions of 128 in bf16) is
+            refused by that shape test, where the call is traced.
 
-Geometry. All three kernels run on a grid of (batch * head, step), one step
+Geometry. Both kernels run on a grid of (batch * head, step), one step
 per (q block, kv block) PAIR THE MASK LEAVES ANYTHING OF: `_live_pairs`
 lists them once per shape (three small int32 tables, prefetched to SMEM,
 which the block index maps read), so a pair the causal mask empties costs
 neither a grid step nor a K/V fetch. The steps of one accumulation
-(a q block's row for forward and dq, a kv block's column for dk/dv) adjoin;
+(a q block's row for the forward, a kv block's column for dk/dv) adjoin;
 the tables' flag says which step opens and which closes it. Block sizes
 come from `choose_tiles`, a pure function of the sequence length: 512 x 512
 where the sequence allows (a step then carries about a microsecond of MXU
@@ -30,7 +34,7 @@ work; the fixed 128 x 128 of before carried 0.04 us under 0.4 us of step
 overhead), 128 x 128 for a 128-token prompt. There is no option for them.
 
 A sliding window (`window`: query i sees key j iff 0 <= i - j < window,
-causal calls only) is the same kernels over a shorter table: `_live_pairs`
+causal calls only) is the same two kernels over a shorter table: `_live_pairs`
 also drops the pairs that lie wholly behind the window, so the band's
 pairs alone cost a step, and `_scores` masks the window's edge in the
 select it makes on every live pair anyway. At 16384 positions and
@@ -81,28 +85,31 @@ MAX_PAIR = MAX_BLOCK * MAX_BLOCK
 FIRST, LAST = 1, 2
 
 # The batch * head axis is independent work (cores may split it); the steps
-# of one accumulation are not. Every tile `choose_tiles` returns compiles
-# within the default scoped VMEM limit (16 MiB on a v5e; the dk/dv kernel
-# at 512 x 512 is the largest), so none is stated.
-_COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "arbitrary"))
+# of one accumulation are not.
+_DIMENSION_SEMANTICS = ("parallel", "arbitrary")
+# What a kernel may hold of VMEM unless it says otherwise (16 MiB on a
+# v5e). One step's blocks and a pair's [Bq, Bk] f32 temporaries fit in it
+# at every tile `choose_tiles` returns, so the forward states no limit; the
+# backward asks for this much beside the head's dq it keeps resident.
+SCOPED_VMEM = 16 << 20
+# The most a head's resident dq may take: half a v5e's 128 MiB of VMEM.
+MAX_RESIDENT_DQ = 64 << 20
 
 
 class KernelNames(NamedTuple):
-    """What a device trace calls the three kernels of one attention (a TPU
+    """What a device trace calls the two kernels of one attention (a TPU
     trace names a kernel by its `pallas_call`'s `name=` alone)."""
     fwd: str
-    bwd_dq: str
-    bwd_dkv: str
+    bwd: str
 
 
-PLAIN = KernelNames("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+PLAIN = KernelNames("flash_fwd", "flash_bwd_dqkv")
 # Latent attention's calls (`latent_flash_attention`): the same kernels at
 # scores wider than the values, under names of their own, so that a reader
 # of `%flash_fwd.` never counts them at one width.
-LATENT = KernelNames("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")
+LATENT = KernelNames("flash_mla_fwd", "flash_mla_bwd_dqkv")
 # Calls with a sliding window: the same kernels over the band's pairs.
-WINDOW = KernelNames("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv")
+WINDOW = KernelNames("flash_swa_fwd", "flash_swa_bwd_dqkv")
 
 
 # The forward rule's names for the kernel's two outputs, O and the row
@@ -151,9 +158,9 @@ def _live_pairs(t: Tiles, causal: bool, q_major: bool,
     the mask leaves anything of, and no step for the others. Returns
     three int32 tables indexed by step: the q block, the kv block, and the
     step's flag. `q_major` orders the steps of one q block together
-    (forward, dq: FIRST and LAST bracket a q block's accumulation);
-    otherwise those of one kv block (dk/dv). Built once per shape, from
-    Python ints."""
+    (the forward: FIRST and LAST bracket a q block's accumulation);
+    otherwise those of one kv block (the backward: dk/dv). Built once per
+    shape, from Python ints."""
     def live(qi: int, ki: int) -> bool:
         if not causal:
             return True
@@ -279,51 +286,42 @@ def _fwd_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
                                             lse_ref.shape)
 
 
-def _dq_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
-               causal: bool, has_bias: bool, has_slopes: bool, kv_len: int,
-               window: int | None):
-    refs = list(refs)
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
-    del refs[:6]
-    bias_ref = refs.pop(0) if has_bias else None
-    slope_ref = refs.pop(0) if has_slopes else None
-    dq_ref, dq_acc = refs
-
-    @pl.when(flag & FIRST != 0)
-    def _():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    q, k, v = q_ref[...], k_ref[...], v_ref[...]   # native dtype (MXU-rate dots)
-    do, o = do_ref[...], o_ref[...]
-    s = _scores(q, k, qi, ki, tiles, scale, bias_ref, slope_ref,
-                causal=causal, kv_len=kv_len, window=window)
-    # The LSE block is lane-broadcast [Bq, 128]: tiled, not re-broadcast.
-    p = jnp.exp(s - jnp.tile(lse_ref[...], (1, s.shape[1] // LANE)))  # [Bq, Bk]
-    dp = jax.lax.dot_general(                      # dO @ V^T  [Bq, Bk]
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)        # [Bq, 1]
-    ds = p * (dp - delta)                          # dlogits  [Bq, Bk] f32
-    dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(flag & LAST != 0)
-    def _():
-        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
+def _bwd_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
                 causal: bool, has_bias: bool, has_slopes: bool, kv_len: int,
                 window: int | None):
+    """dq, dk and dv of one block pair, in kv-major order: P and dS are
+    computed once and feed all three. dk and dv accumulate over a kv
+    block's column, whose steps adjoin. A q block comes back once a column,
+    so dq of the WHOLE head accumulates in `dq_acc` ([S, D] f32, resident
+    for the head's steps) and leaves at the head's last step. A q block
+    meets its kv blocks in ascending order, as a q-major pass would."""
     refs = list(refs)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     del refs[:6]
     bias_ref = refs.pop(0) if has_bias else None
     slope_ref = refs.pop(0) if has_slopes else None
-    dk_ref, dv_ref, dk_acc, dv_acc = refs
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    block_q = tiles.block_q
+
+    def q_rows(block):
+        return pl.ds(pl.multiple_of(block * block_q, block_q), block_q)
+
+    def each_q_block(fn):
+        # A loop, not one [S, D] expression: the kernel's size must not
+        # follow the sequence (2048 vregs at 16384 x 128).
+        def step(block, carry):
+            fn(q_rows(block))
+            return carry
+        jax.lax.fori_loop(0, tiles.seq // block_q, step, 0)
+
+    # The first column opens the head, the last closes it: both exist under
+    # every mask (the diagonal pair is always live).
+    @pl.when((flag & FIRST != 0) & (ki == 0))
+    def _():
+        def zero(rows):
+            dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]),
+                                        jnp.float32)
+        each_q_block(zero)
 
     @pl.when(flag & FIRST != 0)
     def _():
@@ -334,26 +332,36 @@ def _dkv_kernel(qi, ki, flag, *refs, scale: float, tiles: Tiles,
     do, o = do_ref[...], o_ref[...]
     s = _scores(q, k, qi, ki, tiles, scale, bias_ref, slope_ref,
                 causal=causal, kv_len=kv_len, window=window)
+    # The LSE block is lane-broadcast [Bq, 128]: tiled, not re-broadcast.
     p = jnp.exp(s - jnp.tile(lse_ref[...], (1, s.shape[1] // LANE)))  # [Bq, Bk]
     dv_acc[...] = dv_acc[...] + jax.lax.dot_general(   # P^T @ dO  [Bk, D]
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    dp = jax.lax.dot_general(
+    dp = jax.lax.dot_general(                      # dO @ V^T  [Bq, Bk]
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    ds = p * (dp - delta)
+                    axis=-1, keepdims=True)        # [Bq, 1]
+    ds = (p * (dp - delta)).astype(q.dtype)        # dlogits  [Bq, Bk]
     dk_acc[...] = dk_acc[...] + jax.lax.dot_general(   # dS^T @ Q  [Bk, D]
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    )
+    rows = q_rows(qi)
+    dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(  # dS @ K [Bq, D]
+        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
     )
 
     @pl.when(flag & LAST != 0)
     def _():
         dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((flag & LAST != 0) & (ki == tiles.seq // tiles.block_k - 1))
+    def _():
+        def write(rows):
+            dq_ref[rows, :] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
+        each_q_block(write)
 
 
 def _pad_inputs(q, k, v, bias):
@@ -400,7 +408,7 @@ def _interpret() -> bool:
 
 
 def _call(body, name: str, pairs, operands, *, in_specs, out_shape,
-          out_specs, scratch, **statics):
+          out_specs, scratch, vmem_limit_bytes: int | None = None, **statics):
     """One `pallas_call` over the live pairs: grid (batch * head, step);
     each step looks its pair up in the prefetched tables of `_live_pairs`
     and runs `body(qi, ki, flag, *refs, **statics)` on it.
@@ -432,7 +440,9 @@ def _call(body, name: str, pairs, operands, *, in_specs, out_shape,
             out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM((rows, width), jnp.float32)
                             for rows, width in scratch]),
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_DIMENSION_SEMANTICS,
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
         name=name,
     )(*pairs, *operands)
@@ -457,6 +467,12 @@ def _q_rows(rows: int, width: int):
 def _k_rows(rows: int, width: int):
     return pl.BlockSpec((None, rows, width),
                         lambda b_, s, q_of, k_of, flag_of: (b_, k_of[s], 0))
+
+
+def _head_rows(rows: int, width: int):
+    # A head's whole [S, width]: the block does not move along the steps.
+    return pl.BlockSpec((None, rows, width),
+                        lambda b_, s, q_of, k_of, flag_of: (b_, 0, 0))
 
 
 def _bias_specs(has_bias: bool, h: int, t: Tiles):
@@ -532,31 +548,34 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
     if has_slopes:
         slopes = jnp.asarray(slopes, jnp.float32).reshape(h, 1, 1)
 
-    common = ([qp, kp, vp, op, gp, lse] + ([bias] if has_bias else [])
-              + ([slopes] if has_slopes else []))
+    operands = ([qp, kp, vp, op, gp, lse] + ([bias] if has_bias else [])
+                + ([slopes] if has_slopes else []))
+    # dq of one head stays in VMEM while the head's steps run: the f32
+    # accumulator and the two buffers of the output block it is rounded to.
+    resident = sp * dp * (4 + 2 * q.dtype.itemsize)
+    if resident > MAX_RESIDENT_DQ:
+        raise ValueError(
+            f"the flash backward keeps a head's dq [{sp}, {dp}] in VMEM: "
+            f"{resident} bytes are over {MAX_RESIDENT_DQ}; split the "
+            "sequence (ring attention) or use the XLA path")
     # Gradients leave in the operands' dtype: one rounding from the f32
     # accumulator, here and not in a cast after the kernel.
     grad_shape = lambda x, width: _out_struct((bh, sp, width), x.dtype,
-                                              *common)
-    shared = dict(
+                                              *operands)
+    dq, dk, dv_ = _call(
+        _bwd_kernel, names.bwd,
+        _live_pairs(t, causal, q_major=False, window=window), operands,
         in_specs=([_q_rows(t.block_q, dp), _k_rows(t.block_k, dp),
                    _k_rows(t.block_k, dvp), _q_rows(t.block_q, dvp),
                    _q_rows(t.block_q, dvp), _q_rows(t.block_q, LANE)]
                   + _bias_specs(has_bias, h, t) + _slope_specs(has_slopes, h)),
+        out_shape=(grad_shape(q, dp), grad_shape(k, dp), grad_shape(v, dvp)),
+        out_specs=(_head_rows(sp, dp), _k_rows(t.block_k, dp),
+                   _k_rows(t.block_k, dvp)),
+        scratch=[(sp, dp), (t.block_k, dp), (t.block_k, dvp)],
+        vmem_limit_bytes=resident + SCOPED_VMEM,
         scale=scale, tiles=t, causal=causal, has_bias=has_bias,
         has_slopes=has_slopes, kv_len=s_len, window=window)
-
-    dq = _call(
-        _dq_kernel, names.bwd_dq,
-        _live_pairs(t, causal, q_major=True, window=window), common,
-        out_shape=grad_shape(q, dp), out_specs=_q_rows(t.block_q, dp),
-        scratch=[(t.block_q, dp)], **shared)
-    dk, dv_ = _call(
-        _dkv_kernel, names.bwd_dkv,
-        _live_pairs(t, causal, q_major=False, window=window), common,
-        out_shape=(grad_shape(k, dp), grad_shape(v, dvp)),
-        out_specs=(_k_rows(t.block_k, dp), _k_rows(t.block_k, dvp)),
-        scratch=[(t.block_k, dp), (t.block_k, dvp)], **shared)
 
     def unpad(x, width):
         return x.reshape(b, h, sp, x.shape[-1])[:, :, :s_len, :width]
@@ -659,7 +678,7 @@ def latent_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
     against 128; `_pad_inputs` pads each to the lane width by itself),
     under `LATENT`'s names. The shared key is broadcast over the heads
     outside the kernels (`attention.latent_qk`), so its gradient is the sum
-    over the heads of what the dk/dv kernel gives each."""
+    over the heads of what the backward kernel gives each."""
     from oobleck_tpu.ops.attention import latent_qk
 
     q, k = latent_qk(q_nope, q_rope, k_nope, k_rope)

@@ -117,15 +117,14 @@ def _kernels(fn, *args):
 def test_names_of_both_families():
     plain = [jnp.zeros((1, 2, 256, 80), jnp.bfloat16)] * 3
     calls = dict(_kernels(flash.flash_attention, *plain))
-    assert set(calls) == set(flash.PLAIN) == {
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert set(calls) == set(flash.PLAIN) == {"flash_fwd", "flash_bwd_dqkv"}
     assert set(map(tuple, calls["flash_fwd"])) == {(2, 256, 128)}
     latent = [jnp.zeros(s, jnp.bfloat16) for s in (
         (1, 2, 256, 128), (1, 2, 256, 64), (1, 2, 256, 128), (1, 256, 64),
         (1, 2, 256, 128))]
     calls = dict(_kernels(flash.latent_flash_attention, *latent))
     assert set(calls) == set(flash.LATENT) == {
-        "flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"}
+        "flash_mla_fwd", "flash_mla_bwd_dqkv"}
     # No name of the one family matches the other's trace pattern.
     for name in flash.LATENT:
         assert not name.startswith(("flash_fwd", "flash_bwd_"))
@@ -133,7 +132,7 @@ def test_names_of_both_families():
     # not padded to the scores' width.
     assert calls["flash_mla_fwd"] == [(2, 256, 256), (2, 256, 256),
                                       (2, 256, 128)]
-    q, k, v, o, do, lse = calls["flash_mla_bwd_dkv"]
+    q, k, v, o, do, lse = calls["flash_mla_bwd_dqkv"]
     assert (q, k, v, o, do) == ((2, 256, 256), (2, 256, 256), (2, 256, 128),
                                 (2, 256, 128), (2, 256, 128))
 
@@ -149,8 +148,7 @@ def test_one_width_calls_are_what_they_were(seq, head_dim):
     assert qp.shape == kp.shape == vp.shape == (6, t.seq, wide)
     assert info == (2, 3, seq, head_dim, head_dim, 6, t.seq, wide, wide)
     calls = _kernels(flash.flash_attention, q, q, q)
-    assert sorted(n for n, _ in calls) == [
-        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert sorted(n for n, _ in calls) == ["flash_bwd_dqkv", "flash_fwd"]
     for _, shapes in calls:
         assert set(shapes) <= {(6, t.seq, wide), (6, t.seq, 128)}
     steps = len(flash._live_pairs(t, True, True)[0])

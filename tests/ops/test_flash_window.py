@@ -1,5 +1,5 @@
 """A sliding window in the flash kernels (`ops/flash.py`, `window=`): the
-three kernels in interpret mode against `_xla_causal_attention` under the
+two kernels in interpret mode against `_xla_causal_attention` under the
 band mask, output and all three gradients, for windows smaller than a
 block, equal to one, not a multiple of one, a multiple, and at least the
 sequence; the table of live block pairs at the benchmark cell's sizes;
@@ -159,7 +159,7 @@ def test_window_calls_go_out_under_names_of_their_own():
     plain = [n for n, _ in pallas_calls(jax.make_jaxpr(
         lambda q, k, v: flash.flash_attention(q, k, v))(q, k, v).jaxpr)]
     assert plain == ["flash_fwd"]
-    assert len(set(flash.WINDOW + flash.PLAIN + flash.LATENT)) == 9
+    assert len(set(flash.WINDOW + flash.PLAIN + flash.LATENT)) == 6
 
 
 def test_live_pairs_gauge_reads_the_last_call_s_grid_steps():
@@ -170,7 +170,7 @@ def test_live_pairs_gauge_reads_the_last_call_s_grid_steps():
     jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
         flash.flash_attention(q, k, v, window=512))))(q)
     jax.make_jaxpr(lambda q: flash.flash_attention(q, k, v))(q)
-    assert [gauge.value(kernel=n) for n in flash.WINDOW] == [7, 7, 7]
+    assert [gauge.value(kernel=n) for n in flash.WINDOW] == [7, 7]
     assert gauge.value(kernel="flash_fwd") == 10
 
 

@@ -223,9 +223,8 @@ def test_kept_residuals_halve_the_forward_calls_and_keep_the_gradients(
         jax.make_jaxpr(fn)(w, x).jaxpr)]
     assert calls(bare).count(names.fwd) == 4
     assert calls(kept).count(names.fwd) == 2
-    for fn in (bare, kept):     # the backward kernels: once a block
-        assert calls(fn).count(names.bwd_dq) == 2
-        assert calls(fn).count(names.bwd_dkv) == 2
+    for fn in (bare, kept):     # the ONE backward kernel: once a block
+        assert calls(fn).count(names.bwd) == 2
     # A kept value is the value a second call would have written.
     for got, want in zip(jax.tree.leaves(jax.jit(kept)(w, x)),
                          jax.tree.leaves(jax.jit(bare)(w, x))):

@@ -58,32 +58,54 @@ def _grads(fn, *args):
     return jax.grad(lambda *a: jnp.sum(fn(*a) ** 2), argnums=(0, 1, 2))(*args)
 
 
-@pytest.mark.parametrize("shape,causal,with_bias", [
-    ((2, 4, 256, 64), True, False),    # aligned causal
-    ((2, 4, 200, 48), True, False),    # unaligned seq + head
-    ((2, 4, 256, 64), True, True),     # ALiBi-style bias
-    ((2, 4, 200, 48), True, True),     # unaligned + bias
-    ((2, 4, 256, 64), False, False),   # bidirectional (encoder)
-    ((2, 4, 200, 48), False, True),    # bidirectional + bias, unaligned
+@pytest.mark.parametrize("shape,causal,with_bias,window,v_width", [
+    ((2, 4, 256, 64), True, False, None, None),    # aligned causal
+    ((2, 4, 200, 48), True, False, None, None),    # unaligned seq + head
+    ((2, 4, 256, 64), True, True, None, None),     # ALiBi-style bias
+    ((2, 4, 200, 48), True, True, None, None),     # unaligned + bias
+    ((2, 4, 256, 64), False, False, None, None),   # bidirectional (encoder)
+    ((2, 4, 200, 48), False, True, None, None),    # bidirectional + bias, unaligned
+    # The resident dq. Four kv columns of 512 under a window of 700: a
+    # column's band spans three q blocks, so a q block's rows are added to
+    # in three columns, steps apart; two heads of two sequences, so the
+    # accumulator opens and closes four times.
+    ((2, 2, 2048, 64), True, False, 700, None),
+    # One kv column of 640 against five q blocks of 128: the head's first
+    # step is its column's first, its last the column's last.
+    ((2, 2, 640, 48), True, False, None, None),
+    ((2, 2, 640, 48), False, True, None, None),
+    # One 128-row block: the head opens and closes in the one step.
+    ((2, 2, 128, 64), True, False, None, None),
+    # Latent widths: scores 192 wide (256 in the kernel), values 128; dq
+    # and dk 256 wide beside a 128-wide dv, over 3 live pairs.
+    ((2, 2, 1024, 192), True, False, None, 128),
 ])
-def test_flash_bwd_kernel_matches_xla(shape, causal, with_bias):
-    """The Pallas dq/dk/dv kernels against XLA autodiff, every shape class."""
+def test_flash_bwd_kernel_matches_xla(shape, causal, with_bias, window,
+                                      v_width):
+    """The one Pallas backward kernel (dq, dk, dv) against XLA autodiff,
+    every shape class."""
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
     q, k, v = (jax.random.normal(kk, shape, jnp.float32) * 0.3 for kk in ks[:3])
+    v = v[..., :v_width]
     bias = None
     if with_bias:
         from oobleck_tpu.ops.attention import alibi_bias
 
         bias = alibi_bias(shape[1], shape[2], shape[2])
-    want_o = _xla_causal_attention(q, k, v, bias=bias, causal=causal)
-    got_o = flash_attention(q, k, v, bias=bias, causal=causal)
-    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+    if v_width is None:
+        kernel = lambda q, k, v: flash_attention(q, k, v, bias=bias,
+                                                 causal=causal, window=window)
+    else:
+        from oobleck_tpu.ops import flash
+
+        kernel = lambda q, k, v: flash._flash(
+            q, k, v, None, None, shape[-1] ** -0.5, True, flash.LATENT, None)
+    reference = lambda q, k, v: _xla_causal_attention(
+        q, k, v, bias=bias, causal=causal, window=window)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(reference(q, k, v)),
                                rtol=2e-3, atol=2e-3)
-    g1 = _grads(lambda q, k, v: flash_attention(q, k, v, bias=bias,
-                                                causal=causal), q, k, v)
-    g2 = _grads(lambda q, k, v: _xla_causal_attention(q, k, v, bias=bias,
-                                                      causal=causal), q, k, v)
-    for a, b in zip(g1, g2):
+    for a, b in zip(_grads(kernel, q, k, v), _grads(reference, q, k, v)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-2, atol=2e-3)
 
