@@ -313,20 +313,18 @@ class ServeArguments:
     newest committed step while serving."""
 
     port: int = 0                 # HTTP port; 0 = ephemeral (tests)
-    slots: int = 4                # dense slots / paged memory-budget unit
+    slots: int = 4                # memory-budget unit: slots * max_seq tokens
     max_seq: int = 256            # per-request length cap (prompt + gen)
     max_queue: int = 64           # bounded admission queue; full -> reject
     reload_secs: float = 5.0      # checkpoint-watcher poll period
     max_tokens_default: int = 64  # per-request cap when unspecified
     # Paged KV cache (serve/kv_blocks.py + ops/paged_attention.py).
-    # "paged" is the default discipline; "dense" restores the slot cache.
-    kv_cache: str = "paged"
     page_size: int = 16           # tokens per KV pool page
     kv_pages: int = 0             # pool pages incl. garbage page; 0 = auto
     #                               (slots * max_seq / page_size — the same
     #                               HBM budget the dense cache would take)
     lanes: int = 0                # paged decode batch width; 0 = auto
-    # Speculative multi-token decode (serve/speculative.py; paged only).
+    # Speculative multi-token decode (serve/speculative.py).
     # "off" keeps the one-token step; "lookup" = model-free prompt-lookup
     # drafting; "draft" = second-checkpoint draft model (needs
     # spec_draft_root, falls back to lookup without one).
@@ -340,7 +338,7 @@ class ServeArguments:
     def apply_serve_env_overrides(self) -> None:
         """Deployment-property overrides, same contract as the durable
         plane's: OOBLECK_SERVE_PORT, OOBLECK_SERVE_SLOTS,
-        OOBLECK_SERVE_RELOAD_SECS, OOBLECK_SERVE_KV_CACHE,
+        OOBLECK_SERVE_RELOAD_SECS,
         OOBLECK_SERVE_PAGE_SIZE, OOBLECK_SERVE_KV_PAGES,
         OOBLECK_SERVE_LANES, OOBLECK_SERVE_SPEC, OOBLECK_SERVE_SPEC_K,
         OOBLECK_SERVE_SPEC_MIN_ACCEPT, OOBLECK_SERVE_SPEC_NGRAM,
@@ -357,9 +355,6 @@ class ServeArguments:
         v = os.environ.get("OOBLECK_SERVE_RELOAD_SECS")
         if v:
             self.reload_secs = float(v)
-        v = os.environ.get("OOBLECK_SERVE_KV_CACHE")
-        if v:
-            self.kv_cache = v
         v = os.environ.get("OOBLECK_SERVE_PAGE_SIZE")
         if v:
             self.page_size = int(v)

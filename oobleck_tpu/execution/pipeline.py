@@ -16,7 +16,7 @@ single-controller JAX runtime:
   * the 1F1B instruction streams (execution.schedule) are interpreted by a
     dependency-driven loop; backward recomputes the stage forward inside the
     jitted VJP, so only per-microbatch stage *inputs* are stashed, as in
-    1F1B. Inside that VJP a layer's checkpoint (`ops/flash.checkpoint_layer`)
+    1F1B. Inside that VJP a layer's checkpoint (`ops/remat.checkpoint_layer`)
     keeps the layer's input and what the flash forward kernel wrote (O and
     the row logsumexp) and recomputes the rest: the forward kernel runs once
     a layer in `jit_bwd`, and a layer on the XLA path keeps its input
@@ -117,23 +117,6 @@ def canonical_order(S: int, M: int, v: int = 1) -> list[Instruction]:
                 progress = True
     _ORDER_CACHE[key] = order
     return order
-
-
-def _fit_spec(spec: P, shape: tuple, mesh: Mesh) -> P:
-    """Clear spec entries whose mesh-axis product doesn't divide the dim."""
-    out = []
-    for d, entry in enumerate(spec):
-        names = entry if isinstance(entry, tuple) else (
-            (entry,) if entry is not None else ()
-        )
-        size = 1
-        for n in names:
-            size *= mesh.shape[n]
-        if not names or shape[d] % size == 0:
-            out.append(entry)
-        else:
-            out.append(None)
-    return P(*out)
 
 
 def _project_spec(spec: P, keep: frozenset) -> P:
@@ -412,25 +395,6 @@ class PipelineInstance:
                     else _specs["head"] if name == "head"
                     else _specs["blocks"]
                 )
-        elif hasattr(model, "generic_param_specs"):
-            # Generic-path models may still declare per-layer shardings
-            # (e.g. MoE expert dims over the fsdp axis — GSPMD then runs
-            # the expert einsums as true expert parallelism and inserts the
-            # combine psum itself). Axes that don't divide a leaf's dim are
-            # cleared per-stage below (shapes cached: eval_shape per layer
-            # runs once, not once per stage per use).
-            _shape_cache: dict[int, Any] = {}
-
-            def layer_shapes(li: int):
-                if li not in _shape_cache:
-                    _shape_cache[li] = jax.eval_shape(
-                        lambda r, _li=li: model.init_layer(r, _li),
-                        jax.random.PRNGKey(0),
-                    )
-                return _shape_cache[li]
-
-            def spec_tree(li: int):
-                return model.generic_param_specs(li)
         else:
             _spec_rng = jax.random.PRNGKey(0)
 
@@ -485,14 +449,9 @@ class PipelineInstance:
                 stage_devices.reshape(fsdp_deg, sp, tp),
                 ("fsdp", "seq", "tensor"),
             )
-            generic_specs = hasattr(model, "generic_param_specs")
             keep = frozenset(
                 a for a, on in (
-                    # Generic-spec (plain-jit GSPMD) params may shard over
-                    # the fsdp axis even when the BATCH cannot (use_fsdp
-                    # False) — manual shard_map programs may not, their
-                    # in_specs are coupled to the batch layout.
-                    ("fsdp", fsdp_deg > 1 if generic_specs else use_fsdp),
+                    ("fsdp", use_fsdp),
                     ("tensor", tp > 1),
                 ) if on
             )
@@ -511,14 +470,6 @@ class PipelineInstance:
                     spec_tree(li),
                     is_leaf=lambda x: isinstance(x, P),
                 )
-                if generic_specs:
-                    # Clear axis entries that don't divide the leaf dim
-                    # (e.g. 3 experts over a 2-way fsdp axis -> replicate).
-                    pspecs = jax.tree.map(
-                        lambda s, sh: _fit_spec(s, sh.shape, mesh),
-                        pspecs, layer_shapes(li),
-                        is_leaf=lambda x: isinstance(x, P),
-                    )
                 param_pspecs[li] = pspecs
                 param_shardings[li] = jax.tree.map(
                     lambda s: NamedSharding(mesh, s),

@@ -42,17 +42,6 @@ register("gpt3-6.7b")(lambda o: _gpt(o, hidden_size=4096, num_layers=32, num_hea
 register("gpt2-tiny")(lambda o: _gpt(o, vocab_size=256, hidden_size=64, num_layers=4, num_heads=4, max_position_embeddings=128))
 
 
-def _moe(overrides: dict[str, Any], **preset):
-    from oobleck_tpu.models.moe import MoEGPTConfig, MoEGPTModel
-
-    return MoEGPTModel(MoEGPTConfig().override(**preset).override(**overrides))
-
-
-# Mixture-of-experts decoders (BEYOND reference: no MoE exists there).
-register("gpt2-moe")(lambda o: _moe(o, hidden_size=768, num_layers=12, num_heads=12, num_experts=8))
-register("gpt2-moe-tiny")(lambda o: _moe(o, vocab_size=256, hidden_size=64, num_layers=4, num_heads=4, max_position_embeddings=128, num_experts=4))
-
-
 def _lfm2(overrides: dict[str, Any], **preset):
     from oobleck_tpu.models.lfm2 import Lfm2Config, Lfm2Model
 

@@ -1,12 +1,13 @@
-"""TPU compute kernels: attention (XLA, Pallas flash, ring, Ulysses) and
-switch-MoE with expert parallelism. Heavy submodules import lazily at their
-call sites; this surface re-exports the dispatching entry points."""
+"""TPU compute kernels: attention (XLA, Pallas flash, ring, Ulysses), the
+routed experts and the layers' checkpoint policy. Heavy submodules import
+lazily at their call sites; this surface re-exports the dispatching entry
+points."""
 
 from oobleck_tpu.ops.attention import causal_attention, select_attention_impl
 
 
 def checkpoint_layer(fn, **kwargs):
-    from oobleck_tpu.ops.flash import checkpoint_layer as wrap
+    from oobleck_tpu.ops.remat import checkpoint_layer as wrap
 
     return wrap(fn, **kwargs)
 
@@ -23,11 +24,5 @@ def ulysses_attention(*args, **kwargs):
     return fn(*args, **kwargs)
 
 
-def switch_moe(*args, **kwargs):
-    from oobleck_tpu.ops.moe import switch_moe as fn
-
-    return fn(*args, **kwargs)
-
-
 __all__ = ["causal_attention", "select_attention_impl", "checkpoint_layer",
-           "ring_attention", "ulysses_attention", "switch_moe"]
+           "ring_attention", "ulysses_attention"]

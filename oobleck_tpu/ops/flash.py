@@ -69,8 +69,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from oobleck_tpu.ops import gdn
-
 NEG_INF = -1e9
 
 LANE = 128
@@ -115,18 +113,6 @@ WINDOW = KernelNames("flash_swa_fwd", "flash_swa_bwd_dqkv")
 # The forward rule's names for the kernel's two outputs, O and the row
 # logsumexp: the residuals that only a second kernel call could give back.
 RESIDUAL_NAMES = ("flash_out", "flash_lse")
-
-
-def checkpoint_layer(fn, **kwargs):
-    """`jax.checkpoint` for a layer, whatever its body can reach: the
-    backward pass recomputes the layer from its input, all but what the op
-    modules' forward rules named: what the flash forward kernel wrote, the
-    delta rule's inverse (`ops/gdn.RESIDUAL_NAMES`). A layer whose body
-    emits no value by a name (attention on the XLA path, no delta rule)
-    keeps nothing by it."""
-    return jax.checkpoint(
-        fn, policy=jax.checkpoint_policies.save_only_these_names(
-            *RESIDUAL_NAMES, *gdn.RESIDUAL_NAMES), **kwargs)
 
 
 class Tiles(NamedTuple):

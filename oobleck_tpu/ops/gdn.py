@@ -28,7 +28,7 @@ them (under the layer's checkpoint like every other layer), but for the
 inverse, whose gradient is written down (`-X^T dX X^T`: two products a head
 and chunk where the series' own would be eighteen) and whose forward rule
 NAMES it (`RESIDUAL_NAMES`), so that a layer's checkpoint
-(`ops/flash.checkpoint_layer`) keeps it and the recomputed forward holds no
+(`ops/remat.checkpoint_layer`) keeps it and the recomputed forward holds no
 series: the one value of the rule that only `2 (log2 Q - 1)` more float32
 products could give back. No Pallas kernel: `D`, `A` and `T` are written
 out, [Q, Q] float32 blocks a head and chunk, and the traffic that costs is

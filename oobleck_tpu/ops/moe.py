@@ -1,18 +1,24 @@
-"""Switch-style mixture-of-experts with expert parallelism.
+"""Routed experts: dropless top-k over a held range of the experts.
 
-BEYOND-reference capability (SURVEY §2.2 "EP: absent" — absent in the
-reference too): a top-1 switch MoE MLP (Switch Transformer routing:
-per-token argmax expert, static capacity, load-balancing aux loss)
-formulated entirely as dense einsums over STATIC shapes — the TPU
-discipline: no gather/scatter, no data-dependent shapes, everything lands
-on the MXU.
+The expert layer of the repo. `routed_experts` drops no token and does
+work for the (token, slot) pairs that are routed to the experts this chip
+HOLDS: pairs are laid out by expert in one buffer of the static worst-case
+length, each expert's rows padded to a whole number of row tiles, and the
+products (three of a SwiGLU expert, two of an expert without a gate) run as
+grouped matrix multiplications that visit the tiles in use and skip the
+empty tail. What XLA does around them (rows in and out of the buffer, the
+activation) loops over the tiles in use too, so no work follows the
+buffer's length but its zero fill. Kernels (stable names on the
+`pallas_call`, so a device trace shows `%moe_gmm.N` / `%moe_tgmm.N`):
 
-Expert parallelism shards the expert dimension over a mesh axis: each
-device holds NE/P experts, computes its experts' outputs from the
-(replicated) token stream, and one `psum` combines — the dispatch/combine
-einsums are cheap relative to the expert FFNs, so this trades a little
-redundant routing math for zero all-to-all choreography. Exactness vs the
-unsharded formulation is tested under shard_map on the CPU mesh.
+  moe_gmm   rows [M, K] x experts [E, K, N] -> [M, N]   forward, and dX
+            with the expert matrices read transposed
+  moe_tgmm  rows^T [M, K] x rows [M, N] -> [E, K, N]    dW, on top of
+            a running gradient sum where the caller hands one (`GradSum`)
+
+On one chip nothing is exchanged; with `num_experts_held` < `num_experts`
+the result is this chip's PART of the layer's output (the partial sums
+of all shares add up to the uncut layer, tests/ops/test_routed_experts.py).
 """
 
 from __future__ import annotations
@@ -28,109 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def switch_moe(
-    x: jax.Array,
-    router_w: jax.Array,
-    w1: jax.Array,
-    b1: jax.Array,
-    w2: jax.Array,
-    b2: jax.Array,
-    *,
-    num_experts: int,
-    capacity_factor: float = 1.25,
-    axis_name: str | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Top-1 switch MoE over the token stream.
-
-    x: [B, S, M] tokens; router_w: [M, NE] (always the GLOBAL expert
-    count); w1/b1/w2/b2: this shard's experts — [NE_local, M, F] /
-    [NE_local, F] / [NE_local, F, M] / [NE_local, M]. Without `axis_name`,
-    NE_local == num_experts (unsharded). Returns (y [B, S, M], aux_loss) —
-    aux is the Switch load-balancing loss over the global router
-    distribution (identical on every shard).
-    """
-    B, S, M = x.shape
-    T = B * S
-    NE = num_experts
-    ne_local = w1.shape[0]
-    xf = x.reshape(T, M)
-
-    logits = (xf.astype(jnp.float32)
-              @ router_w.astype(jnp.float32))          # [T, NE]
-    probs = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)                # [T]
-    gate = jnp.max(probs, axis=-1)                     # [T]
-
-    # Static per-expert capacity; tokens beyond it are DROPPED (pass
-    # through the residual only), the standard switch behavior.
-    capacity = max(1, int(capacity_factor * T / NE))
-    onehot = jax.nn.one_hot(expert, NE, dtype=jnp.float32)      # [T, NE]
-    position = (jnp.cumsum(onehot, axis=0) - 1.0) * onehot      # [T, NE]
-    keep = (position < capacity).astype(jnp.float32) * onehot
-    slot = jax.nn.one_hot(
-        position.sum(-1).astype(jnp.int32), capacity, dtype=jnp.float32
-    )                                                           # [T, C]
-    dispatch = keep[:, :, None] * slot[:, None, :]              # [T, NE, C]
-
-    # Local expert slice of the dispatch tensor (EP: this shard computes
-    # only its experts; the trailing psum restores the full combine).
-    if axis_name is not None:
-        offset = lax.axis_index(axis_name) * ne_local
-        local_dispatch = lax.dynamic_slice_in_dim(
-            dispatch, offset, ne_local, axis=1
-        )
-    else:
-        assert ne_local == NE, (ne_local, NE)
-        local_dispatch = dispatch
-
-    dt = x.dtype
-    inp = jnp.einsum("tec,tm->ecm", local_dispatch.astype(dt), xf)
-    h = jax.nn.gelu(jnp.einsum("ecm,emf->ecf", inp, w1.astype(dt))
-                    + b1.astype(dt)[:, None, :])
-    # Unoccupied slots never appear in the combine (their dispatch weights
-    # are zero), so the bias can be added unconditionally.
-    out = jnp.einsum("ecf,efm->ecm", h, w2.astype(dt)) + b2.astype(dt)[:, None, :]
-    combine = (local_dispatch * gate[:, None, None]).astype(dt)
-    y = jnp.einsum("tec,ecm->tm", combine, out)
-    if axis_name is not None:
-        y = lax.psum(y, axis_name)
-
-    # Switch load-balancing loss: NE * sum_e(fraction_routed_e * mean_prob_e)
-    # over the GLOBAL distribution (router inputs are replicated, so this is
-    # identical on every shard — no collective needed).
-    fraction = onehot.mean(axis=0)
-    mean_prob = probs.mean(axis=0)
-    aux = NE * jnp.sum(fraction * mean_prob)
-    return y.reshape(B, S, M), aux.astype(jnp.float32)
-
-
-# ===================================================================== #
-# Routed experts: dropless top-k over a held range of the experts        #
-# ===================================================================== #
-#
-# The other expert layer of the repo. Where `switch_moe` builds a dense
-# [T, NE, C] one-hot and drops what exceeds a capacity, `routed_experts`
-# drops nothing and does work for the (token, slot) pairs that are routed
-# to the experts this chip HOLDS: pairs are laid out by expert in one
-# buffer of the static worst-case length, each expert's rows padded to a
-# whole number of row tiles, and the products (three of a SwiGLU expert, two
-# of an expert without a gate) run as grouped matrix
-# multiplications that visit the tiles in use and skip the empty tail. What
-# XLA does around them (rows in and out of the buffer, the activation) loops
-# over the tiles in use too, so no work follows the buffer's length but
-# its zero fill. Kernels (stable names on the `pallas_call`, so a device trace shows
-# `%moe_gmm.N` / `%moe_tgmm.N`):
-#
-#   moe_gmm   rows [M, K] x experts [E, K, N] -> [M, N]   forward, and dX
-#             with the expert matrices read transposed
-#   moe_tgmm  rows^T [M, K] x rows [M, N] -> [E, K, N]    dW, on top of
-#             a running gradient sum where the caller hands one (`GradSum`)
-#
-# On one chip nothing is exchanged; with `num_experts_held` < `num_experts`
-# the result is this chip's PART of the layer's output (the partial sums
-# of all shares add up to the uncut layer, tests/ops/test_routed_experts.py).
 
 LANE = 128
 MAX_ROW_TILE = 512        # rows of one tile: what an expert's rows pad to

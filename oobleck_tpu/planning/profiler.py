@@ -233,7 +233,7 @@ def profile_execution_layers(model, microbatch_size: int, seq_len: int | None = 
             # real backward. jax.vjp re-runs the forward inside, so this cost
             # includes recompute, as execution under a layer's checkpoint
             # does; the flash forward kernel runs once here and once there
-            # (its O and LSE are kept: ops/flash.checkpoint_layer).
+            # (its O and LSE are kept: ops/remat.checkpoint_layer).
             def bwd(ct, x, p, i=idx):
                 _, vjp = jax.vjp(
                     lambda x_, p_: model.apply_layer(i, p_, x_, batch), x, p

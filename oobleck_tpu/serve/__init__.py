@@ -7,7 +7,7 @@ server bound to a live training job's checkpoint root
 (`OOBLECK_CKPT_DIR`) hot-reloads the newest committed step while
 serving, without dropping in-flight requests.
 
-    engine.py    DecodeEngine — KV cache + jitted prefill/decode
+    engine.py    PagedDecodeEngine — KV pool + jitted prefill/decode
                  (persistent-compile-cache routed, cache donated)
     batcher.py   ContinuousBatcher — bounded admission queue, slot
                  scheduling between decode steps, backpressure
@@ -31,7 +31,7 @@ import time
 
 from oobleck_tpu.config import ServeArguments
 from oobleck_tpu.serve.batcher import ContinuousBatcher, GenRequest, QueueFull
-from oobleck_tpu.serve.engine import DecodeEngine, PagedDecodeEngine
+from oobleck_tpu.serve.engine import PagedDecodeEngine
 from oobleck_tpu.serve.kv_blocks import BlockAllocator, PagesExhausted
 from oobleck_tpu.serve.reload import (
     CheckpointWatcher,
@@ -43,7 +43,7 @@ from oobleck_tpu.serve.server import ServeHTTPServer
 
 __all__ = [
     "BlockAllocator", "CheckpointWatcher", "ContinuousBatcher",
-    "DecodeEngine", "GenRequest", "PagedDecodeEngine", "PagesExhausted",
+    "GenRequest", "PagedDecodeEngine", "PagesExhausted",
     "QueueFull", "ServeArguments", "ServeHTTPServer", "ServingPlane",
     "load_latest_params", "params_from_payload", "publish_params",
 ]
@@ -77,7 +77,7 @@ class ServingPlane:
         self.router_url = router_url \
             if router_url is not None \
             else (os.environ.get("OOBLECK_ROUTER_URL") or None)
-        self.engine: DecodeEngine | None = None
+        self.engine: PagedDecodeEngine | None = None
         self.batcher: ContinuousBatcher | None = None
         self.watcher: CheckpointWatcher | None = None
         self.server: ServeHTTPServer | None = None
@@ -112,16 +112,11 @@ class ServingPlane:
         return build_model(name, margs)
 
     def _build_engine(self, model, max_seq: int):
-        """kv_cache="paged" (default): block/paged pool sized to the SAME
-        HBM budget the dense slot cache would take (slots * max_seq
-        tokens), with the decode width (`lanes`) freed from that budget —
-        short requests no longer pay a max_seq reservation. "dense"
-        restores the slot cache."""
+        """A paged pool sized to the HBM budget a dense slot cache would
+        take (slots * max_seq tokens), with the decode width (`lanes`)
+        freed from that budget: short requests pay no max_seq
+        reservation."""
         a = self.args
-        if a.kv_cache == "dense":
-            return DecodeEngine(model, slots=a.slots, max_seq=max_seq)
-        if a.kv_cache != "paged":
-            raise ValueError(f"unknown kv_cache {a.kv_cache!r}")
         page = a.page_size
         num_pages = a.kv_pages or max(2, a.slots * max_seq // page)
         lanes = a.lanes or max(a.slots, min(num_pages - 1, 8 * a.slots))
@@ -130,9 +125,9 @@ class ServingPlane:
 
     def _build_spec(self):
         """Speculative-decode controller from the serve args; None when
-        speculation is off or the engine has no multi-token verify path
-        (dense engines). Warms the fixed-width verify program so the
-        first drafting request doesn't pay a compile."""
+        speculation is off or the engine has no multi-token verify path.
+        Warms the fixed-width verify program so the first drafting request
+        doesn't pay a compile."""
         a = self.args
         if a.speculation == "off" \
                 or not getattr(self.engine, "supports_verify", False):

@@ -1,19 +1,19 @@
-"""Decode engines: jitted prefill/decode over dense-slot or paged KV state.
+"""Decode engines: jitted prefill/decode over paged or dense-slot KV state.
 
 An engine owns the device-side serving state for one model: the current
 weights (swappable between decode steps), the KV cache, and the compiled
-prefill/decode executables. Two cache disciplines:
+prefill/decode executables. The serving plane builds one kind:
 
-  DecodeEngine       dense slots — `[L, slots, H, max_seq, D]`, HBM per
-                     slot scales with max_seq regardless of actual
-                     lengths. Kept as the reference the paged engine's
-                     tests compare against.
   PagedDecodeEngine  block/paged — `[L, N_pages, Hkv, page, D]` pool,
                      per-request page chains (serve/kv_blocks.py), ragged
                      paged attention (ops/paged_attention.py), prefix
                      reuse. HBM per request is its true token span, so
                      concurrency is bounded by total live tokens, not by
                      a handful of max_seq reservations.
+  DecodeEngine       dense slots — `[L, slots, H, max_seq, D]`, HBM per
+                     slot scales with max_seq regardless of actual
+                     lengths. Nothing serves from it: it is the reference
+                     the paged engine's tests compare against.
 
 Prompt lengths are padded to a small set of power-of-two buckets so the
 number of distinct prefill programs is O(log max_seq) instead of one per
@@ -123,7 +123,10 @@ class _EngineBase:
 
 
 class DecodeEngine(_EngineBase):
-    """Dense-slot serving state: weights + slot KV cache + compiled steps."""
+    """Dense-slot state: weights + slot KV cache + compiled steps. The
+    reference that the tests of the paged engine, of the prefill kernels
+    and of the programs' names compare against, and nothing else: the
+    serving plane builds `PagedDecodeEngine` only."""
 
     def __init__(self, model, *, slots: int, max_seq: int,
                  prefill_buckets: tuple[int, ...] | None = None):

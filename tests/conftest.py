@@ -38,9 +38,9 @@ import pytest
 # finishes them alone while the others idle (minutes, against a fixed wall
 # budget); a serial run is cut inside tests/execution/ either way. So they
 # run right after the engine tests, and the many fast tests fill the tail.
-_HEAVY_LATE = ("tests/models/", "tests/serve/", "tests/test_moe.py",
-               "tests/test_ops.py", "tests/test_overlap.py",
-               "tests/test_smoke.py", "tests/test_train_spmd.py")
+_HEAVY_LATE = ("tests/models/", "tests/serve/", "tests/test_ops.py",
+               "tests/test_overlap.py", "tests/test_smoke.py",
+               "tests/test_train_spmd.py")
 
 
 def pytest_configure(config):

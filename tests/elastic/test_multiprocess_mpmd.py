@@ -246,16 +246,17 @@ def _kill(pid: int) -> None:
     "n_hosts,model_name,model_args,recovery_budget,chaos_kill", [
         (2, "gpt2", TINY_MODEL, 60, False),
         (3, "gpt2", TINY_MODEL, 60, False),
-        # Elastic MoE across hosts: switch-MoE decoder (tuple carry with
-        # the aux accumulator) through the same recovery machinery. The
-        # survivor re-plans to a SINGLE fused stage the pre-failure world
-        # never ran — historically a ~480 s cold compile on the CPU test
-        # mesh. With the recovery precompiler the pre-failure workers AOT
-        # that plan into the shared persistent compilation cache, so the
-        # respawn deserializes instead of compiling: budget 120 s. The
-        # failure itself is injected INSIDE the victim (OOBLECK_CHAOS
-        # SIGKILL at the step-3 barrier), not by the test poking pids.
-        (2, "gpt2-moe-tiny", {}, 120, True),
+        # Routed experts across hosts: a family whose layers differ and
+        # whose experts are one chip's share, through the same recovery
+        # machinery. The survivor re-plans to a SINGLE stage the
+        # pre-failure world never ran; the pre-failure workers AOT that
+        # plan into the shared persistent compilation cache (the recovery
+        # precompiler), so the respawn deserializes instead of compiling:
+        # budget 120 s. The failure itself is injected INSIDE the victim
+        # (OOBLECK_CHAOS SIGKILL at the step-3 barrier), not by the test
+        # poking pids.
+        (2, "lfm2-moe-tiny", {"num_experts_held": 4, "expert_offset": 2,
+                              "vocab_rows_held": 128}, 120, True),
     ])
 def test_multiprocess_mpmd_checkpoint_free_recovery(tmp_path, n_hosts,
                                                     model_name, model_args,
@@ -328,12 +329,12 @@ def test_multiprocess_mpmd_checkpoint_free_recovery(tmp_path, n_hosts,
                 cwd=str(REPO),
             )
         procs.append(master)
-        # Startup window before the kill is compile-bound (MoE stage
-        # programs trace slowly on a COLD persistent compile cache — the
-        # full-suite first run; PRECOMPILE_WAIT additionally AOT-compiles
-        # the predicted recovery plans before step 1); the recovery_budget
-        # itself is only asserted kill->resume.
-        startup = 900 if "moe" in model_name else 420
+        # Startup window before the kill is compile-bound (the routed
+        # family's stage programs on a COLD persistent compile cache;
+        # PRECOMPILE_WAIT additionally AOT-compiles the predicted recovery
+        # plans before step 1); the recovery_budget itself is only
+        # asserted kill->resume.
+        startup = 900 if chaos_kill else 420
         deadline = time.monotonic() + startup + recovery_budget
         _wait_for(r"master listening", log, deadline)
 
@@ -365,15 +366,17 @@ def test_multiprocess_mpmd_checkpoint_free_recovery(tmp_path, n_hosts,
 
         # ---- failure injection: SIGKILL the LAST host ----
         survivors = hosts[:-1]
-        offset = log.stat().st_size
         if chaos_kill:
             # The victim kills ITSELF (OOBLECK_CHAOS, utils/chaos.py) at
             # the step-3 barrier — an honest in-process crash, no outside
-            # hand on the pid. The recovery clock starts at the kill line.
-            _wait_for(r"chaos: killing worker at barrier step_end",
-                      log, deadline, after=offset)
+            # hand on the pid. The recovery clock starts at the kill line,
+            # and so does the log's "after": a tiny model's step 3 can be
+            # written before this test has read step 2.
+            offset = _wait_for(r"chaos: killing worker at barrier step_end",
+                               log, deadline).start()
             t_kill = time.monotonic()
         else:
+            offset = log.stat().st_size
             t_kill = time.monotonic()
             _kill(worker_pids[victim])
             _kill(agent_pids[victim])

@@ -77,6 +77,14 @@ def test_fused_path_rejects_non_lm_families():
         OobleckEngine(args)
 
 
+def test_a_name_the_registry_does_not_hold_is_refused_with_the_known_ones():
+    """`gpt2-moe` is no family of this repo (its one expert layer is
+    `ops/moe.routed_experts`): the registry's own error names what is."""
+    with pytest.raises(ValueError, match="unknown model 'gpt2-moe'") as e:
+        build_model("gpt2-moe")
+    assert "'lfm2-24b-a2b'" in str(e.value)
+
+
 def test_bert_attention_is_bidirectional():
     model = build_model("bert-tiny")
     params = model.init_params(jax.random.PRNGKey(0))

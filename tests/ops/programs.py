@@ -27,7 +27,7 @@ def all_eqns(jaxpr):
 
 def checkpoint_keeping(*names):
     """`jax.checkpoint` with the policy that keeps values by `names` and
-    nothing else: what `ops/flash.checkpoint_layer` is for its own list."""
+    nothing else: what `ops/remat.checkpoint_layer` is for its own list."""
     return functools.partial(
         jax.checkpoint,
         policy=jax.checkpoint_policies.save_only_these_names(*names))

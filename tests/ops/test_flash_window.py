@@ -11,7 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from oobleck_tpu.ops import attention, flash
+from oobleck_tpu.ops import attention, flash, remat
 from oobleck_tpu.ops.attention import _xla_causal_attention, causal_attention
 from tests.ops.programs import pallas_calls
 
@@ -145,7 +145,7 @@ def test_window_calls_go_out_under_names_of_their_own():
     before = {n: built.value(kernel=n) for n in flash.WINDOW + flash.PLAIN}
     named_before = named.value(kernel="flash_swa_fwd")
     grad = jax.grad(lambda q, k, v: jnp.sum(
-        flash.checkpoint_layer(
+        remat.checkpoint_layer(
             lambda q, k, v: flash.flash_attention(q, k, v, window=100)
         )(q, k, v)), argnums=(0, 1, 2))
     calls = [n for n, _ in pallas_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)]

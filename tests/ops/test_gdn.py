@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oobleck_tpu.ops import flash, gdn
+from oobleck_tpu.ops import flash, gdn, remat
 from oobleck_tpu.ops.gdn import gated_delta_rule, unit_lower_inverse
 from tests.ops.programs import all_eqns, checkpoint_keeping
 
@@ -279,7 +279,7 @@ def _products(fn, *args):
 
 
 @pytest.mark.parametrize("wrap,fewer", [
-    (flash.checkpoint_layer, 10),
+    (remat.checkpoint_layer, 10),
     (checkpoint_keeping(*gdn.RESIDUAL_NAMES), 10),
     # The name is emitted and the policy does not keep it: a bare
     # checkpoint's count, the series twice.
@@ -294,7 +294,7 @@ def test_a_checkpoint_that_keeps_the_inverse_recomputes_no_series(wrap, fewer):
 @functools.cache
 def _kept_and_bare_gradients():
     args = operands(128, 4, 2, seed=2)
-    return (jax.jit(_layer_gradients(flash.checkpoint_layer))(*args),
+    return (jax.jit(_layer_gradients(remat.checkpoint_layer))(*args),
             jax.jit(_layer_gradients(jax.checkpoint))(*args))
 
 

@@ -11,12 +11,13 @@ has a side effect.
 (i) The benchmark cells' one-stage `jit_bwd`, compiled for a
 described (not attached) TPU v5e: each forward kernel once a layer that
 has attention, the inverse's series once a layer that has the rule, the
-expert kernels as they were, temporaries under a bound. Nothing executes
-there; no number comes out. (ii) On the CPU, kernels interpreted: half the
-forward-kernel equations of a bare `jax.checkpoint` and bit-identical
-gradients (the rule's: `tests/ops/test_gdn.py`). (iii) A layer emits no
-value by a name: nothing is kept by it, and the layer lowers to the text
-it lowered to without that name in the policy.
+expert kernels as they were, temporaries under a bound; and of
+`gpt3-2.7b`'s, the same executable, that it holds the gradients once.
+Nothing executes there; no number comes out. (ii) On the CPU, kernels
+interpreted: half the forward-kernel equations of a bare `jax.checkpoint`
+and bit-identical gradients (the rule's: `tests/ops/test_gdn.py`).
+(iii) A layer emits no value by a name: nothing is kept by it, and the
+layer lowers to the text it lowered to without that name in the policy.
 
 A file of its own: under `--dist loadfile` its six compiles (about 250 s
 together) do not lengthen `test_tpu_compile.py`'s worker.
@@ -34,7 +35,7 @@ import pytest
 from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 
-from oobleck_tpu.ops import attention, flash
+from oobleck_tpu.ops import attention, flash, remat
 from tests.ops.programs import (
     cell_stage, checkpoint_keeping, pallas_calls)
 
@@ -109,12 +110,31 @@ def compiled_for_tpu(monkeypatch):
     attention.select_attention_impl.cache_clear()
 
 
+# `gpt3-2.7b`'s executable has two readers in this file and is compiled for
+# the first of them; the other cells' have one and are not kept alive.
+_READ_TWICE = "gpt3-2.7b"
+_kept = {}
+
+
+def _cell_backward(cell, devices):
+    """(stage, params, executable) of a cell's one stage, which is first and
+    last: bwd(params, sum, x=None, batch) is the loss's value-and-gradient,
+    compiled for the described chip. Under `compiled_for_tpu` only."""
+    if cell in _kept:
+        return _kept[cell]
+    (mb, seq), _, _ = CELLS[cell]
+    st, params, batch = cell_stage(cell, devices, microbatch=mb, seq=seq)
+    made = st, params, st.bwd[0].lower(params, params, None, batch).compile()
+    if cell == _READ_TWICE:
+        _kept[cell] = made
+    return made
+
+
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
                                                       cell):
-    (mb, seq), kernels, temp_bound = CELLS[cell]
-    st, params, batch = cell_stage(cell, v5e, microbatch=mb, seq=seq)
-    compiled = st.bwd[0].lower(params, params, None, batch).compile()
+    _, kernels, temp_bound = CELLS[cell]
+    _, _, compiled = _cell_backward(cell, v5e)
     text = compiled.as_text()
     calls = re.findall(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
@@ -131,6 +151,37 @@ def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
         r'= f32\[[\d,]*64,64\]\S* convolution\([^\n]*op_name="[^"]*/gdn/'
         r'gdn_inverse/dot_general"', text)
     assert len(inverse) == INVERSE_PRODUCTS.get(cell, 0)
+
+
+# Microbatch gradients accumulate inside each chunk's backward program: the
+# running sum is a donated operand and comes back in its own buffers
+# (execution/pipeline.py). At the `gpt3-2.7b.steady` cell's size (embedding,
+# 3 blocks and head on one chip, microbatches of 4 x 1024, bfloat16 + remat)
+# the gradients are 2 GB of float32 on a 16 GB chip: held once they leave
+# room, held a second time (a donation that did not take, or a gradient set
+# built in temporaries and added afterwards) they do not.
+def test_cell_sized_backward_holds_the_gradients_once(v5e, compiled_for_tpu):
+    st, params, compiled = _cell_backward("gpt3-2.7b", v5e)
+    leaves = jax.tree.leaves(params)
+    grad_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+
+    header = compiled.as_text().split("\n", 1)[0]
+    aliased = sorted(int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header))
+    # Operands flatten as (params..., sum..., batch): every leaf of the sum.
+    assert aliased == list(range(len(leaves), 2 * len(leaves))), header[:400]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= grad_bytes
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes)
+    slack = 4 << 20      # the batch, the loss, tile padding of small leaves
+    assert held <= 2 * grad_bytes + slack, mem   # parameters + ONE gradient set
+    assert mem.temp_size_in_bytes < grad_bytes, mem
+
+    fill = st.zero[0].lower(params).compile().memory_analysis()
+    assert fill.argument_size_in_bytes == 0      # the parameters are not read
+    assert fill.temp_size_in_bytes == 0
+    assert grad_bytes <= fill.output_size_in_bytes <= grad_bytes + slack
 
 
 # cell -> the float32 shapes of a routed layer's held experts (w1 / w3,
@@ -218,7 +269,7 @@ def test_kept_residuals_halve_the_forward_calls_and_keep_the_gradients(
         block, names):
     w, x = _operands()
     bare = _two_blocks(block, jax.checkpoint)
-    kept = _two_blocks(block, flash.checkpoint_layer)
+    kept = _two_blocks(block, remat.checkpoint_layer)
     calls = lambda fn: [name for name, _ in pallas_calls(
         jax.make_jaxpr(fn)(w, x).jaxpr)]
     assert calls(bare).count(names.fwd) == 4
@@ -239,10 +290,10 @@ def _lowered(block, wrap):
 def test_a_layer_on_the_xla_path_lowers_as_under_a_bare_checkpoint():
     """Names absent, nothing kept: what every CPU test leans on."""
     w, x = _operands()
-    assert _lowered(_xla_block, flash.checkpoint_layer) == _lowered(
+    assert _lowered(_xla_block, remat.checkpoint_layer) == _lowered(
         _xla_block, jax.checkpoint)
     assert not pallas_calls(jax.make_jaxpr(
-        _two_blocks(_xla_block, flash.checkpoint_layer))(w, x).jaxpr)
+        _two_blocks(_xla_block, remat.checkpoint_layer))(w, x).jaxpr)
 
 
 @pytest.mark.parametrize("block", [_plain_block, _latent_block],
@@ -251,7 +302,7 @@ def test_a_layer_without_the_delta_rule_keeps_nothing_new(block):
     """The policy also names the rule's inverse (`ops/gdn.py`); a layer
     that emits no such value lowers to the text it lowered to under
     flash's two names alone."""
-    assert _lowered(block, flash.checkpoint_layer) == _lowered(
+    assert _lowered(block, remat.checkpoint_layer) == _lowered(
         block, checkpoint_keeping(*flash.RESIDUAL_NAMES))
 
 

@@ -36,15 +36,18 @@ def layer():
     }
 
 
-def _share(layer, offset, held, **kw):
+TOP_KS = pytest.mark.parametrize("top_k", [K, 1], ids=["top2", "top1"])
+
+
+def _share(layer, offset, held, top_k=K, **kw):
     sl = slice(offset, offset + held)
     return moe.routed_experts(
         layer["x"], layer["router"], layer["bias"], layer["w1"][sl],
-        layer["w3"][sl], layer["w2"][sl], num_experts=NE, top_k=K,
+        layer["w3"][sl], layer["w2"][sl], num_experts=NE, top_k=top_k,
         expert_offset=offset, **kw)
 
 
-def _loop_over_tokens(layer, offset, held):
+def _loop_over_tokens(layer, offset, held, top_k=K):
     """The layer as its definition reads, one token and one pick at a
     time, in float64 on the host."""
     f64 = lambda a: np.asarray(a, np.float64)
@@ -53,7 +56,7 @@ def _loop_over_tokens(layer, offset, held):
     y = np.zeros_like(x)
     picks = []
     for t in range(x.shape[0]):
-        chosen = np.argsort(-(scores[t] + b), kind="stable")[:K]
+        chosen = np.argsort(-(scores[t] + b), kind="stable")[:top_k]
         picks.append(chosen)
         g = scores[t, chosen] / (scores[t, chosen].sum() + 1e-6)
         for weight, e in zip(g, chosen):
@@ -67,21 +70,24 @@ def _loop_over_tokens(layer, offset, held):
 
 @pytest.mark.parametrize("offset,held", [(0, NE), (2, 4), (4, 2), (3, 1)],
                          ids=["all", "middle4", "two", "empty_expert_only"])
-def test_routed_experts_matches_a_loop_over_tokens(layer, offset, held):
-    want, picks = _loop_over_tokens(layer, offset, held)
+@TOP_KS
+def test_routed_experts_matches_a_loop_over_tokens(layer, offset, held, top_k):
+    want, picks = _loop_over_tokens(layer, offset, held, top_k)
     got, chosen = jax.jit(
-        lambda: _share(layer, offset, held, return_routing=True))()
+        lambda: _share(layer, offset, held, top_k, return_routing=True))()
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
     assert (np.sort(np.asarray(chosen), -1) == np.sort(picks, -1)).all()
     if (offset, held) == (3, 1):
         assert not np.asarray(got).any()      # its one expert got no token
 
 
-def test_no_token_is_dropped_and_loads_are_uneven(layer):
-    _, picks = _loop_over_tokens(layer, 0, NE)
+@TOP_KS
+def test_no_token_is_dropped_and_loads_are_uneven(layer, top_k):
+    _, picks = _loop_over_tokens(layer, 0, NE, top_k)
     loads = np.bincount(picks.reshape(-1), minlength=NE)
-    assert loads.sum() == T * K and loads[3] == 0 and loads.max() > 2 * T * K / NE
-    rows, tile = moe.buffer_rows(T, K, NE, NE)
+    pairs = T * top_k
+    assert loads.sum() == pairs and loads[3] == 0 and loads.max() > 2 * pairs / NE
+    rows, tile = moe.buffer_rows(T, top_k, NE, NE)
     plan = moe.plan_routing(jnp.asarray(picks.reshape(-1), jnp.int32), NE,
                             rows, tile)
     np.testing.assert_array_equal(np.asarray(plan.group_sizes), loads)
@@ -97,7 +103,7 @@ def test_no_token_is_dropped_and_loads_are_uneven(layer):
         assert (picks.reshape(-1)[pairs_of_tile] == group[i]).all()
         assert (np.diff(pairs_of_tile) > 0).all()      # the pairs' own order
         seen.extend(pairs_of_tile.tolist())
-    assert sorted(seen) == list(range(T * K))
+    assert sorted(seen) == list(range(pairs))
     assert not valid_rows[used:].any()
     assert used * tile == int(np.asarray(plan.padded_sizes).sum())
     # An expert with no token still has its one (empty) tile.
@@ -115,19 +121,20 @@ def test_all_picks_of_a_token_can_be_held_here(layer):
                                atol=2e-5)
 
 
-def test_the_shares_add_up_to_the_uncut_layer(layer):
-    whole = np.asarray(_share(layer, 0, NE))
+@TOP_KS
+def test_the_shares_add_up_to_the_uncut_layer(layer, top_k):
+    whole = np.asarray(_share(layer, 0, NE, top_k))
     for parts in (8, 4, 2):
         held = NE // parts
-        total = sum(np.asarray(_share(layer, i * held, held))
+        total = sum(np.asarray(_share(layer, i * held, held, top_k))
                     for i in range(parts))
         np.testing.assert_allclose(total, whole, atol=2e-5)
 
 
-def _dense(x, router, bias, w1, w3, w2, offset):
+def _dense(x, router, bias, w1, w3, w2, offset, top_k):
     """The same function, dense over the held experts, for autodiff."""
     scores = jax.nn.sigmoid(x @ router)
-    _, chosen = jax.lax.top_k(scores + bias, K)
+    _, chosen = jax.lax.top_k(scores + bias, top_k)
     picked = jax.nn.one_hot(chosen, NE).sum(-2)
     g = picked * scores
     g = g / (g.sum(-1, keepdims=True) + 1e-6)
@@ -138,7 +145,8 @@ def _dense(x, router, bias, w1, w3, w2, offset):
 
 
 @pytest.mark.parametrize("offset,held", [(0, NE), (2, 4)], ids=["all", "share"])
-def test_gradients_match_the_dense_formulation(layer, offset, held):
+@TOP_KS
+def test_gradients_match_the_dense_formulation(layer, offset, held, top_k):
     sl = slice(offset, offset + held)
     args = (layer["x"], layer["router"], layer["w1"][sl], layer["w3"][sl],
             layer["w2"][sl])
@@ -146,12 +154,13 @@ def test_gradients_match_the_dense_formulation(layer, offset, held):
 
     def routed(x, router, w1, w3, w2):
         y = moe.routed_experts(x, router, layer["bias"], w1, w3, w2,
-                               num_experts=NE, top_k=K, expert_offset=offset)
+                               num_experts=NE, top_k=top_k,
+                               expert_offset=offset)
         return jnp.sum(y * target)
 
     def dense(x, router, w1, w3, w2):
-        return jnp.sum(_dense(x, router, layer["bias"], w1, w3, w2, offset)
-                       * target)
+        return jnp.sum(_dense(x, router, layer["bias"], w1, w3, w2, offset,
+                              top_k) * target)
 
     got = jax.jit(jax.grad(routed, argnums=range(5)))(*args)
     want = jax.grad(dense, argnums=range(5))(*args)

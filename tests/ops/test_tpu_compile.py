@@ -29,17 +29,14 @@ from jax.sharding import SingleDeviceSharding
 
 from oobleck_tpu.config import ServeArguments
 from oobleck_tpu.ops import attention
-from oobleck_tpu.ops.flash import (
-    checkpoint_layer,
-    flash_attention,
-    latent_flash_attention,
-)
+from oobleck_tpu.ops.flash import flash_attention, latent_flash_attention
 from oobleck_tpu.ops.paged_attention import (
     _select_paged_impl,
     _select_paged_verify_impl,
     paged_decode_attention,
     paged_verify_attention,
 )
+from oobleck_tpu.ops.remat import checkpoint_layer
 from oobleck_tpu.serve.kv_blocks import pages_for
 from tests.ops.programs import cell_stage
 
@@ -572,40 +569,6 @@ def test_head_loss_value_and_grad_holds_no_f32_logits(v5e):
         *args).compile()
     f32_logits = mb * (seq - 1) * padded * 4
     assert compiled.memory_analysis().temp_size_in_bytes < f32_logits
-
-
-# Microbatch gradients accumulate inside each chunk's backward program: the
-# running sum is a donated operand and comes back in its own buffers
-# (execution/pipeline.py). At the `gpt3-2.7b.steady` cell's size (embedding,
-# 3 blocks and head on one chip, microbatches of 4 x 1024, bfloat16 + remat)
-# the gradients are 2 GB of float32 on a 16 GB chip: held once they leave
-# room, held a second time (a donation that did not take, or a gradient set
-# built in temporaries and added afterwards) they do not.
-def test_cell_sized_backward_holds_the_gradients_once(v5e):
-    st, params, batch = cell_stage("gpt3-2.7b", v5e, microbatch=4, seq=1024)
-    leaves = jax.tree.leaves(params)
-    grad_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
-
-    # The one stage is first and last: bwd(params, sum, x=None, batch) is
-    # the loss's value-and-gradient.
-    compiled = st.bwd[0].lower(params, params, None, batch).compile()
-    header = compiled.as_text().split("\n", 1)[0]
-    aliased = sorted(int(p) for p in re.findall(
-        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header))
-    # Operands flatten as (params..., sum..., batch): every leaf of the sum.
-    assert aliased == list(range(len(leaves), 2 * len(leaves))), header[:400]
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= grad_bytes
-    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            - mem.alias_size_in_bytes)
-    slack = 4 << 20      # the batch, the loss, tile padding of small leaves
-    assert held <= 2 * grad_bytes + slack, mem   # parameters + ONE gradient set
-    assert mem.temp_size_in_bytes < grad_bytes, mem
-
-    fill = st.zero[0].lower(params).compile().memory_analysis()
-    assert fill.argument_size_in_bytes == 0      # the parameters are not read
-    assert fill.temp_size_in_bytes == 0
-    assert grad_bytes <= fill.output_size_in_bytes <= grad_bytes + slack
 
 
 # The routed experts' running gradient sums go INTO `moe_tgmm` (ops/moe.py):
