@@ -7,8 +7,9 @@ length, each expert's rows padded to a whole number of row tiles, and the
 products (three of a SwiGLU expert, two of an expert without a gate) run as
 grouped matrix multiplications that visit the tiles in use and skip the
 empty tail. What XLA does around them (rows in and out of the buffer, the
-activation) loops over the tiles in use too, so no work follows the
-buffer's length but its zero fill. Kernels (stable names on the
+activation) loops over the tiles in use too, and starts from a buffer that
+is allocated and not filled (`_unwritten`), so no work follows the buffer's
+length. Kernels (stable names on the
 `pallas_call`, so a device trace shows `%moe_gmm.N` / `%moe_tgmm.N`):
 
   moe_gmm   rows [M, K] x experts [E, K, N] -> [M, N]   forward, and dX
@@ -139,6 +140,26 @@ def buffer_rows(num_tokens: int, top_k: int, held: int,
     pairs = num_tokens * min(top_k, held)
     tile = choose_row_tile(num_tokens * top_k, num_experts)
     return -(-pairs // tile) * tile + held * tile, tile
+
+
+def _unwritten(shape, dtype) -> jax.Array:
+    """A buffer of rows that a loop over the row tiles IN USE is about to
+    write: allocated, not filled (on a TPU an `AllocateBuffer` custom call
+    with no operand and no write; zeros elsewhere), because a fill follows
+    the worst-case length and nobody reads it. The one place that makes
+    such a buffer, and its contract is the one `gmm_call`'s output already
+    keeps: rows of tiles past `num_tiles` hold NOTHING (whatever the memory
+    held), rows of a tile in use that hold no pair are zero because the
+    loop writes them so, and no consumer may read the former: the kernels
+    skip those tiles, the loops run `num_tiles` trips. Counted where it is
+    built, as the calls below are: `oobleck_moe_unfilled_buffers_total`."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_moe_unfilled_buffers_total",
+        "Row buffers of the routed experts handed out allocated and not "
+        "filled, built into traced programs").inc()
+    return lax.empty(shape, dtype)
 
 
 def plan_routing(local_expert: jax.Array, held: int, rows: int,
@@ -446,8 +467,8 @@ def _tile_of(plan: RoutingPlan, i, tile: int, top_k: int):
 def _rows_from_tokens(src, plan: RoutingPlan, tile: int, top_k: int,
                       weights=None):
     """[T, D] -> the buffer's rows [M, D]: row r is `src[token of r]`
-    (times its pair's weight); rows that hold no pair, and tiles not in
-    use, are zero."""
+    (times its pair's weight); rows of a tile in use that hold no pair are
+    zero, tiles not in use are not written (`_unwritten`)."""
     rows, d = plan.tile_group.shape[0] * tile, src.shape[1]
 
     def one_tile(i, out):
@@ -459,7 +480,7 @@ def _rows_from_tokens(src, plan: RoutingPlan, tile: int, top_k: int,
         return lax.dynamic_update_slice(out, block, (start, 0))
 
     return lax.fori_loop(0, plan.num_tiles[0], one_tile,
-                         jnp.zeros((rows, d), src.dtype))
+                         _unwritten((rows, d), src.dtype))
 
 
 def _tokens_from_rows(rows, plan: RoutingPlan, tile: int, top_k: int,
@@ -519,7 +540,9 @@ def _combine_fwd(rows, weights, plan, tile, top_k):
 
 def _combine_bwd(tile, top_k, res, dy):
     """d rows = weight x the token's dy; d weight of a pair = its row .
-    its token's dy. One loop over the tiles in use gives both."""
+    its token's dy. One loop over the tiles in use gives both; d rows of
+    tiles not in use are not written (`_unwritten`), d weight is a sum and
+    starts from zeros."""
     rows, weights, plan = res
     m, d = rows.shape
     flat = weights.reshape(-1)
@@ -541,7 +564,7 @@ def _combine_bwd(tile, top_k, res, dy):
 
     d_rows, d_w = lax.fori_loop(
         0, plan.num_tiles[0], one_tile,
-        (jnp.zeros((m, d), rows.dtype), jnp.zeros(flat.shape, jnp.float32)))
+        (_unwritten((m, d), rows.dtype), jnp.zeros(flat.shape, jnp.float32)))
     return d_rows, d_w.reshape(weights.shape).astype(weights.dtype), None
 
 
@@ -550,8 +573,8 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 def _over_tiles(plan: RoutingPlan, tile: int, fn, *operands):
     """`fn` on the row tiles in use of [M, .] operands, into [M, .]
-    results that are zero elsewhere: elementwise work over the buffer
-    follows the rows routed here too."""
+    results that are not written elsewhere (`_unwritten`): elementwise work
+    over the buffer follows the rows routed here too."""
     m = operands[0].shape[0]
     shapes = jax.eval_shape(fn, *[
         jax.ShapeDtypeStruct((tile, o.shape[1]), o.dtype) for o in operands])
@@ -565,7 +588,7 @@ def _over_tiles(plan: RoutingPlan, tile: int, fn, *operands):
 
     return lax.fori_loop(
         0, plan.num_tiles[0], one_tile,
-        tuple(jnp.zeros((m, sh.shape[1]), sh.dtype) for sh in shapes))
+        tuple(_unwritten((m, sh.shape[1]), sh.dtype) for sh in shapes))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -547,7 +547,12 @@ def test_the_swiglu_call_lowers_to_the_text_it_lowered_to_before():
     """Experts without a gate went in beside the SwiGLU path, not through
     it: value-and-gradient of the SwiGLU call lowers to the text it lowered
     to at the parent of the PR that added them (sha256 of the module's
-    text and its length, taken there)."""
+    text and its length, taken there). Moved once since, by PR 51: the row
+    buffers the tile loops start from are `lax.empty` (`moe._unwritten`),
+    which off a TPU lowers to the zero broadcast `jnp.zeros` lowered to;
+    the text differs in the numbers of its private functions alone
+    (`@_where_78` where `@_where_79` stood), so the length stayed and the
+    hash did not."""
     import hashlib
 
     s = jax.ShapeDtypeStruct
@@ -562,7 +567,90 @@ def test_the_swiglu_call_lowers_to_the_text_it_lowered_to_before():
         s((T, D), f32), s((D, NE), f32), s((NE,), f32), s((4, D, F), f32),
         s((4, D, F), f32), s((4, F, D), f32)).as_text()
     assert (hashlib.sha256(text.encode()).hexdigest()[:16], len(text)) == (
-        "c20d547349e09276", 76341)
+        "2257fd6ad66ffeda", 76341)
+
+
+# --------------------------------------------------------------------- #
+# the row buffers are allocated, not filled (`moe._unwritten`)           #
+# --------------------------------------------------------------------- #
+#
+# On a TPU a loop over the row tiles in use starts from whatever the memory
+# held. Here the buffers are handed out full of NaN instead: a consumer that
+# read a row of a tile past `num_tiles` would carry it into the value or a
+# gradient (NaN times zero is NaN), and an exact comparison with the run
+# from zeros would fail.
+
+# activation -> (the call's own arguments, buffers a value-and-gradient
+# trace hands out: the dispatch and the activation forward; d rows of the
+# combine, the activation's gradients and, gated, the sum of the two d rows)
+UNWRITTEN_CALLS = {
+    "swiglu": ({}, 6),
+    "reglu": ({"score": "softmax", "activation": "reglu"}, 6),
+    "ungated": ({}, 4),
+}
+# Experts 2..5 of 8 are held. As routed, expert 3 gets no token: its one
+# tile holds no pair. Forced onto experts 0 and 7, no pick lands here:
+# `num_tiles` at its least, an empty tile an expert.
+UNWRITTEN_ROUTINGS = {"an_expert_without_a_token": None,
+                      "no_pick_lands_here": (0, 7)}
+
+
+def _value_and_grads(layer, activation, forced):
+    kw, _ = UNWRITTEN_CALLS[activation]
+    names = ("x", "router", "w1", "w2") + (
+        () if activation == "ungated" else ("w3",))
+    sl = slice(2, 6)
+    operands = {n: layer[n] if n in ("x", "router") else layer[n][sl]
+                for n in names}
+    if forced is not None:
+        forced = jnp.tile(jnp.asarray([forced], jnp.int32), (T, 1))
+
+    def loss(ops):
+        y = moe.routed_experts(
+            ops["x"], ops["router"], layer["bias"], ops["w1"], ops.get("w3"),
+            ops["w2"], num_experts=NE, top_k=K, expert_offset=2,
+            forced_experts=forced, **kw)
+        return jnp.sum(y ** 2) + jnp.sum(y)
+
+    return jax.jit(jax.value_and_grad(loss))(operands)
+
+
+@pytest.mark.parametrize("routing", sorted(UNWRITTEN_ROUTINGS))
+@pytest.mark.parametrize("activation", sorted(UNWRITTEN_CALLS))
+@pytest.mark.parametrize("path", ["kernels", "fallback"])
+def test_nothing_reads_a_row_that_no_tile_loop_wrote(
+        layer, ungated, monkeypatch, path, activation, routing):
+    from oobleck_tpu.utils import metrics
+
+    if path == "kernels":
+        monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
+        monkeypatch.setattr(moe, "_interpret", lambda: True)
+    operands = ungated if activation == "ungated" else layer
+    forced = UNWRITTEN_ROUTINGS[routing]
+    built = metrics.registry().counter("oobleck_moe_unfilled_buffers_total")
+    before = built.value()
+    want = _value_and_grads(operands, activation, forced)
+    assert built.value() - before == UNWRITTEN_CALLS[activation][1]
+
+    handed = []
+
+    def poisoned(shape, dtype):
+        assert jnp.issubdtype(dtype, jnp.floating), dtype
+        handed.append(shape)
+        return jnp.full(shape, jnp.nan, dtype)
+
+    monkeypatch.setattr(moe, "_unwritten", poisoned)
+    got = _value_and_grads(operands, activation, forced)
+    assert len(handed) == UNWRITTEN_CALLS[activation][1]
+    rows = moe.buffer_rows(T, K, 4, NE)[0]
+    assert {shape[0] for shape in handed} == {rows}
+    for (path_, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                             jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(g)).all(), path_
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=str(path_))
+    if forced is not None:      # nothing of this call's is held here
+        assert not float(got[0]) and not np.asarray(got[1]["w1"]).any()
 
 
 def test_odd_widths_are_taken_whole_by_the_kernels():

@@ -265,6 +265,25 @@ def _routed_shapes(t, d, f, ne, held, top_k):
             ((held, d, f), jnp.float32), ((held, f, d), jnp.float32)]
 
 
+def _the_row_buffers_are_allocated_not_filled(text, t, d, f, ne, held, top_k):
+    """The tile loops of a routed gradient start from `moe._unwritten`
+    buffers: the executable holds no whole-buffer zero fill (`broadcast`)
+    of a `[buffer rows, D]` or `[buffer rows, F]` array, as its parent held
+    six (four without a gate), and no `copy` of one, which is what XLA
+    would insert had it merged two allocations or changed a layout; an
+    `AllocateBuffer` custom call stands where each fill stood (PR 51)."""
+    from oobleck_tpu.ops.moe import buffer_rows
+
+    rows, _ = buffer_rows(t, top_k, held, ne)
+    of_a_buffer = rf"= \w+\[{rows},(?:{d}|{f})\]\S* "
+    made = re.findall(of_a_buffer + r"(broadcast|copy)\(", text)
+    assert not made, made
+    allocated = re.findall(
+        of_a_buffer + r'custom-call\(\), custom_call_target="AllocateBuffer"',
+        text)
+    return len(allocated)
+
+
 @pytest.mark.parametrize("width", sorted(MOE_WIDTHS))
 @pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
 def test_routed_experts_compile(v5e, width, mode):
@@ -274,6 +293,11 @@ def test_routed_experts_compile(v5e, width, mode):
     # Three products forward; three dX and three dW more backward.
     assert text.count('custom_call_target="tpu_custom_call"') == (
         3 if mode == "fwd" else 9)
+    if mode == "fwd_bwd":
+        # The dispatch and the activation; d rows of the combine, d gate
+        # and d up, the sum of the two d rows.
+        assert _the_row_buffers_are_allocated_not_filled(
+            text, *MOE_WIDTHS[width]) == 6
 
 
 # Experts WITHOUT a gate (`w3=None`), at `nemotron-3-nano-30b-a3b.steady`'s
@@ -309,6 +333,9 @@ def test_ungated_experts_compile(v5e, width, mode):
     # Two products forward; two dX and two dW more backward.
     assert text.count('custom_call_target="tpu_custom_call"') == (
         2 if mode == "fwd" else 6)
+    if mode == "fwd_bwd":
+        assert _the_row_buffers_are_allocated_not_filled(
+            text, *UNGATED_WIDTHS[width]) == 4
     # The parameters stay as wide as published: no operand is padded.
     assert f"{held},{d},{f}" in text.replace(" ", "")
 
@@ -489,6 +516,8 @@ def test_reglu_experts_with_a_router_of_their_own_compile(v5e):
     shapes[2] = shapes[0]                       # the router's rows, not a bias
     text = _compile(jax.grad(fn, argnums=(0, 1, 2, 3, 4, 5)), v5e[0], *shapes)
     assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert _the_row_buffers_are_allocated_not_filled(
+        text, t, d, f, ne, held, top_k) == 6
 
 
 # Every kernel has a stable name on the device: `name=` on its pallas_call
@@ -631,22 +660,26 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
 
 
 # cell -> sha256 and length of the one-stage `jit_bwd`'s lowered text with
-# the kernels' serialized bodies (which carry source lines) blanked. All
-# six as PR 47 left them: every cell's stage holds a flash backward, and
-# each text got SHORTER by the second backward call of each attention
-# layer (its operands' pads, its tables, its call: 3.1 k in `gpt3-2.7b`'s
-# three blocks, 35.0 k in `smallthinker-21b-a3b`'s four layers with their
-# tables of 528 and 252 steps). A PR that changes what one of these
-# programs computes takes its new text's pair from a failing run; one that
-# leaves a pair standing has shown that the program bypasses its change
-# (PR 46's rotary left `gpt3-2.7b`'s and `nemotron-3-nano-30b-a3b`'s).
+# the kernels' serialized bodies (which carry source lines) blanked.
+# `gpt3-2.7b`'s as PR 47 left it (every cell's stage holds a flash backward,
+# and each text got SHORTER by the second backward call of each attention
+# layer: 3.1 k in `gpt3-2.7b`'s three blocks). The five routed cells' as
+# PR 51 left them: every whole-buffer zero fill of a routed layer (a
+# constant and a `broadcast_in_dim`, eight a gated layer and six an ungated
+# one with the recompute) became one `AllocateBuffer` custom call
+# (`moe._unwritten`), 2.8 k less a cell's text (1.6 k in the ungated cell's
+# three routed layers, 4.4 k in `qwen3-next-80b-a3b`'s); `gpt3-2.7b` has no
+# routed layer and its pair stood. A PR that changes what one of these programs
+# computes takes its new text's pair from a failing run; one that leaves a
+# pair standing has shown that the program bypasses its change (PR 46's
+# rotary left `gpt3-2.7b`'s and `nemotron-3-nano-30b-a3b`'s).
 LOWERED = {
     "gpt3-2.7b": ("f815b23b0da3328e", 169829),
-    "lfm2-24b-a2b": ("8ae64d11a72e6840", 618437),
-    "moonlight-16b-a3b": ("c22531f6f06d2b50", 775333),
-    "nemotron-3-nano-30b-a3b": ("4b54248076815c59", 657832),
-    "qwen3-next-80b-a3b": ("b6cc98525f7b26c9", 1047641),
-    "smallthinker-21b-a3b": ("79ac1bb47bc72766", 680685),
+    "lfm2-24b-a2b": ("209db291359e76b0", 615621),
+    "moonlight-16b-a3b": ("88e7b8f9e215f63c", 772517),
+    "nemotron-3-nano-30b-a3b": ("b7c4627cd9176e48", 656248),
+    "qwen3-next-80b-a3b": ("8b7a32c5e894cd98", 1043197),
+    "smallthinker-21b-a3b": ("9cc5115f05283ed4", 677869),
 }
 
 
