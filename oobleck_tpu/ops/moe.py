@@ -10,12 +10,17 @@ empty tail. What XLA does around them (rows in and out of the buffer, the
 activation) loops over the tiles in use too, and starts from a buffer that
 is allocated and not filled (`_unwritten`), so no work follows the buffer's
 length. Kernels (stable names on the
-`pallas_call`, so a device trace shows `%moe_gmm.N` / `%moe_tgmm.N`):
+`pallas_call`, so a device trace shows `%moe_gmm.N` / `%moe_tgmm.N` /
+`%moe_token_sum.N`):
 
-  moe_gmm   rows [M, K] x experts [E, K, N] -> [M, N]   forward, and dX
-            with the expert matrices read transposed
-  moe_tgmm  rows^T [M, K] x rows [M, N] -> [E, K, N]    dW, on top of
-            a running gradient sum where the caller hands one (`GradSum`)
+  moe_gmm        rows [M, K] x experts [E, K, N] -> [M, N]   forward, and
+                 dX with the expert matrices read transposed
+  moe_tgmm       rows^T [M, K] x rows [M, N] -> [E, K, N]    dW, on top of
+                 a running gradient sum where the caller hands one
+                 (`GradSum`)
+  moe_token_sum  rows [M, D] -> tokens [T, D]: a token the (weighted) sum
+                 of its rows, a block of tokens a grid step, the float32
+                 sum in VMEM until the block is written
 
 On one chip nothing is exchanged; with `num_experts_held` < `num_experts`
 the result is this chip's PART of the layer's output (the partial sums
@@ -42,6 +47,8 @@ SUBLANE = 16              # a bfloat16 tile's rows
 MAX_COL_TILE = 512        # columns of the output one grid step produces
 MAX_TGMM_ROWS = 1024      # rows of dW one grid step accumulates
 MAX_WHOLE_COLS = 2048     # a width no tile but one lane divides, taken whole
+MAX_TOKEN_BLOCK = 1024    # tokens whose sum one grid step of moe_token_sum owns
+TOKEN_SUM_VMEM = 24 * 1024 * 1024   # what a block's sum and its passes may take
 _GMM_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"),
     vmem_limit_bytes=64 * 1024 * 1024)
@@ -123,7 +130,14 @@ class RoutingPlan(NamedTuple):
     are (token, slot) flattened to t * k + s; `order` lists them by held
     expert (stable, so in the pairs' own order inside an expert), and a
     tile's rows are a contiguous run of it. Nothing here is as long as the
-    buffer."""
+    buffer.
+
+    The last four are the same layout read from the TOKENS' side, for
+    `moe_token_sum` (None where the sums are XLA's loop: `token_runs`).
+    They rest on the order inside an expert's region: the sort is stable
+    and a token picks an expert at most once, so there the rows ascend by
+    token and none repeats, and the rows expert e gives a BLOCK of tokens
+    are one contiguous run of the buffer."""
     tile_group: jax.Array      # [tiles] int32: the held expert of a row tile
     num_tiles: jax.Array       # [1] int32: tiles in use; the rest is skipped
     padded_sizes: jax.Array    # [held] int32: each expert's rows, padded
@@ -131,6 +145,12 @@ class RoutingPlan(NamedTuple):
     order: jax.Array           # [pairs + tile] int32: pairs by held expert
     tile_first: jax.Array      # [tiles] int32: where in `order` a tile starts
     tile_rows: jax.Array       # [tiles] int32: rows of the tile that hold a pair
+    src_row: jax.Array | None = None    # [T, held] int32: the row of the
+    #                            token's pair on held expert e, or -1
+    w_held: jax.Array | None = None     # [T, held] float32: that pair's weight
+    run_first: jax.Array | None = None  # [T / tb * held] int32, block-major:
+    #                            the first row expert e gives the block
+    run_len: jax.Array | None = None    # likewise: how many rows it gives
 
 
 def buffer_rows(num_tokens: int, top_k: int, held: int,
@@ -189,6 +209,70 @@ def plan_routing(local_expert: jax.Array, held: int, rows: int,
         (tiles * tile).astype(i32), sizes,
         jnp.concatenate([order, jnp.zeros((tile,), i32)]),
         tile_first.astype(i32), tile_rows.astype(i32))
+
+
+def _sum_chunk(tile: int) -> int:
+    """Buffer rows `moe_token_sum` copies at a time: one pass of the MXU's
+    contraction, and no more than a row tile, so a chunk that ends with the
+    last tile in use always starts inside the tiles in use (every expert
+    has a tile)."""
+    return min(LANE, tile)
+
+
+@functools.cache
+def choose_token_block(num_tokens: int, top_k: int, num_experts: int,
+                       tile: int) -> int:
+    """Tokens of one block of `moe_token_sum`, from the call's shape. A
+    grid step takes, for every held expert, ONE chunk of `_sum_chunk(tile)`
+    buffer rows for the block's tokens and a further one only where the
+    expert's run is longer, and the chunk's products cost the same however
+    many of its rows the block uses. So: the largest block (a multiple of
+    the bfloat16 sublane tile that divides the tokens, up to
+    MAX_TOKEN_BLOCK) whose EXPECTED rows an expert, block x k / experts,
+    fill half a chunk at most: the other half is room for where the run
+    starts in its sublane tile and for what the router does to the load.
+    512 tokens at 16384 x top 6 of 64 (48 rows expected of 128), 1024 at
+    top 4 of 64 and at top 10 of 512. All the tokens where no such block
+    divides them."""
+    fits = _sum_chunk(tile) / 2
+    blocks = [b for b in range(SUBLANE, min(num_tokens, MAX_TOKEN_BLOCK) + 1,
+                               SUBLANE) if num_tokens % b == 0]
+    if not blocks:
+        return num_tokens
+    roomy = [b for b in blocks if b * top_k / num_experts <= fits]
+    return max(roomy) if roomy else min(blocks)
+
+
+def token_runs(plan: RoutingPlan, local_expert: jax.Array,
+               weights: jax.Array, block: int) -> RoutingPlan:
+    """The plan with its last four fields: `local_expert` and `weights`
+    [T, k] as `route` gave them (the held expert's index, or `held`).
+    Dense arithmetic only: compares, one cumulative sum along the tokens,
+    and slices of it; no sort, and no scatter of an integer a pair. A
+    pair's row is its expert's first row plus its rank among the expert's
+    pairs, which in a stable order is a running count.
+
+    PRECONDITION, the caller's: a token's picks are DISTINCT experts
+    (`lax.top_k`'s are; `forced_experts` come from a reference's own
+    top-k). A token that named one held expert twice would own two rows
+    there and `src_row` has room for one."""
+    i32 = jnp.int32
+    held = plan.padded_sizes.shape[0]
+    num_tokens = local_expert.shape[0]
+    picked = local_expert[None] == jnp.arange(held, dtype=i32)[:, None, None]
+    hit = jnp.any(picked, axis=2).astype(i32)                   # [held, T]
+    upto = jnp.cumsum(hit, axis=1)                              # inclusive
+    first_row = (jnp.cumsum(plan.padded_sizes) - plan.padded_sizes)[:, None]
+    src_row = jnp.where(hit > 0, first_row + upto - hit, -1)
+    ends = upto[:, block - 1::block]                            # [held, T/tb]
+    before = jnp.concatenate([jnp.zeros((held, 1), i32), ends[:, :-1]], 1)
+    assert ends.shape[1] * block == num_tokens, (num_tokens, block)
+    w_held = jnp.sum(jnp.where(picked, lax.stop_gradient(weights)[None], 0.0),
+                     axis=2)
+    return plan._replace(
+        src_row=src_row.T, w_held=w_held.T,
+        run_first=(first_row + before).T.reshape(-1),
+        run_len=(ends - before).T.reshape(-1))
 
 
 # --------------------------------------------------------------------- #
@@ -356,6 +440,174 @@ def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
     )(tile_group, num_tiles, lhs, rhs, *started)
 
 
+def _token_sum_body(run_first, run_len, num_tiles, src_ref, *refs,
+                    held: int, tile: int, weighted: bool):
+    """The sum of ONE block of tokens (and block of columns), in float32
+    in `acc` until it is written. The held experts in ascending order, as
+    the row tiles have them, so a token's sum adds the same terms in the
+    same order as a loop over the tiles would. For each, the rows it gives
+    the block are a contiguous run of the buffer (`RoutingPlan`): a chunk
+    of rows is copied from where the run starts, aligned down to a sublane
+    tile and held back to end with the last tile in use, and the one-hot
+    `P[token, row of the chunk]` times the chunk, on the MXU with float32
+    accumulation, puts each token's row in its place EXACTLY (one bfloat16
+    value times one, and zeros). The pair's weight and the sum are the
+    VPU's, in float32. A run longer than a chunk takes further chunks, so
+    nothing is dropped whatever the router does. No row past the tiles in
+    use is read (they hold NOTHING, `_unwritten`): every chunk ends at or
+    before the last one's end.
+
+    The next expert's first chunk is in flight while this one's is added.
+
+    `lax` primitives where `jnp` has a jitted helper (`//`, `%`, `where`,
+    `sum`, `dot`): a helper's jaxpr is cached with the source location of
+    its FIRST trace in the process, a kernel's serialized body carries
+    that location, and the compile cache's key the body. With `//` here a
+    cell's second run compiled `jit_bwd` anew (+ 54 s of `setup_s`, my chip
+    run, PR 52): its first trace came by another call stack than the cold
+    run's. tests/ops/test_routed_experts.py holds every expert kernel's
+    body free of such calls."""
+    w_ref = refs[0] if weighted else None
+    rows_ref, out_ref, acc, buf, sem = refs[1:] if weighted else refs
+    b, n = pl.program_id(0), pl.program_id(1)
+    tb, tn = acc.shape
+    chunk = buf.shape[1]
+    last_start = num_tiles[0] * tile - chunk
+    lane = lax.broadcasted_iota(jnp.int32, (tb, chunk), 1)
+
+    def copy(start, slot):
+        return pltpu.make_async_copy(
+            rows_ref.at[pl.ds(start, chunk), pl.ds(n * tn, tn)],
+            buf.at[slot], sem.at[slot])
+
+    def first_chunk(e):
+        aligned = lax.div(run_first[b * held + e], SUBLANE) * SUBLANE
+        return jnp.minimum(aligned, last_start)
+
+    def column(ref, e):
+        """Column `e` of a [tb, held] block as [tb, 1]: the lane is picked
+        by a select and a sum along the lanes, in float32 (a row's index is
+        far under 2**24), because the expert is a loop's index and a
+        dynamic lane offset is no load the chip has."""
+        picked = lax.broadcasted_iota(jnp.int32, ref.shape, 1) == e
+        values = ref[...].astype(jnp.float32)
+        return lax.expand_dims(lax.reduce_sum(
+            lax.select(picked, values, jnp.zeros_like(values)), (1,)), (1,))
+
+    def add(e, start, slot, from_row=None):
+        """acc += (weight x) the chunk's rows at the block's tokens whose
+        row lies in it, at or after `from_row`."""
+        src = column(src_ref, e).astype(jnp.int32)
+        at = src - start == lane
+        if from_row is not None:
+            at = jnp.logical_and(at, src >= from_row)
+        got = lax.dot_general(
+            at.astype(jnp.float32).astype(buf.dtype), buf[slot],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        acc[...] += column(w_ref, e) * got if weighted else got
+
+    def expert(e, carry):
+        slot = lax.rem(e, 2)
+        start = first_chunk(e)
+
+        @pl.when(e + 1 < held)
+        def _():
+            copy(first_chunk(e + 1), 1 - slot).start()
+
+        copy(start, slot).wait()
+        end = run_first[b * held + e] + run_len[b * held + e]
+
+        @pl.when(run_len[b * held + e] > 0)
+        def _():
+            add(e, start, slot)
+
+        def further(j, carry):
+            row = start + (j + 1) * chunk       # the first row not yet added
+            at = jnp.minimum(row, last_start)
+            more = copy(at, 2)
+            more.start()
+            more.wait()
+            add(e, at, 2, from_row=row)
+            return carry
+
+        left = jnp.maximum(end - start - chunk, 0)
+        return lax.fori_loop(0, lax.div(left + chunk - 1, chunk), further,
+                             carry)
+
+    acc[...] = jnp.zeros_like(acc)
+    copy(first_chunk(0), 0).start()
+    lax.fori_loop(0, held, expert, 0)
+    out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+def _sum_col_tile(d: int, block: int) -> int:
+    """Columns of one block of `moe_token_sum`: all of them where the
+    block's float32 sum and the passes over it (the product, the weighted
+    product, the result's two buffers: 16 bytes an element) stay within
+    TOKEN_SUM_VMEM; else the largest lane multiple that divides them and
+    does."""
+    room = TOKEN_SUM_VMEM // (16 * block)
+    if d <= room or d % LANE:
+        return d
+    return _col_tile(d, max(room, LANE))
+
+
+def token_sum_call(rows, plan: RoutingPlan, *, tile: int, weighted: bool,
+                   chunk: int | None = None):
+    """`moe_token_sum`: rows [M, D] (the buffer; rows of tiles past
+    `num_tiles` hold nothing and are not read) -> [T, D] in the rows'
+    dtype, token t the float32 sum over the held experts it picked of its
+    row there (times `plan.w_held` with `weighted`). ONE kernel a sum: the
+    buffer stays in HBM and each grid step copies the chunks its block
+    needs; the sum never leaves VMEM before it is whole. `chunk` is the
+    tests', to make a short run span several. Counted where it is built:
+    `oobleck_moe_token_sum_kernels_total`."""
+    from oobleck_tpu.utils import metrics
+
+    num_tokens, held = plan.src_row.shape
+    m_rows, d = rows.shape
+    blocks = plan.run_first.shape[0] // held
+    tb = num_tokens // blocks
+    chunk = _sum_chunk(tile) if chunk is None else chunk
+    assert blocks * tb == num_tokens and m_rows % tile == 0
+    assert tile % chunk == 0 and chunk % SUBLANE == 0, (tile, chunk)
+    assert m_rows < 2 ** 24, m_rows     # a row's number, exact in float32
+    tn = _sum_col_tile(d, tb)
+    metrics.registry().counter(
+        "oobleck_moe_token_sum_kernels_total",
+        "Sums of the routed experts' rows into their tokens built into "
+        "traced programs as one moe_token_sum kernel each").inc()
+
+    by_token = pl.BlockSpec((tb, held), lambda b, n, *_: (b, 0))
+    operands = [plan.src_row] + ([plan.w_held] if weighted else []) + [rows]
+    item = rows.dtype.itemsize
+    # Asked for from the shape: the sum and two float32 passes over it (the
+    # product, the weighted product), the result's two buffers, the three
+    # chunks, the by-token blocks (two buffers each, padded to a lane
+    # tile), and the 16 MiB a kernel has without asking.
+    vmem = (tb * tn * (12 + 2 * item) + 3 * chunk * tn * item
+            + 4 * tb * LANE * 4 + 16 * 1024 * 1024)
+    return pl.pallas_call(
+        functools.partial(_token_sum_body, held=held, tile=tile,
+                          weighted=weighted),
+        out_shape=jax.ShapeDtypeStruct((num_tokens, d), rows.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(blocks, d // tn),
+            in_specs=[by_token] * (len(operands) - 1) + [
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tb, tn), lambda b, n, *_: (b, n)),
+            scratch_shapes=[pltpu.VMEM((tb, tn), jnp.float32),
+                            pltpu.VMEM((3, chunk, tn), rows.dtype),
+                            pltpu.SemaphoreType.DMA((3,))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=_interpret(),
+        name="moe_token_sum",
+    )(plan.run_first, plan.run_len, plan.num_tiles, *operands)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class GradSum:
@@ -449,13 +701,23 @@ def grouped_matmul(rows, experts, plan: RoutingPlan, tile: int,
 # rows in and out of the buffer                                          #
 # --------------------------------------------------------------------- #
 #
-# Both directions, forward and backward, are one of two loops over the row
-# tiles IN USE (a `fori_loop` whose trip count is the plan's `num_tiles`,
-# so the work follows the rows routed here and not the worst-case buffer):
-# a tile's rows gathered from their tokens, or a tile's rows added to
-# their tokens. A whole-buffer gather costs the chip about 80 ns a row,
-# used or not: at 8 x 1024 tokens, top 4, eight such gathers a layer and
-# microbatch were 40 % of the cell's step (my chip run, PR 29).
+# Both directions, forward and backward, are one of two passes over the row
+# tiles IN USE, so the work follows the rows routed here and not the
+# worst-case buffer. A tile's rows gathered from their tokens: a
+# `fori_loop` whose trip count is the plan's `num_tiles`. A whole-buffer
+# gather costs the chip about 80 ns a row, used or not: at 8 x 1024 tokens,
+# top 4, eight such gathers a layer and microbatch were 40 % of the cell's
+# step (my chip run, PR 29). And a token the sum of its rows (`_token_sum`:
+# the combine, and the dispatch's dx): on the kernels' path ONE
+# `moe_token_sum` call, token-block major, because as a loop of XLA
+# scatter-adds into a float32 [tokens, D] array in HBM it walked the rows
+# one at a time, 283 ns a row at [16384, 2560], 147 ms of
+# `smallthinker-21b-a3b.steady`'s 1,383 ms step (my chip run, PR 51). What
+# lets a dense kernel do it is the order inside an expert's region of the
+# buffer (`RoutingPlan`): ascending by token, none twice, so a block of
+# tokens reads one contiguous run of rows an expert. Elsewhere (the CPU,
+# the tests' engines) the sum stays the loop, which is also what the kernel
+# is tested against, bit for bit.
 
 def _tile_of(plan: RoutingPlan, i, tile: int, top_k: int):
     """(first row, pairs, tokens, valid) of row tile `i`."""
@@ -485,11 +747,13 @@ def _rows_from_tokens(src, plan: RoutingPlan, tile: int, top_k: int,
 
 def _tokens_from_rows(rows, plan: RoutingPlan, tile: int, top_k: int,
                       num_tokens: int, weights=None):
-    """The buffer's rows [M, D] -> [T, D] float32: a token is the sum of
-    its rows (times their pairs' weights); a row that holds no pair adds
-    past the end and is dropped. (Within a tile the tokens ascend and none
-    repeats, but telling the scatter so made it 2.5 x slower on the chip:
-    1.86 against 0.76 ms over 20 tiles, my chip run, PR 29.)"""
+    """The buffer's rows [M, D] -> [T, D] float32, as a loop of XLA
+    scatter-adds over the tiles in use (off the kernels' path, and the
+    reference `moe_token_sum` is held to): a token is the sum of its rows
+    (times their pairs' weights); a row that holds no pair adds past the
+    end and is dropped. (Within a tile the tokens ascend and none repeats,
+    but telling the scatter so made it 2.5 x slower on the chip: 1.86
+    against 0.76 ms over 20 tiles, my chip run, PR 29.)"""
     d = rows.shape[1]
 
     def one_tile(i, acc):
@@ -505,6 +769,20 @@ def _tokens_from_rows(rows, plan: RoutingPlan, tile: int, top_k: int,
                          jnp.zeros((num_tokens, d), jnp.float32))
 
 
+def _token_sum(rows, plan: RoutingPlan, tile: int, top_k: int,
+               num_tokens: int, weights=None):
+    """The buffer's rows [M, D] -> [T, D] in their dtype, a token the
+    float32 sum of its rows (times their pairs' weights): `moe_token_sum`
+    where the plan carries the tokens' side (`token_runs`), else the
+    loop."""
+    if plan.src_row is None:
+        return _tokens_from_rows(rows, plan, tile, top_k, num_tokens,
+                                 weights).astype(rows.dtype)
+    with jax.named_scope("routed_experts"):     # as `grouped_matmul`'s
+        return token_sum_call(rows, plan, tile=tile,
+                              weighted=weights is not None)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _dispatch(x, plan: RoutingPlan, tile: int, top_k: int):
     """x [T, D] -> the buffer's rows [M, D]. Backward: a token's gradient
@@ -518,8 +796,7 @@ def _dispatch_fwd(x, plan, tile, top_k):
 
 def _dispatch_bwd(tile, top_k, res, d_rows):
     plan, tokens = res
-    dx = _tokens_from_rows(d_rows, plan, tile, top_k, tokens)
-    return dx.astype(d_rows.dtype), None
+    return _token_sum(d_rows, plan, tile, top_k, tokens), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -529,8 +806,7 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 def _combine(rows, weights, plan: RoutingPlan, tile: int, top_k: int):
     """The buffer's rows [M, D] and the pairs' weights [T, k] (float32)
     -> y [T, D], a token the weighted sum of its rows."""
-    y = _tokens_from_rows(rows, plan, tile, top_k, weights.shape[0], weights)
-    return y.astype(rows.dtype)
+    return _token_sum(rows, plan, tile, top_k, weights.shape[0], weights)
 
 
 def _combine_fwd(rows, weights, plan, tile, top_k):
@@ -839,9 +1115,13 @@ def routed_experts(
         _count_softmax_call()
 
     local = experts.reshape(-1) - expert_offset
-    local = jnp.where((local >= 0) & (local < held), local, held)
+    local = jnp.where((local >= 0) & (local < held), local, held).astype(
+        jnp.int32)
     rows, tile = buffer_rows(t, top_k, held, num_experts)
-    plan = plan_routing(local.astype(jnp.int32), held, rows, tile)
+    plan = plan_routing(local, held, rows, tile)
+    if _pallas_ok():
+        plan = token_runs(plan, local.reshape(t, top_k), weights,
+                          choose_token_block(t, top_k, num_experts, tile))
 
     xs = _dispatch(x, plan, tile, top_k)
     if w3 is None:

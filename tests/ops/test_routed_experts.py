@@ -507,8 +507,9 @@ def test_ungated_gradients_match_the_dense_formulation(ungated, offset, held,
 
 def test_an_ungated_call_holds_two_products_forward_and_four_backward(
         ungated, monkeypatch):
-    """`grouped_matmul`'s own backward: dX and dW of each of the two.
-    Counted on the kernels' path (interpreted)."""
+    """`grouped_matmul`'s own backward: dX and dW of each of the two; and
+    the two sums of rows into tokens, the combine forward and the
+    dispatch's dx backward. Counted on the kernels' path (interpreted)."""
     from tests.ops.programs import pallas_calls
 
     monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
@@ -522,10 +523,11 @@ def test_an_ungated_call_holds_two_products_forward_and_four_backward(
 
     args = (ungated["x"], ungated["w1"][sl], ungated["w2"][sl])
     forward = [n for n, _ in pallas_calls(jax.make_jaxpr(loss)(*args).jaxpr)]
-    assert forward == ["moe_gmm"] * 2
+    assert forward == ["moe_gmm"] * 2 + ["moe_token_sum"]
     both = [n for n, _ in pallas_calls(jax.make_jaxpr(
         jax.grad(loss, argnums=(0, 1, 2)))(*args).jaxpr)]
-    assert sorted(both) == ["moe_gmm"] * 4 + ["moe_tgmm"] * 2
+    assert sorted(both) == (["moe_gmm"] * 4 + ["moe_tgmm"] * 2
+                            + ["moe_token_sum"] * 2)
 
 
 def test_ungated_calls_are_counted_where_they_are_built(ungated):
@@ -628,9 +630,13 @@ def test_nothing_reads_a_row_that_no_tile_loop_wrote(
     operands = ungated if activation == "ungated" else layer
     forced = UNWRITTEN_ROUTINGS[routing]
     built = metrics.registry().counter("oobleck_moe_unfilled_buffers_total")
-    before = built.value()
+    sums = metrics.registry().counter("oobleck_moe_token_sum_kernels_total")
+    before, sums_before = built.value(), sums.value()
     want = _value_and_grads(operands, activation, forced)
     assert built.value() - before == UNWRITTEN_CALLS[activation][1]
+    # On the kernels' path both sums (the combine, the dispatch's dx) are
+    # `moe_token_sum` calls: the dispatch's reads a buffer handed out here.
+    assert sums.value() - sums_before == (2 if path == "kernels" else 0)
 
     handed = []
 
@@ -651,6 +657,190 @@ def test_nothing_reads_a_row_that_no_tile_loop_wrote(
                                       err_msg=str(path_))
     if forced is not None:      # nothing of this call's is held here
         assert not float(got[0]) and not np.asarray(got[1]["w1"]).any()
+
+
+# --------------------------------------------------------------------- #
+# a token's sum over its rows as one kernel (`moe_token_sum`)            #
+# --------------------------------------------------------------------- #
+#
+# The kernel in the interpreter against the loop of scatter-adds it stands
+# in for, bit for bit. Experts 2..5 of 8 are held, 64 tokens pick 2. The
+# rows and the weights are bfloat16 values (held in float32 where the case
+# says so): a weight times a row is then exact in float32, and XLA:CPU,
+# which contracts a multiply and an add of ONE program into a fused
+# multiply-add, cannot round the interpreter's sum otherwise than the
+# loop's (the chip's VPU has no such instruction; unrounded weights agree
+# to one unit in the last place, below).
+SUM_T, SUM_D = 64, 32
+_one = lambda a, b: np.tile(np.asarray([[a, b]], np.int32), (SUM_T, 1))
+def _off_a_boundary():
+    t = np.arange(SUM_T)
+    picks, on_two = _one(0, 7), (t < 5) | (t >= 16)
+    picks[on_two] = (2, 0)
+    picks[on_two & (t % 2 == 1), 1] = 5
+    return picks
+
+
+# routing -> (picks [T, 2] or None for the router's own, tokens a block,
+# rows a chunk). As routed, a router that never picks expert 3 (an expert
+# with no row) leaves about half the tokens with no held pick.
+TOKEN_SUM_ROUTINGS = {
+    "as_routed": (None, 16, None),
+    # Every token of every block on expert 4: a run of 32 rows, two chunks
+    # and, from the second block on, three (the run starts mid-chunk).
+    "a_block_on_one_expert": (_one(4, 7), 32, 16),
+    # Tokens 0..4 and 16.. pick expert 2: the second block's run starts at
+    # row 5 of the expert's region, off every sublane tile's edge; expert 5
+    # takes the odd ones of them, and tokens 5..15 pick nothing held.
+    "a_run_off_a_sublane_boundary": (_off_a_boundary(), 16, None),
+    # No pick lands here: `num_tiles` at its least, every run empty.
+    "nothing_held": (_one(0, 7), 64, None),
+}
+
+
+def _sum_case(routing, dtype):
+    picks, block, chunk = TOKEN_SUM_ROUTINGS[routing]
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    if picks is None:
+        scores = jax.random.normal(ks[0], (SUM_T, NE)).at[:, 3].set(-100.0)
+        picks = np.asarray(jax.lax.top_k(scores, K)[1])
+    bf16_values = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+    weights = bf16_values(jax.random.uniform(ks[1], (SUM_T, K), minval=0.1))
+    local = np.where((picks >= 2) & (picks < 6), picks - 2, 4).astype(np.int32)
+    m_rows, tile = moe.buffer_rows(SUM_T, K, 4, NE)
+    plan = moe.plan_routing(jnp.asarray(local.reshape(-1)), 4, m_rows, tile)
+    plan = moe.token_runs(plan, jnp.asarray(local), weights, block)
+    used = int(plan.num_tiles[0]) * tile
+    rows = bf16_values(jax.random.normal(ks[2], (m_rows, SUM_D))).astype(dtype)
+    # Rows of tiles past the last one in use hold NOTHING (`_unwritten`).
+    return plan, rows.at[used:].set(jnp.nan), weights, tile, chunk, local
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["plain", "weighted"])
+@pytest.mark.parametrize("routing", sorted(TOKEN_SUM_ROUTINGS))
+def test_the_token_sum_kernel_is_the_loop_bit_for_bit(interpreted, routing,
+                                                      weighted):
+    from oobleck_tpu.utils import metrics
+
+    dtype = jnp.bfloat16 if routing == "as_routed" else jnp.float32
+    plan, rows, weights, tile, chunk, local = _sum_case(routing, dtype)
+    built = metrics.registry().counter("oobleck_moe_token_sum_kernels_total")
+    before = built.value()
+    got = moe.token_sum_call(rows, plan, tile=tile, weighted=weighted,
+                             chunk=chunk)
+    assert built.value() - before == 1
+    want = moe._tokens_from_rows(rows, plan, tile, K, SUM_T,
+                                 weights if weighted else None)
+    assert got.dtype == rows.dtype and np.isfinite(
+        np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want.astype(dtype), np.float32))
+    held_picks = (local < 4).sum(axis=1)
+    assert not np.asarray(got, np.float32)[held_picks == 0].any()
+    if routing == "as_routed":      # what the case is there for
+        assert (held_picks == 0).any() and not (local == 1).any()
+    if routing == "a_block_on_one_expert":
+        assert (np.asarray(plan.run_len).reshape(-1, 4)[:, 2] == 32).all()
+    if routing == "a_run_off_a_sublane_boundary":
+        assert np.asarray(plan.run_first).reshape(-1, 4)[1, 0] % moe.SUBLANE == 5
+
+
+def test_unrounded_weights_agree_to_the_last_place(interpreted):
+    """The same sum with float32 weights as `route` gives them: the loop
+    rounds weight x row and then the sum, the interpreter's one XLA:CPU
+    program may fuse the two (one rounding): a unit in the last place of a
+    partial sum, never more."""
+    plan, rows, weights, tile, chunk, _ = _sum_case("as_routed", jnp.float32)
+    weights = weights * (1 + jnp.float32(2.0 ** -12))
+    plan = plan._replace(w_held=plan.w_held * (1 + jnp.float32(2.0 ** -12)))
+    got = moe.token_sum_call(rows, plan, tile=tile, weighted=True)
+    want = moe._tokens_from_rows(rows, plan, tile, K, SUM_T, weights)
+    scale = np.abs(np.asarray(rows[:int(plan.num_tiles[0]) * tile])).max()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=2 * K * scale * 2.0 ** -24)
+
+
+@pytest.mark.parametrize("routing", sorted(TOKEN_SUM_ROUTINGS))
+def test_the_plan_s_rows_by_token_are_the_rows_the_tiles_hold(routing):
+    """`token_runs` against a walk over the tiles in use, as the loops make
+    it: `src_row[t, e]` is the row that holds token t's pair on held expert
+    e, `w_held` its weight, and the rows an expert gives a block of tokens
+    are `run_len` from `run_first` on, ascending with the tokens. That
+    rests on a token's picks being DISTINCT (`lax.top_k`'s are;
+    `forced_experts` are a reference's own top-k): with a pick named twice
+    the plan has one row where the tiles hold two, which is the caller's to
+    rule out and is not checked inside the traced program."""
+    plan, _, weights, tile, _, local = _sum_case(routing, jnp.float32)
+    _, block, _ = TOKEN_SUM_ROUTINGS[routing]
+    assert all(len(set(row[row < 4])) == (row < 4).sum() for row in local)
+    order, first = np.asarray(plan.order), np.asarray(plan.tile_first)
+    src_row = np.full((SUM_T, 4), -1)
+    w_held = np.zeros((SUM_T, 4), np.float32)
+    for i in range(int(plan.num_tiles[0])):
+        for r in range(int(plan.tile_rows[i])):
+            pair, e = order[first[i] + r], int(plan.tile_group[i])
+            assert src_row[pair // K, e] == -1
+            src_row[pair // K, e] = i * tile + r
+            w_held[pair // K, e] = np.asarray(weights).reshape(-1)[pair]
+    np.testing.assert_array_equal(np.asarray(plan.src_row), src_row)
+    np.testing.assert_array_equal(np.asarray(plan.w_held), w_held)
+    run_first = np.asarray(plan.run_first).reshape(-1, 4)
+    run_len = np.asarray(plan.run_len).reshape(-1, 4)
+    for b in range(SUM_T // block):
+        for e in range(4):
+            mine = src_row[b * block:(b + 1) * block, e]
+            np.testing.assert_array_equal(
+                mine[mine >= 0], run_first[b, e] + np.arange(run_len[b, e]))
+
+
+def test_the_kernels_bodies_call_no_jitted_helper(layer, interpreted):
+    """No expert kernel's body holds an inner `jit` call (`//` and `%` on
+    a traced integer are `jnp.floor_divide` / `jnp.remainder`, and
+    `jnp.where`, `jnp.sum`, `jnp.dot` are jitted too). Such a helper's
+    jaxpr is cached with the source location of its FIRST trace in the
+    process; a Mosaic kernel's serialized body carries that location and
+    the persistent compile cache's key is computed from the body: with
+    `//` in `moe_token_sum`, a cell's second run (which reaches the
+    stage's first trace by another call stack than the cold run, which
+    profiles the layers first) missed the cache and compiled `jit_bwd`
+    again, 54 s of `setup_s` (my chip run, PR 52)."""
+    from tests.ops.programs import all_eqns
+
+    grads = jax.value_and_grad(lambda x: jnp.sum(_share(
+        dict(layer, x=x), 2, 4)))
+    kernels = [e for e in all_eqns(jax.make_jaxpr(grads)(layer["x"]).jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert {e.params["name"] for e in kernels} == {
+        "moe_gmm", "moe_tgmm", "moe_token_sum"}
+    for kernel in kernels:
+        inner = {e.primitive.name for e in all_eqns(kernel.params["jaxpr"])}
+        assert not inner & {"jit", "pjit", "closed_call", "core_call"}, (
+            kernel.params["name"], sorted(inner))
+
+
+# cell -> its call (tokens a microbatch, D, picks, experts), the block of
+# tokens and of columns a grid step of `moe_token_sum` owns there, and the
+# rows a block EXPECTS of a held expert, in a chunk of 128.
+CELL_TOKEN_BLOCKS = {
+    "lfm2-24b-a2b": ((8192, 2048, 4, 64), (1024, 1024), 64),
+    "moonlight-16b-a3b": ((4096, 2048, 6, 64), (512, 2048), 48),
+    "nemotron-3-nano-30b-a3b": ((4096, 2688, 6, 128), (1024, 896), 48),
+    "qwen3-next-80b-a3b": ((4096, 2048, 10, 512), (1024, 1024), 20),
+    "smallthinker-21b-a3b": ((16384, 2560, 6, 64), (512, 2560), 48),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_TOKEN_BLOCKS))
+def test_token_blocks_follow_the_shapes(cell):
+    (tokens, d, top_k, experts), blocks, expected = CELL_TOKEN_BLOCKS[cell]
+    tile = CELL_TILES[cell][1]
+    block = moe.choose_token_block(tokens, top_k, experts, tile)
+    assert (block, moe._sum_col_tile(d, block)) == blocks
+    assert block * top_k / experts == expected <= moe._sum_chunk(tile) / 2
+    # The tests' sizes: a block the tokens divide, or all of them.
+    assert moe.choose_token_block(48, 2, 8, 32) == 48
+    assert moe.choose_token_block(50, 2, 8, 32) == 50
+    assert moe._sum_col_tile(40, 48) == 40
 
 
 def test_odd_widths_are_taken_whole_by_the_kernels():
@@ -935,14 +1125,15 @@ def test_the_reglu_shares_add_up_to_the_uncut_layer(layer, router_rows):
 def test_a_reglu_call_holds_swiglu_s_nine_products(layer, router_rows,
                                                    monkeypatch):
     """Three grouped products forward, three dX and three dW backward: the
-    kernels are SwiGLU's, under the same names."""
+    kernels are SwiGLU's, under the same names, and so are the two sums of
+    rows into tokens."""
     from tests.ops.programs import pallas_calls
 
     monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
     monkeypatch.setattr(moe, "_interpret", lambda: True)
     fn = lambda y: jnp.sum(_reglu_share(dict(layer, x=y), router_rows, 2, 4))
     names = [n for n, _ in pallas_calls(jax.make_jaxpr(fn)(layer["x"]).jaxpr)]
-    assert names == ["moe_gmm"] * 3
+    assert names == ["moe_gmm"] * 3 + ["moe_token_sum"]
     grads = jax.grad(lambda y, w1: jnp.sum(moe.routed_experts(
         y, layer["router"], None, w1, layer["w3"][2:6], layer["w2"][2:6],
         num_experts=NE, top_k=K, expert_offset=2, score="softmax",
@@ -951,8 +1142,9 @@ def test_a_reglu_call_holds_swiglu_s_nine_products(layer, router_rows,
         jax.make_jaxpr(grads)(layer["x"], layer["w1"][2:6]).jaxpr)]
     # (The jaxpr also holds `_gate_and_up`'s forward products again, which
     # its backward rule traces to pull through and XLA drops.)
-    assert set(names) == {"moe_gmm", "moe_tgmm"}
+    assert set(names) == {"moe_gmm", "moe_tgmm", "moe_token_sum"}
     assert names.count("moe_tgmm") == 3
+    assert names.count("moe_token_sum") == 2
 
 
 def test_reglu_and_early_router_calls_are_counted_where_they_are_built(

@@ -256,7 +256,9 @@ def _routed_sum(top_k):
 
 
 def _routed_grads(top_k):
-    return jax.grad(_routed_sum(top_k), argnums=(0, 1, 3, 4, 5))
+    # The value too, as a stage's `jit_bwd` asks for the loss: the gradient
+    # alone needs no y, and the combine's sum would be dead code.
+    return jax.value_and_grad(_routed_sum(top_k), argnums=(0, 1, 3, 4, 5))
 
 
 def _routed_shapes(t, d, f, ne, held, top_k):
@@ -284,15 +286,26 @@ def _the_row_buffers_are_allocated_not_filled(text, t, d, f, ne, held, top_k):
     return len(allocated)
 
 
+def _token_sums(text):
+    """`moe_token_sum` calls of a compiled routed program, which holds no
+    scatter into a `[tokens, D]` array: the loop of scatter-adds each of
+    them stands in for is off the kernels' path."""
+    assert not re.findall(r"= f32\[\d+,\d+\]\S* scatter\(", text)
+    return len(re.findall(r"%moe_token_sum[.\d]* = ", text))
+
+
 @pytest.mark.parametrize("width", sorted(MOE_WIDTHS))
 @pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
 def test_routed_experts_compile(v5e, width, mode):
     top_k = MOE_WIDTHS[width][-1]
     fn = _routed_sum(top_k) if mode == "fwd" else _routed_grads(top_k)
     text = _compile(fn, v5e[0], *_routed_shapes(*MOE_WIDTHS[width]))
-    # Three products forward; three dX and three dW more backward.
+    # Three products and the combine's sum forward; three dX, three dW and
+    # the dispatch's sum more backward (`moe_token_sum`: each sum ONE
+    # kernel, and no float32 [tokens, D] scatter in a loop).
     assert text.count('custom_call_target="tpu_custom_call"') == (
-        3 if mode == "fwd" else 9)
+        4 if mode == "fwd" else 11)
+    assert _token_sums(text) == (1 if mode == "fwd" else 2)
     if mode == "fwd_bwd":
         # The dispatch and the activation; d rows of the combine, d gate
         # and d up, the sum of the two d rows.
@@ -326,13 +339,15 @@ def _ungated_sum(top_k):
 @pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
 def test_ungated_experts_compile(v5e, width, mode):
     t, d, f, ne, held, top_k = UNGATED_WIDTHS[width]
-    fn = _ungated_sum(top_k) if mode == "fwd" else jax.grad(
+    fn = _ungated_sum(top_k) if mode == "fwd" else jax.value_and_grad(
         _ungated_sum(top_k), argnums=(0, 1, 3, 4))
     shapes = _routed_shapes(t, d, f, ne, held, top_k)
     text = _compile(fn, v5e[0], *shapes[:4], shapes[5])
-    # Two products forward; two dX and two dW more backward.
+    # Two products and the combine's sum forward; two dX, two dW and the
+    # dispatch's sum more backward.
     assert text.count('custom_call_target="tpu_custom_call"') == (
-        2 if mode == "fwd" else 6)
+        3 if mode == "fwd" else 8)
+    assert _token_sums(text) == (1 if mode == "fwd" else 2)
     if mode == "fwd_bwd":
         assert _the_row_buffers_are_allocated_not_filled(
             text, *UNGATED_WIDTHS[width]) == 4
@@ -341,9 +356,11 @@ def test_ungated_experts_compile(v5e, width, mode):
 
 
 # A routed block's host cost at process start, as flash's below: the
-# gradient of one routed layer at the cell's shapes lowers to 112 k
-# characters (nine kernels with small bodies, the plan's sort, a dozen loops
-# over the row tiles in use); the limit leaves room for a quarter more.
+# gradient of one routed layer at the cell's shapes lowers to 127 k
+# characters (nine kernels with small bodies, the two sums' ONE body, which
+# loops over the held experts and is not unrolled over them, the plan's
+# sort, ten loops over the row tiles in use); the limit leaves room for a
+# tenth more.
 ROUTED_GRAD_MODULE_CHARS = 140_000
 
 
@@ -353,7 +370,7 @@ def test_routed_grad_module_stays_small(v5e):
             _routed_shapes(*MOE_WIDTHS["lfm2-24b-a2b-cell"])]
     text = jax.jit(_routed_grads(
         MOE_WIDTHS["lfm2-24b-a2b-cell"][-1])).lower(*args).as_text()
-    assert text.count("tpu_custom_call") == 9
+    assert text.count("tpu_custom_call") == 11
     assert len(text) < ROUTED_GRAD_MODULE_CHARS, len(text)
 
 
@@ -499,7 +516,8 @@ def test_rotary_alone_is_one_pass_over_its_operand(v5e, mode):
 # activation="reglu", router_x=)`), value and gradient, at the same cell's
 # call: 16384 tokens, 8 of 64 experts of 2560 x 768, top 6. 1,536 rows
 # expected an expert sit on the edge of every tile up to 512: 1024-row
-# tiles, a 106,496-row buffer. The kernels are SwiGLU's nine, the XLA
+# tiles, a 106,496-row buffer. The kernels are SwiGLU's nine and the two
+# sums (`moe_token_sum`: blocks of 512 tokens x all 2560 columns), the XLA
 # between them differs.
 def test_reglu_experts_with_a_router_of_their_own_compile(v5e):
     from oobleck_tpu.ops.moe import routed_experts
@@ -514,8 +532,10 @@ def test_reglu_experts_with_a_router_of_their_own_compile(v5e):
 
     shapes = _routed_shapes(t, d, f, ne, held, top_k)
     shapes[2] = shapes[0]                       # the router's rows, not a bias
-    text = _compile(jax.grad(fn, argnums=(0, 1, 2, 3, 4, 5)), v5e[0], *shapes)
-    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    text = _compile(jax.value_and_grad(fn, argnums=(0, 1, 2, 3, 4, 5)),
+                    v5e[0], *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 11
+    assert _token_sums(text) == 2
     assert _the_row_buffers_are_allocated_not_filled(
         text, t, d, f, ne, held, top_k) == 6
 
@@ -530,7 +550,7 @@ KERNEL_NAMES = {
     "flash_mla_fwd": "latent", "flash_mla_bwd_dqkv": "latent",
     "flash_swa_fwd": "window", "flash_swa_bwd_dqkv": "window",
     "paged_decode": "decode", "paged_verify": "verify",
-    "moe_gmm": "moe", "moe_tgmm": "moe",
+    "moe_gmm": "moe", "moe_tgmm": "moe", "moe_token_sum": "moe",
 }
 
 
@@ -648,7 +668,7 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
     calls = _kernel_calls(_lowered_backward(cell, tuple(v5e))[0])
     # The rooflines and `moe_*_ms` match `%moe_gmm.` / `%moe_tgmm.`.
     assert {n for n, _ in calls if n.startswith("moe")} == {
-        "moe_gmm", "moe_tgmm"}
+        "moe_gmm", "moe_tgmm", "moe_token_sum"}
     dw = [attrs for n, attrs in calls if n == "moe_tgmm"]
     assert len(dw) == sums
     for attrs in dw:        # operands: two tables, rows, rows, the sum
@@ -664,22 +684,26 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
 # `gpt3-2.7b`'s as PR 47 left it (every cell's stage holds a flash backward,
 # and each text got SHORTER by the second backward call of each attention
 # layer: 3.1 k in `gpt3-2.7b`'s three blocks). The five routed cells' as
-# PR 51 left them: every whole-buffer zero fill of a routed layer (a
-# constant and a `broadcast_in_dim`, eight a gated layer and six an ungated
-# one with the recompute) became one `AllocateBuffer` custom call
-# (`moe._unwritten`), 2.8 k less a cell's text (1.6 k in the ungated cell's
-# three routed layers, 4.4 k in `qwen3-next-80b-a3b`'s); `gpt3-2.7b` has no
+# PR 52 left them: each routed layer's sums of rows into tokens (the
+# combine's, forward and recomputed, and the dispatch's dx) are ONE
+# `moe_token_sum` call each where a `while` around a float32 [tokens, D]
+# scatter-add stood, and the plan gained the tokens' side
+# (`moe.token_runs`: a cumulative sum, four small arrays): 1.4 k less in
+# `lfm2-24b-a2b`'s and `moonlight-16b-a3b`'s text, 0.9 k in the ungated
+# cell's three routed layers, 1.2 k in `smallthinker-21b-a3b`'s, 0.6 k MORE
+# in `qwen3-next-80b-a3b`'s; before it PR 51 made every whole-buffer zero
+# fill an `AllocateBuffer` custom call (`moe._unwritten`). `gpt3-2.7b` has no
 # routed layer and its pair stood. A PR that changes what one of these programs
 # computes takes its new text's pair from a failing run; one that leaves a
 # pair standing has shown that the program bypasses its change (PR 46's
 # rotary left `gpt3-2.7b`'s and `nemotron-3-nano-30b-a3b`'s).
 LOWERED = {
     "gpt3-2.7b": ("f815b23b0da3328e", 169829),
-    "lfm2-24b-a2b": ("209db291359e76b0", 615621),
-    "moonlight-16b-a3b": ("88e7b8f9e215f63c", 772517),
-    "nemotron-3-nano-30b-a3b": ("b7c4627cd9176e48", 656248),
-    "qwen3-next-80b-a3b": ("8b7a32c5e894cd98", 1043197),
-    "smallthinker-21b-a3b": ("9cc5115f05283ed4", 677869),
+    "lfm2-24b-a2b": ("eab93850ea8613bd", 614222),
+    "moonlight-16b-a3b": ("49a3b8f2ddf364a7", 771085),
+    "nemotron-3-nano-30b-a3b": ("e607038bf59e8ea0", 655370),
+    "qwen3-next-80b-a3b": ("281d8f36e6f2551e", 1043793),
+    "smallthinker-21b-a3b": ("8e162b7d6c4c371a", 676696),
 }
 
 
