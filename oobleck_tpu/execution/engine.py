@@ -51,7 +51,7 @@ from oobleck_tpu.execution.dataloader import (
     PrefetchingLoader,
 )
 from oobleck_tpu.execution.dataset import build_dataset
-from oobleck_tpu.execution.pipeline import PipelineInstance
+from oobleck_tpu.execution.pipeline import PROGRAMS, PipelineInstance
 from oobleck_tpu.execution.reconfigure import (
     fit_host_groups,
     hosts_to_ranks,
@@ -130,6 +130,56 @@ class DeferredLoss:
         ) / max(1, total)
 
 
+# The data-parallel programs that bake nothing in are jitted once, here (jit
+# keys each by its operands' trees and shardings); the others: `PROGRAMS`.
+
+
+@jax.jit
+def pack_flat(trees: list):
+    """One flat f32 buffer from same-mesh trees (single fused program)."""
+    return jnp.concatenate([
+        l.ravel().astype(jnp.float32)
+        for t in trees for l in jax.tree.leaves(t)])
+
+
+def _slices(flat, leaves):
+    """`flat` cut into the shapes and dtypes of `leaves`, in their order."""
+    off = 0
+    for l in leaves:
+        yield flat[off:off + l.size].reshape(l.shape).astype(l.dtype)
+        off += l.size
+
+
+@jax.jit
+def unpack_add(flat, trees: list) -> list:
+    """trees[i] + slices-of-flat, one jitted program on the dst mesh."""
+    leaves, struct = jax.tree.flatten(trees)
+    return jax.tree.unflatten(
+        struct, [l + seg for l, seg in zip(leaves, _slices(flat, leaves))])
+
+
+def unpack_to(flat, likes: list, shardings: list) -> list:
+    """Slices of flat in the trees, shapes and dtypes of `likes`, placed on
+    `shardings`: one jitted program with explicit out_shardings on the dst
+    mesh, which key it in `PROGRAMS` beside the shapes (identical shapes on
+    different destination stages need different out_shardings)."""
+    leaves, struct = jax.tree.flatten(jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), likes))
+    key = ("unpack_to", tuple((l.shape, l.dtype) for l in leaves), struct,
+           tuple(jax.tree.leaves(shardings)))
+    if key not in PROGRAMS:
+        def unpack(f):
+            return jax.tree.unflatten(struct, list(_slices(f, leaves)))
+        PROGRAMS[key] = jax.jit(unpack, out_shardings=shardings)
+    return PROGRAMS[key](flat)
+
+
+@jax.jit
+def sum_trees(per_tree: list) -> list:
+    """Leaf-wise sum of equal lists of leaves."""
+    return [sum(g[1:], start=g[0]) for g in zip(*per_tree)]
+
+
 class DataParallelEngine:
     """Layer-granularity gradient sync across heterogeneous pipelines
     (reference engine.py:363-412): each layer's grads are summed over every
@@ -149,7 +199,6 @@ class DataParallelEngine:
         for p in pipelines:
             for li in p.params:
                 self.owners.setdefault(li, []).append(p)
-        self._jit_cache: dict = {}
         # Observability for tests/benchmarks: batched cross-mesh device_put
         # calls issued by the last do_allreduce (at most one per phase).
         self.last_transfer_count = 0
@@ -160,70 +209,6 @@ class DataParallelEngine:
     def _group_key(pipe: PipelineInstance, li: int) -> tuple:
         """Transfer-group key: the stage (sub-mesh) owning layer li."""
         return (pipe.pipeline_id, pipe.stage_of_layer(li))
-
-    def _pack(self, trees: list) -> Any:
-        """One flat f32 buffer from same-mesh trees (single fused program)."""
-        sig = ("pack",
-               tuple((l.shape, str(l.dtype))
-                     for t in trees for l in jax.tree.leaves(t)))
-        if sig not in self._jit_cache:
-            def pack(ts):
-                leaves = [l for t in ts for l in jax.tree.leaves(t)]
-                return jnp.concatenate(
-                    [l.ravel().astype(jnp.float32) for l in leaves]
-                )
-            self._jit_cache[sig] = jax.jit(pack)
-        return self._jit_cache[sig](trees)
-
-    def _unpack_add(self, flat: Any, trees: list) -> list:
-        """trees[i] + slices-of-flat, one jitted program on the dst mesh."""
-        sig = ("unpack_add",
-               tuple((l.shape, str(l.dtype))
-                     for t in trees for l in jax.tree.leaves(t)))
-        if sig not in self._jit_cache:
-            def unpack(f, ts):
-                out, off = [], 0
-                for t in ts:
-                    leaves, struct = jax.tree.flatten(t)
-                    new = []
-                    for l in leaves:
-                        seg = f[off:off + l.size].reshape(l.shape).astype(l.dtype)
-                        new.append(l + seg)
-                        off += l.size
-                    out.append(jax.tree.unflatten(struct, new))
-                return out
-            self._jit_cache[sig] = jax.jit(unpack)
-        return self._jit_cache[sig](flat, trees)
-
-    def _unpack_to(self, flat: Any, metas: list, shardings: list,
-                   group: tuple) -> list:
-        """Slice flat into trees with `metas` shapes, placed on `shardings`
-        (one jitted program with explicit out_shardings on the dst mesh).
-        `group` keys the cache: identical shapes on different destination
-        stages need different baked-in out_shardings."""
-        sig = ("unpack_to", group,
-               tuple((shape, str(dtype))
-                     for layer in metas for shape, dtype in layer[0]))
-        if sig not in self._jit_cache:
-            structs = [struct for _, struct in metas]
-            leaf_metas = [lm for lm, _ in metas]
-
-            def unpack(f):
-                out, off = [], 0
-                for lm, struct in zip(leaf_metas, structs):
-                    new = []
-                    for shape, dtype in lm:
-                        size = int(np.prod(shape)) if shape else 1
-                        new.append(
-                            f[off:off + size].reshape(shape).astype(dtype)
-                        )
-                        off += size
-                    out.append(jax.tree.unflatten(struct, new))
-                return out
-            self._jit_cache[sig] = jax.jit(
-                unpack, out_shardings=shardings
-            )
-        return self._jit_cache[sig](flat)
 
     def do_allreduce(self) -> dict[int, dict[int, Any]]:
         """Returns {pipeline_id: {layer: synced_grad_tree}}.
@@ -259,7 +244,7 @@ class DataParallelEngine:
         for ((src_id, _), (dst_id, dst_st)), lis in sorted(fwd_groups.items()):
             lis = sorted(lis)
             src, dst = by_id[src_id], by_id[dst_id]
-            flat = self._pack([src.grads[li] for li in lis])
+            flat = pack_flat([src.grads[li] for li in lis])
             sharding = NamedSharding(
                 dst.stages[dst_st].mesh, jax.sharding.PartitionSpec()
             )
@@ -269,7 +254,7 @@ class DataParallelEngine:
             moved = jax.device_put(list(flats), list(dst_shardings))
             self.last_transfer_count += 1
             for lis, flat in zip(group_lis, moved):
-                added = self._unpack_add(flat, [totals[li] for li in lis])
+                added = unpack_add(flat, [totals[li] for li in lis])
                 for li, tree in zip(lis, added):
                     totals[li] = tree
 
@@ -284,7 +269,7 @@ class DataParallelEngine:
         for ((_, _), (dst_id, dst_st)), lis in sorted(bwd_groups.items()):
             lis = sorted(lis)
             dst = by_id[dst_id]
-            flat = self._pack([totals[li] for li in lis])
+            flat = pack_flat([totals[li] for li in lis])
             sharding = NamedSharding(
                 dst.stages[dst_st].mesh, jax.sharding.PartitionSpec()
             )
@@ -294,18 +279,9 @@ class DataParallelEngine:
             moved = jax.device_put(list(flats), list(dst_shardings))
             self.last_transfer_count += 1
             for lis, flat, dst, dst_st in zip(group_lis, moved, dsts, dst_sts):
-                metas = []
-                shardings = []
-                for li in lis:
-                    tree = totals[li]
-                    leaves, struct = jax.tree.flatten(tree)
-                    metas.append(
-                        ([(l.shape, l.dtype) for l in leaves], struct)
-                    )
-                    sh = dst.stages[dst_st].param_shardings[li]
-                    shardings.append(sh)
-                unpacked = self._unpack_to(flat, metas, shardings,
-                                           group=(dst.pipeline_id, dst_st))
+                unpacked = unpack_to(
+                    flat, [totals[li] for li in lis],
+                    [dst.stages[dst_st].param_shardings[li] for li in lis])
                 for li, tree in zip(lis, unpacked):
                     synced[dst.pipeline_id][li] = tree
         return synced
@@ -372,7 +348,9 @@ class MultiHostDataParallelEngine:
         self._wire_layer_group = {
             li: gi for gi, (_, lis) in enumerate(self.groups) for li in lis
         }
-        self._jit_cache: dict = {}
+        # What the layouts are made from, beside a group's layers: for the
+        # keys of the programs that bake one in.
+        self._model_key = (type(model), model.config)
         self.last_transfer_count = 0
         self.last_wire_bytes = 0
         self.n_pipelines = len(pipelines)
@@ -395,8 +373,8 @@ class MultiHostDataParallelEngine:
             all_leaves, self.comm.local_device_sharding
         )
         counts = tuple(len(per_layer[li]) for li in lis)
-        key = ("pack", gi, counts)
-        if key not in self._jit_cache:
+        key = ("pack_group", self._model_key, tuple(lis), counts)
+        if key not in PROGRAMS:
             nleaves = {li: len(layout.leaf_metas[li]) for li in lis}
 
             def pack(leaves):
@@ -418,37 +396,32 @@ class MultiHostDataParallelEngine:
                     jnp.concatenate(segs[dt]) for dt in layout.dtypes
                 )
 
-            self._jit_cache[key] = jax.jit(pack)
-        return self._jit_cache[key](all_leaves)
+            PROGRAMS[key] = jax.jit(pack)
+        return PROGRAMS[key](all_leaves)
 
     def _unpack_layer_device(self, gi: int, totals, li: int):
         """Slice one layer's grad tree out of group gi's reduced vectors,
         on the local device (the subsequent device_put to the stage
         sharding is a D2D placement)."""
-        key = ("unpack", gi, li)
-        if key not in self._jit_cache:
+        key = ("unpack_layer", self._model_key,
+               tuple(self.groups[gi][1]), li)
+        if key not in PROGRAMS:
             layout = self.layouts[gi]
             def unpack_layer(vs, _li=li):
                 return layout.unpack(vs, _li)
 
-            self._jit_cache[key] = jax.jit(unpack_layer)
-        return self._jit_cache[key](totals)
+            PROGRAMS[key] = jax.jit(unpack_layer)
+        return PROGRAMS[key](totals)
 
     def _local_sum(self, trees: list):
         """Sum same-layer grads from multiple LOCAL pipelines (no wire)."""
         if len(trees) == 1:
             return trees[0]
-        leaves = [l for t in trees for l in jax.tree.leaves(t)]
-        leaves = jax.device_put(leaves, self.comm.local_device_sharding)
-        n = len(jax.tree.leaves(trees[0]))
-        struct = jax.tree.structure(trees[0])
-        key = ("localsum", len(trees), n, struct)
-        if key not in self._jit_cache:
-            def add(ls):
-                per_tree = [ls[i * n:(i + 1) * n] for i in range(len(trees))]
-                return [sum(g[1:], start=g[0]) for g in zip(*per_tree)]
-            self._jit_cache[key] = jax.jit(add)
-        return jax.tree.unflatten(struct, self._jit_cache[key](leaves))
+        per_tree = jax.device_put(
+            [jax.tree.leaves(t) for t in trees],
+            self.comm.local_device_sharding)
+        return jax.tree.unflatten(
+            jax.tree.structure(trees[0]), sum_trees(per_tree))
 
     def allreduce(self, local_losses: dict[int, tuple[float, int]]
                   ) -> tuple[dict[int, dict[int, Any]], float]:
@@ -664,7 +637,6 @@ class OobleckEngine:
         self.plan: HeterogeneousPlan | None = None
         self.dp_engine: DataParallelEngine | None = None
         self.step = 0
-        self._exec_cache: dict = {}
         # Async-dispatch state: device-resident losses awaiting readback
         # (loss_readback_every > 1) and the resolved (step, loss) history —
         # identical in content between deferred and per-step readback, which
@@ -1430,7 +1402,7 @@ class OobleckEngine:
         stages, enough layers), else 1 — with a flight-recorder event so a
         silent fallback after reconfiguration is diagnosable. The recovery
         precompiler calls this with record=False for PREDICTED plans (same
-        decision, hence same exec-cache keys, without logging a fallback
+        decision, hence same program keys, without logging a fallback
         that has not happened)."""
         v = self.args.execution.resolved_virtual_stages
         if v <= 1 or num_stages <= 1:
@@ -1526,7 +1498,6 @@ class OobleckEngine:
                 microbatch_size=self.args.job.microbatch_size,
                 seq_len=self.seq_len,
                 params=old_params,
-                exec_cache=self._exec_cache,
                 tensor_parallel=self.args.execution.tensor_parallel,
                 sequence_parallel=self.args.execution.sequence_parallel,
                 fsdp=self.args.execution.fsdp,
@@ -2846,7 +2817,7 @@ class OobleckEngine:
         OOBLECK_PRECOMPILE=0) or when there is no MPMD plan to predict from
         (fused path recovers by mesh shrink — same program geometry class,
         not a template re-match). Where the persistent cache is off (the
-        CPU backend) the walk still warms the in-process exec cache an
+        CPU backend) the walk still fills the process's `PROGRAMS`, which an
         in-place reconfigure reuses; only the respawn path goes cold.
         `wait=True` blocks until warm — tests that inject a failure at
         a fixed early step need the warmth guaranteed, production wants the

@@ -35,9 +35,10 @@ single-controller JAX runtime:
     experts' dW, `ops/moe.py`) the model says so and the sum goes down into
     the kernel, which starts from it: on a one-device stage no pass over a
     gradient only adds;
-  * compiled stage executables are cached by stage signature so
-    re-instantiation after a failure reuses them — the pre-compile-per-
-    template idea from SURVEY §7.3.1.
+  * the jitted programs live in ONE table for the life of the process
+    (`PROGRAMS`), keyed by everything their traces read: re-instantiation
+    after a failure finds what the recovery precompiler's predicted layouts
+    built — the pre-compile-per-template idea from SURVEY §7.3.1.
 """
 
 from __future__ import annotations
@@ -175,17 +176,28 @@ def _accumulate(acc, backward, *, layers, in_kernel):
     return new, rest
 
 
-def make_optimizer_update(optimizer):
-    """The per-layer optimizer step as a named function
-    (`jit_optimizer_update` in a device trace). PipelineInstance and the
-    recovery precompiler both jit THIS, so the precompiled program is the
-    one the live path runs."""
+# Every jitted program of this process that bakes something in (a model, a
+# mesh, an optimizer, a flat layout), by a key of VALUES: all its trace reads
+# that its operands do not carry. The process never empties it, so a
+# reconfiguration, the recovery precompiler and a second engine find what
+# was built before. No `id` keys anything: the table outlives the object.
+PROGRAMS: dict[tuple, Any] = {}
 
-    def optimizer_update(g, state, p):
-        updates, new_state = optimizer.update(g, state, p)
-        return optax.apply_updates(p, updates), new_state
 
-    return optimizer_update
+def optimizer_update_program(optimizer):
+    """THE jitted per-layer step (`jit_optimizer_update` in a device trace)
+    of every optimizer built from `optimizer`'s arguments
+    (`parallel/train.Optimizer.built_from`). PipelineInstance and the
+    recovery precompiler both take it from here, so the precompiled program
+    is the one the live path runs."""
+    key = ("optimizer_update", optimizer.built_from)
+    if key not in PROGRAMS:
+        def optimizer_update(g, state, p):
+            updates, new_state = optimizer.update(g, state, p)
+            return optax.apply_updates(p, updates), new_state
+
+        PROGRAMS[key] = jax.jit(optimizer_update)
+    return PROGRAMS[key]
 
 
 @dataclass
@@ -254,7 +266,6 @@ class PipelineInstance:
         microbatch_size: int,
         seq_len: int,
         params: dict[int, Any] | None = None,
-        exec_cache: dict | None = None,
         tensor_parallel: int = 1,
         sequence_parallel: int = 1,
         fsdp: int = -1,
@@ -290,7 +301,6 @@ class PipelineInstance:
         self.total_num_microbatches = total_num_microbatches
         self.microbatch_size = microbatch_size
         self.seq_len = seq_len
-        self._exec_cache = exec_cache if exec_cache is not None else {}
         self.comm = comm
         self._process_of_rank = process_of_rank
         # Filled by each train_step: per-stage dispatch busy seconds, read
@@ -571,6 +581,49 @@ class PipelineInstance:
 
     # ------------------------------------------------------------------ #
 
+    def stage_program_key(self, st: StageRuntime, c: int) -> tuple:
+        """The key of stage `st`'s chunk `c` in `PROGRAMS`: everything the
+        traces of its `fwd` / `bwd` / `eval_fwd` / `grad_zero` read that is
+        not an operand. A field a trace reads and this key lacks is a wrong
+        program handed out silently, so this is the list, by reader.
+        `_stage_apply`:
+          * the model: its class (the methods called, `num_pipeline_layers`)
+            and its config (`remat`, the dtype, every width). A model IS its
+            class and its frozen config: what else an instance holds is
+            made from the config, and nothing in `models/` or `ops/` reads
+            the environment;
+          * the chunk's layers, which also say whether it is the first
+            (layer 0: no `x`, reads the tokens) and the last (the model's
+            last layer: returns the loss);
+          * `st.ctx` and the specs of `x` and the tokens: `st.tp`, `st.sp`,
+            `st.use_fsdp` (`st.manual` is the model's class's);
+          * `st.mesh`, which the `shard_map` is built over: the stage's
+            DEVICES in their (fsdp, seq, tensor) arrangement, not their
+            ranks in one engine's list;
+          * `st.param_pspecs`: the model's specs projected on `use_fsdp`
+            and `tp > 1`, all above;
+          * `microbatch_size`, `seq_len`: the manual loss's divisor.
+        `_build_stage_fns`:
+          * `total_num_microbatches`, the loss's scale in `bwd`;
+          * `st.param_shardings`, the gradient sum's `out_shardings`:
+            `st.param_pspecs` on `st.mesh`;
+          * whether the last chunk has an eval program: the class, `st.ctx`.
+        `_sums_in_kernel`, `_accumulate`:
+          * the marks themselves, flattened (the model's `sums_in_kernel`,
+            `st.mesh.size` and the backend decide them).
+        Not in the key, because a process has ONE: the backend
+        (`ops/attention._pallas_ok`) and JAX's configuration flags. Read by
+        no trace: the pipeline's id and own microbatch count
+        (`adopt_microbatches`), the stage's ranks and owning process."""
+        layers = st.chunks[c]
+        marks, tree = jax.tree.flatten(
+            tuple(self._sums_in_kernel(st, li) for li in layers))
+        return (
+            type(self.model), self.model.config, layers, st.mesh,
+            st.tp, st.sp, st.use_fsdp, self.microbatch_size, self.seq_len,
+            self.total_num_microbatches, tuple(marks), tree,
+        )
+
     def _stage_apply(self, st: StageRuntime, layers: tuple[int, ...]):
         """Stage program over one chunk's contiguous `layers` (== the whole
         stage under canonical 1F1B; one of v chunks interleaved)."""
@@ -688,10 +741,9 @@ class PipelineInstance:
         return apply
 
     def _build_stage_fns(self) -> None:
-        """jit each chunk's forward and (recomputing) backward, with caching
-        keyed by the chunk signature so reconfiguration reuses executables.
-        Under canonical 1F1B each stage has exactly one chunk and the cache
-        key is the stage signature as before.
+        """jit each chunk's forward and (recomputing) backward, or take
+        them from `PROGRAMS` where the process built them before
+        (`stage_program_key`).
 
         Microbatch gradients accumulate INSIDE the backward program: `acc`,
         the chunk's running gradient sum (the parameters' tree, dtypes and
@@ -711,8 +763,7 @@ class PipelineInstance:
         sum, dx): the unscaled microbatch loss its `fwd` would give,
         gradients of loss / total microbatches. train_step never calls that
         chunk's `fwd` (eval_step does)."""
-        S, v = self.num_stages, self.virtual_stages
-        last_vs = S * v - 1
+        last_layer = self.model.num_pipeline_layers - 1
         scale = 1.0 / self.total_num_microbatches
         for st in self.stages:
             st.fwd = [None] * len(st.chunks)
@@ -723,22 +774,16 @@ class PipelineInstance:
             if not st.is_local:
                 continue
             for c, chunk_layers in enumerate(st.chunks):
-                vs = c * S + st.stage_index
-                is_first = vs == 0
-                is_last = vs == last_vs
-                key = (
-                    chunk_layers, len(st.ranks), tuple(st.ranks),
-                    self.microbatch_size, self.seq_len, is_first, is_last,
-                    self.total_num_microbatches, st.tp, st.sp, st.use_fsdp,
-                )
-                if key in self._exec_cache:
-                    (st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c],
-                     st.kernel_sums[c]) = self._exec_cache[key]
-                    continue
-                apply = self._stage_apply(st, chunk_layers)
+                is_last = chunk_layers[-1] == last_layer
                 in_kernel = tuple(
                     self._sums_in_kernel(st, li) for li in chunk_layers)
                 st.kernel_sums[c] = sum(jax.tree.leaves(in_kernel))
+                key = self.stage_program_key(st, c)
+                if key in PROGRAMS:
+                    st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c] = (
+                        PROGRAMS[key])
+                    continue
+                apply = self._stage_apply(st, chunk_layers)
                 accumulate = functools.partial(
                     _accumulate, layers=chunk_layers, in_kernel=in_kernel)
                 # The sum leaves each program where it came in: the
@@ -805,9 +850,8 @@ class PipelineInstance:
                                       with_metrics=True)
 
                     st.efwd[c] = jax.jit(eval_fwd)
-                self._exec_cache[key] = (
-                    st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c],
-                    st.kernel_sums[c])
+                PROGRAMS[key] = (
+                    st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c])
 
     def _sums_in_kernel(self, st: StageRuntime, li: int):
         """The leaves of layer `li` whose running gradient sum goes down
@@ -895,8 +939,7 @@ class PipelineInstance:
         microbatch count from the next train_step on, WITHOUT recompiling.
 
         Safe because nothing compiled depends on the per-pipeline count:
-        the stage executables are keyed on (layers, ranks, microbatch_size,
-        seq_len, total_num_microbatches, ...) — see _build_stage_fns — and
+        no stage program's trace reads it (`stage_program_key`) and
         total_num_microbatches is preserved by rerouting (the borrowed
         microbatches exist either way, so the 1/total gradient scale baked
         into the last stage's backward stays exact). train_step reads
@@ -1221,10 +1264,7 @@ class PipelineInstance:
         recovery hang). No donation: live-mirror snapshots hold references
         to the pre-step arrays (engine._write_mirror), which donation
         would invalidate."""
-        fn = self._exec_cache.get(("opt_update", id(optimizer)))
-        if fn is None:
-            fn = jax.jit(make_optimizer_update(optimizer))
-            self._exec_cache[("opt_update", id(optimizer))] = fn
+        fn = optimizer_update_program(optimizer)
         new_state = dict(opt_state)
         for li in self.params:
             self.params[li], new_state[li] = fn(

@@ -25,10 +25,10 @@ RecoveryPrecompiler closes that gap. On a background thread it
 
 Warmth propagates through two layers:
 
-  * the engine's shared `_exec_cache` holds the predicted plans' jit
-    objects under the same stage-signature keys `_build_stage_fns`
-    computes, so an in-place `reconfigure()` (single-controller) reuses
-    them directly;
+  * building a predicted layout puts its jit objects into the process's
+    one table of programs (`execution/pipeline.PROGRAMS`) under the keys
+    `stage_program_key` gives the live layout's, so an in-place
+    `reconfigure()` (single-controller) finds them there;
   * every AOT compile writes the serialized executable into JAX's
     persistent compilation cache (utils/compile_cache.py), which is what
     survives the respawn-based multi-host recovery — the fresh process
@@ -56,7 +56,7 @@ from typing import Any
 import jax
 import numpy as np
 
-from oobleck_tpu.execution.pipeline import make_optimizer_update
+from oobleck_tpu.execution.pipeline import optimizer_update_program
 from oobleck_tpu.utils import background
 
 logger = logging.getLogger("oobleck.precompile")
@@ -219,7 +219,7 @@ class RecoveryPrecompiler:
     def _predicted_grow(self, live_pipelines):
         """Warm the most likely post-GROW plan: one arriving host folded
         in as new DP pipeline(s) via engine.predict_grow — the SAME fit
-        the live grow_dp arm runs at JOIN time, so the exec-cache keys
+        the live grow_dp arm runs at JOIN time, so the program keys
         match exactly. Only when a free device block exists to bind the
         prediction against (the joiner's chips, by construction, are not
         in engine.devices yet); grow_reshape recompiles by design (every
@@ -280,8 +280,8 @@ class RecoveryPrecompiler:
                     ranks=list(a.ranks),
                     # Same interleave-or-fallback decision reconfigure()
                     # will make for this plan (record=False: a predicted
-                    # fallback is not an event) — required for the chunked
-                    # exec-cache keys to match at failure time.
+                    # fallback is not an event) — required for the chunks'
+                    # program keys to match at failure time.
                     virtual_stages=engine._effective_virtual_stages(
                         a.template.num_stages, a.num_microbatches,
                         a.pipeline_index, record=False,
@@ -293,7 +293,6 @@ class RecoveryPrecompiler:
                     microbatch_size=engine.args.job.microbatch_size,
                     seq_len=engine.seq_len,
                     params=None,
-                    exec_cache=engine._exec_cache,
                     tensor_parallel=engine.args.execution.tensor_parallel,
                     sequence_parallel=engine.args.execution.sequence_parallel,
                     fsdp=engine.args.execution.fsdp,
@@ -312,8 +311,7 @@ class RecoveryPrecompiler:
     # -- per-stage AOT -------------------------------------------------- #
 
     def _aot_pipeline(self, pipe) -> None:
-        S, v = pipe.num_stages, pipe.virtual_stages
-        last_vs = S * v - 1
+        last_layer = pipe.model.num_pipeline_layers - 1
         for st in pipe.stages:
             if self._cancel.is_set():
                 return
@@ -322,16 +320,9 @@ class RecoveryPrecompiler:
             for c, chunk_layers in enumerate(st.chunks):
                 if self._cancel.is_set():
                     return
-                vs = c * S + st.stage_index
-                is_first = vs == 0
-                is_last = vs == last_vs
-                # Byte-identical to the chunk signature _build_stage_fns
-                # keys the shared exec cache with.
-                key = (
-                    chunk_layers, len(st.ranks), tuple(st.ranks),
-                    pipe.microbatch_size, pipe.seq_len, is_first, is_last,
-                    pipe.total_num_microbatches, st.tp, st.sp, st.use_fsdp,
-                )
+                is_first = chunk_layers[0] == 0
+                is_last = chunk_layers[-1] == last_layer
+                key = pipe.stage_program_key(st, c)
                 if key in self._done_keys:
                     self.stats["stages_cached"] += 1
                     continue
@@ -422,24 +413,14 @@ class RecoveryPrecompiler:
         from jax.sharding import NamedSharding, PartitionSpec
 
         optimizer = self.engine.optimizer
-        cache = self.engine._exec_cache
-        fn = cache.get(("opt_update", id(optimizer)))
-        if fn is None:
-            fn = jax.jit(make_optimizer_update(optimizer))
-            cache[("opt_update", id(optimizer))] = fn
-        replicated_of = {}
+        fn = optimizer_update_program(optimizer)
+        replicated = NamedSharding(st.mesh, PartitionSpec())
         for li, p_aval in zip(layer_ids, params_avals):
             key = ("opt_update",
                    tuple(str(a) for a in jax.tree.leaves(p_aval)))
             if key in self._done_keys:
                 continue
             sharding_tree = st.param_shardings[li]
-            mesh = jax.tree.leaves(
-                sharding_tree, is_leaf=lambda x: hasattr(x, "mesh")
-            )[0].mesh
-            if id(mesh) not in replicated_of:
-                replicated_of[id(mesh)] = NamedSharding(mesh, PartitionSpec())
-            replicated = replicated_of[id(mesh)]
             # Mirrors engine._place_opt_state: Adam mu/nu avals take the
             # param shardings, scalar bookkeeping leaves go replicated.
             state_aval = optax.tree_map_params(

@@ -38,14 +38,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from oobleck_tpu.execution.pipeline import PROGRAMS
+
 
 class ProcessComm:
-    """Collectives over jax.distributed processes (cached meshes + jits)."""
+    """Collectives over jax.distributed processes (cached meshes; the
+    jitted programs are in the process's `PROGRAMS`, by the process mesh
+    or the flat layout each bakes in)."""
 
     def __init__(self):
         self._mesh_cache: dict[tuple[int, ...], Mesh] = {}
-        self._jit_cache: dict[tuple, Any] = {}
-        self._layout_cache: dict[tuple, "TypedFlatLayout"] = {}
         self._local_device = jax.local_devices()[0]
         self.process_index = jax.process_index()
         self.process_count = jax.process_count()
@@ -94,13 +96,13 @@ class ProcessComm:
         garr = jax.make_array_from_single_device_arrays(
             (n, length), sharding, [row]
         )
-        key = (participants, n, length, op, np.dtype(dtype).name)
-        if key not in self._jit_cache:
+        key = ("reduce", mesh, op)
+        if key not in PROGRAMS:
             fn = {"sum": lambda a: a.sum(0), "min": lambda a: a.min(0)}[op]
-            self._jit_cache[key] = jax.jit(
+            PROGRAMS[key] = jax.jit(
                 fn, out_shardings=NamedSharding(mesh, P())
             )
-        out = self._jit_cache[key](garr)
+        out = PROGRAMS[key](garr)
         return out.addressable_data(0)
 
     # -- public primitives ---------------------------------------------- #
@@ -146,25 +148,21 @@ class ProcessComm:
         carries NATIVE dtypes (one flat vector per distinct leaf dtype):
         bf16 activations cost bf16 bytes, and the receiver's zero
         contribution keeps the sum bit-exact."""
-        sig = tuple((tuple(l.shape), str(l.dtype))
-                    for l in jax.tree.leaves(aval))
-        if sig not in self._layout_cache:
-            self._layout_cache[sig] = TypedFlatLayout({0: aval})
-        layout = self._layout_cache[sig]
-        struct = jax.tree.structure(aval)
+        key = ("send", tuple((tuple(l.shape), str(l.dtype))
+                             for l in jax.tree.leaves(aval)))
+        if key not in PROGRAMS:
+            made = TypedFlatLayout({0: aval})
+            PROGRAMS[key] = (
+                made,
+                jax.jit(lambda ls: made.pack_leaves(0, ls)),
+                jax.jit(lambda vs: jax.tree.leaves(made.unpack(vs, 0))),
+            )
+        layout, pack, unpack = PROGRAMS[key]
         if self.process_index == src:
             # Consolidate onto the local proc-mesh device (D2D within the
             # host), then fuse ravel/cast/concat in one jitted program.
-            leaves = jax.device_put(
-                jax.tree.leaves(value),
-                jax.sharding.SingleDeviceSharding(self._local_device),
-            )
-            key = ("pack", sig)
-            if key not in self._jit_cache:
-                self._jit_cache[key] = jax.jit(
-                    lambda ls: layout.pack_leaves(0, ls)
-                )
-            vecs = self._jit_cache[key](leaves)
+            vecs = pack(jax.device_put(
+                jax.tree.leaves(value), self.local_device_sharding))
         else:
             vecs = tuple(jnp.zeros(layout.lengths[dt], dt)
                          for dt in layout.dtypes)
@@ -174,12 +172,7 @@ class ProcessComm:
         )
         if self.process_index == src:
             return None
-        key = ("unpack", sig)
-        if key not in self._jit_cache:
-            self._jit_cache[key] = jax.jit(
-                lambda vs: jax.tree.leaves(layout.unpack(vs, 0))
-            )
-        return jax.tree.unflatten(struct, self._jit_cache[key](totals))
+        return jax.tree.unflatten(jax.tree.structure(aval), unpack(totals))
 
 
 # ---------------------------------------------------------------------- #

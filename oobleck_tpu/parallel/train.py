@@ -75,6 +75,16 @@ class StepMetrics(NamedTuple):
     grad_norm: jax.Array
 
 
+class Optimizer(NamedTuple):
+    """An `optax.GradientTransformation` that says what it was built from:
+    `make_optimizer`'s arguments, all its closures read, and so the key of
+    its compiled update (`execution/pipeline.optimizer_update_program`)."""
+
+    init: Any
+    update: Any
+    built_from: tuple
+
+
 def freeze_leaves(inner: optax.GradientTransformation,
                   names: tuple[str, ...]) -> optax.GradientTransformation:
     """`inner`, with every leaf whose dict key is in `names` left out: its
@@ -112,7 +122,7 @@ def make_optimizer(
     weight_decay: float = 0.01,
     max_grad_norm: float = 1.0,
     frozen: tuple[str, ...] = (),
-) -> optax.GradientTransformation:
+) -> Optimizer:
     """AdamW + linear-warmup LR + global-norm clipping.
 
     Matches the reference's optimizer stack (fused AdamW + WarmupLR,
@@ -127,7 +137,11 @@ def make_optimizer(
         optax.clip_by_global_norm(max_grad_norm),
         optax.adamw(schedule, b1=0.9, b2=0.999, weight_decay=weight_decay),
     )
-    return freeze_leaves(optimizer, tuple(frozen)) if frozen else optimizer
+    if frozen:
+        optimizer = freeze_leaves(optimizer, tuple(frozen))
+    return Optimizer(*optimizer, built_from=(
+        learning_rate, warmup_steps, weight_decay, max_grad_norm,
+        tuple(sorted(frozen))))
 
 
 def state_partition_specs(model, optimizer) -> TrainState:

@@ -25,6 +25,7 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 
 
+from oobleck_tpu.execution.pipeline import PROGRAMS
 from oobleck_tpu.utils.compile_cache import ensure_persistent_cache
 
 assert ensure_persistent_cache() is None  # CPU backend: switched off
@@ -69,8 +70,11 @@ def pytest_collection_modifyitems(items):
 def _clear_jax_caches():
     """Drop compiled-executable caches between test modules: the full suite
     compiles hundreds of programs over 8 virtual devices and can exhaust
-    host memory in a single process otherwise."""
+    host memory in a single process otherwise. The process's table of
+    jitted programs goes with them, so engines share programs within a
+    module (which `--dist loadfile` keeps on one worker) and no further."""
     yield
+    PROGRAMS.clear()
     jax.clear_caches()
 
 

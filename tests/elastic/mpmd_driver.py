@@ -94,7 +94,7 @@ def main() -> None:
 
     common = dict(
         model=model, devices=devices, total_num_microbatches=4,
-        microbatch_size=MB, seq_len=SEQ, exec_cache={},
+        microbatch_size=MB, seq_len=SEQ,
         process_of_rank=process_of_rank, comm=comm,
         tensor_parallel=args.tp,
     )

@@ -267,6 +267,14 @@ def test_infeasible_reroute_falls_back_with_decision(cache_env, devices8):
     eng = _dp2_engine(devices8, steps=4)
     eng.args.execution.degrade_max_slowdown = 1.01  # merge costs ~2x
     eng._train_step()
+    # The projection replays each replica's schedule at the op durations
+    # its last step recorded. A first step used to hold its own compiles,
+    # on both replicas alike; one that finds its programs built records
+    # enqueue times, a few of which can differ by the 2x this test is
+    # about. Equal durations: what two replicas of one plan have.
+    for pipe in eng.pipelines:
+        pipe.last_op_times = {
+            op: (0.01 * n, n) for op, (_, n) in pipe.last_op_times.items()}
 
     eng.reconfigure("10.0.0.1")
 

@@ -15,7 +15,7 @@ import jax
 import numpy as np
 import pytest
 
-from oobleck_tpu.execution.pipeline import PipelineInstance
+from oobleck_tpu.execution.pipeline import PROGRAMS, PipelineInstance
 from oobleck_tpu.models import build_model
 from oobleck_tpu.ops import moe
 from oobleck_tpu.planning.templates import PipelineTemplate, StageSpec
@@ -29,8 +29,14 @@ ROUTED = {"lfm2-moe-tiny": 3 * 3, "nemotron-h-tiny": 2 * 2}
 
 @pytest.fixture
 def interpreted(monkeypatch):
+    """The backend is one of the few things a stage program's trace reads
+    and its key leaves out (a process has one): a test that swaps it starts
+    from, and leaves, an empty table of programs."""
+    PROGRAMS.clear()
     monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
     monkeypatch.setattr(moe, "_interpret", lambda: True)
+    yield
+    PROGRAMS.clear()
 
 
 def _train_step(name, *, chips=1, marks=True):

@@ -1,25 +1,28 @@
 """Bounded-time recovery: the RecoveryPrecompiler must make reconfigure()
-planning-free AND compile-free. The predicted-plan walk registers its jitted
-stage programs in the engine's shared exec cache under the exact
-stage-signature keys `_build_stage_fns` computes, so the post-failure
-instantiation cache-hits every stage instead of cold-compiling it (the 480 s
-MoE recovery hang this PR retires)."""
+planning-free AND compile-free. The predicted-plan walk puts its jitted
+stage programs into the process's one table of programs
+(`execution/pipeline.PROGRAMS`) under the keys `stage_program_key` gives, so
+the post-failure instantiation finds every stage there instead of
+cold-compiling it (the 480 s MoE recovery hang this PR retires). The table
+is the process's, not an engine's: the second half of this module holds it
+to that."""
 
 import numpy as np
 import pytest
 
+from oobleck_tpu.execution.pipeline import PROGRAMS
 from tests.execution.test_engine import cache_env, make_engine  # noqa: F401
 
 
-def _stage_keys(cache):
-    # Stage-signature keys are the 11-tuples _build_stage_fns computes;
-    # the ("opt_update", id) aux entries are keyed differently.
-    return {k for k in cache if isinstance(k, tuple) and len(k) == 11}
+def _stage_keys():
+    # A stage program's key starts with the model's class; every other
+    # program's with its kind, a string.
+    return {k for k in PROGRAMS if isinstance(k[0], type)}
 
 
 def test_precompile_makes_reconfigure_compile_free(cache_env, devices8):
     """Start the precompiler, let it finish, kill a host: reconfigure must
-    add ZERO new stage-signature keys to the exec cache — every stage
+    add ZERO new stage keys to the table of programs — every stage
     program of the recovery plan was already built — and training resumes
     finite. This is the tentpole acceptance gate in miniature."""
     engine = make_engine(num_hosts=4, steps=10, devices=devices8)
@@ -32,12 +35,12 @@ def test_precompile_makes_reconfigure_compile_free(cache_env, devices8):
     assert pc.stats["plans"] >= 1          # live plan + n-1 (+ n-2) worlds
     assert pc.stats["stages_compiled"] > 0
     assert pc.stats["errors"] == 0, pc.stats
-    keys_before = _stage_keys(engine._exec_cache)
+    keys_before = _stage_keys()
     assert keys_before
 
     engine.reconfigure("10.0.0.2")
 
-    assert _stage_keys(engine._exec_cache) == keys_before, (
+    assert _stage_keys() == keys_before, (
         "reconfigure compiled stage programs the precompiler should have "
         "already built"
     )

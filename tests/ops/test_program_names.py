@@ -38,12 +38,11 @@ def test_no_jitted_program_is_a_lambda(module):
 
 
 def test_training_programs_carry_their_names():
-    import optax
-
     from oobleck_tpu.execution import pipeline
+    from oobleck_tpu.parallel.train import make_optimizer
 
     assert pipeline.grad_zero.__name__ == "grad_zero"
-    update = pipeline.make_optimizer_update(optax.sgd(0.1))
+    update = pipeline.optimizer_update_program(make_optimizer())
     assert update.__name__ == "optimizer_update"
 
 
