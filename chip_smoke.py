@@ -9,7 +9,9 @@ JAX until its last step):
 
   1. kernels   the Pallas flash (fwd + dq/dk/dv), paged-decode and
                paged-verify kernels against their XLA references at gpt2
-               124M widths and the serve engine's default geometry, each
+               124M widths and the serve engine's default geometry, and the
+               Mamba-2 scan's two (`ssd_fwd`, `ssd_bwd`) against its
+               `jax.numpy` path at nemotron-3-nano-30b-a3b's widths, each
                shown to be a compiled Mosaic kernel (`tpu_custom_call`);
   2. trainer   `python -m oobleck_tpu.elastic.master` plus
                `python -m oobleck_tpu.elastic.run --config-path <yaml>`:
@@ -147,6 +149,7 @@ def phase_kernels() -> None:
         _paged_verify_pallas,
         _paged_verify_xla,
     )
+    from oobleck_tpu.ops.ssd import _scan_xla, ssd_scan
     from oobleck_tpu.serve.kv_blocks import pages_for
     from oobleck_tpu.utils.compile_cache import ensure_persistent_cache
 
@@ -198,6 +201,28 @@ def phase_kernels() -> None:
           (qd, kp, vp, tables, lengths), 2e-2)
     check(f"paged_verify_T{t}", _paged_verify_pallas, _paged_verify_xla,
           (qv, kp, vp, tables, lengths), 2e-2)
+
+    # The Mamba-2 scan at nemotron-3-nano-30b-a3b's widths (heads of 64 in
+    # groups of 8, a state of 128, chunks of 128), 16 heads of its 64 over a
+    # quarter of its sequence; the reference is the scan's own `jax.numpy`
+    # path, every gradient over its own largest entry.
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    scan_args = (
+        jax.random.normal(ks[0], (1, 1024, 16, 64), jnp.bfloat16),
+        0.1 * jax.nn.softplus(jax.random.normal(ks[1], (1, 1024, 16))),
+        -jnp.exp(jax.random.normal(ks[2], (16,))),
+        jax.random.normal(ks[3], (1, 1024, 2, 128), jnp.bfloat16) * 0.3,
+        jax.random.normal(ks[4], (1, 1024, 2, 128), jnp.bfloat16) * 0.3,
+        jax.random.normal(ks[5], (16,)))
+    scan_grads = lambda fn: lambda *args: [
+        g / jnp.max(jnp.abs(g)) for g in jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+            argnums=range(6))(*args)]
+    scan_xla = lambda *args: _scan_xla(*args, 128)
+    check("ssd_fwd", lambda *args: ssd_scan(*args, chunk=128), scan_xla,
+          scan_args, 5e-2)
+    check("ssd_bwd", scan_grads(lambda *args: ssd_scan(*args, chunk=128)),
+          scan_grads(scan_xla), scan_args, 2e-2)
     say("kernels", done=True, **_cache_counts(), **device)
 
 

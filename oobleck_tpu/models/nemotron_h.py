@@ -11,7 +11,9 @@ layer:
      `ssm_state_size`; head h reads group h // (heads / groups));
      `dt = softplus(dt + dt_bias)`, `A = -exp(A_log)`, a scalar a head;
      the recurrence `H_t = exp(dt_t A) H_{t-1} + dt_t x_t (x) B_t`,
-     `y_t = H_t C_t + D x_t` in chunks of `chunk_size` (`ops/ssd.py`);
+     `y_t = H_t C_t + D x_t` in chunks of `chunk_size` (`ops/ssd.py`: on
+     a TPU two Pallas kernels, `ssd_fwd` and `ssd_bwd`, whose forward's
+     outputs the block's checkpoint keeps; `jax.numpy` elsewhere);
      `y * silu(z)`, then an RMSNorm over each group's channels with a
      learned scale (gate first, norm after); `W_out`. No projection bias.
   *  grouped-query attention: `num_heads` query and `num_kv_heads`

@@ -3,18 +3,20 @@
 An op module whose forward rule emits a value that only more kernel or
 product time could give back NAMES it (`checkpoint_name`) and declares the
 names beside that rule, as `RESIDUAL_NAMES`. `KEPT` is the one place that
-lists those modules; a third is one more line of it.
+lists those modules; a further one is one more line of it.
 """
 
 from __future__ import annotations
 
 import jax
 
-from oobleck_tpu.ops import flash, gdn
+from oobleck_tpu.ops import flash, gdn, ssd
 
 KEPT = (
     *flash.RESIDUAL_NAMES,   # what the flash forward kernel wrote: O, LSE
     *gdn.RESIDUAL_NAMES,     # the delta rule's inverse
+    *ssd.RESIDUAL_NAMES,     # what the scan's forward kernel wrote: y, the
+                             # state at every chunk's start
 )
 
 

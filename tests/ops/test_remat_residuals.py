@@ -1,8 +1,11 @@
-"""What a layer's checkpoint keeps: of flash attention (`ops/flash.py`)
-and of the gated delta rule (`ops/gdn.py`).
+"""What a layer's checkpoint keeps: of flash attention (`ops/flash.py`),
+of the gated delta rule (`ops/gdn.py`) and of the Mamba-2 scan
+(`ops/ssd.py`).
 
 `_flash_fwd` names the two things the forward kernel wrote, O and the row
-logsumexp, `ops/gdn._inverse_fwd` the inverse its series gave, and
+logsumexp, `ops/gdn._inverse_fwd` the inverse its series gave,
+`ops/ssd._scan_fwd` the two things ITS forward kernel wrote, y and the
+state at every chunk's start, and
 `checkpoint_layer` is `jax.checkpoint` with the policy that keeps values
 by those names. So the forward a backward pass recomputes holds no kernel
 call and no series: their only consumers are the kept values, and neither
@@ -43,6 +46,9 @@ def _calls(names, layers):
     return dict.fromkeys(names, layers)
 
 
+SCAN = ("ssd_fwd", "ssd_bwd")       # `ops/ssd.py`'s two kernels
+
+
 # cell -> (microbatch, sequence), its attention's kernels with how many
 # layers call each, the bound on `jit_bwd`'s temporaries. The compile gave
 # 1,188,759,552 / 2,136,438,784 / 1,732,474,368 bytes when the policy went
@@ -53,7 +59,13 @@ CELLS = {
     "lfm2-24b-a2b": ((8, 1024), _calls(flash.PLAIN, 1), 2.3e9),
     "moonlight-16b-a3b": ((1, 4096), _calls(flash.LATENT, 5), 1.8e9),
     # 1,592,583,680 when the cell went in (PR 37): under ISSUE 37's 2.2 GB.
-    "nemotron-3-nano-30b-a3b": ((1, 4096), _calls(flash.PLAIN, 1), 2.2e9),
+    # 1,881,395,712 since the three Mamba-2 layers' scan is two kernels
+    # (PR 54): y (33.5 MB) and the 32 chunk-start states (67 MB float32) of
+    # each are the program's to hold across a microbatch's backward, where
+    # the [Q, Q] blocks of `L` (134 MB a pass) were temporaries. One
+    # `ssd_fwd` and one `ssd_bwd` a layer: the recomputed forward holds none.
+    "nemotron-3-nano-30b-a3b": ((1, 4096), {**_calls(flash.PLAIN, 1),
+                                            **_calls(SCAN, 3)}, 2.2e9),
     # 4,402,778,624 when the cell went in (PR 43): the dropless buffers of
     # 4096 x 10 + 16 x 128 rows (168 MB each at 2048 bfloat16 columns)
     # beside the delta rule's float32 [64, 64] blocks. One attention layer
@@ -155,10 +167,25 @@ def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
     assert compiled.memory_analysis().temp_size_in_bytes < temp_bound
     if cell in EXPERT_SETS:
         _the_experts_sums_are_the_kernels(cell, text)
+    if set(SCAN) <= set(kernels):
+        _the_scan_is_its_kernels(text)
     inverse = re.findall(
         r'= f32\[[\d,]*64,64\]\S* convolution\([^\n]*op_name="[^"]*/gdn/'
         r'gdn_inverse/dot_general"', text)
     assert len(inverse) == INVERSE_PRODUCTS.get(cell, 0)
+
+
+def _the_scan_is_its_kernels(text):
+    """Of what the program built under the scope `ssd`: no [Q, Q] float32
+    block (`L`, `M`, `C B^T` at a chunk of 128: they live in VMEM) and no
+    loop (the walk across the chunks is the kernels' grid)."""
+    built = re.findall(
+        r'^\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\([^\n]*op_name="'
+        r'[^"]*[/(]ssd[/)][^"]*"', text, re.M)
+    assert len(built) > 2 * 3
+    assert not [shape for shape, _ in built
+                if re.match(r"f32\[[\d,]*128,128\]", shape)]
+    assert "while" not in {kind for _, kind in built}
 
 
 # Microbatch gradients accumulate inside each chunk's backward program: the
@@ -285,6 +312,48 @@ def test_kept_residuals_halve_the_forward_calls_and_keep_the_gradients(
     for fn in (bare, kept):     # the ONE backward kernel: once a block
         assert calls(fn).count(names.bwd) == 2
     # A kept value is the value a second call would have written.
+    for got, want in zip(jax.tree.leaves(jax.jit(kept)(w, x)),
+                         jax.tree.leaves(jax.jit(bare)(w, x))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _scan_block(w, x):
+    """A layer around `ssd_scan`: 2 heads of 64 in one group, a state of
+    128, chunks of 128 (what the kernels tile), all made from x [1, S, 32]."""
+    from oobleck_tpu.ops.ssd import ssd_scan
+
+    b, s, d = x.shape
+    y = ssd_scan((x @ w[0]).reshape(b, s, 2, 64),
+                 jax.nn.softplus(x @ w[1][:, :2]), -jnp.ones((2,)),
+                 (x @ w[2]).reshape(b, s, 1, 128),
+                 (x @ w[3]).reshape(b, s, 1, 128), jnp.ones((2,)), chunk=128)
+    return x + y.reshape(b, s, 128) @ w[4]
+
+
+def test_the_scan_s_kept_residuals_halve_its_forward_calls_and_keep_the_gradients(
+        monkeypatch):
+    from oobleck_tpu.ops import ssd
+
+    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
+    monkeypatch.setattr(ssd, "_interpret", lambda: True)
+    rng = np.random.default_rng(0)
+    w = [jnp.asarray(rng.normal(0, 0.2, (2, *shape)), jnp.float32)
+         for shape in ((32, 128), (32, 128), (32, 128), (32, 128), (128, 32))]
+    x = jnp.asarray(rng.normal(0, 1.0, (1, 256, 32)), jnp.float32)
+
+    def two_blocks(wrap):
+        layer = wrap(_scan_block)
+        return jax.grad(lambda w, x: jnp.sum(
+            layer([m[1] for m in w], layer([m[0] for m in w], x)) ** 2),
+            argnums=(0, 1))
+
+    bare, kept = two_blocks(jax.checkpoint), two_blocks(remat.checkpoint_layer)
+    calls = lambda fn: [name for name, _ in pallas_calls(
+        jax.make_jaxpr(fn)(w, x).jaxpr)]
+    assert calls(bare).count("ssd_fwd") == 4
+    assert calls(kept).count("ssd_fwd") == 2
+    for fn in (bare, kept):
+        assert calls(fn).count("ssd_bwd") == 2
     for got, want in zip(jax.tree.leaves(jax.jit(kept)(w, x)),
                          jax.tree.leaves(jax.jit(bare)(w, x))):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

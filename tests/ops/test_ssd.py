@@ -6,6 +6,13 @@ and groups: a length that is no multiple of the chunk, one chunk only, one
 group for all heads, a group a head; decays of e^-20 a chunk and far beyond
 (no inf, no nan, forward or backward: `L` comes from a difference of running
 sums); bfloat16 operands; and what the scan counts where it is built.
+
+Those tests run the `jax.numpy` path, the CPU's. The second half of the
+file runs the TPU's path, the two Pallas kernels (`ssd_fwd`, `ssd_bwd`),
+under the interpreter and against that `jax.numpy` path at the widths the
+kernels tile (chunks of 128, heads of 64 side by side, a state of 128):
+the forward and each of the six gradients, the same decays, padding and
+float32 claims, and which shapes take which path.
 """
 
 import functools
@@ -17,7 +24,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from oobleck_tpu.ops import attention, ssd
 from oobleck_tpu.ops.ssd import ssd_scan
+from tests.ops.programs import all_eqns
 
 # (length, chunk, heads, groups)
 CASES = {
@@ -171,3 +180,216 @@ def test_the_scan_counts_what_it_builds():
     fn(*args)                       # a cache hit traces nothing
     assert built.value() - before == 1
     assert reg.gauge("oobleck_ssd_chunks").value(layer="3") == 3
+
+
+# --------------------------------------------------------------------- #
+# the kernels, interpreted, against the jax.numpy path                   #
+# --------------------------------------------------------------------- #
+
+# (batch, length, heads, groups) at chunk 128, head 64, state 128.
+KERNEL_CASES = {
+    "two_chunks_two_groups": (1, 256, 4, 2),
+    "three_chunks_one_group": (2, 384, 4, 1),
+    "ragged_tail": (1, 300, 2, 1),
+}
+KQ, KP, KN = 128, 64, 128
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """`ssd_scan` takes the kernels' path as on a TPU, interpreted."""
+    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
+    monkeypatch.setattr(ssd, "_interpret", lambda: True)
+
+
+def kernel_operands(case, *, seed=0, dtype=jnp.float32):
+    bsz, length, heads, groups = KERNEL_CASES[case]
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(k[0], (bsz, length, heads, KP)).astype(dtype),
+            0.1 * jax.nn.softplus(jax.random.normal(k[1], (bsz, length, heads))),
+            -jnp.exp(jax.random.normal(k[2], (heads,))),
+            0.3 * jax.random.normal(k[3], (bsz, length, groups, KN)).astype(dtype),
+            0.3 * jax.random.normal(k[4], (bsz, length, groups, KN)).astype(dtype),
+            jax.random.normal(k[5], (heads,)))
+
+
+def numpy_path(*args, chunk=KQ):
+    """`ssd_scan` as the CPU runs it, whatever the fixture says."""
+    length = args[0].shape[1]
+    pad = -length % chunk
+    rows = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+    x, dt, a_neg, b, c, d = args
+    return ssd._scan_xla(rows(x), rows(dt), a_neg, rows(b), rows(c), d,
+                         chunk)[:, :length]
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_forward_kernel_is_the_numpy_path(kernels, case):
+    args = kernel_operands(case)
+    got = scan(*args, chunk=KQ)
+    want = jax.jit(numpy_path)(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@functools.cache
+def _kernel_gradients(case):
+    """All six gradients of a case, through the kernels and through the
+    `jax.numpy` path: computed once, compared one operand a test. (Called
+    under the `kernels` fixture only.)"""
+    args = kernel_operands(case, seed=1)
+    target = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    got = jax.jit(jax.grad(
+        lambda *a: jnp.sum(ssd_scan(*a, chunk=KQ) * target),
+        argnums=range(6)))(*args)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(numpy_path(*a) * target),
+                            argnums=range(6)))(*args)
+    return got, want
+
+
+@pytest.mark.parametrize("wrt", range(6), ids=ARGS)
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_every_gradient_of_the_kernels_is_the_numpy_paths(kernels, case, wrt):
+    got, want = (g[wrt] for g in _kernel_gradients(case))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5 * max(scale, 1.0), rtol=2e-4)
+
+
+@pytest.mark.parametrize("decay_a_chunk", [20.0, 2000.0],
+                         ids=["e-20", "e-2000"])
+def test_both_kernels_have_no_inf_and_no_nan_in_a_chunk_that_decays_to_nothing(
+        kernels, decay_a_chunk):
+    x, dt, a_neg, b, c, d = kernel_operands("two_chunks_two_groups")
+    dt = jnp.full_like(dt, decay_a_chunk / KQ)
+    a_neg = -jnp.ones_like(a_neg)
+    args = (x, dt, a_neg, b, c, d)
+    y = scan(*args, chunk=KQ)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(np.asarray(y), np.asarray(numpy_path(*args)),
+                               atol=2e-4, rtol=2e-4)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=KQ)),
+                             argnums=range(6)))(*args)
+    assert all(np.isfinite(np.asarray(g)).all() for g in grads)
+
+
+def test_padding_rows_move_no_state_through_the_kernels(kernels):
+    args = kernel_operands("ragged_tail")
+    whole = scan(*args, chunk=KQ)
+    cut = scan(*(a[:, :256] if a.ndim > 1 else a for a in args), chunk=KQ)
+    np.testing.assert_allclose(np.asarray(whole[:, :256]), np.asarray(cut),
+                               atol=1e-5)
+    # ... nor take a gradient: the tail's rows past the length are not there.
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=KQ)),
+                             argnums=(0, 1)))(*args)
+    assert all(g.shape == a.shape for g, a in zip(grads, args))
+
+
+def _kernel_calls(fn, *args):
+    return [e for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def test_the_kernels_keep_running_sums_and_state_in_float32(kernels):
+    bf = lambda t: t.astype(jnp.bfloat16)
+    x, dt, a_neg, b, c, d = kernel_operands("two_chunks_two_groups")
+    args = (bf(x), dt, a_neg, bf(b), bf(c), d)
+    got = scan(*args, chunk=KQ)
+    assert got.dtype == jnp.bfloat16
+    want = recurrence(bf(x).astype(jnp.float32), dt, a_neg,
+                      bf(b).astype(jnp.float32), bf(c).astype(jnp.float32), d)
+    err = np.abs(np.asarray(got, np.float32) - np.asarray(want))
+    assert err.max() < 0.05 * np.abs(np.asarray(want)).max()
+    grad = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=KQ).astype(jnp.float32)),
+                    argnums=range(6))
+    fwd, bwd = _kernel_calls(grad, *args)
+    assert [e.params["name"] for e in (fwd, bwd)] == ["ssd_fwd", "ssd_bwd"]
+    for call in (fwd, bwd):
+        dtypes = [v.aval.dtype for v in call.invars]
+        # x (and dY), B, C in the operands' dtype; the running sums (twice),
+        # D and the states float32.
+        wide = 2 if call is bwd else 1
+        assert dtypes[:wide + 2] == [jnp.bfloat16] * (wide + 2)
+        assert set(dtypes[wide + 2:]) == {jnp.dtype(jnp.float32)}
+        # Every exp inside the kernel reads float32, and so does the carried
+        # state (the one scratch).
+        inner = call.params["jaxpr"]
+        exps = [e for e in all_eqns(inner) if e.primitive.name == "exp"]
+        assert exps and all(e.invars[0].aval.dtype == jnp.float32 for e in exps)
+        assert inner.invars[-1].aval.dtype == jnp.float32
+    # The states the forward wrote for the backward: float32 too.
+    assert fwd.outvars[1].aval.dtype == jnp.float32
+    assert fwd.outvars[1].aval.shape == (1, 2, 2, KN, 2 * KP)
+
+
+def test_the_kernels_bodies_call_no_jitted_helper(kernels):
+    """As `ops/moe.py`'s (`tests/ops/test_routed_experts.py`): `jnp.where`
+    in a body is an inner `jit` whose cached jaxpr carries the source
+    location of its first trace in the process into the kernel's serialized
+    body, and the compile cache's key with it. With 19 of them in these two
+    and `i // groups`, `i % groups` in their blocks' index maps, the cell's
+    first warm start compiled `jit_bwd` again: `setup_s` 88.3 and 92.6 s
+    where the parent's read 43.3 and 51.3 (my chip runs, PR 54)."""
+    grad = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=KQ)),
+                    argnums=range(6))
+    fwd, bwd = _kernel_calls(grad, *kernel_operands("two_chunks_two_groups"))
+    for call in (fwd, bwd):
+        # The body, and the blocks' index maps, which are serialized with it
+        # (`//` and `%` of a grid index there are `jnp.floor_divide` and
+        # `jnp.remainder`).
+        maps = [m.index_map_jaxpr.jaxpr
+                for m in call.params["grid_mapping"].block_mappings]
+        assert len(maps) == len(call.invars) + len(call.outvars)
+        for jaxpr in (call.params["jaxpr"], *maps):
+            inner = {e.primitive.name for e in all_eqns(jaxpr)}
+            assert not inner & {"jit", "pjit", "closed_call", "core_call"}, (
+                call.params["name"], sorted(inner))
+
+
+def test_a_group_is_read_through_the_block_index_never_copied(kernels):
+    args = kernel_operands("three_chunks_one_group")
+    fwd, = _kernel_calls(lambda *a: ssd_scan(*a, chunk=KQ), *args)
+    # B and C go in as [B, S, G N]: one group for the four heads.
+    assert [v.aval.shape for v in fwd.invars[1:3]] == [(2, 384, KN)] * 2
+
+
+# (chunk, heads, groups, head width, state) the kernels do not tile: a chunk
+# that is no multiple of 128, one head of 64 a group (half a lane tile), a
+# state of 64.
+NOT_TAKEN = {"chunk_64": (64, 4, 2, 64, 128), "half_a_lane_tile": (128, 2, 2, 64, 128),
+             "state_64": (128, 4, 2, 64, 64)}
+
+
+@pytest.mark.parametrize("shape", ["taken", *sorted(NOT_TAKEN)])
+def test_the_counter_says_which_path_a_scan_took(monkeypatch, shape):
+    """On a TPU (`_pallas_ok`): one `fwd` and one `bwd` a scan built where
+    the kernels tile the shape, none where they do not; on the CPU none.
+    Traced only: nothing runs."""
+    from oobleck_tpu.utils import metrics
+
+    reg = metrics.registry()
+    scans = reg.counter("oobleck_ssd_scans_total")
+    calls = reg.counter("oobleck_ssd_kernel_calls_total")
+    chunk, heads, groups, p, n = NOT_TAKEN.get(shape, (128, 4, 2, 64, 128))
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    args = (jax.random.normal(k[0], (1, 256, heads, p)),
+            jnp.ones((1, 256, heads)), -jnp.ones((heads,)),
+            jax.random.normal(k[1], (1, 256, groups, n)),
+            jax.random.normal(k[2], (1, 256, groups, n)), jnp.ones((heads,)))
+    read = lambda: (scans.value(), calls.value(kernel="fwd"),
+                    calls.value(kernel="bwd"))
+
+    def built(on_tpu):
+        monkeypatch.setattr(attention, "_pallas_ok", lambda: on_tpu)
+        before = read()
+        # A function of its own a trace: an equal one would be a cache hit.
+        found = _kernel_calls(jax.grad(
+            lambda *a: jnp.sum(ssd_scan(*a, chunk=chunk)), argnums=0), *args)
+        return (tuple(b - a for a, b in zip(before, read())),
+                sorted(e.params["name"] for e in found))
+
+    assert built(on_tpu=False) == ((1, 0, 0), [])
+    took = shape == "taken"
+    assert built(on_tpu=True) == (
+        (1, int(took), int(took)), ["ssd_bwd", "ssd_fwd"] if took else [])

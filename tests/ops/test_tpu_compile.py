@@ -540,6 +540,64 @@ def test_reglu_experts_with_a_router_of_their_own_compile(v5e):
         text, t, d, f, ne, held, top_k) == 6
 
 
+# The Mamba-2 scan's two kernels (`ops/ssd.py`) at `nemotron-3-nano-30b-a3b`'s
+# call: [1, 4096, 64, 64] in 8 groups, a state of 128, chunks of 128. A grid
+# step holds a group's 8 heads side by side ([128, 512] blocks), the state
+# [128, 512] float32 in a scratch, and a head's [128, 128] float32 blocks
+# one after another: inside the 16 MiB a kernel has without asking.
+SSD_CELL = dict(heads=64, groups=8, head_dim=64, state=128, chunk=128)
+
+
+def _scan(*operands):
+    from oobleck_tpu.ops.ssd import ssd_scan
+
+    return ssd_scan(*operands, chunk=SSD_CELL["chunk"])
+
+
+def _scan_grads(fn):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=range(6))
+
+
+def _scan_shapes(batch, seq):
+    h, g, p, n = (SSD_CELL[k] for k in ("heads", "groups", "head_dim", "state"))
+    bf, f32 = jnp.bfloat16, jnp.float32
+    return [((batch, seq, h, p), bf), ((batch, seq, h), f32), ((h,), f32),
+            ((batch, seq, g, n), bf), ((batch, seq, g, n), bf), ((h,), f32)]
+
+
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_ssd_compiles_at_the_cell(v5e, mode):
+    text = _compile(_scan if mode == "fwd" else _scan_grads(_scan), v5e[0],
+                    *_scan_shapes(1, 4096))
+    calls = re.findall(r"%(ssd_\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert sorted(calls) == (["ssd_fwd"] if mode == "fwd"
+                             else ["ssd_bwd", "ssd_fwd"])
+    # Neither `L` nor `M` nor `C B^T` is an array of the program.
+    assert not re.search(r"= f32\[[\d,]*128,128\]", text)
+    assert " while(" not in text
+
+
+def test_ssd_compiles_inside_check_vma_shard_map_under_the_layers_checkpoint(
+        v5e):
+    """As a stage program holds it: under `checkpoint_layer` inside a
+    default (check_vma=True) shard_map, the batch over a data axis,
+    differentiated from outside. y and the chunk-start states keep their
+    varying axes through their names, and each kernel is in the program
+    once: the recomputed forward holds none."""
+    mesh = Mesh(v5e[:2], ("data",))
+    row, all_ = P("data"), P()
+    specs = (row, row, all_, row, row, all_)
+    sm = jax.shard_map(checkpoint_layer(_scan), mesh=mesh, in_specs=specs,
+                       out_specs=row)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, spec))
+            for (s, d), spec in zip(_scan_shapes(2, 1024), specs)]
+    text = jax.jit(_scan_grads(sm)).lower(*args).compile().as_text()
+    for kernel in ("ssd_fwd", "ssd_bwd"):
+        assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 1
+
+
 # Every kernel has a stable name on the device: `name=` on its pallas_call
 # is the innermost component of the operation's JAX name stack, and the
 # chip's compiler names the custom call after that component. A profiler
@@ -551,6 +609,7 @@ KERNEL_NAMES = {
     "flash_swa_fwd": "window", "flash_swa_bwd_dqkv": "window",
     "paged_decode": "decode", "paged_verify": "verify",
     "moe_gmm": "moe", "moe_tgmm": "moe", "moe_token_sum": "moe",
+    "ssd_fwd": "ssd", "ssd_bwd": "ssd",
 }
 
 
@@ -574,6 +633,11 @@ def test_kernel_is_named_in_location_and_executable(v5e, name):
     elif KERNEL_NAMES[name] == "latent":
         fn = _latent_grads(jax.checkpoint(latent_flash_attention))
         shapes = _latent_shapes(1, 4, 1024, *LATENT_WIDTHS[1:])
+    elif KERNEL_NAMES[name] == "ssd":
+        # `ssd_scan` is a scope of its own, and its backward rule opens it
+        # again: the wrappers go around "ssd", not around the kernels.
+        fn = _scan_grads(jax.checkpoint(_scan))
+        shapes = _scan_shapes(1, 512)
     else:
         hq, hkv, d = PAGED_WIDTHS["gpt2"]
         lanes, num_pages, page, table_pages, t = _serve_geometry()
@@ -693,7 +757,11 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
 # cell's three routed layers, 1.2 k in `smallthinker-21b-a3b`'s, 0.6 k MORE
 # in `qwen3-next-80b-a3b`'s; before it PR 51 made every whole-buffer zero
 # fill an `AllocateBuffer` custom call (`moe._unwritten`). `gpt3-2.7b` has no
-# routed layer and its pair stood. A PR that changes what one of these programs
+# routed layer and its pair stood. `nemotron-3-nano-30b-a3b`'s as PR 54 left
+# it: the three Mamba-2 layers' scan is `ssd_fwd` and `ssd_bwd` (`ops/ssd.py`)
+# where the `jax.numpy` scan, its recompute and JAX's derivative of both
+# stood, 128 k less; the five other cells hold no scan and their pairs
+# stood. A PR that changes what one of these programs
 # computes takes its new text's pair from a failing run; one that leaves a
 # pair standing has shown that the program bypasses its change (PR 46's
 # rotary left `gpt3-2.7b`'s and `nemotron-3-nano-30b-a3b`'s).
@@ -701,7 +769,7 @@ LOWERED = {
     "gpt3-2.7b": ("f815b23b0da3328e", 169829),
     "lfm2-24b-a2b": ("eab93850ea8613bd", 614222),
     "moonlight-16b-a3b": ("49a3b8f2ddf364a7", 771085),
-    "nemotron-3-nano-30b-a3b": ("e607038bf59e8ea0", 655370),
+    "nemotron-3-nano-30b-a3b": ("ba6f9b889e95edcc", 527349),
     "qwen3-next-80b-a3b": ("281d8f36e6f2551e", 1043793),
     "smallthinker-21b-a3b": ("8e162b7d6c4c371a", 676696),
 }
