@@ -105,29 +105,69 @@ class HostSyncCounter:
 host_sync_counter = HostSyncCounter()
 
 
-def _host_sync(value) -> float:
-    """The engine's ONLY device->host readback funnel (counted)."""
+def _host_sync(value, read=float):
+    """The engine's ONLY device->host readback funnel (counted). `read`
+    is what reads `value`: `float` a loss, `jax.device_get` a step's loads
+    (`StepLoad`)."""
     host_sync_counter.count += 1
     # Where the host blocks on the device: the step's work has been
     # enqueued, and this returns when the loss is there.
     with obs_spans.region("engine.loss_readback"):
-        return float(value)
+        return read(value)
+
+
+class StepLoad:
+    """Where a step routed (each pipeline's `load`: its routed chunks'
+    loads summed over the microbatches), on the device until `read()`.
+    Read only where the step's loss is, and after it: the backward
+    programs that wrote these few hundred bytes a routed layer have
+    finished by then. Empty (false) where no pipeline hands a load out."""
+
+    def __init__(self, pipelines) -> None:
+        self._arrays = []
+        self._rows = []        # (label, the tile's rows) a row of them
+        for pipe in pipelines:
+            for layers, array in pipe.load or ():
+                self._arrays.append(array)
+                self._rows += [pipe.load_info[li] for li in layers]
+
+    def __bool__(self) -> bool:
+        return bool(self._arrays)
+
+    def read(self) -> dict:
+        """{layer: (rows of each held expert..., tiles in use, the tile's
+        rows)}, pipelines summed: ONE transfer through the counted
+        funnel, whatever the number of chunks."""
+        arrays = _host_sync(self._arrays, read=jax.device_get)
+        out: dict[str, list[int]] = {}
+        for (label, tile), row in zip(
+                self._rows, (row for array in arrays for row in array)):
+            seen = out.setdefault(label, [0] * len(row) + [tile])
+            for i, n in enumerate(row.tolist()):
+                seen[i] += n
+        return {label: tuple(seen) for label, seen in out.items()}
 
 
 class DeferredLoss:
     """Weighted on-device loss scalars whose host readback is postponed
     (execution.loss_readback_every > 1). Holding the jax arrays keeps them
     alive without forcing a sync; resolve() is the single point where the
-    host finally blocks."""
+    host finally blocks. The step's load rides with them (`StepLoad`, or
+    None) and is read when they are."""
 
-    def __init__(self, parts: list[tuple[Any, int]]) -> None:
+    def __init__(self, parts: list[tuple[Any, int]],
+                 load: "StepLoad | None" = None) -> None:
         self._parts = parts
+        self._load = load
 
     def resolve(self) -> float:
         total = sum(w for _, w in self._parts)
         return sum(
             _host_sync(l) * w for l, w in self._parts
         ) / max(1, total)
+
+    def resolve_load(self) -> dict | None:
+        return self._load.read() if self._load else None
 
 
 # The data-parallel programs that bake nothing in are jitted once, here (jit
@@ -1590,7 +1630,7 @@ class OobleckEngine:
                 self.step += 1
                 if self._defer_losses():
                     return DeferredLoss([(loss, 1)])
-                return _host_sync(loss)
+                return _host_sync(loss)     # no routed experts run fused
 
             if self.multihost:
                 return self._train_step_multihost()
@@ -1613,12 +1653,20 @@ class OobleckEngine:
                     )
             self._m_dispatch_stall.observe(stall_s)
             self.step += 1
+            load = StepLoad(self.pipelines)
             if self._defer_losses():
-                return DeferredLoss(list(zip(losses, weights)))
+                return DeferredLoss(list(zip(losses, weights)), load)
             total = sum(w for w in weights)
             loss = sum(
                 _host_sync(l) * w for l, w in zip(losses, weights)) / total
+            self._record_load(self.step, load)
             return loss
+
+    def _record_load(self, step: int, load: "StepLoad | None") -> None:
+        """Step `step`'s loss has been read: read where it routed, into
+        the telemetry ring."""
+        if load:
+            obs_telemetry.telemetry().record_load(step, load.read())
 
     def _train_step_multihost(self) -> float:
         """One step across the jax.distributed world: every process
@@ -1653,6 +1701,7 @@ class OobleckEngine:
                         synced[pipe.pipeline_id],
                     )
         self.step += 1
+        self._record_load(self.step, StepLoad(self.pipelines))
         return global_loss
 
     def _set_template_gauge(self) -> None:
@@ -1864,6 +1913,7 @@ class OobleckEngine:
             for step_i, pending in self._pending_losses:
                 try:
                     val = pending.resolve()
+                    load = pending.resolve_load()
                 except Exception as e:  # backing buffers gone (reconfig)
                     logger.warning(
                         "step %d loss unavailable (deferred readback: %s)",
@@ -1872,6 +1922,8 @@ class OobleckEngine:
                     continue
                 self.loss_history.append((step_i, val))
                 self._m_loss.set(val)
+                if load:
+                    obs_telemetry.telemetry().record_load(step_i, load)
                 logger.info("step %d/%d loss %.4f", step_i, max_steps, val)
         self._pending_losses.clear()
 
@@ -2056,10 +2108,11 @@ class OobleckEngine:
                     n = sum(c["count"] for c in hist)
                     mean_s = sum(c["sum"] for c in hist) / max(n, 1)
                     logger.info(
-                        "step timer: n=%d, last=%.1fms, mean=%.1fms | %s%s",
+                        "step timer: n=%d, last=%.1fms, mean=%.1fms | %s%s%s",
                         n, step_s * 1e3, mean_s * 1e3,
                         _device_memory_summary(
-                            obs_telemetry.telemetry().last()), wire)
+                            obs_telemetry.telemetry().last()), wire,
+                        _load_summary(obs_telemetry.telemetry()))
                     self._publish_metrics()
                 if sync_interval and self.step % sync_interval == 0:
                     self._sync_replicas()
@@ -3869,6 +3922,16 @@ def _hbm_sample() -> tuple:
                 key=lambda m: m.get("bytes_in_use", 0))
     return (stats.get("bytes_in_use"), stats.get("bytes_limit"),
             stats.get("largest_free_block_bytes"))
+
+
+def _load_summary(ring) -> str:
+    """Where the ring's window of steps routed, for the step timer's line;
+    nothing where no step recorded a load."""
+    window = ring.load_window()
+    if not window:
+        return ""
+    stats = obs_telemetry.load_stats(window)
+    return f" | moe fill {stats['fill_pct']:.1f}% skew {stats['skew']:.2f}"
 
 
 def _device_memory_summary(sample: tuple | None) -> str:

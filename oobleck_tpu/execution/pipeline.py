@@ -35,6 +35,14 @@ single-controller JAX runtime:
     experts' dW, `ops/moe.py`) the model says so and the sum goes down into
     the kernel, which starts from it: on a one-device stage no pass over a
     gradient only adds;
+  * a chunk with routed layers says where it routed: its `jit_bwd` has one
+    more OUTPUT, the layers' loads (`ops/moe.load_of`: each held expert's
+    rows and the row tiles in use, int32 [routed layers, held + 1]), taken
+    from the layers' primal call. `train_step` sums a chunk's microbatches
+    on the device and leaves the sums on `self.load`; the engine reads them
+    where it reads the loss. `fwd` and `eval_fwd` have no such output, a
+    chunk without a routed layer has none anywhere, and with
+    `OOBLECK_TELEMETRY=0` no chunk has;
   * the jitted programs live in ONE table for the life of the process
     (`PROGRAMS`), keyed by everything their traces read: re-instantiation
     after a failure finds what the recovery precompiler's predicted layouts
@@ -64,7 +72,7 @@ from oobleck_tpu.execution.schedule import (
     send_grad_dest,
     validate_interleaving,
 )
-from oobleck_tpu.obs import spans
+from oobleck_tpu.obs import spans, telemetry
 from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.planning.templates import PipelineTemplate
 from oobleck_tpu.utils import metrics
@@ -138,6 +146,13 @@ def grad_zero(params_tuple):
     trace). The parameters give the tree, shapes and dtypes and are not
     read: jit drops them from the program's operands."""
     return jax.tree.map(jnp.zeros_like, params_tuple)
+
+
+@jax.jit
+def load_sum(loads):
+    """A chunk's load over a step: the sum of its microbatches' (`jit_
+    load_sum` in a device trace; a few hundred bytes a routed layer)."""
+    return sum(loads[1:], start=loads[0])
 
 
 def _accumulate(acc, backward, *, layers, in_kernel):
@@ -226,6 +241,8 @@ class StageRuntime:
     zero: list[Callable | None] = field(default_factory=list)  # gradient-sum fill
     # Leaves a chunk's backward sums inside a kernel (`_sums_in_kernel`).
     kernel_sums: list[int] = field(default_factory=list)
+    # Layers whose load a chunk's backward hands out (`_load_layers`).
+    load_layers: list[tuple[int, ...]] = field(default_factory=list)
 
     @property
     def ctx(self):
@@ -541,6 +558,13 @@ class PipelineInstance:
                     self.params[li] = jax.device_put(src, st.param_shardings[li])
 
         self.grads: dict[int, Any] = {}
+        # Where the last train_step routed (its docstring), or None; and
+        # the layers that can say, with what the host keeps of each (the
+        # model's `load_layers`: a label, the rows of a row tile).
+        self.load: tuple | None = None
+        marks = getattr(model, "load_layers", None)
+        self.load_info: dict[int, tuple[str, int]] = (
+            {} if marks is None else marks(microbatch_size * seq_len))
         self.last_eval_metrics: tuple[float, float] | None = None
         # Static activation avals for cross-process edges (computed lazily:
         # single-controller runs never need them).
@@ -608,6 +632,10 @@ class PipelineInstance:
           * `st.param_shardings`, the gradient sum's `out_shardings`:
             `st.param_pspecs` on `st.mesh`;
           * whether the last chunk has an eval program: the class, `st.ctx`.
+          * `_load_layers`: the chunk's layers whose load `bwd` hands out
+            (the model's `load_layers` at `microbatch_size * seq_len`
+            tokens, `st.ctx` and the telemetry ring's switch decide them):
+            whether that output is there.
         `_sums_in_kernel`, `_accumulate`:
           * the marks themselves, flattened (the model's `sums_in_kernel`,
             `st.mesh.size` and the backend decide them).
@@ -621,7 +649,8 @@ class PipelineInstance:
         return (
             type(self.model), self.model.config, layers, st.mesh,
             st.tp, st.sp, st.use_fsdp, self.microbatch_size, self.seq_len,
-            self.total_num_microbatches, tuple(marks), tree,
+            self.total_num_microbatches, tuple(self._load_layers(st, c)),
+            tuple(marks), tree,
         )
 
     def _stage_apply(self, st: StageRuntime, layers: tuple[int, ...]):
@@ -638,17 +667,27 @@ class PipelineInstance:
             # apply_layer, with the last layer's logits fed to the model's
             # own loss_from_logits — the engine is objective-agnostic like
             # the reference's (pipeline.py:169-216).
-            def layer_fn(li, handed):
+            def layer_fn(li, handed, give_load):
                 # Gradient sums handed to a layer are inputs of its
                 # checkpoint, like its parameters: kept, not recomputed.
+                # Its load is one more OUTPUT of the checkpoint, an
+                # integer one: the primal call's, which nothing
+                # differentiates and the recompute has no reader for.
                 kw = {} if handed is None else {"grad_sums": handed}
+                if give_load:
+                    kw["return_load"] = True
                 fn = lambda p, c, b: model.apply_layer(li, p, c, b, **kw)
                 if remat and 0 < li < last_layer:
                     fn = checkpoint_layer(fn)
                 return fn
 
-            def apply(params_tuple, x, batch, with_metrics=False, sums=None):
-                carry = x
+            def apply(params_tuple, x, batch, with_metrics=False, sums=None,
+                      load_layers=None):
+                """The chunk's output (the carry, or the loss). With
+                `load_layers`, the chunk's layers that are to hand out
+                their load: (that, their loads in layer order as ONE int32
+                [layers, held + 1] in a tuple, or () for no such layer)."""
+                carry, loads = x, []
                 sums = sums or (None,) * len(layers)
                 for li, p, handed in zip(layers, params_tuple, sums):
                     if li == last_layer:
@@ -660,9 +699,16 @@ class PipelineInstance:
                             # reports, dataset.py:39-54 — reported here).
                             c, n = model.accuracy_from_logits(logits, batch)
                             return loss, c, n
-                        return loss
-                    carry = layer_fn(li, handed)(p, carry, batch)
-                return carry
+                        carry = loss
+                        break
+                    give_load = li in (load_layers or ())
+                    carry = layer_fn(li, handed, give_load)(p, carry, batch)
+                    if give_load:
+                        carry, load = carry
+                        loads.append(load)
+                if load_layers is None:
+                    return carry
+                return carry, ((jnp.stack(loads),) if loads else ())
 
             return apply
 
@@ -716,7 +762,9 @@ class PipelineInstance:
             core, mesh=st.mesh, in_specs=tuple(in_specs), out_specs=out_spec
         )
 
-        def apply(params_tuple, x, batch):
+        def apply(params_tuple, x, batch, load_layers=None):
+            """As the generic `apply`; no layer of this path hands out a
+            load (`_load_layers`), so its loads are the empty tuple."""
             tokens = batch["input_ids"] if batch is not None else None
             ops: list[Any] = [params_tuple]
             if not is_first:
@@ -736,7 +784,8 @@ class PipelineInstance:
                     tokens.shape,
                 )
                 ops.extend([targets, mask])
-            return smap(*ops)
+            out = smap(*ops)
+            return out if load_layers is None else (out, ())
 
         return apply
 
@@ -762,7 +811,9 @@ class PipelineInstance:
         batch)` is the loss's value-and-gradient and returns (loss, new
         sum, dx): the unscaled microbatch loss its `fwd` would give,
         gradients of loss / total microbatches. train_step never calls that
-        chunk's `fwd` (eval_step does)."""
+        chunk's `fwd` (eval_step does). A chunk with `_load_layers` returns
+        their loads as one output more, last, from the forward its
+        backward differentiates: the operands are what they were."""
         last_layer = self.model.num_pipeline_layers - 1
         scale = 1.0 / self.total_num_microbatches
         for st in self.stages:
@@ -771,6 +822,7 @@ class PipelineInstance:
             st.efwd = [None] * len(st.chunks)
             st.zero = [None] * len(st.chunks)
             st.kernel_sums = [0] * len(st.chunks)
+            st.load_layers = [()] * len(st.chunks)
             if not st.is_local:
                 continue
             for c, chunk_layers in enumerate(st.chunks):
@@ -778,12 +830,17 @@ class PipelineInstance:
                 in_kernel = tuple(
                     self._sums_in_kernel(st, li) for li in chunk_layers)
                 st.kernel_sums[c] = sum(jax.tree.leaves(in_kernel))
+                load_layers = st.load_layers[c] = self._load_layers(st, c)
                 key = self.stage_program_key(st, c)
                 if key in PROGRAMS:
                     st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c] = (
                         PROGRAMS[key])
                     continue
                 apply = self._stage_apply(st, chunk_layers)
+
+                # What `bwd` differentiates: (the chunk's output, its
+                # loads in a tuple: of a chunk that hands out none, empty).
+                loaded = functools.partial(apply, load_layers=load_layers)
                 accumulate = functools.partial(
                     _accumulate, layers=chunk_layers, in_kernel=in_kernel)
                 # The sum leaves each program where it came in: the
@@ -798,46 +855,50 @@ class PipelineInstance:
                     # The loss and d(loss·scale)/d(params, x) from one
                     # forward: the loss rides out as the aux value,
                     # unscaled, exactly what `fwd` returns.
-                    def bwd(params_tuple, acc, x, tokens, _apply=apply,
+                    def bwd(params_tuple, acc, x, tokens, _loaded=loaded,
                             _accumulate=accumulate):
                         def backward(**sums):
                             def loss_fn(pt, x_):
-                                loss = _apply(pt, x_, tokens, **sums)
-                                return loss * scale, loss
+                                loss, loads = _loaded(pt, x_, tokens, **sums)
+                                return loss * scale, (loss, loads)
 
                             if x is None:
-                                (_, loss), grads = jax.value_and_grad(
+                                (_, aux), grads = jax.value_and_grad(
                                     lambda pt: loss_fn(pt, None),
                                     has_aux=True)(params_tuple)
-                                return grads, (loss, None)
-                            (_, loss), (grads, dx) = jax.value_and_grad(
+                                return grads, (*aux, None)
+                            (_, aux), (grads, dx) = jax.value_and_grad(
                                 loss_fn, argnums=(0, 1),
                                 has_aux=True)(params_tuple, x)
-                            return grads, (loss, dx)
+                            return grads, (*aux, dx)
 
-                        new, (loss, dx) = _accumulate(acc, backward)
-                        return loss, new, dx
+                        new, (loss, loads, dx) = _accumulate(acc, backward)
+                        return (loss, new, dx, *loads)
 
                     out_shardings = (None, acc_shardings, None)
                 else:
-                    def bwd(params_tuple, acc, x, tokens, dy, _apply=apply,
+                    def bwd(params_tuple, acc, x, tokens, dy, _loaded=loaded,
                             _accumulate=accumulate):
                         def backward(**sums):
                             if x is None:
                                 # First chunk: differentiate wrt params only.
-                                _, vjp = jax.vjp(
-                                    lambda pt: _apply(pt, None, tokens,
-                                                      **sums),
-                                    params_tuple)
-                                return vjp(dy)[0], None
-                            _, vjp = jax.vjp(
-                                lambda pt, x_: _apply(pt, x_, tokens, **sums),
-                                params_tuple, x)
-                            return vjp(dy)
+                                _, vjp, loads = jax.vjp(
+                                    lambda pt: _loaded(pt, None, tokens,
+                                                       **sums),
+                                    params_tuple, has_aux=True)
+                                return vjp(dy)[0], (None, loads)
+                            _, vjp, loads = jax.vjp(
+                                lambda pt, x_: _loaded(pt, x_, tokens,
+                                                       **sums),
+                                params_tuple, x, has_aux=True)
+                            grads, dx = vjp(dy)
+                            return grads, (dx, loads)
 
-                        return _accumulate(acc, backward)
+                        new, (dx, loads) = _accumulate(acc, backward)
+                        return (new, dx, *loads)
 
                     out_shardings = (acc_shardings, None)
+                out_shardings += (None,) * bool(load_layers)
 
                 st.fwd[c] = jax.jit(fwd)
                 st.bwd[c] = jax.jit(bwd, donate_argnums=1,
@@ -852,6 +913,16 @@ class PipelineInstance:
                     st.efwd[c] = jax.jit(eval_fwd)
                 PROGRAMS[key] = (
                     st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c])
+
+    def _load_layers(self, st: StageRuntime, c: int) -> tuple[int, ...]:
+        """The layers of stage `st`'s chunk `c` whose load the chunk's
+        `bwd` hands out (`apply_layer(return_load=True)`): the model's
+        `load_layers` among them, on the generic stage path (routed
+        experts run on no other), while the telemetry ring, their one
+        reader, is on."""
+        if st.ctx is not None or not telemetry.telemetry().enabled:
+            return ()
+        return tuple(li for li in st.chunks[c] if li in self.load_info)
 
     def _sums_in_kernel(self, st: StageRuntime, li: int):
         """The leaves of layer `li` whose running gradient sum goes down
@@ -959,7 +1030,11 @@ class PipelineInstance:
         _place_batch returns), taking the device_put off the critical path.
         Fills self.grads (sum over microbatches, scaled by 1/total global
         microbatches) and returns the mean loss over this pipeline's
-        microbatches as a device scalar.
+        microbatches as a device scalar. Leaves on self.load where the
+        step routed: a local chunk's routed layers and the sum of their
+        loads over the microbatches, still on the device, `((layers, int32
+        [layers, held + 1]), ...)`, or None where no local chunk hands one
+        out (`_load_layers`).
         """
         batch = self._as_batch_dict(batch)
         S, M = self.num_stages, self.num_microbatches
@@ -980,6 +1055,7 @@ class PipelineInstance:
         stash: dict[tuple, Any] = {}   # forward input stash for bwd
         losses: list[Any] = []
         grads: dict[int, Any] = {}
+        loads: dict[tuple[int, ...], list] = {}   # chunk layers -> a microbatch
         # Per-stage dispatch busy time this step, for the engine's measured
         # pipeline-bubble gauge, plus per-(stage, chunk, op) durations for
         # the schedule-replay simulation. Wall-clock around the fwd/bwd
@@ -1123,11 +1199,13 @@ class PipelineInstance:
                     accumulated["zero_fill"] += 1
                 t0 = time.perf_counter()
                 if is_last:
-                    loss, acc, dx = st.bwd[c](params, acc, x, mb)
+                    loss, acc, dx, *load = st.bwd[c](params, acc, x, mb)
                     losses.append(loss)
                 else:
                     dy = gacts.pop(key)
-                    acc, dx = st.bwd[c](params, acc, x, mb, dy)
+                    acc, dx, *load = st.bwd[c](params, acc, x, mb, dy)
+                if load:
+                    loads.setdefault(st.load_layers[c], []).extend(load)
                 if self.sync_op_timing:
                     # oobleck: allow[OBL002] -- opt-in per-op profiling mode
                     jax.block_until_ready(acc)
@@ -1178,6 +1256,11 @@ class PipelineInstance:
             for ins in canonical_order(S, M, v):
                 execute(ins)
             flush_sends()
+            # One small program a routed chunk: its M loads as one sum.
+            self.load = tuple(
+                (layers, load_sum(tuple(per_mb)) if len(per_mb) > 1
+                 else per_mb[0])
+                for layers, per_mb in loads.items()) or None
 
         self.grads = grads
         self.last_stage_busy_s = stage_busy

@@ -222,13 +222,28 @@ class RoutedShareModel:
         return self._init_block(jax.random.fold_in(ks[1], index), index - 1)
 
     def apply_layer(self, index: int, params, carry, batch, ctx=None,
-                    grad_sums=None):
+                    grad_sums=None, return_load: bool = False):
+        """`return_load` (a layer of `load_layers` only): (the carry, the
+        routed call's load, `ops/moe.load_of`)."""
         if index == 0:
             return self.embed(params, batch["input_ids"])
         if index == self.num_pipeline_layers - 1:
             return self.head(params, carry)
         return self.apply_block(index - 1, params, carry,
-                                grad_sums=grad_sums)
+                                grad_sums=grad_sums, return_load=return_load)
+
+    def load_layers(self, num_tokens: int) -> dict[int, tuple[str, int]]:
+        """The layers that can hand out their load (`apply_layer(...,
+        return_load=True)`), the routed blocks: {layer index: (the label
+        the block's counters carry, the rows of one of its row tiles at
+        `num_tokens` tokens a call)}. The tile's rows are static, so they
+        travel here and not in the load."""
+        from oobleck_tpu.ops import moe
+
+        c = self.config
+        tile = moe.buffer_rows(num_tokens, c.num_experts_per_tok,
+                               c.experts_held, c.num_experts)[1]
+        return {b + 1: (str(b), tile) for b in self.routed_blocks}
 
     def sums_in_kernel(self, index: int, params):
         """Which leaves' running gradient sums layer `index` takes down
@@ -297,9 +312,10 @@ class RoutedShareModel:
 
     def routed_ff(self, p, h, *, forced_experts=None,
                   return_routing: bool = False, grad_sums=None,
-                  router_in=None):
+                  router_in=None, return_load: bool = False):
         """The part of the routed layer that the experts held here give.
-        With `return_routing`, (that, the chosen experts [B, S, k]).
+        With `return_routing` and / or `return_load`, a tuple: that, the
+        chosen experts [B, S, k], the call's load (`ops/moe.load_of`).
         `grad_sums`: `p`'s tree with the experts' running gradient sums
         (`sums_in_kernel`). `router_in` [B, S, E]: what the router scores
         where that is not `h`."""
@@ -320,23 +336,26 @@ class RoutedShareModel:
             dw_sums=tuple(sums.get(w) for w in ("w1", "w3", "w2")),
             score=self.router_score, activation=self.expert_activation,
             router_x=(None if router_in is None
-                      else router_in.reshape(b * s, e)))
+                      else router_in.reshape(b * s, e)),
+            return_load=return_load)
+        if not (return_routing or return_load):
+            return out.reshape(b, s, e)
+        y, *extras = out
         if return_routing:
-            y, experts = out
-            return y.reshape(b, s, e), experts.reshape(b, s, -1)
-        return out.reshape(b, s, e)
+            extras[0] = extras[0].reshape(b, s, -1)
+        return (y.reshape(b, s, e), *extras)
 
     @jax.named_scope("mlp")
     def feed_forward(self, block: int, p, h, *, forced_experts=None,
                      return_routing: bool = False, grad_sums=None,
-                     router_in=None):
+                     router_in=None, return_load: bool = False):
         """The dense feed-forward or the routed experts, by the block: of
         a routed block the part its held experts give, plus, where the
         entry has them, the `shared` experts (on every token, weight 1:
         every chip of an expert-parallel group computes them alike, so the
         parts the shares give add up to the layer with them counted once).
         With `return_routing` a routed block also returns its chosen
-        experts [B, S, k]."""
+        experts [B, S, k], with `return_load` its load, in that order."""
         if not self.is_routed(block):
             return self.dense_ff(p, h)
         shared = self.dense_ff(p["shared"], h) if "shared" in p else None
@@ -347,16 +366,21 @@ class RoutedShareModel:
                 preferred_element_type=jnp.float32))[..., None].astype(h.dtype)
         out = self.routed_ff(p, h, forced_experts=forced_experts,
                              return_routing=return_routing,
-                             grad_sums=grad_sums, router_in=router_in)
+                             grad_sums=grad_sums, router_in=router_in,
+                             return_load=return_load)
         if shared is None:
             return out
-        if return_routing:
-            return out[0] + shared, out[1]
+        if return_routing or return_load:
+            return (out[0] + shared, *out[1:])
         return out + shared
 
     def apply_block(self, block: int, p, x, *, forced_experts=None,
-                    return_routing: bool = False, grad_sums=None):
-        experts = router_in = None
+                    return_routing: bool = False, grad_sums=None,
+                    return_load: bool = False):
+        """A routed block asked for them returns (x, the chosen experts,
+        its load): those asked for, in that order."""
+        extras = (return_routing or return_load) and self.is_routed(block)
+        router_in, rest = None, []
         for branch in self.branches(block):
             if branch == OP:
                 h = self.norm(x, p["ln_op"]["scale"])
@@ -368,13 +392,12 @@ class RoutedShareModel:
             out = self.feed_forward(
                 block, p["ff"], h, forced_experts=forced_experts,
                 return_routing=return_routing, router_in=router_in,
-                grad_sums=None if grad_sums is None else grad_sums["ff"])
-            if return_routing and self.is_routed(block):
-                out, experts = out
+                grad_sums=None if grad_sums is None else grad_sums["ff"],
+                return_load=return_load)
+            if extras:
+                out, *rest = out
             x = x + out
-        if return_routing and self.is_routed(block):
-            return x, experts
-        return x
+        return (x, *rest) if extras else x
 
     @jax.named_scope("lm_head")
     def head(self, p, x):
@@ -412,9 +435,13 @@ def routing_probe(model: RoutedShareModel, params_list, tokens):
     `routed_blocks` order, and counts what it saw: the probed tokens and,
     per block, the (token, slot) pairs whose expert is held here.
 
-    On demand and not every step: the pipeline's stage programs have no
-    output beside the carry and the loss, so a training step cannot say
-    where it routed."""
+    On demand: which expert each token chose, for a comparison that needs
+    the choices themselves (the benchmark's reference is handed them).
+    HOW MANY rows each held expert got, and the row tiles they filled, a
+    training step says itself, every step: the routed layers' loads ride
+    out of the pipeline's backward programs beside the loss
+    (`apply_layer(return_load=True)`, `execution/pipeline.py`) into the
+    telemetry ring (`obs/telemetry.TelemetryRing.loads`)."""
     from oobleck_tpu.obs import spans
     from oobleck_tpu.utils import metrics
 

@@ -30,12 +30,23 @@ end (``hbm_in_use``, ``hbm_limit``, ``hbm_largest_free``; bytes of the
 fullest local device, None where the platform reports none). A step's
 self time is ``step_s`` less the sum of its phases.
 
+BESIDE the samples, not in them (a sample keeps its 12 positions), the ring
+keeps where each step ROUTED: ``record_load(step, {layer: (rows a held
+expert..., tiles in use, the tile's rows)})``, a step's routed layers'
+loads summed over its microbatches, which rode out of the pipeline's
+backward programs beside the loss (``execution/pipeline.py``) and were
+read where the loss was. They arrive here as host integers.
+``loads()`` / ``last_load()`` read them back; ``record_load`` also sets the
+five registry names an operator of a routed model watches (``load_stats``).
+
 ``StepWatchdog`` is the one reader that looks at a step while it is still
 open: a daemon thread per ``train()`` call that records what the train
 thread is inside when a step has lasted twice the recent median.
 
 Knobs:
-    OOBLECK_TELEMETRY=0            disable sampling entirely
+    OOBLECK_TELEMETRY=0            disable sampling entirely (the stage
+                                   programs are then built without the
+                                   loads' output and nothing is read)
     OOBLECK_TELEMETRY_CAPACITY     ring size in samples (default 512)
     OOBLECK_TELEMETRY_WINDOW       samples per digest (default 32)
 """
@@ -117,6 +128,8 @@ class TelemetryRing:
         self._lock = threading.Lock()
         self._ring: collections.deque = collections.deque(
             maxlen=max(capacity, 1))
+        self._loads: collections.deque = collections.deque(
+            maxlen=max(capacity, 1))
 
     # -- hot path ----------------------------------------------------------- #
 
@@ -136,6 +149,45 @@ class TelemetryRing:
         with self._lock:
             self._ring.append(sample)
         return sample
+
+    def record_load(self, step: int, load: dict) -> None:
+        """Where step `step` routed: {layer: (rows that held a pair of each
+        held expert..., row tiles in use, the rows of a tile)}, summed over
+        the step's microbatches; host integers the caller has read already.
+        Counts the step's rows under the two counters and sets the three
+        gauges from the ring's window (`load_stats`)."""
+        if not self.enabled or not load:
+            return
+        with self._lock:
+            self._loads.append((step, load))
+        reg = metrics.registry()
+        rows = reg.counter(
+            "oobleck_moe_step_rows_total",
+            "Rows of the routed experts' buffers that held a (token, slot) "
+            "pair in training steps, by routed block")
+        walked = reg.counter(
+            "oobleck_moe_step_tile_rows_total",
+            "Rows the grouped kernels walked in training steps, row tiles "
+            "in use x the rows of a tile, by routed block")
+        for layer, (*held, tiles, tile) in load.items():
+            rows.inc(sum(held), layer=layer)
+            walked.inc(tiles * tile, layer=layer)
+        stats = load_stats(self.load_window())
+        reg.gauge(
+            "oobleck_moe_tile_fill_pct",
+            "100 x rows that held a pair / rows the grouped kernels walked, "
+            "all routed blocks, over the telemetry window").set(
+                stats["fill_pct"])
+        reg.gauge(
+            "oobleck_moe_load_skew",
+            "Most loaded held expert's rows over the mean rows a held "
+            "expert, the worst routed block's, over the telemetry "
+            "window").set(stats["skew"])
+        reg.gauge(
+            "oobleck_moe_step_rows_spread_pct",
+            "100 x (max - min) / median of a step's rows that held a pair, "
+            "all routed blocks, over the telemetry window").set(
+                stats["rows_spread_pct"])
 
     # -- digest (publish cadence, not per-step) ----------------------------- #
 
@@ -183,6 +235,23 @@ class TelemetryRing:
         with self._lock:
             return self._ring[-1] if self._ring else None
 
+    def loads(self) -> list[tuple]:
+        """(step, load) of every step whose load was recorded, oldest
+        first (`record_load`)."""
+        with self._lock:
+            return list(self._loads)
+
+    def last_load(self) -> tuple | None:
+        with self._lock:
+            return self._loads[-1] if self._loads else None
+
+    def load_window(self) -> list[tuple]:
+        """The newest `window` entries of `loads()`."""
+        with self._lock:
+            size = len(self._loads)
+            return [self._loads[i]
+                    for i in range(max(size - self.window, 0), size)]
+
     def recent_step_s(self, n: int) -> list[float]:
         """`step_s` of the newest `n` samples, oldest first."""
         with self._lock:
@@ -193,6 +262,50 @@ class TelemetryRing:
     def __len__(self) -> int:
         with self._lock:
             return len(self._ring)
+
+
+def load_stats(loads: list) -> dict:
+    """What a window of (step, load) entries says, by plain arithmetic on a
+    few dozen integers a step: `fill_pct`, 100 x rows that held a pair over
+    the rows the grouped kernels walked (tiles in use x the tile's rows),
+    all layers; `skew`, the worst layer's (most loaded held expert's rows /
+    mean rows a held expert), a layer's experts summed over the window;
+    `rows_spread_pct`, 100 x (max - min) / median of a step's rows over
+    all layers."""
+    rows = walked = 0
+    by_expert: dict[str, list[int]] = {}
+    by_step = []
+    for _, load in loads:
+        step_rows = 0
+        for layer, (*held, tiles, tile) in load.items():
+            step_rows += sum(held)
+            walked += tiles * tile
+            seen = by_expert.setdefault(layer, [0] * len(held))
+            for e, n in enumerate(held):
+                seen[e] += n
+        rows += step_rows
+        by_step.append(step_rows)
+    median = statistics.median(by_step) if by_step else 0
+    return {
+        "fill_pct": 100.0 * rows / walked if walked else 0.0,
+        "skew": max((max(seen) * len(seen) / sum(seen)
+                     for seen in by_expert.values() if sum(seen)),
+                    default=0.0),
+        "rows_spread_pct": (100.0 * (max(by_step) - min(by_step)) / median
+                            if median else 0.0),
+    }
+
+
+def load_totals(entry: tuple | None) -> dict | None:
+    """A `last_load()` entry by layer, as the `step_stall` event carries
+    it: {"step", "rows": {layer: rows that held a pair}, "tile_rows":
+    {layer: rows the grouped kernels walked}}."""
+    if entry is None:
+        return None
+    step, load = entry
+    return {"step": step,
+            "rows": {layer: sum(v[:-2]) for layer, v in load.items()},
+            "tile_rows": {layer: v[-2] * v[-1] for layer, v in load.items()}}
 
 
 def digest_ok(d) -> bool:
@@ -375,7 +488,8 @@ class StepWatchdog:
         before = sample_fields(last) if last is not None else None
         event = dict(step=step, open_s=round(open_s, 3),
                      median_s=round(median, 6), phase=phase, frames=frames,
-                     last_sample=before)
+                     last_sample=before,
+                     last_load=load_totals(self._ring.last_load()))
         metrics.flight_recorder().record("step_stall", **event)
         self._m_stalls.inc(phase=phase)
         # The same on one line: a run with no sink set shows it on stderr.

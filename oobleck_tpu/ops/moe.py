@@ -1077,6 +1077,7 @@ def routed_experts(
     score: str = SIGMOID,
     activation: str = SWIGLU,
     router_x: jax.Array | None = None,
+    return_load: bool = False,
 ):
     """Dropless top-k routed experts (`score`: sigmoid or softmax scores,
     `route`), the part that the experts held here give. Gated experts by
@@ -1097,7 +1098,9 @@ def routed_experts(
     weights still come from this call's own scores). With
     `return_routing`, also the chosen experts [T, k]. `dw_sums`: the
     running gradient sums of (w1, w3, w2) that the dW kernels are to add
-    to (`GradSum`); their cotangents then come back as sum + dW."""
+    to (`GradSum`); their cotangents then come back as sum + dW. With
+    `return_load`, last, the call's load (`load_of`): int32 [held + 1],
+    each held expert's rows and the row tiles in use."""
     t, d = x.shape
     held = w1.shape[0]
     assert router_w.shape == (d, num_experts), router_w.shape
@@ -1137,6 +1140,16 @@ def routed_experts(
             hidden = _swiglu(gate, up, plan, tile)
     out = grouped_matmul(hidden, w2, plan, tile, dw_sums[2])
     y = _combine(out, weights, plan, tile, top_k)
-    if return_routing:
-        return y, experts
-    return y
+    extras = ((experts,) if return_routing else ()) + (
+        (load_of(plan),) if return_load else ())
+    return (y, *extras) if extras else y
+
+
+def load_of(plan: RoutingPlan) -> jax.Array:
+    """What a call's grouped products walked, as ONE small integer array a
+    training step can hand out beside its loss: int32 [held + 1], the rows
+    that hold a pair of each held expert (`group_sizes`) and, last, the
+    row tiles in use (`num_tiles`). Two things the plan already holds: no
+    new pass over the tokens. The rows of a tile are static
+    (`buffer_rows`) and stay on the host."""
+    return jnp.concatenate([plan.group_sizes, plan.num_tiles])

@@ -196,15 +196,17 @@ def test_every_kind_of_input_the_key_lists_has_a_case(devices8):
     """The fields of a stage program's key, against ONE_APART: a field added
     to the key comes with a case here. The marks of the sums a kernel takes
     (the key's last two fields) are `test_kernel_grad_sums.py`'s: a model
-    that marks nothing, the same kernels, another program."""
+    that marks nothing, the same kernels, another program. The layers whose
+    load `bwd` hands out (the field before them) are `test_step_load.py`'s:
+    the telemetry ring off, the same model, another program."""
     pipe = _pipeline(devices8, **{**BASE, **ONE_APART["the chunk's layers"]})
     key = pipe.stage_program_key(pipe.stages[0], 0)
     model = pipe.model
-    assert key[:-2] == (
+    assert key[:-3] == (
         type(model), model.config, (0,), pipe.stages[0].mesh, 1, 1, False,
         MB, SEQ, NUM_MB)
-    assert key[-2:] == ((), jax.tree.structure((None,)))
-    assert len(ONE_APART) >= len(key[:-2]) + 3  # config x3, mesh x2
+    assert key[-3:] == ((), (), jax.tree.structure((None,)))
+    assert len(ONE_APART) >= len(key[:-3]) + 3  # config x3, mesh x2
 
 
 # --------------------------------------------------------------------- #

@@ -9,6 +9,8 @@ autodiff differentiates, and the kernels in interpreter mode against one
 against the sum plus what the kernel gives without it.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -45,6 +47,37 @@ def _share(layer, offset, held, top_k=K, **kw):
         layer["x"], layer["router"], layer["bias"], layer["w1"][sl],
         layer["w3"][sl], layer["w2"][sl], num_experts=NE, top_k=top_k,
         expert_offset=offset, **kw)
+
+
+@functools.partial(jax.jit, static_argnames=("held", "top_k", "kw"))
+def _share_by_one_program(layer, offset, *, held, top_k, kw=()):
+    """The share of experts `offset` .. `offset + held - 1`, by ONE program
+    a (layer's shapes, `held`, `top_k`, `kw`) whatever the offset, which is
+    an operand: the router's columns (and the bias) rolled so that the
+    share's experts are experts 0 .. `held - 1`, their matrices sliced at
+    the offset, the call made at `expert_offset=0`. Every token picks the
+    experts it picked, under other numbers, so the share is the one
+    `expert_offset=offset` gives: what the tests that add shares up need,
+    without an eager call a share (each re-traces and compiles its tile
+    loops, a third of a second). What an offset does inside the call is
+    the loop-over-tokens tests', at offsets of their own."""
+    roll = lambda a: None if a is None else jnp.roll(a, -offset, axis=-1)
+    cut = lambda a: None if a is None else jax.lax.dynamic_slice_in_dim(
+        a, offset, held)
+    num_experts = layer["router"].shape[-1]
+    return moe.routed_experts(
+        layer["x"], roll(layer["router"]), roll(layer.get("bias")),
+        cut(layer["w1"]), cut(layer.get("w3")), cut(layer["w2"]),
+        num_experts=num_experts, top_k=top_k, expert_offset=0,
+        router_x=layer.get("router_x"), **dict(kw))
+
+
+def _shares_total(layer, parts, top_k=K, **kw):
+    """The sum of the `parts` equal shares of `layer`'s experts."""
+    held = layer["router"].shape[-1] // parts
+    return sum(np.asarray(_share_by_one_program(
+        layer, jnp.int32(i * held), held=held, top_k=top_k,
+        kw=tuple(sorted(kw.items())))) for i in range(parts))
 
 
 def _loop_over_tokens(layer, offset, held, top_k=K):
@@ -124,11 +157,15 @@ def test_all_picks_of_a_token_can_be_held_here(layer):
 @TOP_KS
 def test_the_shares_add_up_to_the_uncut_layer(layer, top_k):
     whole = np.asarray(_share(layer, 0, NE, top_k))
-    for parts in (8, 4, 2):
-        held = NE // parts
-        total = sum(np.asarray(_share(layer, i * held, held, top_k))
-                    for i in range(parts))
-        np.testing.assert_allclose(total, whole, atol=2e-5)
+    for parts in (8, 4):
+        np.testing.assert_allclose(_shares_total(layer, parts, top_k), whole,
+                                   atol=2e-5)
+    # Two halves by the call's own `expert_offset`, and by the one program.
+    halves = sum(np.asarray(_share(layer, i * NE // 2, NE // 2, top_k))
+                 for i in range(2))
+    np.testing.assert_allclose(halves, whole, atol=2e-5)
+    np.testing.assert_allclose(_shares_total(layer, 2, top_k), halves,
+                               atol=1e-6)
 
 
 def _dense(x, router, bias, w1, w3, w2, offset, top_k):
@@ -458,9 +495,7 @@ def test_ungated_experts_match_a_loop_over_tokens(ungated, offset, held):
 
 @pytest.mark.parametrize("parts", [8, 4, 2])
 def test_the_ungated_shares_add_up_to_the_uncut_layer(ungated, parts):
-    held = NE // parts
-    total = sum(np.asarray(_ungated_share(ungated, i * held, held))
-                for i in range(parts))
+    total = _shares_total({**ungated, "w3": None}, parts)
     np.testing.assert_allclose(
         total, np.asarray(_ungated_share(ungated, 0, NE)), atol=2e-5)
 
@@ -479,10 +514,15 @@ def _ungated_dense(x, router, bias, w1, w2, offset):
 UNGATED_OPERANDS = ("x", "router", "w1", "w2")
 
 
-@pytest.mark.parametrize("wrt", range(4), ids=UNGATED_OPERANDS)
-@pytest.mark.parametrize("offset,held", [(0, NE), (2, 4)], ids=["all", "share"])
-def test_ungated_gradients_match_the_dense_formulation(ungated, offset, held,
-                                                       wrt):
+_UNGATED_GRADS = {}
+
+
+def _ungated_grads(ungated, offset, held):
+    """(the routed call's, the dense form's) gradients by EVERY operand of
+    one share: compiled once a share, whichever operand's case asks
+    first."""
+    if (offset, held) in _UNGATED_GRADS:
+        return _UNGATED_GRADS[offset, held]
     sl = slice(offset, offset + held)
     args = (ungated["x"], ungated["router"], ungated["w1"][sl],
             ungated["w2"][sl])
@@ -497,8 +537,17 @@ def test_ungated_gradients_match_the_dense_formulation(ungated, offset, held,
         return jnp.sum(_ungated_dense(x, router, ungated["bias"], w1, w2,
                                       offset) * target)
 
-    got = jax.jit(jax.grad(routed, argnums=wrt))(*args)
-    want = jax.jit(jax.grad(dense, argnums=wrt))(*args)
+    both = _UNGATED_GRADS[offset, held] = tuple(
+        jax.jit(jax.grad(fn, argnums=range(4)))(*args)
+        for fn in (routed, dense))
+    return both
+
+
+@pytest.mark.parametrize("wrt", range(4), ids=UNGATED_OPERANDS)
+@pytest.mark.parametrize("offset,held", [(0, NE), (2, 4)], ids=["all", "share"])
+def test_ungated_gradients_match_the_dense_formulation(ungated, offset, held,
+                                                       wrt):
+    got, want = (g[wrt] for g in _ungated_grads(ungated, offset, held))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
     # The empty expert's matrices get an exact zero, not garbage.
     if offset == 0 and UNGATED_OPERANDS[wrt] in ("w1", "w2"):
@@ -597,7 +646,9 @@ UNWRITTEN_ROUTINGS = {"an_expert_without_a_token": None,
                       "no_pick_lands_here": (0, 7)}
 
 
-def _value_and_grads(layer, activation, forced):
+def _value_and_grads(layer, activation, forced, load=False):
+    """Value and every gradient of one call; with `load`, also what the
+    call says beside them: (the chosen experts, its load)."""
     kw, _ = UNWRITTEN_CALLS[activation]
     names = ("x", "router", "w1", "w2") + (
         () if activation == "ungated" else ("w3",))
@@ -608,36 +659,35 @@ def _value_and_grads(layer, activation, forced):
         forced = jnp.tile(jnp.asarray([forced], jnp.int32), (T, 1))
 
     def loss(ops):
-        y = moe.routed_experts(
+        out = moe.routed_experts(
             ops["x"], ops["router"], layer["bias"], ops["w1"], ops.get("w3"),
             ops["w2"], num_experts=NE, top_k=K, expert_offset=2,
-            forced_experts=forced, **kw)
-        return jnp.sum(y ** 2) + jnp.sum(y)
+            forced_experts=forced, return_routing=load, return_load=load,
+            **kw)
+        y, *said = out if load else (out,)
+        return jnp.sum(y ** 2) + jnp.sum(y), tuple(said)
 
-    return jax.jit(jax.value_and_grad(loss))(operands)
+    return jax.jit(jax.value_and_grad(loss, has_aux=True))(operands)
 
 
-@pytest.mark.parametrize("routing", sorted(UNWRITTEN_ROUTINGS))
-@pytest.mark.parametrize("activation", sorted(UNWRITTEN_CALLS))
-@pytest.mark.parametrize("path", ["kernels", "fallback"])
-def test_nothing_reads_a_row_that_no_tile_loop_wrote(
-        layer, ungated, monkeypatch, path, activation, routing):
+_UNWRITTEN_RUNS = {}
+
+
+def _unwritten_run(layer, ungated, path, activation, routing):
+    """One case of the matrix below, run once a module whichever test asks
+    first: the call as it is (value and gradients), and the call with every
+    unwritten buffer handed out full of NaN AND its load handed back
+    (value, gradients, the chosen experts, the load); what the first built
+    by the two counters, the buffers the second was handed."""
     from oobleck_tpu.utils import metrics
 
-    if path == "kernels":
-        monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
-        monkeypatch.setattr(moe, "_interpret", lambda: True)
+    key = (path, activation, routing)
+    if key in _UNWRITTEN_RUNS:
+        return _UNWRITTEN_RUNS[key]
     operands = ungated if activation == "ungated" else layer
     forced = UNWRITTEN_ROUTINGS[routing]
     built = metrics.registry().counter("oobleck_moe_unfilled_buffers_total")
     sums = metrics.registry().counter("oobleck_moe_token_sum_kernels_total")
-    before, sums_before = built.value(), sums.value()
-    want = _value_and_grads(operands, activation, forced)
-    assert built.value() - before == UNWRITTEN_CALLS[activation][1]
-    # On the kernels' path both sums (the combine, the dispatch's dx) are
-    # `moe_token_sum` calls: the dispatch's reads a buffer handed out here.
-    assert sums.value() - sums_before == (2 if path == "kernels" else 0)
-
     handed = []
 
     def poisoned(shape, dtype):
@@ -645,18 +695,82 @@ def test_nothing_reads_a_row_that_no_tile_loop_wrote(
         handed.append(shape)
         return jnp.full(shape, jnp.nan, dtype)
 
-    monkeypatch.setattr(moe, "_unwritten", poisoned)
-    got = _value_and_grads(operands, activation, forced)
-    assert len(handed) == UNWRITTEN_CALLS[activation][1]
+    with pytest.MonkeyPatch.context() as mp:
+        if path == "kernels":
+            mp.setattr(moe, "_pallas_ok", lambda: True)
+            mp.setattr(moe, "_interpret", lambda: True)
+        before, sums_before = built.value(), sums.value()
+        (value, _), grads = _value_and_grads(operands, activation, forced)
+        counted = built.value() - before, sums.value() - sums_before
+        mp.setattr(moe, "_unwritten", poisoned)
+        (got_value, (chosen, load)), got_grads = _value_and_grads(
+            operands, activation, forced, load=True)
+    run = _UNWRITTEN_RUNS[key] = {
+        "want": (value, grads), "got": (got_value, got_grads),
+        "counted": counted, "handed": handed,
+        "chosen": np.asarray(chosen), "load": np.asarray(load)}
+    return run
+
+
+UNWRITTEN_MATRIX = [
+    pytest.mark.parametrize("routing", sorted(UNWRITTEN_ROUTINGS)),
+    pytest.mark.parametrize("activation", sorted(UNWRITTEN_CALLS)),
+    pytest.mark.parametrize("path", ["kernels", "fallback"]),
+]
+
+
+def _matrix(test):
+    for mark in reversed(UNWRITTEN_MATRIX):
+        test = mark(test)
+    return test
+
+
+@_matrix
+def test_nothing_reads_a_row_that_no_tile_loop_wrote(
+        layer, ungated, path, activation, routing):
+    """Value and every gradient with the unwritten buffers full of NaN (and
+    the load handed back beside them) are bit for bit the plain call's."""
+    run = _unwritten_run(layer, ungated, path, activation, routing)
+    assert run["counted"][0] == UNWRITTEN_CALLS[activation][1]
+    # On the kernels' path both sums (the combine, the dispatch's dx) are
+    # `moe_token_sum` calls: the dispatch's reads a buffer handed out here.
+    assert run["counted"][1] == (2 if path == "kernels" else 0)
+    assert len(run["handed"]) == UNWRITTEN_CALLS[activation][1]
     rows = moe.buffer_rows(T, K, 4, NE)[0]
-    assert {shape[0] for shape in handed} == {rows}
+    assert {shape[0] for shape in run["handed"]} == {rows}
+    got, want = run["got"], run["want"]
     for (path_, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
                              jax.tree.leaves(want)):
         assert np.isfinite(np.asarray(g)).all(), path_
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
                                       err_msg=str(path_))
-    if forced is not None:      # nothing of this call's is held here
+    if UNWRITTEN_ROUTINGS[routing] is not None:   # nothing is held here
         assert not float(got[0]) and not np.asarray(got[1]["w1"]).any()
+
+
+@_matrix
+def test_the_load_is_the_count_of_the_chosen_experts(
+        layer, ungated, path, activation, routing):
+    """`return_load`: each held expert's rows are the picks `return_routing`
+    shows on it, exactly, and the last number the row tiles they fill (one
+    at least an expert); kernels interpreted and fallback, SwiGLU, ReGLU
+    and no gate. The same run as the test above, so the value and every
+    gradient beside this load are bit for bit what they are without it."""
+    run = _unwritten_run(layer, ungated, path, activation, routing)
+    held, offset = 4, 2
+    local = run["chosen"] - offset
+    assert run["chosen"].shape == (T, K)
+    counts = np.bincount(local[(local >= 0) & (local < held)],
+                         minlength=held)
+    load = run["load"]
+    assert load.dtype == np.int32 and load.shape == (held + 1,)
+    np.testing.assert_array_equal(load[:held], counts)
+    tile = moe.buffer_rows(T, K, held, NE)[1]
+    assert load[held] == sum(max(-(-int(n) // tile), 1) for n in counts)
+    if UNWRITTEN_ROUTINGS[routing] is None:
+        assert counts[1] == 0 and counts.sum() > 0    # expert 3 gets none
+    else:
+        assert not counts.any() and load[held] == held
 
 
 # --------------------------------------------------------------------- #
@@ -921,9 +1035,7 @@ def test_the_softmax_shares_add_up_to_the_uncut_layer(softmaxed, parts):
     """Every share routes over all 32 and gives its own experts' part; the
     parts add up to the uncut layer (the shared expert is the model's to
     add, once: tests/models/test_qwen3_next.py)."""
-    held = NE_S // parts
-    total = sum(_softmax_share(softmaxed, i * held, held)
-                for i in range(parts))
+    total = _shares_total(softmaxed, parts, K_S, score="softmax")
     np.testing.assert_allclose(np.asarray(total),
                                np.asarray(_softmax_share(softmaxed, 0, NE_S)),
                                atol=3e-6)
@@ -944,11 +1056,15 @@ def _softmax_dense(x, router, w1, w3, w2, offset):
 SOFTMAX_OPERANDS = ("x", "router", "w1", "w3", "w2")
 
 
-@pytest.mark.parametrize("wrt", range(5), ids=SOFTMAX_OPERANDS)
-@pytest.mark.parametrize("offset,held", [(0, NE_S), (8, 8)],
-                         ids=["all", "share"])
-def test_softmax_gradients_match_the_dense_formulation(softmaxed, offset,
-                                                       held, wrt):
+_SOFTMAX_GRADS = {}
+
+
+def _softmax_grads(softmaxed, offset, held):
+    """(the routed call's, the dense form's) gradients by EVERY operand of
+    one share: compiled once a share, whichever operand's case asks
+    first."""
+    if (offset, held) in _SOFTMAX_GRADS:
+        return _SOFTMAX_GRADS[offset, held]
     sl = slice(offset, offset + held)
     args = (softmaxed["x"], softmaxed["router"], softmaxed["w1"][sl],
             softmaxed["w3"][sl], softmaxed["w2"][sl])
@@ -963,8 +1079,18 @@ def test_softmax_gradients_match_the_dense_formulation(softmaxed, offset,
     def dense(x, router, w1, w3, w2):
         return jnp.sum(_softmax_dense(x, router, w1, w3, w2, offset) * target)
 
-    got = jax.jit(jax.grad(routed, argnums=wrt))(*args)
-    want = jax.grad(dense, argnums=wrt)(*args)
+    both = _SOFTMAX_GRADS[offset, held] = (
+        jax.jit(jax.grad(routed, argnums=range(5)))(*args),
+        jax.grad(dense, argnums=range(5))(*args))
+    return both
+
+
+@pytest.mark.parametrize("wrt", range(5), ids=SOFTMAX_OPERANDS)
+@pytest.mark.parametrize("offset,held", [(0, NE_S), (8, 8)],
+                         ids=["all", "share"])
+def test_softmax_gradients_match_the_dense_formulation(softmaxed, offset,
+                                                       held, wrt):
+    got, want = (g[wrt] for g in _softmax_grads(softmaxed, offset, held))
     scale = max(float(jnp.max(jnp.abs(want))), 1e-3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5 * scale + 1e-6)
@@ -1115,10 +1241,10 @@ def test_the_router_s_rows_choose_and_the_experts_rows_are_computed_on(
 
 def test_the_reglu_shares_add_up_to_the_uncut_layer(layer, router_rows):
     whole = np.asarray(_reglu_share(layer, router_rows, 0, NE))
+    call = {**layer, "bias": None, "router_x": router_rows}
     for parts in (8, 4, 2):
-        held = NE // parts
-        total = sum(np.asarray(_reglu_share(layer, router_rows, i * held, held))
-                    for i in range(parts))
+        total = _shares_total(call, parts, score="softmax",
+                              activation="reglu")
         np.testing.assert_allclose(total, whole, atol=2e-5)
 
 

@@ -761,11 +761,26 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
 # it: the three Mamba-2 layers' scan is `ssd_fwd` and `ssd_bwd` (`ops/ssd.py`)
 # where the `jax.numpy` scan, its recompute and JAX's derivative of both
 # stood, 128 k less; the five other cells hold no scan and their pairs
-# stood. A PR that changes what one of these programs
+# stood. PR 55: each routed cell's `jit_bwd` has one more OUTPUT, its routed
+# layers' loads (`ops/moe.load_of`, int32 [layers, held + 1]: a concatenate
+# a layer and one stack), 0.8 k to 1.1 k more text; `gpt3-2.7b` has no routed
+# layer and its pair stood; with the telemetry ring off all six lower to the
+# pairs PR 54 left (`LOWERED_RING_OFF`; all six read so by hand, PR 55, one
+# held below). A PR that changes what one of these programs
 # computes takes its new text's pair from a failing run; one that leaves a
 # pair standing has shown that the program bypasses its change (PR 46's
 # rotary left `gpt3-2.7b`'s and `nemotron-3-nano-30b-a3b`'s).
 LOWERED = {
+    "gpt3-2.7b": ("f815b23b0da3328e", 169829),
+    "lfm2-24b-a2b": ("a6041591a22bd3a0", 615247),
+    "moonlight-16b-a3b": ("b970670abf461bb4", 772113),
+    "nemotron-3-nano-30b-a3b": ("c217ffac7beb08a7", 528152),
+    "qwen3-next-80b-a3b": ("af1f761d3f90d518", 1044847),
+    "smallthinker-21b-a3b": ("fea640d2303c8879", 677722),
+}
+# With `OOBLECK_TELEMETRY=0`: the programs without the loads' output, which
+# are the texts PR 54 left.
+LOWERED_RING_OFF = {
     "gpt3-2.7b": ("f815b23b0da3328e", 169829),
     "lfm2-24b-a2b": ("eab93850ea8613bd", 614222),
     "moonlight-16b-a3b": ("49a3b8f2ddf364a7", 771085),
@@ -789,10 +804,33 @@ ROTATIONS = {
 
 @pytest.mark.parametrize("cell", sorted(LOWERED))
 def test_a_cell_s_backward_lowers_to_the_text_it_lowered_to(v5e, cell):
-    import hashlib
-
     text, rotations = _lowered_backward(cell, tuple(v5e))
     assert rotations == ROTATIONS[cell]
+    assert _pair(text) == LOWERED[cell]
+
+
+def _pair(text):
+    import hashlib
+
     text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
-    assert (hashlib.sha256(text.encode()).hexdigest()[:16],
-            len(text)) == LOWERED[cell]
+    return hashlib.sha256(text.encode()).hexdigest()[:16], len(text)
+
+
+def test_with_the_telemetry_ring_off_a_routed_cell_lowers_to_pr_54_s_text(
+        v5e, monkeypatch):
+    """The switch that is there turns the loads' output off: the stage
+    program is then the one the parent built (the cell with the shortest
+    text; the five others read the same by hand)."""
+    from oobleck_tpu.execution.pipeline import PROGRAMS
+    from oobleck_tpu.obs import telemetry
+
+    cell = "nemotron-3-nano-30b-a3b"
+    monkeypatch.setenv(telemetry.ENV_TELEMETRY, "0")
+    monkeypatch.setattr(telemetry, "_instance", telemetry.TelemetryRing())
+    held = dict(PROGRAMS)
+    try:
+        text, _ = _lowered_backward.__wrapped__(cell, tuple(v5e))
+    finally:
+        PROGRAMS.clear()
+        PROGRAMS.update(held)
+    assert _pair(text) == LOWERED_RING_OFF[cell] != LOWERED[cell]
