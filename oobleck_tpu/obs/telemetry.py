@@ -273,14 +273,16 @@ def load_stats(loads: list) -> dict:
     `rows_spread_pct`, 100 x (max - min) / median of a step's rows over
     all layers."""
     rows = walked = 0
-    by_expert: dict[str, list[int]] = {}
+    by_expert: dict[tuple, list[int]] = {}
     by_step = []
     for _, load in loads:
         step_rows = 0
         for layer, (*held, tiles, tile) in load.items():
             step_rows += sum(held)
             walked += tiles * tile
-            seen = by_expert.setdefault(layer, [0] * len(held))
+            # By the experts held too: a window can span a pipeline built
+            # anew over another share (and the tests' several models).
+            seen = by_expert.setdefault((layer, len(held)), [0] * len(held))
             for e, n in enumerate(held):
                 seen[e] += n
         rows += step_rows

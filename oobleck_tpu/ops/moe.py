@@ -5,11 +5,14 @@ work for the (token, slot) pairs that are routed to the experts this chip
 HOLDS: pairs are laid out by expert in one buffer of the static worst-case
 length, each expert's rows padded to a whole number of row tiles, and the
 products (three of a SwiGLU expert, two of an expert without a gate) run as
-grouped matrix multiplications that visit the tiles in use and skip the
-empty tail. What XLA does around them (rows in and out of the buffer, the
-activation) loops over the tiles in use too, and starts from a buffer that
-is allocated and not filled (`_unwritten`), so no work follows the buffer's
-length. Kernels (stable names on the
+grouped matrix multiplications whose grids END at the tiles in use: the row
+axis's bound is the plan's `num_tiles`, a dynamic grid bound, so the empty
+tail is no grid step at all (as steps that did nothing, the tail was 83 to
+95 % of the benchmark cells' grids and real time: PERF.md section 6, PR 56).
+What XLA does around them (rows in and out of the buffer, the activation)
+loops over the tiles in use too, and starts from a buffer that is allocated
+and not filled (`_unwritten`), so no work follows the buffer's length.
+Kernels (stable names on the
 `pallas_call`, so a device trace shows `%moe_gmm.N` / `%moe_tgmm.N` /
 `%moe_token_sum.N`):
 
@@ -170,9 +173,10 @@ def _unwritten(shape, dtype) -> jax.Array:
     such a buffer, and its contract is the one `gmm_call`'s output already
     keeps: rows of tiles past `num_tiles` hold NOTHING (whatever the memory
     held), rows of a tile in use that hold no pair are zero because the
-    loop writes them so, and no consumer may read the former: the kernels
-    skip those tiles, the loops run `num_tiles` trips. Counted where it is
-    built, as the calls below are: `oobleck_moe_unfilled_buffers_total`."""
+    loop writes them so, and no consumer may read the former: the kernels'
+    grids end at `num_tiles`, the loops run `num_tiles` trips. Counted where
+    it is built, as the calls below are:
+    `oobleck_moe_unfilled_buffers_total`."""
     from oobleck_tpu.utils import metrics
 
     metrics.registry().counter(
@@ -279,83 +283,84 @@ def token_runs(plan: RoutingPlan, local_expert: jax.Array,
 # kernels                                                                #
 # --------------------------------------------------------------------- #
 
-def _gmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, out_ref, w_scratch,
-              *, transpose_rhs: bool):
+def _gmm_body(tile_group, lhs_ref, rhs_ref, out_ref, w_scratch, *,
+              transpose_rhs: bool):
     """One row tile times its expert's [K, tn] (or [tn, K], transposed)
     block. The expert's block stays in VMEM over the tiles of one expert
     (its block index does not change), so an expert's matrix is read once
     a call; it is cast to the rows' dtype once per expert too."""
     m = pl.program_id(1)
+    if w_scratch is None:
+        w = rhs_ref[...]
+    else:
+        new_expert = jnp.logical_or(
+            m == 0, tile_group[m] != tile_group[jnp.maximum(m - 1, 0)])
 
-    @pl.when(m < num_tiles[0])
-    def _():
-        if w_scratch is None:
-            w = rhs_ref[...]
-        else:
-            new_expert = jnp.logical_or(
-                m == 0, tile_group[m] != tile_group[jnp.maximum(m - 1, 0)])
+        @pl.when(new_expert)
+        def _():
+            w_scratch[...] = rhs_ref[...].astype(w_scratch.dtype)
 
-            @pl.when(new_expert)
-            def _():
-                w_scratch[...] = rhs_ref[...].astype(w_scratch.dtype)
-
-            w = w_scratch[...]
-        dims = (((1,), (1,)), ((), ())) if transpose_rhs else (
-            ((1,), (0,)), ((), ()))
-        out_ref[...] = lax.dot_general(
-            lhs_ref[...], w, dims,
-            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+        w = w_scratch[...]
+    dims = (((1,), (1,)), ((), ())) if transpose_rhs else (
+        ((1,), (0,)), ((), ()))
+    out_ref[...] = lax.dot_general(
+        lhs_ref[...], w, dims,
+        preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
 def _interpret() -> bool:
     return not _pallas_ok()
 
 
-def _last_tile(m, num_tiles):
-    """Tiles past the last one in use keep the block indices of the last:
-    nothing is fetched for them and nothing written."""
-    return jnp.minimum(m, num_tiles[0] - 1)
+def _count_plan_bounded_grid(kernel: str) -> None:
+    """`oobleck_moe_plan_bounded_grids_total{kernel}`: counted where the
+    kernel is built, once a call traced (not once a step). How many steps
+    the grids then run is read every step:
+    `oobleck_moe_step_tile_rows_total` over the tile's rows."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_moe_plan_bounded_grids_total",
+        "Grouped expert kernels whose grid's row axis ends at the plan's "
+        "tiles in use (a dynamic grid bound), built into traced programs"
+    ).inc(kernel=kernel)
 
 
 def gmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int,
              transpose_rhs: bool = False):
     """`moe_gmm`: lhs [M, K] (rows by expert, whole tiles) x rhs [E, K, N]
     -> [M, N]; with `transpose_rhs`, rhs is [E, N, K]. Rows of tiles past
-    `num_tiles` are not written."""
+    `num_tiles` are not written: the grid's row axis ends there (a dynamic
+    bound; `num_tiles` >= the experts >= 1, so no grid is empty)."""
     m_rows, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     assert rhs.shape[2 if transpose_rhs else 1] == k, (lhs.shape, rhs.shape)
     assert m_rows % tile == 0, (m_rows, tile)
     tn = _col_tile(n, MAX_COL_TILE)
     cast = rhs.dtype != lhs.dtype
+    _count_plan_bounded_grid("gmm")
 
-    last = _last_tile
-    rows_of = lambda n_, m_, tg, nt: (last(m_, nt), 0)
     if transpose_rhs:
-        rhs_block, rhs_of = (None, tn, k), (
-            lambda n_, m_, tg, nt: (tg[last(m_, nt)], n_, 0))
+        rhs_block, rhs_of = (None, tn, k), lambda n_, m_, tg: (tg[m_], n_, 0)
     else:
-        rhs_block, rhs_of = (None, k, tn), (
-            lambda n_, m_, tg, nt: (tg[last(m_, nt)], 0, n_))
+        rhs_block, rhs_of = (None, k, tn), lambda n_, m_, tg: (tg[m_], 0, n_)
     scratch = [pltpu.VMEM(rhs_block[1:], lhs.dtype)] if cast else []
     body = functools.partial(_gmm_body, transpose_rhs=transpose_rhs)
-    kernel = body if cast else (
-        lambda tg, nt, l, r, o: body(tg, nt, l, r, o, None))
+    kernel = body if cast else (lambda tg, l, r, o: body(tg, l, r, o, None))
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((m_rows, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n // tn, m_rows // tile),
-            in_specs=[pl.BlockSpec((tile, k), rows_of),
+            num_scalar_prefetch=1,
+            grid=(n // tn, num_tiles[0]),
+            in_specs=[pl.BlockSpec((tile, k), lambda n_, m_, tg: (m_, 0)),
                       pl.BlockSpec(rhs_block, rhs_of)],
-            out_specs=pl.BlockSpec(
-                (tile, tn), lambda n_, m_, tg, nt: (last(m_, nt), n_)),
+            out_specs=pl.BlockSpec((tile, tn), lambda n_, m_, tg: (m_, n_)),
             scratch_shapes=scratch),
         compiler_params=_GMM_PARAMS,
         interpret=_interpret(),
         name="moe_gmm",
-    )(tile_group, num_tiles, lhs, rhs)
+    )(tile_group, lhs, rhs)
 
 
 def _tgmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, *refs):
@@ -366,37 +371,34 @@ def _tgmm_body(tile_group, num_tiles, lhs_ref, rhs_ref, *refs):
     *start_ref, out_ref, acc = refs
     m = pl.program_id(2)
     used = num_tiles[0]
+    group = tile_group[m]
+    first = jnp.logical_or(
+        m == 0, group != tile_group[jnp.maximum(m - 1, 0)])
+    last = jnp.logical_or(
+        m == used - 1, group != tile_group[jnp.minimum(m + 1, used - 1)])
 
-    @pl.when(m < used)
+    @pl.when(first)
     def _():
-        group = tile_group[m]
-        first = jnp.logical_or(
-            m == 0, group != tile_group[jnp.maximum(m - 1, 0)])
-        last = jnp.logical_or(
-            m == used - 1,
-            group != tile_group[jnp.minimum(m + 1, used - 1)])
+        if start_ref:
+            acc[...] = start_ref[0][...].astype(acc.dtype)
+        else:
+            acc[...] = jnp.zeros_like(acc)
 
-        @pl.when(first)
-        def _():
-            if start_ref:
-                acc[...] = start_ref[0][...].astype(acc.dtype)
-            else:
-                acc[...] = jnp.zeros_like(acc)
+    acc[...] += lax.dot_general(
+        lhs_ref[...], rhs_ref[...], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-        acc[...] += lax.dot_general(
-            lhs_ref[...], rhs_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-        @pl.when(last)
-        def _():
-            out_ref[...] = acc[...].astype(out_ref.dtype)
+    @pl.when(last)
+    def _():
+        out_ref[...] = acc[...].astype(out_ref.dtype)
 
 
 def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
               out_dtype, start=None):
     """`moe_tgmm`: lhs [M, K], rhs [M, N] -> [E, K, N], expert e's block
-    the product over e's rows. Every expert has a tile, so every block is
-    written.
+    the product over e's rows. The grid's row axis ends at `num_tiles` (a
+    dynamic bound, as `gmm_call`'s); every expert has a tile, so every
+    block is written.
 
     With `start` [E, K, N] in `out_dtype` the result is `start` + that
     product, IN `start`'s buffer: a third tensor operand under the output's
@@ -408,12 +410,11 @@ def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
     assert rhs.shape[0] == m_rows and m_rows % tile == 0
     tk = _col_tile(k, MAX_TGMM_ROWS)
     tn = _col_tile(n, MAX_COL_TILE)
+    _count_plan_bounded_grid("tgmm")
 
-    last = _last_tile
     out_shape = jax.ShapeDtypeStruct((num_groups, k, n), out_dtype)
     block_of_dw = pl.BlockSpec(
-        (None, tk, tn),
-        lambda k_, n_, m_, tg, nt: (tg[last(m_, nt)], k_, n_))
+        (None, tk, tn), lambda k_, n_, m_, tg, nt: (tg[m_], k_, n_))
     started = [] if start is None else [start]
     for operand in started:
         assert (operand.shape, operand.dtype) == (
@@ -422,17 +423,19 @@ def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
         _tgmm_body,
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
+            # `num_tiles` twice: the row axis's bound, and a prefetched
+            # table for the body's test of the last tile.
             num_scalar_prefetch=2,
-            grid=(k // tk, n // tn, m_rows // tile),
+            grid=(k // tk, n // tn, num_tiles[0]),
             in_specs=[
-                pl.BlockSpec((tile, tk),
-                             lambda k_, n_, m_, tg, nt: (last(m_, nt), k_)),
-                pl.BlockSpec((tile, tn),
-                             lambda k_, n_, m_, tg, nt: (last(m_, nt), n_)),
+                pl.BlockSpec((tile, tk), lambda k_, n_, m_, tg, nt: (m_, k_)),
+                pl.BlockSpec((tile, tn), lambda k_, n_, m_, tg, nt: (m_, n_)),
                 *[block_of_dw for _ in started]],
             out_specs=block_of_dw,
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
-        # Operands count the two prefetched tables: `start` is the fifth.
+        # Counted over the call's own operands, the two prefetched tables
+        # among them: `start` is the fifth. (In the lowered custom call
+        # the grid's bound comes first and the alias reads 5.)
         input_output_aliases={4: 0} if started else {},
         compiler_params=_TGMM_PARAMS,
         interpret=_interpret(),

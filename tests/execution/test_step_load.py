@@ -379,3 +379,16 @@ def test_the_log_line_and_the_stall_event_carry_the_load(by_hand, caplog):
                 if e["event"] == "step_stall"]
     assert event["last_load"] == totals
     assert event["last_sample"] is None
+
+
+def test_a_window_that_spans_another_share_of_the_experts_still_adds_up():
+    """A pipeline built anew over another share holds another number of
+    experts under the same layer's label, and the ring's window can hold
+    both (so can one process of the tests, model after model: a whole run
+    of PR 56 failed there): each share's experts are summed among their
+    own."""
+    two = (7, {"1": (3, 1, 2, 8)})              # 2 held: 3 + 1 of 2 x 8
+    four = (8, {"1": (1, 1, 1, 5, 4, 8)})       # 4 held: 8 of 4 x 8
+    assert telemetry.load_stats([two, four]) == {
+        "fill_pct": 100.0 * 12 / 48, "skew": 5 * 4 / 8,
+        "rows_spread_pct": 100.0 * 4 / 6}

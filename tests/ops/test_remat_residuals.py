@@ -248,9 +248,13 @@ def _the_experts_sums_are_the_kernels(cell, text):
     for operands in dw:
         # The sum is the call's last operand: the donated `acc` leaf
         # itself (or its layout copy), written in place.
-        assert re.search(r", %(acc_\w+|copy)[.\d]*\), custom_call_target",
-                         operands), operands[:300]
-        assert "output_to_operand_aliasing={{}: (4, {})}" in operands
+        # (Six operands since PR 56: the text numbers the sixth.)
+        assert re.search(r", /\*index=5\*/%(acc_\w+|copy)[.\d]*\), "
+                         r"custom_call_target", operands), operands[:300]
+        # Operand 5 of the compiled call: the grid's dynamic bound comes
+        # first since PR 56 (the plan's `num_tiles`), then the two tables
+        # and the two row operands; it was 4.
+        assert "output_to_operand_aliasing={{}: (5, {})}" in operands
     kinds = [kind for _, kind, _ in ops]
     assert kinds.count("add") == 0
     assert kinds.count("copy") == layout_copies

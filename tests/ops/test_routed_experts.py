@@ -1309,6 +1309,110 @@ def test_every_cell_keeps_its_row_tile(cell):
     assert moe.choose_row_tile(tokens * top_k, experts) == want
 
 
+# --------------------------------------------------------------------- #
+# the grouped kernels' grids end at the tiles in use (PR 56)             #
+# --------------------------------------------------------------------- #
+
+# Each routed cell's call: tokens a microbatch, picks a token, experts held,
+# experts, D, F (`benchmarks/configs/*.json`'s published widths, the runners'
+# microbatches), and the row steps a grid ran over the worst-case buffer
+# before its row axis took the plan's bound: buffer rows / tile. In use, with
+# even loads: one tile an expert (`choose_row_tile`), two in `lfm2-24b-a2b`
+# and `smallthinker-21b-a3b`: 4.8 to 17.0 % of them.
+CELL_CALLS = {
+    "lfm2-24b-a2b": ((8192, 4, 8, 64, 2048, 1536), 94),
+    "moonlight-16b-a3b": ((4096, 6, 8, 64, 2048, 1408), 56),
+    "nemotron-3-nano-30b-a3b": ((4096, 6, 8, 128, 2688, 1856), 72),
+    "qwen3-next-80b-a3b": ((4096, 10, 16, 512, 2048, 512), 336),
+    "smallthinker-21b-a3b": ((16384, 6, 8, 64, 2560, 768), 104),
+}
+
+
+def _grouped_call(kernel, rows, held, tile, d, f):
+    """(a function of `tile_group` and `num_tiles` that makes the cell's
+    call of `kernel`, its other operands): `moe_gmm` as the forward's first
+    product, `moe_tgmm` as that product's dW on top of a handed sum."""
+    s, bf16, f32 = jax.ShapeDtypeStruct, jnp.bfloat16, jnp.float32
+    if kernel == "gmm":
+        return (lambda tg, nt, lhs, rhs: moe.gmm_call(
+            lhs, rhs, tg, nt, tile=tile)), (
+            s((rows, d), bf16), s((held, d, f), f32))
+    return (lambda tg, nt, lhs, rhs, start: moe.tgmm_call(
+        lhs, rhs, tg, nt, tile=tile, num_groups=held, out_dtype=f32,
+        start=start)), (
+        s((rows, d), bf16), s((rows, f), bf16), s((held, d, f), f32))
+
+
+@pytest.mark.parametrize("kernel", ["gmm", "tgmm"])
+@pytest.mark.parametrize("cell", sorted(CELL_CALLS))
+def test_a_grouped_kernel_s_row_axis_ends_at_the_plan_s_tiles(cell, kernel):
+    """The traced `pallas_call` carries ONE dynamic grid bound, its row
+    axis's (the last: the expert's block stays in VMEM over it), and the
+    other axes' bounds are the shape's. Read from the jaxpr: no kernel is
+    run. Counted where it is built."""
+    from oobleck_tpu.utils import metrics
+    from tests.ops.programs import all_eqns
+
+    (tokens, top_k, held, experts, d, f), row_steps = CELL_CALLS[cell]
+    rows, tile = moe.buffer_rows(tokens, top_k, held, experts)
+    assert (tile, rows // tile) == (CELL_TILES[cell][1], row_steps)
+    call, operands = _grouped_call(kernel, rows, held, tile, d, f)
+    built = metrics.registry().counter("oobleck_moe_plan_bounded_grids_total")
+    before = built.value(kernel=kernel)
+    jaxpr = jax.make_jaxpr(call)(
+        jax.ShapeDtypeStruct((rows // tile,), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32), *operands)
+    assert built.value(kernel=kernel) - before == 1
+    (eqn,) = [e for e in all_eqns(jaxpr.jaxpr)
+              if e.primitive.name == "pallas_call"]
+    assert eqn.params["name"] == f"moe_{kernel}"
+    mapping = eqn.params["grid_mapping"]
+    assert mapping.num_dynamic_grid_bounds == 1
+    *static, row_axis = mapping.grid
+    assert not isinstance(row_axis, int), mapping.grid
+    columns = f // moe._col_tile(f, moe.MAX_COL_TILE)
+    assert static == {
+        "gmm": [columns],
+        "tgmm": [d // moe._col_tile(d, moe.MAX_TGMM_ROWS), columns]}[kernel]
+    # The bound is the call's first operand; `moe_tgmm` also keeps
+    # `num_tiles` as a prefetched table (its body's test of the last tile).
+    assert mapping.num_index_operands == (2 if kernel == "tgmm" else 1)
+    assert eqn.invars[0].aval.shape == ()
+
+
+@pytest.mark.parametrize("kernel", ["gmm", "tgmm"])
+def test_a_plan_at_its_least_in_a_long_buffer_is_the_buffer_cut_to_it(kernel):
+    """No pick lands here: `num_tiles` == the experts held, a tile each,
+    in a buffer of five times as many. The kernels' results are those of a
+    buffer cut to the tiles in use, bit for bit (the interpreter runs the
+    same dynamic bound): the rows past them are no grid step."""
+    held, tile, k, n = 4, 16, 32, 48
+    rows = 5 * held * tile
+    plan = moe.plan_routing(jnp.full((23,), held, jnp.int32), held, rows, tile)
+    used = int(plan.num_tiles[0])
+    assert used == held < rows // tile
+    ks = jax.random.split(jax.random.PRNGKey(56), 4)
+    lhs = jax.random.normal(ks[0], (rows, k)).astype(jnp.bfloat16)
+    if kernel == "gmm":
+        rhs = jax.random.normal(ks[1], (held, k, n))
+        call = lambda lhs, tg: moe.gmm_call(lhs, rhs, tg, plan.num_tiles,
+                                            tile=tile)
+        got = call(lhs, plan.tile_group)[:used * tile]
+        want = call(lhs[:used * tile], plan.tile_group[:used])
+    else:
+        d_out = jax.random.normal(ks[2], (rows, n)).astype(jnp.bfloat16)
+        start = jax.random.normal(ks[3], (held, k, n))
+        call = lambda lhs, d, tg: moe.tgmm_call(
+            lhs, d, tg, plan.num_tiles, tile=tile, num_groups=held,
+            out_dtype=jnp.float32, start=start)
+        got = call(lhs, d_out, plan.tile_group)
+        want = call(lhs[:used * tile], d_out[:used * tile],
+                    plan.tile_group[:used])
+    assert np.abs(np.asarray(want, np.float32)).max() > 1.0
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
 def test_the_smallthinker_cell_s_expected_rows_sit_on_every_tile_s_edge():
     """1,536 rows expected an expert = 3 x 512 = 4 x 384 = 6 x 256 = 12 x
     128: no tile up to MAX_ROW_TILE keeps them off an edge (at 512 an

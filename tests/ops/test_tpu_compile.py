@@ -735,9 +735,12 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
         "moe_gmm", "moe_tgmm", "moe_token_sum"}
     dw = [attrs for n, attrs in calls if n == "moe_tgmm"]
     assert len(dw) == sums
-    for attrs in dw:        # operands: two tables, rows, rows, the sum
+    # Operands of the lowered call: the grid's dynamic bound (the plan's
+    # `num_tiles`, PR 56: it comes first), two tables, rows, rows, the sum.
+    # `tgmm_call`'s own `input_output_aliases={4: 0}` counts without it.
+    for attrs in dw:
         assert ("output_operand_aliases = [#stablehlo.output_operand_alias<"
-                "output_tuple_indices = [], operand_index = 4, "
+                "output_tuple_indices = [], operand_index = 5, "
                 "operand_tuple_indices = []>]") in attrs
     assert not any("output_operand_aliases" in attrs
                    for n, attrs in calls if n == "moe_gmm")
@@ -764,29 +767,36 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
 # stood. PR 55: each routed cell's `jit_bwd` has one more OUTPUT, its routed
 # layers' loads (`ops/moe.load_of`, int32 [layers, held + 1]: a concatenate
 # a layer and one stack), 0.8 k to 1.1 k more text; `gpt3-2.7b` has no routed
-# layer and its pair stood; with the telemetry ring off all six lower to the
+# layer and its pair stood; with the telemetry ring off all six lowered to the
 # pairs PR 54 left (`LOWERED_RING_OFF`; all six read so by hand, PR 55, one
-# held below). A PR that changes what one of these programs
+# held below). PR 56: the row axis of every `moe_gmm` / `moe_tgmm` grid is
+# bounded by the plan's `num_tiles` (a dynamic grid bound): each call has one
+# more operand, a scalar sliced from the plan, first in the custom call, and
+# `moe_gmm` one prefetched table less; 7.4 k more text in the four gated
+# cells, 3.7 k in the ungated cell's three routed layers, with the ring on
+# and off alike (both tables' five routed pairs are this PR's, all ten read
+# by hand); `gpt3-2.7b` has no routed layer and its pair stood. A PR that
+# changes what one of these programs
 # computes takes its new text's pair from a failing run; one that leaves a
 # pair standing has shown that the program bypasses its change (PR 46's
 # rotary left `gpt3-2.7b`'s and `nemotron-3-nano-30b-a3b`'s).
 LOWERED = {
     "gpt3-2.7b": ("f815b23b0da3328e", 169829),
-    "lfm2-24b-a2b": ("a6041591a22bd3a0", 615247),
-    "moonlight-16b-a3b": ("b970670abf461bb4", 772113),
-    "nemotron-3-nano-30b-a3b": ("c217ffac7beb08a7", 528152),
-    "qwen3-next-80b-a3b": ("af1f761d3f90d518", 1044847),
-    "smallthinker-21b-a3b": ("fea640d2303c8879", 677722),
+    "lfm2-24b-a2b": ("30ac16217d2aa3d1", 622621),
+    "moonlight-16b-a3b": ("3a164cc50547f3f1", 779490),
+    "nemotron-3-nano-30b-a3b": ("017819795168df87", 531837),
+    "qwen3-next-80b-a3b": ("b5ec433431af958f", 1052225),
+    "smallthinker-21b-a3b": ("20f2cca60c7db319", 685091),
 }
-# With `OOBLECK_TELEMETRY=0`: the programs without the loads' output, which
-# are the texts PR 54 left.
+# With `OOBLECK_TELEMETRY=0`: the programs without the loads' output: the
+# texts PR 54 left, with PR 56's grid bounds.
 LOWERED_RING_OFF = {
     "gpt3-2.7b": ("f815b23b0da3328e", 169829),
-    "lfm2-24b-a2b": ("eab93850ea8613bd", 614222),
-    "moonlight-16b-a3b": ("49a3b8f2ddf364a7", 771085),
-    "nemotron-3-nano-30b-a3b": ("ba6f9b889e95edcc", 527349),
-    "qwen3-next-80b-a3b": ("281d8f36e6f2551e", 1043793),
-    "smallthinker-21b-a3b": ("8e162b7d6c4c371a", 676696),
+    "lfm2-24b-a2b": ("a5a182008b7e884f", 621595),
+    "moonlight-16b-a3b": ("fa2544fa1ab56db0", 778461),
+    "nemotron-3-nano-30b-a3b": ("d7e0e1bd093e0f07", 531033),
+    "qwen3-next-80b-a3b": ("69c3f0234a6430ce", 1051170),
+    "smallthinker-21b-a3b": ("bc1577e573eacc84", 684064),
 }
 
 
@@ -819,8 +829,8 @@ def _pair(text):
 def test_with_the_telemetry_ring_off_a_routed_cell_lowers_to_pr_54_s_text(
         v5e, monkeypatch):
     """The switch that is there turns the loads' output off: the stage
-    program is then the one the parent built (the cell with the shortest
-    text; the five others read the same by hand)."""
+    program is then the one PR 54 built, under PR 56's grid bounds (the
+    cell with the shortest text; the five others read the same by hand)."""
     from oobleck_tpu.execution.pipeline import PROGRAMS
     from oobleck_tpu.obs import telemetry
 
