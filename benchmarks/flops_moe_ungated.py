@@ -12,27 +12,27 @@ a SwiGLU layer (`flops_moe.py`) needs nine:
              dW of each of the two (rows^T x rows -> [experts, ., .])
 
 Each is `flops_moe.grouped_product`: 2 * rows * hidden * inter operations,
-its row operand and row result (for dW its two row operands) once and
-every held expert's matrix once a call, at the operands' 2 bytes, at the
-PUBLISHED width (1856 for Nemotron-3-Nano: not the 1920 a call may pad it
-to, which is the kernel's cost). Products recomputed under remat are not
-counted.
+its row operand and row result once and every held expert's matrix once a
+call, at the operands' 2 bytes; a dW product `flops_moe.grouped_product_dw`:
+its two row operands and the float32 running sum of the gradient read and
+written (PR 42); all at the PUBLISHED width (1856 for Nemotron-3-Nano: not
+the 1920 a call may pad it to, which is the kernel's cost). Products
+recomputed under remat are not counted.
 """
 
 from __future__ import annotations
 
-from benchmarks import flops
-from benchmarks.flops_moe import grouped_product
+from benchmarks.flops_moe import layer_train_seconds
 
 PRODUCTS_FORWARD = 2
 PRODUCTS_BACKWARD = 4
+PRODUCTS_DW = 2                  # of the four backward products
 
 
 def routed_layer_train_seconds(rows: float, hidden: int, inter: int,
                                experts: int, device_kind: str) -> float:
-    """The least time one chip could take for the six products of one
-    routed layer over `rows` pairs: each product the larger of operations
-    over peak and bytes over bandwidth."""
-    ops, nbytes = grouped_product(rows, hidden, inter, experts)
-    one, _ = flops.roofline_seconds(ops, nbytes, device_kind)
-    return (PRODUCTS_FORWARD + PRODUCTS_BACKWARD) * one
+    """The six products of one routed layer without a gate, two of them
+    dW."""
+    return layer_train_seconds(rows, hidden, inter, experts, device_kind,
+                               PRODUCTS_FORWARD + PRODUCTS_BACKWARD,
+                               PRODUCTS_DW)

@@ -1,8 +1,11 @@
 """Device busy time inside one named XLA module per training step, in ms.
 
 Every jitted program of the training path is a named function, so its
-module has a name of ours on the device plane: `jit_fwd`, `jit_bwd`,
-`jit_grad_add`, `jit_optimizer_update`. The operations of the first device
+module has a name of ours on the device plane: `jit_bwd`, `jit_grad_zero`,
+`jit_optimizer_update` (and `jit_fwd` where a pipeline has more than one
+stage: the last virtual stage's `jit_bwd` is the loss's value-and-gradient
+since PR 30; no `jit_grad_add` since PR 33, the sum is `jit_bwd`'s own). The
+operations of the first device
 that ran in `module` (their `hlo_module` stat, else containment in the
 module line; `benchmarks/trace_detail.py`) are united into busy intervals,
 and their total is divided by the window's steps. A trace with no such

@@ -1,6 +1,6 @@
 """Mean device time of one call of a kernel over the traced window, in ms:
 all the time in operations whose name holds `match` (the kernel's
-`pallas_call` name, e.g. `%flash_bwd_dq.`) over their number. No operation
+`pallas_call` name, e.g. `%flash_bwd_dqkv.`) over their number. No operation
 that matches: nothing to read.
 """
 

@@ -5,7 +5,9 @@ The least time the chip could take for the nine grouped products
 (`benchmarks/flops_moe.py`) of every routed layer and microbatch of the
 window, over ALL the device time in operations whose name holds one of
 `match` (`%moe_gmm.`, `%moe_tgmm.`: the kernels' `pallas_call` names),
-recomputed forward products included in the time and not in the need.
+recomputed forward products included in the time and not in the need; a
+dW product's bytes hold the float32 running sum `moe_tgmm` reads and writes
+(PR 42).
 
 The rows a microbatch routes to the experts held here are not a constant
 of the cell: they come from the program's own counters, which its routing

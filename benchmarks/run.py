@@ -267,7 +267,14 @@ def main(argv=None) -> int:
                              "idle_gaps": reduced["idle_gaps"]}
         line["end_to_end_traced"] = {k: float(v) for k, v in e2e.items()}
     line["device"] = dev
+    # Each number `correct` was decided on, beside its limit: the line's
+    # last key and, after the line, the last lines of standard error.
+    line["compared"] = {c["check"]: {"value": c["value"], "limit": c["limit"]}
+                        for c in result["checks"]}
     print(json.dumps(line), flush=True)
+    for c in result["checks"]:
+        print(f"compared {c['check']}: {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     return 0
 
 

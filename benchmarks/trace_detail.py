@@ -5,8 +5,8 @@ module line, and the program's own spans on the host plane.
 `trace_reduce.load_xplane` keeps names, starts and durations. Two kinds of
 per-layer metric need more, and read it here:
 
-* which XLA module (`jit_fwd`, `jit_bwd`, `jit_grad_add`,
-  `jit_optimizer_update`) an operation ran in: the operation's `hlo_module`
+* which XLA module (`jit_bwd`, `jit_grad_zero`, `jit_optimizer_update`;
+  `jit_fwd` where a pipeline has more than one stage) an operation ran in: the operation's `hlo_module`
   stat where the event carries one (the CPU's do), else the module-line
   event that contains it in time (a TPU's operations carry their device
   offset and duration and nothing else: no module, and no JAX name stack,

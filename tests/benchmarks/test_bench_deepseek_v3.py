@@ -167,21 +167,29 @@ def test_cell_is_the_traffic_the_issue_gives():
 
 NEW_METRICS = ["flash_mla_fwd_roofline", "flash_mla_bwd_roofline",
                "flash_mla_fwd_calls_per_need", "moe_held_rows_drift"]
+THIS_CELLS_TOO = ["moe_gmm_ms", "moe_tgmm_ms", "moe_gmm_roofline",
+                  "moe_token_sum_ms", "moe_tile_fill_pct", "moe_load_skew",
+                  "moe_step_rows_spread_pct"]
+# Readers that would compute something WRONG on this cell, or find nothing
+# to read: one width of `hidden_size // num_heads`, a dense model's 6 N,
+# kernel names the latent calls do not carry. (Which further cells the
+# lists above name is a later PR's to say: membership, never a list's whole.)
 NOT_THIS_CELLS = ["flash_fwd_roofline", "flash_bwd_roofline",
-                  "flash_fwd_calls_per_need", "flash_dq_ms", "flash_dkv_ms",
-                  "mfu_pct.train", "flash_roofline",
-                  "device_ms_per_step.fwd", "device_ms_per_step.grad_add"]
+                  "flash_fwd_calls_per_need", "flash_bwd_ms",
+                  "mfu_pct.train", "flash_roofline", "ssd_fwd_ms",
+                  "ssd_bwd_ms", "ssd_fwd_roofline", "ssd_bwd_roofline"]
 
 
-@pytest.mark.parametrize("metric", NEW_METRICS + NOT_THIS_CELLS)
+@pytest.mark.parametrize("metric",
+                         NEW_METRICS + THIS_CELLS_TOO + NOT_THIS_CELLS)
 def test_which_metrics_name_the_cell(metric):
     (entry,) = [m for m in MANIFEST["per_layer"] if m["name"] == metric]
-    if metric in NEW_METRICS:
-        assert entry["workloads"] == [CELL["name"]]
-        assert entry["moves"] == "train_tokens_per_s"
-        assert entry["layer"] == "kernels"
-    else:
+    if metric in NOT_THIS_CELLS:
         assert CELL["name"] not in entry["workloads"]
+        return
+    assert CELL["name"] in entry["workloads"]
+    assert entry["moves"] == "train_tokens_per_s"
+    assert entry["layer"] == "kernels"
 
 
 # --------------------------------------------------------------------- #

@@ -1,8 +1,11 @@
 """The benchmark is driven by data: every name resolves to a file."""
 
+import copy
 import importlib
+import inspect
 import json
 import re
+import sys
 from pathlib import Path
 
 import pytest
@@ -129,3 +132,85 @@ def test_run_py_holds_no_list_of_cells_configs_or_metrics():
     text = (BENCH / "run.py").read_text()
     for _, entry in _named():
         assert f'"{entry["name"]}"' not in text.replace('"setup_s"', "")
+
+
+# --------------------------------------------------------------------- #
+# A PR that is NOT of kind `benchmark` adds new files and appends entries #
+# (benchmarks/README.md, "What a PR of another kind may add"): every       #
+# accepted test has to pass after such an append. The guard.              #
+# --------------------------------------------------------------------- #
+
+def _appended(manifest: dict) -> dict:
+    """The manifest as a later PR of another kind may leave it: a
+    configuration, a cell and a per-layer metric at the END of their lists,
+    the cell's name at the end of `train_tokens_per_s`'s `workloads` and of
+    every accepted per-layer metric's, and nothing there was changed,
+    moved or dropped."""
+    later = copy.deepcopy(manifest)
+    first = later["workloads"][0]["name"]
+    later["configs"].append(dict(
+        later["configs"][0], name="the-guards-model",
+        file="benchmarks/configs/the-guards-model.json"))
+    later["workloads"].append(dict(
+        later["workloads"][0], name="the-guards-model.steady",
+        config="the-guards-model"))
+    for metric in later["end_to_end"] + later["per_layer"]:
+        if "workloads" in metric:
+            metric["workloads"].append("the-guards-model.steady")
+    later["per_layer"].append({
+        "name": "the_guards_kernel_ms", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "kernels",
+        "moves": "train_tokens_per_s",
+        "workloads": [first, "the-guards-model.steady"]})
+    return later
+
+
+def _cases(test) -> list[dict]:
+    """The arguments pytest would call `test` with, from its `parametrize`
+    marks; `[{}]` for a test that takes none."""
+    cases = [{}]
+    for mark in getattr(test, "pytestmark", []):
+        if mark.name != "parametrize":
+            continue
+        names = mark.args[0]
+        if isinstance(names, str):
+            names = [n.strip() for n in names.split(",")]
+        rows = [getattr(v, "values", v) for v in mark.args[1]]
+        rows = [v if len(names) > 1 else (v,) for v in rows]
+        cases = [dict(c, **dict(zip(names, row)))
+                 for c in cases for row in rows]
+    return cases
+
+
+def test_appended_entries_pass_every_accepted_manifest_assertion(monkeypatch):
+    """Every test under `tests/benchmarks/` that reads the module global
+    `MANIFEST` (the family files keep `BENCHMARK.json` there) is called
+    again, with every case it is parametrised over, on `_appended`'s copy:
+    a test that holds a list's end (`[-5:]`, `configs[-1]`), a list's
+    whole (`workloads == [cell]`) or a count of entries fails HERE, in the
+    PR that writes it, and not in the later PR that appends. Found by
+    reading the module, so a family file a later PR brings is held too.
+    Of this file, the rules an entry itself must keep; that files and
+    entries come together, `test_layer_metric_file_and_reader` and
+    `test_every_layer_metric_file_is_in_the_manifest` hold on the repo's."""
+    later = _appended(MANIFEST)
+    here = sys.modules[__name__]
+    called = 0
+    for path in sorted(Path(__file__).parent.glob("test_bench_*.py")):
+        module = importlib.import_module(f"{__package__}.{path.stem}")
+        if module is here or not hasattr(module, "MANIFEST"):
+            continue
+        monkeypatch.setattr(module, "MANIFEST", later)
+        for name, test in sorted(vars(module).items()):
+            if not (name.startswith("test_") and inspect.isfunction(test)
+                    and "MANIFEST" in test.__code__.co_names):
+                continue
+            for case in _cases(test):
+                if set(inspect.signature(test).parameters) == set(case):
+                    test(**case)
+                    called += 1
+    assert called >= 100, called
+    monkeypatch.setattr(here, "MANIFEST", later)
+    test_manifest_shape()
+    for section in ("configs", "workloads", "per_layer"):
+        test_names_units_and_sources(section, later[section][-1])

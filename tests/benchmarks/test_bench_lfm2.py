@@ -97,6 +97,12 @@ def test_grouped_product_counts_by_hand():
     assert ops == 2 * 10 * 4 * 6
     assert nbytes == (10 * 4 + 10 * 6 + 2 * 4 * 6) * 2
     assert flops_moe.PRODUCTS_FORWARD + flops_moe.PRODUCTS_BACKWARD == 9
+    # A dW product: the two row operands at 2 bytes, and the float32
+    # running sum of the 2 matrices read and written (PR 42).
+    ops_dw, nbytes_dw = flops_moe.grouped_product_dw(10, 4, 6, 2)
+    assert ops_dw == ops
+    assert nbytes_dw == (10 * 4 + 10 * 6) * 2 + 2 * 4 * 6 * (4 + 4)
+    assert flops_moe.PRODUCTS_DW == 3
 
 
 def test_routed_layer_seconds_at_the_cells_shape():
@@ -105,13 +111,19 @@ def test_routed_layer_seconds_at_the_cells_shape():
     assert ops == pytest.approx(25.77e9, rel=1e-3)
     assert nbytes == pytest.approx(79.7e6, rel=1e-3)
     assert flops.roofline_seconds(ops, nbytes, "TPU v5 lite")[1] == "compute"
+    # Six of the nine by their products; a dW by the float32 sum of 8
+    # matrices of 2048 x 1536 it reads and writes: 201.3 MB beside the
+    # rows' 29.4, 0.2817 ms where its products are 0.1308.
+    dw = (4096 * 3584 * 2 + 8 * 2048 * 1536 * 8) / 819e9
+    assert dw == pytest.approx(0.2817e-3, rel=1e-3)
     assert flops_moe.routed_layer_train_seconds(
         4096, 2048, 1536, 8, "TPU v5 lite") == pytest.approx(
-        9 * ops / 197e12)
-    # Few rows: reading the experts' matrices bounds it.
+        6 * ops / 197e12 + 3 * dw)
+    # Few rows: reading the experts' matrices bounds all nine.
     assert flops_moe.routed_layer_train_seconds(
         64, 2048, 1536, 8, "TPU v5 lite") == pytest.approx(
-        9 * (64 * 3584 + 8 * 2048 * 1536) * 2 / 819e9)
+        (6 * (64 * 3584 + 8 * 2048 * 1536) * 2
+         + 3 * (64 * 3584 * 2 + 8 * 2048 * 1536 * 8)) / 819e9)
 
 
 def test_moe_roofline_reader_by_hand():

@@ -4,6 +4,7 @@ new readers on hand-made data, the reference's recurrence in blocks, forced
 routing and the shared expert, and the runner's and the control's flow
 rehearsed on the CPU at `nemotron-h-tiny` sizes (never a number)."""
 
+import importlib
 import json
 from pathlib import Path
 
@@ -211,6 +212,9 @@ def test_cell_is_the_traffic_the_issue_gives():
 
 NEW_METRICS = ["flash_d128_fwd_roofline", "flash_d128_bwd_roofline",
                "moe_gmm_ungated_roofline", "ssd_scan_ms", "mamba_mixer_ms"]
+# PR 57's, over the scan's two kernels (PR 54): data files, one reader.
+SSD_METRICS = ["ssd_fwd_ms", "ssd_bwd_ms", "ssd_fwd_roofline",
+               "ssd_bwd_roofline"]
 THIS_CELLS_TOO = [
     "dispatch_stall_ms.train", "input_wait_ms.train", "step_ms.train",
     "step_ms_p50.train", "step_ms_max.train", "host_dispatch_ms.train",
@@ -218,7 +222,9 @@ THIS_CELLS_TOO = [
     "device_ms_per_step.grad_zero", "idle_ms_per_step.in_step",
     "idle_ms_per_step.between_steps", "setup_engine_build_s",
     "setup_executables_s", "moe_gmm_ms", "moe_tgmm_ms",
-    "flash_fwd_calls_per_need", "flash_dq_ms", "flash_dkv_ms"]
+    "flash_fwd_calls_per_need", "flash_bwd_ms", "moe_held_rows_drift",
+    "moe_token_sum_ms", "moe_tile_fill_pct", "moe_load_skew",
+    "moe_step_rows_spread_pct"] + SSD_METRICS
 # Readers that would compute something WRONG on this cell: one width of
 # `hidden_size // num_heads` = 84 (no head of this model), 3 + 6 expert
 # products where these experts have 2 + 4, a dense model's 6 N, kernels
@@ -267,11 +273,20 @@ def test_ungated_experts_count_two_and_four_products():
     assert nbytes == 2 * (rows * (2688 + 1856) + 8 * 2688 * 1856)
     one, bound = flops.roofline_seconds(ops, nbytes, "TPU v5 lite")
     assert bound == "memory"        # 192 rows an expert: the matrices' bytes
+    # A dW product (2 of the 6): its two row operands and the float32 sum
+    # of 8 matrices of 2688 x 1856 read and written (PR 42), 319.3 MB.
+    ops_dw, nbytes_dw = flops_moe.grouped_product_dw(rows, hidden, inter, held)
+    assert ops_dw == ops
+    assert nbytes_dw == 2 * rows * (2688 + 1856) + 8 * (8 * 2688 * 1856)
+    dw = nbytes_dw / 819e9
+    assert dw == pytest.approx(0.4069e-3, rel=1e-3) and dw > ops / 197e12
     assert flops_moe_ungated.routed_layer_train_seconds(
-        rows, hidden, inter, held, "TPU v5 lite") == pytest.approx(6 * one)
+        rows, hidden, inter, held, "TPU v5 lite") == pytest.approx(
+        4 * one + 2 * dw)
     # Two thirds of what the same widths would need under SwiGLU.
     assert flops_moe.routed_layer_train_seconds(
-        rows, hidden, inter, held, "TPU v5 lite") == pytest.approx(9 * one)
+        rows, hidden, inter, held, "TPU v5 lite") == pytest.approx(
+        6 * one + 3 * dw)
 
 
 def test_ungated_roofline_reader_by_hand():
@@ -346,6 +361,62 @@ def test_flash_geometry_reader_takes_heads_from_the_configuration():
                        **fwd) is None
     assert reader.read(dict(data, trace={"time_by_name": {}}), **fwd) is None
     assert reader.read({}, **fwd) is None
+
+
+@pytest.mark.parametrize("metric", SSD_METRICS)
+def test_the_scans_kernels_metrics_by_hand(metric):
+    """PR 57's four over `%ssd_fwd.` / `%ssd_bwd.`: a call's mean time
+    through the accepted `kernel_call_ms`, its share of the roofline through
+    `readers/ssd_roofline_pct.py`, every width from the configuration and
+    the `M` layers held from its pattern (3 of `MEMEM*E`)."""
+    from benchmarks import flops_ssd
+
+    spec = json.loads((ROOT / "benchmarks" / "layer_metrics"
+                       / f"{metric}.json").read_text())
+    (entry,) = [m for m in MANIFEST["per_layer"] if m["name"] == metric]
+    assert (entry["layer"], entry["moves"]) == ("kernels",
+                                                "train_tokens_per_s")
+    reader = importlib.import_module(f"benchmarks.readers.{spec['reader']}")
+    # 40 steps of 8 microbatches: 3 x 320 calls of each kernel, PR 54's
+    # 0.3395 and 0.6948 ms a call; a routed kernel beside them.
+    trace = {"time_by_name": {
+        "%ssd_fwd.2 = bf16[1,4096,4096] custom-call": [960 * 0.3395e-3, 960],
+        "%ssd_bwd.5 = bf16[1,4096,4096] custom-call": [960 * 0.6948e-3, 960],
+        "%moe_gmm.3 = bf16[4608,1856] custom-call": [9.0, 1800]}}
+    data = {"trace": trace, "device": {"kind": "TPU v5 lite"},
+            "config": CONFIG,
+            "train": {"microbatch_size": 1, "seq_len": 4096,
+                      "microbatches_run": 320, "num_layers": 1}}
+    got = reader.read(data, **spec.get("args", {}))
+    if metric.endswith("_ms"):
+        assert spec["reader"] == "kernel_call_ms"
+        assert got == pytest.approx({"ssd_fwd_ms": 0.3395,
+                                     "ssd_bwd_ms": 0.6948}[metric])
+    else:
+        assert spec["reader"] == "ssd_roofline_pct"
+        fn = spec["args"]["needed"]
+        ops, nbytes = getattr(flops_ssd, fn)(1, 4096, 64, 64, 8, 128, 128)
+        least, bound = flops.roofline_seconds(ops, nbytes, "TPU v5 lite")
+        assert bound == "memory"
+        assert got == pytest.approx(
+            100 * least / {"scan_fwd": 0.3395e-3, "scan_bwd": 0.6948e-3}[fn])
+        assert got == pytest.approx({"scan_fwd": 55.06, "scan_bwd": 36.12}[fn],
+                                    rel=1e-3)
+        # A kernel called twice a layer and microbatch reads half.
+        twice = {k: [2 * s, 2 * n] for k, (s, n) in
+                 trace["time_by_name"].items()}
+        assert reader.read(dict(data, trace={"time_by_name": twice}),
+                           **spec["args"]) == pytest.approx(got / 2)
+        # A configuration without the scan's widths or without an `M`
+        # layer: nothing to read.
+        for config in ({"hidden_size": 2688},
+                       dict(CONFIG, hybrid_override_pattern="E*E")):
+            assert reader.read(dict(data, config=config),
+                               **spec["args"]) is None
+    # A trace without the kernel (PR 54's parent), no data: nothing.
+    assert reader.read(dict(data, trace={"time_by_name": {}}),
+                       **spec.get("args", {})) is None
+    assert reader.read({}, **spec.get("args", {})) is None
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS)

@@ -222,22 +222,22 @@ THIS_CELLS_TOO = [
     "step_excess_ms.readback", "step_excess_ms.rest",
     "between_steps_ms.train", "slow_steps.train",
     "hbm_headroom_min_pct.train", "moe_gmm_ms", "moe_tgmm_ms",
-    "moe_gmm_roofline", "flash_fwd_calls_per_need", "flash_dq_ms",
-    "flash_dkv_ms"]
+    "moe_gmm_roofline", "flash_fwd_calls_per_need", "flash_bwd_ms",
+    "moe_held_rows_drift", "moe_token_sum_ms", "moe_tile_fill_pct",
+    "moe_load_skew", "moe_step_rows_spread_pct"]
 # Readers that would compute something WRONG on this cell, or find nothing
 # to read: one width of `hidden_size // num_heads` = 128 (no head of this
 # model's attention), a dense model's 6 N, kernels this model does not call,
-# programs a one-stage pipeline does not run, another family's scopes, and
-# a list a test of its own holds. (Which further metrics name the cell is a
-# later PR's to say: this file holds membership and never a list's end or
-# its whole.)
+# another family's scopes. (Which further metrics name the cell is a later
+# PR's to say: this file holds membership and never a list's end or its
+# whole.)
 NOT_THIS_CELLS = ["flash_roofline", "mfu_pct.train", "flash_fwd_roofline",
                   "flash_bwd_roofline", "flash_mla_fwd_roofline",
                   "flash_mla_bwd_roofline", "flash_mla_fwd_calls_per_need",
-                  "device_ms_per_step.fwd", "device_ms_per_step.grad_add",
-                  "moe_held_rows_drift", "moe_gmm_ungated_roofline",
+                  "moe_gmm_ungated_roofline",
                   "flash_d128_fwd_roofline", "flash_d128_bwd_roofline",
-                  "ssd_scan_ms", "mamba_mixer_ms"]
+                  "ssd_scan_ms", "mamba_mixer_ms", "ssd_fwd_ms", "ssd_bwd_ms",
+                  "ssd_fwd_roofline", "ssd_bwd_roofline"]
 
 
 @pytest.mark.parametrize("metric",
@@ -361,8 +361,13 @@ def test_routed_roofline_reads_this_cell_s_sizes_from_the_configuration():
     assert ops == 2 * rows * hidden * inter
     one, bound = flops.roofline_seconds(ops, nbytes, "TPU v5 lite")
     assert bound == "memory"        # 80 rows an expert: the matrices' bytes
+    # A dW product: its two row operands and the float32 sum of 16 matrices
+    # of 2048 x 512 read and written, 134.2 MB beside the rows' 6.6.
+    dw = (rows * (hidden + inter) * 2 + held * hidden * inter * 8) / 819e9
+    assert dw == pytest.approx(0.1719e-3, rel=1e-3)
     assert flops_moe.routed_layer_train_seconds(
-        rows, hidden, inter, held, "TPU v5 lite") == pytest.approx(9 * one)
+        rows, hidden, inter, held, "TPU v5 lite") == pytest.approx(
+        6 * one + 3 * dw)
 
 
 # --------------------------------------------------------------------- #

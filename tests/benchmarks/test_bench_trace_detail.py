@@ -31,24 +31,44 @@ def data():
     return _data()
 
 
+# What the cut's program (PR 26's) ran and today's does not: two backward
+# kernels a flash call (one since PR 47), a forward program in a one-stage
+# pipeline (PR 30), a program for the gradients' sum (PR 33). The metrics
+# that read them went in PR 57; the accepted readers still read the cut.
+GONE = {
+    "kernel_call_ms:flash_bwd_dq": ("kernel_call_ms",
+                                    {"match": "%flash_bwd_dq."}),
+    "kernel_call_ms:flash_bwd_dkv": ("kernel_call_ms",
+                                     {"match": "%flash_bwd_dkv."}),
+    "device_ms_by_module:jit_fwd": ("device_ms_by_module",
+                                    {"module": "jit_fwd"}),
+    "device_ms_by_module:jit_grad_add": ("device_ms_by_module",
+                                         {"module": "jit_grad_add"})}
+
+
 def _read(metric: str, data: dict):
-    spec = json.loads((LAYER_METRICS / f"{metric}.json").read_text())
-    reader = importlib.import_module(f"benchmarks.readers.{spec['reader']}")
-    return reader.read(data, **spec.get("args", {}))
+    """Through the metric's own file (reader and args), or for a name of
+    `GONE` through the reader and args it gives."""
+    if metric in GONE:
+        name, args = GONE[metric]
+    else:
+        spec = json.loads((LAYER_METRICS / f"{metric}.json").read_text())
+        name, args = spec["reader"], spec.get("args", {})
+    reader = importlib.import_module(f"benchmarks.readers.{name}")
+    return reader.read(data, **args)
 
 
-# What the cut reads, through each metric's own file (reader and args).
 ON_THE_CUT = {
     "flash_fwd_roofline": 1.02091,           # forward kernel, recomputes in the time
     "flash_bwd_roofline": 2.94444,           # dq + dk/dv
     "flash_fwd_calls_per_need": 3.0,         # once in fwd, twice in bwd
-    "flash_dq_ms": 2.82851,
-    "flash_dkv_ms": 4.39286,
+    "kernel_call_ms:flash_bwd_dq": 2.82851,
+    "kernel_call_ms:flash_bwd_dkv": 4.39286,
     # The cut is one HOST step plus 30 ms: the device works through a
     # little more than one step's programs in it.
-    "device_ms_per_step.fwd": 251.634,
+    "device_ms_by_module:jit_fwd": 251.634,
     "device_ms_per_step.bwd": 883.068,
-    "device_ms_per_step.grad_add": 71.7324,
+    "device_ms_by_module:jit_grad_add": 71.7324,
     "device_ms_per_step.optimizer": 39.1587,
     "step_ms_p50.train": 1213.767,
     "step_ms_max.train": 1213.767,
@@ -86,9 +106,21 @@ def _without_names(data: dict) -> dict:
 
 
 @pytest.mark.parametrize("metric", sorted(
-    set(ON_THE_CUT) - {"device_ms_per_step.fwd", "device_ms_per_step.bwd"}))
+    set(ON_THE_CUT) - {"device_ms_by_module:jit_fwd",
+                       "device_ms_per_step.bwd"}))
 def test_metric_is_left_out_where_the_program_lacks_the_name(data, metric):
     assert _read(metric, _without_names(data)) is None
+
+
+def test_the_one_backward_kernels_metric_finds_nothing_on_the_cut(data):
+    """`flash_bwd_ms` reads `%flash_bwd_dqkv.`, which PR 26's program did
+    not have: nothing, and not the two older kernels' time."""
+    assert _read("flash_bwd_ms", data) is None
+    by_name = dict(data["trace"]["time_by_name"])
+    by_name["%flash_bwd_dqkv.3 bf16[128,1024,128] custom-call"] = [0.5, 200]
+    by_name["%flash_swa_bwd_dqkv.1 bf16[28,16384,128] custom-call"] = [9., 9]
+    assert _read("flash_bwd_ms", dict(data, trace=dict(
+        data["trace"], time_by_name=by_name))) == pytest.approx(2.5)
 
 
 @pytest.mark.parametrize("metric", sorted(ON_THE_CUT))
