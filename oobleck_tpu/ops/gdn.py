@@ -23,39 +23,77 @@ sum of `g` INSIDE a chunk and `D_ij = exp(cum_i - cum_j)` for i >= j:
 (`N^Q = 0`) and the inverse is the finite series `sum_n N^n`, taken as the
 product `(I + N)(I + N^2)(I + N^4)...` of `log2 Q` factors: batched [Q, Q]
 matrix products the compiler knows, and no triangular solve walked row by
-row. Plain `jax.numpy`: `einsum`s, gradients by JAX's differentiation of
-them (under the layer's checkpoint like every other layer), but for the
-inverse, whose gradient is written down (`-X^T dX X^T`: two products a head
-and chunk where the series' own would be eighteen) and whose forward rule
-NAMES it (`RESIDUAL_NAMES`), so that a layer's checkpoint
+row. The system and its inverse are XLA's on every backend: `A` is one
+`einsum` and one fusion, differentiated by JAX; the inverse's gradient is
+written down (`-X^T dX X^T`: two products a head and chunk where the
+series' own would be eighteen) and its forward rule NAMES it
+(`RESIDUAL_NAMES`), so that a layer's checkpoint
 (`ops/remat.checkpoint_layer`) keeps it and the recomputed forward holds no
-series: the one value of the rule that only `2 (log2 Q - 1)` more float32
-products could give back. No Pallas kernel: `D`, `A` and `T` are written
-out, [Q, Q] float32 blocks a head and chunk, and the traffic that costs is
-what a kernel for this rule would save.
+series.
 
-Held to what `ops/ssd.py` is held to: `g`, `cum`, every `exp`, the inverse
-and the state in float32 (the inverse's products at `Precision.HIGHEST`);
-`D` from the DIFFERENCE of running sums (never a quotient of exponentials:
-a chunk that decays by e^-20 has no inf and no nan in it, forward or
-backward), and `e^cum`, `e^{cum_Q - cum}` of arguments that are never
-positive; the other products' operands in `v`'s dtype with float32
-accumulation; a length that is no multiple of `Q` padded with `g = 0,
-beta = 0` rows, which move no state, and cut off again; the `G` key heads
-read by their `H / G` value heads through an `einsum` index, never copied.
+What comes AFTER the inverse has two paths, and the backend decides between
+them (`attention._pallas_ok`; a shape the kernels do not tile,
+`_kernels_take`, is the other reason for the second):
+
+  on a TPU   two Pallas kernels behind a `jax.custom_vjp` that takes q, k,
+             v, the running sums, beta and the inverse `X`. `gdn_fwd` walks
+             a (batch, key head)'s chunks in order on a grid of (batch, key
+             head, chunk): a step holds the chunk's `q` and `k` [Q, dk], the
+             key head's R value heads of `v` side by side [Q, R dv], `X`
+             [R, Q, Q] float32 and the running sums and beta a position
+             (positions along rows and along lanes, as `ops/ssd.py` hands
+             its sums); makes `D`, `T`, `W`, `U`, `V'` a head and `Q K^T`
+             once in VMEM; carries the R heads' state [dk, R dv] float32 in a
+             VMEM scratch from chunk to chunk; writes `o` and the state at
+             the chunk's START. `gdn_bwd` walks the same grid from the last
+             chunk to the first with the state's gradient in the scratch,
+             makes `D`, `W`, `U`, `V'` again, and writes dq, dk (summed over
+             the R heads inside the step), dv, `dX` [R, Q, Q] float32 (the
+             cotangent the inverse's own rule takes), and the sums a
+             position and a chunk that the running sums' and beta's
+             gradients are made of. `D`, `T`, `W`, `U`, `Q K^T` and `V'`
+             never reach HBM, and no `while` walks the chunks. The running
+             sums are a product with a triangle of ones, not `cumsum` (a
+             `reduce-window` on the chip: PR 54). The forward rule NAMES
+             what the kernel wrote (`RESIDUAL_NAMES`), so the layer's
+             recomputed forward holds no kernel.
+  elsewhere  plain `jax.numpy` (`_rule_xla`): batched `einsum`s and a
+             `lax.scan` across the chunks, gradients by JAX's
+             differentiation of them, `D`, `T` and `Q K^T` written out. The
+             CPU's path, and what the kernels are tested against.
+
+Both are held to what `ops/ssd.py` is held to: `g`, `cum`, every `exp`, the
+inverse and the state in float32 (the inverse's products at
+`Precision.HIGHEST`); `D` from the DIFFERENCE of running sums (never a
+quotient of exponentials: a chunk that decays by e^-20 has no inf and no
+nan in it, forward or backward), and `e^cum`, `e^{cum_Q - cum}` of
+arguments that are never positive; the other products' operands in `v`'s
+dtype with float32 accumulation, rounded at the same places on both paths
+(`T * e^cum`, `T`, `W`, `U`, the state and `V'` where each enters a
+product); a length that is no multiple of `Q` padded with `g = 0, beta = 0`
+rows, which move no state, and cut off again; the `G` key heads read by
+their `H / G` value heads through an index (an `einsum`'s, a block's),
+never copied.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# The forward rule's name for the inverse it returns: the residual that
-# only the series' products could give back (`decay`, `kk` and `a` come
-# back by cheap XLA; `W` and `U` are two products of the kept value).
-RESIDUAL_NAMES = ("gdn_inverse",)
+from oobleck_tpu.ops.flash import LANE, _interpret, _out_struct
+from oobleck_tpu.ops.ssd import _decays, _lower, _nn, _nt, _tn
+
+# The forward rules' names for what only more product or kernel time could
+# give back: the inverse (`decay`, `kk` and `a` come back by cheap XLA), and
+# what `gdn_fwd` wrote, o and the state at every chunk's start.
+RESIDUAL_NAMES = ("gdn_inverse", "gdn_out", "gdn_starts")
 
 
 def _count(chunks: int, layer: str | None) -> None:
@@ -84,6 +122,18 @@ def _count_named_residuals() -> None:
         "oobleck_gdn_residuals_named_total",
         "Forward rules of the delta rule's inverse traced with the inverse "
         "named for the layer's checkpoint").inc()
+
+
+def _count_call(kernel: str) -> None:
+    """`oobleck_gdn_kernel_calls_total{kernel}`: where a kernel is built
+    into a traced program (not once a step). A rule on the `jax.numpy` path
+    counts none."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().counter(
+        "oobleck_gdn_kernel_calls_total",
+        "Pallas kernels of the gated delta rule built into traced "
+        "programs, by kernel (fwd, bwd)").inc(kernel=kernel)
 
 
 def _dot(x: jax.Array, y: jax.Array) -> jax.Array:
@@ -124,6 +174,15 @@ def _inverse_bwd(inverse, d_inverse):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
+def _kernels_take(chunk: int, r: int, dk: int, dv: int) -> bool:
+    """The shapes the kernels tile: a head a lane tile (`dk = dv = 128`: the
+    state of a key head's `r` value heads is [128, r 128]) and a chunk whose
+    [Q, Q] float32 blocks are whole sublane tiles and at most one lane
+    tile."""
+    del r                           # any number of heads side by side
+    return dk == LANE and dv == LANE and chunk % 8 == 0 and chunk <= LANE
+
+
 @jax.named_scope("gdn")
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      beta: jax.Array, *, chunk: int,
@@ -132,6 +191,8 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     and scales); v [B, S, H, dv] with G dividing H (value head h reads key
     head h // (H / G)); g [B, S, H], the log of the decay, never positive;
     beta [B, S, H]. Returns o [B, S, H, dv] in v's dtype."""
+    from oobleck_tpu.ops.attention import _pallas_ok
+
     f32 = jnp.float32
     bsz, seq, heads, dv = v.shape
     groups, dk = k.shape[2], k.shape[3]
@@ -144,27 +205,57 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         rows = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
         q, k, v, g, beta = rows(q), rows(k), rows(v), rows(g), rows(beta)
     dtype = v.dtype
-    qg = q.astype(dtype).reshape(bsz, nc, chunk, groups, dk)
-    kg = k.astype(dtype).reshape(bsz, nc, chunk, groups, dk)
-    vg = v.reshape(bsz, nc, chunk, groups, r, dv)
+    q, k = q.astype(dtype), k.astype(dtype)
+    kernels = _pallas_ok() and _kernels_take(chunk, r, dk, dv)
     # Heads before positions: the [Q, Q] blocks are the minor dimensions.
     per_head = lambda t: jnp.moveaxis(
         t.astype(f32).reshape(bsz, nc, chunk, groups, r), 2, -1)
-    per_row = lambda t: jnp.moveaxis(t, -1, 2)             # [B, nc, Q, G, R]
     beta_h = per_head(beta)                                # [B, nc, G, R, Q]
-    cum = jnp.cumsum(per_head(g), axis=-1)
-    total = cum[..., -1]                                   # [B, nc, G, R]
-    from_start = jnp.exp(cum)           # the decay since the chunk's start
+    cum = (_running_sums if kernels else
+           functools.partial(jnp.cumsum, axis=-1))(per_head(g))
     i = jnp.arange(chunk)
     decay = jnp.exp(jnp.where(i[:, None] >= i[None, :],
                               cum[..., :, None] - cum[..., None, :],
                               -jnp.inf))                   # [B, nc, G, R, Q, Q]
 
     # system: every position's write against the writes before it.
+    kg = k.reshape(bsz, nc, chunk, groups, dk)
     kk = jnp.einsum("bzigd,bzjgd->bzgij", kg, kg, preferred_element_type=f32)
     a = jnp.where(i[:, None] > i[None, :],
                   beta_h[..., :, None] * kk[:, :, :, None] * decay, 0.0)
-    t = unit_lower_inverse(a) * beta_h[..., None, :]
+    inverse = unit_lower_inverse(a)
+    if kernels:
+        return _rule_kernels(q, k, v, cum, beta_h, inverse)[:, :seq]
+    return _rule_xla(q, k, v, cum, beta_h, inverse, decay)[:, :seq]
+
+
+def _running_sums(t):
+    """The running sum along the last axis (a chunk's positions) of a
+    float32 [..., Q]: a product with the [Q, Q] triangle of ones at
+    float32's own precision, as `ops/ssd._running_sums` (XLA's `cumsum` of
+    such a shape is a `reduce_window`, slower than both kernels: PR 54)."""
+    ones = _lower(t.shape[-1]).astype(jnp.float32)
+    return jnp.einsum("ij,...j->...i", ones, t, precision=lax.Precision.HIGHEST)
+
+
+# --------------------------------------------------------------------- #
+# off the chip: jax.numpy                                                #
+# --------------------------------------------------------------------- #
+
+def _rule_xla(q, k, v, cum, beta_h, inverse, decay):
+    """Whole chunks; q and k in v's dtype; cum, beta_h [B, nc, G, R, Q] and
+    inverse, decay [B, nc, G, R, Q, Q] float32."""
+    f32 = jnp.float32
+    dtype = v.dtype
+    bsz, nc, groups, r, chunk = cum.shape
+    dk, dv = k.shape[-1], v.shape[-1]
+    qg = q.reshape(bsz, nc, chunk, groups, dk)
+    kg = k.reshape(bsz, nc, chunk, groups, dk)
+    vg = v.reshape(bsz, nc, chunk, groups, r, dv)
+    per_row = lambda t: jnp.moveaxis(t, -1, 2)             # [B, nc, Q, G, R]
+    total = cum[..., -1]                                   # [B, nc, G, R]
+    from_start = jnp.exp(cum)           # the decay since the chunk's start
+    t = inverse * beta_h[..., None, :]
     w = jnp.einsum("bzgrij,bzjgd->bzgrid",
                    (t * from_start[..., None, :]).astype(dtype), kg,
                    preferred_element_type=f32).astype(dtype)
@@ -201,4 +292,274 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     o = o + per_row(from_start)[..., None] * jnp.einsum(
         "bzigd,bzgrdv->bzigrv", qg, starts.astype(dtype),
         preferred_element_type=f32)
-    return o.reshape(bsz, nc * chunk, heads, dv)[:, :seq].astype(dtype)
+    return o.reshape(v.shape).astype(dtype)
+
+
+# --------------------------------------------------------------------- #
+# on the chip: two kernels                                               #
+# --------------------------------------------------------------------- #
+#
+# A step's blocks, for batch row `i`, key head `g`, chunk `z` (R value heads
+# of width dv a key head, side by side on R dv lanes):
+#
+#   q, k, dq, dk               [Q, dk]     of [B, S, G dk]
+#   v, o, dO, dv               [Q, R dv]   of [B, S, H dv]
+#   X, dX                      [R, Q, Q]   of [B, nc, G, R, Q, Q]   float32
+#   cum, positions on rows     [Q, R]      of [B, nc, G, Q, R]      ("cols")
+#   cum then beta, on lanes    [2 R, Q]    of [B, nc, G, 2 R, Q]    ("rows")
+#   the state at z's start     [dk, R dv]  of [B, nc, G, dk, R dv]  float32
+#
+# Inside the bodies `lax.select`, never `jnp.where`, and no `//` or `%` in
+# an index map: `ops/ssd.py` says why (a jitted helper's source location in
+# the compile cache's key).
+
+def _head(k_ref, v_ref, x_ref, col_ref, row_ref, start, h: int, *, r: int,
+          dv: int):
+    """What both kernels make of value head `h` of a chunk, in VMEM: its
+    running sums' exponentials, `D`, `T`, `T * e^cum` (float32), `W`, `U`
+    (v's dtype) and `V'` (float32), given the chunk-start state `start`
+    [dk, R dv] in v's dtype."""
+    f32 = jnp.float32
+    dtype = v_ref.dtype
+    qn = k_ref.shape[0]
+    lanes = slice(h * dv, (h + 1) * dv)
+    cum = col_ref[:, h:h + 1]                                   # [Q, 1]
+    since_row = jnp.exp(row_ref[h:h + 1, :])                    # [1, Q]
+    total = cum[qn - 1:qn, :]                                   # [1, 1]
+    t = x_ref[h] * row_ref[r + h:r + h + 1, :]                  # X diag(beta)
+    tw = t * since_row
+    w = _nn(tw.astype(dtype), k_ref[...]).astype(dtype)         # [Q, dk]
+    u = _nn(t.astype(dtype), v_ref[:, lanes]).astype(dtype)     # [Q, dv]
+    wrote = u.astype(f32) - _nn(w, start[:, lanes])             # V'
+    return dict(lanes=lanes, since=jnp.exp(cum), to_end=jnp.exp(total - cum),
+                whole=jnp.exp(jnp.broadcast_to(total, (1, dv))),
+                since_row=since_row,
+                decay=_decays(col_ref, row_ref, h, _lower(qn)),
+                t=t, tw=tw, w=w, wrote=wrote)
+
+
+def _fwd_kernel(z, q_ref, k_ref, v_ref, x_ref, col_ref, row_ref,
+                o_ref, start_ref, state, *, r: int, dv: int):
+    dtype = v_ref.dtype
+
+    @pl.when(z == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    start_ref[...] = state[...]
+    qm, km = q_ref[...], k_ref[...]
+    start = state[...].astype(dtype)
+    qk = _nt(qm, km)                                            # [Q, Q]
+    o_off = _nn(qm, start)                                      # Q S
+    for h in range(r):
+        c = _head(k_ref, v_ref, x_ref, col_ref, row_ref, start, h, r=r, dv=dv)
+        lanes, wrote = c["lanes"], c["wrote"]
+        o = _nn((qk * c["decay"]).astype(dtype), wrote.astype(dtype))
+        o_ref[:, lanes] = (o + c["since"] * o_off[:, lanes]).astype(o_ref.dtype)
+        state[:, lanes] = c["whole"] * state[:, lanes] + _tn(
+            km, (wrote * c["to_end"]).astype(dtype))
+
+
+def _bwd_kernel(z, q_ref, k_ref, v_ref, x_ref, col_ref, row_ref, start_ref,
+                do_ref, dq_ref, dk_ref, dv_ref, dx_ref, dcol_ref, drow_ref,
+                whole_ref, dstate, *, r: int, dv: int):
+    """One chunk of the reverse walk: `dstate` comes in as the gradient of
+    the state this chunk ENDS in and leaves as that of the state it starts
+    from. Beside dq, dk, dv and dX it writes what the [B, S, H]-sized rest
+    outside needs and no more: a position's part of the running sums'
+    gradient, what reads the position's sum along rows (`dcol_ref`) and
+    along lanes (`drow_ref`, then beta's gradient, which is `T`'s against
+    `X` summed over rows), and the chunk total's gradient summed over the
+    chunk's rows only (`whole_ref`: `e^total <S, dS_end>` and, from the
+    very products the positions took theirs from, what they gave the
+    state: the two cancel at the last position)."""
+    f32 = jnp.float32
+    dtype = v_ref.dtype
+    qn = q_ref.shape[0]
+
+    @pl.when(z == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    qm, km = q_ref[...], k_ref[...]
+    state = start_ref[...]
+    start = state.astype(dtype)
+    d_end = dstate[...]
+    d_end_low = d_end.astype(dtype)
+    qk = _nt(qm, km)
+    o_off = _nn(qm, start)                                      # Q S
+    d_to_state = _nn(km, d_end_low)                             # K dS_end
+    dqk = jnp.zeros((qn, qn), f32)
+    dq = jnp.zeros(dq_ref.shape, f32)
+    dk = jnp.zeros(dk_ref.shape, f32)
+    for h in range(r):
+        c = _head(k_ref, v_ref, x_ref, col_ref, row_ref, start, h, r=r, dv=dv)
+        lanes, wrote, decay = c["lanes"], c["wrote"], c["decay"]
+        since, to_end, since_row = c["since"], c["to_end"], c["since_row"]
+        do = do_ref[:, lanes]
+        dof = do.astype(f32)
+        # out: O = (Q K^T * D) V' + e^cum * (Q S)
+        p = qk * decay
+        dp = _nt(do, wrote.astype(dtype))                       # [Q, Q]
+        do_since = (dof * since).astype(dtype)
+        dq = dq + _nt(do_since, start[:, lanes])
+        dqk = dqk + dp * decay
+        # inter: S_end = e^total S + K^T (V' * e^{total - cum})
+        added = to_end * d_to_state[:, lanes]
+        dk = dk + _nt((wrote * to_end).astype(dtype), d_end_low[:, lanes])
+        d_wrote = (_tn(p.astype(dtype), do) + added).astype(dtype)
+        # V' = U - W S;  U = T V;  W = (T * e^cum) K
+        dw = (-_nt(d_wrote, start[:, lanes])).astype(dtype)     # [Q, dk]
+        dtw = _nt(dw, km)                                       # [Q, Q]
+        dt = _nt(d_wrote, v_ref[:, lanes]) + dtw * since_row
+        dv_ref[:, lanes] = _tn(c["t"].astype(dtype), d_wrote).astype(
+            dv_ref.dtype)
+        dk = dk + _tn(c["tw"].astype(dtype), dw)
+        dx_ref[h] = dt * row_ref[r + h:r + h + 1, :]
+        dstate[:, lanes] = (c["whole"] * d_end[:, lanes]
+                            + _tn(qm, do_since) - _tn(c["w"], d_wrote))
+        # the running sums' gradient and beta's
+        of_decay = dp * p
+        dcol_ref[:, h:h + 1] = (
+            jnp.sum(of_decay, axis=1, keepdims=True)
+            + jnp.sum(dof * (since * o_off[:, lanes]) - added * wrote,
+                      axis=1, keepdims=True))
+        drow_ref[h:h + 1, :] = jnp.sum(dtw * c["tw"] - of_decay, axis=0,
+                                       keepdims=True)
+        drow_ref[r + h:r + h + 1, :] = jnp.sum(dt * x_ref[h], axis=0,
+                                               keepdims=True)
+        whole_ref[:, lanes] = (
+            c["whole"] * jnp.sum(state[:, lanes] * d_end[:, lanes], axis=0,
+                                 keepdims=True)
+            + jnp.sum(wrote * added, axis=0, keepdims=True))
+    dqk = dqk.astype(dtype)
+    dq_ref[...] = (dq + _nn(dqk, km)).astype(dq_ref.dtype)
+    dk_ref[...] = (dk + _tn(dqk, qm)).astype(dk_ref.dtype)
+
+
+def _call(body, kernel: str, operands, in_specs, out_shape, out_specs, *,
+          grid, state_shape, r: int, dv: int):
+    """One `pallas_call` on the grid (batch, key head, chunk), the chunk
+    axis sequential, with one float32 scratch that lives across it. Under
+    the interpreter the step runs inside a branch that is always taken, for
+    the `check_vma=True` shard_maps (`ops/flash._call`'s docstring)."""
+    interpret = _interpret()
+    _count_call(kernel)
+
+    def step(*refs):
+        z = pl.program_id(2)
+        chunk = functools.partial(body, z, *refs, r=r, dv=dv)
+        if interpret:
+            pl.when(z >= 0)(chunk)
+        else:
+            chunk()
+
+    return pl.pallas_call(
+        step,
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=grid,
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM(state_shape, jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=f"gdn_{kernel}",
+    )(*operands)
+
+
+def _operands(q, k, v, cum, beta_h, inverse, reverse: bool):
+    """What both kernels read, with its block specs. Returns (operands,
+    in_specs, narrow, wide, of_chunk), the last three the specs of a
+    [Q, dk] block, a [Q, R dv] block and a block a (batch, chunk, key
+    head). `reverse` walks the chunks from the last to the first."""
+    bsz, nc, groups, r, chunk = cum.shape
+    dk, dv = k.shape[-1], v.shape[-1]
+    cols = jnp.swapaxes(cum, -1, -2)                       # [B, nc, G, Q, R]
+    rows = jnp.concatenate([cum, beta_h], axis=-2)         # [B, nc, G, 2R, Q]
+    flat = lambda t: t.reshape(bsz, nc * chunk, -1)
+
+    at = (lambda z: nc - 1 - z) if reverse else (lambda z: z)
+    in_chunk = lambda i, g, z: (i, at(z), g)
+    narrow = pl.BlockSpec((None, chunk, dk), in_chunk)
+    wide = pl.BlockSpec((None, chunk, r * dv), in_chunk)
+    of_chunk = lambda *block: pl.BlockSpec(
+        (None, None, None, *block),
+        lambda i, g, z: (*in_chunk(i, g, z), *(0,) * len(block)))
+    return ((flat(q), flat(k), flat(v), inverse, cols, rows),
+            [narrow, narrow, wide, of_chunk(r, chunk, chunk),
+             of_chunk(chunk, r), of_chunk(2 * r, chunk)],
+            narrow, wide, of_chunk)
+
+
+def _forward(q, k, v, cum, beta_h, inverse):
+    bsz, nc, groups, r, chunk = cum.shape
+    dk, dv = k.shape[-1], v.shape[-1]
+    operands, in_specs, _, wide, of_chunk = _operands(
+        q, k, v, cum, beta_h, inverse, reverse=False)
+    o, starts = _call(
+        _fwd_kernel, "fwd", operands, in_specs,
+        (_out_struct(operands[2].shape, v.dtype, *operands),
+         _out_struct((bsz, nc, groups, dk, r * dv), jnp.float32, *operands)),
+        (wide, of_chunk(dk, r * dv)),
+        grid=(bsz, groups, nc), state_shape=(dk, r * dv), r=r, dv=dv)
+    return o.reshape(v.shape), starts
+
+
+def _backward(q, k, v, cum, beta_h, inverse, starts, do):
+    f32 = jnp.float32
+    bsz, nc, groups, r, chunk = cum.shape
+    dk, dv = k.shape[-1], v.shape[-1]
+    operands, in_specs, narrow, wide, of_chunk = _operands(
+        q, k, v, cum, beta_h, inverse, reverse=True)
+    qf, kf, vf = operands[:3]
+    operands = (*operands, starts, do.astype(v.dtype).reshape(vf.shape))
+    dq, dk_, dv_, dx, dcol, drow, whole = _call(
+        _bwd_kernel, "bwd", operands,
+        [*in_specs, of_chunk(dk, r * dv), wide],
+        (_out_struct(qf.shape, q.dtype, *operands),
+         _out_struct(kf.shape, k.dtype, *operands),
+         _out_struct(vf.shape, v.dtype, *operands),
+         _out_struct(inverse.shape, f32, *operands),
+         _out_struct((bsz, nc, groups, chunk, r), f32, *operands),
+         _out_struct((bsz, nc, groups, 2 * r, chunk), f32, *operands),
+         _out_struct((bsz, nc, groups, 1, r * dv), f32, *operands)),
+        (narrow, narrow, wide, of_chunk(r, chunk, chunk), of_chunk(chunk, r),
+         of_chunk(2 * r, chunk), of_chunk(1, r * dv)),
+        grid=(bsz, groups, nc), state_shape=(dk, r * dv), r=r, dv=dv)
+
+    # The [B, S, H]-sized rest. A chunk's total is its last running sum.
+    whole = jnp.sum(whole.reshape(bsz, nc, groups, r, dv), axis=-1)
+    dcum = (jnp.swapaxes(dcol, -1, -2) + drow[..., :r, :]
+            ).at[..., -1].add(whole)
+    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
+            dcum, drow[..., r:, :], dx)
+
+
+@jax.custom_vjp
+def _rule_kernels(q, k, v, cum, beta_h, inverse):
+    """Whole chunks; q and k in v's dtype; cum, beta_h [B, nc, G, R, Q] and
+    inverse [B, nc, G, R, Q, Q] float32."""
+    return _forward(q, k, v, cum, beta_h, inverse)[0]
+
+
+def _rule_fwd(q, k, v, cum, beta_h, inverse):
+    o, starts = _forward(q, k, v, cum, beta_h, inverse)
+    # All that the kernel wrote goes by a name, so that a layer's checkpoint
+    # keeps it and the recomputed forward holds no kernel. The operands are
+    # not named: q, k, v, the running sums and beta come back from the
+    # layer's input by XLA, the inverse by its own name.
+    o = checkpoint_name(o, RESIDUAL_NAMES[1])
+    starts = checkpoint_name(starts, RESIDUAL_NAMES[2])
+    return o, (q, k, v, cum, beta_h, inverse, starts)
+
+
+def _rule_bwd(res, do):
+    # The rule is traced where the program is transposed, outside
+    # `gated_delta_rule`'s scope: under it again, the kernel is
+    # `%gdn_bwd.N` and a reader of the scope finds the whole backward.
+    with jax.named_scope("gdn"):
+        return _backward(*res, do)
+
+
+_rule_kernels.defvjp(_rule_fwd, _rule_bwd)

@@ -14,7 +14,9 @@ from oobleck_tpu.ops import flash, gdn, ssd
 
 KEPT = (
     *flash.RESIDUAL_NAMES,   # what the flash forward kernel wrote: O, LSE
-    *gdn.RESIDUAL_NAMES,     # the delta rule's inverse
+    *gdn.RESIDUAL_NAMES,     # the delta rule's inverse, and what its forward
+                             # kernel wrote: o, the state at every chunk's
+                             # start
     *ssd.RESIDUAL_NAMES,     # what the scan's forward kernel wrote: y, the
                              # state at every chunk's start
 )

@@ -11,6 +11,14 @@ from a difference of running sums); beta at 0 (nothing is written) and at 1
 product; bfloat16 operands; what the rule counts where it is built; and what
 a layer's checkpoint keeps of it: the inverse by its name, so that the
 recomputed forward holds no series.
+
+Those tests run the `jax.numpy` path, the CPU's. The second half of the
+file runs the TPU's path, the two Pallas kernels (`gdn_fwd`, `gdn_bwd`)
+that take everything after the inverse, under the interpreter and against
+that `jax.numpy` path at the widths the kernels tile (chunks of 64, heads
+of 128, one and two value heads a key head): the forward and each of the
+five gradients, the same decays, padding and float32 claims, which shapes
+take which path and what the kernels' counter then says.
 """
 
 import functools
@@ -22,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oobleck_tpu.ops import flash, gdn, remat
+from oobleck_tpu.ops import attention, flash, gdn, remat
 from oobleck_tpu.ops.gdn import gated_delta_rule, unit_lower_inverse
 from tests.ops.programs import all_eqns, checkpoint_keeping
 
@@ -318,3 +326,280 @@ def test_a_named_inverse_is_counted_once_a_forward_rule_traced():
     # A rule that is not differentiated runs no forward rule.
     jax.jit(lambda *a: gated_delta_rule(*a, chunk=16, layer="5"))(*args)
     assert named.value() - before == 1
+
+
+# --------------------------------------------------------------------- #
+# the kernels, interpreted, against the jax.numpy path                   #
+# --------------------------------------------------------------------- #
+
+# (batch, length, value heads, key heads) at chunk 64, heads of 128.
+KERNEL_CASES = {
+    "two_chunks_two_heads_a_key_head": (1, 128, 4, 2),
+    "three_chunks_a_head_a_key_head": (2, 192, 2, 2),
+    "ragged_tail": (1, 150, 2, 1),
+}
+KQ, KD = 64, 128
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """`gated_delta_rule` takes the kernels' path as on a TPU,
+    interpreted."""
+    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
+    monkeypatch.setattr(gdn, "_interpret", lambda: True)
+
+
+def kernel_operands(case, *, seed=0, dtype=jnp.float32):
+    bsz, length, heads, groups = KERNEL_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return ((unit(jax.random.normal(ks[0], (bsz, length, groups, KD)))
+             * KD ** -0.5).astype(dtype),
+            unit(jax.random.normal(ks[1], (bsz, length, groups, KD))).astype(
+                dtype),
+            jax.random.normal(ks[2], (bsz, length, heads, KD)).astype(dtype),
+            -jax.nn.softplus(jax.random.normal(ks[3], (bsz, length, heads))),
+            jax.nn.sigmoid(jax.random.normal(ks[4], (bsz, length, heads))))
+
+
+def numpy_path(*args, chunk=KQ):
+    """`gated_delta_rule` as the CPU runs it, whatever the fixture says."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "_pallas_ok", lambda: False)
+        return gated_delta_rule(*args, chunk=chunk)
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_forward_kernel_is_the_numpy_path(kernels, case):
+    args = kernel_operands(case)
+    got = rule(*args, chunk=KQ)
+    want = jax.jit(numpy_path)(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+
+
+@functools.cache
+def _kernel_gradients(case):
+    """All five gradients of a case, through the kernels and through the
+    `jax.numpy` path: computed once, compared one operand a test. (Called
+    under the `kernels` fixture only.)"""
+    args = kernel_operands(case, seed=1)
+    target = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    got = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ) * target),
+        argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(numpy_path(*a) * target),
+                            argnums=range(5)))(*args)
+    return got, want
+
+
+@pytest.mark.parametrize("wrt", range(5), ids=ARGS)
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_every_gradient_of_the_kernels_is_the_numpy_paths(kernels, case, wrt):
+    got, want = (g[wrt] for g in _kernel_gradients(case))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5 * max(scale, 1.0), rtol=2e-4)
+
+
+@pytest.mark.parametrize("decay_a_chunk", [20.0, 2000.0],
+                         ids=["e-20", "e-2000"])
+def test_both_kernels_have_no_inf_and_no_nan_in_a_chunk_that_decays_to_nothing(
+        kernels, decay_a_chunk):
+    q, k, v, g, beta = kernel_operands("two_chunks_two_heads_a_key_head")
+    args = (q, k, v, jnp.full_like(g, -decay_a_chunk / KQ), beta)
+    o = rule(*args, chunk=KQ)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(np.asarray(o), np.asarray(numpy_path(*args)),
+                               atol=2e-5, rtol=2e-4)
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ)),
+        argnums=range(5)))(*args)
+    assert all(np.isfinite(np.asarray(x)).all() for x in grads)
+
+
+def test_padding_rows_move_no_state_through_the_kernels(kernels):
+    args = kernel_operands("ragged_tail")
+    whole = rule(*args, chunk=KQ)
+    cut = rule(*(a[:, :128] for a in args), chunk=KQ)
+    np.testing.assert_allclose(np.asarray(whole[:, :128]), np.asarray(cut),
+                               atol=1e-6)
+    # ... nor take a gradient: the tail's rows past the length are not there.
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ)),
+        argnums=range(5)))(*args)
+    assert all(g.shape == a.shape for g, a in zip(grads, args))
+
+
+def _kernel_calls(fn, *args):
+    return [e for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def test_the_kernels_keep_running_sums_inverse_and_state_in_float32(kernels):
+    bf = lambda t: t.astype(jnp.bfloat16)
+    f32 = lambda t: bf(t).astype(jnp.float32)
+    q, k, v, g, beta = kernel_operands("two_chunks_two_heads_a_key_head")
+    args = (bf(q), bf(k), bf(v), g, beta)
+    got = rule(*args, chunk=KQ)
+    assert got.dtype == jnp.bfloat16
+    want = recurrence(f32(q), f32(k), f32(v), g, beta)
+    err = np.abs(np.asarray(got, np.float32) - np.asarray(want))
+    assert err.max() < 0.05 * np.abs(np.asarray(want)).max()
+    grad = jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ).astype(jnp.float32)),
+        argnums=range(5))
+    fwd, bwd = _kernel_calls(grad, *args)
+    assert [e.params["name"] for e in (fwd, bwd)] == ["gdn_fwd", "gdn_bwd"]
+    for call in (fwd, bwd):
+        dtypes = [v.aval.dtype for v in call.invars]
+        # q, k, v in the operands' dtype; the inverse, the running sums and
+        # beta (twice) and the states float32; dO, last, in v's dtype.
+        assert dtypes[:3] == [jnp.bfloat16] * 3
+        assert set(dtypes[3:7]) == {jnp.dtype(jnp.float32)}
+        assert dtypes[7:] == ([jnp.bfloat16] if call is bwd else [])
+        # Every exp inside the kernel reads float32, and so does the carried
+        # state (the one scratch).
+        inner = call.params["jaxpr"]
+        exps = [e for e in all_eqns(inner) if e.primitive.name == "exp"]
+        assert exps and all(e.invars[0].aval.dtype == jnp.float32 for e in exps)
+        assert inner.invars[-1].aval.dtype == jnp.float32
+    # The states the forward wrote for the backward, and the inverse's
+    # cotangent: float32 too.
+    assert fwd.outvars[1].aval.dtype == jnp.float32
+    assert fwd.outvars[1].aval.shape == (1, 2, 2, KD, 2 * KD)
+    assert bwd.outvars[3].aval.dtype == jnp.float32
+    assert bwd.outvars[3].aval.shape == (1, 2, 2, 2, KQ, KQ)
+    # The series is the program's as before: ten float32 products at
+    # HIGHEST, outside both kernels.
+    outside = [e for e in all_eqns(jax.make_jaxpr(
+        lambda *a: gated_delta_rule(*a, chunk=KQ))(*args).jaxpr)
+        if e.primitive.name == "dot_general"
+        and e.params["precision"] is not None
+        and e.invars[0].aval.shape[-2:] == (KQ, KQ) == e.invars[1].aval.shape[-2:]]
+    assert len(outside) == 2 * (KQ.bit_length() - 2)
+    assert all(v.aval.dtype == jnp.float32 for e in outside for v in e.invars)
+
+
+def test_the_kernels_bodies_call_no_jitted_helper(kernels):
+    """As `ops/ssd.py`'s (`tests/ops/test_ssd.py`): a jitted helper in a
+    body or in a block's index map carries the source location of its
+    first trace in the process into the compile cache's key."""
+    grad = jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ)),
+                    argnums=range(5))
+    fwd, bwd = _kernel_calls(
+        grad, *kernel_operands("two_chunks_two_heads_a_key_head"))
+    for call in (fwd, bwd):
+        maps = [m.index_map_jaxpr.jaxpr
+                for m in call.params["grid_mapping"].block_mappings]
+        assert len(maps) == len(call.invars) + len(call.outvars)
+        for jaxpr in (call.params["jaxpr"], *maps):
+            inner = {e.primitive.name for e in all_eqns(jaxpr)}
+            assert not inner & {"jit", "pjit", "closed_call", "core_call"}, (
+                call.params["name"], sorted(inner))
+
+
+def test_a_key_head_is_read_through_the_block_index_never_copied(kernels):
+    args = kernel_operands("two_chunks_two_heads_a_key_head")
+    fwd, = _kernel_calls(lambda *a: gated_delta_rule(*a, chunk=KQ), *args)
+    # q and k go in as [B, S, G dk]: one key head for its two value heads.
+    assert [v.aval.shape for v in fwd.invars[:2]] == [(1, 128, 2 * KD)] * 2
+    assert fwd.invars[2].aval.shape == (1, 128, 4 * KD)
+
+
+def test_the_kernels_walk_no_loop(kernels):
+    """The walk across the chunks is the kernels' grid: the differentiated
+    program holds no `scan`, where the `jax.numpy` path's holds the
+    forward's and the backward's."""
+    args = kernel_operands("two_chunks_two_heads_a_key_head")
+    grad = jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ)),
+                    argnums=range(5))
+    kinds = lambda fn: [e.primitive.name
+                        for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)]
+    assert "scan" not in kinds(grad) and "while" not in kinds(grad)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "_pallas_ok", lambda: False)
+        assert kinds(jax.grad(
+            lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ)),
+            argnums=range(5))).count("scan") == 2
+
+
+# (chunk, value heads, key heads, dk, dv) the kernels do not tile: a key
+# width of 64, a value width of 64, a chunk of 256 (its [Q, Q] float32 blocks
+# are more than a lane tile wide), a chunk of 12 (no whole sublane tile).
+NOT_TAKEN = {"keys_of_64": (64, 4, 2, 64, 128),
+             "values_of_64": (64, 4, 2, 128, 64),
+             "chunk_256": (256, 4, 2, 128, 128),
+             "chunk_12": (12, 4, 2, 128, 128)}
+TAKEN = {"taken": (64, 4, 2, 128, 128), "taken_one_head": (64, 2, 2, 128, 128),
+         "taken_chunk_128": (128, 2, 1, 128, 128)}
+
+
+@pytest.mark.parametrize("shape", [*sorted(TAKEN), *sorted(NOT_TAKEN)])
+def test_the_counter_says_which_path_a_rule_took(monkeypatch, shape):
+    """On a TPU (`_pallas_ok`): one `fwd` and one `bwd` a rule built where
+    the kernels tile the shape, none where they do not; on the CPU none.
+    Traced only: nothing runs."""
+    from oobleck_tpu.utils import metrics
+
+    reg = metrics.registry()
+    scans = reg.counter("oobleck_gdn_scans_total")
+    named = reg.counter("oobleck_gdn_residuals_named_total")
+    calls = reg.counter("oobleck_gdn_kernel_calls_total")
+    chunk, heads, groups, dk, dv = {**TAKEN, **NOT_TAKEN}[shape]
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    args = (jax.random.normal(k[0], (1, 256, groups, dk)),
+            jax.random.normal(k[1], (1, 256, groups, dk)),
+            jax.random.normal(k[2], (1, 256, heads, dv)),
+            -jnp.ones((1, 256, heads)), jnp.ones((1, 256, heads)) / 2)
+    read = lambda: (scans.value(), named.value(), calls.value(kernel="fwd"),
+                    calls.value(kernel="bwd"))
+
+    def built(on_tpu):
+        monkeypatch.setattr(attention, "_pallas_ok", lambda: on_tpu)
+        before = read()
+        # A function of its own a trace: an equal one would be a cache hit.
+        found = _kernel_calls(jax.grad(
+            lambda *a: jnp.sum(gated_delta_rule(*a, chunk=chunk)),
+            argnums=1), *args)
+        return (tuple(b - a for a, b in zip(before, read())),
+                sorted(e.params["name"] for e in found))
+
+    assert built(on_tpu=False) == ((1, 1, 0, 0), [])
+    took = shape in TAKEN
+    assert built(on_tpu=True) == (
+        (1, 1, int(took), int(took)), ["gdn_bwd", "gdn_fwd"] if took else [])
+
+
+def _kernel_layer_gradients(wrap):
+    layer = wrap(functools.partial(gated_delta_rule, chunk=KQ))
+    return jax.grad(lambda *a: jnp.sum(layer(*a) ** 2), argnums=range(5))
+
+
+@pytest.mark.parametrize("wrap,fwd_calls", [
+    (jax.checkpoint, 2), (remat.checkpoint_layer, 1),
+    (checkpoint_keeping(*gdn.RESIDUAL_NAMES), 1),
+    (checkpoint_keeping(gdn.RESIDUAL_NAMES[0]), 2),
+], ids=["bare", "the_layers_checkpoint", "the_rules_names_alone",
+        "the_inverse_s_name_alone"])
+def test_a_checkpoint_that_keeps_what_the_forward_kernel_wrote_recomputes_none(
+        kernels, wrap, fwd_calls):
+    args = kernel_operands("two_chunks_two_heads_a_key_head")
+    names = [e.params["name"] for e in
+             _kernel_calls(_kernel_layer_gradients(wrap), *args)]
+    assert names.count("gdn_fwd") == fwd_calls
+    assert names.count("gdn_bwd") == 1
+
+
+@functools.cache
+def _kept_and_bare_kernel_gradients():
+    args = kernel_operands("two_chunks_two_heads_a_key_head", seed=2)
+    return (jax.jit(_kernel_layer_gradients(remat.checkpoint_layer))(*args),
+            jax.jit(_kernel_layer_gradients(jax.checkpoint))(*args))
+
+
+@pytest.mark.parametrize("wrt", range(5), ids=ARGS)
+def test_what_the_forward_kernel_wrote_is_what_a_second_call_would_write(
+        kernels, wrt):
+    got, want = (g[wrt] for g in _kept_and_bare_kernel_gradients())
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

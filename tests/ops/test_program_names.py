@@ -73,3 +73,32 @@ def test_routing_probe_program_carries_its_name():
 
     model = build_model("lfm2-moe-tiny", {})
     assert lfm2._probe_program(model).__name__ == "routing_probe_forward"
+
+
+
+def test_the_delta_rule_s_kernels_carry_their_names(monkeypatch):
+    """`name=` on each `pallas_call` is what the chip's compiler names the
+    custom call after (`%gdn_fwd.N`, `%gdn_bwd.N`) and what the benchmark's
+    kernel metrics match (`benchmarks/layer_metrics/gdn_fwd_ms.json`,
+    `gdn_bwd_ms.json`); both are built under the scope `gdn`, which
+    `gdn_rule_ms` reads. Traced only: nothing runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from oobleck_tpu.ops import attention
+    from oobleck_tpu.ops.gdn import gated_delta_rule
+    from tests.ops.programs import all_eqns
+
+    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=64)), argnums=(0, 1)))(
+        shape(1, 128, 1, 128), shape(1, 128, 1, 128), shape(1, 128, 2, 128),
+        shape(1, 128, 2), shape(1, 128, 2))
+    calls = [e for e in all_eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["gdn_fwd", "gdn_bwd"]
+    for e in calls:
+        scopes = [getattr(part, "name", None)
+                  for part in e.source_info.name_stack.stack]
+        assert scopes[-2:] == ["gdn", e.params["name"]], scopes

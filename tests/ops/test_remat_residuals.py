@@ -3,9 +3,10 @@ of the gated delta rule (`ops/gdn.py`) and of the Mamba-2 scan
 (`ops/ssd.py`).
 
 `_flash_fwd` names the two things the forward kernel wrote, O and the row
-logsumexp, `ops/gdn._inverse_fwd` the inverse its series gave,
-`ops/ssd._scan_fwd` the two things ITS forward kernel wrote, y and the
-state at every chunk's start, and
+logsumexp, `ops/gdn._inverse_fwd` the inverse its series gave and
+`ops/gdn._rule_fwd` the two things the rule's forward kernel wrote, o and
+the state at every chunk's start, `ops/ssd._scan_fwd` the two things ITS
+forward kernel wrote, y and the state at every chunk's start, and
 `checkpoint_layer` is `jax.checkpoint` with the policy that keeps values
 by those names. So the forward a backward pass recomputes holds no kernel
 call and no series: their only consumers are the kept values, and neither
@@ -47,6 +48,7 @@ def _calls(names, layers):
 
 
 SCAN = ("ssd_fwd", "ssd_bwd")       # `ops/ssd.py`'s two kernels
+RULE = ("gdn_fwd", "gdn_bwd")       # `ops/gdn.py`'s two kernels
 
 
 # cell -> (microbatch, sequence), its attention's kernels with how many
@@ -71,8 +73,14 @@ CELLS = {
     # beside the delta rule's float32 [64, 64] blocks. One attention layer
     # of four, at heads of 256. 3,732,470,272 since the layers' checkpoint
     # keeps the rule's inverse (PR 44): 100.7 MB kept, and the recompute
-    # holds no power and no partial product of the series.
-    "qwen3-next-80b-a3b": ((1, 4096), _calls(flash.PLAIN, 1), 4.6e9),
+    # holds no power and no partial product of the series. 2,677,982,720
+    # since what comes after the inverse is two kernels (PR 59): o (33.5 MB)
+    # and the 64 chunk-start states (134 MB float32) of each layer are the
+    # program's to hold across a microbatch's backward, where `D`, `T`,
+    # `Q K^T` (134 MB each a pass), `W`, `U` and `V'` were temporaries. One
+    # `gdn_fwd` and one `gdn_bwd` a layer: the recomputed forward holds none.
+    "qwen3-next-80b-a3b": ((1, 4096), {**_calls(flash.PLAIN, 1),
+                                       **_calls(RULE, 3)}, 3.3e9),
     # 5,650,993,664 when the cell went in (PR 45), at ONE sequence of 16384:
     # the dropless buffers of 16384 x 6 + 8 x 1024 rows (545 MB each at 2560
     # bfloat16 columns) beside the head's float32 logits. One full-attention
@@ -169,6 +177,8 @@ def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
         _the_experts_sums_are_the_kernels(cell, text)
     if set(SCAN) <= set(kernels):
         _the_scan_is_its_kernels(text)
+    if set(RULE) <= set(kernels):
+        _the_rule_is_its_kernels_and_its_inverse(text)
     inverse = re.findall(
         r'= f32\[[\d,]*64,64\]\S* convolution\([^\n]*op_name="[^"]*/gdn/'
         r'gdn_inverse/dot_general"', text)
@@ -186,6 +196,31 @@ def _the_scan_is_its_kernels(text):
     assert not [shape for shape, _ in built
                 if re.match(r"f32\[[\d,]*128,128\]", shape)]
     assert "while" not in {kind for _, kind in built}
+
+
+def _the_rule_is_its_kernels_and_its_inverse(text):
+    """Of what the program built under the scope `gdn`: no loop (the walk
+    across the 64 chunks is the kernels' grid), and of the [Q, Q] float32
+    blocks a head and chunk only those outside the kernels' part: what
+    builds `A` (`K K^T`, its decay block, `A`, their gradients: the rule's
+    `einsum`, into whose fusions the compiler takes the elementwise rest),
+    the inverse's own rule, and `X` in and `dX` out of the kernels (tuple
+    elements, no operation of their own). `W`, `U` and `V'` ([..., 64, 128]
+    a value head) are no arrays of it."""
+    built = re.findall(
+        r'^\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\([^\n]*op_name="'
+        r'([^"]*[/(]gdn[/)][^"]*)"', text, re.M)
+    assert len(built) > 2 * 3
+    assert "while" not in {kind for _, kind, _ in built}
+    blocks = [(kind, name) for shape, kind, name in built
+              if re.match(r"f32\[[\d,]*64,64\]", shape)
+              and kind in ("fusion", "convolution")]
+    assert blocks
+    for kind, name in blocks:
+        assert ("/gdn_inverse/" in name
+                or "bzigd,bzjgd->bzgij" in name), (kind, name)
+    assert not [shape for shape, _, _ in built
+                if re.match(r"(?:bf16|f32)\[[\d,]*16,2,64,128\]", shape)]
 
 
 # Microbatch gradients accumulate inside each chunk's backward program: the

@@ -598,6 +598,67 @@ def test_ssd_compiles_inside_check_vma_shard_map_under_the_layers_checkpoint(
         assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 1
 
 
+# The gated delta rule's two kernels (`ops/gdn.py`) at `qwen3-next-80b-a3b`'s
+# call: [1, 4096, 32, 128] values over 16 key heads of 128, chunks of 64. A
+# grid step holds a key head's two value heads side by side ([64, 256]
+# blocks), their inverse [2, 64, 64] float32, the state [128, 256] float32 in
+# a scratch and a head's [64, 64] float32 blocks one after another.
+GDN_CELL = dict(heads=32, key_heads=16, head_dim=128, chunk=64)
+
+
+def _rule(*operands):
+    from oobleck_tpu.ops.gdn import gated_delta_rule
+
+    return gated_delta_rule(*operands, chunk=GDN_CELL["chunk"])
+
+
+def _rule_grads(fn):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=range(5))
+
+
+def _rule_shapes(batch, seq):
+    h, g, d = (GDN_CELL[k] for k in ("heads", "key_heads", "head_dim"))
+    bf, f32 = jnp.bfloat16, jnp.float32
+    return [((batch, seq, g, d), bf), ((batch, seq, g, d), bf),
+            ((batch, seq, h, d), bf), ((batch, seq, h), f32),
+            ((batch, seq, h), f32)]
+
+
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_gdn_compiles_at_the_cell(v5e, mode):
+    text = _compile(_rule if mode == "fwd" else _rule_grads(_rule), v5e[0],
+                    *_rule_shapes(1, 4096))
+    calls = re.findall(r"%(gdn_\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert sorted(calls) == (["gdn_fwd"] if mode == "fwd"
+                             else ["gdn_bwd", "gdn_fwd"])
+    # No loop walks the chunks, and of the [Q, Q] float32 blocks a head and
+    # chunk the program writes those that build `A`, the inverse and its
+    # cotangent: nothing under the scope `gdn` that is a product with q or
+    # v, `W`, `U` or `V'` ([..., 64, 128] a value head).
+    assert " while(" not in text
+    assert not re.search(r"= (?:bf16|f32)\[[\d,]*16,2,64,128\]", text)
+
+
+def test_gdn_compiles_inside_check_vma_shard_map_under_the_layers_checkpoint(
+        v5e):
+    """As a stage program holds it: under `checkpoint_layer` inside a
+    default (check_vma=True) shard_map, the batch over a data axis,
+    differentiated from outside. o, the chunk-start states and the inverse
+    keep their varying axes through their names, and each kernel is in the
+    program once: the recomputed forward holds none."""
+    mesh = Mesh(v5e[:2], ("data",))
+    specs = (P("data"),) * 5
+    sm = jax.shard_map(checkpoint_layer(_rule), mesh=mesh, in_specs=specs,
+                       out_specs=P("data"))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, spec))
+            for (s, d), spec in zip(_rule_shapes(2, 1024), specs)]
+    text = jax.jit(_rule_grads(sm)).lower(*args).compile().as_text()
+    for kernel in ("gdn_fwd", "gdn_bwd"):
+        assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 1
+
+
 # Every kernel has a stable name on the device: `name=` on its pallas_call
 # is the innermost component of the operation's JAX name stack, and the
 # chip's compiler names the custom call after that component. A profiler
@@ -610,6 +671,7 @@ KERNEL_NAMES = {
     "paged_decode": "decode", "paged_verify": "verify",
     "moe_gmm": "moe", "moe_tgmm": "moe", "moe_token_sum": "moe",
     "ssd_fwd": "ssd", "ssd_bwd": "ssd",
+    "gdn_fwd": "gdn", "gdn_bwd": "gdn",
 }
 
 
@@ -638,6 +700,11 @@ def test_kernel_is_named_in_location_and_executable(v5e, name):
         # again: the wrappers go around "ssd", not around the kernels.
         fn = _scan_grads(jax.checkpoint(_scan))
         shapes = _scan_shapes(1, 512)
+    elif KERNEL_NAMES[name] == "gdn":
+        # As `ssd`'s: the rule is a scope of its own and its backward rule
+        # opens it again.
+        fn = _rule_grads(jax.checkpoint(_rule))
+        shapes = _rule_shapes(1, 256)
     else:
         hq, hkv, d = PAGED_WIDTHS["gpt2"]
         lanes, num_pages, page, table_pages, t = _serve_geometry()
@@ -775,7 +842,12 @@ def test_routed_cell_backward_hands_every_sum_to_its_dw_kernel(v5e, cell):
 # `moe_gmm` one prefetched table less; 7.4 k more text in the four gated
 # cells, 3.7 k in the ungated cell's three routed layers, with the ring on
 # and off alike (both tables' five routed pairs are this PR's, all ten read
-# by hand); `gpt3-2.7b` has no routed layer and its pair stood. A PR that
+# by hand); `gpt3-2.7b` has no routed layer and its pair stood. PR 59:
+# `qwen3-next-80b-a3b`'s three Gated DeltaNet layers run everything after the
+# delta rule's inverse as `gdn_fwd` and `gdn_bwd` (`ops/gdn.py`) where the
+# `jax.numpy` scan across the chunks, its recompute and JAX's derivative of
+# both stood, 197 k less text, ring on and off (the second read by hand); the
+# five other cells hold no delta rule and their pairs stood. A PR that
 # changes what one of these programs
 # computes takes its new text's pair from a failing run; one that leaves a
 # pair standing has shown that the program bypasses its change (PR 46's
@@ -785,7 +857,7 @@ LOWERED = {
     "lfm2-24b-a2b": ("30ac16217d2aa3d1", 622621),
     "moonlight-16b-a3b": ("3a164cc50547f3f1", 779490),
     "nemotron-3-nano-30b-a3b": ("017819795168df87", 531837),
-    "qwen3-next-80b-a3b": ("b5ec433431af958f", 1052225),
+    "qwen3-next-80b-a3b": ("f0990c6af79f8854", 855564),
     "smallthinker-21b-a3b": ("20f2cca60c7db319", 685091),
 }
 # With `OOBLECK_TELEMETRY=0`: the programs without the loads' output: the
@@ -795,7 +867,7 @@ LOWERED_RING_OFF = {
     "lfm2-24b-a2b": ("a5a182008b7e884f", 621595),
     "moonlight-16b-a3b": ("fa2544fa1ab56db0", 778461),
     "nemotron-3-nano-30b-a3b": ("d7e0e1bd093e0f07", 531033),
-    "qwen3-next-80b-a3b": ("69c3f0234a6430ce", 1051170),
+    "qwen3-next-80b-a3b": ("2ed89fe0ef5d70c6", 854511),
     "smallthinker-21b-a3b": ("bc1577e573eacc84", 684064),
 }
 
