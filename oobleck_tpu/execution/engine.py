@@ -648,6 +648,14 @@ class OobleckEngine:
                 args.model.model_name, self._profile_tag(),
                 args.job.microbatch_size
             )
+        # What the largest carry any layer hands the next takes for one
+        # microbatch: what a stage edge would ship were the list cut there,
+        # and what the planner was charged for it (`mem_activation`).
+        metrics.registry().gauge(
+            "oobleck_pipeline_carry_bytes_max",
+            "Bytes of the largest carry (one microbatch) any layer of the "
+            "model hands the next, from the planner's layer profiles",
+        ).set(max(p.mem_activation for p in self.profiles[:-1]))
 
         # Cluster geometry: hosts partition the device list. Ranks encode
         # ORIGINAL host indices (rank = original_index * chips_per_host +

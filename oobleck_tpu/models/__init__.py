@@ -119,6 +119,25 @@ register("smallthinker_21b_instruct")(lambda o: _smallthinker(o))
 register("smallthinker-tiny")(lambda o: _smallthinker(o, vocab_size=256, hidden_size=64, num_layers=4, num_heads=4, num_kv_heads=2, head_dim=16, sliding_window_size=24, moe_intermediate_size=32, num_experts=16, num_experts_per_tok=4, max_position_embeddings=128))
 
 
+def _phi4flash(overrides: dict[str, Any], **preset):
+    from oobleck_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashModel
+
+    return Phi4FlashModel(
+        Phi4FlashConfig().override(**preset).override(**overrides))
+
+
+# Phi-4-mini-flash family (`phi4flash`, the SambaY decoder-hybrid-decoder):
+# Mamba-1 beside differential attention under a window in the first half, a
+# cross-decoder of Gated Memory Units and cross-attention that read ONE
+# layer's scan output and ONE layer's keys and values through the carry in
+# the second; no routed block. The defaults are
+# Phi-4-mini-flash-reasoning's.
+register("phi-4-mini-flash")(lambda o: _phi4flash(o))
+# The published name.
+register("Phi-4-mini-flash-reasoning")(lambda o: _phi4flash(o))
+register("phi4flash-tiny")(lambda o: _phi4flash(o, vocab_size=256, hidden_size=64, num_layers=8, num_heads=4, num_kv_heads=2, head_dim=16, sliding_window=8, intermediate_size=128, max_position_embeddings=128))
+
+
 # Bloom family: GPT architecture with ALiBi position biases (no wpe)
 register("bloom-560m")(lambda o: _gpt(o, vocab_size=250880, hidden_size=1024, num_layers=24, num_heads=16, position_embedding="alibi"))
 register("bloom-7b1")(lambda o: _gpt(o, vocab_size=250880, hidden_size=4096, num_layers=30, num_heads=32, position_embedding="alibi"))

@@ -236,7 +236,9 @@ def causal_attention(
     `constant_bias=True` asserts the bias carries no gradient (ALiBi and
     other position-only biases) — required for the flash kernel, whose VJP
     treats the bias as a constant. Learned/batch-dependent biases and
-    cross-attention (seq_q != seq_k) always take the XLA path.
+    queries and keys of different lengths always take the XLA path (keys
+    and values from another layer at the SAME positions do not: the flash
+    kernels need one length under one causal mask and no more).
 
     Prefer `alibi_slopes` ([H] f32) over a materialized ALiBi `bias`: the
     flash kernel generates the bias block in-kernel from the slopes, so no
@@ -331,3 +333,36 @@ def latent_attention(
         raise ValueError(f"unknown attention impl: {impl!r}")
     q, k = latent_qk(q_nope, q_rope, k_nope, k_rope)
     return _xla_causal_attention(q, k, v, scale=scale)
+
+
+def differential_attention(
+    q1: jax.Array,
+    k1: jax.Array,
+    q2: jax.Array,
+    k2: jax.Array,
+    v: jax.Array,
+    *,
+    impl: str = "auto",
+    scale: float | None = None,
+    window: int | None = None,
+):
+    """Differential attention's two causal softmaxes over ONE set of
+    values: (softmax(q1 k1^T) v, softmax(q2 k2^T) v), q and k [B, H, S, D],
+    v [B, H, S, 2 D]; `window` as `causal_attention`'s. What is done with
+    the two (the difference, `lambda`, the norm) is the model's.
+
+    The flash kernels on a TPU ("auto") or where asked for ("pallas": the
+    interpreter elsewhere), under names of their own; otherwise the XLA
+    reference twice. Ring and Ulysses have no such form and take the
+    single-device choice."""
+    if impl in ("ring", "ulysses"):
+        impl = "auto"
+    if impl == "pallas" or (impl == "auto" and _pallas_ok()):
+        from oobleck_tpu.ops.flash import differential_flash_attention
+
+        return differential_flash_attention(q1, k1, q2, k2, v, scale=scale,
+                                            window=window)
+    if impl not in ("auto", "xla"):
+        raise ValueError(f"unknown attention impl: {impl!r}")
+    return tuple(_xla_causal_attention(q, k, v, scale=scale, window=window)
+                 for q, k in ((q1, k1), (q2, k2)))

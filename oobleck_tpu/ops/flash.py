@@ -108,6 +108,11 @@ PLAIN = KernelNames("flash_fwd", "flash_bwd_dqkv")
 LATENT = KernelNames("flash_mla_fwd", "flash_mla_bwd_dqkv")
 # Calls with a sliding window: the same kernels over the band's pairs.
 WINDOW = KernelNames("flash_swa_fwd", "flash_swa_bwd_dqkv")
+# Differential attention's calls (`differential_flash_attention`): two a
+# layer, each a softmax of 64-wide scores (padded to the lane) over values
+# twice as wide; the band's under the second pair.
+DIFF = KernelNames("flash_diff_fwd", "flash_diff_bwd_dqkv")
+DIFF_WINDOW = KernelNames("flash_diff_swa_fwd", "flash_diff_swa_bwd_dqkv")
 
 
 # The forward rule's names for the kernel's two outputs, O and the row
@@ -611,7 +616,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     alibi_slopes: jax.Array | None = None,
                     causal: bool = True,
                     window: int | None = None) -> jax.Array:
-    """Flash attention. [B, H, S, D] -> [B, H, S, D].
+    """Flash attention. [B, H, S, D] -> [B, H, S, D]. Whose keys and values
+    they are is the caller's business (this layer's, or another layer's at
+    the same positions: a cross-decoder's shared keys and values); what the
+    kernels need is queries and keys of one length under one causal mask.
 
     `bias` is an additive [H, S, S] (or broadcastable) logit bias, treated as
     a constant under differentiation (exact for ALiBi). Prefer
@@ -631,8 +639,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scale = q.shape[-1] ** -0.5
     if q.shape[-2] != k.shape[-2]:
         raise ValueError(
-            "flash kernel is self-attention only (seq_q == seq_k); "
-            "use the XLA path for cross-attention")
+            "the flash kernels need queries and keys of ONE length under "
+            f"one causal mask (got {q.shape[-2]} and {k.shape[-2]}); keys "
+            "and values from another layer at the same positions are fine, "
+            "a shorter or longer memory takes the XLA path")
     if bias is not None and alibi_slopes is not None:
         raise ValueError("pass bias OR alibi_slopes, not both")
     if bias is not None:
@@ -674,6 +684,28 @@ def latent_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
     return _flash(q, k, v, None, None, scale, True, LATENT, None)
 
 
+def differential_flash_attention(q1: jax.Array, k1: jax.Array,
+                                 q2: jax.Array, k2: jax.Array, v: jax.Array,
+                                 *, scale: float | None = None,
+                                 window: int | None = None):
+    """Differential attention's two softmaxes, causal:
+    (softmax(q1 k1^T) v, softmax(q2 k2^T) v). q1, k1, q2, k2 [B, H, S, D];
+    v [B, H, S, 2 D], the pair's two value heads side by side; scale
+    1 / sqrt(D) by default; `window` as `flash_attention`'s.
+
+    Two calls of the plain kernels (`_pad_inputs` pads the 64-wide queries
+    and keys to the lane by itself, the values are a lane already), under
+    `DIFF`'s names (`DIFF_WINDOW`'s with a window): a reader of
+    `%flash_fwd.` counts one width and a causal half. The difference, its
+    norm and `lambda` are the caller's, outside."""
+    if scale is None:
+        scale = q1.shape[-1] ** -0.5
+    names = DIFF if window is None else DIFF_WINDOW
+    window = None if window is None else int(window)
+    return tuple(_flash(q, k, v, None, None, scale, True, names, window)
+                 for q, k in ((q1, k1), (q2, k2)))
+
+
 def _count_named_residuals(kernel: str) -> None:
     """`oobleck_flash_residuals_named_total{kernel}`: once a forward rule
     traced (not once a step), by the forward kernel whose O and LSE it
@@ -689,8 +721,9 @@ def _count_named_residuals(kernel: str) -> None:
 def _count_call(kernel: str, steps: int, window: int | None) -> None:
     """Where a kernel is built into a traced program (not once a step):
     `oobleck_flash_live_pairs{kernel}`, the grid steps a head of the last
-    such call, and `oobleck_flash_window_calls_total{kernel}` where the
-    call has a window."""
+    such call, `oobleck_flash_window_calls_total{kernel}` where the call
+    has a window and `oobleck_flash_diff_calls_total{kernel}` where it is
+    one of differential attention's."""
     from oobleck_tpu.utils import metrics
 
     reg = metrics.registry()
@@ -702,6 +735,11 @@ def _count_call(kernel: str, steps: int, window: int | None) -> None:
         reg.counter(
             "oobleck_flash_window_calls_total",
             "Flash kernels with a sliding window built into traced "
+            "programs, by kernel").inc(kernel=kernel)
+    if kernel in DIFF + DIFF_WINDOW:
+        reg.counter(
+            "oobleck_flash_diff_calls_total",
+            "Flash kernels of differential attention built into traced "
             "programs, by kernel").inc(kernel=kernel)
 
 

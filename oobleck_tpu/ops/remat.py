@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import jax
 
-from oobleck_tpu.ops import flash, gdn, ssd
+from oobleck_tpu.ops import flash, gdn, sscan, ssd
 
 KEPT = (
     *flash.RESIDUAL_NAMES,   # what the flash forward kernel wrote: O, LSE
@@ -19,6 +19,8 @@ KEPT = (
                              # start
     *ssd.RESIDUAL_NAMES,     # what the scan's forward kernel wrote: y, the
                              # state at every chunk's start
+    *sscan.RESIDUAL_NAMES,   # what the selective scan's forward kernel
+                             # wrote: y, the state at every chunk's start
 )
 
 

@@ -18,8 +18,8 @@ HOT_PATH = ["execution/engine.py", "execution/pipeline.py",
             "models/gpt.py", "models/llama.py", "models/lfm2.py",
             "models/routed.py", "models/deepseek_v3.py",
             "models/nemotron_h.py", "models/qwen3_next.py",
-            "models/smallthinker.py", "ops/flash.py",
-            "ops/moe.py", "ops/ssd.py", "ops/gdn.py"]
+            "models/smallthinker.py", "models/phi4flash.py", "ops/flash.py",
+            "ops/moe.py", "ops/ssd.py", "ops/gdn.py", "ops/sscan.py"]
 
 
 def _is_jit(call: ast.Call) -> bool:
@@ -102,3 +102,43 @@ def test_the_delta_rule_s_kernels_carry_their_names(monkeypatch):
         scopes = [getattr(part, "name", None)
                   for part in e.source_info.name_stack.stack]
         assert scopes[-2:] == ["gdn", e.params["name"]], scopes
+
+
+def test_the_selective_scan_s_and_differential_attention_s_kernels_carry_their_names(
+        monkeypatch):
+    """`%sscan_fwd.N`, `%sscan_bwd.N` under the scope `sscan`, and
+    `%flash_diff_fwd.N`, `%flash_diff_bwd_dqkv.N` (with a window:
+    `flash_diff_swa_*`), never `%flash_fwd.`: what the benchmark's kernel
+    metrics match (`benchmarks/layer_metrics/sscan_*`, `flash_diff_*`).
+    Traced only: nothing runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from oobleck_tpu.ops import attention
+    from oobleck_tpu.ops.sscan import selective_scan
+    from tests.ops.programs import all_eqns
+
+    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+
+    def calls(fn, *args):
+        return [e for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+                if e.primitive.name == "pallas_call"]
+
+    scan = calls(jax.grad(lambda *a: jnp.sum(selective_scan(*a)),
+                          argnums=(0, 1)),
+                 shape(1, 128, 128), shape(1, 128, 128), shape(128, 16),
+                 shape(1, 128, 16), shape(1, 128, 16), shape(128))
+    assert [e.params["name"] for e in scan] == ["sscan_fwd", "sscan_bwd"]
+    for e in scan:
+        scopes = [getattr(part, "name", None)
+                  for part in e.source_info.name_stack.stack]
+        assert scopes[-2:] == ["sscan", e.params["name"]], scopes
+    for window, names in ((None, ["flash_diff_fwd", "flash_diff_bwd_dqkv"]),
+                          (64, ["flash_diff_swa_fwd",
+                                "flash_diff_swa_bwd_dqkv"])):
+        diff = calls(jax.grad(lambda *a: sum(
+            jnp.sum(x) for x in attention.differential_attention(
+                *a, window=window)), argnums=(0, 4)),
+            *[shape(1, 2, 128, 64)] * 4, shape(1, 2, 128, 128))
+        assert sorted(e.params["name"] for e in diff) == sorted(names * 2)

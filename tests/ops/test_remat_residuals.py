@@ -23,7 +23,7 @@ and bit-identical gradients (the rule's: `tests/ops/test_gdn.py`).
 (iii) A layer emits no value by a name: nothing is kept by it, and the
 layer lowers to the text it lowered to without that name in the policy.
 
-A file of its own: under `--dist loadfile` its six compiles (about 250 s
+A file of its own: under `--dist loadfile` its seven compiles (about 275 s
 together) do not lengthen `test_tpu_compile.py`'s worker.
 """
 
@@ -49,6 +49,7 @@ def _calls(names, layers):
 
 SCAN = ("ssd_fwd", "ssd_bwd")       # `ops/ssd.py`'s two kernels
 RULE = ("gdn_fwd", "gdn_bwd")       # `ops/gdn.py`'s two kernels
+SELECTIVE = ("sscan_fwd", "sscan_bwd")   # `ops/sscan.py`'s two kernels
 
 
 # cell -> (microbatch, sequence), its attention's kernels with how many
@@ -98,6 +99,15 @@ CELLS = {
     # (11,292,769,792 -> 11,295,044,608; my chip runs, PR 52).
     "smallthinker-21b-a3b": ((1, 16384), {**_calls(flash.PLAIN, 1),
                                           **_calls(flash.WINDOW, 3)}, 6.05e9),
+    # 1,944,687,616 when the cell went in (PR 60), at ONE sequence of 8192:
+    # the feed-forwards' [8192, 20480] intermediates and the head's float32
+    # logits over 25,088 rows. One Mamba-1 layer (one `sscan_fwd`, one
+    # `sscan_bwd`: y, 84 MB, and the 64 chunk-start states, 21 MB float32,
+    # are the program's to hold) and two layers of differential attention,
+    # two softmaxes each through `flash_diff_*`: four forward calls and
+    # four backward, none in the recompute. No routed block.
+    "phi-4-mini-flash": ((1, 8192), {**_calls(SELECTIVE, 1),
+                                     **_calls(flash.DIFF, 4)}, 2.15e9),
 }
 # The three Gated DeltaNet layers' inverse (`ops/gdn.unit_lower_inverse`,
 # scope `gdn_inverse`): ten [64, 64] float32 products of the series a
@@ -170,8 +180,8 @@ def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
     assert {k: count.pop(k, 0) for k in kernels} == kernels
     # What the policy does not name is recomputed as before: the routed
     # layers' three forward products run twice (ROADMAP.md S8 a).
-    assert count == {"gpt3-2.7b": {}, "nemotron-3-nano-30b-a3b": UNGATED}.get(
-        cell, ROUTED)
+    assert count == {"gpt3-2.7b": {}, "phi-4-mini-flash": {},
+                     "nemotron-3-nano-30b-a3b": UNGATED}.get(cell, ROUTED)
     assert compiled.memory_analysis().temp_size_in_bytes < temp_bound
     if cell in EXPERT_SETS:
         _the_experts_sums_are_the_kernels(cell, text)
@@ -179,6 +189,12 @@ def test_cell_backward_holds_each_forward_kernel_once(v5e, compiled_for_tpu,
         _the_scan_is_its_kernels(text)
     if set(RULE) <= set(kernels):
         _the_rule_is_its_kernels_and_its_inverse(text)
+    if set(SELECTIVE) <= set(kernels):
+        # The walk over the positions is the kernels': no loop in the
+        # program, and no [L, C, N] array (8192 x 5120 x 16).
+        assert " while(" not in text
+        assert not re.search(r"\[(?:1,)?8192,5120,16\]|\[(?:1,)?8192,16,5120\]",
+                             text)
     inverse = re.findall(
         r'= f32\[[\d,]*64,64\]\S* convolution\([^\n]*op_name="[^"]*/gdn/'
         r'gdn_inverse/dot_general"', text)

@@ -598,6 +598,103 @@ def test_ssd_compiles_inside_check_vma_shard_map_under_the_layers_checkpoint(
         assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 1
 
 
+# The Mamba-1 selective scan's two kernels (`ops/sscan.py`) at
+# `phi-4-mini-flash`'s call: [1, 8192, 5120] channels of 16 states, chunks
+# of 128. A grid step holds a channel tile's [128, 1024] blocks, the state
+# [16, 1024] float32 in a scratch and, in the backward, the chunk's 129
+# states [129, 16, 1024] (8.5 MB), which the backward asks for by
+# `vmem_limit_bytes` beside the 16 MiB a kernel has without asking.
+SSCAN_CELL = dict(channels=5120, state=16)
+
+
+def _selective(*operands):
+    from oobleck_tpu.ops.sscan import selective_scan
+
+    return selective_scan(*operands)
+
+
+def _selective_shapes(batch, seq):
+    c, n = SSCAN_CELL["channels"], SSCAN_CELL["state"]
+    bf, f32 = jnp.bfloat16, jnp.float32
+    return [((batch, seq, c), bf), ((batch, seq, c), f32), ((c, n), f32),
+            ((batch, seq, n), f32), ((batch, seq, n), f32), ((c,), f32)]
+
+
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_sscan_compiles_at_the_cell(v5e, mode):
+    text = _compile(_selective if mode == "fwd" else _scan_grads(_selective),
+                    v5e[0], *_selective_shapes(1, 8192))
+    calls = re.findall(r"%(sscan_\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert sorted(calls) == (["sscan_fwd"] if mode == "fwd"
+                             else ["sscan_bwd", "sscan_fwd"])
+    # The state at every position is no array of the program, and the walk
+    # over the positions is the kernels'.
+    assert not re.search(r"\[(?:1,)?8192,5120,16\]|\[(?:1,)?8192,16,5120\]",
+                         text)
+    assert " while(" not in text
+
+
+def test_sscan_compiles_inside_check_vma_shard_map_under_the_layers_checkpoint(
+        v5e):
+    """As `ssd`'s: under `checkpoint_layer` inside a default
+    (check_vma=True) shard_map, the batch over a data axis, differentiated
+    from outside; each kernel is in the program once."""
+    mesh = Mesh(v5e[:2], ("data",))
+    row, all_ = P("data"), P()
+    specs = (row, row, all_, row, row, all_)
+    sm = jax.shard_map(checkpoint_layer(_selective), mesh=mesh,
+                       in_specs=specs, out_specs=row)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, spec))
+            for (s, d), spec in zip(_selective_shapes(2, 1024), specs)]
+    text = jax.jit(_scan_grads(sm)).lower(*args).compile().as_text()
+    for kernel in ("sscan_fwd", "sscan_bwd"):
+        assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 1
+
+
+# Differential attention's two softmaxes (`ops/flash.
+# differential_flash_attention`) at `phi-4-mini-flash`'s call: 20 paired
+# heads at 8192 positions, queries and keys 64 wide (padded to the lane by
+# `_pad_inputs`), values 128 wide: the plain kernels under `DIFF`'s names,
+# a head's dq [8192, 128] float32 resident in the backward.
+def _differential(window=None):
+    from oobleck_tpu.ops.flash import differential_flash_attention
+
+    # Under a scope, as the model's mixer calls it: the chip's compiler
+    # names a custom call after the innermost component of its name stack,
+    # and under a transformation that is `jvp(flash_diff_fwd)` where the
+    # kernel's name is the stack's only component.
+    @jax.named_scope("diff_attn")
+    def fn(q1, k1, q2, k2, v):
+        a1, a2 = differential_flash_attention(q1, k1, q2, k2, v,
+                                              window=window)
+        return a1 - 0.5 * a2
+    return fn
+
+
+def _diff_shapes(batch, pairs, seq, d=64):
+    bf = jnp.bfloat16
+    return [((batch, pairs, seq, d), bf)] * 4 + [((batch, pairs, seq, 2 * d),
+                                                  bf)]
+
+
+def _diff_grads(fn):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=range(5))
+
+
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+def test_differential_attention_compiles_at_the_cell(v5e, mode):
+    fn = _differential()
+    text = _compile(fn if mode == "fwd" else _diff_grads(fn), v5e[0],
+                    *_diff_shapes(1, 20, 8192))
+    calls = re.findall(r"%(flash_\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert sorted(calls) == (["flash_diff_fwd"] * 2 if mode == "fwd" else
+                             ["flash_diff_bwd_dqkv"] * 2
+                             + ["flash_diff_fwd"] * 2)
+
+
 # The gated delta rule's two kernels (`ops/gdn.py`) at `qwen3-next-80b-a3b`'s
 # call: [1, 4096, 32, 128] values over 16 key heads of 128, chunks of 64. A
 # grid step holds a key head's two value heads side by side ([64, 256]
@@ -672,6 +769,10 @@ KERNEL_NAMES = {
     "moe_gmm": "moe", "moe_tgmm": "moe", "moe_token_sum": "moe",
     "ssd_fwd": "ssd", "ssd_bwd": "ssd",
     "gdn_fwd": "gdn", "gdn_bwd": "gdn",
+    "sscan_fwd": "sscan", "sscan_bwd": "sscan",
+    "flash_diff_fwd": "diff", "flash_diff_bwd_dqkv": "diff",
+    "flash_diff_swa_fwd": "diff_window",
+    "flash_diff_swa_bwd_dqkv": "diff_window",
 }
 
 
@@ -705,6 +806,13 @@ def test_kernel_is_named_in_location_and_executable(v5e, name):
         # opens it again.
         fn = _rule_grads(jax.checkpoint(_rule))
         shapes = _rule_shapes(1, 256)
+    elif KERNEL_NAMES[name] == "sscan":
+        fn = _scan_grads(jax.checkpoint(_selective))
+        shapes = _selective_shapes(1, 256)
+    elif KERNEL_NAMES[name].startswith("diff"):
+        window = 512 if KERNEL_NAMES[name] == "diff_window" else None
+        fn = _diff_grads(jax.checkpoint(_differential(window)))
+        shapes = _diff_shapes(1, 4, 1024)
     else:
         hq, hkv, d = PAGED_WIDTHS["gpt2"]
         lanes, num_pages, page, table_pages, t = _serve_geometry()
