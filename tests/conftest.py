@@ -12,17 +12,35 @@ The suite runs WITHOUT JAX's persistent compilation cache, whatever the
 environment says: on the CPU backend a warm entry of a multi-device program
 aborts the process (utils/compile_cache.py has the finding), and one abort
 costs every test after it.
+
+Every test process, and a child that keeps its environment, builds XLA:CPU
+programs at LLVM level 1, not the default: the models here are tiny, so the
+optimiser buys seconds of run time and costs a sixth of the suite in compile
+(PR 61, seven dear files in one hour, 378 tests passing each time, junit s:
+default 807, level 1 663, level 0 538; PERF.md section 7 has the whole runs).
+Level 0 waits for tests/benchmarks/test_bench_nemotron_h.py's `two_blocks`
+(ROADMAP.md D24). It says nothing of a chip: the TPU compiler never sees it.
 """
 
 import os
 
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-)
+# Appended, so they win over a caller's; in the environment, so a child that
+# keeps it builds as the tests do (the sacrificial children of tests/ckpt/,
+# tests/serve/ and tests/elastic/). The `-m slow` worlds set XLA_FLAGS anew
+# and tests/benchmarks/test_bench_run.py's children drop it: the default level.
+XLA_FLAGS = ("--xla_force_host_platform_device_count=8",
+             "--xla_backend_optimization_level=1")
+os.environ["XLA_FLAGS"] = " ".join([os.environ.get("XLA_FLAGS", ""), *XLA_FLAGS])
 
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+# One-device programs run inline, on the caller's thread. Dispatched
+# asynchronously, one compiled program's calls gave one of two outputs an ulp
+# apart, the rare one in 2 % of calls beside busy processes, and a test that
+# holds two calls to bits was red in one run of eight (PR 61, 29 of 240 runs
+# of tests/benchmarks/test_bench_deepseek_v3.py's first 44 tests; 0 of 120 so).
+jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 
 from oobleck_tpu.execution.pipeline import PROGRAMS
@@ -34,14 +52,35 @@ import numpy as np
 import pytest
 
 
-# Compile-bound modules that sort late in the alphabet. Collected in place,
-# they are the last thing a pytest-xdist run hands out, and one worker
-# finishes them alone while the others idle (minutes, against a fixed wall
-# budget); a serial run is cut inside tests/execution/ either way. So they
-# run right after the engine tests, and the many fast tests fill the tail.
-_HEAVY_LATE = ("tests/models/", "tests/serve/", "tests/test_ops.py",
-               "tests/test_overlap.py", "tests/test_smoke.py",
-               "tests/test_train_spmd.py")
+# The files over 100 s of test time, dearest first, then the alphabet: under
+# `--dist loadfile` a file is one worker's from start to end, and a 300 s file
+# handed out when the others have 100 s left ends the run 200 s late. From the
+# junit file of the driver's command on PR 61's tree (2026-10-04 09:51 UTC,
+# six workers on 8 cores, 3,095 passed in 1,133 s, 6,663 s of test time), s in
+# the tuple's order: 276 251 209 209 209 203 201 197 195 188 188 187 185 181
+# 173 157 154 154 153 149 147 138 138 135 125 125 119 110 108 102 101. Stale
+# when a file here has halved or one outside has passed 150 s.
+DEAREST_FIRST = (
+    "tests/ops/test_tpu_compile_routed.py",
+    "tests/benchmarks/test_bench_qwen3_next.py",
+    "tests/models/test_qwen3_next.py", "tests/ops/test_routed_experts.py",
+    "tests/benchmarks/test_bench_phi4flash.py", "tests/test_overlap.py",
+    "tests/test_ops.py", "tests/models/test_lfm2.py",
+    "tests/execution/test_engine_families.py",
+    "tests/benchmarks/test_bench_hostloss.py",
+    "tests/models/test_smallthinker.py", "tests/ops/test_remat_cells_c.py",
+    "tests/serve/test_speculative.py", "tests/models/test_families.py",
+    "tests/benchmarks/test_bench_nemotron_h.py",
+    "tests/benchmarks/test_bench_smallthinker.py",
+    "tests/ops/test_remat_cells_b.py", "tests/test_train_spmd.py",
+    "tests/models/test_phi4flash.py", "tests/execution/test_exec_args.py",
+    "tests/execution/test_engine_reconfig.py", "tests/ops/test_tpu_compile.py",
+    "tests/ops/test_remat_cells_a.py", "tests/models/test_nemotron_h.py",
+    "tests/models/test_deepseek_v3.py", "tests/serve/test_paged_parity.py",
+    "tests/execution/test_programs.py",
+    "tests/execution/test_pipeline_mpmd.py", "tests/ops/test_gdn.py",
+    "tests/execution/test_precompile.py",
+    "tests/execution/test_kernel_grad_sums.py")
 
 
 def pytest_configure(config):
@@ -54,14 +93,17 @@ def pytest_configure(config):
                  for a in config.invocation_params.args)
     if getattr(config.option, "dist", "no") == "load" and not chosen:
         config.option.dist = "loadfile"
+    # xdist >= 3.7 otherwise hands files out by their NUMBER of tests, most
+    # first: a file of three 50 s compiles would go last of all.
+    config.option.loadscopereorder = False
 
 
 def pytest_collection_modifyitems(items):
+    place = {path: i for i, path in enumerate(DEAREST_FIRST)}
+
     def rank(item):
         path = item.nodeid.split("::", 1)[0]
-        if path.startswith(_HEAVY_LATE):
-            return "tests/execution/test_reconfigure.py~"
-        return path
+        return place.get(path, len(place)), path  # then the alphabet
 
     items.sort(key=rank)  # stable: order inside a module is kept
 
