@@ -640,7 +640,7 @@ class PipelineInstance:
           * the marks themselves, flattened (the model's `sums_in_kernel`,
             `st.mesh.size` and the backend decide them).
         Not in the key, because a process has ONE: the backend
-        (`ops/attention._pallas_ok`) and JAX's configuration flags. Read by
+        (`ops/kernel.on_tpu`) and JAX's configuration flags. Read by
         no trace: the pipeline's id and own microbatch count
         (`adopt_microbatches`), the stage's ranks and owning process."""
         layers = st.chunks[c]

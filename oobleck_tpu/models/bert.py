@@ -27,8 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from oobleck_tpu.models.base import stack_layer_params
 from oobleck_tpu.models.gpt import NEG_INF, ShardCtx, _layer_norm
-from oobleck_tpu.ops import checkpoint_layer
-from oobleck_tpu.ops.attention import _xla_causal_attention
+from oobleck_tpu.ops import attention, checkpoint_layer
 
 
 @dataclass(frozen=True)
@@ -196,7 +195,7 @@ class BertModel:
         h = _layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], c.layer_norm_epsilon)
         qkv = jnp.einsum("bse,ethd->tbhsd", h, p["attn"]["wqkv"].astype(dt))
         qkv = qkv + p["attn"]["bqkv"].astype(dt)[:, None, :, None, :]
-        attn = _xla_causal_attention(qkv[0], qkv[1], qkv[2], causal=False)
+        attn = attention._xla_causal_attention(qkv[0], qkv[1], qkv[2], causal=False)
         out = jnp.einsum("bhsd,hde->bse", attn, p["attn"]["wo"].astype(dt))
         x = x + out + p["attn"]["bo"].astype(dt)
         h = _layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], c.layer_norm_epsilon)

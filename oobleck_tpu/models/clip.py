@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from oobleck_tpu.models.gpt import _layer_norm
-from oobleck_tpu.ops.attention import _xla_causal_attention
+from oobleck_tpu.ops import attention
 
 
 @dataclass(frozen=True)
@@ -97,7 +97,7 @@ def _apply_tx_block(p, x, *, causal: bool, eps: float, dtype):
     h = _layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], eps)
     qkv = jnp.einsum("bse,ethd->tbhsd", h, p["attn"]["wqkv"].astype(dtype))
     qkv = qkv + p["attn"]["bqkv"].astype(dtype)[:, None, :, None, :]
-    attn = _xla_causal_attention(qkv[0], qkv[1], qkv[2], causal=causal)
+    attn = attention._xla_causal_attention(qkv[0], qkv[1], qkv[2], causal=causal)
     out = jnp.einsum("bhsd,hde->bse", attn, p["attn"]["wo"].astype(dtype))
     x = x + out + p["attn"]["bo"].astype(dtype)
     h = _layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], eps)

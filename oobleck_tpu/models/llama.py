@@ -479,7 +479,7 @@ class LlamaModel:
         at absolute positions prior_len + i; mask is explicit."""
         c = self.config
         dt = c.dtype
-        from oobleck_tpu.ops.attention import _xla_causal_attention
+        from oobleck_tpu.ops import attention
         from oobleck_tpu.ops.paged_attention import paged_gather_kv
 
         h = _rms_norm(x, p["ln1"]["scale"], c.rms_norm_eps)
@@ -506,7 +506,7 @@ class LlamaModel:
             jnp.tril(jnp.ones((t_len, t_len), bool)),
         ], axis=1)
         bias = jnp.where(live, 0.0, NEG_INF)[None]                      # [1,T,S]
-        attn = _xla_causal_attention(q, k, v, bias=bias, causal=False)
+        attn = attention._xla_causal_attention(q, k, v, bias=bias, causal=False)
         out = jnp.einsum("bhsd,hde->bse", attn, p["attn"]["wo"].astype(dt))
         return x + out, k_tail, v_tail
 

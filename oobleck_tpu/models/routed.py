@@ -254,11 +254,11 @@ class RoutedShareModel:
         products with nothing between (`routed_ff`), so such a leaf's
         cotangent comes back as sum + gradient and `apply_layer` wants the
         sums of the marked leaves in `grad_sums`."""
-        from oobleck_tpu.ops import moe
+        from oobleck_tpu.ops import kernel
 
         block = index - 1
         if not (0 <= block < self.config.num_layers and self.is_routed(block)
-                and moe._pallas_ok()):
+                and kernel.on_tpu()):
             return None
         marks = jax.tree.map(lambda _: False, params)
         marks["ff"].update(

@@ -30,8 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from oobleck_tpu.models.base import stack_layer_params
-from oobleck_tpu.ops import checkpoint_layer
-from oobleck_tpu.ops.attention import _xla_causal_attention
+from oobleck_tpu.ops import attention, checkpoint_layer
 
 NEG_INF = -1e9
 
@@ -280,15 +279,15 @@ class T5Model:
                          bidirectional=not causal,
                          num_buckets=c.rel_buckets,
                          max_dist=c.rel_max_distance)
-        out = _xla_causal_attention(qkv[0], qkv[1], qkv[2], bias=bias,
-                                    causal=causal, scale=1.0)
+        out = attention._xla_causal_attention(
+            qkv[0], qkv[1], qkv[2], bias=bias, causal=causal, scale=1.0)
         return jnp.einsum("bhsd,hde->bse", out, p["wo"].astype(dt))
 
     def _cross_attn(self, p, y, enc_out):
         dt = self.config.dtype
         q = jnp.einsum("bse,ehd->bhsd", y, p["wq"].astype(dt))
         kv = jnp.einsum("bse,ekhd->kbhsd", enc_out, p["wkv"].astype(dt))
-        out = _xla_causal_attention(q, kv[0], kv[1], causal=False, scale=1.0)
+        out = attention._xla_causal_attention(q, kv[0], kv[1], causal=False, scale=1.0)
         return jnp.einsum("bhsd,hde->bse", out, p["wo"].astype(dt))
 
     def _ff(self, p, x):

@@ -1,17 +1,23 @@
-"""Attention kernels.
+"""Attention behind one functional interface.
 
-The reference has no custom kernels (GPU compute goes through torch modules,
-/root/reference/oobleck/module/model.py:71-83); on TPU the attention inner loop
-is the one op worth a hand-written Pallas kernel. Three implementations behind
-one functional interface:
+`select_attention_impl` resolves six names:
 
-  - "xla":    einsum + masked softmax; XLA fuses this well and it is the
-              reference implementation for correctness tests.
-  - "pallas": blockwise flash attention Pallas kernel (oobleck_tpu.ops.flash).
-  - "ring":   ring attention over a sequence-parallel mesh axis
-              (oobleck_tpu.ops.ring_attention) for long-context training.
+  - "xla":     einsum + masked softmax (`_xla_causal_attention`); XLA fuses
+               this well and it is the reference for correctness tests.
+  - "pallas":  the blockwise flash kernels (`ops/flash.py`).
+  - "ring":    ring attention over a sequence-parallel mesh axis
+               (`ops/ring_attention.py`) for long-context training.
+  - "ulysses": the all-to-all layout exists only under a sequence-parallel
+               axis (`ops/ulysses.py`, which models call there); without
+               one, the "auto" choice.
+  - "auto":    flash on a TPU (`kernel.on_tpu`), XLA elsewhere.
+  - "paged":   ragged paged decode (`ops/paged_attention.py`), with ANOTHER
+               signature: page pools and block tables, not [B, H, S, D].
 
-All take [batch, heads, seq, head_dim] Q/K/V and return the same shape.
+All but "paged" take [batch, heads, seq, head_dim] Q/K/V and return the
+same shape. `causal_attention` dispatches on top (which calls the flash
+kernels take: `flash_ok`); `latent_attention` and `differential_attention`
+are the latent (MLA) and differential forms over the same two paths.
 """
 
 from __future__ import annotations
@@ -21,33 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from oobleck_tpu.ops import kernel
+
 NEG_INF = -1e9  # large-but-finite: jnp.finfo(bf16).min overflows under softmax subtraction
-
-
-def _pallas_ok() -> bool:
-    """True when Pallas TPU kernels run compiled (i.e. the backend is TPU).
-
-    Shared by the flash/paged "auto" policies and the kernels' interpret
-    toggles: off-TPU the kernels would run in interpreter mode — correct but
-    slow — so auto selection falls back to XLA and explicit pallas requests
-    flip `interpret=True` (CPU parity tests). One helper so the policy and
-    the toggle can never disagree.
-
-    That fallback is for processes with no TPU. One that can reach a TPU
-    while its default backend is something else would run the interpreter
-    or the XLA reference beside an idle chip, so there the question is an
-    error, not False."""
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return True
-    try:
-        jax.devices("tpu")
-    except RuntimeError:  # no TPU backend in this process
-        return False
-    raise RuntimeError(
-        f"a TPU is visible but the default JAX backend is {backend!r}: "
-        "refusing to pick interpret-mode Pallas or the XLA reference in "
-        "its place (fix JAX_PLATFORMS, or ask for attention_impl 'xla')")
 
 
 def _xla_causal_attention(
@@ -210,7 +192,7 @@ def select_attention_impl(impl: str = "auto"):
         # [S, S] logits. Elsewhere (CPU mesh tests) the kernel would run in
         # interpreter mode, so the fused XLA path is faster. Never silently
         # swallow an ImportError here — a masked fallback hides real bugs.
-        if _pallas_ok():
+        if kernel.on_tpu():
             from oobleck_tpu.ops.flash import flash_attention
 
             return flash_attention
@@ -324,7 +306,7 @@ def latent_attention(
     single-device choice."""
     if impl in ("ring", "ulysses"):
         impl = "auto"
-    if impl == "pallas" or (impl == "auto" and _pallas_ok()):
+    if impl == "pallas" or (impl == "auto" and kernel.on_tpu()):
         from oobleck_tpu.ops.flash import latent_flash_attention
 
         return latent_flash_attention(q_nope, q_rope, k_nope, k_rope, v,
@@ -357,7 +339,7 @@ def differential_attention(
     single-device choice."""
     if impl in ("ring", "ulysses"):
         impl = "auto"
-    if impl == "pallas" or (impl == "auto" and _pallas_ok()):
+    if impl == "pallas" or (impl == "auto" and kernel.on_tpu()):
         from oobleck_tpu.ops.flash import differential_flash_attention
 
         return differential_flash_attention(q1, k1, q2, k2, v, scale=scale,

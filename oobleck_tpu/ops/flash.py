@@ -67,11 +67,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from oobleck_tpu.ops import kernel
+from oobleck_tpu.ops.kernel import LANE, SCOPED_VMEM
 
 NEG_INF = -1e9
 
-LANE = 128
 # The widest block of queries or keys a grid step holds, and the most
 # [Bq, Bk] logits of one block pair. 512 x 512 x 128 is about a microsecond
 # of MXU work: enough for a step to cost its matmuls and not its
@@ -82,14 +83,10 @@ MAX_PAIR = MAX_BLOCK * MAX_BLOCK
 # Bits of a grid step's flag: the step opens, closes an accumulation.
 FIRST, LAST = 1, 2
 
-# The batch * head axis is independent work (cores may split it); the steps
-# of one accumulation are not.
-_DIMENSION_SEMANTICS = ("parallel", "arbitrary")
-# What a kernel may hold of VMEM unless it says otherwise (16 MiB on a
-# v5e). One step's blocks and a pair's [Bq, Bk] f32 temporaries fit in it
-# at every tile `choose_tiles` returns, so the forward states no limit; the
-# backward asks for this much beside the head's dq it keeps resident.
-SCOPED_VMEM = 16 << 20
+# One step's blocks and a pair's [Bq, Bk] f32 temporaries fit in
+# `SCOPED_VMEM` at every tile `choose_tiles` returns, so the forward states
+# no limit; the backward asks for that much beside the head's dq it keeps
+# resident.
 # The most a head's resident dq may take: half a v5e's 128 MiB of VMEM.
 MAX_RESIDENT_DQ = 64 << 20
 
@@ -391,61 +388,20 @@ def _canon_bias(bias, h, s_len):
     return jnp.broadcast_to(bias, (h, s_len, s_len))
 
 
-def _interpret() -> bool:
-    # Interpreter mode off-TPU: tests validate kernel math on the CPU mesh.
-    from oobleck_tpu.ops import attention
-
-    return not attention._pallas_ok()
-
-
 def _call(body, name: str, pairs, operands, *, in_specs, out_shape,
           out_specs, scratch, vmem_limit_bytes: int | None = None, **statics):
     """One `pallas_call` over the live pairs: grid (batch * head, step);
     each step looks its pair up in the prefetched tables of `_live_pairs`
-    and runs `body(qi, ki, flag, *refs, **statics)` on it.
+    and runs `body(qi, ki, flag, *refs, **statics)` on it."""
+    def pair(step, q_of, k_of, flag_of, *refs):
+        body(q_of[step], k_of[step], flag_of[step], *refs, **statics)
 
-    The interpreter evaluates a kernel's top level as plain operations of
-    the enclosing program, and inside a `check_vma=True` shard_map those
-    refuse a block (varying over the mesh) beside a constant (not varying);
-    a branch's body is opaque to that check. So under the interpreter, and
-    only there, the pair runs inside a branch that is always taken."""
-    interpret = _interpret()
-    _count_call(name, len(pairs[0]), statics["window"])
-
-    def kernel(q_of, k_of, flag_of, *refs):
-        step = pl.program_id(1)
-        pair = functools.partial(body, q_of[step], k_of[step], flag_of[step],
-                                 *refs, **statics)
-        if interpret:
-            pl.when(step >= 0)(pair)
-        else:
-            pair()
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(pairs),
-            grid=(operands[0].shape[0], len(pairs[0])),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((rows, width), jnp.float32)
-                            for rows, width in scratch]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=_DIMENSION_SEMANTICS,
-            vmem_limit_bytes=vmem_limit_bytes),
-        interpret=interpret,
-        name=name,
-    )(*pairs, *operands)
-
-
-def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
-    """A `pallas_call` out_shape entry varying over every mesh axis any
-    operand varies over: inside a `check_vma=True` shard_map (the fused
-    step's three phases, the MPMD stage programs) Pallas refuses an output
-    whose varying-manual-axes it would have to guess."""
-    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    return kernel.sequential_call(
+        pair, name, operands, in_specs, out_shape, out_specs,
+        grid=(operands[0].shape[0], len(pairs[0])), scratch=scratch,
+        prefetch=pairs, vmem_limit_bytes=vmem_limit_bytes,
+        count=functools.partial(_count_call, name, len(pairs[0]),
+                                statics["window"]))
 
 
 # Block index maps: the grid is (batch * head, step) and the step's q and
@@ -497,13 +453,13 @@ def _flash_forward(q, k, v, bias, slopes, scale: float, causal: bool,
 
     operands = ([q, k, v] + ([bias] if has_bias else [])
                 + ([slopes] if has_slopes else []))
-    o_shape = _out_struct((bh, sp, dvp), q.dtype, *operands)
+    o_shape = kernel.out_struct((bh, sp, dvp), q.dtype, *operands)
     o_spec = _q_rows(t.block_q, dvp)
     if emit_lse:
         # The LSE residual is only needed when a backward pass will run;
         # forward-only (eval) calls skip the extra [BH, S, 128] HBM write.
-        out_shape = (o_shape,
-                     _out_struct((bh, sp, LANE), jnp.float32, *operands))
+        out_shape = (o_shape, kernel.out_struct((bh, sp, LANE), jnp.float32,
+                                                *operands))
         out_specs = (o_spec, _q_rows(t.block_q, LANE))
     else:
         out_shape, out_specs = o_shape, o_spec
@@ -551,8 +507,8 @@ def _flash_backward(q, k, v, bias, slopes, out, lse, g, scale: float,
             "sequence (ring attention) or use the XLA path")
     # Gradients leave in the operands' dtype: one rounding from the f32
     # accumulator, here and not in a cast after the kernel.
-    grad_shape = lambda x, width: _out_struct((bh, sp, width), x.dtype,
-                                              *operands)
+    grad_shape = lambda x, width: kernel.out_struct(
+        (bh, sp, width), x.dtype, *operands)
     dq, dk, dv_ = _call(
         _bwd_kernel, names.bwd,
         _live_pairs(t, causal, q_major=False, window=window), operands,

@@ -32,7 +32,7 @@ series' own would be eighteen) and its forward rule NAMES it
 series.
 
 What comes AFTER the inverse has two paths, and the backend decides between
-them (`attention._pallas_ok`; a shape the kernels do not tile,
+them (`kernel.on_tpu`; a shape the kernels do not tile,
 `_kernels_take`, is the other reason for the second):
 
   on a TPU   two Pallas kernels behind a `jax.custom_vjp` that takes q, k,
@@ -53,10 +53,8 @@ them (`attention._pallas_ok`; a shape the kernels do not tile,
              position and a chunk that the running sums' and beta's
              gradients are made of. `D`, `T`, `W`, `U`, `Q K^T` and `V'`
              never reach HBM, and no `while` walks the chunks. The running
-             sums are a product with a triangle of ones, not `cumsum` (a
-             `reduce-window` on the chip: PR 54). The forward rule NAMES
-             what the kernel wrote (`RESIDUAL_NAMES`), so the layer's
-             recomputed forward holds no kernel.
+             sums are `kernel.running_sums`, not `cumsum`. The rule
+             (`kernel.kernel_vjp`) names what `gdn_fwd` wrote.
   elsewhere  plain `jax.numpy` (`_rule_xla`): batched `einsum`s and a
              `lax.scan` across the chunks, gradients by JAX's
              differentiation of them, `D`, `T` and `Q K^T` written out. The
@@ -85,10 +83,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from oobleck_tpu.ops.flash import LANE, _interpret, _out_struct
-from oobleck_tpu.ops.ssd import _decays, _lower, _nn, _nt, _tn
+from oobleck_tpu.ops import kernel
+from oobleck_tpu.ops.kernel import LANE, decays, lower, nn, nt, out_struct, tn
 
 # The forward rules' names for what only more product or kernel time could
 # give back: the inverse (`decay`, `kk` and `a` come back by cheap XLA), and
@@ -124,7 +121,7 @@ def _count_named_residuals() -> None:
         "named for the layer's checkpoint").inc()
 
 
-def _count_call(kernel: str) -> None:
+def _count_call(which: str) -> None:
     """`oobleck_gdn_kernel_calls_total{kernel}`: where a kernel is built
     into a traced program (not once a step). A rule on the `jax.numpy` path
     counts none."""
@@ -133,7 +130,7 @@ def _count_call(kernel: str) -> None:
     metrics.registry().counter(
         "oobleck_gdn_kernel_calls_total",
         "Pallas kernels of the gated delta rule built into traced "
-        "programs, by kernel (fwd, bwd)").inc(kernel=kernel)
+        "programs, by kernel (fwd, bwd)").inc(kernel=which)
 
 
 def _dot(x: jax.Array, y: jax.Array) -> jax.Array:
@@ -191,8 +188,6 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     and scales); v [B, S, H, dv] with G dividing H (value head h reads key
     head h // (H / G)); g [B, S, H], the log of the decay, never positive;
     beta [B, S, H]. Returns o [B, S, H, dv] in v's dtype."""
-    from oobleck_tpu.ops.attention import _pallas_ok
-
     f32 = jnp.float32
     bsz, seq, heads, dv = v.shape
     groups, dk = k.shape[2], k.shape[3]
@@ -206,12 +201,12 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         q, k, v, g, beta = rows(q), rows(k), rows(v), rows(g), rows(beta)
     dtype = v.dtype
     q, k = q.astype(dtype), k.astype(dtype)
-    kernels = _pallas_ok() and _kernels_take(chunk, r, dk, dv)
+    kernels = kernel.on_tpu() and _kernels_take(chunk, r, dk, dv)
     # Heads before positions: the [Q, Q] blocks are the minor dimensions.
     per_head = lambda t: jnp.moveaxis(
         t.astype(f32).reshape(bsz, nc, chunk, groups, r), 2, -1)
     beta_h = per_head(beta)                                # [B, nc, G, R, Q]
-    cum = (_running_sums if kernels else
+    cum = (kernel.running_sums if kernels else
            functools.partial(jnp.cumsum, axis=-1))(per_head(g))
     i = jnp.arange(chunk)
     decay = jnp.exp(jnp.where(i[:, None] >= i[None, :],
@@ -227,15 +222,6 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     if kernels:
         return _rule_kernels(q, k, v, cum, beta_h, inverse)[:, :seq]
     return _rule_xla(q, k, v, cum, beta_h, inverse, decay)[:, :seq]
-
-
-def _running_sums(t):
-    """The running sum along the last axis (a chunk's positions) of a
-    float32 [..., Q]: a product with the [Q, Q] triangle of ones at
-    float32's own precision, as `ops/ssd._running_sums` (XLA's `cumsum` of
-    such a shape is a `reduce_window`, slower than both kernels: PR 54)."""
-    ones = _lower(t.shape[-1]).astype(jnp.float32)
-    return jnp.einsum("ij,...j->...i", ones, t, precision=lax.Precision.HIGHEST)
 
 
 # --------------------------------------------------------------------- #
@@ -310,8 +296,7 @@ def _rule_xla(q, k, v, cum, beta_h, inverse, decay):
 #   the state at z's start     [dk, R dv]  of [B, nc, G, dk, R dv]  float32
 #
 # Inside the bodies `lax.select`, never `jnp.where`, and no `//` or `%` in
-# an index map: `ops/ssd.py` says why (a jitted helper's source location in
-# the compile cache's key).
+# an index map: `ops/__init__.py` has the rule.
 
 def _head(k_ref, v_ref, x_ref, col_ref, row_ref, start, h: int, *, r: int,
           dv: int):
@@ -328,13 +313,13 @@ def _head(k_ref, v_ref, x_ref, col_ref, row_ref, start, h: int, *, r: int,
     total = cum[qn - 1:qn, :]                                   # [1, 1]
     t = x_ref[h] * row_ref[r + h:r + h + 1, :]                  # X diag(beta)
     tw = t * since_row
-    w = _nn(tw.astype(dtype), k_ref[...]).astype(dtype)         # [Q, dk]
-    u = _nn(t.astype(dtype), v_ref[:, lanes]).astype(dtype)     # [Q, dv]
-    wrote = u.astype(f32) - _nn(w, start[:, lanes])             # V'
+    w = nn(tw.astype(dtype), k_ref[...]).astype(dtype)         # [Q, dk]
+    u = nn(t.astype(dtype), v_ref[:, lanes]).astype(dtype)     # [Q, dv]
+    wrote = u.astype(f32) - nn(w, start[:, lanes])             # V'
     return dict(lanes=lanes, since=jnp.exp(cum), to_end=jnp.exp(total - cum),
                 whole=jnp.exp(jnp.broadcast_to(total, (1, dv))),
                 since_row=since_row,
-                decay=_decays(col_ref, row_ref, h, _lower(qn)),
+                decay=decays(col_ref, row_ref, h, lower(qn)),
                 t=t, tw=tw, w=w, wrote=wrote)
 
 
@@ -349,14 +334,14 @@ def _fwd_kernel(z, q_ref, k_ref, v_ref, x_ref, col_ref, row_ref,
     start_ref[...] = state[...]
     qm, km = q_ref[...], k_ref[...]
     start = state[...].astype(dtype)
-    qk = _nt(qm, km)                                            # [Q, Q]
-    o_off = _nn(qm, start)                                      # Q S
+    qk = nt(qm, km)                                            # [Q, Q]
+    o_off = nn(qm, start)                                      # Q S
     for h in range(r):
         c = _head(k_ref, v_ref, x_ref, col_ref, row_ref, start, h, r=r, dv=dv)
         lanes, wrote = c["lanes"], c["wrote"]
-        o = _nn((qk * c["decay"]).astype(dtype), wrote.astype(dtype))
+        o = nn((qk * c["decay"]).astype(dtype), wrote.astype(dtype))
         o_ref[:, lanes] = (o + c["since"] * o_off[:, lanes]).astype(o_ref.dtype)
-        state[:, lanes] = c["whole"] * state[:, lanes] + _tn(
+        state[:, lanes] = c["whole"] * state[:, lanes] + tn(
             km, (wrote * c["to_end"]).astype(dtype))
 
 
@@ -386,9 +371,9 @@ def _bwd_kernel(z, q_ref, k_ref, v_ref, x_ref, col_ref, row_ref, start_ref,
     start = state.astype(dtype)
     d_end = dstate[...]
     d_end_low = d_end.astype(dtype)
-    qk = _nt(qm, km)
-    o_off = _nn(qm, start)                                      # Q S
-    d_to_state = _nn(km, d_end_low)                             # K dS_end
+    qk = nt(qm, km)
+    o_off = nn(qm, start)                                      # Q S
+    d_to_state = nn(km, d_end_low)                             # K dS_end
     dqk = jnp.zeros((qn, qn), f32)
     dq = jnp.zeros(dq_ref.shape, f32)
     dk = jnp.zeros(dk_ref.shape, f32)
@@ -400,24 +385,24 @@ def _bwd_kernel(z, q_ref, k_ref, v_ref, x_ref, col_ref, row_ref, start_ref,
         dof = do.astype(f32)
         # out: O = (Q K^T * D) V' + e^cum * (Q S)
         p = qk * decay
-        dp = _nt(do, wrote.astype(dtype))                       # [Q, Q]
+        dp = nt(do, wrote.astype(dtype))                       # [Q, Q]
         do_since = (dof * since).astype(dtype)
-        dq = dq + _nt(do_since, start[:, lanes])
+        dq = dq + nt(do_since, start[:, lanes])
         dqk = dqk + dp * decay
         # inter: S_end = e^total S + K^T (V' * e^{total - cum})
         added = to_end * d_to_state[:, lanes]
-        dk = dk + _nt((wrote * to_end).astype(dtype), d_end_low[:, lanes])
-        d_wrote = (_tn(p.astype(dtype), do) + added).astype(dtype)
+        dk = dk + nt((wrote * to_end).astype(dtype), d_end_low[:, lanes])
+        d_wrote = (tn(p.astype(dtype), do) + added).astype(dtype)
         # V' = U - W S;  U = T V;  W = (T * e^cum) K
-        dw = (-_nt(d_wrote, start[:, lanes])).astype(dtype)     # [Q, dk]
-        dtw = _nt(dw, km)                                       # [Q, Q]
-        dt = _nt(d_wrote, v_ref[:, lanes]) + dtw * since_row
-        dv_ref[:, lanes] = _tn(c["t"].astype(dtype), d_wrote).astype(
+        dw = (-nt(d_wrote, start[:, lanes])).astype(dtype)     # [Q, dk]
+        dtw = nt(dw, km)                                       # [Q, Q]
+        dt = nt(d_wrote, v_ref[:, lanes]) + dtw * since_row
+        dv_ref[:, lanes] = tn(c["t"].astype(dtype), d_wrote).astype(
             dv_ref.dtype)
-        dk = dk + _tn(c["tw"].astype(dtype), dw)
+        dk = dk + tn(c["tw"].astype(dtype), dw)
         dx_ref[h] = dt * row_ref[r + h:r + h + 1, :]
         dstate[:, lanes] = (c["whole"] * d_end[:, lanes]
-                            + _tn(qm, do_since) - _tn(c["w"], d_wrote))
+                            + tn(qm, do_since) - tn(c["w"], d_wrote))
         # the running sums' gradient and beta's
         of_decay = dp * p
         dcol_ref[:, h:h + 1] = (
@@ -433,39 +418,8 @@ def _bwd_kernel(z, q_ref, k_ref, v_ref, x_ref, col_ref, row_ref, start_ref,
                                  keepdims=True)
             + jnp.sum(wrote * added, axis=0, keepdims=True))
     dqk = dqk.astype(dtype)
-    dq_ref[...] = (dq + _nn(dqk, km)).astype(dq_ref.dtype)
-    dk_ref[...] = (dk + _tn(dqk, qm)).astype(dk_ref.dtype)
-
-
-def _call(body, kernel: str, operands, in_specs, out_shape, out_specs, *,
-          grid, state_shape, r: int, dv: int):
-    """One `pallas_call` on the grid (batch, key head, chunk), the chunk
-    axis sequential, with one float32 scratch that lives across it. Under
-    the interpreter the step runs inside a branch that is always taken, for
-    the `check_vma=True` shard_maps (`ops/flash._call`'s docstring)."""
-    interpret = _interpret()
-    _count_call(kernel)
-
-    def step(*refs):
-        z = pl.program_id(2)
-        chunk = functools.partial(body, z, *refs, r=r, dv=dv)
-        if interpret:
-            pl.when(z >= 0)(chunk)
-        else:
-            chunk()
-
-    return pl.pallas_call(
-        step,
-        out_shape=out_shape,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=grid,
-            in_specs=in_specs, out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM(state_shape, jnp.float32)]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name=f"gdn_{kernel}",
-    )(*operands)
+    dq_ref[...] = (dq + nn(dqk, km)).astype(dq_ref.dtype)
+    dk_ref[...] = (dk + tn(dqk, qm)).astype(dk_ref.dtype)
 
 
 def _operands(q, k, v, cum, beta_h, inverse, reverse: bool):
@@ -497,12 +451,13 @@ def _forward(q, k, v, cum, beta_h, inverse):
     dk, dv = k.shape[-1], v.shape[-1]
     operands, in_specs, _, wide, of_chunk = _operands(
         q, k, v, cum, beta_h, inverse, reverse=False)
-    o, starts = _call(
-        _fwd_kernel, "fwd", operands, in_specs,
-        (_out_struct(operands[2].shape, v.dtype, *operands),
-         _out_struct((bsz, nc, groups, dk, r * dv), jnp.float32, *operands)),
-        (wide, of_chunk(dk, r * dv)),
-        grid=(bsz, groups, nc), state_shape=(dk, r * dv), r=r, dv=dv)
+    o, starts = kernel.sequential_call(
+        _fwd_kernel, "gdn_fwd", operands, in_specs,
+        (out_struct(operands[2].shape, v.dtype, *operands),
+         out_struct((bsz, nc, groups, dk, r * dv), jnp.float32, *operands)),
+        (wide, of_chunk(dk, r * dv)), grid=(bsz, groups, nc),
+        scratch=[(dk, r * dv)],
+        count=functools.partial(_count_call, "fwd"), r=r, dv=dv)
     return o.reshape(v.shape), starts
 
 
@@ -514,19 +469,20 @@ def _backward(q, k, v, cum, beta_h, inverse, starts, do):
         q, k, v, cum, beta_h, inverse, reverse=True)
     qf, kf, vf = operands[:3]
     operands = (*operands, starts, do.astype(v.dtype).reshape(vf.shape))
-    dq, dk_, dv_, dx, dcol, drow, whole = _call(
-        _bwd_kernel, "bwd", operands,
+    dq, dk_, dv_, dx, dcol, drow, whole = kernel.sequential_call(
+        _bwd_kernel, "gdn_bwd", operands,
         [*in_specs, of_chunk(dk, r * dv), wide],
-        (_out_struct(qf.shape, q.dtype, *operands),
-         _out_struct(kf.shape, k.dtype, *operands),
-         _out_struct(vf.shape, v.dtype, *operands),
-         _out_struct(inverse.shape, f32, *operands),
-         _out_struct((bsz, nc, groups, chunk, r), f32, *operands),
-         _out_struct((bsz, nc, groups, 2 * r, chunk), f32, *operands),
-         _out_struct((bsz, nc, groups, 1, r * dv), f32, *operands)),
+        (out_struct(qf.shape, q.dtype, *operands),
+         out_struct(kf.shape, k.dtype, *operands),
+         out_struct(vf.shape, v.dtype, *operands),
+         out_struct(inverse.shape, f32, *operands),
+         out_struct((bsz, nc, groups, chunk, r), f32, *operands),
+         out_struct((bsz, nc, groups, 2 * r, chunk), f32, *operands),
+         out_struct((bsz, nc, groups, 1, r * dv), f32, *operands)),
         (narrow, narrow, wide, of_chunk(r, chunk, chunk), of_chunk(chunk, r),
          of_chunk(2 * r, chunk), of_chunk(1, r * dv)),
-        grid=(bsz, groups, nc), state_shape=(dk, r * dv), r=r, dv=dv)
+        grid=(bsz, groups, nc), scratch=[(dk, r * dv)],
+        count=functools.partial(_count_call, "bwd"), r=r, dv=dv)
 
     # The [B, S, H]-sized rest. A chunk's total is its last running sum.
     whole = jnp.sum(whole.reshape(bsz, nc, groups, r, dv), axis=-1)
@@ -536,30 +492,7 @@ def _backward(q, k, v, cum, beta_h, inverse, starts, do):
             dcum, drow[..., r:, :], dx)
 
 
-@jax.custom_vjp
-def _rule_kernels(q, k, v, cum, beta_h, inverse):
-    """Whole chunks; q and k in v's dtype; cum, beta_h [B, nc, G, R, Q] and
-    inverse [B, nc, G, R, Q, Q] float32."""
-    return _forward(q, k, v, cum, beta_h, inverse)[0]
-
-
-def _rule_fwd(q, k, v, cum, beta_h, inverse):
-    o, starts = _forward(q, k, v, cum, beta_h, inverse)
-    # All that the kernel wrote goes by a name, so that a layer's checkpoint
-    # keeps it and the recomputed forward holds no kernel. The operands are
-    # not named: q, k, v, the running sums and beta come back from the
-    # layer's input by XLA, the inverse by its own name.
-    o = checkpoint_name(o, RESIDUAL_NAMES[1])
-    starts = checkpoint_name(starts, RESIDUAL_NAMES[2])
-    return o, (q, k, v, cum, beta_h, inverse, starts)
-
-
-def _rule_bwd(res, do):
-    # The rule is traced where the program is transposed, outside
-    # `gated_delta_rule`'s scope: under it again, the kernel is
-    # `%gdn_bwd.N` and a reader of the scope finds the whole backward.
-    with jax.named_scope("gdn"):
-        return _backward(*res, do)
-
-
-_rule_kernels.defvjp(_rule_fwd, _rule_bwd)
+# Whole chunks; q and k in v's dtype; cum, beta_h [B, nc, G, R, Q] and
+# inverse [B, nc, G, R, Q, Q] float32. The inverse is kept by its own name.
+_rule_kernels = kernel.kernel_vjp(
+    _forward, _backward, names=RESIDUAL_NAMES[1:], scope="gdn")

@@ -44,7 +44,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128
+from oobleck_tpu.ops import kernel
+from oobleck_tpu.ops.kernel import LANE
+
 MAX_ROW_TILE = 512        # rows of one tile: what an expert's rows pad to
 SUBLANE = 16              # a bfloat16 tile's rows
 MAX_COL_TILE = 512        # columns of the output one grid step produces
@@ -58,12 +60,6 @@ _GMM_PARAMS = pltpu.CompilerParams(
 _TGMM_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
     vmem_limit_bytes=64 * 1024 * 1024)
-
-
-def _pallas_ok() -> bool:
-    from oobleck_tpu.ops import attention
-
-    return attention._pallas_ok()
 
 
 def _col_tile(n: int, limit: int) -> int:
@@ -308,11 +304,7 @@ def _gmm_body(tile_group, lhs_ref, rhs_ref, out_ref, w_scratch, *,
         preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
-def _interpret() -> bool:
-    return not _pallas_ok()
-
-
-def _count_plan_bounded_grid(kernel: str) -> None:
+def _count_plan_bounded_grid(which: str) -> None:
     """`oobleck_moe_plan_bounded_grids_total{kernel}`: counted where the
     kernel is built, once a call traced (not once a step). How many steps
     the grids then run is read every step:
@@ -323,7 +315,7 @@ def _count_plan_bounded_grid(kernel: str) -> None:
         "oobleck_moe_plan_bounded_grids_total",
         "Grouped expert kernels whose grid's row axis ends at the plan's "
         "tiles in use (a dynamic grid bound), built into traced programs"
-    ).inc(kernel=kernel)
+    ).inc(kernel=which)
 
 
 def gmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int,
@@ -346,9 +338,9 @@ def gmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int,
         rhs_block, rhs_of = (None, k, tn), lambda n_, m_, tg: (tg[m_], 0, n_)
     scratch = [pltpu.VMEM(rhs_block[1:], lhs.dtype)] if cast else []
     body = functools.partial(_gmm_body, transpose_rhs=transpose_rhs)
-    kernel = body if cast else (lambda tg, l, r, o: body(tg, l, r, o, None))
+    step = body if cast else (lambda tg, l, r, o: body(tg, l, r, o, None))
     return pl.pallas_call(
-        kernel,
+        step,
         out_shape=jax.ShapeDtypeStruct((m_rows, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -358,7 +350,7 @@ def gmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int,
             out_specs=pl.BlockSpec((tile, tn), lambda n_, m_, tg: (m_, n_)),
             scratch_shapes=scratch),
         compiler_params=_GMM_PARAMS,
-        interpret=_interpret(),
+        interpret=kernel.interpret(),
         name="moe_gmm",
     )(tile_group, lhs, rhs)
 
@@ -438,7 +430,7 @@ def tgmm_call(lhs, rhs, tile_group, num_tiles, *, tile: int, num_groups: int,
         # the grid's bound comes first and the alias reads 5.)
         input_output_aliases={4: 0} if started else {},
         compiler_params=_TGMM_PARAMS,
-        interpret=_interpret(),
+        interpret=kernel.interpret(),
         name="moe_tgmm",
     )(tile_group, num_tiles, lhs, rhs, *started)
 
@@ -462,14 +454,9 @@ def _token_sum_body(run_first, run_len, num_tiles, src_ref, *refs,
 
     The next expert's first chunk is in flight while this one's is added.
 
-    `lax` primitives where `jnp` has a jitted helper (`//`, `%`, `where`,
-    `sum`, `dot`): a helper's jaxpr is cached with the source location of
-    its FIRST trace in the process, a kernel's serialized body carries
-    that location, and the compile cache's key the body. With `//` here a
-    cell's second run compiled `jit_bwd` anew (+ 54 s of `setup_s`, my chip
-    run, PR 52): its first trace came by another call stack than the cold
-    run's. tests/ops/test_routed_experts.py holds every expert kernel's
-    body free of such calls."""
+    `lax` primitives where `jnp` has a jitted helper (`ops/__init__.py` has
+    the rule): with `//` here a cell's second run compiled `jit_bwd` anew
+    (+ 54 s of `setup_s`, my chip run, PR 52)."""
     w_ref = refs[0] if weighted else None
     rows_ref, out_ref, acc, buf, sem = refs[1:] if weighted else refs
     b, n = pl.program_id(0), pl.program_id(1)
@@ -606,7 +593,7 @@ def token_sum_call(rows, plan: RoutingPlan, *, tile: int, weighted: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem),
-        interpret=_interpret(),
+        interpret=kernel.interpret(),
         name="moe_token_sum",
     )(plan.run_first, plan.run_len, plan.num_tiles, *operands)
 
@@ -689,7 +676,7 @@ def grouped_matmul(rows, experts, plan: RoutingPlan, tile: int,
     The Pallas kernels on a TPU; elsewhere XLA's own ragged product over
     the same padded groups (the kernels' interpreter is for their tests).
     `dw_sum`, `experts`' running gradient sum, is the kernels' alone."""
-    if _pallas_ok():
+    if kernel.on_tpu():
         # Under an outer scope a transformation's wrapper (jvp(...),
         # transpose(...)) goes around THAT component of the name stack and
         # the kernels keep their own: `%moe_gmm.N`, not `%jvp_moe_gmm_.N`.
@@ -1125,7 +1112,7 @@ def routed_experts(
         jnp.int32)
     rows, tile = buffer_rows(t, top_k, held, num_experts)
     plan = plan_routing(local, held, rows, tile)
-    if _pallas_ok():
+    if kernel.on_tpu():
         plan = token_runs(plan, local.reshape(t, top_k), weights,
                           choose_token_block(t, top_k, num_experts, tile))
 

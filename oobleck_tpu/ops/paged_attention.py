@@ -43,10 +43,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from oobleck_tpu.ops.flash import _interpret, _out_struct
+from oobleck_tpu.ops import kernel
+from oobleck_tpu.ops.kernel import LANE
 
 NEG_INF = -1e9
-LANE = 128
 
 
 # -- block-table plumbing ------------------------------------------------ #
@@ -278,7 +278,7 @@ def _paged_decode_pallas(
     # k/v blocks arrive [1, page, dp] (head dim collapsed by the block
     # shape's leading 1s — Pallas drops size-1 block dims only when the
     # BlockSpec says so; keep explicit [1, ...] and index [0] in-kernel).
-    kernel = functools.partial(
+    body = functools.partial(
         _paged_kernel, scale=scale, pages=pages, page=page,
         has_slopes=has_slopes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -294,10 +294,10 @@ def _paged_decode_pallas(
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        body,
         grid_spec=grid_spec,
-        out_shape=_out_struct((b, hkv, g, dp), q.dtype, *operands),
-        interpret=_interpret(),
+        out_shape=kernel.out_struct((b, hkv, g, dp), q.dtype, *operands),
+        interpret=kernel.interpret(),
         name="paged_decode",
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *operands)
@@ -402,7 +402,7 @@ def _paged_verify_pallas(
             pl.BlockSpec((1, t * g, 1), lambda bi, h, p, bt, ln: (h, 0, 0)))
         operands.append(slopes)
 
-    kernel = functools.partial(
+    body = functools.partial(
         _paged_verify_kernel, scale=scale, pages=pages, page=page, t=t, g=g,
         has_slopes=has_slopes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -418,10 +418,10 @@ def _paged_verify_pallas(
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        body,
         grid_spec=grid_spec,
-        out_shape=_out_struct((b, hkv, t * g, dp), q.dtype, *operands),
-        interpret=_interpret(),
+        out_shape=kernel.out_struct((b, hkv, t * g, dp), q.dtype, *operands),
+        interpret=kernel.interpret(),
         name="paged_verify",
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *operands)
@@ -441,9 +441,7 @@ def _select_paged_impl(impl: str = "auto"):
         # Same policy as select_attention_impl("auto"): the Pallas kernel
         # on TPU (streamed pages, no HBM gather), the fused XLA gather on
         # CPU where the kernel would run interpreted.
-        from oobleck_tpu.ops.attention import _pallas_ok
-
-        if _pallas_ok():
+        if kernel.on_tpu():
             return _paged_decode_pallas
         return _paged_decode_xla
     raise ValueError(f"unknown paged attention impl: {impl!r}")
@@ -479,9 +477,7 @@ def _select_paged_verify_impl(impl: str = "auto"):
     if impl == "pallas":
         return _paged_verify_pallas
     if impl == "auto":
-        from oobleck_tpu.ops.attention import _pallas_ok
-
-        if _pallas_ok():
+        if kernel.on_tpu():
             return _paged_verify_pallas
         return _paged_verify_xla
     raise ValueError(f"unknown paged attention impl: {impl!r}")

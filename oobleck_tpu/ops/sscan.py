@@ -12,7 +12,7 @@ walked, and what the walk needs is small (C N multiply-adds and
 exponentials a position). What must never exist is the state at every
 position, [L, C, N], in HBM.
 
-Two paths, and the backend decides between them (`attention._pallas_ok`; a
+Two paths, and the backend decides between them (`kernel.on_tpu`; a
 shape the kernels do not tile, `_kernels_take`, is the other reason for the
 second):
 
@@ -32,10 +32,8 @@ second):
              backwards with the state's gradient in a scratch of its own;
              it writes dx, d dt, dB and dC (a tile's part: the tiles are
              summed outside) and sums dA over the chunks in its output
-             block. The forward rule NAMES what `sscan_fwd` wrote
-             (`RESIDUAL_NAMES`), so a layer's checkpoint
-             (`ops/remat.checkpoint_layer`) keeps it and the recomputed
-             forward holds no kernel.
+             block. The rule (`kernel.kernel_vjp`) names what `sscan_fwd`
+             wrote: `RESIDUAL_NAMES`.
   elsewhere  plain `jax.numpy` (`_scan_xla`): a `lax.scan` over chunks
              around an associative scan of one chunk's positions, each
              chunk a `jax.checkpoint`; gradients by JAX's differentiation.
@@ -55,11 +53,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from oobleck_tpu.ops.flash import LANE, SCOPED_VMEM, _interpret, _out_struct
+from oobleck_tpu.ops import kernel
+from oobleck_tpu.ops.kernel import LANE, SCOPED_VMEM, out_struct
 
 # The forward rule's names for what `sscan_fwd` wrote, y and the state at
 # every chunk's start: what only a second kernel call could give back.
@@ -92,7 +89,7 @@ def _count(chunks: int, layer: str | None) -> None:
         "layer").inc(chunks, layer=str(layer))
 
 
-def _count_call(kernel: str) -> None:
+def _count_call(which: str) -> None:
     """`oobleck_sscan_calls_total{kernel}`: where a kernel is built into a
     traced program (not once a step). A scan on the `jax.numpy` path counts
     none."""
@@ -101,7 +98,7 @@ def _count_call(kernel: str) -> None:
     metrics.registry().counter(
         "oobleck_sscan_calls_total",
         "Pallas kernels of the selective scan built into traced programs, "
-        "by kernel (fwd, bwd)").inc(kernel=kernel)
+        "by kernel (fwd, bwd)").inc(kernel=which)
 
 
 def _tile(channels: int, most: int) -> int:
@@ -122,8 +119,6 @@ def selective_scan(x: jax.Array, dt: jax.Array, a_neg: jax.Array,
                    chunk: int = CHUNK, layer: str | None = None) -> jax.Array:
     """x, dt [B, L, C] (dt after its softplus); a_neg [C, N] (A, negative);
     b, c [B, L, N]; d_skip [C]. Returns y [B, L, C] in x's dtype."""
-    from oobleck_tpu.ops.attention import _pallas_ok
-
     seq, channels = x.shape[1:]
     n = a_neg.shape[1]
     nc = -(-seq // chunk)
@@ -135,15 +130,7 @@ def selective_scan(x: jax.Array, dt: jax.Array, a_neg: jax.Array,
     f32 = jnp.float32
     dt, a_neg, b, c, d_skip = (t.astype(f32)
                                for t in (dt, a_neg, b, c, d_skip))
-    if _pallas_ok() and _kernels_take(chunk, channels, n):
-        # A, D (parameters) vary over fewer mesh axes than the activations
-        # inside a `check_vma=True` shard_map; a `custom_vjp` hands each
-        # operand a gradient that varies as the operand does, so they are
-        # cast to vary as `x` does here, outside the rule (`ops/ssd.py`).
-        from oobleck_tpu.parallel.collectives import pvary_to
-
-        dt, a_neg, b, c, d_skip = pvary_to(
-            (dt, a_neg, b, c, d_skip), tuple(jax.typeof(x).vma))
+    if kernel.on_tpu() and _kernels_take(chunk, channels, n):
         return _scan_kernels(x, dt, a_neg, b, c, d_skip, chunk)[:, :seq]
     return _scan_xla(x, dt, a_neg, b, c, d_skip, chunk)[:, :seq]
 
@@ -196,9 +183,7 @@ def _scan_xla(x, dt, a_neg, b, c, d_skip, chunk: int):
 #   the state at z's start      [N, W]        of [B, nc, N, C]
 #
 # Inside the bodies `lax.select` and `lax.broadcast_in_dim`, never
-# `jnp.where`: a jitted helper's jaxpr carries the source location of its
-# first trace into the kernel's serialized body and with it into the
-# compile cache's key (`ops/ssd.py`).
+# `jnp.where`: `ops/__init__.py` has the rule.
 
 def _rows_of(row, u: int, tile):
     """`tile` [U, W] with row `u` replaced by `row` [1, W]."""
@@ -336,38 +321,6 @@ def _bwd_kernel(z, x_ref, dt_ref, dy_ref, b_ref, c_ref, a_ref, skip_ref,
     ddt_ref[...] = ddts[...] + du * x
 
 
-def _call(body, kernel: str, operands, in_specs, out_shape, out_specs, *,
-          grid, scratch, vmem_limit_bytes: int | None = None):
-    """One `pallas_call` on the grid (batch, channel tile, chunk), the chunk
-    axis sequential, its float32 scratches living across it. Under the
-    interpreter the step runs inside a branch that is always taken, for the
-    `check_vma=True` shard_maps (`ops/flash._call`'s docstring)."""
-    interpret = _interpret()
-    _count_call(kernel)
-
-    def step(*refs):
-        z = pl.program_id(2)
-        chunk = functools.partial(body, z, *refs)
-        if interpret:
-            pl.when(z >= 0)(chunk)
-        else:
-            chunk()
-
-    return pl.pallas_call(
-        step,
-        out_shape=out_shape,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=grid,
-            in_specs=in_specs, out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=vmem_limit_bytes),
-        interpret=interpret,
-        name=f"sscan_{kernel}",
-    )(*operands)
-
-
 def _operands(x, dt, a_neg, b, c, d_skip, chunk: int, tile: int,
               reverse: bool):
     """What both kernels read, with its block specs. Returns (operands,
@@ -397,15 +350,16 @@ def _forward(x, dt, a_neg, b, c, d_skip, chunk: int):
     tile = _tile(channels, FWD_TILE)
     operands, in_specs, wide, at = _operands(
         x, dt, a_neg, b, c, d_skip, chunk, tile, reverse=False)
-    return _call(
-        _fwd_kernel, "fwd", operands, in_specs,
-        (_out_struct(x.shape, x.dtype, *operands),
-         _out_struct((bsz, seq // chunk, n, channels), jnp.float32,
-                     *operands)),
+    return kernel.sequential_call(
+        _fwd_kernel, "sscan_fwd", operands, in_specs,
+        (out_struct(x.shape, x.dtype, *operands),
+         out_struct((bsz, seq // chunk, n, channels), jnp.float32,
+                    *operands)),
         (wide, pl.BlockSpec((None, None, n, tile),
                             lambda i, j, z: (i, at(z), 0, j))),
         grid=(bsz, channels // tile, seq // chunk),
-        scratch=[(n, tile), (chunk, tile), (chunk, tile)])
+        scratch=[(n, tile), (chunk, tile), (chunk, tile)],
+        count=functools.partial(_count_call, "fwd"))
 
 
 def _backward(x, dt, a_neg, b, c, d_skip, starts, dy, chunk: int):
@@ -419,21 +373,21 @@ def _backward(x, dt, a_neg, b, c, d_skip, starts, dy, chunk: int):
     operands = (*operands[:2], dy.astype(x.dtype), *operands[2:], starts)
     part = pl.BlockSpec((None, None, chunk // UNROLL, n, UNROLL),
                         lambda i, j, z: (i, j, at(z), 0, 0))
-    parts = _out_struct((bsz, tiles, seq // UNROLL, n, UNROLL), f32,
-                        *operands)
+    parts = out_struct((bsz, tiles, seq // UNROLL, n, UNROLL), f32, *operands)
     scratch = [(n, tile), (n, tile), (chunk, tile), (chunk, tile),
                (chunk, tile), (chunk, tile), (chunk + 1, n, tile)]
-    dx, ddt, db, dc, da = _call(
-        _bwd_kernel, "bwd", operands,
+    dx, ddt, db, dc, da = kernel.sequential_call(
+        _bwd_kernel, "sscan_bwd", operands,
         [*in_specs[:2], wide, *in_specs[2:],
          pl.BlockSpec((None, None, n, tile),
                       lambda i, j, z: (i, at(z), 0, j))],
-        (_out_struct(x.shape, x.dtype, *operands),
-         _out_struct(x.shape, f32, *operands), parts, parts,
-         _out_struct((bsz, n, channels), f32, *operands)),
+        (out_struct(x.shape, x.dtype, *operands),
+         out_struct(x.shape, f32, *operands), parts, parts,
+         out_struct((bsz, n, channels), f32, *operands)),
         (wide, wide, part, part,
          pl.BlockSpec((None, n, tile), lambda i, j, z: (i, 0, j))),
         grid=(bsz, tiles, seq // chunk), scratch=scratch,
+        count=functools.partial(_count_call, "bwd"),
         # The chunk's states stay in VMEM while its positions are walked
         # back: the kernel asks for its scratches beside the default scoped
         # limit, which is left to the blocks and the values in flight (at a
@@ -449,28 +403,7 @@ def _backward(x, dt, a_neg, b, c, d_skip, starts, dy, chunk: int):
             d_skip_grad)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _scan_kernels(x, dt, a_neg, b, c, d_skip, chunk: int):
-    """Whole chunks; everything but `x` float32."""
-    return _forward(x, dt, a_neg, b, c, d_skip, chunk)[0]
-
-
-def _scan_fwd(x, dt, a_neg, b, c, d_skip, chunk):
-    y, starts = _forward(x, dt, a_neg, b, c, d_skip, chunk)
-    # All that the kernel wrote goes by a name, so that a layer's checkpoint
-    # keeps it and the recomputed forward holds no kernel. The operands are
-    # not named: they come back from the layer's input by XLA.
-    y = checkpoint_name(y, RESIDUAL_NAMES[0])
-    starts = checkpoint_name(starts, RESIDUAL_NAMES[1])
-    return y, (x, dt, a_neg, b, c, d_skip, starts)
-
-
-def _scan_bwd(chunk, res, dy):
-    # The rule is traced where the program is transposed, outside
-    # `selective_scan`'s scope: under it again, a reader of the scope finds
-    # the whole backward.
-    with jax.named_scope("sscan"):
-        return _backward(*res, dy, chunk)
-
-
-_scan_kernels.defvjp(_scan_fwd, _scan_bwd)
+# Whole chunks; everything but `x` float32.
+_scan_kernels = kernel.kernel_vjp(
+    _forward, _backward, names=RESIDUAL_NAMES, scope="sscan",
+    nondiff_argnums=(6,))

@@ -16,7 +16,7 @@ The sequence is not walked step by step. With chunks of `Q` positions,
 
 and `y = Y_diag + Y_off + D x`.
 
-Two paths, and the backend decides between them (`attention._pallas_ok`; a
+Two paths, and the backend decides between them (`kernel.on_tpu`; a
 shape the kernels do not tile, `_kernels_take`, is the other reason for
 the second):
 
@@ -40,10 +40,9 @@ the second):
              running sums' gradient, which needs no [Q, Q] block:
              dcum_j = sum_p (dY y - D dY x - dx~ x~)_jp, plus, at a chunk's
              last position, the chunk total's (the kernel sums it from the
-             products its positions read: the two cancel). The
-             forward rule NAMES what the kernel wrote (`RESIDUAL_NAMES`), so
-             a layer's checkpoint (`ops/remat.checkpoint_layer`) keeps it
-             and the recomputed forward holds no kernel.
+             products its positions read: the two cancel). The rule
+             (`kernel.kernel_vjp`) names what `ssd_fwd` wrote:
+             `RESIDUAL_NAMES`.
   elsewhere  plain `jax.numpy` (`_scan_xla`): batched `einsum`s, gradients
              by JAX's differentiation of them, `L` written out. The CPU's
              path, and what the kernels are tested against.
@@ -64,11 +63,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from oobleck_tpu.ops.flash import LANE, _interpret, _out_struct
+from oobleck_tpu.ops import kernel
+from oobleck_tpu.ops.kernel import LANE, decays, lower, nn, nt, out_struct, tn
 
 # The forward rule's names for what `ssd_fwd` wrote, y and the state at
 # every chunk's start: what only a second kernel call could give back.
@@ -96,7 +94,7 @@ def _count(chunks: int, layer: str | None) -> None:
         "into, by layer").set(chunks, layer=str(layer))
 
 
-def _count_call(kernel: str) -> None:
+def _count_call(which: str) -> None:
     """`oobleck_ssd_kernel_calls_total{kernel}`: where a kernel is built
     into a traced program (not once a step). A scan on the `jax.numpy` path
     counts none."""
@@ -105,7 +103,7 @@ def _count_call(kernel: str) -> None:
     metrics.registry().counter(
         "oobleck_ssd_kernel_calls_total",
         "Pallas kernels of the state-space scan built into traced "
-        "programs, by kernel (fwd, bwd)").inc(kernel=kernel)
+        "programs, by kernel (fwd, bwd)").inc(kernel=which)
 
 
 def _kernels_take(chunk: int, r: int, p: int, n: int) -> bool:
@@ -124,8 +122,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a_neg: jax.Array, b: jax.Array,
     negative scalar a head); b, c [B, S, G, N] with G dividing H (head h
     reads group h // (H / G)); d_skip [H]. Returns y [B, S, H, P] in x's
     dtype."""
-    from oobleck_tpu.ops.attention import _pallas_ok
-
     seq, heads, p = x.shape[1:]
     groups, n = b.shape[2], b.shape[3]
     assert heads % groups == 0, (heads, groups)
@@ -136,16 +132,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a_neg: jax.Array, b: jax.Array,
         rows = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
         x, dt, b, c = rows(x), rows(dt), rows(b), rows(c)
     dt = dt.astype(jnp.float32)
-    if _pallas_ok() and _kernels_take(chunk, heads // groups, p, n):
-        # Inside a `check_vma=True` shard_map `A` and `D`, parameters, vary
-        # over fewer mesh axes than the activations, and a `custom_vjp` must
-        # hand each operand a gradient that varies as the operand does. So
-        # they are cast to vary as `x` does HERE, outside the rule: the
-        # cast's own transpose is the sum over those axes.
-        from oobleck_tpu.parallel.collectives import pvary_to
-
-        dt, a_neg, b, c, d_skip = pvary_to(
-            (dt, a_neg, b, c, d_skip), tuple(jax.typeof(x).vma))
+    if kernel.on_tpu() and _kernels_take(chunk, heads // groups, p, n):
         return _scan_kernels(x, dt, a_neg, b, c, d_skip, chunk)[:, :seq]
     return _scan_xla(x, dt, a_neg, b, c, d_skip, chunk)[:, :seq]
 
@@ -227,21 +214,6 @@ def _scan_xla(x, dt, a_neg, b, c, d_skip, chunk: int):
 # and each keeps its lanes of the result (or, where the lanes are summed
 # over, zeroes the other's lanes of an operand first).
 
-def _nn(x, y):
-    return lax.dot_general(x, y, (((1,), (0,)), ((), ())),
-                           preferred_element_type=jnp.float32)
-
-
-def _nt(x, y):
-    return lax.dot_general(x, y, (((1,), (1,)), ((), ())),
-                           preferred_element_type=jnp.float32)
-
-
-def _tn(x, y):
-    return lax.dot_general(x, y, (((0,), (0,)), ((), ())),
-                           preferred_element_type=jnp.float32)
-
-
 def _lane_tiles(r: int, p: int):
     """(lanes, heads) of every 128-lane tile of a group's [Q, R P]."""
     per = LANE // p
@@ -250,10 +222,8 @@ def _lane_tiles(r: int, p: int):
 
 
 # Inside the kernels' bodies `lax.select`, never `jnp.where`, and a grid
-# axis a thing the index maps would divide by (no `//`, no `%`): a jitted
-# helper's jaxpr carries the source location of its first trace in the
-# process into the kernel's serialized body, and with it into the compile
-# cache's key (`ops/moe.py`'s kernels, PR 52).
+# axis a thing the index maps would divide by (no `//`, no `%`):
+# `ops/__init__.py` has the rule.
 
 def _by_head(parts, p: int):
     """One [rows, 128] tile from its heads' `parts` (each [rows, 128] or
@@ -279,18 +249,6 @@ def _only_head(tile, t: int, p: int):
     return lax.select(mine, wide, jnp.zeros_like(wide)).astype(tile.dtype)
 
 
-def _decays(col_ref, row_ref, head: int, lower):
-    """`L` of one head, [Q, Q] float32, from the difference of the running
-    sums under the mask."""
-    diff = col_ref[:, head:head + 1] - row_ref[head:head + 1, :]
-    return jnp.exp(lax.select(lower, diff, jnp.full_like(diff, -jnp.inf)))
-
-
-def _lower(q: int):
-    return (lax.broadcasted_iota(jnp.int32, (q, q), 0)
-            >= lax.broadcasted_iota(jnp.int32, (q, q), 1))
-
-
 def _chunk_scalars(col_ref, r: int):
     """From a chunk's [Q, 2 R] block of per-position float32 values (the
     running sums, then dt; a head a lane): exp(cum), exp(cum_Q - cum), dt,
@@ -312,21 +270,21 @@ def _fwd_kernel(z, x_ref, b_ref, c_ref, col_ref, row_ref, skip_ref,
 
     start_ref[...] = state[...]
     bm, cm = b_ref[...], c_ref[...]
-    cb = _nt(cm, bm)                                       # [Q, Q]
-    lower = _lower(q)
+    cb = nt(cm, bm)                                       # [Q, Q]
+    mask = lower(q)
     from_start, to_end, dt = _chunk_scalars(col_ref, r)
-    y_off = _nn(cm, state[...].astype(dtype))              # C H^T  [Q, W]
+    y_off = nn(cm, state[...].astype(dtype))              # C H^T  [Q, W]
     for lanes, heads in _lane_tiles(r, p):
         spread = lambda cols: _by_head([cols[:, h:h + 1] for h in heads], p)
         x = x_ref[:, lanes].astype(f32)
         xt = (x * spread(dt)).astype(dtype)
         y_diag = _by_head(
-            [_nn((cb * _decays(col_ref, row_ref, h, lower)).astype(dtype), xt)
+            [nn((cb * decays(col_ref, row_ref, h, mask)).astype(dtype), xt)
              for h in heads], p)
         since = spread(from_start)
         y_ref[:, lanes] = (y_diag + since * y_off[:, lanes]
                            + skip_ref[:, lanes] * x).astype(y_ref.dtype)
-        state[:, lanes] = since[q - 1:q, :] * state[:, lanes] + _tn(
+        state[:, lanes] = since[q - 1:q, :] * state[:, lanes] + tn(
             bm, (xt.astype(f32) * spread(to_end)).astype(dtype))
 
 
@@ -346,7 +304,7 @@ def _position_sums(tile, k: int, p: int, *, parts: int, at: int = 0):
     sums = jnp.zeros(tile.shape, f32)
     for _ in range(parts):
         part = tile.astype(bf16)
-        sums = sums + _nn(part, pick)
+        sums = sums + nn(part, pick)
         tile = tile - part.astype(f32)
     return sums
 
@@ -373,11 +331,11 @@ def _bwd_kernel(z, x_ref, dy_ref, b_ref, c_ref, col_ref, row_ref, skip_ref,
         dstate[...] = jnp.zeros_like(dstate)
 
     bm, cm = b_ref[...], c_ref[...]
-    cb = _nt(cm, bm)
-    lower = _lower(q)
+    cb = nt(cm, bm)
+    mask = lower(q)
     from_start, to_end, dt = _chunk_scalars(col_ref, r)
-    y_off = _nn(cm, start_ref[...].astype(dtype))          # C H^T   [Q, W]
-    d_added = _nn(bm, dstate[...].astype(dtype))           # B dH^T  [Q, W]
+    y_off = nn(cm, start_ref[...].astype(dtype))          # C H^T   [Q, W]
+    d_added = nn(bm, dstate[...].astype(dtype))           # B dH^T  [Q, W]
     ds = jnp.zeros((q, q), f32)
     dc = jnp.zeros(dc_ref.shape, f32)
     db = jnp.zeros(db_ref.shape, f32)
@@ -390,20 +348,20 @@ def _bwd_kernel(z, x_ref, dy_ref, b_ref, c_ref, col_ref, row_ref, skip_ref,
         xtf = xt.astype(f32)
         y_diag, dxt_diag, y_low, dxt_low = [], [], [], []
         for t, h in enumerate(heads):
-            decay = _decays(col_ref, row_ref, h, lower)
+            decay = decays(col_ref, row_ref, h, mask)
             m = cb * decay
             m_high = m.astype(dtype)
-            y_diag.append(_nn(m_high, xt))
-            dxt_diag.append(_tn(m_high, dy))
-            ds = ds + _nt(_only_head(dy, t, p), xt) * decay
+            y_diag.append(nn(m_high, xt))
+            dxt_diag.append(tn(m_high, dy))
+            ds = ds + nt(_only_head(dy, t, p), xt) * decay
             if dtype != f32:
                 # What rounding M dropped, for the running sums' gradient
                 # alone: it is sum_j dM_ij M_ij of the float32 M, less the
                 # same over i, and a product with the rounded M would be
                 # off by M's rounding in every term.
                 m_low = (m - m_high.astype(f32)).astype(dtype)
-                y_low.append(_nn(m_low, xt))
-                dxt_low.append(_tn(m_low, dy))
+                y_low.append(nn(m_low, xt))
+                dxt_low.append(tn(m_low, dy))
         dyf = dy.astype(f32)
         added = until * d_added[:, lanes]
         dxt = _by_head(dxt_diag, p) + added
@@ -420,9 +378,9 @@ def _bwd_kernel(z, x_ref, dy_ref, b_ref, c_ref, col_ref, row_ref, skip_ref,
         x_until = (xtf * until).astype(dtype)
         start = start_ref[:, lanes]
         d_end = dstate[:, lanes]
-        dc = dc + _nt(dy_since, start.astype(dtype))
-        db = db + _nt(x_until, d_end.astype(dtype))
-        dstate[:, lanes] = since[q - 1:q, :] * d_end + _tn(cm, dy_since)
+        dc = dc + nt(dy_since, start.astype(dtype))
+        db = db + nt(x_until, d_end.astype(dtype))
+        dstate[:, lanes] = since[q - 1:q, :] * d_end + tn(cm, dy_since)
         # The chunk total's gradient, from the very products the positions
         # took theirs from (`added`, inside dx~): in exact arithmetic
         # <H, dH> of the state the chunk ends in, but a float32 <H, dH>
@@ -433,51 +391,9 @@ def _bwd_kernel(z, x_ref, dy_ref, b_ref, c_ref, col_ref, row_ref, skip_ref,
             + jnp.sum(xtf * added, axis=0, keepdims=True))
         whole_ref[1:2, lanes] = jnp.sum(dyf * x, axis=0, keepdims=True)
     ds = ds.astype(dtype)
-    dc_ref[...] = (dc + _nn(ds, bm)).astype(dc_ref.dtype)
-    db_ref[...] = (db + _tn(ds, cm)).astype(db_ref.dtype)
+    dc_ref[...] = (dc + nn(ds, bm)).astype(dc_ref.dtype)
+    db_ref[...] = (db + tn(ds, cm)).astype(db_ref.dtype)
     sums_ref[...] = sums[:, :2 * r]
-
-
-def _call(body, kernel: str, operands, in_specs, out_shape, out_specs, *,
-          grid, state_shape, r: int, p: int):
-    """One `pallas_call` on the grid (batch, group, chunk), the chunk axis
-    sequential, with one float32 scratch that lives across it. Under the
-    interpreter the step runs inside a branch that is always taken, for the
-    `check_vma=True` shard_maps (`ops/flash._call`'s docstring)."""
-    interpret = _interpret()
-    _count_call(kernel)
-
-    def step(*refs):
-        z = pl.program_id(2)
-        chunk = functools.partial(body, z, *refs, r=r, p=p)
-        if interpret:
-            pl.when(z >= 0)(chunk)
-        else:
-            chunk()
-
-    return pl.pallas_call(
-        step,
-        out_shape=out_shape,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=grid,
-            in_specs=in_specs, out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM(state_shape, jnp.float32)]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name=f"ssd_{kernel}",
-    )(*operands)
-
-
-def _running_sums(t, reverse: bool = False):
-    """The running sum along axis 2 (a chunk's positions) of a float32
-    [B, nc, Q, G, R], from the last position back if `reverse`: a product
-    with the [Q, Q] triangle of ones at float32's own precision. (XLA's
-    `cumsum` of this shape is a `reduce_window` of 0.41 ms on a v5e, three
-    a scan and more than both kernels: my chip run, PR 54.)"""
-    ones = _lower(t.shape[2]).astype(jnp.float32)
-    return jnp.einsum("ji,bzjgr->bzigr" if reverse else "ij,bzjgr->bzigr",
-                      ones, t, precision=lax.Precision.HIGHEST)
 
 
 def _operands(x, dt, a_neg, b, c, d_skip, chunk: int, reverse: bool):
@@ -495,7 +411,7 @@ def _operands(x, dt, a_neg, b, c, d_skip, chunk: int, reverse: bool):
     w = r * p
     a = dt * a_neg.astype(jnp.float32)
     per_chunk = lambda t: t.reshape(bsz, nc, chunk, groups, r)
-    cum = _running_sums(per_chunk(a))
+    cum = kernel.running_sums(per_chunk(a), axis=2)
     cols = jnp.transpose(jnp.concatenate([cum, per_chunk(dt)], axis=-1),
                          (0, 1, 3, 2, 4))
     rows = jnp.transpose(cum, (0, 1, 3, 4, 2))
@@ -521,12 +437,13 @@ def _forward(x, dt, a_neg, b, c, d_skip, chunk: int):
     r, nc = heads // groups, seq // chunk
     operands, in_specs, wide, _, of_chunk = _operands(
         x, dt, a_neg, b, c, d_skip, chunk, reverse=False)
-    y, starts = _call(
-        _fwd_kernel, "fwd", operands, in_specs,
-        (_out_struct((bsz, seq, heads * p), x.dtype, *operands),
-         _out_struct((bsz, nc, groups, n, r * p), jnp.float32, *operands)),
-        (wide, of_chunk(n, r * p)),
-        grid=(bsz, groups, nc), state_shape=(n, r * p), r=r, p=p)
+    y, starts = kernel.sequential_call(
+        _fwd_kernel, "ssd_fwd", operands, in_specs,
+        (out_struct((bsz, seq, heads * p), x.dtype, *operands),
+         out_struct((bsz, nc, groups, n, r * p), jnp.float32, *operands)),
+        (wide, of_chunk(n, r * p)), grid=(bsz, groups, nc),
+        scratch=[(n, r * p)],
+        count=functools.partial(_count_call, "fwd"), r=r, p=p)
     return y.reshape(x.shape), starts
 
 
@@ -540,22 +457,24 @@ def _backward(x, dt, a_neg, b, c, d_skip, starts, dy, chunk: int):
     xf, bf, cf = operands[:3]
     operands = (xf, dy.astype(x.dtype).reshape(xf.shape), *operands[1:],
                 starts)
-    dx, db, dc, sums, whole = _call(
-        _bwd_kernel, "bwd", operands,
+    dx, db, dc, sums, whole = kernel.sequential_call(
+        _bwd_kernel, "ssd_bwd", operands,
         [wide, wide, *in_specs[1:], of_chunk(n, r * p)],
-        (_out_struct(xf.shape, x.dtype, *operands),
-         _out_struct(bf.shape, b.dtype, *operands),
-         _out_struct(cf.shape, c.dtype, *operands),
-         _out_struct((bsz, nc, groups, chunk, 2 * r), f32, *operands),
-         _out_struct((bsz, nc, groups, 2, r * p), f32, *operands)),
+        (out_struct(xf.shape, x.dtype, *operands),
+         out_struct(bf.shape, b.dtype, *operands),
+         out_struct(cf.shape, c.dtype, *operands),
+         out_struct((bsz, nc, groups, chunk, 2 * r), f32, *operands),
+         out_struct((bsz, nc, groups, 2, r * p), f32, *operands)),
         (wide, narrow, narrow, of_chunk(chunk, 2 * r), of_chunk(2, r * p)),
-        grid=(bsz, groups, nc), state_shape=(n, r * p), r=r, p=p)
+        grid=(bsz, groups, nc), scratch=[(n, r * p)],
+        count=functools.partial(_count_call, "bwd"), r=r, p=p)
 
     # The [B, S, H]-sized rest. A chunk's total is its last running sum.
     whole = jnp.sum(whole.reshape(bsz, nc, groups, 2, r, p), axis=-1)
     sums = jnp.swapaxes(sums, 2, 3)                        # [B, nc, Q, G, 2 R]
     dcum = sums[..., :r].at[:, :, -1].add(whole[:, :, :, 0])
-    da = _running_sums(dcum, reverse=True).reshape(bsz, seq, heads)
+    da = kernel.running_sums(dcum, axis=2, reverse=True).reshape(
+        bsz, seq, heads)
     d_dt = sums[..., r:].reshape(bsz, seq, heads) + da * a_neg.astype(f32)
     return (dx.reshape(x.shape), d_dt,
             jnp.sum(da * dt, axis=(0, 1)).astype(a_neg.dtype),
@@ -564,28 +483,7 @@ def _backward(x, dt, a_neg, b, c, d_skip, starts, dy, chunk: int):
                 d_skip.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _scan_kernels(x, dt, a_neg, b, c, d_skip, chunk: int):
-    """Whole chunks, `dt` in float32."""
-    return _forward(x, dt, a_neg, b, c, d_skip, chunk)[0]
-
-
-def _scan_fwd(x, dt, a_neg, b, c, d_skip, chunk):
-    y, starts = _forward(x, dt, a_neg, b, c, d_skip, chunk)
-    # All that the kernel wrote goes by a name, so that a layer's checkpoint
-    # keeps it and the recomputed forward holds no kernel. The operands are
-    # not named: they come back from the layer's input by XLA.
-    y = checkpoint_name(y, RESIDUAL_NAMES[0])
-    starts = checkpoint_name(starts, RESIDUAL_NAMES[1])
-    return y, (x, dt, a_neg, b, c, d_skip, starts)
-
-
-def _scan_bwd(chunk, res, dy):
-    # The rule is traced where the program is transposed, outside
-    # `ssd_scan`'s scope: under it again, the kernel is `%ssd_bwd.N` and
-    # a reader of the scope finds the whole backward.
-    with jax.named_scope("ssd"):
-        return _backward(*res, dy, chunk)
-
-
-_scan_kernels.defvjp(_scan_fwd, _scan_bwd)
+# Whole chunks, `dt` in float32.
+_scan_kernels = kernel.kernel_vjp(
+    _forward, _backward, names=RESIDUAL_NAMES, scope="ssd",
+    nondiff_argnums=(6,))

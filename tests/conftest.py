@@ -44,6 +44,7 @@ jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 
 from oobleck_tpu.execution.pipeline import PROGRAMS
+from oobleck_tpu.ops import attention, kernel, paged_attention
 from oobleck_tpu.utils.compile_cache import ensure_persistent_cache
 
 assert ensure_persistent_cache() is None  # CPU backend: switched off
@@ -130,6 +131,35 @@ def _empty_flight_ring():
 
     metrics.flight_recorder().clear()
     yield
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Every kernel module takes its kernels' path, compiled, as on a TPU:
+    for a trace nothing runs of, or a compile for a described chip. ONE
+    name decides for all of them (`ops/kernel.on_tpu`). What a process
+    keeps of the decision (the "auto" choices, resolved once; the stage
+    programs, whose key leaves the backend out) is forgotten before and
+    after: no test is handed, or hands on, a choice made under the other
+    answer."""
+    def forget():
+        for choice in (attention.select_attention_impl,
+                       paged_attention._select_paged_impl,
+                       paged_attention._select_paged_verify_impl):
+            choice.cache_clear()
+        PROGRAMS.clear()
+
+    monkeypatch.setattr(kernel, "on_tpu", lambda: True)
+    forget()
+    yield
+    forget()
+
+
+@pytest.fixture
+def kernels_interpreted(as_on_tpu, monkeypatch):
+    """The kernels' path as on a TPU, in Pallas's interpreter: the kernels'
+    arithmetic, on the CPU."""
+    monkeypatch.setattr(kernel, "interpret", lambda: True)
 
 
 @pytest.fixture(scope="session")

@@ -15,9 +15,8 @@ import jax
 import numpy as np
 import pytest
 
-from oobleck_tpu.execution.pipeline import PROGRAMS, PipelineInstance
+from oobleck_tpu.execution.pipeline import PipelineInstance
 from oobleck_tpu.models import build_model
-from oobleck_tpu.ops import moe
 from oobleck_tpu.planning.templates import PipelineTemplate, StageSpec
 from oobleck_tpu.utils import metrics
 
@@ -25,18 +24,6 @@ MB, SEQ, NUM_MB = 2, 32, 2
 # model -> leaves its one stage sums in the kernel: three routed blocks of
 # SwiGLU experts (w1, w3, w2), two of experts without a gate (w1, w2).
 ROUTED = {"lfm2-moe-tiny": 3 * 3, "nemotron-h-tiny": 2 * 2}
-
-
-@pytest.fixture
-def interpreted(monkeypatch):
-    """The backend is one of the few things a stage program's trace reads
-    and its key leaves out (a process has one): a test that swaps it starts
-    from, and leaves, an empty table of programs."""
-    PROGRAMS.clear()
-    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(moe, "_interpret", lambda: True)
-    yield
-    PROGRAMS.clear()
 
 
 def _train_step(name, *, chips=1, marks=True):
@@ -64,7 +51,7 @@ def _train_step(name, *, chips=1, marks=True):
 
 
 @pytest.mark.parametrize("name", sorted(ROUTED))
-def test_sums_taken_in_the_kernel_equal_the_plain_add(interpreted, name):
+def test_sums_taken_in_the_kernel_equal_the_plain_add(kernels_interpreted, name):
     st, got, counted = _train_step(name)
     assert st.kernel_sums == [ROUTED[name]]
     assert counted == ROUTED[name] * NUM_MB
@@ -78,7 +65,7 @@ def test_sums_taken_in_the_kernel_equal_the_plain_add(interpreted, name):
     assert any(np.abs(w).max() > 0 for w in want)
 
 
-def test_a_stage_that_splits_the_microbatch_keeps_the_plain_add(interpreted):
+def test_a_stage_that_splits_the_microbatch_keeps_the_plain_add(kernels_interpreted):
     st, _, counted = _train_step("lfm2-moe-tiny", chips=2)
     assert st.use_fsdp and st.mesh.size == 2      # gradients reduce over it
     assert st.kernel_sums == [0] and counted == 0

@@ -20,9 +20,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs: not /tmp
 import jax
 import pytest
 from jax.experimental import topologies
-from jax.experimental.compilation_cache import compilation_cache
 
-from oobleck_tpu.ops import attention, flash
+from oobleck_tpu.ops import flash
 from tests.ops.programs import cell_stage
 
 
@@ -114,21 +113,6 @@ def v5e():
     except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
         pytest.skip(f"cannot describe a v5e topology here: {e}")
     return topo.devices
-
-
-@pytest.fixture
-def compiled_for_tpu(monkeypatch):
-    """As `test_tpu_compile.py`'s: kernels lower through Mosaic, "auto"
-    resolves again, and the persistent cache stays out of it."""
-    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
-    attention.select_attention_impl.cache_clear()
-    before = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    compilation_cache.reset_cache()
-    attention.select_attention_impl.cache_clear()
 
 
 # `gpt3-2.7b`'s executable has two readers (`test_remat_cells_a.py`) and

@@ -25,6 +25,13 @@ def all_eqns(jaxpr):
                     yield from all_eqns(inner)
 
 
+def kernel_calls(fn, *args):
+    """The `pallas_call` equations of `fn`'s jaxpr at `args`, in the
+    program's order. Traced only: nothing runs."""
+    return [e for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
 def checkpoint_keeping(*names):
     """`jax.checkpoint` with the policy that keeps values by `names` and
     nothing else: what `ops/remat.checkpoint_layer` is for its own list."""

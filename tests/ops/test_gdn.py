@@ -30,9 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oobleck_tpu.ops import attention, flash, gdn, remat
+from oobleck_tpu.ops import flash, gdn, kernel, remat
 from oobleck_tpu.ops.gdn import gated_delta_rule, unit_lower_inverse
-from tests.ops.programs import all_eqns, checkpoint_keeping
+from tests.ops.programs import all_eqns, checkpoint_keeping, kernel_calls
 
 # (length, chunk, value heads, key heads)
 CASES = {
@@ -341,14 +341,6 @@ KERNEL_CASES = {
 KQ, KD = 64, 128
 
 
-@pytest.fixture
-def kernels(monkeypatch):
-    """`gated_delta_rule` takes the kernels' path as on a TPU,
-    interpreted."""
-    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(gdn, "_interpret", lambda: True)
-
-
 def kernel_operands(case, *, seed=0, dtype=jnp.float32):
     bsz, length, heads, groups = KERNEL_CASES[case]
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
@@ -364,12 +356,12 @@ def kernel_operands(case, *, seed=0, dtype=jnp.float32):
 def numpy_path(*args, chunk=KQ):
     """`gated_delta_rule` as the CPU runs it, whatever the fixture says."""
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(attention, "_pallas_ok", lambda: False)
+        patch.setattr(kernel, "on_tpu", lambda: False)
         return gated_delta_rule(*args, chunk=chunk)
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
-def test_the_forward_kernel_is_the_numpy_path(kernels, case):
+def test_the_forward_kernel_is_the_numpy_path(kernels_interpreted, case):
     args = kernel_operands(case)
     got = rule(*args, chunk=KQ)
     want = jax.jit(numpy_path)(*args)
@@ -394,7 +386,7 @@ def _kernel_gradients(case):
 
 @pytest.mark.parametrize("wrt", range(5), ids=ARGS)
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
-def test_every_gradient_of_the_kernels_is_the_numpy_paths(kernels, case, wrt):
+def test_every_gradient_of_the_kernels_is_the_numpy_paths(kernels_interpreted, case, wrt):
     got, want = (g[wrt] for g in _kernel_gradients(case))
     assert got.shape == want.shape and got.dtype == want.dtype
     scale = float(jnp.max(jnp.abs(want)))
@@ -405,7 +397,7 @@ def test_every_gradient_of_the_kernels_is_the_numpy_paths(kernels, case, wrt):
 @pytest.mark.parametrize("decay_a_chunk", [20.0, 2000.0],
                          ids=["e-20", "e-2000"])
 def test_both_kernels_have_no_inf_and_no_nan_in_a_chunk_that_decays_to_nothing(
-        kernels, decay_a_chunk):
+        kernels_interpreted, decay_a_chunk):
     q, k, v, g, beta = kernel_operands("two_chunks_two_heads_a_key_head")
     args = (q, k, v, jnp.full_like(g, -decay_a_chunk / KQ), beta)
     o = rule(*args, chunk=KQ)
@@ -418,7 +410,7 @@ def test_both_kernels_have_no_inf_and_no_nan_in_a_chunk_that_decays_to_nothing(
     assert all(np.isfinite(np.asarray(x)).all() for x in grads)
 
 
-def test_padding_rows_move_no_state_through_the_kernels(kernels):
+def test_padding_rows_move_no_state_through_the_kernels(kernels_interpreted):
     args = kernel_operands("ragged_tail")
     whole = rule(*args, chunk=KQ)
     cut = rule(*(a[:, :128] for a in args), chunk=KQ)
@@ -431,12 +423,7 @@ def test_padding_rows_move_no_state_through_the_kernels(kernels):
     assert all(g.shape == a.shape for g, a in zip(grads, args))
 
 
-def _kernel_calls(fn, *args):
-    return [e for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
-            if e.primitive.name == "pallas_call"]
-
-
-def test_the_kernels_keep_running_sums_inverse_and_state_in_float32(kernels):
+def test_the_kernels_keep_running_sums_inverse_and_state_in_float32(kernels_interpreted):
     bf = lambda t: t.astype(jnp.bfloat16)
     f32 = lambda t: bf(t).astype(jnp.float32)
     q, k, v, g, beta = kernel_operands("two_chunks_two_heads_a_key_head")
@@ -449,7 +436,7 @@ def test_the_kernels_keep_running_sums_inverse_and_state_in_float32(kernels):
     grad = jax.grad(
         lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ).astype(jnp.float32)),
         argnums=range(5))
-    fwd, bwd = _kernel_calls(grad, *args)
+    fwd, bwd = kernel_calls(grad, *args)
     assert [e.params["name"] for e in (fwd, bwd)] == ["gdn_fwd", "gdn_bwd"]
     for call in (fwd, bwd):
         dtypes = [v.aval.dtype for v in call.invars]
@@ -481,33 +468,15 @@ def test_the_kernels_keep_running_sums_inverse_and_state_in_float32(kernels):
     assert all(v.aval.dtype == jnp.float32 for e in outside for v in e.invars)
 
 
-def test_the_kernels_bodies_call_no_jitted_helper(kernels):
-    """As `ops/ssd.py`'s (`tests/ops/test_ssd.py`): a jitted helper in a
-    body or in a block's index map carries the source location of its
-    first trace in the process into the compile cache's key."""
-    grad = jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ)),
-                    argnums=range(5))
-    fwd, bwd = _kernel_calls(
-        grad, *kernel_operands("two_chunks_two_heads_a_key_head"))
-    for call in (fwd, bwd):
-        maps = [m.index_map_jaxpr.jaxpr
-                for m in call.params["grid_mapping"].block_mappings]
-        assert len(maps) == len(call.invars) + len(call.outvars)
-        for jaxpr in (call.params["jaxpr"], *maps):
-            inner = {e.primitive.name for e in all_eqns(jaxpr)}
-            assert not inner & {"jit", "pjit", "closed_call", "core_call"}, (
-                call.params["name"], sorted(inner))
-
-
-def test_a_key_head_is_read_through_the_block_index_never_copied(kernels):
+def test_a_key_head_is_read_through_the_block_index_never_copied(kernels_interpreted):
     args = kernel_operands("two_chunks_two_heads_a_key_head")
-    fwd, = _kernel_calls(lambda *a: gated_delta_rule(*a, chunk=KQ), *args)
+    fwd, = kernel_calls(lambda *a: gated_delta_rule(*a, chunk=KQ), *args)
     # q and k go in as [B, S, G dk]: one key head for its two value heads.
     assert [v.aval.shape for v in fwd.invars[:2]] == [(1, 128, 2 * KD)] * 2
     assert fwd.invars[2].aval.shape == (1, 128, 4 * KD)
 
 
-def test_the_kernels_walk_no_loop(kernels):
+def test_the_kernels_walk_no_loop(kernels_interpreted):
     """The walk across the chunks is the kernels' grid: the differentiated
     program holds no `scan`, where the `jax.numpy` path's holds the
     forward's and the backward's."""
@@ -518,7 +487,7 @@ def test_the_kernels_walk_no_loop(kernels):
                         for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)]
     assert "scan" not in kinds(grad) and "while" not in kinds(grad)
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(attention, "_pallas_ok", lambda: False)
+        patch.setattr(kernel, "on_tpu", lambda: False)
         assert kinds(jax.grad(
             lambda *a: jnp.sum(gated_delta_rule(*a, chunk=KQ)),
             argnums=range(5))).count("scan") == 2
@@ -537,7 +506,7 @@ TAKEN = {"taken": (64, 4, 2, 128, 128), "taken_one_head": (64, 2, 2, 128, 128),
 
 @pytest.mark.parametrize("shape", [*sorted(TAKEN), *sorted(NOT_TAKEN)])
 def test_the_counter_says_which_path_a_rule_took(monkeypatch, shape):
-    """On a TPU (`_pallas_ok`): one `fwd` and one `bwd` a rule built where
+    """On a TPU (`kernel.on_tpu`): one `fwd` and one `bwd` a rule built where
     the kernels tile the shape, none where they do not; on the CPU none.
     Traced only: nothing runs."""
     from oobleck_tpu.utils import metrics
@@ -556,10 +525,10 @@ def test_the_counter_says_which_path_a_rule_took(monkeypatch, shape):
                     calls.value(kernel="bwd"))
 
     def built(on_tpu):
-        monkeypatch.setattr(attention, "_pallas_ok", lambda: on_tpu)
+        monkeypatch.setattr(kernel, "on_tpu", lambda: on_tpu)
         before = read()
         # A function of its own a trace: an equal one would be a cache hit.
-        found = _kernel_calls(jax.grad(
+        found = kernel_calls(jax.grad(
             lambda *a: jnp.sum(gated_delta_rule(*a, chunk=chunk)),
             argnums=1), *args)
         return (tuple(b - a for a, b in zip(before, read())),
@@ -583,10 +552,10 @@ def _kernel_layer_gradients(wrap):
 ], ids=["bare", "the_layers_checkpoint", "the_rules_names_alone",
         "the_inverse_s_name_alone"])
 def test_a_checkpoint_that_keeps_what_the_forward_kernel_wrote_recomputes_none(
-        kernels, wrap, fwd_calls):
+        kernels_interpreted, wrap, fwd_calls):
     args = kernel_operands("two_chunks_two_heads_a_key_head")
     names = [e.params["name"] for e in
-             _kernel_calls(_kernel_layer_gradients(wrap), *args)]
+             kernel_calls(_kernel_layer_gradients(wrap), *args)]
     assert names.count("gdn_fwd") == fwd_calls
     assert names.count("gdn_bwd") == 1
 
@@ -600,6 +569,6 @@ def _kept_and_bare_kernel_gradients():
 
 @pytest.mark.parametrize("wrt", range(5), ids=ARGS)
 def test_what_the_forward_kernel_wrote_is_what_a_second_call_would_write(
-        kernels, wrt):
+        kernels_interpreted, wrt):
     got, want = (g[wrt] for g in _kept_and_bare_kernel_gradients())
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -76,7 +76,7 @@ def test_routing_probe_program_carries_its_name():
 
 
 
-def test_the_delta_rule_s_kernels_carry_their_names(monkeypatch):
+def test_the_delta_rule_s_kernels_carry_their_names(as_on_tpu):
     """`name=` on each `pallas_call` is what the chip's compiler names the
     custom call after (`%gdn_fwd.N`, `%gdn_bwd.N`) and what the benchmark's
     kernel metrics match (`benchmarks/layer_metrics/gdn_fwd_ms.json`,
@@ -85,11 +85,9 @@ def test_the_delta_rule_s_kernels_carry_their_names(monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    from oobleck_tpu.ops import attention
     from oobleck_tpu.ops.gdn import gated_delta_rule
     from tests.ops.programs import all_eqns
 
-    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(gated_delta_rule(*a, chunk=64)), argnums=(0, 1)))(
@@ -105,7 +103,7 @@ def test_the_delta_rule_s_kernels_carry_their_names(monkeypatch):
 
 
 def test_the_selective_scan_s_and_differential_attention_s_kernels_carry_their_names(
-        monkeypatch):
+        as_on_tpu):
     """`%sscan_fwd.N`, `%sscan_bwd.N` under the scope `sscan`, and
     `%flash_diff_fwd.N`, `%flash_diff_bwd_dqkv.N` (with a window:
     `flash_diff_swa_*`), never `%flash_fwd.`: what the benchmark's kernel
@@ -116,14 +114,9 @@ def test_the_selective_scan_s_and_differential_attention_s_kernels_carry_their_n
 
     from oobleck_tpu.ops import attention
     from oobleck_tpu.ops.sscan import selective_scan
-    from tests.ops.programs import all_eqns
+    from tests.ops.programs import kernel_calls as calls
 
-    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
-
-    def calls(fn, *args):
-        return [e for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
-                if e.primitive.name == "pallas_call"]
 
     scan = calls(jax.grad(lambda *a: jnp.sum(selective_scan(*a)),
                           argnums=(0, 1)),

@@ -11,7 +11,7 @@ import jax
 import pytest
 
 from tests.ops import cells, test_remat_cells_b, test_remat_cells_c
-from tests.ops.cells import compiled_for_tpu, v5e  # noqa: F401 (fixtures)
+from tests.ops.cells import v5e  # noqa: F401 (a fixture)
 
 HERE = ("gpt3-2.7b", "nemotron-3-nano-30b-a3b", "phi-4-mini-flash")
 assert sorted(HERE + test_remat_cells_b.HERE + test_remat_cells_c.HERE) \

@@ -3,7 +3,7 @@
 import pytest
 
 from tests.ops import cells
-from tests.ops.cells import compiled_for_tpu, v5e  # noqa: F401 (fixtures)
+from tests.ops.cells import v5e  # noqa: F401 (a fixture)
 
 HERE = ("lfm2-24b-a2b", "moonlight-16b-a3b")
 

@@ -3,7 +3,7 @@
 import pytest
 
 from tests.ops import cells
-from tests.ops.cells import compiled_for_tpu, v5e  # noqa: F401 (fixtures)
+from tests.ops.cells import v5e  # noqa: F401 (a fixture)
 
 HERE = ("qwen3-next-80b-a3b", "smallthinker-21b-a3b")
 

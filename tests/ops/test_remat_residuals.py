@@ -106,11 +106,7 @@ def _scan_block(w, x):
 
 
 def test_the_scan_s_kept_residuals_halve_its_forward_calls_and_keep_the_gradients(
-        monkeypatch):
-    from oobleck_tpu.ops import ssd
-
-    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(ssd, "_interpret", lambda: True)
+        kernels_interpreted):
     rng = np.random.default_rng(0)
     w = [jnp.asarray(rng.normal(0, 0.2, (2, *shape)), jnp.float32)
          for shape in ((32, 128), (32, 128), (32, 128), (32, 128), (128, 32))]

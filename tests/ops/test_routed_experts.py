@@ -17,7 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from oobleck_tpu.ops import moe
+from oobleck_tpu.ops import kernel, moe
 
 T, D, F, NE, K = 48, 32, 64, 8, 2
 
@@ -305,13 +305,6 @@ def test_tgmm_kernel_starts_from_a_sum_it_is_handed(sizes, dtype):
                                atol=1e-5)
 
 
-@pytest.fixture
-def interpreted(monkeypatch):
-    """The kernels' path, in the interpreter."""
-    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(moe, "_interpret", lambda: True)
-
-
 def _weights_and_sums(layer, offset, held, gated):
     sl = slice(offset, offset + held)
     names = ("w1", "w3", "w2") if gated else ("w1", "w2")
@@ -349,7 +342,7 @@ def _weight_grads(layer, offset, ws, sums, names=None):
 @pytest.mark.parametrize("offset,held", [(0, NE), (2, 4), (3, 1)],
                          ids=["all", "share", "empty_expert_only"])
 def test_gradients_with_sums_handed_are_the_sums_plus_the_gradients(
-        layer, interpreted, offset, held, gated):
+        layer, kernels_interpreted, offset, held, gated):
     ws, sums = _weights_and_sums(layer, offset, held, gated)
     plain = _weight_grads(layer, offset, ws, {})
     got = _weight_grads(layer, offset, ws, sums)
@@ -369,7 +362,7 @@ WRONGLY_HANDED = {
 
 
 @pytest.mark.parametrize("case", sorted(WRONGLY_HANDED))
-def test_a_sum_not_taken_exactly_once_fails_the_trace(layer, interpreted,
+def test_a_sum_not_taken_exactly_once_fails_the_trace(layer, kernels_interpreted,
                                                       case):
     names, said = WRONGLY_HANDED[case]
     ws, sums = _weights_and_sums(layer, 2, 4, True)
@@ -378,7 +371,7 @@ def test_a_sum_not_taken_exactly_once_fails_the_trace(layer, interpreted,
 
 
 def test_a_sum_cannot_be_handed_outside_its_ledger_or_off_the_kernels(layer,
-                                                                      monkeypatch):
+                                                                      request):
     ws, sums = _weights_and_sums(layer, 2, 4, True)
     handed = tuple(moe.GradSum(sums[n], n) for n in ("w1", "w3", "w2"))
     loss = lambda ws: jnp.sum(moe.routed_experts(
@@ -386,8 +379,7 @@ def test_a_sum_cannot_be_handed_outside_its_ledger_or_off_the_kernels(layer,
         ws["w2"], num_experts=NE, top_k=K, expert_offset=2, dw_sums=handed))
     with pytest.raises(AssertionError, match="off the kernels' path"):
         jax.grad(loss)(ws)              # `lax.ragged_dot` takes no sum
-    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(moe, "_interpret", lambda: True)
+    request.getfixturevalue("kernels_interpreted")
     with pytest.raises(ValueError, match="outside `handing_sums`"):
         jax.grad(loss)(ws)
 
@@ -555,14 +547,12 @@ def test_ungated_gradients_match_the_dense_formulation(ungated, offset, held,
 
 
 def test_an_ungated_call_holds_two_products_forward_and_four_backward(
-        ungated, monkeypatch):
+        ungated, kernels_interpreted):
     """`grouped_matmul`'s own backward: dX and dW of each of the two; and
     the two sums of rows into tokens, the combine forward and the
     dispatch's dx backward. Counted on the kernels' path (interpreted)."""
     from tests.ops.programs import pallas_calls
 
-    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(moe, "_interpret", lambda: True)
     sl = slice(2, 6)
 
     def loss(x, w1, w2):
@@ -697,8 +687,8 @@ def _unwritten_run(layer, ungated, path, activation, routing):
 
     with pytest.MonkeyPatch.context() as mp:
         if path == "kernels":
-            mp.setattr(moe, "_pallas_ok", lambda: True)
-            mp.setattr(moe, "_interpret", lambda: True)
+            mp.setattr(kernel, "on_tpu", lambda: True)
+            mp.setattr(kernel, "interpret", lambda: True)
         before, sums_before = built.value(), sums.value()
         (value, _), grads = _value_and_grads(operands, activation, forced)
         counted = built.value() - before, sums.value() - sums_before
@@ -832,7 +822,7 @@ def _sum_case(routing, dtype):
 
 @pytest.mark.parametrize("weighted", [False, True], ids=["plain", "weighted"])
 @pytest.mark.parametrize("routing", sorted(TOKEN_SUM_ROUTINGS))
-def test_the_token_sum_kernel_is_the_loop_bit_for_bit(interpreted, routing,
+def test_the_token_sum_kernel_is_the_loop_bit_for_bit(kernels_interpreted, routing,
                                                       weighted):
     from oobleck_tpu.utils import metrics
 
@@ -859,7 +849,7 @@ def test_the_token_sum_kernel_is_the_loop_bit_for_bit(interpreted, routing,
         assert np.asarray(plan.run_first).reshape(-1, 4)[1, 0] % moe.SUBLANE == 5
 
 
-def test_unrounded_weights_agree_to_the_last_place(interpreted):
+def test_unrounded_weights_agree_to_the_last_place(kernels_interpreted):
     """The same sum with float32 weights as `route` gives them: the loop
     rounds weight x row and then the sum, the interpreter's one XLA:CPU
     program may fuse the two (one rounding): a unit in the last place of a
@@ -905,31 +895,6 @@ def test_the_plan_s_rows_by_token_are_the_rows_the_tiles_hold(routing):
             mine = src_row[b * block:(b + 1) * block, e]
             np.testing.assert_array_equal(
                 mine[mine >= 0], run_first[b, e] + np.arange(run_len[b, e]))
-
-
-def test_the_kernels_bodies_call_no_jitted_helper(layer, interpreted):
-    """No expert kernel's body holds an inner `jit` call (`//` and `%` on
-    a traced integer are `jnp.floor_divide` / `jnp.remainder`, and
-    `jnp.where`, `jnp.sum`, `jnp.dot` are jitted too). Such a helper's
-    jaxpr is cached with the source location of its FIRST trace in the
-    process; a Mosaic kernel's serialized body carries that location and
-    the persistent compile cache's key is computed from the body: with
-    `//` in `moe_token_sum`, a cell's second run (which reaches the
-    stage's first trace by another call stack than the cold run, which
-    profiles the layers first) missed the cache and compiled `jit_bwd`
-    again, 54 s of `setup_s` (my chip run, PR 52)."""
-    from tests.ops.programs import all_eqns
-
-    grads = jax.value_and_grad(lambda x: jnp.sum(_share(
-        dict(layer, x=x), 2, 4)))
-    kernels = [e for e in all_eqns(jax.make_jaxpr(grads)(layer["x"]).jaxpr)
-               if e.primitive.name == "pallas_call"]
-    assert {e.params["name"] for e in kernels} == {
-        "moe_gmm", "moe_tgmm", "moe_token_sum"}
-    for kernel in kernels:
-        inner = {e.primitive.name for e in all_eqns(kernel.params["jaxpr"])}
-        assert not inner & {"jit", "pjit", "closed_call", "core_call"}, (
-            kernel.params["name"], sorted(inner))
 
 
 # cell -> its call (tokens a microbatch, D, picks, experts), the block of
@@ -1249,14 +1214,12 @@ def test_the_reglu_shares_add_up_to_the_uncut_layer(layer, router_rows):
 
 
 def test_a_reglu_call_holds_swiglu_s_nine_products(layer, router_rows,
-                                                   monkeypatch):
+                                                   kernels_interpreted):
     """Three grouped products forward, three dX and three dW backward: the
     kernels are SwiGLU's, under the same names, and so are the two sums of
     rows into tokens."""
     from tests.ops.programs import pallas_calls
 
-    monkeypatch.setattr(moe, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(moe, "_interpret", lambda: True)
     fn = lambda y: jnp.sum(_reglu_share(dict(layer, x=y), router_rows, 2, 4))
     names = [n for n, _ in pallas_calls(jax.make_jaxpr(fn)(layer["x"]).jaxpr)]
     assert names == ["moe_gmm"] * 3 + ["moe_token_sum"]

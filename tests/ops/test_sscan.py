@@ -18,9 +18,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oobleck_tpu.ops import attention, sscan
+from oobleck_tpu.ops import kernel, sscan
 from oobleck_tpu.ops.sscan import selective_scan
-from tests.ops.programs import all_eqns
+from tests.ops.programs import kernel_calls
 
 ARGS = ("x", "dt", "A", "B", "C", "D")
 N = 16
@@ -58,11 +58,9 @@ def operands(case, *, seed=0, dtype=jnp.float32):
 
 
 @pytest.fixture
-def kernels(monkeypatch):
+def kernels(kernels_interpreted, monkeypatch):
     """`selective_scan` takes the kernels' path as on a TPU, interpreted,
     at channel tiles of 128 (so 256 channels are two tiles)."""
-    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(sscan, "_interpret", lambda: True)
     monkeypatch.setattr(sscan, "FWD_TILE", 128)
     monkeypatch.setattr(sscan, "BWD_TILE", 128)
 
@@ -140,28 +138,17 @@ def test_bfloat16_x_keeps_the_state_and_the_rest_in_float32(kernels):
 def _kernel_calls(fn, *args):
     """The `pallas_call`s of `fn`'s jaxpr, by name: sscan_fwd before
     sscan_bwd."""
-    calls = [e for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
-             if e.primitive.name == "pallas_call"]
-    return sorted(calls, key=lambda e: e.params["name"], reverse=True)
+    return sorted(kernel_calls(fn, *args), key=lambda e: e.params["name"],
+                  reverse=True)
 
 
-def test_the_kernels_bodies_call_no_jitted_helper(kernels):
-    """As `ops/ssd.py`'s: a jitted helper in a body, or in a block's index
-    map, carries the source location of its first trace in the process into
-    the kernel's serialized body and the compile cache's key with it."""
+def test_the_grid_is_batch_tile_chunk_and_no_state_a_position_leaves_a_kernel(
+        kernels):
     grad = jax.grad(lambda *a: jnp.sum(selective_scan(*a, chunk=16)),
                     argnums=range(6))
     fwd, bwd = _kernel_calls(grad, *operands("two_tiles_three_chunks"))
     assert [c.params["name"] for c in (fwd, bwd)] == ["sscan_fwd",
                                                       "sscan_bwd"]
-    for call in (fwd, bwd):
-        maps = [m.index_map_jaxpr.jaxpr
-                for m in call.params["grid_mapping"].block_mappings]
-        assert len(maps) == len(call.invars) + len(call.outvars)
-        for jaxpr in (call.params["jaxpr"], *maps):
-            inner = {e.primitive.name for e in all_eqns(jaxpr)}
-            assert not inner & {"jit", "pjit", "closed_call", "core_call"}, (
-                call.params["name"], sorted(inner))
     # The grid: (batch, channel tile, chunk); no [L, C, N] operand or result.
     assert fwd.params["grid_mapping"].grid == (1, 2, 3)
     for call in (fwd, bwd):
@@ -177,7 +164,7 @@ NOT_TAKEN = {"channels_96": (16, 96, 16), "states_4": (16, 128, 4),
 
 @pytest.mark.parametrize("shape", ["taken", *sorted(NOT_TAKEN)])
 def test_the_counters_say_which_path_a_scan_took(monkeypatch, shape):
-    """On a TPU (`_pallas_ok`): one `fwd` and one `bwd` a scan built where
+    """On a TPU (`kernel.on_tpu`): one `fwd` and one `bwd` a scan built where
     the kernels tile the shape, none where they do not; on the CPU none;
     the chunks by layer either way. Traced only: nothing runs."""
     from oobleck_tpu.utils import metrics
@@ -194,7 +181,7 @@ def test_the_counters_say_which_path_a_scan_took(monkeypatch, shape):
                     chunks.value(layer="7"))
 
     def built(on_tpu):
-        monkeypatch.setattr(attention, "_pallas_ok", lambda: on_tpu)
+        monkeypatch.setattr(kernel, "on_tpu", lambda: on_tpu)
         before = read()
         found = _kernel_calls(jax.grad(lambda *a: jnp.sum(
             selective_scan(*a, chunk=chunk, layer="7")), argnums=0), *args)

@@ -24,9 +24,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oobleck_tpu.ops import attention, ssd
+from oobleck_tpu.ops import kernel, ssd
 from oobleck_tpu.ops.ssd import ssd_scan
-from tests.ops.programs import all_eqns
+from tests.ops.programs import all_eqns, kernel_calls
 
 # (length, chunk, heads, groups)
 CASES = {
@@ -195,13 +195,6 @@ KERNEL_CASES = {
 KQ, KP, KN = 128, 64, 128
 
 
-@pytest.fixture
-def kernels(monkeypatch):
-    """`ssd_scan` takes the kernels' path as on a TPU, interpreted."""
-    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
-    monkeypatch.setattr(ssd, "_interpret", lambda: True)
-
-
 def kernel_operands(case, *, seed=0, dtype=jnp.float32):
     bsz, length, heads, groups = KERNEL_CASES[case]
     k = jax.random.split(jax.random.PRNGKey(seed), 6)
@@ -224,7 +217,7 @@ def numpy_path(*args, chunk=KQ):
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
-def test_the_forward_kernel_is_the_numpy_path(kernels, case):
+def test_the_forward_kernel_is_the_numpy_path(kernels_interpreted, case):
     args = kernel_operands(case)
     got = scan(*args, chunk=KQ)
     want = jax.jit(numpy_path)(*args)
@@ -249,7 +242,7 @@ def _kernel_gradients(case):
 
 @pytest.mark.parametrize("wrt", range(6), ids=ARGS)
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
-def test_every_gradient_of_the_kernels_is_the_numpy_paths(kernels, case, wrt):
+def test_every_gradient_of_the_kernels_is_the_numpy_paths(kernels_interpreted, case, wrt):
     got, want = (g[wrt] for g in _kernel_gradients(case))
     assert got.shape == want.shape and got.dtype == want.dtype
     scale = float(jnp.max(jnp.abs(want)))
@@ -260,7 +253,7 @@ def test_every_gradient_of_the_kernels_is_the_numpy_paths(kernels, case, wrt):
 @pytest.mark.parametrize("decay_a_chunk", [20.0, 2000.0],
                          ids=["e-20", "e-2000"])
 def test_both_kernels_have_no_inf_and_no_nan_in_a_chunk_that_decays_to_nothing(
-        kernels, decay_a_chunk):
+        kernels_interpreted, decay_a_chunk):
     x, dt, a_neg, b, c, d = kernel_operands("two_chunks_two_groups")
     dt = jnp.full_like(dt, decay_a_chunk / KQ)
     a_neg = -jnp.ones_like(a_neg)
@@ -274,7 +267,7 @@ def test_both_kernels_have_no_inf_and_no_nan_in_a_chunk_that_decays_to_nothing(
     assert all(np.isfinite(np.asarray(g)).all() for g in grads)
 
 
-def test_padding_rows_move_no_state_through_the_kernels(kernels):
+def test_padding_rows_move_no_state_through_the_kernels(kernels_interpreted):
     args = kernel_operands("ragged_tail")
     whole = scan(*args, chunk=KQ)
     cut = scan(*(a[:, :256] if a.ndim > 1 else a for a in args), chunk=KQ)
@@ -286,12 +279,7 @@ def test_padding_rows_move_no_state_through_the_kernels(kernels):
     assert all(g.shape == a.shape for g, a in zip(grads, args))
 
 
-def _kernel_calls(fn, *args):
-    return [e for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
-            if e.primitive.name == "pallas_call"]
-
-
-def test_the_kernels_keep_running_sums_and_state_in_float32(kernels):
+def test_the_kernels_keep_running_sums_and_state_in_float32(kernels_interpreted):
     bf = lambda t: t.astype(jnp.bfloat16)
     x, dt, a_neg, b, c, d = kernel_operands("two_chunks_two_groups")
     args = (bf(x), dt, a_neg, bf(b), bf(c), d)
@@ -303,7 +291,7 @@ def test_the_kernels_keep_running_sums_and_state_in_float32(kernels):
     assert err.max() < 0.05 * np.abs(np.asarray(want)).max()
     grad = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=KQ).astype(jnp.float32)),
                     argnums=range(6))
-    fwd, bwd = _kernel_calls(grad, *args)
+    fwd, bwd = kernel_calls(grad, *args)
     assert [e.params["name"] for e in (fwd, bwd)] == ["ssd_fwd", "ssd_bwd"]
     for call in (fwd, bwd):
         dtypes = [v.aval.dtype for v in call.invars]
@@ -323,33 +311,9 @@ def test_the_kernels_keep_running_sums_and_state_in_float32(kernels):
     assert fwd.outvars[1].aval.shape == (1, 2, 2, KN, 2 * KP)
 
 
-def test_the_kernels_bodies_call_no_jitted_helper(kernels):
-    """As `ops/moe.py`'s (`tests/ops/test_routed_experts.py`): `jnp.where`
-    in a body is an inner `jit` whose cached jaxpr carries the source
-    location of its first trace in the process into the kernel's serialized
-    body, and the compile cache's key with it. With 19 of them in these two
-    and `i // groups`, `i % groups` in their blocks' index maps, the cell's
-    first warm start compiled `jit_bwd` again: `setup_s` 88.3 and 92.6 s
-    where the parent's read 43.3 and 51.3 (my chip runs, PR 54)."""
-    grad = jax.grad(lambda *a: jnp.sum(ssd_scan(*a, chunk=KQ)),
-                    argnums=range(6))
-    fwd, bwd = _kernel_calls(grad, *kernel_operands("two_chunks_two_groups"))
-    for call in (fwd, bwd):
-        # The body, and the blocks' index maps, which are serialized with it
-        # (`//` and `%` of a grid index there are `jnp.floor_divide` and
-        # `jnp.remainder`).
-        maps = [m.index_map_jaxpr.jaxpr
-                for m in call.params["grid_mapping"].block_mappings]
-        assert len(maps) == len(call.invars) + len(call.outvars)
-        for jaxpr in (call.params["jaxpr"], *maps):
-            inner = {e.primitive.name for e in all_eqns(jaxpr)}
-            assert not inner & {"jit", "pjit", "closed_call", "core_call"}, (
-                call.params["name"], sorted(inner))
-
-
-def test_a_group_is_read_through_the_block_index_never_copied(kernels):
+def test_a_group_is_read_through_the_block_index_never_copied(kernels_interpreted):
     args = kernel_operands("three_chunks_one_group")
-    fwd, = _kernel_calls(lambda *a: ssd_scan(*a, chunk=KQ), *args)
+    fwd, = kernel_calls(lambda *a: ssd_scan(*a, chunk=KQ), *args)
     # B and C go in as [B, S, G N]: one group for the four heads.
     assert [v.aval.shape for v in fwd.invars[1:3]] == [(2, 384, KN)] * 2
 
@@ -363,7 +327,7 @@ NOT_TAKEN = {"chunk_64": (64, 4, 2, 64, 128), "half_a_lane_tile": (128, 2, 2, 64
 
 @pytest.mark.parametrize("shape", ["taken", *sorted(NOT_TAKEN)])
 def test_the_counter_says_which_path_a_scan_took(monkeypatch, shape):
-    """On a TPU (`_pallas_ok`): one `fwd` and one `bwd` a scan built where
+    """On a TPU (`kernel.on_tpu`): one `fwd` and one `bwd` a scan built where
     the kernels tile the shape, none where they do not; on the CPU none.
     Traced only: nothing runs."""
     from oobleck_tpu.utils import metrics
@@ -381,10 +345,10 @@ def test_the_counter_says_which_path_a_scan_took(monkeypatch, shape):
                     calls.value(kernel="bwd"))
 
     def built(on_tpu):
-        monkeypatch.setattr(attention, "_pallas_ok", lambda: on_tpu)
+        monkeypatch.setattr(kernel, "on_tpu", lambda: on_tpu)
         before = read()
         # A function of its own a trace: an equal one would be a cache hit.
-        found = _kernel_calls(jax.grad(
+        found = kernel_calls(jax.grad(
             lambda *a: jnp.sum(ssd_scan(*a, chunk=chunk)), argnums=0), *args)
         return (tuple(b - a for a, b in zip(before, read())),
                 sorted(e.params["name"] for e in found))

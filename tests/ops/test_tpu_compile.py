@@ -8,7 +8,7 @@ budget, or a `pallas_call` that will not trace inside the training step's
 `check_vma=True` shard_maps. Nothing executes here; no number comes out.
 
 `jax.default_backend()` is still "cpu" during such a compile, so the tests
-steer `ops.attention._pallas_ok` themselves — the program has no option
+steer `ops.kernel.on_tpu` themselves — the program has no option
 for it.
 """
 
@@ -21,8 +21,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs: not /tmp
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental import topologies
-from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
@@ -30,13 +28,12 @@ from oobleck_tpu.config import ServeArguments
 from oobleck_tpu.ops import attention
 from oobleck_tpu.ops.flash import flash_attention, latent_flash_attention
 from oobleck_tpu.ops.paged_attention import (
-    _select_paged_impl,
-    _select_paged_verify_impl,
     paged_decode_attention,
     paged_verify_attention,
 )
 from oobleck_tpu.ops.remat import checkpoint_layer
 from oobleck_tpu.serve.kv_blocks import pages_for
+from tests.ops.cells import v5e  # noqa: F401 (a fixture)
 
 # [B, H, S, D] of one microbatch's attention call: gpt2 124M
 # (examples/gpt2.yaml: microbatch 8, 12 heads of 64, seq 1024), a llama-7B
@@ -80,35 +77,9 @@ def _serve_geometry():
             pages_for(a.max_seq, a.page_size), a.spec_k + 1)
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
-    return topo.devices
-
-
 @pytest.fixture(autouse=True)
-def _compiled_for_tpu(monkeypatch):
-    """Kernels lower through Mosaic (not the interpreter), and the
-    persistent cache stays out of it: an executable for an unattached
-    device is written but can never be read back, and warns on the way."""
-    def forget_backend_choices():  # "auto" is resolved once per process
-        for choice in (attention.select_attention_impl, _select_paged_impl,
-                       _select_paged_verify_impl):
-            choice.cache_clear()
-
-    monkeypatch.setattr(attention, "_pallas_ok", lambda: True)
-    forget_backend_choices()
-    before = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    compilation_cache.reset_cache()
-    forget_backend_choices()
+def _compiled_for_tpu(compiled_for_tpu):
+    """Every test of this file, and of `test_tpu_compile_routed.py`."""
 
 
 def _compile(fn, dev, *shapes):

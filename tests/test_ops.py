@@ -424,7 +424,7 @@ def test_auto_never_hides_a_tpu_behind_the_cpu(monkeypatch, tpu_visible):
     """Off-TPU "auto" is the XLA reference and explicit pallas runs
     interpreted — for processes with no TPU. One that can reach a TPU while
     its default backend is something else must not idle the chip quietly."""
-    from oobleck_tpu.ops import attention
+    from oobleck_tpu.ops import kernel
 
     def devices(backend=None):
         if backend == "tpu" and not tpu_visible:
@@ -435,6 +435,6 @@ def test_auto_never_hides_a_tpu_behind_the_cpu(monkeypatch, tpu_visible):
     assert jax.default_backend() == "cpu"
     if tpu_visible:
         with pytest.raises(RuntimeError, match="a TPU is visible"):
-            attention._pallas_ok()
+            kernel.on_tpu()
     else:
-        assert attention._pallas_ok() is False
+        assert kernel.on_tpu() is False

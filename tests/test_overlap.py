@@ -402,22 +402,23 @@ def test_flash_train_step_matches_xla_alibi():
 
 
 def test_pallas_ok_drives_auto_selection(monkeypatch):
-    """The hoisted _pallas_ok helper is the single policy point: flipping
-    it flips BOTH the flash and the paged 'auto' resolutions."""
+    """`ops/kernel.on_tpu` is the single policy point: flipping it flips
+    BOTH the flash and the paged 'auto' resolutions."""
     from oobleck_tpu.ops import attention as attn
+    from oobleck_tpu.ops import kernel
     from oobleck_tpu.ops import paged_attention as paged
     from oobleck_tpu.ops.flash import flash_attention
 
     attn.select_attention_impl.cache_clear()
     paged._select_paged_impl.cache_clear()
     try:
-        monkeypatch.setattr(attn, "_pallas_ok", lambda: True)
+        monkeypatch.setattr(kernel, "on_tpu", lambda: True)
         assert attn.select_attention_impl("auto") is flash_attention
         assert paged._select_paged_impl("auto") is paged._paged_decode_pallas
 
         attn.select_attention_impl.cache_clear()
         paged._select_paged_impl.cache_clear()
-        monkeypatch.setattr(attn, "_pallas_ok", lambda: False)
+        monkeypatch.setattr(kernel, "on_tpu", lambda: False)
         assert attn.select_attention_impl("auto") is attn._xla_causal_attention
         assert paged._select_paged_impl("auto") is paged._paged_decode_xla
     finally:
