@@ -40,7 +40,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from oobleck_tpu.config import OobleckArguments, training_seq_len
 from oobleck_tpu.elastic.message import JOINED_KEY
@@ -83,6 +83,7 @@ from oobleck_tpu.policy import (
     decision_from_payload,
 )
 from oobleck_tpu.utils import background, metrics, recovery
+from oobleck_tpu.utils.compile_cache import compile_unpersisted
 from oobleck_tpu.utils.chaos import chaos
 
 logger = logging.getLogger("oobleck.engine")
@@ -176,7 +177,11 @@ class DeferredLoss:
 
 @jax.jit
 def pack_flat(trees: list):
-    """One flat f32 buffer from same-mesh trees (single fused program)."""
+    """One flat f32 buffer from same-mesh trees: ONE jitted program
+    (`jit_pack_flat`), which reads every leaf and writes a new buffer of
+    all their elements at four bytes each. The anchor path's, for owners
+    whose meshes differ; congruent owners sum in `dp_sum_program` and pack
+    nothing."""
     return jnp.concatenate([
         l.ravel().astype(jnp.float32)
         for t in trees for l in jax.tree.leaves(t)])
@@ -220,30 +225,210 @@ def sum_trees(per_tree: list) -> list:
     return [sum(g[1:], start=g[0]) for g in zip(*per_tree)]
 
 
+# The axis of a collective group's mesh that runs over the layer's owners.
+DP_AXIS = "dp"
+
+
+def dp_spec(spec: PartitionSpec, ndim: int) -> PartitionSpec:
+    """An owner's `spec` of a leaf, as the spec of all the owners' leaves
+    laid end to end along dimension 0 over `DP_AXIS` and then the axes the
+    leaf's own dimension 0 is sharded over: a chip's shard of the whole IS
+    its shard of its owner's leaf, shape and all. (A leaf of no dimension
+    gets one, of the owners.)"""
+    if ndim == 0:
+        return PartitionSpec(DP_AXIS)
+    first = spec[0] if len(spec) else None
+    names = (() if first is None
+             else first if isinstance(first, tuple) else (first,))
+    return PartitionSpec((DP_AXIS, *names), *spec[1:])
+
+
+def dp_sum_program(shardings: list, operands: list):
+    """THE compiled sum over `DP_AXIS` of a list of leaves (`jit_dp_sum` in
+    a device trace): `operands` are the whole arrays or their shapes, and
+    `shardings` theirs, all on one mesh whose first axis is `DP_AXIS`. One
+    all-reduce a leaf among the chips that hold the same shard of it, every
+    owner left with the same bits, in the leaves' own dtypes; one program
+    for the whole list, so XLA may combine the leaves' all-reduces. The
+    operands are NOT donated: they are the owners' own gradient buffers
+    (`PipelineInstance.grads`), which callers read after the sum.
+
+    What `PROGRAMS` keeps, by the shardings (the owners' chips in their
+    stage meshes' arrangement, each leaf's spec), the shapes and the
+    dtypes, is the EXECUTABLE, compiled here and never read back from the
+    persistent cache (`compile_cache.compile_unpersisted`: a collective
+    over some of the process's chips does not survive it): whoever builds
+    it first, the recovery precompiler's walk or a step, the next caller
+    in the process compiles nothing."""
+    key = ("dp_sum", tuple(shardings),
+           tuple((o.shape, jnp.dtype(o.dtype)) for o in operands))
+    if key not in PROGRAMS:
+        def dp_sum(leaves):
+            return [jax.lax.psum(l, DP_AXIS) for l in leaves]
+
+        specs = [sh.spec for sh in shardings]
+        PROGRAMS[key] = compile_unpersisted(
+            jax.jit(jax.shard_map(dp_sum, mesh=shardings[0].mesh,
+                                  in_specs=(specs,), out_specs=specs)),
+            [jax.ShapeDtypeStruct(o.shape, o.dtype, sharding=sh)
+             for o, sh in zip(operands, shardings)])
+    return PROGRAMS[key]
+
+
+class CollectiveGroup:
+    """Layers that the same pipelines hold on congruent stages (one stage
+    of each owner: meshes of one shape and axis names over distinct chips,
+    every leaf at the same spec), and what sums their gradients in one
+    program: a mesh with `DP_AXIS` over the owners and then the stage
+    mesh's own axes, each leaf's spec on it (`dp_spec`), the program."""
+
+    def __init__(self, layers, owners, stages):
+        self.layers = tuple(layers)
+        self.owners = list(owners)
+        self.stages = list(stages)
+        first = stages[0]
+        self.mesh = Mesh(
+            np.stack([st.mesh.devices for st in stages]),
+            (DP_AXIS, *first.mesh.axis_names))
+        self.owner_of_device = {
+            d: i for i, st in enumerate(stages) for d in st.mesh.devices.flat}
+        # Each owner's shardings of the layers' leaves, in the order
+        # `jax.tree.flatten` gives the layers' gradient trees.
+        self.leaf_shardings = [
+            jax.tree.leaves([st.param_shardings[li] for li in self.layers])
+            for st in stages]
+        self._program: tuple | None = None
+
+    @staticmethod
+    def congruent(stages, layers) -> bool:
+        """Whether one collective can sum these layers between `stages`,
+        from what the stages are: equal mesh shapes and axis names, no
+        chip twice, and every leaf of every layer at one spec."""
+        first = stages[0]
+        chips = [d for st in stages for d in st.mesh.devices.flat]
+        if len(set(chips)) != len(chips):
+            return False
+        for st in stages[1:]:
+            if (st.mesh.devices.shape != first.mesh.devices.shape
+                    or st.mesh.axis_names != first.mesh.axis_names):
+                return False
+            for li in layers:
+                a, b = (jax.tree.flatten(
+                    s.param_pspecs[li],
+                    is_leaf=lambda x: isinstance(x, PartitionSpec))
+                    for s in (first, st))
+                if a != b:
+                    return False
+        return True
+
+    def program(self, leaves):
+        """(the leaves' shardings on the group's mesh, the shapes of the
+        owners' leaves end to end, the compiled sum), made once from one
+        owner's `leaves`: arrays, or their shapes (what the recovery
+        precompiler has: `parallel/cross_host.layer_avals` of the group's
+        layers, a gradient having its parameter's shape and dtype)."""
+        if self._program is None:
+            n = len(self.owners)
+            shardings = [
+                NamedSharding(self.mesh, dp_spec(sh.spec, l.ndim))
+                for sh, l in zip(self.leaf_shardings[0], leaves)]
+            whole = [jax.ShapeDtypeStruct(
+                (n * l.shape[0], *l.shape[1:]) if l.ndim else (n,), l.dtype)
+                for l in leaves]
+            self._program = (shardings, [w.shape for w in whole],
+                             dp_sum_program(shardings, whole))
+        return self._program
+
+    def summed_grads(self) -> list[list]:
+        """The layers' summed gradient trees, a list an owner: every
+        owner's on its OWN shardings. Nothing is copied on the way in (the
+        owners' shards ARE the whole array's) or out (each owner's arrays
+        are made of the result's shards on its chips)."""
+        per_owner, structs = zip(*(
+            jax.tree.flatten([p.grads[li] for li in self.layers])
+            for p in self.owners))
+        firsts = per_owner[0]
+        shardings, shapes, program = self.program(firsts)
+        whole = []
+        for like, sharding, shape, *leaves in zip(
+                firsts, shardings, shapes, *per_owner):
+            shards = [s.data for l in leaves for s in l.addressable_shards]
+            if not like.ndim:
+                shards = [s.reshape(1) for s in shards]
+            whole.append(jax.make_array_from_single_device_arrays(
+                shape, sharding, shards))
+        sums = program(whole)
+        out: list[list] = [[] for _ in self.owners]
+        for like, total in zip(firsts, sums):
+            pieces: list[list] = [[] for _ in self.owners]
+            for s in total.addressable_shards:
+                pieces[self.owner_of_device[s.device]].append(
+                    s.data if like.ndim else s.data.reshape(()))
+            for i, mine in enumerate(pieces):
+                out[i].append(jax.make_array_from_single_device_arrays(
+                    like.shape, self.leaf_shardings[i][len(out[i])], mine))
+        return [jax.tree.unflatten(struct, leaves)
+                for struct, leaves in zip(structs, out)]
+
+
 class DataParallelEngine:
     """Layer-granularity gradient sync across heterogeneous pipelines
     (reference engine.py:363-412): each layer's grads are summed over every
     pipeline that owns it, at whatever sharding each owner uses.
 
-    Transfers are BATCHED per pipeline pair: each non-anchor owner flattens
-    every shared layer's grads into ONE buffer (a single fused concat on its
-    own meshes), ships it to the anchor in one `jax.device_put`, and the
-    anchor adds it back per-layer inside one jitted program — instead of a
-    per-layer, per-leaf transfer loop on the step critical path (the
-    reference issues one collective per layer, engine.py:404-412; round-2
-    weak #5). Redistribution anchor -> owner batches the same way."""
+    Shared layers are grouped by who holds them and on which stage, and
+    each group takes one of two paths, decided at construction from the
+    owners' stage meshes and specs alone (`CollectiveGroup.congruent`):
+
+      * congruent owners (pipelines of one shape: the same stage mesh
+        shape, every leaf at the same spec): ONE jitted collective a group
+        (`dp_sum_program`), over a mesh made of the owners' chips. The
+        owners' gradient shards go in as they lie and the sums come back
+        on each owner's own shardings: no packed copy, no anchor, every
+        owner given the same bits;
+      * anything else (pipelines of different stage counts that hold a
+        layer on one chip here and two there): the anchor path. The first
+        owner is the anchor; every other owner flattens its shared layers'
+        grads into ONE f32 buffer a stage pair (`pack_flat`), all buffers
+        ship in one `jax.device_put`, the anchor adds them in per layer
+        inside one jitted program (`unpack_add`), and the totals go back
+        the same way (`unpack_to`).
+
+    `oobleck_dp_sync_layer_sums_total{path}` counts a shared layer a step
+    under the path that summed it."""
 
     def __init__(self, pipelines: list[PipelineInstance]):
         self.pipelines = pipelines
         self.owners: dict[int, list[PipelineInstance]] = {}
         for p in pipelines:
-            for li in p.params:
-                self.owners.setdefault(li, []).append(p)
-        # Observability for tests/benchmarks: batched cross-mesh device_put
-        # calls issued by the last do_allreduce (at most one per phase).
+            for st in p.stages:
+                for li in st.layer_ids:
+                    self.owners.setdefault(li, []).append(p)
+        # Cross-mesh programs the last do_allreduce issued: a group's
+        # collective counts one, the anchor path one batched device_put a
+        # phase.
         self.last_transfer_count = 0
-
-    # -- flat-buffer helpers ------------------------------------------- #
+        by_holders: dict[tuple, list[int]] = {}
+        for li, owners in sorted(self.owners.items()):
+            if len(owners) > 1:
+                by_holders.setdefault(tuple(
+                    self._group_key(p, li) for p in owners), []).append(li)
+        self.collective_groups: list[CollectiveGroup] = []
+        self.anchor_layers: list[int] = []
+        for holders, layers in by_holders.items():
+            owners = self.owners[layers[0]]
+            stages = [p.stages[si] for p, (_, si) in zip(owners, holders)]
+            if CollectiveGroup.congruent(stages, layers):
+                self.collective_groups.append(
+                    CollectiveGroup(layers, owners, stages))
+            else:
+                self.anchor_layers += layers
+        self._m_layer_sums = metrics.registry().counter(
+            "oobleck_dp_sync_layer_sums_total",
+            "Layers whose gradients were summed between the pipelines "
+            "that hold them, a layer a step, by path (collective: one "
+            "program over the owners' chips; anchor: packed copies to the "
+            "first owner and back)")
 
     @staticmethod
     def _group_key(pipe: PipelineInstance, li: int) -> tuple:
@@ -251,22 +436,41 @@ class DataParallelEngine:
         return (pipe.pipeline_id, pipe.stage_of_layer(li))
 
     def do_allreduce(self) -> dict[int, dict[int, Any]]:
-        """Returns {pipeline_id: {layer: synced_grad_tree}}.
+        """Returns {pipeline_id: {layer: synced_grad_tree}}: every owner's
+        tree on its own stage's shardings. A layer with one owner passes
+        through; a congruent group's layers are summed by the group's one
+        collective (`CollectiveGroup.summed_grads`); the rest take the
+        anchor path (`_anchor_sums`). A one-pipeline engine dispatches
+        nothing."""
+        synced: dict[int, dict[int, Any]] = {p.pipeline_id: {} for p in self.pipelines}
+        self.last_transfer_count = 0
+        for li, owners in self.owners.items():
+            if len(owners) == 1:
+                synced[owners[0].pipeline_id][li] = owners[0].grads[li]
+        for group in self.collective_groups:
+            for pipe, trees in zip(group.owners, group.summed_grads()):
+                synced[pipe.pipeline_id].update(zip(group.layers, trees))
+            self.last_transfer_count += 1
+            self._m_layer_sums.inc(len(group.layers), path="collective")
+        if self.anchor_layers:
+            self._anchor_sums(synced)
+            self._m_layer_sums.inc(len(self.anchor_layers), path="anchor")
+        return synced
+
+    def _anchor_sums(self, synced: dict[int, dict[int, Any]]) -> None:
+        """`self.anchor_layers` summed on their first owners and handed
+        back, into `synced`.
 
         Transfer granularity is (src stage) -> (anchor stage): one packed
         buffer per stage pair per direction, because a jitted program's
         inputs must share one mesh — a stage IS a mesh here. The
-        replicated-flat hop is the single-controller stand-in for the DCN
-        allreduce a multi-slice deployment would issue."""
-        synced: dict[int, dict[int, Any]] = {p.pipeline_id: {} for p in self.pipelines}
-        self.last_transfer_count = 0
+        replicated-flat hop stands in for a collective where the owners'
+        meshes have no common shape to build one over."""
         # Group shared layers by (src stage, anchor stage).
         fwd_groups: dict[tuple, list[int]] = {}
         anchors: dict[int, PipelineInstance] = {}
-        for li, owners in self.owners.items():
-            if len(owners) == 1:
-                synced[owners[0].pipeline_id][li] = owners[0].grads[li]
-                continue
+        for li in self.anchor_layers:
+            owners = self.owners[li]
             anchor = owners[0]
             anchors[li] = anchor
             for other in owners[1:]:
@@ -324,7 +528,6 @@ class DataParallelEngine:
                     [dst.stages[dst_st].param_shardings[li] for li in lis])
                 for li, tree in zip(lis, unpacked):
                     synced[dst.pipeline_id][li] = tree
-        return synced
 
 
 class MultiHostDataParallelEngine:

@@ -20,8 +20,9 @@ RecoveryPrecompiler closes that gap. On a background thread it
      only — no arrays, no optimizer state);
   3. AOT-lowers and compiles every process-local stage executable
      (fwd/bwd/efwd, plus best-effort gradient-sum fill and
-     optimizer-update programs) against abstract inputs carrying the exact
-     shardings the live path will dispatch with.
+     optimizer-update programs, and the collective that sums a layer's
+     gradients between congruent pipelines) against abstract inputs
+     carrying the exact shardings the live path will dispatch with.
 
 Warmth propagates through two layers:
 
@@ -85,6 +86,7 @@ class RecoveryPrecompiler:
             "grow_plans": 0,
         }
         self._done_keys: set = set()
+        self._layer_avals: dict | None = None   # made once, for `_aot_dp_sums`
         self._thread: threading.Thread | None = None
         self._cancel = threading.Event()
 
@@ -129,6 +131,7 @@ class RecoveryPrecompiler:
                     if self._cancel.is_set():
                         break
                     self._aot_pipeline(pipe)
+                self._aot_dp_sums(pipes)
         except Exception:
             # The walk itself failing (planner infeasibility at the root,
             # model without sample_batch, ...) degrades to cold recovery.
@@ -194,6 +197,11 @@ class RecoveryPrecompiler:
                 "predicted loss of host %d: degrade verdict %s",
                 lost, rep.as_record()["reason"],
             )
+            # A reroute keeps the surviving pipelines as they are and sums
+            # between them alone: another set of owners, another program.
+            self._aot_dp_sums([p for p, hosts in zip(live_pipelines,
+                                                     frontier[0])
+                               if lost not in hosts])
         seen_groups: set = set()
         for _ in range(self.depth):
             next_frontier = []
@@ -406,6 +414,40 @@ class RecoveryPrecompiler:
             self.stats["errors"] += 1
             logger.debug("aux AOT warm failed for stage %d chunk %d",
                          st.stage_index, c, exc_info=True)
+
+    def _aot_dp_sums(self, pipes) -> None:
+        """Build the gradient sum between the plan's pipelines where it is
+        a collective (`engine.CollectiveGroup`: a layer's owners on
+        congruent stages). Its executable never comes from the persistent
+        cache (`engine.dp_sum_program`): `PROGRAMS` holds it, and the
+        data-parallel engine the re-instantiation builds over equal
+        pipelines takes it from there, in this process. The anchor path's
+        programs take their operands' shapes as they come and are not
+        warmed."""
+        from oobleck_tpu.execution.engine import DataParallelEngine
+        from oobleck_tpu.parallel.cross_host import layer_avals
+
+        if self.engine.multihost or len(pipes) < 2:
+            return
+        for group in DataParallelEngine(pipes).collective_groups:
+            # One model a walk: the owners' chips and the layers say the rest.
+            key = ("dp_sum", group.mesh, group.layers)
+            if self._cancel.is_set():
+                return
+            if key in self._done_keys:
+                continue
+            try:
+                if self._layer_avals is None:
+                    self._layer_avals = layer_avals(self.engine.model)
+                with background.device_work("precompile"):
+                    group.program(jax.tree.leaves(
+                        [self._layer_avals[li] for li in group.layers]))
+                self._done_keys.add(key)
+                self.stats["aux_compiled"] += 1
+            except Exception:
+                self.stats["errors"] += 1
+                logger.debug("gradient-sum AOT warm failed for layers %s",
+                             list(group.layers), exc_info=True)
 
     def _aot_opt_update(self, layer_ids, st, params_avals) -> None:
         import optax

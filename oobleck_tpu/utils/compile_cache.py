@@ -19,6 +19,19 @@ readback — not every time (10 of 20 warm runs of tests/test_smoke.py -k
 fused; 0 of 10 cold or cache-off runs), with intact entries written by the
 same machine. An abort takes a whole pytest process with it, so a CPU
 world must not read such an entry at all, whoever set the directory.
+
+On a TPU one kind of executable must not come back from the cache either,
+and `compile_unpersisted` compiles it so that no entry is left: a program
+with a collective over a SUBSET of the process's chips (PR 63: the
+gradient sum between two pipelines' chips, `execution/engine.
+dp_sum_program`). Compiled in the process it runs; where two such programs
+(chips 0, 2 and 1, 3 of a v5e 2x2; 0, 1 and 2, 3 the same) were READ from
+the cache, the first run halted the chip ("Core halted unexpectedly ...
+schecklt: Invalid logical z: enhanced-barrier-parent-phase-1", then "The
+program continuator has halted unexpectedly" at the next readback) in
+every one of five warm processes, where one such program alone (0, 2) and
+a program over all four chips read back sound (jax / jaxlib 0.9.0,
+libtpu 0.0.34; `chiprun_out/t63c`, `t63d`).
 """
 
 from __future__ import annotations
@@ -214,3 +227,23 @@ def ensure_persistent_cache() -> str | None:
         jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
         cache_event("enabled")
     return d
+
+
+def compile_unpersisted(jitted, *operands):
+    """`jitted.lower(*operands).compile()`, leaving no entry in the
+    persistent cache, so that no later process reads the executable back
+    (the module's docstring says which executables, and why). JAX writes an
+    entry only where the compile took `jax_persistent_cache_min_compile_
+    time_secs` at the least: that is raised for the length of this compile.
+    The flag is the process's: call it where other compiles are held off
+    (`utils/background.device_work`), as every caller does. The caller
+    keeps the executable for the life of the process."""
+    import jax
+
+    name = "jax_persistent_cache_min_compile_time_secs"
+    before = getattr(jax.config, name)
+    jax.config.update(name, float("inf"))
+    try:
+        return jitted.lower(*operands).compile()
+    finally:
+        jax.config.update(name, before)

@@ -133,6 +133,22 @@ def _empty_flight_ring():
     yield
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _empty_metrics_registry():
+    """Every test module starts from an empty metrics registry, as it
+    starts from an empty flight ring. The registry is process-global and a
+    family's series outlive the test that made them: a reader that takes
+    every series of a family (`benchmarks/readers/moe_gmm_roofline_pct.py`:
+    pairs a token by routed layer) read a THIRD layer, left by whichever
+    routed model the worker had run before, in one whole run of two
+    (`test_bench_deepseek_v3.py::test_runner_control_flow_on_the_cpu`,
+    PR 63), by how `--dist loadfile` happened to deal the files out."""
+    from oobleck_tpu.utils import metrics
+
+    metrics.registry().clear()
+    yield
+
+
 @pytest.fixture
 def as_on_tpu(monkeypatch):
     """Every kernel module takes its kernels' path, compiled, as on a TPU:

@@ -242,3 +242,61 @@ def test_optimizers_share_an_update_only_when_built_from_equal_arguments(
     # (Adam's first step does not see a gradient's scale, so not its clip.)
     assert "max_grad_norm" in other or any(
         not np.array_equal(got[name], other_got[name]) for name in p)
+
+
+# --------------------------------------------------------------------- #
+# (e): the gradient sum's collective after a recovery, warmed by the walk
+
+
+@pytest.mark.parametrize("hosts,pipelines_after", [
+    (3, 2),     # three one-host pipelines, one lost: the two that are left
+    (4, 3),     # four, one lost: re-instantiated as three
+])
+def test_a_re_plan_s_first_gradient_sum_compiles_nothing(
+        cache_env, devices8, hosts, pipelines_after):  # noqa: F811
+    """After `start_recovery_precompile(wait=True)` a recovery onto
+    congruent pipelines finds its collective's EXECUTABLE in the table,
+    under a key the first layout's did not have (other owners, another
+    mesh): the recovery and the first `do_allreduce` after it add no such
+    key and compile no `dp_sum`. (The table, not the persistent cache, is
+    what carries it: `engine.dp_sum_program`.)"""
+    PROGRAMS.clear()
+    sums = lambda: {k for k in PROGRAMS if k[0] == "dp_sum"}
+    meshes = lambda keys: {k[1][0].mesh for k in keys}
+    counter = _CompileCounter()
+    jax.monitoring.register_event_duration_secs_listener(counter)
+    try:
+        engine = make_engine(num_hosts=hosts, steps=8,
+                             devices=devices8[:hosts])
+        engine.initialize_distributed()
+        engine.instantiate_pipelines(engine.args.job.global_num_microbatch)
+        engine._train_step()
+        first_layout = sums()
+        assert len(first_layout) == len(
+            engine.dp_engine.collective_groups) > 0
+        assert len(counter.take("jit(dp_sum)")) == len(first_layout)
+
+        pc = engine.start_recovery_precompile(wait=True)
+        assert pc.stats["errors"] == 0, pc.stats
+        warmed = sums()
+        assert warmed > first_layout
+        # The walk compiled the other layouts' sums, and not the live one's.
+        assert len(counter.take("jit(dp_sum)")) == len(warmed - first_layout)
+
+        engine.reconfigure("10.0.0.1")
+        engine._precompiler.wait()  # re-armed, for the NEXT loss's layouts
+        counter.take()
+        groups = engine.dp_engine.collective_groups
+        assert len(engine.pipelines) == pipelines_after
+        assert groups and not engine.dp_engine.anchor_layers
+        assert all(len(g.owners) == pipelines_after for g in groups)
+        after_recovery = sums()
+        loss = engine._train_step()
+        assert np.isfinite(loss)
+        assert engine.dp_engine.last_transfer_count == len(groups)
+        assert counter.take("jit(dp_sum)") == []
+        assert sums() == after_recovery
+        mine = {g.mesh for g in groups}
+        assert mine <= meshes(warmed) and not mine & meshes(first_layout)
+    finally:
+        counter.on = False
