@@ -58,6 +58,7 @@ from oobleck_tpu.execution.reconfigure import (
     reconfigure_hosts,
 )
 from oobleck_tpu.models import build_model
+from oobleck_tpu.models.base import applied_param_count, passes_of, repeated
 from oobleck_tpu.obs import goodput as obs_goodput
 from oobleck_tpu.obs import incident as obs_incident
 from oobleck_tpu.obs import spans as obs_spans
@@ -853,12 +854,14 @@ class OobleckEngine:
             )
         # What the largest carry any layer hands the next takes for one
         # microbatch: what a stage edge would ship were the list cut there,
-        # and what the planner was charged for it (`mem_activation`).
+        # and what the planner was charged for it (`mem_activation`: of a
+        # layer the model repeats, once a pass).
         metrics.registry().gauge(
             "oobleck_pipeline_carry_bytes_max",
             "Bytes of the largest carry (one microbatch) any layer of the "
             "model hands the next, from the planner's layer profiles",
-        ).set(max(p.mem_activation for p in self.profiles[:-1]))
+        ).set(max(p.mem_activation // passes_of(self.model, p.layer_index)
+                  for p in self.profiles[:-1]))
 
         # Cluster geometry: hosts partition the device list. Ranks encode
         # ORIGINAL host indices (rank = original_index * chips_per_host +
@@ -1956,9 +1959,14 @@ class OobleckEngine:
             )
 
             cfg = self.model.config
+            # Of a model that repeats layers: the parameters a token is
+            # multiplied through and the attention layers it visits, each
+            # once a pass. Of every other model, its parameters and layers.
+            passes = repeated(self.model)[1]
             fpt = estimate_flops_per_token(
-                count_params(self.model), self.seq_len,
-                num_layers=getattr(cfg, "num_layers", 0),
+                applied_param_count(self.model) if passes > 1
+                else count_params(self.model), self.seq_len,
+                num_layers=passes * getattr(cfg, "num_layers", 0),
                 hidden_size=getattr(cfg, "hidden_size", 0),
             )
         except Exception as e:  # MFU is best-effort; training never pays

@@ -43,6 +43,20 @@ single-controller JAX runtime:
     where it reads the loss. `fwd` and `eval_fwd` have no such output, a
     chunk without a routed layer has none anywhere, and with
     `OOBLECK_TELEMETRY=0` no chunk has;
+  * a model may say that a range of its layers is gone through several
+    times a microbatch over one set of weights (`models/base.py`: the
+    layer-list contract's `repeated_layers`, `num_passes`). Nothing in the
+    job says so; the pipeline derives the VISITS. Where ONE stage holds the
+    whole range its chunk's walk repeats the range inside the one program
+    (`StageRuntime.walks`: a folded loop; autodiff sums a weight's uses).
+    Where the template's cut falls inside the range, every stage holds
+    `num_passes` chunks that are THE SAME repeated layers (chunk 0 with
+    the stage's layers in front of the range, the last chunk with those
+    behind it), scheduled as the interleaved schedule schedules `v`
+    chunks: the carry leaving the last stage that holds repeated layers
+    goes back to the first. Either way a layer's parameters are held once
+    and its gradient sum, keyed by layer, takes every visit's addition
+    before anything downstream reads it;
   * the jitted programs live in ONE table for the life of the process
     (`PROGRAMS`), keyed by everything their traces read: re-instantiation
     after a failure finds what the recovery precompiler's predicted layouts
@@ -72,6 +86,7 @@ from oobleck_tpu.execution.schedule import (
     send_grad_dest,
     validate_interleaving,
 )
+from oobleck_tpu.models.base import layer_walk, repeated
 from oobleck_tpu.obs import spans, telemetry
 from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.planning.templates import PipelineTemplate
@@ -79,6 +94,12 @@ from oobleck_tpu.utils import metrics
 
 logger = logging.getLogger("oobleck.pipeline")
 
+
+# Where ONE stage holds a model's whole repeated range, its visits are
+# folded into the stage's one program. False schedules them as chunks there
+# too (a backward then runs its visit's forward a second time): the switch
+# of a measurement or a test, which no argument and no environment reads.
+FOLD_WHOLE_RANGE = True
 
 _ORDER_CACHE: dict[tuple[int, int, int], list[Instruction]] = {}
 
@@ -228,6 +249,10 @@ class StageRuntime:
     # (== layer_ids) under canonical 1F1B; v entries interleaved, chunk c
     # being virtual stage c*S + stage_index.
     chunks: tuple[tuple[int, ...], ...] = ()
+    # The layer applications of each chunk's program, in order: the chunk
+    # itself, but for a chunk that holds a model's whole repeated range
+    # folded (`num_passes` times the range).
+    walks: tuple[tuple[int, ...], ...] = ()
     tp: int = 1                            # tensor-parallel degree in-stage
     sp: int = 1                            # sequence-parallel degree in-stage
     use_fsdp: bool = False                 # params + batch sharded over fsdp
@@ -308,7 +333,11 @@ class PipelineInstance:
         holding chunks {c*S + i} — the template's chip assignment per
         physical stage is kept, its layer partition is superseded by the
         even v-way split (the template profiled a contiguous S-way cut; an
-        interleaved layout needs S*v cuts)."""
+        interleaved layout needs S*v cuts).
+
+        A model that repeats a range of its layers (`models/base.repeated`)
+        decides its own visits (module docstring) and takes no
+        `virtual_stages`."""
         assert len(ranks) == template.num_chips, (len(ranks), template.num_chips)
         self.pipeline_id = pipeline_id
         self.template = template
@@ -350,12 +379,42 @@ class PipelineInstance:
                     f"interleaved schedule needs at least num_stages * "
                     f"virtual_stages = {S * v} pipeline layers, model has {L}"
                 )
+        # What the model repeats: folded into one stage's program, or
+        # visited as `passes` chunks a stage.
+        rep, passes = repeated(model)
+        self.repeated_layers, self.num_passes = rep, passes
+        holders = [i for i, stage in enumerate(template.stages)
+                   if set(stage.layer_indices) & set(rep)]
+        visits = passes > 1 and not (FOLD_WHOLE_RANGE and len(holders) == 1)
+        if passes > 1 and v > 1:
+            raise ValueError(
+                f"{type(model).__name__} goes through layers {rep[0]}.."
+                f"{rep[-1]} {passes} times: its visits are its chunks, and "
+                f"virtual_stages={v} cannot be laid over them")
+        if visits:
+            v = passes
+            validate_interleaving(S, num_microbatches, v)
         self.virtual_stages = v
         # chunks_of_stage[i][c] = layer range of virtual stage c*S + i. The
         # template's layer cut stands when v == 1; interleaving re-cuts the
         # model into S*v even contiguous ranges (the template only profiled
         # an S-way cut) while keeping the template's chip assignment.
-        if v == 1:
+        # A looped model's visits: chunk c of a stage is the stage's share
+        # of the repeated range, the same layers for every c, with the
+        # stage's layers in front of the range on the first visit alone and
+        # those behind it on the last alone (a stage without a repeated
+        # layer has empty chunks between: the carry passes through).
+        if visits:
+            chunks_of_stage = [
+                tuple(
+                    tuple(li for li in stage.layer_indices
+                          if li in rep or (li < rep[0] and c == 0)
+                          or (li > rep[-1] and c == v - 1))
+                    for c in range(v)
+                )
+                for stage in template.stages
+            ]
+        elif v == 1:
             chunks_of_stage = [
                 (tuple(stage.layer_indices),) for stage in template.stages
             ]
@@ -434,9 +493,9 @@ class PipelineInstance:
         self.stages: list[StageRuntime] = []
         cursor = 0
         for si, stage in enumerate(template.stages):
-            stage_layers = tuple(
+            stage_layers = tuple(dict.fromkeys(
                 li for ch in chunks_of_stage[si] for li in ch
-            )
+            ))
             stage_ranks = tuple(self.ranks[cursor:cursor + stage.num_chips])
             cursor += stage.num_chips
             stage_devices = np.array([devices[r] for r in stage_ranks])
@@ -531,6 +590,8 @@ class PipelineInstance:
                 param_shardings=param_shardings,
                 param_pspecs=param_pspecs,
                 chunks=chunks_of_stage[si],
+                walks=(chunks_of_stage[si] if visits else tuple(
+                    layer_walk(model, ch) for ch in chunks_of_stage[si])),
                 tp=tp,
                 sp=sp,
                 use_fsdp=use_fsdp,
@@ -539,6 +600,10 @@ class PipelineInstance:
                 process=stage_process,
                 is_local=stage_local,
             ))
+            if passes > 1 and self.stages[-1].ctx is not None:
+                raise ValueError(
+                    f"{type(model).__name__} repeats layers: generic stage "
+                    "path only (no tensor, sequence or manual fsdp split)")
 
         # Parameters: dict layer -> pytree placed on the owning stage's mesh.
         # Multi-host: only this process's stages materialize (remote device
@@ -618,7 +683,13 @@ class PipelineInstance:
             the environment;
           * the chunk's layers, which also say whether it is the first
             (layer 0: no `x`, reads the tokens) and the last (the model's
-            last layer: returns the loss);
+            last layer: returns the loss), and its WALK: the layers in the
+            order and as often as the program applies them (a folded loop
+            repeats a range `num_passes` times, the config's). A chunk's
+            place among a looped model's visits is in its layers (the
+            first has those in front of the range, the last those behind)
+            and nowhere else: the carry has one shape over all visits, so
+            the visits between are one program;
           * `st.ctx` and the specs of `x` and the tokens: `st.tp`, `st.sp`,
             `st.use_fsdp` (`st.manual` is the model's class's);
           * `st.mesh`, which the `shard_map` is built over: the stage's
@@ -647,15 +718,19 @@ class PipelineInstance:
         marks, tree = jax.tree.flatten(
             tuple(self._sums_in_kernel(st, li) for li in layers))
         return (
-            type(self.model), self.model.config, layers, st.mesh,
+            type(self.model), self.model.config, layers, st.walks[c], st.mesh,
             st.tp, st.sp, st.use_fsdp, self.microbatch_size, self.seq_len,
             self.total_num_microbatches, tuple(self._load_layers(st, c)),
             tuple(marks), tree,
         )
 
-    def _stage_apply(self, st: StageRuntime, layers: tuple[int, ...]):
+    def _stage_apply(self, st: StageRuntime, layers: tuple[int, ...],
+                     walk: tuple[int, ...]):
         """Stage program over one chunk's contiguous `layers` (== the whole
-        stage under canonical 1F1B; one of v chunks interleaved)."""
+        stage under canonical 1F1B; one of v chunks interleaved), applied
+        in the order of `walk` (`layers` itself, each once, but for a
+        folded loop, which names a layer several times: its parameters'
+        gradient is then the sum over the applications)."""
         model = self.model
         last_layer = model.num_pipeline_layers - 1
         remat = bool(getattr(model.config, "remat", False))
@@ -687,9 +762,11 @@ class PipelineInstance:
                 `load_layers`, the chunk's layers that are to hand out
                 their load: (that, their loads in layer order as ONE int32
                 [layers, held + 1] in a tuple, or () for no such layer)."""
-                carry, loads = x, []
+                carry, loads = x, {}
                 sums = sums or (None,) * len(layers)
-                for li, p, handed in zip(layers, params_tuple, sums):
+                held = dict(zip(layers, zip(params_tuple, sums)))
+                for li in walk:
+                    p, handed = held[li]
                     if li == last_layer:
                         logits = model.apply_layer(li, p, carry, batch)
                         loss = model.loss_from_logits(logits, batch)
@@ -704,11 +781,14 @@ class PipelineInstance:
                     give_load = li in (load_layers or ())
                     carry = layer_fn(li, handed, give_load)(p, carry, batch)
                     if give_load:
+                        # A layer applied several times says its load once:
+                        # the sum over its applications.
                         carry, load = carry
-                        loads.append(load)
+                        loads[li] = loads[li] + load if li in loads else load
                 if load_layers is None:
                     return carry
-                return carry, ((jnp.stack(loads),) if loads else ())
+                return carry, ((jnp.stack(list(loads.values())),)
+                               if loads else ())
 
             return apply
 
@@ -826,7 +906,12 @@ class PipelineInstance:
             if not st.is_local:
                 continue
             for c, chunk_layers in enumerate(st.chunks):
+                if not chunk_layers:
+                    # A visit on which this stage has nothing to apply
+                    # (`train_step` passes the carry through).
+                    continue
                 is_last = chunk_layers[-1] == last_layer
+                self._count_loop(st.walks[c], is_last)
                 in_kernel = tuple(
                     self._sums_in_kernel(st, li) for li in chunk_layers)
                 st.kernel_sums[c] = sum(jax.tree.leaves(in_kernel))
@@ -836,7 +921,7 @@ class PipelineInstance:
                     st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c] = (
                         PROGRAMS[key])
                     continue
-                apply = self._stage_apply(st, chunk_layers)
+                apply = self._stage_apply(st, chunk_layers, st.walks[c])
 
                 # What `bwd` differentiates: (the chunk's output, its
                 # loads in a tuple: of a chunk that hands out none, empty).
@@ -914,6 +999,55 @@ class PipelineInstance:
                 PROGRAMS[key] = (
                     st.fwd[c], st.bwd[c], st.efwd[c], st.zero[c])
 
+    def _count_loop(self, walk: tuple[int, ...], is_last: bool) -> None:
+        """What a local chunk's programs hold of a looped model:
+        applications of repeated layers, and exits. Counted where a
+        pipeline takes its programs, built or found, so a pipeline's
+        chunks add up to what ONE microbatch goes through, whoever
+        implements the loop."""
+        if self.num_passes == 1:
+            return
+        reg = metrics.registry()
+        reg.counter(
+            "oobleck_loop_block_visits_total",
+            "Applications of a model's repeated layers in the local chunks "
+            "of the pipelines instantiated in this process (one microbatch "
+            "each)",
+        ).inc(sum(li in self.repeated_layers for li in walk))
+        if is_last:
+            reg.counter(
+                "oobleck_loop_exits_total",
+                "Exits (one a pass) in the last chunks of the pipelines "
+                "instantiated in this process, of a model that repeats layers",
+            ).inc(self.num_passes)
+
+    def _edge_layer(self, stage: int, chunk: int) -> int:
+        """The last layer applied up to and including virtual stage
+        (stage, chunk): the layer whose carry leaves that chunk (a chunk
+        with nothing to apply passes on what it was given)."""
+        S = self.num_stages
+        vs = chunk * S + stage
+        while not self.stages[vs % S].chunks[vs // S]:
+            vs -= 1
+        return self.stages[vs % S].chunks[vs // S][-1]
+
+    def input_edge_layer(self, stage: int, chunk: int) -> int:
+        """The layer whose carry ENTERS virtual stage (stage, chunk), which
+        is not the first."""
+        vs = chunk * self.num_stages + stage - 1
+        return self._edge_layer(vs % self.num_stages, vs // self.num_stages)
+
+    def _zeros_for(self, st: StageRuntime, layers: tuple[int, ...]):
+        """Zero-filled gradient sums of `layers` alone: what a visit adds
+        to for the layers no earlier visit of the step has touched (the
+        embedding, on a looped model's first chunk)."""
+        shardings = tuple(st.param_shardings[li] for li in layers)
+        leaves, tree = jax.tree.flatten(shardings)
+        key = ("grad_zero", tuple(leaves), tree)
+        if key not in PROGRAMS:
+            PROGRAMS[key] = jax.jit(grad_zero, out_shardings=shardings)
+        return PROGRAMS[key](tuple(self.params[li] for li in layers))
+
     def _load_layers(self, st: StageRuntime, c: int) -> tuple[int, ...]:
         """The layers of stage `st`'s chunk `c` whose load the chunk's
         `bwd` hands out (`apply_layer(return_load=True)`): the model's
@@ -932,7 +1066,9 @@ class PipelineInstance:
         sum with them) and no partitioner stands between the donated sum
         and the kernel that writes into it."""
         marks = getattr(self.model, "sums_in_kernel", None)
-        if marks is None or st.mesh.size > 1:
+        # A repeated layer's sum takes several additions a microbatch and
+        # a kernel takes the sum once: XLA's add has it.
+        if marks is None or st.mesh.size > 1 or li in self.repeated_layers:
             return None
         return marks(li, st.param_shardings[li])
 
@@ -1071,6 +1207,8 @@ class PipelineInstance:
         # Gradient sums of local chunks: started from a zero fill, added
         # to inside a backward program.
         accumulated = {"backward": 0, "zero_fill": 0, "moe_tgmm": 0}
+        # A microbatch's visits of each local stage (its FORWARDs).
+        stage_visits: dict[int, int] = {}
 
         def record_op(stage, chunk, kind, dt):
             tot, n = op_times.get((stage, chunk, kind), (0.0, 0))
@@ -1124,6 +1262,10 @@ class PipelineInstance:
                 if not st.is_local:
                     return
                 flush_sends()
+                stage_visits[ins.stage] = stage_visits.get(ins.stage, 0) + 1
+                if not st.chunks[c]:
+                    stash[(ins.stage, c, m, "out")] = acts.pop(key)
+                    return
                 x = None if is_first else acts[key]
                 stash[key] = x
                 fwd_dispatches["folded" if is_last else "run"] += 1
@@ -1150,7 +1292,7 @@ class PipelineInstance:
                 if not (st.is_local or nxt.is_local):
                     return
                 y = stash.pop((ins.stage, c, m, "out"), None)
-                aval_layer = st.chunks[c][-1]
+                aval_layer = self._edge_layer(ins.stage, c)
                 if st.is_local and nxt.is_local:
                     if self.sync_op_timing and y is not None:
                         # Timed mode sends eagerly (no batching) so each
@@ -1180,6 +1322,9 @@ class PipelineInstance:
                 if not st.is_local:
                     return
                 flush_sends()
+                if not st.chunks[c]:
+                    stash[(ins.stage, c, m, "dx")] = gacts.pop(key)
+                    return
                 x = stash.pop(key)
                 mb = stage_batch[m] if stage_batch is not None else None
                 if self.sync_op_timing:
@@ -1190,13 +1335,19 @@ class PipelineInstance:
                 # The chunk's running sum goes in donated and comes back
                 # with this microbatch's gradients added; the step's first
                 # BACKWARD of a chunk starts it from zeros, so every
-                # microbatch runs the same program.
+                # microbatch runs the same program. The sums are keyed by
+                # LAYER: a looped model's visits name the same layers and
+                # add to the same sums.
                 chunk_layers, params = st.chunks[c], chunk_params(st, c)
-                if chunk_layers[0] in grads:
-                    acc = tuple(grads.pop(li) for li in chunk_layers)
-                else:
+                fresh = tuple(li for li in chunk_layers if li not in grads)
+                if len(fresh) == len(chunk_layers):
                     acc = st.zero[c](params)
                     accumulated["zero_fill"] += 1
+                else:
+                    if fresh:
+                        grads.update(zip(fresh, self._zeros_for(st, fresh)))
+                        accumulated["zero_fill"] += 1
+                    acc = tuple(grads.pop(li) for li in chunk_layers)
                 t0 = time.perf_counter()
                 if is_last:
                     loss, acc, dx, *load = st.bwd[c](params, acc, x, mb)
@@ -1224,7 +1375,7 @@ class PipelineInstance:
                 dx = stash.pop((ins.stage, c, m, "dx"), None)
                 # The gradient entering chunk (ins.stage, c) has the shape
                 # of the PRODUCING chunk's output activation.
-                aval_layer = prev.chunks[dc][-1]
+                aval_layer = self._edge_layer(ds, dc)
                 if st.is_local and prev.is_local:
                     if self.sync_op_timing and dx is not None:
                         # oobleck: allow[OBL002] -- opt-in per-op profiling mode
@@ -1281,6 +1432,13 @@ class PipelineInstance:
         for where, n in accumulated.items():
             if n:
                 accumulations.inc(n, where=where)
+        visited = metrics.registry().counter(
+            "oobleck_pipeline_stage_visits_total",
+            "Visits of a local pipeline stage by a microbatch (FORWARD "
+            "instructions dispatched to it): one a microbatch, `num_passes` "
+            "where a looped model's range is cut across stages")
+        for stage, n in stage_visits.items():
+            visited.inc(n, stage=str(stage))
         if not losses:
             return None  # last stage lives on another process
         loss = sum(losses[1:], start=losses[0]) / len(losses)
@@ -1304,7 +1462,9 @@ class PipelineInstance:
                 c = vs // S
                 is_last = vs == last_vs
                 out = None
-                if st.is_local:
+                if not st.chunks[c]:
+                    out = x
+                elif st.is_local:
                     stage_batch = placed[st.stage_index]
                     mb = stage_batch[m] if stage_batch is not None else None
                     params = tuple(self.params[li] for li in st.chunks[c])
@@ -1321,8 +1481,9 @@ class PipelineInstance:
                 else:
                     nxt = self.stages[(vs + 1) % S]
                     if st.is_local or nxt.is_local:
-                        x = self._move_edge(out, st, nxt,
-                                            aval_layer=st.chunks[c][-1])
+                        x = self._move_edge(
+                            out, st, nxt,
+                            aval_layer=self._edge_layer(st.stage_index, c))
                     else:
                         x = None
         self.last_eval_metrics = (

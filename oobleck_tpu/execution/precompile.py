@@ -328,6 +328,8 @@ class RecoveryPrecompiler:
             for c, chunk_layers in enumerate(st.chunks):
                 if self._cancel.is_set():
                     return
+                if not chunk_layers:
+                    continue  # a visit this stage passes through
                 is_first = chunk_layers[0] == 0
                 is_last = chunk_layers[-1] == last_layer
                 key = pipe.stage_program_key(st, c)
@@ -368,11 +370,12 @@ class RecoveryPrecompiler:
         )
         x_aval = None
         if not is_first:
-            # Chunks are globally contiguous in virtual-stage order, so the
-            # producing chunk's last layer is chunk_layers[0] - 1.
+            # The carry of the chunk before this one in virtual-stage order
+            # (`chunk_layers[0] - 1`'s, but where a looped model's visits
+            # bring the range's last layer's back to its first).
             x_aval = jax.tree.map(
                 lambda a: _sds(a, st.batch_sharding),
-                pipe._edge_aval(chunk_layers[0] - 1),
+                pipe._edge_aval(pipe.input_edge_layer(st.stage_index, c)),
             )
         mb_aval = None
         if st.needs_batch:

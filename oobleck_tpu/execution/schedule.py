@@ -21,6 +21,17 @@ min((S-1-i)*2 + (v-1)*S, v*M) forward units, but every unit is 1/v of the
 model, shrinking the bubble to (S-1)/(v*M+S-1). v=1 degenerates to exactly
 the canonical streams above (the interleaved warmup formula does not — it is
 special-cased, and the invariant tests pin that down).
+
+A LOOPED model's visits are the same streams. A model that goes through a
+range of its layers R times over one set of weights (`models/base.py`) and
+whose range a template cuts across stages runs with v = R: chunk c of a
+stage is the stage's share of the repeated range, THE SAME layers for every
+c (the first also holds the stage's layers in front of the range, the last
+those behind it), where the interleaved schedule's v chunks are v different
+layer ranges. Nothing here knows the difference: "stage S-1 hands chunk c
+straight to stage 0's chunk c+1" is the carry going back into the range's
+first layer, and the warm-up arithmetic is the same. The pipeline derives
+R from the model, never from `virtual_stages` (`execution/pipeline.py`).
 """
 
 from __future__ import annotations

@@ -138,6 +138,23 @@ register("Phi-4-mini-flash-reasoning")(lambda o: _phi4flash(o))
 register("phi4flash-tiny")(lambda o: _phi4flash(o, vocab_size=256, hidden_size=64, num_layers=8, num_heads=4, num_kv_heads=2, head_dim=16, sliding_window=8, intermediate_size=128, max_position_embeddings=128))
 
 
+def _ouro(overrides: dict[str, Any], **preset):
+    from oobleck_tpu.models.ouro import OuroConfig, OuroModel
+
+    return OuroModel(OuroConfig().override(**preset).override(**overrides))
+
+
+# Ouro family (`ouro`, looped language models): a stack of sandwich-norm
+# blocks a microbatch goes through `num_passes` times over one set of
+# weights, the final norm, an exit gate and an exit after every pass, the
+# loss over all exits under the gates' exit distribution. The defaults are
+# Ouro-2.6B's.
+register("ouro-2.6b")(lambda o: _ouro(o))
+# The published name.
+register("Ouro-2.6B")(lambda o: _ouro(o))
+register("ouro-tiny")(lambda o: _ouro(o, vocab_size=256, hidden_size=64, num_layers=2, num_passes=3, num_heads=4, num_kv_heads=4, head_dim=16, intermediate_size=128, max_position_embeddings=128))
+
+
 # Bloom family: GPT architecture with ALiBi position biases (no wpe)
 register("bloom-560m")(lambda o: _gpt(o, vocab_size=250880, hidden_size=1024, num_layers=24, num_heads=16, position_embedding="alibi"))
 register("bloom-7b1")(lambda o: _gpt(o, vocab_size=250880, hidden_size=4096, num_layers=30, num_heads=32, position_embedding="alibi"))

@@ -12,8 +12,10 @@ what `Op` is (`operator_out`), which blocks are routed (`is_routed`), what
 its router scores with (`router_score`), what its router READS
 (`router_reads`: `FF`'s normed input as the experts do, or `OP`'s, a router
 placed before the block's operator), its gated experts' activation
-(`expert_activation`) and what its norm is (`norm`: the
-plain RMSNorm unless it says otherwise); the feed-forwards (SwiGLU, or
+(`expert_activation`), what its norm is (`norm`: the
+plain RMSNorm unless it says otherwise) and what stands between a branch's
+output and the residual sum (`branch_out`: nothing, unless it says
+otherwise; `models/ouro.py` norms the output too, a sandwich); the feed-forwards (SwiGLU, or
 `W2 relu(W1 h)^2` for an entry without `w3`), the routed call
 (`ops/moe.routed_experts`), the shared expert added to the routed sum
 (times `sigmoid(h w_g)` where the entry has a gate `w_g`), the embedding
@@ -191,6 +193,11 @@ class RoutedShareModel:
     def norm(self, x, scale):
         """The RMSNorm in front of every branch and of the head."""
         return rms_norm(x, scale, self.config.norm_eps)
+
+    def branch_out(self, branch: str, p, out):
+        """What branch `branch` (`OP` or `FF`) of a block with parameters
+        `p` adds to the residual stream, given the branch's output."""
+        return out
 
     def _init_block(self, rng, block: int):
         raise NotImplementedError
@@ -386,7 +393,7 @@ class RoutedShareModel:
                 h = self.norm(x, p["ln_op"]["scale"])
                 if self.router_reads == OP:
                     router_in = h
-                x = x + self.operator_out(block, p, h)
+                x = x + self.branch_out(OP, p, self.operator_out(block, p, h))
                 continue
             h = self.norm(x, p["ln_ff"]["scale"])
             out = self.feed_forward(
@@ -396,7 +403,7 @@ class RoutedShareModel:
                 return_load=return_load)
             if extras:
                 out, *rest = out
-            x = x + out
+            x = x + self.branch_out(FF, p, out)
         return (x, *rest) if extras else x
 
     @jax.named_scope("lm_head")

@@ -189,7 +189,11 @@ def estimate_flops_per_token(n_params: int, seq_len: int, *,
                              num_layers: int = 0,
                              hidden_size: int = 0) -> float:
     """Training FLOPs per token: 6N for the matmuls (fwd+bwd) plus the
-    causal-attention term. Read by the engine's per-step MFU gauge."""
+    causal-attention term. Read by the engine's per-step MFU gauge. Of a
+    model that repeats layers `n_params` is its APPLIED parameters
+    (`models/base.applied_param_count`: a repeated layer's and the exits'
+    once a pass) and `num_layers` its attention layers times its passes:
+    6 x what it holds would read such a model low by the passes."""
     return 6.0 * n_params + 6.0 * (num_layers * hidden_size * seq_len)
 
 

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from oobleck_tpu.config import training_seq_len
-from oobleck_tpu.models.base import param_bytes
+from oobleck_tpu.models.base import param_bytes, passes_of
 from oobleck_tpu.planning.templates import LayerProfile
 
 # What a cached profile was measured WITH, beside the model and the tag: a
@@ -172,6 +172,12 @@ def profile_execution_layers(model, microbatch_size: int, seq_len: int | None = 
     Returns the reference's mb{N}.json rows: {forward, backward,
     mem_required: [param_bytes, activation_bytes]} per layer
     (cf. reference profile_execution_layers, profiler.py:41-123).
+
+    A layer the model repeats (`models/base.repeated`) is timed ONCE and
+    charged what a microbatch costs: its forward, its backward and the
+    bytes it hands on (under remat a visit saves its input, so R visits
+    save R) times its passes, its parameters once; `"passes"` says how
+    often, for a reader that wants one application's.
     """
     seq_len = training_seq_len(model.config, seq_len)
     rng = jax.random.PRNGKey(0)
@@ -187,6 +193,15 @@ def profile_execution_layers(model, microbatch_size: int, seq_len: int | None = 
 
     def _ones_like_tree(shapes):
         return jax.tree.map(lambda s: jnp.ones(s.shape, s.dtype), shapes)
+
+    def charged(row: dict, idx: int) -> dict:
+        passes = passes_of(model, idx)
+        if passes == 1:
+            return dict(row)
+        params, saved = row["mem_required"]
+        return {"forward": row["forward"] * passes,
+                "backward": row["backward"] * passes,
+                "mem_required": [params, saved * passes], "passes": passes}
 
     for idx in range(model.num_pipeline_layers):
         name = model.layer_name(idx)
@@ -210,7 +225,7 @@ def profile_execution_layers(model, microbatch_size: int, seq_len: int | None = 
         out_t = jax.eval_shape(fwd, x0, params)
         reused = proto_rows.get(prefix) if prefix else None
         if reused is not None:
-            results.append(dict(reused))
+            results.append(charged(reused, idx))
             carry_t = out_t
             continue
         pbytes = param_bytes(params)
@@ -254,7 +269,7 @@ def profile_execution_layers(model, microbatch_size: int, seq_len: int | None = 
         }
         if prefix:
             proto_rows[prefix] = row
-        results.append(row)
+        results.append(charged(row, idx))
         carry_t = out_t
     return results
 
