@@ -48,7 +48,12 @@ single-controller JAX runtime:
     layer-list contract's `repeated_layers`, `num_passes`). Nothing in the
     job says so; the pipeline derives the VISITS. Where ONE stage holds the
     whole range its chunk's walk repeats the range inside the one program
-    (`StageRuntime.walks`: a folded loop; autodiff sums a weight's uses).
+    (`StageRuntime.walks`: a folded loop), and the program runs the repeats
+    as the trips of ONE `lax.scan` whose body is the range's layers once:
+    the carry has one tree and one set of shapes over the whole range, so
+    it is the loop's carry as it is the pipeline's, the parameters are
+    closed over, and autodiff sums a weight's gradient over the trips. The
+    program's text holds the range once however many passes there are.
     Where the template's cut falls inside the range, every stage holds
     `num_passes` chunks that are THE SAME repeated layers (chunk 0 with
     the stage's layers in front of the range, the last chunk with those
@@ -176,15 +181,75 @@ def load_sum(loads):
     return sum(loads[1:], start=loads[0])
 
 
-def _accumulate(acc, backward, *, layers, in_kernel):
+def fold_of(walk: tuple[int, ...]):
+    """(front, body, trips, behind) of a chunk's walk, `front + body * trips
+    + behind`: the layers applied once in front of what the walk repeats,
+    the repeated range, how often, and the layers behind it. A walk that
+    repeats nothing is all `front` (no body, one trip)."""
+    body = tuple(li for li in dict.fromkeys(walk) if walk.count(li) > 1)
+    if not body:
+        return walk, (), 1, ()
+    first, trips = walk.index(body[0]), walk.count(body[0])
+    front, behind = walk[:first], walk[first + len(body) * trips:]
+    assert front + body * trips + behind == walk, walk
+    return front, body, trips, behind
+
+
+# A folded loop's gradient sums RIDE THE LOOP'S CARRY. Autodiff of a scan
+# sums a closed-over weight's gradient over the trips in accumulators of
+# its own, filled with zeros before the backward loop and added to the
+# chunk's running sum after it: a second set of the repeated layers'
+# float32 gradients in the program's temporaries and two more passes over
+# them a microbatch. Instead the running sum itself is the accumulator:
+# beside the pipeline's carry the loop carries, per repeated layer, a tree
+# shaped as its parameters whose VALUE nothing reads (the parameters stand
+# in) and whose COTANGENT is the sum. `_route` sends a trip's gradient of a
+# weight into that cotangent instead of the weight's own, `_carried` keeps
+# the tree a carry (a carry the body hands on untouched is made a constant
+# of the scan, and a constant's gradient is summed from zeros), and
+# `_seeded` starts the backward loop's cotangent from the running sum. The
+# parameters' gradient then arrives as running sum + every trip's gradient.
+
+
+@jax.custom_vjp
+def _route(p, riding):
+    """`p`, whose gradient goes to `riding`'s cotangent."""
+    return p
+
+
+_route.defvjp(lambda p, riding: (p, None), lambda _, ct: (None, ct))
+
+
+@jax.custom_vjp
+def _carried(riding):
+    """`riding` as it is, and not for `lax.scan` to see through."""
+    return riding
+
+
+_carried.defvjp(lambda riding: (riding, None), lambda _, ct: (ct,))
+
+
+@jax.custom_vjp
+def _seeded(carry, riding, sums):
+    """`carry`; `riding`'s cotangent starts from `sums`."""
+    return carry
+
+
+_seeded.defvjp(lambda carry, riding, sums: (carry, sums),
+               lambda sums, ct: (ct, sums, None))
+
+
+def _accumulate(acc, backward, *, layers, below, looped):
     """A chunk's new gradient sum and whatever else its backward gives.
-    `backward(**sums)` returns (grads, rest). `in_kernel` marks, a layer,
-    the leaves the model sums in a kernel (a tree of booleans, or None):
-    those leaves of `acc` go down as `sums` (`ops/moe.GradSum`, named
-    after layer and leaf), each is taken by exactly one kernel call or the
-    trace fails, and they come back in `grads` as sum + gradient. Every
-    other leaf is `acc + grads`."""
-    if not any(jax.tree.leaves(in_kernel)):
+    `backward(**sums)` returns (grads, rest). `below` marks, a layer, the
+    leaves whose sum goes down into the program (a tree of booleans, or
+    None): those leaves of `acc` go down as `sums` and come back in `grads`
+    as sum + gradient. Of a layer in `looped` that is every leaf, as it
+    is: the loop's carry takes them (`_route`). Of another layer they are
+    the leaves the model sums in a kernel (`ops/moe.GradSum`, named after
+    layer and leaf), each taken by exactly one kernel call or the trace
+    fails. Every other leaf is `acc + grads`."""
+    if not any(jax.tree.leaves(below)):
         grads, rest = backward()
         return jax.tree.map(jnp.add, acc, grads), rest
     from oobleck_tpu.ops import moe
@@ -200,15 +265,17 @@ def _accumulate(acc, backward, *, layers, in_kernel):
 
         if marks is None:
             return None
+        if li in looped:
+            return layer_acc
         return jax.tree_util.tree_map_with_path(leaf, layer_acc, marks)
 
-    sums = tuple(map(hand, layers, in_kernel, acc))
+    sums = tuple(map(hand, layers, below, acc))
     with moe.handing_sums(names):
         grads, rest = backward(sums=sums)
     new = tuple(
         jax.tree.map(jnp.add, a, g) if marks is None else jax.tree.map(
             lambda a_, g_, on: g_ if on else a_ + g_, a, g, marks)
-        for a, g, marks in zip(acc, grads, in_kernel))
+        for a, g, marks in zip(acc, grads, below))
     return new, rest
 
 
@@ -707,16 +774,16 @@ class PipelineInstance:
             (the model's `load_layers` at `microbatch_size * seq_len`
             tokens, `st.ctx` and the telemetry ring's switch decide them):
             whether that output is there.
-        `_sums_in_kernel`, `_accumulate`:
+        `_sums_below`, `_accumulate`:
           * the marks themselves, flattened (the model's `sums_in_kernel`,
-            `st.mesh.size` and the backend decide them).
+            `st.mesh.size` and the backend decide them; the walk, which
+            layers' sums ride a folded loop's carry).
         Not in the key, because a process has ONE: the backend
         (`ops/kernel.on_tpu`) and JAX's configuration flags. Read by
         no trace: the pipeline's id and own microbatch count
         (`adopt_microbatches`), the stage's ranks and owning process."""
         layers = st.chunks[c]
-        marks, tree = jax.tree.flatten(
-            tuple(self._sums_in_kernel(st, li) for li in layers))
+        marks, tree = jax.tree.flatten(self._sums_below(st, c))
         return (
             type(self.model), self.model.config, layers, st.walks[c], st.mesh,
             st.tp, st.sp, st.use_fsdp, self.microbatch_size, self.seq_len,
@@ -728,9 +795,13 @@ class PipelineInstance:
                      walk: tuple[int, ...]):
         """Stage program over one chunk's contiguous `layers` (== the whole
         stage under canonical 1F1B; one of v chunks interleaved), applied
-        in the order of `walk` (`layers` itself, each once, but for a
-        folded loop, which names a layer several times: its parameters'
-        gradient is then the sum over the applications)."""
+        in the order of `walk`: `layers` itself, each once in a Python
+        loop, but for a folded loop, which names a range several times.
+        There the layers in front run as ever, the range's repeats are the
+        trips of one `lax.scan` over the range's layers (`fold_of`: the
+        carry keeps one tree and one set of shapes over the range, so it
+        can be a loop's), then the layers behind; a repeated layer's
+        gradient is the sum over the trips, and so is its load."""
         model = self.model
         last_layer = model.num_pipeline_layers - 1
         remat = bool(getattr(model.config, "remat", False))
@@ -756,35 +827,72 @@ class PipelineInstance:
                     fn = checkpoint_layer(fn)
                 return fn
 
+            ends = walk[-1] == last_layer
+            front, body, trips, behind = fold_of(walk[:-1] if ends else walk)
+
             def apply(params_tuple, x, batch, with_metrics=False, sums=None,
                       load_layers=None):
                 """The chunk's output (the carry, or the loss). With
                 `load_layers`, the chunk's layers that are to hand out
                 their load: (that, their loads in layer order as ONE int32
                 [layers, held + 1] in a tuple, or () for no such layer)."""
-                carry, loads = x, {}
                 sums = sums or (None,) * len(layers)
                 held = dict(zip(layers, zip(params_tuple, sums)))
-                for li in walk:
-                    p, handed = held[li]
-                    if li == last_layer:
-                        logits = model.apply_layer(li, p, carry, batch)
-                        loss = model.loss_from_logits(logits, batch)
-                        if with_metrics:
-                            # Task metric next to the loss (the reference
-                            # builds an accuracy metric the engine never
-                            # reports, dataset.py:39-54 — reported here).
-                            c, n = model.accuracy_from_logits(logits, batch)
-                            return loss, c, n
-                        carry = loss
-                        break
-                    give_load = li in (load_layers or ())
-                    carry = layer_fn(li, handed, give_load)(p, carry, batch)
-                    if give_load:
-                        # A layer applied several times says its load once:
-                        # the sum over its applications.
-                        carry, load = carry
-                        loads[li] = loads[li] + load if li in loads else load
+
+                def through(lis, carry, held=held):
+                    """The carry after the layers `lis`, each applied once,
+                    and {layer: load} of those that hand theirs out."""
+                    loads = {}
+                    for li in lis:
+                        p, handed = held[li]
+                        give_load = li in (load_layers or ())
+                        carry = layer_fn(li, handed, give_load)(
+                            p, carry, batch)
+                        if give_load:
+                            carry, loads[li] = carry
+                    return carry, loads
+
+                carry, loads = through(front, x)
+                if body:
+                    # The passes are the trips of ONE loop: the carry has
+                    # one tree and one set of shapes over the whole range
+                    # (`models/base.py`), so it is the loop's, and the
+                    # parameters and the batch are closed over. A weight's
+                    # gradient is summed over the trips: into the running
+                    # sum where one was handed down (`_route`), by
+                    # autodiff's own accumulators otherwise. A layer
+                    # applied several times says its load once, the sum.
+                    ps = {li: held[li][0] for li in body}
+                    running = {li: held[li][1] for li in body}
+                    rides = None not in running.values()
+
+                    def one_pass(state, _):
+                        c, riding = state
+                        used = _route(ps, riding) if rides else ps
+                        c, by_layer = through(
+                            body, c, {li: (used[li], None) for li in body})
+                        return (c, _carried(riding) if rides else None), (
+                            by_layer)
+
+                    (carry, riding), by_trip = jax.lax.scan(
+                        one_pass, (carry, ps if rides else None), None,
+                        length=trips)
+                    if rides:
+                        carry = _seeded(carry, riding, running)
+                    loads.update(jax.tree.map(lambda l: l.sum(0), by_trip))
+                    carry, more = through(behind, carry)
+                    loads.update(more)
+                if ends:
+                    p, _ = held[last_layer]
+                    logits = model.apply_layer(last_layer, p, carry, batch)
+                    loss = model.loss_from_logits(logits, batch)
+                    if with_metrics:
+                        # Task metric next to the loss (the reference
+                        # builds an accuracy metric the engine never
+                        # reports, dataset.py:39-54 — reported here).
+                        c, n = model.accuracy_from_logits(logits, batch)
+                        return loss, c, n
+                    carry = loss
                 if load_layers is None:
                     return carry
                 return carry, ((jnp.stack(list(loads.values())),)
@@ -912,9 +1020,11 @@ class PipelineInstance:
                     continue
                 is_last = chunk_layers[-1] == last_layer
                 self._count_loop(st.walks[c], is_last)
-                in_kernel = tuple(
-                    self._sums_in_kernel(st, li) for li in chunk_layers)
-                st.kernel_sums[c] = sum(jax.tree.leaves(in_kernel))
+                below = self._sums_below(st, c)
+                looped = fold_of(st.walks[c])[1]
+                st.kernel_sums[c] = sum(jax.tree.leaves([
+                    marks for li, marks in zip(chunk_layers, below)
+                    if li not in looped]))
                 load_layers = st.load_layers[c] = self._load_layers(st, c)
                 key = self.stage_program_key(st, c)
                 if key in PROGRAMS:
@@ -927,7 +1037,8 @@ class PipelineInstance:
                 # loads in a tuple: of a chunk that hands out none, empty).
                 loaded = functools.partial(apply, load_layers=load_layers)
                 accumulate = functools.partial(
-                    _accumulate, layers=chunk_layers, in_kernel=in_kernel)
+                    _accumulate, layers=chunk_layers, below=below,
+                    looped=looped)
                 # The sum leaves each program where it came in: the
                 # parameters' own shardings, or the donation does not take.
                 acc_shardings = tuple(
@@ -1001,12 +1112,14 @@ class PipelineInstance:
 
     def _count_loop(self, walk: tuple[int, ...], is_last: bool) -> None:
         """What a local chunk's programs hold of a looped model:
-        applications of repeated layers, and exits. Counted where a
+        applications of repeated layers, the passes among them that are
+        one loop's trips (`fold_of`), and exits. Counted where a
         pipeline takes its programs, built or found, so a pipeline's
         chunks add up to what ONE microbatch goes through, whoever
         implements the loop."""
         if self.num_passes == 1:
             return
+        _, body, trips, _ = fold_of(walk)
         reg = metrics.registry()
         reg.counter(
             "oobleck_loop_block_visits_total",
@@ -1014,6 +1127,12 @@ class PipelineInstance:
             "of the pipelines instantiated in this process (one microbatch "
             "each)",
         ).inc(sum(li in self.repeated_layers for li in walk))
+        reg.counter(
+            "oobleck_loop_scanned_passes_total",
+            "Passes that are the trips of ONE loop inside a local chunk's "
+            "program (a folded walk: `num_passes`; passes that are visits "
+            "of a stage: 0), in the pipelines instantiated in this process",
+        ).inc(trips if body else 0)
         if is_last:
             reg.counter(
                 "oobleck_loop_exits_total",
@@ -1057,6 +1176,19 @@ class PipelineInstance:
         if st.ctx is not None or not telemetry.telemetry().enabled:
             return ()
         return tuple(li for li in st.chunks[c] if li in self.load_info)
+
+    def _sums_below(self, st: StageRuntime, c: int) -> tuple:
+        """A layer of stage `st`'s chunk `c`, the leaves whose running
+        gradient sum goes down into the chunk's backward program
+        (`_accumulate`): a tree of booleans over the layer's parameters,
+        or None. Every leaf of a layer the chunk's walk repeats (its sum
+        rides the folded loop's carry), and what `_sums_in_kernel` says of
+        another."""
+        looped = fold_of(st.walks[c])[1]
+        return tuple(
+            jax.tree.map(lambda _: True, st.param_shardings[li])
+            if li in looped else self._sums_in_kernel(st, li)
+            for li in st.chunks[c])
 
     def _sums_in_kernel(self, st: StageRuntime, li: int):
         """The leaves of layer `li` whose running gradient sum goes down

@@ -93,7 +93,11 @@ def layer_walk(model, layers=None) -> tuple[int, ...]:
     """The layer applications of one microbatch over `layers` (default: the
     whole list), in order: the repeated range `num_passes` times where
     `layers` holds ALL of it, every layer once otherwise (a part of the
-    range is one visit's share; who holds it schedules the visits)."""
+    range is one visit's share; who holds it schedules the visits). Who
+    runs a walk that repeats may run the repeats as the trips of one loop
+    over the range (`execution/pipeline.py` does, a `lax.scan`): the carry
+    into the range's first layer and out of its last is one tree of one
+    set of shapes, which is all a loop's carry has to be."""
     layers = tuple(range(model.num_pipeline_layers)
                    if layers is None else layers)
     rep, passes = repeated(model)

@@ -45,7 +45,10 @@ head, whichever pass and whichever block: `{"h": [B, S, E], "exits":
 [R, B, S, E], "gates": [R, B, S] float32}`. A close pushes its pass's
 normed state and gate logit in at the END of `exits` and `gates` and drops
 the oldest, so after R closes they hold passes 1 .. R in order and no layer
-needs to be told which pass it is in: a stage's R visits are one program.
+needs to be told which pass it is in: a stage's R visits are one program,
+and where one stage holds all the blocks the R passes are the R trips of
+one loop inside that program (`execution/pipeline.py`: a `lax.scan` over
+the blocks, this carry the loop's, the weights closed over).
 The head takes the exits ONE AFTER ANOTHER, each under a checkpoint of its
 own (one exit's float32 logits alive at a time, forward and backward), and
 hands `loss_from_logits` the R per-position cross-entropies and the gate

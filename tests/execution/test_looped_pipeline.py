@@ -10,7 +10,13 @@ embedding alone. Each against the plain reference
 what holds whatever the route: a layer's gradient sum is zero-filled once a
 step and takes every visit's addition, the key holds the walk, the
 precompiler's walk compiles such a pipeline's programs, a re-cut pipeline
-takes the old parameters, and what the constructor refuses."""
+takes the old parameters, and what the constructor refuses. And what the
+fold IS: the passes are the trips of ONE `lax.scan` over the range's layers
+(the body once in the program however many passes), a walk that repeats
+nothing traces no loop, and a repeated layer's load is the sum over the
+trips."""
+
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -258,9 +264,14 @@ def test_the_loop_s_counters_say_what_a_microbatch_goes_through(
         model, devices8):
     before = [counter("oobleck_loop_block_visits_total"),
               counter("oobleck_loop_exits_total")]
-    for splits, fold in (([(0, 4)], True), ([(0, 2), (2, 4)], True),
-                         ([(0, 4)], False)):
+    # How the visits are made: the folded walk's three passes are trips of
+    # one loop in the program, a stage's visits are none.
+    for splits, fold, scanned in (([(0, 4)], True, 3),
+                                  ([(0, 2), (2, 4)], True, 0),
+                                  ([(0, 4)], False, 0)):
+        trips = counter("oobleck_loop_scanned_passes_total")
         build(model, devices8, splits, fold=fold)
+        assert counter("oobleck_loop_scanned_passes_total") - trips == scanned
     assert counter("oobleck_loop_block_visits_total") - before[0] == 3 * 6
     assert counter("oobleck_loop_exits_total") - before[1] == 3 * 3
     # A model that repeats nothing counts nothing.
@@ -272,3 +283,189 @@ def test_the_loop_s_counters_say_what_a_microbatch_goes_through(
         devices=devices8, num_microbatches=2, total_num_microbatches=2,
         microbatch_size=MB, seq_len=SEQ, materialize_params=False)
     assert counter("oobleck_loop_block_visits_total") - before[0] == 3 * 6
+
+
+# ---- the fold is one loop ------------------------------------------------
+
+
+def equations(jaxpr, name, *, in_loop=False):
+    """[(equation, inside a `scan` body?)] of the primitive `name`, through
+    every sub-jaxpr (a checkpoint's, a call's, a loop's body)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            out.append((eqn, in_loop))
+        inner = in_loop or eqn.primitive.name == "scan"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(equations(sub, name, in_loop=inner))
+    return out
+
+
+def bwd_jaxpr(pipe):
+    """The jaxpr of a one-stage pipeline's `jit_bwd` (first and last)."""
+    st = pipe.stages[0]
+    params = tuple(pipe.params[li] for li in st.chunks[0])
+    tokens = {"input_ids": jnp.zeros((MB, SEQ), jnp.int32)}
+    return jax.make_jaxpr(st.bwd[0])(params, params, None, tokens).jaxpr
+
+
+def block_products(jaxpr):
+    """(outside a loop, inside one): the products of the repeated blocks
+    (the model's scope `loop_blocks`) in `jaxpr`."""
+    where = [inside for eqn, inside in equations(jaxpr, "dot_general")
+             if "loop_blocks" in str(eqn.source_info.name_stack)]
+    return where.count(False), where.count(True)
+
+
+@pytest.fixture(scope="module")
+def folded_jaxprs(devices8):
+    """passes -> the folded `jit_bwd`'s jaxpr, ouro-tiny's two blocks."""
+    return {passes: bwd_jaxpr(build(
+        build_model("ouro-tiny", {"dtype": jnp.float32,
+                                  "num_passes": passes}),
+        devices8, SPLITS[FOLDED])) for passes in (1, 2, 3)}
+
+
+def the_fold_s(jaxpr):
+    """The `scan`s of `jaxpr`: ouro-tiny's layers bring none themselves."""
+    return equations(jaxpr, "scan")
+
+
+def test_the_folded_program_holds_the_range_s_body_once(folded_jaxprs):
+    """Forward and backward are a `scan` each, `num_passes` long; every
+    product of a block is in their bodies, as many at three passes as at
+    two; outside them only the exits grow with the passes."""
+    inside = {}
+    for passes in (2, 3):
+        scans = the_fold_s(folded_jaxprs[passes])
+        assert [(eqn.params["length"], nested) for eqn, nested in scans] == [
+            (passes, False)] * 2
+        outside, inside[passes] = block_products(folded_jaxprs[passes])
+        assert outside == 0
+    assert inside[2] == inside[3] > 0
+    # The same blocks in a Python loop, once through: as many products.
+    assert block_products(folded_jaxprs[1]) == (inside[3], 0)
+
+
+def test_the_running_sums_ride_the_loop_s_carry(folded_jaxprs, model):
+    """The backward loop carries, beside the pipeline's carry, a tree
+    shaped as the repeated blocks' parameters that starts from the running
+    gradient sum and takes every trip's gradient (`_route`): the forward
+    loop hands the same tree on untouched, which a closed-over weight's
+    own accumulators (filled with zeros, in the backward loop alone) would
+    not show. (What the chip's compiler makes of it, 1.2 GB of
+    temporaries less at `ouro-2.6b`'s size, is in `PERF.md` §6, PR 65: the
+    cell-sized compile takes 44-58 s here, too long for a test.)"""
+    (forward, _), (backward, _) = the_fold_s(folded_jaxprs[3])
+    leaves = sum(len(jax.tree.leaves(jax.eval_shape(
+        lambda r: model.init_layer(r, li), jax.random.PRNGKey(0))))
+        for li in model.repeated_layers)
+    assert forward.params["num_carry"] == 3 + leaves   # h, exits, gates
+    assert backward.params["num_carry"] >= 3 + leaves
+
+
+def test_the_fold_on_a_stage_of_two_chips_sums_what_one_chip_sums(
+        model, seeded, devices8):
+    """Under the partitioner (a stage of two chips that split the
+    microbatch) the sums that ride the loop take the chips' reduced
+    gradients as one chip's do: the arrays are global ones."""
+    batch = np.random.default_rng(1).integers(
+        0, 256, size=(NUM_MB, 2, SEQ), dtype=np.int32)
+    grads = []
+    for chips in (1, 2):
+        template = make_template(SPLITS[FOLDED], [chips])
+        pipe = PipelineInstance(
+            pipeline_id=0, template=template, ranks=list(range(chips)),
+            model=model, devices=devices8, num_microbatches=NUM_MB,
+            total_num_microbatches=NUM_MB, microbatch_size=2, seq_len=SEQ,
+            params=dict(enumerate(as_list(seeded))))
+        assert pipe.stages[0].use_fsdp == (chips == 2)
+        pipe.train_step(batch)
+        grads.append(jax.tree.map(np.asarray, pipe.grads))
+    for a, b in zip(*map(jax.tree.leaves, grads)):
+        np.testing.assert_allclose(a, b, rtol=3e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("name", ["ouro-tiny one pass", "gpt2-tiny"])
+def test_a_walk_that_repeats_nothing_traces_no_loop(
+        name, folded_jaxprs, devices8):
+    """One pass, or a model that says nothing of passes: the Python loop
+    over the layers, the program every other model has."""
+    if name == "gpt2-tiny":
+        plain = build_model(name, {})
+        n = plain.num_pipeline_layers
+        jaxpr = bwd_jaxpr(build(plain, devices8, [(0, n)]))
+    else:
+        jaxpr = folded_jaxprs[1]
+    assert the_fold_s(jaxpr) == []
+    assert equations(jaxpr, "while") == []
+
+
+@dataclass(frozen=True)
+class CountingConfig:
+    remat: bool = True
+    width: int = 8
+
+
+class Counting:
+    """A layer list on the contract alone: [embed, a, b, head], `a` and
+    `b` gone through three times, `b` handing out a load that follows the
+    carry (two numbers: the carry's positive entries, and one a visit)."""
+
+    config = CountingConfig()
+    num_pipeline_layers = 4
+    repeated_layers = range(1, 3)
+    num_passes = 3
+
+    def layer_name(self, index):
+        return ("embed", "a", "b", "head")[index]
+
+    def init_layer(self, rng, index):
+        w = self.config.width
+        return {"w": jax.random.normal(jax.random.fold_in(rng, index),
+                                       (w, w)) / w ** 0.5}
+
+    def load_layers(self, num_tokens):
+        return {2: ("b", 1)}
+
+    def apply_layer(self, index, params, carry, batch, return_load=False):
+        if index == 0:
+            carry = jax.nn.one_hot(batch["input_ids"] % self.config.width,
+                                   self.config.width)
+        out = jnp.tanh(carry @ params["w"]) + (carry if index else 0.0)
+        if return_load:
+            return out, jnp.stack([jnp.sum(out > 0), 1]).astype(jnp.int32)
+        return out
+
+    def loss_from_logits(self, logits, batch):
+        return jnp.mean(logits ** 2)
+
+    def sample_batch(self, batch_size, seq_len):
+        return {"input_ids": jnp.zeros((batch_size, seq_len), jnp.int32)}
+
+
+def test_a_repeated_layer_s_load_is_the_sum_over_the_trips(batch, devices8):
+    model = Counting()
+    PROGRAMS.clear()
+    try:
+        pipe = build(model, devices8, [(0, 4)])
+        st = pipe.stages[0]
+        assert st.walks == ((0, 1, 2, 1, 2, 1, 2, 3),)
+        assert st.load_layers == [(2,)]
+        pipe.train_step(batch)
+        ((layers, load),) = pipe.load
+    finally:
+        PROGRAMS.clear()
+    # The same applications one after another, no program around them.
+    want = 0
+    for mb in batch:
+        carry = None
+        for li in st.walks[0][:-1]:
+            carry = model.apply_layer(li, pipe.params[li], carry,
+                                      {"input_ids": mb}, return_load=li == 2)
+            if li == 2:
+                carry, visit = carry
+                want = want + visit
+    assert layers == (2,) and load.shape == (1, 2)
+    np.testing.assert_array_equal(np.asarray(load[0]), np.asarray(want))
+    assert int(want[1]) == NUM_MB * 3 and 0 < int(want[0])
