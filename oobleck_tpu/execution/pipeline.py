@@ -91,7 +91,7 @@ from oobleck_tpu.execution.schedule import (
     send_grad_dest,
     validate_interleaving,
 )
-from oobleck_tpu.models.base import layer_walk, repeated
+from oobleck_tpu.models.base import layer_walk, param_bytes, repeated
 from oobleck_tpu.obs import spans, telemetry
 from oobleck_tpu.ops import checkpoint_layer
 from oobleck_tpu.planning.templates import PipelineTemplate
@@ -688,6 +688,13 @@ class PipelineInstance:
                     else:
                         src = self.model.init_layer(rng, li)
                     self.params[li] = jax.device_put(src, st.param_shardings[li])
+        # The optimizer's step in the order it asks the allocator for memory
+        # (`apply_updates`): the layers by their parameters' bytes, largest
+        # first, ties in layer order. Sizes alone decide it, so it is worked
+        # out here, where the parameters are placed; a reconfiguration
+        # builds a new instance and so takes the order again.
+        self.update_order: tuple[int, ...] = tuple(sorted(
+            self.params, key=lambda li: (-param_bytes(self.params[li]), li)))
 
         self.grads: dict[int, Any] = {}
         # Where the last train_step routed (its docstring), or None; and
@@ -1630,6 +1637,8 @@ class PipelineInstance:
     def apply_updates(self, optimizer, opt_state: dict[int, Any],
                       synced_grads: dict[int, Any]) -> dict[int, Any]:
         """Per-layer optimizer step with (possibly DP-synced) grads.
+        CONSUMES `opt_state`: each layer's new state is written into the
+        dict that was given, and that dict is returned.
 
         The update runs as ONE jitted program per layer signature (jax.jit
         specializes per input shapes/shardings internally). Eager optax is
@@ -1637,16 +1646,32 @@ class PipelineInstance:
         one tiny program PER LEAF over sharded arrays — on a 2-chip
         expert-sharded MoE stage under jax.distributed that turned a step
         into minutes of collective-compile churn (the round-5 elastic-MoE
-        recovery hang). No donation: live-mirror snapshots hold references
-        to the pre-step arrays (engine._write_mirror), which donation
-        would invalidate."""
+        recovery hang).
+
+        The layers go in `update_order`, largest first, and the step keeps
+        nothing it has replaced: once layer k's update is enqueued, neither
+        `self.params` nor `opt_state` refers to its old arrays. A layer's
+        outputs are its new weights and AdamW's two moments, so the largest
+        layer (a vocabulary's head, an expert stack) asks for three blocks
+        of its own size. Asked for first, they come out of what `jit_bwd`'s
+        temporaries just gave back, and a step's last allocations are a
+        block's small leaves. Asked for last, with every old moment still
+        held, they met memory the small leaves had cut up, and the runtime
+        defragmented with the device idle (`ouro-2.6b.steady`, PERF.md
+        section 6, PR 66). The layers' updates share nothing, so any order
+        gives the same bits.
+
+        No donation: `jax.device_put(x, x's own device)` returns a new Array
+        over THE SAME buffer, and donating `x` deletes both. The benchmark's
+        `hold` (`benchmarks/runners/train_hostloss.py:457`) keeps moments
+        that way, and the live mirror (`engine._write_mirror`) holds the
+        pre-step arrays themselves. Whoever else holds an old array keeps it
+        alive by holding it; the step only stops being one of the holders."""
         fn = optimizer_update_program(optimizer)
-        new_state = dict(opt_state)
-        for li in self.params:
-            self.params[li], new_state[li] = fn(
-                synced_grads[li], opt_state[li], self.params[li]
-            )
-        return new_state
+        for li in self.update_order:
+            self.params[li], opt_state[li] = fn(
+                synced_grads[li], opt_state[li], self.params[li])
+        return opt_state
 
     def init_opt_state(self, optimizer) -> dict[int, Any]:
         return {li: optimizer.init(p) for li, p in self.params.items()}

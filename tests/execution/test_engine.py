@@ -129,10 +129,13 @@ def test_dp_sync_consistency(trained_engine):
                 np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
 
     for pipe in e.pipelines:
+        # As the engine's step does; the dict that comes back is the one
+        # that went in, with every layer's new state.
+        state = e.opt_states[pipe.pipeline_id]
         e.opt_states[pipe.pipeline_id] = pipe.apply_updates(
-            e.optimizer, e.opt_states[pipe.pipeline_id],
-            synced[pipe.pipeline_id],
+            e.optimizer, state, synced[pipe.pipeline_id],
         )
+        assert e.opt_states[pipe.pipeline_id] is state
     for li in shared:
         ps = owners[li]
         ref = _np_leaves(ps[0].params[li])

@@ -175,7 +175,8 @@ def test_optimizer_step_changes_params(model, batch, devices8):
     opt = make_optimizer(learning_rate=1e-2, warmup_steps=1)
     state = pipe.init_opt_state(opt)
     before = np.asarray(pipe.params[1]["attn"]["wqkv"]).copy()
-    pipe.apply_updates(opt, state, pipe.grads)
+    # The step consumes the dict it is given: the new state is in it.
+    assert pipe.apply_updates(opt, state, pipe.grads) is state
     after = np.asarray(pipe.params[1]["attn"]["wqkv"])
     assert not np.allclose(before, after)
 
@@ -184,12 +185,12 @@ def test_optimizer_step_changes_params(model, batch, devices8):
 # interleaved schedule parity
 
 
-def _make_pipe(model, devices, template, v, num_mb=NUM_MB):
+def _make_pipe(model, devices, template, v, num_mb=NUM_MB, params=None):
     return PipelineInstance(
         pipeline_id=0, template=template,
         ranks=list(range(template.num_chips)), model=model, devices=devices,
         num_microbatches=num_mb, total_num_microbatches=num_mb,
-        microbatch_size=MB, seq_len=SEQ, virtual_stages=v,
+        microbatch_size=MB, seq_len=SEQ, virtual_stages=v, params=params,
     )
 
 
