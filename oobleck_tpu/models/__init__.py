@@ -97,6 +97,25 @@ register("qwen3-next-80b-a3b")(lambda o: _qwen3_next(o))
 register("qwen3-next-tiny")(lambda o: _qwen3_next(o, vocab_size=256, hidden_size=64, num_layers=4, linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=16, chunk_size=16, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=128, moe_intermediate_size=32, shared_expert_intermediate_size=32, num_experts=16, num_experts_per_tok=4, max_position_embeddings=128))
 
 
+def _kimi_linear(overrides: dict[str, Any], **preset):
+    from oobleck_tpu.models.kimi_linear import (
+        KimiLinearConfig,
+        KimiLinearModel,
+    )
+
+    return KimiLinearModel(
+        KimiLinearConfig().override(**preset).override(**overrides))
+
+
+# Kimi-Linear family (`kimi_linear`): Kimi Delta Attention (a delta rule
+# whose decay is a vector a head) three layers in four beside latent
+# attention without positions, a dense first layer, then top-k
+# sigmoid-routed experts beside a shared one; the defaults are
+# Kimi-Linear-48B-A3B-Instruct's.
+register("kimi-linear-48b-a3b")(lambda o: _kimi_linear(o))
+register("kimi-linear-tiny")(lambda o: _kimi_linear(o, vocab_size=256, hidden_size=64, num_layers=5, kda_layers=(1, 2, 3, 5), full_attn_layers=(4,), linear_num_heads=4, linear_head_dim=16, gate_rank=8, chunk_size=16, num_heads=4, head_dim=16, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128, moe_intermediate_size=32, num_experts=16, num_experts_per_tok=4, max_position_embeddings=128))
+
+
 def _smallthinker(overrides: dict[str, Any], **preset):
     from oobleck_tpu.models.smallthinker import (
         SmallThinkerConfig,

@@ -13,7 +13,10 @@ Every block is `h = x + Attn(N(x)); y = h + FF(N(h))`, RMSNorm `N`:
         Rotary (rotate-half, whole `qk_rope_head_dim`) on q_rope and
         k_rope; scores over the `qk_nope_head_dim + qk_rope_head_dim`
         wide [nope | rope], values `v_head_dim` wide
-        (`ops/attention.latent_attention`).
+        (`ops/attention.latent_attention`). The mixer is `latent_mixer`,
+        which has two users: this family, WITH the rotary, and
+        `models/kimi_linear.py`'s latent layers, WITHOUT (no positions:
+        q_rope and k_rope enter the scores as projected).
   FF    dense   SwiGLU of `intermediate_size`;
         routed  sigmoid scores over ALL `num_experts`, the top k of
                 score + bias (one group: `n_group` = `topk_group` = 1),
@@ -169,15 +172,25 @@ class DeepseekV3Model(RoutedShareModel):
 
     @jax.named_scope("attention")
     def operator_out(self, block: int, p, h):
-        c = self.config
-        p, dt = p["attn"], c.dtype
-        dn, r = c.qk_nope_head_dim, c.kv_lora_rank
-        q = jnp.einsum("bse,ehd->bhsd", h, p["wq"].astype(dt))
-        kv_a = h @ p["wkv_a"].astype(dt)                  # [B, S, r + Dr]
-        latent = rms_norm(kv_a[..., :r], p["kv_norm"], c.latent_norm_eps)
-        kv = jnp.einsum("bsr,rhd->bhsd", latent, p["wkv_b"].astype(dt))
-        attn = latent_attention(
-            q[..., :dn], rotate_half(q[..., dn:], c.rope_theta),
-            kv[..., :dn], rotate_half(kv_a[..., r:], c.rope_theta),
-            kv[..., dn:], impl=c.attention_impl)
-        return jnp.einsum("bhsd,hde->bse", attn, p["wo"].astype(dt))
+        theta = self.config.rope_theta
+        return latent_mixer(self.config, p["attn"], h,
+                            rotary=lambda x: rotate_half(x, theta))
+
+
+def latent_mixer(c, p, h, *, rotary):
+    """Latent attention of `h` [B, S, E] under the projections `p` (`wq`,
+    `wkv_a`, `kv_norm`, `wkv_b`, `wo`) and the widths of `c`. `rotary`
+    turns q_rope [B, H, S, Dr] and the shared k_rope [B, S, Dr] by their
+    positions; None leaves both as projected (`models/kimi_linear.py`'s
+    latent layers, which carry no positions)."""
+    dt = c.dtype
+    dn, r = c.qk_nope_head_dim, c.kv_lora_rank
+    rotary = rotary or (lambda x: x)
+    q = jnp.einsum("bse,ehd->bhsd", h, p["wq"].astype(dt))
+    kv_a = h @ p["wkv_a"].astype(dt)                  # [B, S, r + Dr]
+    latent = rms_norm(kv_a[..., :r], p["kv_norm"], c.latent_norm_eps)
+    kv = jnp.einsum("bsr,rhd->bhsd", latent, p["wkv_b"].astype(dt))
+    attn = latent_attention(
+        q[..., :dn], rotary(q[..., dn:]), kv[..., :dn], rotary(kv_a[..., r:]),
+        kv[..., dn:], impl=c.attention_impl)
+    return jnp.einsum("bhsd,hde->bse", attn, p["wo"].astype(dt))

@@ -2,8 +2,8 @@
 dispatch; `flash.py`: the Pallas flash kernels and their latent, windowed
 and differential forms; `ring_attention.py`; `ulysses.py`;
 `paged_attention.py`: paged decode and verify), the routed experts
-(`moe.py`), the three recurrences (`ssd.py`, `gdn.py`, `sscan.py`: two
-kernels each), the layers' checkpoint policy (`remat.py`) and what two or
+(`moe.py`), the recurrences (`ssd.py`, `gdn.py`, `sscan.py`: two kernels
+each; `kda.py`: `jax.numpy` alone so far), the layers' checkpoint policy (`remat.py`) and what two or
 more kernel modules need (`kernel.py`). Heavy submodules import lazily at
 their call sites; this surface re-exports the dispatching entry points.
 

@@ -10,13 +10,14 @@ from __future__ import annotations
 
 import jax
 
-from oobleck_tpu.ops import flash, gdn, sscan, ssd
+from oobleck_tpu.ops import flash, gdn, kda, sscan, ssd
 
 KEPT = (
     *flash.RESIDUAL_NAMES,   # what the flash forward kernel wrote: O, LSE
     *gdn.RESIDUAL_NAMES,     # the delta rule's inverse, and what its forward
                              # kernel wrote: o, the state at every chunk's
                              # start
+    *kda.RESIDUAL_NAMES,     # the Kimi delta rule's inverse
     *ssd.RESIDUAL_NAMES,     # what the scan's forward kernel wrote: y, the
                              # state at every chunk's start
     *sscan.RESIDUAL_NAMES,   # what the selective scan's forward kernel

@@ -585,6 +585,33 @@ def test_gdn_compiles_inside_check_vma_shard_map_under_the_layers_checkpoint(
         assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == 1
 
 
+# The Kimi delta rule (`ops/kda.py`: `jax.numpy` alone, no kernel yet) at
+# `kimi-linear-48b-a3b`'s call: [1, 4096, 32, 128] q, k, v and a decay a
+# CHANNEL, chunks of 64, differentiated under the layer's checkpoint. What
+# the compile holds it to is its memory: no array with a [64, 64, 128]
+# block a head (the decayed products written out would be 4.3 GB), and all
+# its temporaries together no more than they are: 1,857,094,144 B (1.73 GiB)
+# when this was written, held here with 4 % of room. ISSUE 67's target is
+# 1 GB a layer: the `perf_opt` that takes the levels into VMEM (PERF.md
+# section 7) TIGHTENS this number, and nothing may loosen it.
+def test_kda_compiles_at_the_cell_and_writes_no_q_q_dk_block(v5e):
+    from oobleck_tpu.ops.kda import kimi_delta_rule
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    shape = (1, 4096, 32, 128)
+    one = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in
+            [(shape, bf)] * 3 + [(shape, f32), (shape[:3], f32)]]
+    rule = checkpoint_layer(lambda *a: kimi_delta_rule(*a, chunk=64))
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(rule(*a).astype(f32)),
+        argnums=range(5))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert not re.search(r"= (?:bf16|f32)\[[\d,]*64,64,128\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.8 * 2 ** 30
+
+
 # Every kernel has a stable name on the device: `name=` on its pallas_call
 # is the innermost component of the operation's JAX name stack, and the
 # chip's compiler names the custom call after that component. A profiler
